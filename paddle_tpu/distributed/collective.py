@@ -97,13 +97,12 @@ def axis_index(group: AxisName):
 
 
 def axis_size(group: AxisName):
-    from ..utils.jax_compat import axis_size as _axis_size
-    return _axis_size(group)
+    return lax.axis_size(group)
 
 
 # ------------------------------------------------------------ eager facades
 def _eager(fn, x, group, out_spec=None, in_spec=None):
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     mesh = get_mesh()
     in_spec = in_spec if in_spec is not None else P(group)
     out_spec = out_spec if out_spec is not None else in_spec

@@ -84,13 +84,14 @@ def supervise(argv: Sequence[str], max_restarts: int = 3,
     topology manifest on resume.
 
     compile_cache_dir: persistent XLA compilation cache shared by every
-    (re)launch — injected into children as
-    ``$PADDLE_TPU_COMPILE_CACHE_DIR`` (the child's ``Trainer.train``
-    resolves it via ``utils.compile_cache.enable``), so a
-    preempted-and-relaunched worker restores its step executable from
-    disk instead of paying full recompilation. None inherits the
-    supervisor's env (which may itself carry the var); the supervisor
-    never imports jax — the child owns the accelerator.
+    (re)launch — handed to children as ``$JAX_COMPILATION_CACHE_DIR``,
+    which their jax reads at import, so a preempted-and-relaunched
+    worker restores its step executable from disk instead of paying
+    full recompilation. The supervisor's own
+    ``$JAX_COMPILATION_CACHE_DIR`` wins over this argument; with
+    neither, each child's ``utils.compile_cache.enable`` resolves the
+    same fixed in-checkout directory. The supervisor never imports jax
+    — the child owns the accelerator.
 
     run_dir: where to land the SUPERVISOR'S OWN telemetry on exit —
     ``flight_supervisor.json`` (child launch/exit events with rcs) and
@@ -107,9 +108,7 @@ def supervise(argv: Sequence[str], max_restarts: int = 3,
     # flight_<attempt>.json / trace_<attempt>.json, so an elastic run's
     # attempts sit side by side in one run dir and stitch into one
     # timeline (epoch-microsecond trace timestamps).
-    base_env = compile_cache.child_env(compile_cache_dir) \
-        if compile_cache.resolve_dir(compile_cache_dir) \
-        else dict(os.environ)
+    base_env = compile_cache.child_env(compile_cache_dir)
     base_env[obs.ENV_RUN_ID] = obs.run_id()
     launches = [0]
     preemptions = [0]

@@ -28,8 +28,8 @@ TPU-native design — everything the XLA program sees is STATIC:
   logprob, done) per tick and re-uploads its numpy mirrors only on
   slot transitions. Steady-state decode is therefore exactly one
   dispatch + one small D2H per token — none of the per-tick
-  ``jnp.asarray`` uploads and Python stop/eos bookkeeping that left
-  the r05 bench at 49 tok/s. ``fused_tick=False`` restores the
+  ``jnp.asarray`` uploads and Python stop/eos bookkeeping of the
+  original per-tick host path. ``fused_tick=False`` restores the
   per-tick host path (the bit-exactness reference).
 - ``spec_tokens=k`` (ISSUE 7) turns each fused tick into a speculative
   MULTI-token tick: a device-resident prompt-lookup proposer (shared
@@ -97,6 +97,7 @@ block 0) so they can never corrupt a live block; it is never allocated.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
@@ -128,6 +129,38 @@ _engine_ids = itertools.count()
 _SPEC_EMA_ALPHA = 0.3      # EMA step toward this tick's accept fraction
 _SPEC_EMA_FLOOR = 0.25     # below: stop drafting (probes only)
 _SPEC_PROBE_EVERY = 16     # collapsed rows re-probe with k=1 this often
+
+
+def _home_device(params):
+    """Where an engine over ``params`` lives: the one device every
+    weight is on; the current default device for a weightless stub;
+    None when the weights span several devices (a sharded model keeps
+    JAX's own placement)."""
+    devs = set()
+    for leaf in jax.tree_util.tree_leaves(params):
+        if isinstance(leaf, jax.Array):
+            devs |= leaf.devices()
+    if not devs:
+        devs = jnp.zeros((), jnp.int32).devices()
+    return devs.pop() if len(devs) == 1 else None
+
+
+def _on_device(method):
+    """Run an engine entry point inside the engine's own default-device
+    scope, whatever the calling thread's is. jit keys its caches on the
+    THREAD-LOCAL default device, so without one fixed scope every
+    program compiled while an engine is warmed up inside
+    ``jax.default_device(dev)`` (how its weights get to ``dev``) is
+    traced and loaded a second time by the gateway's tick thread, which
+    inherits no scope — seconds per program at real widths, under the
+    watchdog's deadline (PR 21: the first chip runs paid ~10 s of this
+    while serving). The eager helper ops between programs land on the
+    engine's device for the same reason."""
+    @functools.wraps(method)
+    def scoped(self, *args, **kwargs):
+        with jax.default_device(self.device):
+            return method(self, *args, **kwargs)
+    return scoped
 
 
 class PagedKV(NamedTuple):
@@ -218,6 +251,26 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
     return dense_attention(q, ks, vs, attn_mask=keep[None, None])
 
 
+def paged_decode_route(q, kp) -> str:
+    """Which attention path ``paged_decode_attention`` takes for q
+    [R, T, h, d] against pools shaped like ``kp`` [P, B, kvh, d]:
+    ``"ragged"`` (the schedule-driven Pallas kernel, the default),
+    ``"grid"`` (the grid-per-row Pallas kernel, single-query only) or
+    ``"dense"`` (XLA whole-table gather). Only shapes are read, so a
+    caller can ask with the engine's geometry (``PagedEngine.
+    decode_route``) and see the choice the traced program made — the
+    shape gates drop to dense silently otherwise."""
+    import os
+
+    from ..ops.pallas.paged_attention import use_paged_kernel
+    mode = os.environ.get("PADDLE_TPU_PAGED_ATTN", "ragged")
+    if mode == "dense" or not use_paged_kernel(q, kp):
+        return "dense"
+    if mode == "grid":
+        return "grid" if q.shape[1] == 1 else "dense"
+    return "ragged"
+
+
 def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
                            window: Optional[int] = None):
     """q [R, T, h, d] against each row's blocks: query t of row r sits
@@ -230,33 +283,29 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     one grid over the batch's ACTUAL live blocks, packed live-first, no
     per-request padding (ISSUE 6); it serves both T == 1 and the
     multi-query rows. ``PADDLE_TPU_PAGED_ATTN=grid`` keeps the
-    r05-hardware-validated grid-per-row kernel (single-query only —
-    multi-query falls through to dense under it); ``=dense`` forces the
-    fallback. Fallback (CPU tests / odd shapes): dense whole-table
-    gather — the math is dense_attention's, only the gather and the
-    per-(row, position) mask live here."""
-    import os
-
+    grid-per-row kernel (single-query only — multi-query falls through
+    to dense under it); ``=dense`` forces the fallback. Fallback (CPU
+    tests / odd shapes): dense whole-table gather — the math is
+    dense_attention's, only the gather and the per-(row, position) mask
+    live here. ``paged_decode_route`` is the one place that chooses."""
     from ..ops.attention import dense_attention
-    from ..ops.pallas.paged_attention import (paged_attention_pallas,
-                                              use_paged_kernel)
-    from ..ops.pallas.ragged_paged_attention import \
-        ragged_paged_attention_pallas
     R, T = q.shape[0], q.shape[1]
     kvh, d = pk.kp.shape[2], pk.kp.shape[3]
-    mode = os.environ.get("PADDLE_TPU_PAGED_ATTN", "ragged")
-    if mode != "dense" and use_paged_kernel(q, pk.kp):
+    route = paged_decode_route(q, pk.kp)
+    if route != "dense":
         sc = scale if scale is not None else d ** -0.5
-        if T == 1:
-            fn = (paged_attention_pallas if mode == "grid"
-                  else ragged_paged_attention_pallas)
-            out = fn(q[:, 0], pk.kp, pk.vp, pk.block_tables,
-                     pk.seq_lens, sc, window=window)
+        if route == "grid":
+            from ..ops.pallas.paged_attention import paged_attention_pallas
+            out = paged_attention_pallas(q[:, 0], pk.kp, pk.vp,
+                                         pk.block_tables, pk.seq_lens,
+                                         sc, window=window)
             return out[:, None]
-        if mode != "grid":
-            return ragged_paged_attention_pallas(
-                q, pk.kp, pk.vp, pk.block_tables, pk.seq_lens, sc,
-                window=window)
+        from ..ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention_pallas
+        out = ragged_paged_attention_pallas(
+            q if T > 1 else q[:, 0], pk.kp, pk.vp, pk.block_tables,
+            pk.seq_lens, sc, window=window)
+        return out if T > 1 else out[:, None]
     ks = pk.kp[pk.block_tables]                  # [R, M, B, kvh, d]
     vs = pk.vp[pk.block_tables]
     Tk = ks.shape[1] * ks.shape[2]
@@ -475,6 +524,16 @@ class PagedEngine:
         cfg = model.config
         self.model = model
         self.fn, self.params = model.functional()
+        # placement: the engine lives where its weights live. Params are
+        # committed there; pools, masks and every later upload name the
+        # device (``_put`` / ``_zeros``) and every entry point runs
+        # inside its default scope (``_on_device``) — the process
+        # default is thread-local, and a gateway tick thread would
+        # otherwise upload to device 0 and hop. None (weights sharded
+        # over several devices) keeps JAX's default placement.
+        self.device = _home_device(self.params)
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
         self.R, self.P, self.B, self.M = (max_slots, num_blocks,
                                           block_size, max_blocks_per_seq)
         self.prefill_buckets = sorted(prefill_buckets)
@@ -519,11 +578,7 @@ class PagedEngine:
         # the arena outlives the engine (supervisor rebuilds re-attach
         # it), which is what makes a crashed replica come back warm.
         self._spill = None
-        L = cfg.num_hidden_layers
-        kvh, d = cfg.num_key_value_heads, cfg.head_dim
-        self.pools = [(jnp.zeros((self.P, self.B, kvh, d), cfg.dtype),
-                       jnp.zeros((self.P, self.B, kvh, d), cfg.dtype))
-                      for _ in range(L)]
+        self.pools, self.seen = self._fresh_device_arrays()
         # block 0 is the garbage block: pad scatter lands there
         self.free_blocks = list(range(1, self.P))
         self.block_tables = np.zeros((self.R, self.M), np.int32)
@@ -534,9 +589,6 @@ class PagedEngine:
         self.top_ps = np.ones((self.R,), np.float32)
         self.reps = np.ones((self.R,), np.float32)
         self.keys = np.zeros((self.R, 2), np.uint32)
-        # per-row seen-token masks for the repetition penalty: seeded by
-        # the prefill scatter, updated inside the jitted decode step
-        self.seen = jnp.zeros((self.R, cfg.vocab_size), bool)
         self.slots: List[Optional[_Request]] = [None] * self.R
         self.queue: List[_Request] = []
         self.results: Dict[Any, List[int]] = {}
@@ -666,7 +718,6 @@ class PagedEngine:
         # _scan_ticks); K=1 (default) keeps strict per-tick scheduling.
         self._ticks_per_dispatch = max(1, int(ticks_per_dispatch))
         if self._ticks_per_dispatch > 1:
-            import functools
             self._scan_greedy_jit = jax.jit(
                 functools.partial(self._fused_scan, greedy=True,
                                   K=self._ticks_per_dispatch),
@@ -702,7 +753,6 @@ class PagedEngine:
                     "spec_tokens requires fused_tick=True: the "
                     "proposer/verify/commit live inside the fused "
                     "device program")
-            import functools
             self._tick_spec_jit = jax.jit(
                 functools.partial(self._fused_tick_spec, greedy=False),
                 donate_argnums=(1, 2))
@@ -810,6 +860,41 @@ class PagedEngine:
             # replica leaves tickphase_<engine>.json in the run dir
             # beside its series/reqtrace files
             obs.register_flusher(self._flush_tick_profile)
+
+    # ---------------------------------------------------------- placement
+    # Uploads and allocations are COMMITTED to the engine's device, like
+    # its params and like every program output: a program whose inputs
+    # go from uncommitted (first call) to committed (its own outputs fed
+    # back) is traced twice.
+    def _put(self, x):
+        return jax.device_put(x, self.device)
+
+    def _zeros(self, shape, dtype):
+        return jnp.zeros(shape, dtype, device=self.device)
+
+    def _fresh_device_arrays(self):
+        """New KV pools (one [P, B, kvh, d] K/V pair per layer) and the
+        per-row seen-token masks for the repetition penalty (seeded by
+        the prefill scatter, updated inside the jitted decode step).
+        ``hard_reset`` takes fresh ones too: the old arrays may be
+        donated into a dead or in-flight program."""
+        cfg = self.model.config
+        shape = (self.P, self.B, cfg.num_key_value_heads, cfg.head_dim)
+        pools = [(self._zeros(shape, cfg.dtype),
+                  self._zeros(shape, cfg.dtype))
+                 for _ in range(cfg.num_hidden_layers)]
+        return pools, self._zeros((self.R, cfg.vocab_size), bool)
+
+    def decode_route(self) -> str:
+        """The attention path this engine's decode tick takes
+        (``paged_decode_route`` asked with the tick's own q and pool
+        shapes): "ragged" or "grid" is a Pallas kernel, "dense" the XLA
+        whole-table gather."""
+        cfg = self.model.config
+        q = jax.ShapeDtypeStruct(
+            (self.R, self._spec_k + 1, cfg.num_attention_heads,
+             cfg.head_dim), cfg.dtype)
+        return paged_decode_route(q, self.pools[0][0])
 
     # ------------------------------------------------------ tick profiler
     @property
@@ -1373,8 +1458,8 @@ class PagedEngine:
             prof = self._prof
             if prof is not None:
                 tp = prof.clock()
-            self._dev["pq"] = jnp.asarray(pq)
-            self._dev["pqn"] = jnp.asarray(np.int32(len(rows)))
+            self._dev["pq"] = self._put(pq)
+            self._dev["pqn"] = self._put(np.int32(len(rows)))
             if prof is not None:
                 prof.add("h2d", (prof.clock() - tp) * 1e3)
             nbytes = pq.nbytes + 4
@@ -1399,7 +1484,7 @@ class PagedEngine:
             self._count("delta_patches")
             self._count("h2d_upload_bytes", desc.nbytes)
             self._h_bytes.observe(desc.nbytes)
-            self._dev = self._patch_jit(self._dev, jnp.asarray(desc))
+            self._dev = self._patch_jit(self._dev, self._put(desc))
             # the device now holds this row's authoritative key (the
             # patch either uploaded the host's override or preserved
             # the device stream), same as a rebuild's upload
@@ -1459,17 +1544,17 @@ class PagedEngine:
         if prof is not None:
             tp = prof.clock()
         self._dev = dict(
-            tables=jnp.asarray(self.block_tables),
-            lens=jnp.asarray(self.seq_lens),
-            last=jnp.asarray(last),
-            keys=jnp.asarray(self.keys),
-            temps=jnp.asarray(self.temps),
-            tks=jnp.asarray(self.top_ks),
-            tps=jnp.asarray(self.top_ps),
-            reps=jnp.asarray(self.reps),
-            eos=jnp.asarray(eos),
-            rem=jnp.asarray(rem),
-            active=jnp.asarray(act),
+            tables=self._put(self.block_tables),
+            lens=self._put(self.seq_lens),
+            last=self._put(last),
+            keys=self._put(self.keys),
+            temps=self._put(self.temps),
+            tks=self._put(self.top_ks),
+            tps=self._put(self.top_ps),
+            reps=self._put(self.reps),
+            eos=self._put(eos),
+            rem=self._put(rem),
+            active=self._put(act),
         )
         if self._spec_k:
             # committed-stream buffer the n-gram proposer matches over
@@ -1486,24 +1571,24 @@ class PagedEngine:
                 tk[i] = token_buffer_row(s.prompt + s.tokens, Lbuf)
                 ema[i] = s.spec_ema
             nbytes += tk.nbytes + ema.nbytes
-            self._dev.update(toks=jnp.asarray(tk), ema=jnp.asarray(ema),
-                             tickc=jnp.zeros((self.R,), jnp.int32))
+            self._dev.update(toks=self._put(tk), ema=self._put(ema),
+                             tickc=self._zeros((self.R,), jnp.int32))
         if self._ring:
             # async token ring (ISSUE 11): rebuilt empty on every
             # refresh — a refresh only ever runs with the ring fully
             # drained (every transition drains first), so resetting
             # the write cursors cannot lose entries
             self._dev.update(
-                ring=jnp.zeros((self.R, self._ring_len), jnp.int32),
-                rlps=jnp.zeros((self.R, self._ring_len), jnp.float32),
-                wcur=jnp.zeros((self.R,), jnp.int32))
+                ring=self._zeros((self.R, self._ring_len), jnp.int32),
+                rlps=self._zeros((self.R, self._ring_len), jnp.float32),
+                wcur=self._zeros((self.R,), jnp.int32))
             if self._spec_k:
                 # per-dispatch proposer stats ride the state so the
                 # drain can count spec_proposed/accepted without a
                 # second readback
                 self._dev.update(
-                    kprop_last=jnp.zeros((self.R,), jnp.int32),
-                    macc_last=jnp.zeros((self.R,), jnp.int32))
+                    kprop_last=self._zeros((self.R,), jnp.int32),
+                    macc_last=self._zeros((self.R,), jnp.int32))
             self._drained[:] = 0
         if self._fuse_patches:
             # empty staged-patch queue: a rebuild by definition leaves
@@ -1511,8 +1596,9 @@ class PagedEngine:
             # host-side payload, and the tests pin the rebuild byte
             # cost as the non-fused reference)
             self._dev.update(
-                pq=jnp.zeros((self._pq_len, self._desc_len), jnp.int32),
-                pqn=jnp.zeros((), jnp.int32))
+                pq=self._zeros((self._pq_len, self._desc_len),
+                               jnp.int32),
+                pqn=self._zeros((), jnp.int32))
         if prof is not None:
             prof.add("h2d", (prof.clock() - tp) * 1e3)
         self.h2d_upload_bytes += nbytes
@@ -1575,6 +1661,7 @@ class PagedEngine:
                 [(c.kp, c.vp) for c in new_caches])
 
     # ------------------------------------------------------------- host
+    @_on_device
     def submit(self, request_id, input_ids, max_new_tokens: int = 32,
                eos_token_id: Optional[int] = None,
                temperature: float = 0.0, top_k: int = 0,
@@ -1800,6 +1887,7 @@ class PagedEngine:
                               self.prefix_generation)
         self._count("spill_spans", n)
 
+    @_on_device
     def spill_parked(self) -> int:
         """Bank EVERY live prefix-cache span into the arena (gateway
         drain / SIGTERM: the device pool is about to die, the arena is
@@ -1813,6 +1901,7 @@ class PagedEngine:
         self._count("spill_spans", n)
         return n
 
+    @_on_device
     def spill_live(self) -> int:
         """Bank every ACTIVE slot's computed KV span into the arena
         (drain migration / crash salvage, ISSUE 18). For each live
@@ -1934,8 +2023,8 @@ class PagedEngine:
         self._count("h2d_upload_bytes", padded.nbytes)
         self._h_bytes.observe(padded.nbytes)
         self.pools = self._spill_upload_jit(self.pools,
-                                            jnp.asarray(idx),
-                                            jnp.asarray(padded))
+                                            self._put(idx),
+                                            self._put(padded))
         # register every sub-span over the restored blocks (mirror of
         # _register_prefix), then park them: the caller's normal
         # _prefix_lookup adoption does the rest
@@ -2130,7 +2219,7 @@ class PagedEngine:
             self.seq_lens[slot_id] = cached
             # seed the seen mask with prefix-cache-skipped tokens (their
             # chunks never run); later chunks scatter their own ids
-            seen0 = jnp.zeros((self.seen.shape[1],), bool)
+            seen0 = self._zeros((self.seen.shape[1],), bool)
             if cached:
                 seen0 = seen0.at[np.asarray(ids[:cached])].set(True)
             self.seen = self.seen.at[slot_id].set(seen0)
@@ -2147,9 +2236,9 @@ class PagedEngine:
         self.dispatch_count += 1
         self._count("dispatches")
         nxt, lp, new_key, seen_row, self.pools = self._prefill_jit(
-            self.params, self.pools, jnp.asarray(row),
-            jnp.asarray(padded), np.int32(len(ids)),
-            jnp.asarray(req.key), np.float32(req.temperature),
+            self.params, self.pools, self._put(row),
+            self._put(padded), np.int32(len(ids)),
+            self._put(req.key), np.float32(req.temperature),
             np.int32(req.top_k), np.float32(req.top_p),
             np.float32(req.rep), bucket=bucket)
         self.seen = self.seen.at[slot_id].set(seen_row)
@@ -2187,9 +2276,9 @@ class PagedEngine:
         self.dispatch_count += 1
         self._count("dispatches")
         nxt, lp, new_key, seen_mid, seen_fin, self.pools = self._chunk_jit(
-            self.params, self.pools, jnp.asarray(row),
-            jnp.asarray(padded), np.int32(start),
-            np.int32(start + live), jnp.asarray(req.key),
+            self.params, self.pools, self._put(row),
+            self._put(padded), np.int32(start),
+            np.int32(start + live), self._put(req.key),
             np.float32(req.temperature), np.int32(req.top_k),
             np.float32(req.top_p), np.float32(req.rep),
             self.seen[slot_id], bucket=self.chunk)
@@ -2374,6 +2463,7 @@ class PagedEngine:
                         and now > s.deadline:
                     self._abort(s, "timeout", slot_id=i)
 
+    @_on_device
     def cancel(self, request_id) -> bool:
         """Abort a queued or running request (client disconnect). Its
         blocks/slot free immediately; no result is recorded. Returns
@@ -2415,7 +2505,9 @@ class PagedEngine:
         ticks = snap.get("decode_steps", 0)
         snap["dispatches_per_tick"] = round(
             snap.get("dispatches", 0) / ticks, 4) if ticks else 0.0
+        dev = self.device or jax.devices()[0]
         snap.update(
+            device={"platform": dev.platform, "kind": dev.device_kind},
             queued=len(self.queue),
             queue_capacity=self.max_queue,
             active_slots=sum(s is not None for s in self.slots),
@@ -2590,6 +2682,7 @@ class PagedEngine:
                 out[s.request_id] = _desc(s)
         return out
 
+    @_on_device
     def hard_reset(self):
         """Forcibly return the engine to its empty post-``__init__``
         state WITHOUT touching whatever the device is doing (ISSUE 12:
@@ -2603,12 +2696,7 @@ class PagedEngine:
         on shapes, which don't change), so a restart costs one
         allocation, not a recompile. Counters are monotonic and keep
         counting across the reset."""
-        cfg = self.model.config
-        kvh, d = cfg.num_key_value_heads, cfg.head_dim
-        self.pools = [(jnp.zeros((self.P, self.B, kvh, d), cfg.dtype),
-                       jnp.zeros((self.P, self.B, kvh, d), cfg.dtype))
-                      for _ in range(cfg.num_hidden_layers)]
-        self.seen = jnp.zeros((self.R, cfg.vocab_size), bool)
+        self.pools, self.seen = self._fresh_device_arrays()
         self.free_blocks = list(range(1, self.P))
         self.block_tables = np.zeros((self.R, self.M), np.int32)
         self.seq_lens = np.zeros((self.R,), np.int32)
@@ -2639,6 +2727,7 @@ class PagedEngine:
         obs.record_event("paged_hard_reset",
                          engine=self._obs_labels["engine"])
 
+    @_on_device
     def close(self, drain: bool = True):
         """``drain=True`` (default) runs the engine until every queued
         and in-flight request completes (graceful shutdown);
@@ -2655,6 +2744,7 @@ class PagedEngine:
             if self.slots[i] is not None:
                 self._abort(self.slots[i], "cancelled", slot_id=i)
 
+    @_on_device
     def step(self):
         """One scheduler tick: drain the previous ring dispatch (ring
         mode — its tokens land here, one step behind the device),
@@ -2745,12 +2835,9 @@ class PagedEngine:
         if spec:
             arrs += [st["kprop_last"], st["macc_last"]]
         self.ring_drains += 1
-        try:
-            if not all(a.is_ready() for a in arrs):
-                self.ring_blocking_drains += 1
-                self.d2h_syncs += 1
-        except AttributeError:      # backend without is_ready probes
-            pass
+        if not all(a.is_ready() for a in arrs):
+            self.ring_blocking_drains += 1
+            self.d2h_syncs += 1
         prof = self._prof
         if prof is not None:
             # device-wait vs D2H split (ISSUE 20): block-until-ready is
@@ -2856,18 +2943,14 @@ class PagedEngine:
         # the blocking/all ratio a profiler reads <= 1
         self.ring_drains += 1
         self.ring_scoped_drains += 1
-        try:
-            # probe the DISPATCH OUTPUTS, not the row slices built
-            # below — the slices are freshly enqueued computations
-            # whose is_ready() would read False even when the
-            # in-flight program finished long ago, inflating the
-            # blocking-drain counters a profiler reads as "host
-            # falling behind"
-            if not all(a.is_ready() for a in base_arrs):
-                self.ring_blocking_drains += 1
-                self.d2h_syncs += 1
-        except AttributeError:      # backend without is_ready probes
-            pass
+        # probe the DISPATCH OUTPUTS, not the row slices built below —
+        # the slices are freshly enqueued computations whose is_ready()
+        # would read False even when the in-flight program finished
+        # long ago, inflating the blocking-drain counters a profiler
+        # reads as "host falling behind"
+        if not all(a.is_ready() for a in base_arrs):
+            self.ring_blocking_drains += 1
+            self.d2h_syncs += 1
         prof = self._prof
         if prof is not None:
             # same device/drain bracketing as the global drain; outside
@@ -2947,16 +3030,15 @@ class PagedEngine:
         prof = self._prof
         if prof is not None:
             t = prof.clock()
-            out = jnp.asarray(x)
+            out = self._put(x)
             prof.add("h2d", (prof.clock() - t) * 1e3)
             return out
-        return jnp.asarray(x)
+        return self._put(x)
 
     def _decode_host(self, active):
         """The pre-fusion per-tick path: re-uploads every mirror and
         runs all stop/eos/budget bookkeeping in Python. Kept as the
-        bit-exactness reference for the fused tick (and as a fallback
-        while the ragged kernel awaits its hardware window)."""
+        bit-exactness reference for the fused tick."""
         t_decode = time.perf_counter()
         last = np.zeros((self.R,), np.int32)
         for i in active:

@@ -379,7 +379,7 @@ class LlamaAttention(Layer):
             spec = P(("dp", "fsdp"), "sp", "tp", None)
             ring = functools.partial(ring_attention, axis_name="sp",
                                      causal=True, window=self.window)
-            from ..utils.jax_compat import shard_map
+            from jax import shard_map
             if segment_ids is not None:
                 sspec = P(("dp", "fsdp"), "sp")
                 out = shard_map(
@@ -480,13 +480,16 @@ class LlamaModel(Layer):
                                                    config.hidden_size)
         self.embed_tokens.weight = self.embed_tokens.weight.astype(config.dtype) \
             * jnp.asarray(config.initializer_range / 0.02, config.dtype)
+        # compute-weight dtype (fp32 masters live in the optimizer). Each
+        # layer is cast as it is built: weights are drawn in float32, and
+        # holding every layer's draw until one cast at the end peaks at
+        # three times the bf16 model — more than a 16 GB chip has at the
+        # depths it can otherwise serve. Same values either way.
         self.layers = nn.LayerList(
-            [LlamaDecoderLayer(config, layer_idx=i)
+            [LlamaDecoderLayer(config, layer_idx=i).to(dtype=config.dtype)
              for i in range(config.num_hidden_layers)])
-        self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
-        if config.dtype != jnp.float32:
-            # compute-weight dtype (fp32 masters live in the optimizer)
-            self.to(dtype=config.dtype)
+        self.norm = nn.RMSNorm(config.hidden_size,
+                               config.rms_norm_eps).to(dtype=config.dtype)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,

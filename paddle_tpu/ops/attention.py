@@ -15,13 +15,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _platform() -> str:
-    try:
-        return jax.devices()[0].platform
-    except Exception:  # pragma: no cover
-        return "cpu"
-
-
 def _flash_enabled() -> bool:
     # NOT cached: both terms (env toggles in tests, platform) must be
     # re-read so interpret-mode coverage is real
@@ -29,8 +22,8 @@ def _flash_enabled() -> bool:
         return False
     # interpret mode counts: CPU tests must be able to exercise every
     # branch that will select the kernel on hardware
-    return _platform() == "tpu" or \
-        bool(os.environ.get("PADDLE_TPU_PALLAS_INTERPRET"))
+    from .pallas import kernels_enabled
+    return kernels_enabled()
 
 
 def use_flash(query, key, attn_mask, dropout_p) -> bool:
@@ -121,13 +114,12 @@ def use_decode_kernel(q, k_cache) -> bool:
     exercises the same dispatch glue), MXU-friendly head_dim, a cache
     length with a 128-multiple tile, and a whole number of query heads
     per kv head."""
-    from .pallas import interpret_enabled, kernels_enabled
+    from .pallas import interpret_enabled
     b, s, h, d = q.shape
     T, kv = k_cache.shape[1], k_cache.shape[2]
     if s != 1 or h % kv:
         return False
-    if not (interpret_enabled()
-            or (_flash_enabled() and kernels_enabled())):
+    if not (interpret_enabled() or _flash_enabled()):
         return False
     if interpret_enabled():
         # interpret mode skips Mosaic's tiling checks; any shape the

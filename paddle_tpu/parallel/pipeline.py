@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jax_compat import axis_size as _axis_size
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.env import get_mesh
@@ -41,7 +40,7 @@ def spmd_pipeline(stage_fn: Callable, axis_name: str = "pp"):
     """
 
     def pipelined(stacked_params, microbatches):
-        n_stages = _axis_size(axis_name)
+        n_stages = lax.axis_size(axis_name)
         stage = lax.axis_index(axis_name)
         n_micro = microbatches.shape[0]
         params = jax.tree.map(lambda p: p[0], stacked_params)  # my stage
@@ -95,7 +94,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, microbatches,
     """
     mesh = mesh or get_mesh()
     fn = spmd_pipeline(stage_fn, axis_name)
-    from ..utils.jax_compat import shard_map
+    from jax import shard_map
     return shard_map(
         fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis_name), stacked_params), P()),
@@ -309,7 +308,7 @@ def pipeline_value_and_grad(embed_fn: Callable, stage_fn: Callable,
             loss = lax.psum(c["loss"], axis_name) / M
             return loss, grads
 
-        from ..utils.jax_compat import shard_map
+        from jax import shard_map
         return shard_map(body, mesh=m, in_specs=in_specs,
                          out_specs=out_specs, axis_names={axis_name},
                          check_vma=False)(params, tokens, labels)

@@ -263,7 +263,7 @@ def interleaved_pipeline_value_and_grad(
             loss = lax.psum(c["loss"], axis_name) / M
             return loss, grads
 
-        from ..utils.jax_compat import shard_map
+        from jax import shard_map
         return shard_map(body, mesh=m, in_specs=in_specs + (P(),) * 6,
                          out_specs=out_specs, axis_names={axis_name},
                          check_vma=False)(params, tokens, labels, *xs)

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jax_compat import axis_size as _axis_size
-
 NEG_INF = -1e30
 
 
@@ -62,7 +60,7 @@ def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     if window is not None and not causal:
         raise ValueError("window requires causal=True (sliding-window "
                          "attention narrows the causal band)")
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -123,7 +121,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = False,
     sequence, since each device sees every position after the swap);
     ``window`` narrows the causal band (sliding-window attention)."""
     from ..ops.attention import dense_attention, segment_mask
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
 
     def swap_in(x):   # [b, s/n, h, d] -> [b, s, h/n, d]
         return lax.all_to_all(x, axis_name, split_axis=2, concat_axis=1,
@@ -201,7 +199,7 @@ def ring_flash_attention(q, k, v, axis_name: str = "sp",
                               scale=scale, segment_ids=segment_ids,
                               window=window)
     from ..ops.pallas.flash_attention import flash_attention_with_lse
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, s, h, d = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)

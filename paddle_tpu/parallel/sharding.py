@@ -148,32 +148,20 @@ def constraint(x, *spec):
         fitted.pop()
     if not fitted:
         return x
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        abstract = get_abstract()
-        if not abstract.empty:
-            # inside a mesh context — e.g. the partial-manual 1F1B body
-            # (shard_map axis_names={'pp'}): a NamedSharding built on the
-            # outer all-Auto mesh would clash with the context mesh's axis
-            # types, so hand over a bare PartitionSpec (manual axes in the
-            # hint would be invalid; drop them)
-            fitted = [None if _mentions_manual(a, abstract) else a
-                      for a in fitted]
-            while fitted and fitted[-1] is None:
-                fitted.pop()
-            if not any(a is not None for a in fitted):
-                return x
-            return jax.lax.with_sharding_constraint(x, P(*fitted))
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P(*fitted)))
-    # old jax (<= 0.4.x): no abstract-mesh introspection, and a
-    # NamedSharding hint inside a (partial-)manual shard_map region
-    # lowers to an XLA PartitionId op SPMD can't partition — drop the
-    # hint there (it is an optimization hint, never load-bearing) and
-    # keep it everywhere else.
-    from ..utils.jax_compat import inside_manual_region
-    if inside_manual_region():
-        return x
+    abstract = jax.sharding.get_abstract_mesh()
+    if not abstract.empty:
+        # inside a mesh context — e.g. the partial-manual 1F1B body
+        # (shard_map axis_names={'pp'}): a NamedSharding built on the
+        # outer all-Auto mesh would clash with the context mesh's axis
+        # types, so hand over a bare PartitionSpec (manual axes in the
+        # hint would be invalid; drop them)
+        fitted = [None if _mentions_manual(a, abstract) else a
+                  for a in fitted]
+        while fitted and fitted[-1] is None:
+            fitted.pop()
+        if not any(a is not None for a in fitted):
+            return x
+        return jax.lax.with_sharding_constraint(x, P(*fitted))
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*fitted)))
 

@@ -140,32 +140,48 @@ class LocalProcessManager:
 
     # -------------------------------------------------------------- spawn
     def spawn(self) -> RemoteReplica:
-        """Start one gateway process, wait for readiness, join it."""
+        """Start one gateway process, wait for readiness, join it.
+
+        The manager hands a child no chip of its own: on an
+        accelerator every child claims all local chips, so a second
+        child would fail or hang behind the first. That is refused
+        here, before the process starts — several replicas on one
+        accelerator host run inside ONE process (``Gateway`` over one
+        engine per device, ``replica_main --engines N``). Children held
+        to the CPU (``JAX_PLATFORMS=cpu``) contend for nothing."""
+        env = {**os.environ, **self.env}
+        # check + start + register under the lock: a concurrent
+        # scale_up must see this child before it may start its own
         with self._lock:
+            if self.procs and env.get("JAX_PLATFORMS") != "cpu":
+                raise RuntimeError(
+                    f"LocalProcessManager assigns no chip per child: "
+                    f"with JAX_PLATFORMS={env.get('JAX_PLATFORMS')!r} "
+                    f"replica process {sorted(self.procs)[0]!r} already "
+                    f"holds this host's accelerator and a second one "
+                    f"would fail or hang at backend start-up. Run the "
+                    f"replicas in one process (engines_per_replica=N) "
+                    f"or set JAX_PLATFORMS=cpu.")
             idx = self._counter
             self._counter += 1
-        name = f"peer{idx}"
-        cmd = [sys.executable, "-m",
-               "paddle_tpu.serving.fleet.replica_main",
-               "--port", "0", "--model", self.model,
-               "--chunk-tokens", str(self.chunk_tokens),
-               "--engines", str(self.engines_per_replica),
-               "--name", f"{self.name}-{name}"] + self.extra_args
-        env = {**os.environ, **self.env}
-        # children share one persistent compile cache: a scale-up's
-        # cold start deserializes executables instead of recompiling
-        env.setdefault("PADDLE_TPU_COMPILE_CACHE_DIR",
-                       "/tmp/paddle_tpu_fleet_cache")
-        stderr = subprocess.DEVNULL
-        if self.log_dir:
-            os.makedirs(self.log_dir, exist_ok=True)
-            stderr = open(os.path.join(
-                self.log_dir, f"{name}.stderr.log"), "w")
-        proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                stderr=stderr, text=True, env=env,
-                                cwd=os.path.dirname(os.path.dirname(
-                                    os.path.dirname(os.path.dirname(
-                                        os.path.abspath(__file__))))))
+            name = f"peer{idx}"
+            cmd = [sys.executable, "-m",
+                   "paddle_tpu.serving.fleet.replica_main",
+                   "--port", "0", "--model", self.model,
+                   "--chunk-tokens", str(self.chunk_tokens),
+                   "--engines", str(self.engines_per_replica),
+                   "--name", f"{self.name}-{name}"] + self.extra_args
+            stderr = subprocess.DEVNULL
+            if self.log_dir:
+                os.makedirs(self.log_dir, exist_ok=True)
+                stderr = open(os.path.join(
+                    self.log_dir, f"{name}.stderr.log"), "w")
+            proc = subprocess.Popen(
+                cmd, stdout=subprocess.PIPE, stderr=stderr, text=True,
+                env=env,
+                cwd=os.path.dirname(os.path.dirname(os.path.dirname(
+                    os.path.dirname(os.path.abspath(__file__))))))
+            self.procs[name] = proc
         deadline = time.monotonic() + self.spawn_timeout_s
         port = None
         while time.monotonic() < deadline:
@@ -179,6 +195,7 @@ class LocalProcessManager:
                         port = int(v)
                 break
         if port is None:
+            self.procs.pop(name, None)
             proc.kill()
             raise RuntimeError(
                 f"replica process never reported ready "
@@ -186,7 +203,6 @@ class LocalProcessManager:
         # keep draining the child's stdout so its pipe never fills
         threading.Thread(target=self._drain_stdout, args=(proc,),
                          daemon=True).start()
-        self.procs[name] = proc
         first = None
         for fe in self.frontends:
             peer = RemoteReplica(

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import os
 import sys
 from typing import Any, Dict
 
@@ -41,18 +40,6 @@ def tiny_engine_kw(chunk_tokens: int = 32) -> Dict[str, Any]:
                 max_blocks_per_seq=16, prefill_buckets=(32,),
                 chunk_prefill_tokens=int(chunk_tokens),
                 enable_prefix_cache=True)
-
-
-def _enable_compile_cache():
-    import jax
-    cache = os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR",
-                           "/tmp/paddle_tpu_fleet_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:
-        pass
 
 
 def build_engine(model: str, chunk_tokens: int):
@@ -115,15 +102,13 @@ def main(argv=None) -> int:
                          "them here; requires --spill-mb > 0")
     ns = ap.parse_args(argv)
 
-    plat = os.environ.get("PADDLE_TPU_BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
-    _enable_compile_cache()
-
     import paddle_tpu as pt
     from paddle_tpu.serving import Gateway
+    from paddle_tpu.utils import compile_cache
     from paddle_tpu.utils import observability as obs
+    # replica processes share one persistent compile cache: a scale-up's
+    # cold start deserializes executables instead of recompiling
+    compile_cache.enable(min_compile_time_s=0.1)
     pt.seed(0)
     if ns.run_dir:
         obs.configure(ns.run_dir)

@@ -353,7 +353,14 @@ class ReplicaSupervisor(threading.Thread):
                 pass        # salvage only costs warmth, never safety
         try:
             if gw._engine_factory is not None:
-                engine = gw._engine_factory()
+                # the replacement belongs on the dead replica's device:
+                # the factory runs inside that device's default scope,
+                # so the weights it builds — and with them the engine —
+                # land there without the factory naming a device
+                import jax
+                with jax.default_device(
+                        getattr(worker.engine, "device", None)):
+                    engine = gw._engine_factory()
             else:
                 # rebuild in place: fresh pools/mirrors on the same
                 # engine object (safe — the old thread is DEAD, gated

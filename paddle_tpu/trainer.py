@@ -90,12 +90,6 @@ class TrainingArguments:
     # host pipeline, the seeded `prefetch_stall` fault) degrades the
     # loop to synchronous feeding instead of deadlocking it
     prefetch_stall_timeout_s: float = 5.0
-    # persistent XLA compilation cache: a preempted-and-relaunched
-    # worker restores the step executable from disk instead of paying
-    # full recompilation. None falls back to
-    # $PADDLE_TPU_COMPILE_CACHE_DIR (which elastic.supervise propagates
-    # to relaunched children); unset entirely = no-op.
-    compile_cache_dir: Optional[str] = None
     # compile the train step ahead-of-time on the first batch (before
     # step 0 "runs"), so compile time never counts against the first
     # checkpoint/logging interval
@@ -305,9 +299,9 @@ class Trainer:
         max_steps = max_steps or args.max_steps
         # persistent compilation cache BEFORE anything traces: a
         # relaunched (e.g. preempted) worker restores the byte-identical
-        # step executable from disk instead of recompiling. No-op when
-        # neither args nor $PADDLE_TPU_COMPILE_CACHE_DIR is set.
-        compile_cache.enable(args.compile_cache_dir)
+        # step executable from disk instead of recompiling
+        # ($JAX_COMPILATION_CACHE_DIR, else the in-checkout default).
+        compile_cache.enable()
         # observability artifacts (trace_<attempt>.json,
         # flight_<attempt>.json, metrics.prom) land in the SAME run dir
         # as the JSONL metrics — one dir answers "what happened"
@@ -494,9 +488,11 @@ class Trainer:
                         # so the denominator only spans the steps since —
                         # the numerator must match
                         "steps_per_sec": win_steps / (now - t_last),
-                        "tokens_per_sec": tps,
-                        "mfu": timer.flops_per_token * tps /
-                        timer.peak_flops if timer.flops_per_token else 0.0}
+                        "tokens_per_sec": tps}
+                if timer.peak_flops is not None:
+                    # no published peak for this device (CPU): no MFU
+                    logs["mfu"] = \
+                        timer.flops_per_token * tps / timer.peak_flops
                 win_tokens = 0
                 win_steps = 0
                 t_last = now

@@ -1,22 +1,25 @@
-"""Persistent XLA compilation cache wiring (ISSUE 4 tentpole).
+"""Persistent XLA compilation cache: the ONE place this repository
+points JAX at a cache directory.
 
-PR 3 made preemption restarts free at the supervisor level
-(``PREEMPTED_RC`` never consumes a restart attempt) — but each relaunch
-still paid a full XLA recompilation of the train step before its first
-post-resume step. This module points jax's persistent compilation cache
-(``jax_compilation_cache_dir``) at a directory that survives the
-process, so a preempted-and-relaunched worker compiles the
-byte-identical step program once and restores it from disk thereafter.
+A relaunched worker (preemption, scale-up, the next chip-tool call on a
+machine that kept its disk) compiles each byte-identical program once
+and restores it from disk thereafter.
 
-Two enablement channels, one resolver:
+Where the directory comes from, in order:
 
-- ``TrainingArguments.compile_cache_dir`` → ``Trainer.train`` calls
-  ``enable()`` before building the step;
-- ``$PADDLE_TPU_COMPILE_CACHE_DIR`` — picked up by ``enable()`` when no
-  explicit dir is given, and injected into relaunched children by
-  ``distributed.elastic.supervise`` via ``child_env()`` so the whole
-  supervise/preempt/relaunch loop shares one cache without any trainer
-  code changes.
+- ``$JAX_COMPILATION_CACHE_DIR`` — JAX's own variable. JAX reads it at
+  import (``jax.config.jax_compilation_cache_dir`` shows it), it is what
+  an operator or the chip machine sets, and when it is set nothing in
+  the program names another directory.
+- otherwise ``DEFAULT_DIR``, a fixed git-ignored path inside the
+  checkout. Fixed because a cache that moves between runs never hits;
+  never a temporary name, a pid or a time.
+
+Every entry point that wants the cache (``Trainer.train``, the fleet
+replica process, the load generator, ``bench.py``, ``chip_smoke.py``)
+calls ``enable()``. ``distributed.elastic.supervise`` hands its
+children the directory through the same standard variable
+(``child_env``), so the supervisor itself never imports jax.
 
 ``entries()`` lists the cache's program keys (the ``*-cache`` payload
 files, not the ``-atime`` access-time markers) so tests and tools can
@@ -26,95 +29,72 @@ population, not wall time.
 from __future__ import annotations
 
 import os
-import sys
 import threading
 from typing import Dict, List, Optional
 
-__all__ = ["ENV_VAR", "MIN_COMPILE_ENV_VAR", "enable", "enabled",
-           "active_dir", "resolve_dir", "entries", "child_env"]
+__all__ = ["ENV_VAR", "DEFAULT_DIR", "enable", "active_dir",
+           "resolve_dir", "entries", "child_env"]
 
-ENV_VAR = "PADDLE_TPU_COMPILE_CACHE_DIR"
-MIN_COMPILE_ENV_VAR = "PADDLE_TPU_COMPILE_CACHE_MIN_S"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = threading.Lock()
-_dir: Optional[str] = None
 
 
-def resolve_dir(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Explicit dir wins; falls back to ``$PADDLE_TPU_COMPILE_CACHE_DIR``;
-    None means "leave whatever jax config is already active alone"."""
-    return cache_dir or os.environ.get(ENV_VAR) or None
+def resolve_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    in-checkout ``DEFAULT_DIR``."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
 
-def enable(cache_dir: Optional[str] = None,
-           min_compile_time_s: Optional[float] = None) -> Optional[str]:
-    """Point jax at a persistent compilation cache directory.
-
-    No-op (returns None) when neither ``cache_dir`` nor the env var is
-    set — an already-configured cache (e.g. the test suite's) is left
-    untouched. Idempotent and cheap; safe to call every ``train()``.
-    ``min_compile_time_s`` gates trivial programs out of the cache
-    (default ``$PADDLE_TPU_COMPILE_CACHE_MIN_S`` or 1.0s — the train
-    step is far above it, per-op jits mostly below)."""
-    global _dir
-    cache_dir = resolve_dir(cache_dir)
-    if not cache_dir:
-        return None
-    if min_compile_time_s is None:
-        min_compile_time_s = float(
-            os.environ.get(MIN_COMPILE_ENV_VAR, "1.0"))
+def enable(min_compile_time_s: Optional[float] = None) -> str:
+    """Turn the persistent cache on at ``resolve_dir()`` and return that
+    directory. Idempotent and cheap; safe to call every ``train()``.
+    ``min_compile_time_s`` gates trivial programs out of the cache;
+    None keeps jax's own setting (1.0s unless
+    ``$JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise — a
+    train step or a serving tick is far above it, per-op jits mostly
+    below)."""
+    cache_dir = resolve_dir()
     import jax
     with _lock:
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
+        os.makedirs(cache_dir, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != cache_dir:
             jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_compile_time_s))
-            _reset_latched_cache(cache_dir)
-        except Exception as e:   # config drift across jax versions
-            print(f"[compile_cache] could not enable persistent cache at "
-                  f"{cache_dir}: {e}", file=sys.stderr, flush=True)
-            return None
-        _dir = cache_dir
+        if min_compile_time_s is not None:
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs",
+                float(min_compile_time_s))
+        _reset_latched_cache(cache_dir)
     return cache_dir
 
 
 def _reset_latched_cache(cache_dir: str) -> None:
-    """jax initializes its cache object AT MOST ONCE, on the first XLA
-    compile — and model/optimizer init usually compiles something long
-    before ``Trainer.train`` calls ``enable()``, latching "no cache"
-    for the whole process. If the latched cache doesn't point at
-    ``cache_dir``, reset it so the next compile re-initializes against
-    the directory just configured."""
-    try:
-        # the _src module, not the jax.experimental re-export: the
-        # latter's module-level ints/bools are frozen at its import
-        from jax._src import compilation_cache as cc
-        latched = getattr(cc, "_cache", None)
-        if getattr(cc, "_cache_initialized", False) and \
-                str(getattr(latched, "_path", None)) != cache_dir:
-            cc.reset_cache()
-    except Exception:
-        pass     # private latch moved (newer jax): dir config still set
-
-
-def enabled() -> bool:
-    return active_dir() is not None
+    """jax builds its cache object AT MOST ONCE, on the first compile
+    that finds a directory configured. If that object points somewhere
+    other than ``cache_dir`` (the directory changed after a compile),
+    reset it so the next compile re-initializes against ``cache_dir``."""
+    # the _src module, not the jax.experimental re-export: the latter's
+    # module-level ints/bools are frozen at its import
+    from jax._src import compilation_cache as cc
+    if cc._cache_initialized and \
+            str(getattr(cc._cache, "_path", None)) != cache_dir:
+        cc.reset_cache()
 
 
 def active_dir() -> Optional[str]:
     """The directory jax is currently caching into (None if disabled)."""
-    try:
-        import jax
-        return jax.config.jax_compilation_cache_dir or None
-    except Exception:
-        return _dir
+    import jax
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def entries(cache_dir: Optional[str] = None) -> List[str]:
-    """Sorted program keys currently in the cache (payload files only;
-    ``-atime`` access markers are bookkeeping, not programs)."""
-    d = resolve_dir(cache_dir) or active_dir()
+    """Sorted program keys currently in ``cache_dir`` (default: the
+    active directory); payload files only — ``-atime`` access markers
+    are bookkeeping, not programs."""
+    d = cache_dir or active_dir()
     if not d or not os.path.isdir(d):
         return []
     return sorted(f for f in os.listdir(d) if not f.endswith("-atime"))
@@ -122,12 +102,13 @@ def entries(cache_dir: Optional[str] = None) -> List[str]:
 
 def child_env(cache_dir: Optional[str] = None,
               base: Optional[Dict[str, str]] = None) -> Dict[str, str]:
-    """Environment for a relaunched worker: the cache dir propagates via
-    ``$PADDLE_TPU_COMPILE_CACHE_DIR``, which ``enable()`` inside the
-    child's ``Trainer.train`` resolves — the supervisor never imports
-    jax (the child owns the accelerator)."""
+    """Environment for a worker process. The cache directory rides
+    JAX's own variable, which the child's jax reads at import: the
+    parent's ``$JAX_COMPILATION_CACHE_DIR`` when set (it wins over the
+    argument, as everywhere), else ``cache_dir`` when given, else
+    nothing — the child's own ``enable()`` then resolves the default."""
     env = dict(os.environ if base is None else base)
-    d = resolve_dir(cache_dir)
+    d = os.environ.get(ENV_VAR) or cache_dir
     if d:
         env[ENV_VAR] = d
     return env

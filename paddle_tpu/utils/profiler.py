@@ -14,26 +14,36 @@ from typing import Optional
 
 import jax
 
+# Peak dense bf16 FLOP/s of ONE chip, keyed by jax's ``device_kind``.
+# The repository's only peak table (bench.py reads it too). Source:
+# Google Cloud TPU documentation, the per-generation "System
+# architecture" pages (v4: 275 TFLOP/s; v5e: 197; v5p: 459; v6e
+# "Trillium": 918). A device that is not listed has no peak here and
+# therefore no MFU — never a default.
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
+    "TPU v5 lite": 197e12,   # v5e, as jax reports it
     "TPU v5e": 197e12,
     "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
+    "TPU v6 lite": 918e12,   # v6e
 }
 
 
-def device_peak_flops(device=None) -> float:
+def device_peak_flops(device=None) -> Optional[float]:
+    """Published bf16 peak of ``device`` (default: the first device),
+    None when its ``device_kind`` is not in ``PEAK_BF16_FLOPS``."""
     device = device or jax.devices()[0]
-    return PEAK_BF16_FLOPS.get(getattr(device, "device_kind", ""), 197e12)
+    return PEAK_BF16_FLOPS.get(device.device_kind)
 
 
-def local_peak_flops() -> float:
+def local_peak_flops() -> Optional[float]:
     """Aggregate peak of every local chip. The trainer's token counts
     span the whole per-process batch (all local mesh devices), so MFU
     must divide by the matching aggregate peak — a single chip's peak
-    would overstate it by the local device count."""
-    return sum(device_peak_flops(d) for d in jax.local_devices())
+    would overstate it by the local device count. None when any local
+    device has no published peak (the CPU)."""
+    peaks = [device_peak_flops(d) for d in jax.local_devices()]
+    return None if None in peaks else sum(peaks)
 
 
 # jax.profiler supports ONE live trace per process; the owner lets
@@ -103,7 +113,7 @@ def annotate(name: str):
 class StepTimer:
     """Running step-time / throughput / MFU meter."""
     flops_per_token: float = 0.0
-    peak_flops: float = field(default_factory=local_peak_flops)
+    peak_flops: Optional[float] = field(default_factory=local_peak_flops)
     _t0: Optional[float] = None
     steps: int = 0
     total_s: float = 0.0
@@ -136,9 +146,10 @@ class StepTimer:
         return self.total_tokens / max(self.total_s, 1e-9)
 
     @property
-    def mfu(self) -> float:
-        if not self.flops_per_token:
-            return 0.0
+    def mfu(self) -> Optional[float]:
+        """None on a device with no published peak."""
+        if self.peak_flops is None:
+            return None
         return self.flops_per_token * self.tokens_per_sec / self.peak_flops
 
 

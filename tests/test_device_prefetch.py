@@ -257,9 +257,15 @@ def _tiny_trainer(out_dir, *, batches=None, max_steps=6, **kw):
 
 
 class TestTrainerIntegration:
-    def test_logs_carry_tokens_per_sec_and_mfu(self, tmp_path):
+    def test_logs_carry_tokens_per_sec_and_mfu(self, tmp_path,
+                                               monkeypatch):
         """ACCEPTANCE: the bench-visible numbers get a first-class
-        in-loop source."""
+        in-loop source. MFU needs the device's published peak, so this
+        run gives the CPU one; without it a run logs no "mfu" at all
+        (next test)."""
+        from paddle_tpu.utils import profiler
+        monkeypatch.setitem(profiler.PEAK_BF16_FLOPS,
+                            jax.devices()[0].device_kind, 1e12)
         tr = _tiny_trainer(tmp_path, max_steps=4)
         tr.train()
         hist = tr.logger.history
@@ -281,6 +287,11 @@ class TestTrainerIntegration:
         h_off = [(s, v) for s, v in off.logger.history["loss"]]
         h_on = [(s, v) for s, v in on.logger.history["loss"]]
         assert h_off == h_on                  # exact float equality
+        # the CPU has no published peak: no MFU, never one against an
+        # assumed peak
+        assert on.step_timer.peak_flops is None
+        assert on.step_timer.mfu is None
+        assert "mfu" not in on.logger.history
 
     def test_save_wall_time_excluded_from_throughput(self, tmp_path,
                                                      monkeypatch):
@@ -366,14 +377,17 @@ class TestTrainerIntegration:
 
 # ========================================================= compile cache
 @pytest.fixture
-def _isolated_cache(tmp_path):
-    """Redirect the persistent cache for one test, then restore (and
-    re-latch) the suite-wide cache conftest.py installed."""
-    prev_dir = jax.config.jax_compilation_cache_dir
+def _isolated_cache(tmp_path, monkeypatch):
+    """Redirect the persistent cache for one test through the standard
+    variable, then restore (and re-latch) the suite-wide cache
+    conftest.py installed."""
+    prev_dir = os.environ[compile_cache.ENV_VAR]
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     cache = str(tmp_path / "xla_cache")
+    monkeypatch.setenv(compile_cache.ENV_VAR, cache)
     yield cache
-    compile_cache.enable(prev_dir, min_compile_time_s=prev_min)
+    monkeypatch.setenv(compile_cache.ENV_VAR, prev_dir)
+    compile_cache.enable(min_compile_time_s=prev_min)
 
 
 class TestCompileCache:
@@ -383,9 +397,9 @@ class TestCompileCache:
         compile must still take effect (reset + re-init), because
         Trainer.train always runs after model init has compiled ops."""
         jax.jit(lambda x: x * 2 + 1)(jnp.ones((8, 8))).block_until_ready()
-        compile_cache.enable(_isolated_cache, min_compile_time_s=0.0)
+        assert compile_cache.enable(min_compile_time_s=0.0) \
+            == _isolated_cache
         assert compile_cache.active_dir() == _isolated_cache
-        assert compile_cache.enabled()
 
         @jax.jit
         def f(x):
@@ -403,9 +417,8 @@ class TestCompileCache:
         asserted via population (no new entries) plus jax's own
         cache-hit events, not wall time."""
         from jax._src import monitoring as _mon
-        monkeypatch.setenv(compile_cache.MIN_COMPILE_ENV_VAR, "0")
-        cold = _tiny_trainer(tmp_path / "cold", max_steps=2,
-                             compile_cache_dir=_isolated_cache)
+        compile_cache.enable(min_compile_time_s=0.0)
+        cold = _tiny_trainer(tmp_path / "cold", max_steps=2)
         cold.train()
         populated = set(compile_cache.entries(_isolated_cache))
         assert populated                      # cold startup wrote programs
@@ -416,24 +429,23 @@ class TestCompileCache:
             lambda name, **kw: hits.append(name)
             if name == "/jax/compilation_cache/cache_hits" else None)
         try:
-            warm = _tiny_trainer(tmp_path / "warm", max_steps=2,
-                                 compile_cache_dir=_isolated_cache)
+            warm = _tiny_trainer(tmp_path / "warm", max_steps=2)
             warm.train()
         finally:
             _mon._event_listeners[:] = saved
         assert set(compile_cache.entries(_isolated_cache)) == populated
         assert hits                           # executables restored, not rebuilt
 
-    def test_resolve_dir_and_child_env(self, monkeypatch, tmp_path):
+    def test_entries_and_child_env(self, monkeypatch, tmp_path):
+        """(The resolver's own order — the standard variable over every
+        argument, else the fixed in-checkout path — is pinned in
+        tests/test_chip_smoke.py.)"""
         monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-        assert compile_cache.resolve_dir(None) is None
-        assert compile_cache.resolve_dir("/a/b") == "/a/b"
-        monkeypatch.setenv(compile_cache.ENV_VAR, "/from/env")
-        assert compile_cache.resolve_dir(None) == "/from/env"
-        assert compile_cache.resolve_dir("/a/b") == "/a/b"  # explicit wins
         env = compile_cache.child_env("/a/b", base={"PATH": "/bin"})
         assert env[compile_cache.ENV_VAR] == "/a/b"
         assert env["PATH"] == "/bin"
+        assert compile_cache.ENV_VAR not in compile_cache.child_env(
+            base={"PATH": "/bin"})
         # entries() hides -atime bookkeeping files
         d = tmp_path / "c"
         d.mkdir()
@@ -441,12 +453,15 @@ class TestCompileCache:
         (d / "prog-1-atime").write_bytes(b"")
         assert compile_cache.entries(str(d)) == ["prog-1-cache"]
 
-    def test_supervise_propagates_cache_dir_to_children(self, tmp_path):
-        """elastic.supervise injects $PADDLE_TPU_COMPILE_CACHE_DIR into
-        every (re)launch, so a preempted-and-relaunched worker resolves
-        the same cache without trainer-side plumbing (jax-free child:
-        tier-1 budget)."""
+    def test_supervise_propagates_cache_dir_to_children(self, tmp_path,
+                                                        monkeypatch):
+        """elastic.supervise hands $JAX_COMPILATION_CACHE_DIR to every
+        (re)launch, so a preempted-and-relaunched worker resolves the
+        same cache without trainer-side plumbing (jax-free child:
+        tier-1 budget). The supervisor's own variable would win over
+        the argument, so it is cleared here."""
         from paddle_tpu.distributed.elastic import supervise
+        monkeypatch.delenv(compile_cache.ENV_VAR)
         out = tmp_path / "seen"
         child = (f"import os; open({str(out)!r}, 'w').write("
                  f"os.environ.get('{compile_cache.ENV_VAR}', 'MISSING'))")

@@ -37,6 +37,7 @@ diurnal trace) rides behind ``slow`` (``tools/marker_audit.py``
 """
 import asyncio
 import json
+import os
 import time
 
 import pytest
@@ -891,3 +892,63 @@ def test_fleet_multiproc_frontend_ha_kill():
     assert ha["resumed_failed"] == 0
     # the mesh actually gossiped before (and after) the kill
     assert sum(g["rounds"] for g in ha["gossip"]) > 0
+
+
+@pytest.mark.slow
+def test_fleet_loadgen_parent_stays_off_jax(tmp_path):
+    """ISSUE 21: a chip belongs to one process, so while the replica
+    PROCESSES live the loadgen parent must hold no jax backend (device
+    kind comes from a child's /healthz; the bitwise replay builds its
+    reference engine only after the fleet is down). Fresh interpreter:
+    this session's own backend is long initialised."""
+    import subprocess
+    import sys
+    tools = os.path.join(os.path.dirname(__file__), os.pardir, "tools")
+    driver = tmp_path / "driver.py"
+    driver.write_text(f"""
+import json, sys
+sys.path.insert(0, {os.path.abspath(tools)!r})
+import serve_loadgen as slg
+from jax._src import xla_bridge as xb
+from paddle_tpu.serving.fleet import LocalProcessManager
+
+seen = []
+stop_all = LocalProcessManager.stop_all
+def checked(self, *a, **kw):
+    seen.append(sorted(xb._backends))      # fleet still up here
+    return stop_all(self, *a, **kw)
+LocalProcessManager.stop_all = checked
+rc = slg.main(["--fleet", "2", "--model", "stub", "--requests", "8",
+               "--rate", "20", "--max-new", "6", "--sys-tokens", "8",
+               "--out", {str(tmp_path / "rung.json")!r}])
+print("RESULT " + json.dumps({{"rc": rc, "seen": seen}}))
+""")
+    proc = subprocess.run([sys.executable, str(driver)], timeout=300,
+                          capture_output=True, text=True)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    res = json.loads(next(ln for ln in proc.stdout.splitlines()
+                          if ln.startswith("RESULT "))[7:])
+    assert res == {"rc": 0, "seen": [[]]}, res
+    with open(tmp_path / "rung.json") as f:
+        rung = json.load(f)
+    assert rung["device"] == "cpu"          # read off a child's /healthz
+    assert rung["fleet"]["fleet_gate"]["ok"]
+
+
+def test_manager_refuses_second_child_off_the_cpu(monkeypatch):
+    """ISSUE 21: the manager hands a child no chip of its own, so off
+    the CPU a second replica process would fail or hang behind the
+    first — spawn refuses BEFORE starting it, with a message that says
+    so (no process is started here: the first child is a stand-in)."""
+    from paddle_tpu.serving.fleet import LocalProcessManager
+    mgr = LocalProcessManager(FleetFrontend([], chunk_tokens=8,
+                                            name="t-chip"))
+    mgr.procs["peer0"] = object()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="assigns no chip per child"):
+        mgr.spawn()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS=None"):
+        mgr.spawn()
+    assert list(mgr.procs) == ["peer0"] and mgr._counter == 0
+

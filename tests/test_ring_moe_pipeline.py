@@ -14,7 +14,7 @@ from paddle_tpu.ops.attention import dense_attention
 from paddle_tpu.parallel import (MoEMLP, pipeline_apply, ring_attention,
                                  stack_stage_params, top_k_routing,
                                  ulysses_attention)
-from paddle_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 @pytest.fixture
@@ -194,7 +194,7 @@ class TestRingFlash:
         pallas kernels in interpret mode on CPU)."""
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.parallel.ring import ring_flash_attention
         from paddle_tpu.ops.attention import dense_attention
 
@@ -221,7 +221,7 @@ class TestRingFlash:
     def test_gradients_flow(self, monkeypatch):
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
         from jax.sharding import Mesh, PartitionSpec as P
-        from paddle_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from paddle_tpu.parallel.ring import ring_flash_attention
         from paddle_tpu.ops.attention import dense_attention
 
