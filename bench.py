@@ -390,8 +390,8 @@ def _run_child(mode, timeout):
 
 def _ingest_rung(result, probe, filename, section_key, profile_field,
                  promote):
-    """Fold one rung file (written by tools/decode_profile.py or
-    tools/serve_loadgen.py next to this script) into the bench result:
+    """Fold one rung file (written by tools/serve_loadgen.py or
+    tools/fleet_sim.py next to this script) into the bench result:
     always annotate ``result["decode"][profile_field]`` with the full
     section + provenance; promote the keys in ``promote`` (first one
     required for the file to count at all) only under the same-device
@@ -504,20 +504,13 @@ def main():
             failures.append({"stage": "decode", "rc": rc,
                              "stderr_tail": err[-300:]})
 
-    # Profiler/loadgen rung ingestion — decode_profile (ISSUE 6) and
-    # serve_loadgen (ISSUE 9) share one contract: annotate the banked
-    # bench with the profile either way, but promote the headline keys
+    # Loadgen rung ingestion (serve_loadgen, ISSUE 9; fleet_sim):
+    # annotate the banked bench with the profile either way, but
+    # promote the headline keys
     # only when the file came from THIS window (same device kind,
     # started < 6h ago — a stale CPU-run file, or a week-old hardware
     # window's, must not masquerade as this run's number).
     if result is not None:
-        _ingest_rung(result, probe, "DECODE_PROFILE_r06.json", "paged",
-                     "paged_profile",
-                     ("paged_tokens_per_sec",
-                      "paged_spec_tokens_per_sec",
-                      "paged_sampled_spec_tokens_per_sec",
-                      "paged_churn_tokens_per_sec",
-                      "paged_churn_fused_tokens_per_sec"))
         _ingest_rung(result, probe, "SERVE_LOADGEN_r07.json", "gateway",
                      "gateway_profile",
                      ("gateway_tokens_per_sec", "gateway_p99_ttft_ms",

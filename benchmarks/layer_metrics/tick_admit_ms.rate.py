@@ -1,0 +1,12 @@
+"""Worker-loop phase `sched` (posted ops, queue reap, admission into the engine, gauges) plus in-tick phase `admit` (queue to slot, its eager device programs), a decode tick, rate cells. The window's snapshots lie either side of `capture_trace`, whose `stop_trace` works for about 19 s while the server runs and slows the tick thread: the reading is up to twice the untraced per-tick figure (`PERF.md` section 5 gives both) and compares only with other traced runs."""
+from benchmarks.harness import spans
+
+NAME = "tick_admit_ms.rate"
+LAYER = "front door and admission"
+UNIT = "ms"
+MOVES = "gap_p95_ms"
+SOURCE = "program_span"
+
+
+def reduce(sources):
+    return spans.phase_ms(sources, ("sched", "admit"))

@@ -193,23 +193,24 @@ def paged_decode_write(pk: PagedKV, k, v):
     writes are garbage-block noise the attention mask never reads."""
     B = pk.block_size
     R, T = k.shape[0], k.shape[1]
-    if T == 1:
-        r = jnp.arange(R)
-        bidx = pk.block_tables[r, pk.seq_lens // B]      # [R]
-        boff = pk.seq_lens % B
-        kp = pk.kp.at[bidx, boff].set(k[:, 0].astype(pk.kp.dtype))
-        vp = pk.vp.at[bidx, boff].set(v[:, 0].astype(pk.vp.dtype))
+    with jax.named_scope("kv_write"):       # obs.TICK_SCOPES
+        if T == 1:
+            r = jnp.arange(R)
+            bidx = pk.block_tables[r, pk.seq_lens // B]      # [R]
+            boff = pk.seq_lens % B
+            kp = pk.kp.at[bidx, boff].set(k[:, 0].astype(pk.kp.dtype))
+            vp = pk.vp.at[bidx, boff].set(v[:, 0].astype(pk.vp.dtype))
+            return pk._replace(kp=kp, vp=vp)
+        M = pk.block_tables.shape[1]
+        r = jnp.arange(R)[:, None]                           # [R, 1]
+        pos = pk.seq_lens[:, None] + jnp.arange(T)[None, :]  # [R, T]
+        lb = pos // B
+        bidx = jnp.where(lb < M,
+                         pk.block_tables[r, jnp.clip(lb, 0, M - 1)], 0)
+        boff = pos % B
+        kp = pk.kp.at[bidx, boff].set(k.astype(pk.kp.dtype))
+        vp = pk.vp.at[bidx, boff].set(v.astype(pk.vp.dtype))
         return pk._replace(kp=kp, vp=vp)
-    M = pk.block_tables.shape[1]
-    r = jnp.arange(R)[:, None]                           # [R, 1]
-    pos = pk.seq_lens[:, None] + jnp.arange(T)[None, :]  # [R, T]
-    lb = pos // B
-    bidx = jnp.where(lb < M,
-                     pk.block_tables[r, jnp.clip(lb, 0, M - 1)], 0)
-    boff = pos % B
-    kp = pk.kp.at[bidx, boff].set(k.astype(pk.kp.dtype))
-    vp = pk.vp.at[bidx, boff].set(v.astype(pk.vp.dtype))
-    return pk._replace(kp=kp, vp=vp)
 
 
 def paged_prefill_write(pk: PagedKV, k, v, positions=None,
@@ -221,13 +222,15 @@ def paged_prefill_write(pk: PagedKV, k, v, positions=None,
     and seq_lens[0] = start + live-chunk-length."""
     B = pk.block_size
     s = k.shape[1]
-    pos = positions if positions is not None else jnp.arange(s)
-    live = pos < pk.seq_lens[0]
-    bidx = jnp.where(live, pk.block_tables[0, pos // B], garbage_block)
-    boff = pos % B
-    kp = pk.kp.at[bidx, boff].set(k[0].astype(pk.kp.dtype))
-    vp = pk.vp.at[bidx, boff].set(v[0].astype(pk.vp.dtype))
-    return pk._replace(kp=kp, vp=vp)
+    with jax.named_scope("kv_write"):       # obs.TICK_SCOPES
+        pos = positions if positions is not None else jnp.arange(s)
+        live = pos < pk.seq_lens[0]
+        bidx = jnp.where(live, pk.block_tables[0, pos // B],
+                         garbage_block)
+        boff = pos % B
+        kp = pk.kp.at[bidx, boff].set(k[0].astype(pk.kp.dtype))
+        vp = pk.vp.at[bidx, boff].set(v[0].astype(pk.vp.dtype))
+        return pk._replace(kp=kp, vp=vp)
 
 
 def paged_chunk_attention(q, pk: PagedKV, positions,
@@ -241,14 +244,15 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
     compare."""
     from ..ops.attention import dense_attention
     kvh, d = pk.kp.shape[2], pk.kp.shape[3]
-    ks = pk.kp[pk.block_tables[0]].reshape(1, -1, kvh, d)   # [1, T, ...]
-    vs = pk.vp[pk.block_tables[0]].reshape(1, -1, kvh, d)
-    kpos = jnp.arange(ks.shape[1])[None, :]                 # [1, T]
-    qpos = positions[0][:, None]                            # [s, 1]
-    keep = kpos <= qpos                                     # [s, T]
-    if window is not None:
-        keep &= qpos - kpos < window
-    return dense_attention(q, ks, vs, attn_mask=keep[None, None])
+    with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+        ks = pk.kp[pk.block_tables[0]].reshape(1, -1, kvh, d)  # [1, T, ..]
+        vs = pk.vp[pk.block_tables[0]].reshape(1, -1, kvh, d)
+        kpos = jnp.arange(ks.shape[1])[None, :]             # [1, T]
+        qpos = positions[0][:, None]                        # [s, 1]
+        keep = kpos <= qpos                                 # [s, T]
+        if window is not None:
+            keep &= qpos - kpos < window
+        return dense_attention(q, ks, vs, attn_mask=keep[None, None])
 
 
 def paged_decode_route(q, kp) -> str:
@@ -288,37 +292,39 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     tests / odd shapes): dense whole-table gather — the math is
     dense_attention's, only the gather and the per-(row, position) mask
     live here. ``paged_decode_route`` is the one place that chooses."""
-    from ..ops.attention import dense_attention
-    R, T = q.shape[0], q.shape[1]
-    kvh, d = pk.kp.shape[2], pk.kp.shape[3]
-    route = paged_decode_route(q, pk.kp)
-    if route != "dense":
-        sc = scale if scale is not None else d ** -0.5
-        if route == "grid":
-            from ..ops.pallas.paged_attention import paged_attention_pallas
-            out = paged_attention_pallas(q[:, 0], pk.kp, pk.vp,
-                                         pk.block_tables, pk.seq_lens,
-                                         sc, window=window)
-            return out[:, None]
-        from ..ops.pallas.ragged_paged_attention import \
-            ragged_paged_attention_pallas
-        out = ragged_paged_attention_pallas(
-            q if T > 1 else q[:, 0], pk.kp, pk.vp, pk.block_tables,
-            pk.seq_lens, sc, window=window)
-        return out if T > 1 else out[:, None]
-    ks = pk.kp[pk.block_tables]                  # [R, M, B, kvh, d]
-    vs = pk.vp[pk.block_tables]
-    Tk = ks.shape[1] * ks.shape[2]
-    ks = ks.reshape(R, Tk, kvh, d)
-    vs = vs.reshape(R, Tk, kvh, d)
-    kpos = jnp.arange(Tk)[None, None, :]                  # [1, 1, Tk]
-    qpos = pk.seq_lens[:, None, None] + \
-        jnp.arange(T)[None, :, None]                      # [R, T, 1]
-    keep = kpos <= qpos                                   # [R, T, Tk]
-    if window is not None:
-        keep &= kpos > qpos - window
-    return dense_attention(q, ks, vs, attn_mask=keep[:, None],
-                           scale=scale)
+    with jax.named_scope("attn"):           # obs.TICK_SCOPES
+        from ..ops.attention import dense_attention
+        R, T = q.shape[0], q.shape[1]
+        kvh, d = pk.kp.shape[2], pk.kp.shape[3]
+        route = paged_decode_route(q, pk.kp)
+        if route != "dense":
+            sc = scale if scale is not None else d ** -0.5
+            if route == "grid":
+                from ..ops.pallas.paged_attention import \
+                    paged_attention_pallas
+                out = paged_attention_pallas(q[:, 0], pk.kp, pk.vp,
+                                             pk.block_tables, pk.seq_lens,
+                                             sc, window=window)
+                return out[:, None]
+            from ..ops.pallas.ragged_paged_attention import \
+                ragged_paged_attention_pallas
+            out = ragged_paged_attention_pallas(
+                q if T > 1 else q[:, 0], pk.kp, pk.vp, pk.block_tables,
+                pk.seq_lens, sc, window=window)
+            return out if T > 1 else out[:, None]
+        ks = pk.kp[pk.block_tables]                  # [R, M, B, kvh, d]
+        vs = pk.vp[pk.block_tables]
+        Tk = ks.shape[1] * ks.shape[2]
+        ks = ks.reshape(R, Tk, kvh, d)
+        vs = vs.reshape(R, Tk, kvh, d)
+        kpos = jnp.arange(Tk)[None, None, :]                  # [1, 1, Tk]
+        qpos = pk.seq_lens[:, None, None] + \
+            jnp.arange(T)[None, :, None]                      # [R, T, 1]
+        keep = kpos <= qpos                                   # [R, T, Tk]
+        if window is not None:
+            keep &= kpos > qpos - window
+        return dense_attention(q, ks, vs, attn_mask=keep[:, None],
+                               scale=scale)
 
 
 class _Request:
@@ -363,26 +369,81 @@ class _Request:
         self.spec_ema = 1.0
 
 
+class _NoPhase:
+    """``PagedEngine._phase`` with the profiler off: one shared object
+    that measures nothing."""
+    __slots__ = ()
+    on = False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def switch(self, phase: str):
+        pass
+
+
+_NO_PHASE = _NoPhase()
+
+
+class _PhaseSpan:
+    """One open bracket of the profiler, as a ``with`` block
+    (``PagedEngine._phase``). ``switch`` closes the bracket and opens
+    the next in its place, for two phases that abut; leaving the block
+    closes whichever is open then, an exception's way out included.
+    ``on`` lets a caller add what only a profiled tick does (the
+    ``block_until_ready`` that splits the device's wait from the
+    drain)."""
+    __slots__ = ("_prof", "_phase")
+    on = True
+
+    def __init__(self, prof: "_TickPhaseProfile", phase: str):
+        self._prof, self._phase = prof, phase
+
+    def __enter__(self):
+        self._prof.open(self._phase)
+        return self
+
+    def __exit__(self, *exc):
+        self._prof.close()
+        return False
+
+    def switch(self, phase: str):
+        self._prof.close()
+        self._prof.open(phase)
+
+
 class _TickPhaseProfile:
-    """Tick-phase accounting for the tick-phase profiler (ISSUE 20
-    tentpole): where does one scheduler tick's wall time go —
+    """Where the time of the thread that owns the engine goes, under
+    the names of ``obs.TICK_PHASES`` (inside ``PagedEngine.step``) and
+    ``obs.LOOP_PHASES`` (between two steps; the serving gateway's
+    worker loop reports those through ``PagedEngine.loop_phase``).
 
-    - ``h2d``      — mirror/patch-queue uploads (``jnp.asarray``)
-    - ``dispatch`` — the jitted tick program CALL (enqueue time in ring
-                     mode; enqueue+nothing-else either way — compute is
-                     NOT here)
-    - ``device``   — block-until-ready on the drain boundary: the
-                     program-bound wait the host actually ate
-    - ``drain``    — the D2H ``device_get`` after readiness
-    - ``host``     — the RESIDUAL: tick wall minus the four bracketed
-                     phases (scheduler bookkeeping, descriptor packing,
-                     stop matching, trace emission)
+    A phase is a BRACKET, ``with engine._phase(name):`` (a
+    ``_PhaseSpan`` over ``open`` / ``close``). Brackets nest, and a
+    bracket's time is its own: what an inner bracket took
+    (the uploads inside ``stage``, the program call inside ``chunk``)
+    is taken out of the outer one. ``host`` is never bracketed: it is
+    the RESIDUAL of a tick, its wall minus every bracket, so the
+    in-tick phases sum to the tick wall EXACTLY (pinned under an
+    injected clock in tests/test_tick_profile.py) and
+    ``serve_loadgen``'s ``phase_breakdown`` and ``obs_report
+    phase_decompose`` split tok/s with no unexplained remainder.
+    Brackets outside a tick (the loop phases; the scoped drain a
+    cancel runs between steps) feed the totals and histograms but no
+    tick record. ``thread_wall_ms`` runs from the first bracket or tick
+    to the last, so the share of the thread's time under no name is
+    ``1 - (sum(totals)) / thread_wall_ms``.
 
-    The residual construction makes the five phases sum to the tick
-    wall EXACTLY (pinned under an injected clock in
-    tests/test_tick_profile.py), which is what lets ``serve_loadgen``'s
-    ``phase_breakdown`` and ``obs_report phase_decompose`` split tok/s
-    into host/dispatch/device shares without an unexplained remainder.
+    While a bracket is open it also holds a
+    ``jax.profiler.TraceAnnotation("tick/<phase>")``, and a tick one
+    ``TraceAnnotation("tick", n=<tick index>)``: inside a profiler
+    trace the thread's phases lie on a host line of the same
+    ``.xplane.pb`` as the device's ops, on its clock. The k-th
+    ``tick/dispatch`` span of a decode tick is the k-th ``_fused_tick*``
+    module on the device.
 
     Host-side bookkeeping only: phases land in registry histograms
     (``paged_tick_phase_ms{phase=...}`` on the SERVING_MS_BUCKETS grid,
@@ -397,12 +458,14 @@ class _TickPhaseProfile:
     ``clock`` is injectable (tests pin the phase math deterministically
     the way ``MetricsTimeSeries(clock=...)`` does)."""
 
+    PHASES = obs.TICK_PHASES + obs.LOOP_PHASES
+
     def __init__(self, labels: Dict[str, str], clock=None,
                  capacity: int = 1024):
         self.clock = clock if clock is not None else time.perf_counter
         self.capacity = max(int(capacity), 1)
         self.ring: deque = deque(maxlen=self.capacity)
-        self.totals = {p: 0.0 for p in obs.TICK_PHASES}
+        self.totals = {p: 0.0 for p in self.PHASES}
         self.wall_total_ms = 0.0
         self.ticks = 0
         reg = obs.registry()
@@ -410,48 +473,79 @@ class _TickPhaseProfile:
             p: reg.histogram("paged_tick_phase_ms",
                              buckets=obs.SERVING_MS_BUCKETS,
                              phase=p, **labels)
-            for p in obs.TICK_PHASES}
+            for p in self.PHASES}
         self._h_wall = reg.histogram("paged_tick_wall_ms",
                                      buckets=obs.SERVING_MS_BUCKETS,
                                      **labels)
+        self._span_names = {p: "tick/" + p for p in self.PHASES}
+        # open brackets, outermost first: [phase, start, seconds its
+        # inner brackets took, the trace annotation it holds]
+        self._stack: List[list] = []
         self._acc: Optional[Dict[str, float]] = None
+        self._tick_ann = None
         self._t0 = 0.0
+        self._t_first: Optional[float] = None
+        self._t_last = 0.0
         self._last: Optional[Dict[str, float]] = None
+
+    @property
+    def thread_wall_ms(self) -> float:
+        """From the first bracket or tick to the end of the last."""
+        return 0.0 if self._t_first is None \
+            else max(self._t_last - self._t_first, 0.0) * 1e3
+
+    def span(self, phase: str) -> _PhaseSpan:
+        if phase not in self._span_names:
+            raise KeyError(phase)
+        return _PhaseSpan(self, phase)
+
+    def open(self, phase: str):
+        ann = obs._trace_annotation(self._span_names[phase])
+        if ann is not None:
+            ann.__enter__()
+        t = self.clock()
+        if self._t_first is None:
+            self._t_first = t
+        self._stack.append([phase, t, 0.0, ann])
+
+    def close(self):
+        phase, t0, inner, ann = self._stack.pop()
+        t1 = self.clock()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self._t_last = t1
+        if self._stack:
+            self._stack[-1][2] += t1 - t0
+        dt_ms = max((t1 - t0 - inner) * 1e3, 0.0)
+        if self._acc is not None and phase in self._acc:
+            self._acc[phase] += dt_ms
+        else:
+            self.totals[phase] += dt_ms
+            self._hists[phase].observe(dt_ms)
 
     def begin(self):
         """Open a tick window (top of ``PagedEngine.step``)."""
+        self._tick_ann = obs._trace_annotation("tick", n=self.ticks)
+        if self._tick_ann is not None:
+            self._tick_ann.__enter__()
         self._acc = {p: 0.0 for p in obs.TICK_PHASES if p != "host"}
         self._t0 = self.clock()
-
-    def add(self, phase: str, dt_ms: float):
-        """Accumulate one bracketed window. Out-of-tick windows (the
-        scoped drains a cancel/expiry runs between steps) feed the
-        totals and histograms but no tick record — there is no tick."""
-        dt_ms = max(float(dt_ms), 0.0)
-        if self._acc is None:
-            self.totals[phase] += dt_ms
-            self._hists[phase].observe(dt_ms)
-            return
-        self._acc[phase] += dt_ms
-
-    def acc(self, phase: str) -> float:
-        """Current tick's accumulated time for ``phase`` (0 outside a
-        tick) — lets a caller bracket a compound expression and deduct
-        the child uploads it already counted."""
-        return self._acc.get(phase, 0.0) if self._acc is not None \
-            else 0.0
+        if self._t_first is None:
+            self._t_first = self._t0
 
     def end(self, *, dispatches: int, uploads: int, nbytes: int,
             patches: int, active: int):
         """Close the tick: host = wall - bracketed phases (clamped at
         0), observe histograms, append the ring record."""
         t1 = self.clock()
+        self._t_last = t1
+        if self._tick_ann is not None:
+            self._tick_ann.__exit__(None, None, None)
+            self._tick_ann = None
         wall = max((t1 - self._t0) * 1e3, 0.0)
-        acc = self._acc or {}
+        phases = self._acc or {}
         self._acc = None
-        host = max(wall - sum(acc.values()), 0.0)
-        phases = dict(acc)
-        phases["host"] = host
+        phases["host"] = max(wall - sum(phases.values()), 0.0)
         rec: Dict[str, Any] = {
             "tick": self.ticks, "t": round(float(t1), 6),
             "wall_ms": round(wall, 4),
@@ -469,26 +563,35 @@ class _TickPhaseProfile:
         self.ticks += 1
         self.ring.append(rec)
         self._last = {k: rec[k] for k in
-                      ("wall_ms",) + tuple(f"{p}_ms"
-                                           for p in obs.TICK_PHASES)}
+                      ("tick", "wall_ms") + tuple(
+                          f"{p}_ms" for p in obs.TICK_PHASES)}
 
     def last_phases(self) -> Optional[Dict[str, float]]:
-        """Most recent COMPLETED tick's phase split — what a drained
-        tick trace event attaches as its per-request decode share
-        context (the drain commits tokens one dispatch behind)."""
+        """Most recent COMPLETED tick's index and phase split — what a
+        drained tick trace event attaches as its per-request decode
+        share context (the drain commits tokens one dispatch behind).
+        The index is the ``n`` of that tick's ``tick`` span in a
+        profiler trace: request id -> tick -> device program."""
         return dict(self._last) if self._last is not None else None
+
+    def summary(self) -> Dict[str, Any]:
+        """Lifetime totals: the tick side, the loop side, and the
+        thread's wall they are shares of."""
+        return {"ticks": self.ticks,
+                "wall_total_ms": round(self.wall_total_ms, 4),
+                "phase_totals_ms": {p: round(self.totals[p], 4)
+                                    for p in obs.TICK_PHASES},
+                "loop_totals_ms": {p: round(self.totals[p], 4)
+                                   for p in obs.LOOP_PHASES},
+                "thread_wall_ms": round(self.thread_wall_ms, 4)}
 
     def to_doc(self, engine: str) -> Dict[str, Any]:
         """The ``tickphase/1`` document
         (``obs.validate_tickphase_doc`` checks it)."""
-        return {"schema": obs.TICKPHASE_SCHEMA, "engine": engine,
-                "dumped_wall": time.time(),
-                "clock_now": float(self.clock()),
-                "capacity": self.capacity, "ticks": self.ticks,
-                "wall_total_ms": round(self.wall_total_ms, 4),
-                "phase_totals_ms": {p: round(v, 4) for p, v
-                                    in self.totals.items()},
-                "entries": list(self.ring)}
+        return dict(self.summary(), schema=obs.TICKPHASE_SCHEMA,
+                    engine=engine, dumped_wall=time.time(),
+                    clock_now=float(self.clock()),
+                    capacity=self.capacity, entries=list(self.ring))
 
 
 class PagedEngine:
@@ -632,9 +735,22 @@ class PagedEngine:
                       "spill_spans", "spill_restores",
                       "spill_restored_tokens",
                       "spill_restore_failures")}
+        # paged_decode_step_ms is what the host can see of one decode
+        # dispatch: with a readback in the tick (host path, ring_mode
+        # off) the program's whole run, call to tokens on the host; in
+        # ring mode the tick returns without reading, so the window is
+        # the next step's D2H read of the token ring (short when the
+        # program finished while the host worked). The program's time
+        # on the device is the profiler trace's, in either mode.
         self._h_decode = reg.histogram("paged_decode_step_ms",
                                        buckets=obs.SERVING_MS_BUCKETS,
                                        **self._obs_labels)
+        # the host's time in one prefill chunk (_advance_chunk): input
+        # staging, the program's call and, on a prompt's last chunk,
+        # the read of its first token, which waits for the program
+        self._h_chunk = reg.histogram("paged_prefill_chunk_ms",
+                                      buckets=obs.SERVING_MS_BUCKETS,
+                                      **self._obs_labels)
         self._h_wait = reg.histogram("paged_queue_wait_ms",
                                      buckets=obs.SERVING_MS_BUCKETS,
                                      **self._obs_labels)
@@ -694,6 +810,11 @@ class PagedEngine:
         # size: a full-state rebuild and a one-row delta patch are both
         # ONE h2d_uploads event but differ by orders of magnitude here.
         self.dispatch_count = 0
+        # steps that dispatched a decode program: what a per-tick
+        # figure divides by (``decode_steps`` counts K device ticks
+        # for a K-tick scan; a chunk figure divides by the
+        # ``prefill_chunks`` counter)
+        self.decode_ticks = 0
         self.h2d_uploads = 0
         self.h2d_upload_bytes = 0
         self.full_rebuilds = 0
@@ -900,18 +1021,40 @@ class PagedEngine:
     @property
     def tick_phase_totals(self) -> Optional[Dict[str, float]]:
         """Cumulative per-phase milliseconds (None with the profiler
-        off) — what ``serve_loadgen`` sums into ``phase_breakdown``."""
+        off): the in-tick phases (``obs.TICK_PHASES``, what
+        ``serve_loadgen`` sums into ``phase_breakdown``) and the
+        worker loop's (``obs.LOOP_PHASES``)."""
         return dict(self._prof.totals) if self._prof is not None \
             else None
 
     @property
     def tick_wall_ms_total(self) -> float:
         """Cumulative measured tick wall (ms; 0 with the profiler
-        off). By the residual construction,
-        ``sum(tick_phase_totals.values()) == tick_wall_ms_total`` up
-        to per-tick clamping."""
+        off). By the residual construction the ``obs.TICK_PHASES``
+        entries of ``tick_phase_totals`` sum to it, up to per-tick
+        clamping."""
         return self._prof.wall_total_ms if self._prof is not None \
             else 0.0
+
+    def tick_profile_summary(self) -> Optional[Dict[str, Any]]:
+        """Tick count, tick wall, in-tick and loop phase totals and the
+        thread's wall so far (None with the profiler off): what
+        ``/debugz`` shows and a ``/profilez`` window subtracts."""
+        return self._prof.summary() if self._prof is not None else None
+
+    def _phase(self, phase: str):
+        """``with self._phase("stage"): ...`` — one bracket of the tick
+        profiler, the only form there is: a ``_PhaseSpan``, or the
+        shared no-op with the profiler off (one ``None`` check)."""
+        prof = self._prof
+        return _NO_PHASE if prof is None else prof.span(phase)
+
+    def loop_phase(self, phase: str):
+        """``with engine.loop_phase("emit"): ...`` — the same bracket
+        under its public name: how the thread that drives ``step()``
+        reports what it does BETWEEN steps (one of ``obs.LOOP_PHASES``),
+        so that every moment of that thread has a name."""
+        return self._phase(phase)
 
     def tick_profile_doc(self) -> Optional[Dict[str, Any]]:
         """The ``tickphase/1`` ring document (None, profiler off)."""
@@ -953,8 +1096,11 @@ class PagedEngine:
     @property
     def stats(self) -> Dict[str, int]:
         """Scheduler-counter snapshot (pre-migration dict shape; the
-        values now come from the observability registry)."""
-        return {k: int(c.value) for k, c in self._counters.items()}
+        values now come from the observability registry), plus
+        ``decode_ticks``, the count per-tick figures divide by."""
+        out = {k: int(c.value) for k, c in self._counters.items()}
+        out["decode_ticks"] = self.decode_ticks
+        return out
 
     def _count(self, key: str, n: int = 1):
         self._counters[key].inc(n)
@@ -1043,18 +1189,21 @@ class PagedEngine:
         fused patch stage (ISSUE 19) applies any staged transition
         descriptors first — same program, zero extra dispatches."""
         from .sampling import repetition_penalty_rows, sample_token_rows
-        st = self._apply_patch_queue(st)
+        with jax.named_scope("patch"):
+            st = self._apply_patch_queue(st)
         caches = self._paged_caches(pools, st["tables"], st["lens"])
         logits, new_caches = self.fn(params, st["last"][:, None],
                                      kv_caches=caches,
                                      positions=st["lens"][:, None])
-        raw = repetition_penalty_rows(logits[:, -1].astype(jnp.float32),
-                                      seen, st["reps"])
+        with jax.named_scope("penalty"):    # the last position's slice too
+            last = logits[:, -1].astype(jnp.float32)
+        raw = repetition_penalty_rows(last, seen, st["reps"])
         nxt, lps, new_keys = sample_token_rows(raw, st["keys"],
                                                st["temps"], st["tks"],
                                                st["tps"])
-        return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                    new_keys)
+        with jax.named_scope("epilogue"):
+            return self._fused_epilogue(st, new_caches, seen, nxt, lps,
+                                        new_keys)
 
     def _fused_tick_greedy(self, params, pools, seen, st):
         """Argmax-only fused tick (same specialization contract as
@@ -1063,18 +1212,22 @@ class PagedEngine:
         no-split greedy executable). Opens with the same fused patch
         stage as `_fused_tick`."""
         from .sampling import repetition_penalty_rows
-        st = self._apply_patch_queue(st)
+        with jax.named_scope("patch"):
+            st = self._apply_patch_queue(st)
         caches = self._paged_caches(pools, st["tables"], st["lens"])
         logits, new_caches = self.fn(params, st["last"][:, None],
                                      kv_caches=caches,
                                      positions=st["lens"][:, None])
-        raw = repetition_penalty_rows(logits[:, -1].astype(jnp.float32),
-                                      seen, st["reps"])
-        nxt = jnp.argmax(raw, axis=-1).astype(jnp.int32)
-        lps = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
-                                  nxt[:, None], axis=-1)[:, 0]
-        return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                    st["keys"])
+        with jax.named_scope("penalty"):    # the last position's slice too
+            last = logits[:, -1].astype(jnp.float32)
+        raw = repetition_penalty_rows(last, seen, st["reps"])
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(raw, axis=-1).astype(jnp.int32)
+            lps = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
+                                      nxt[:, None], axis=-1)[:, 0]
+        with jax.named_scope("epilogue"):
+            return self._fused_epilogue(st, new_caches, seen, nxt, lps,
+                                        st["keys"])
 
     def _fused_scan(self, params, pools, seen, st, *, greedy: bool,
                     K: int):
@@ -1455,13 +1608,9 @@ class PagedEngine:
             for j, i in enumerate(rows):
                 pq[j] = self._pack_descriptor(i)
                 self._key_overrides.discard(i)
-            prof = self._prof
-            if prof is not None:
-                tp = prof.clock()
-            self._dev["pq"] = self._put(pq)
-            self._dev["pqn"] = self._put(np.int32(len(rows)))
-            if prof is not None:
-                prof.add("h2d", (prof.clock() - tp) * 1e3)
+            with self._phase("h2d"):
+                self._dev["pq"] = self._put(pq)
+                self._dev["pqn"] = self._put(np.int32(len(rows)))
             nbytes = pq.nbytes + 4
             self.h2d_uploads += 1
             self.h2d_upload_bytes += nbytes
@@ -1540,67 +1689,63 @@ class PagedEngine:
                   + self.top_ks.nbytes + self.top_ps.nbytes
                   + self.reps.nbytes + eos.nbytes + rem.nbytes
                   + act.nbytes)
-        prof = self._prof
-        if prof is not None:
-            tp = prof.clock()
-        self._dev = dict(
-            tables=self._put(self.block_tables),
-            lens=self._put(self.seq_lens),
-            last=self._put(last),
-            keys=self._put(self.keys),
-            temps=self._put(self.temps),
-            tks=self._put(self.top_ks),
-            tps=self._put(self.top_ps),
-            reps=self._put(self.reps),
-            eos=self._put(eos),
-            rem=self._put(rem),
-            active=self._put(act),
-        )
-        if self._spec_k:
-            # committed-stream buffer the n-gram proposer matches over
-            # (prompt + emitted tokens per slot; the +k+1 tail slack
-            # absorbs the tick's unconditional candidate writes), plus
-            # the per-request accept EMA and the probe tick counter
-            from .prompt_lookup import token_buffer_row
-            Lbuf = self.M * self.B + self._spec_k + 1
-            tk = np.zeros((self.R, Lbuf), np.int32)
-            ema = np.ones((self.R,), np.float32)
-            for i, s in enumerate(self.slots):
-                if s is None:
-                    continue
-                tk[i] = token_buffer_row(s.prompt + s.tokens, Lbuf)
-                ema[i] = s.spec_ema
-            nbytes += tk.nbytes + ema.nbytes
-            self._dev.update(toks=self._put(tk), ema=self._put(ema),
-                             tickc=self._zeros((self.R,), jnp.int32))
-        if self._ring:
-            # async token ring (ISSUE 11): rebuilt empty on every
-            # refresh — a refresh only ever runs with the ring fully
-            # drained (every transition drains first), so resetting
-            # the write cursors cannot lose entries
-            self._dev.update(
-                ring=self._zeros((self.R, self._ring_len), jnp.int32),
-                rlps=self._zeros((self.R, self._ring_len), jnp.float32),
-                wcur=self._zeros((self.R,), jnp.int32))
+        with self._phase("h2d"):
+            self._dev = dict(
+                tables=self._put(self.block_tables),
+                lens=self._put(self.seq_lens),
+                last=self._put(last),
+                keys=self._put(self.keys),
+                temps=self._put(self.temps),
+                tks=self._put(self.top_ks),
+                tps=self._put(self.top_ps),
+                reps=self._put(self.reps),
+                eos=self._put(eos),
+                rem=self._put(rem),
+                active=self._put(act),
+            )
             if self._spec_k:
-                # per-dispatch proposer stats ride the state so the
-                # drain can count spec_proposed/accepted without a
-                # second readback
+                # committed-stream buffer the n-gram proposer matches over
+                # (prompt + emitted tokens per slot; the +k+1 tail slack
+                # absorbs the tick's unconditional candidate writes), plus
+                # the per-request accept EMA and the probe tick counter
+                from .prompt_lookup import token_buffer_row
+                Lbuf = self.M * self.B + self._spec_k + 1
+                tk = np.zeros((self.R, Lbuf), np.int32)
+                ema = np.ones((self.R,), np.float32)
+                for i, s in enumerate(self.slots):
+                    if s is None:
+                        continue
+                    tk[i] = token_buffer_row(s.prompt + s.tokens, Lbuf)
+                    ema[i] = s.spec_ema
+                nbytes += tk.nbytes + ema.nbytes
+                self._dev.update(toks=self._put(tk), ema=self._put(ema),
+                                 tickc=self._zeros((self.R,), jnp.int32))
+            if self._ring:
+                # async token ring (ISSUE 11): rebuilt empty on every
+                # refresh — a refresh only ever runs with the ring fully
+                # drained (every transition drains first), so resetting
+                # the write cursors cannot lose entries
                 self._dev.update(
-                    kprop_last=self._zeros((self.R,), jnp.int32),
-                    macc_last=self._zeros((self.R,), jnp.int32))
-            self._drained[:] = 0
-        if self._fuse_patches:
-            # empty staged-patch queue: a rebuild by definition leaves
-            # nothing pending (bytes not counted — zeros carry no
-            # host-side payload, and the tests pin the rebuild byte
-            # cost as the non-fused reference)
-            self._dev.update(
-                pq=self._zeros((self._pq_len, self._desc_len),
-                               jnp.int32),
-                pqn=self._zeros((), jnp.int32))
-        if prof is not None:
-            prof.add("h2d", (prof.clock() - tp) * 1e3)
+                    ring=self._zeros((self.R, self._ring_len), jnp.int32),
+                    rlps=self._zeros((self.R, self._ring_len), jnp.float32),
+                    wcur=self._zeros((self.R,), jnp.int32))
+                if self._spec_k:
+                    # per-dispatch proposer stats ride the state so the
+                    # drain can count spec_proposed/accepted without a
+                    # second readback
+                    self._dev.update(
+                        kprop_last=self._zeros((self.R,), jnp.int32),
+                        macc_last=self._zeros((self.R,), jnp.int32))
+                self._drained[:] = 0
+            if self._fuse_patches:
+                # empty staged-patch queue: a rebuild by definition leaves
+                # nothing pending (bytes not counted — zeros carry no
+                # host-side payload, and the tests pin the rebuild byte
+                # cost as the non-fused reference)
+                self._dev.update(
+                    pq=self._zeros((self._pq_len, self._desc_len),
+                                   jnp.int32),
+                    pqn=self._zeros((), jnp.int32))
         self.h2d_upload_bytes += nbytes
         self._count("h2d_upload_bytes", nbytes)
         self._h_bytes.observe(nbytes)
@@ -1648,15 +1793,17 @@ class PagedEngine:
         logits, new_caches = self.fn(params, ids, kv_caches=caches,
                                      positions=positions,
                                      paged_chunk=True)
-        seen_row = seen_row.at[ids[0]].max(
-            jnp.arange(bucket) < total_len - start)
-        row = repetition_penalty_rows(
-            logits[0, total_len - start - 1][None].astype(jnp.float32),
-            seen_row[None], rep[None])
+        with jax.named_scope("penalty"):
+            seen_row = seen_row.at[ids[0]].max(
+                jnp.arange(bucket) < total_len - start)
+            row = logits[0, total_len - start - 1][None]
+        row = repetition_penalty_rows(row.astype(jnp.float32),
+                                      seen_row[None], rep[None])
         nxt, lps, new_key = sample_token_rows(row, key[None],
                                               temp[None], tk[None],
                                               tp[None])
-        seen_out = seen_row.at[nxt[0]].set(True)
+        with jax.named_scope("epilogue"):
+            seen_out = seen_row.at[nxt[0]].set(True)
         return (nxt[0], lps[0], new_key[0], seen_row, seen_out,
                 [(c.kp, c.vp) for c in new_caches])
 
@@ -2264,49 +2411,66 @@ class PagedEngine:
     def _advance_chunk(self, slot_id: int):
         """Run ONE chunk of slot's prompt prefill; on the final chunk the
         first generated token materializes and the slot joins decode."""
-        req = self.slots[slot_id]
-        ids = req.prompt
-        start = req.prefill_pos
-        live = min(self.chunk, len(ids) - start)
-        last = start + live >= len(ids)
-        padded = np.zeros((1, self.chunk), np.int32)
-        padded[0, :live] = ids[start:start + live]
-        row = self.block_tables[slot_id]
-        self._mark_dirty(slot_id)    # lens/activation change this tick
-        self.dispatch_count += 1
-        self._count("dispatches")
-        nxt, lp, new_key, seen_mid, seen_fin, self.pools = self._chunk_jit(
-            self.params, self.pools, self._put(row),
-            self._put(padded), np.int32(start),
-            np.int32(start + live), self._put(req.key),
-            np.float32(req.temperature), np.int32(req.top_k),
-            np.float32(req.top_p), np.float32(req.rep),
-            self.seen[slot_id], bucket=self.chunk)
-        self._count("prefill_chunks")
-        if self.trace_sink is not None:
-            self.trace_sink(req.request_id, "prefill_chunk",
-                            start=start, tokens=live)
-        req.prefill_pos = start + live
-        self.seq_lens[slot_id] = req.prefill_pos
-        # mid chunks keep the ids-only mask; the final chunk's committed
-        # sample rides in seen_fin (mirrors the PRNG-key protocol)
-        self.seen = self.seen.at[slot_id].set(seen_fin if last
-                                              else seen_mid)
-        if last:
-            self._count("prefills")
-            self._register_prefix(req)
-            self.keys[slot_id] = np.array(new_key)
-            self._key_overrides.add(slot_id)
-            req.key = self.keys[slot_id].copy()
-            first = int(nxt)
-            req.tokens.append(first)
-            req.lps.append(float(lp))
+        t_chunk = time.perf_counter()
+        # everything here that is not an upload, the program's call or
+        # the wait for its result is the chunk's own host work
+        with self._phase("chunk") as br:
+            req = self.slots[slot_id]
+            ids = req.prompt
+            start = req.prefill_pos
+            live = min(self.chunk, len(ids) - start)
+            last = start + live >= len(ids)
+            padded = np.zeros((1, self.chunk), np.int32)
+            padded[0, :live] = ids[start:start + live]
+            row = self.block_tables[slot_id]
+            self._mark_dirty(slot_id)    # lens/activation change this tick
+            self.dispatch_count += 1
+            self._count("dispatches")
+            with self._phase("h2d") as call:
+                row, padded, key = (self._put(row), self._put(padded),
+                                    self._put(req.key))
+                call.switch("dispatch")
+                (nxt, lp, new_key, seen_mid, seen_fin,
+                 self.pools) = self._chunk_jit(
+                    self.params, self.pools, row, padded, np.int32(start),
+                    np.int32(start + live), key,
+                    np.float32(req.temperature), np.int32(req.top_k),
+                    np.float32(req.top_p), np.float32(req.rep),
+                    self.seen[slot_id], bucket=self.chunk)
+            self._count("prefill_chunks")
             if self.trace_sink is not None:
-                self.trace_sink(req.request_id, "prefill_done",
-                                tokens=len(ids))
-            if self._stop_hit(req) or req.max_new <= 1 \
-                    or (req.eos is not None and first == req.eos):
-                self._finish(slot_id)
+                self.trace_sink(req.request_id, "prefill_chunk",
+                                start=start, tokens=live)
+            req.prefill_pos = start + live
+            self.seq_lens[slot_id] = req.prefill_pos
+            # mid chunks keep the ids-only mask; the final chunk's
+            # committed sample rides in seen_fin (mirrors the PRNG-key
+            # protocol)
+            self.seen = self.seen.at[slot_id].set(seen_fin if last
+                                                  else seen_mid)
+            if last:
+                self._count("prefills")
+                self._register_prefix(req)
+                if br.on:
+                    # the reads below wait for the chunk's program
+                    with self._phase("device"):
+                        try:
+                            jax.block_until_ready((new_key, nxt, lp))
+                        except Exception:
+                            pass
+                self.keys[slot_id] = np.array(new_key)
+                self._key_overrides.add(slot_id)
+                req.key = self.keys[slot_id].copy()
+                first = int(nxt)
+                req.tokens.append(first)
+                req.lps.append(float(lp))
+                if self.trace_sink is not None:
+                    self.trace_sink(req.request_id, "prefill_done",
+                                    tokens=len(ids))
+                if self._stop_hit(req) or req.max_new <= 1 \
+                        or (req.eos is not None and first == req.eos):
+                    self._finish(slot_id)
+        self._h_chunk.observe((time.perf_counter() - t_chunk) * 1e3)
 
     def _grow_blocks(self, slot_id: int, need: int,
                      reserve: int = 0) -> bool:
@@ -2621,15 +2785,10 @@ class PagedEngine:
             },
             # tick-phase profiler (ISSUE 20): where the last tick's
             # wall time went + lifetime totals, when tick_profile is on
-            "tick_profile": {
-                "enabled": self._prof is not None,
-                "ticks": self._prof.ticks,
-                "wall_total_ms": round(self._prof.wall_total_ms, 3),
-                "phase_totals_ms": {
-                    p: round(v, 3)
-                    for p, v in self._prof.totals.items()},
-                "last_tick": self._prof.last_phases(),
-            } if self._prof is not None else {"enabled": False},
+            "tick_profile": dict(
+                self._prof.summary(), enabled=True,
+                last_tick=self._prof.last_phases(),
+            ) if self._prof is not None else {"enabled": False},
         }
 
     # ------------------------------------------------- fleet fault tolerance
@@ -2754,58 +2913,69 @@ class PagedEngine:
         mode dispatches WITHOUT a readback and returns).
 
         With ``tick_profile`` on, the whole tick runs inside one
-        profiler window: explicitly bracketed h2d/dispatch/device/drain
-        time plus the host residual land in the per-tick ring and the
-        phase histograms (ISSUE 20)."""
+        profiler window: every bracketed phase of ``obs.TICK_PHASES``
+        plus the host residual land in the per-tick ring and the phase
+        histograms."""
         prof = self._prof
-        if prof is None:
-            return self._step_inner()
-        prof.begin()
-        d0, u0 = self.dispatch_count, self.h2d_uploads
-        b0, p0 = self.h2d_upload_bytes, self.patches_fused
+        if prof is not None:
+            prof.begin()
+            d0, u0 = self.dispatch_count, self.h2d_uploads
+            b0, p0 = self.h2d_upload_bytes, self.patches_fused
+        # ONE call site, profiler on or off: the programs traced below
+        # carry this stack in their metadata, which is part of their
+        # compile-cache key (utils/compile_cache.py)
         try:
             return self._step_inner()
         finally:
-            prof.end(
-                dispatches=self.dispatch_count - d0,
-                uploads=self.h2d_uploads - u0,
-                nbytes=self.h2d_upload_bytes - b0,
-                patches=self.patches_fused - p0,
-                active=sum(1 for s in self.slots if s is not None))
+            if prof is not None:
+                prof.end(
+                    dispatches=self.dispatch_count - d0,
+                    uploads=self.h2d_uploads - u0,
+                    nbytes=self.h2d_upload_bytes - b0,
+                    patches=self.patches_fused - p0,
+                    active=sum(1 for s in self.slots if s is not None))
 
     def _step_inner(self):
         self._drain_pending()
-        self._expire()
-        while self._try_admit():
-            pass
+        with self._phase("expire") as br:
+            self._expire()
+            br.switch("admit")
+            while self._try_admit():
+                pass
         if self.chunk is not None:
             for i in range(self.R):
                 s = self.slots[i]
                 if s is not None and s.prefill_pos < len(s.prompt):
                     self._advance_chunk(i)
-        for i in range(self.R):
-            if self.slots[i] is None or \
-                    self.slots[i].prefill_pos < len(self.slots[i].prompt):
-                continue
-            while not self._ensure_block(i):
-                if not self._preempt_youngest(exclude=i):
-                    raise RuntimeError(
-                        "paged KV pool cannot hold even one request; "
-                        "raise num_blocks")
-        active = [i for i, s in enumerate(self.slots)
-                  if s is not None and s.tokens]
+        with self._phase("stage"):
+            for i in range(self.R):
+                if self.slots[i] is None or \
+                        self.slots[i].prefill_pos < len(self.slots[i].prompt):
+                    continue
+                while not self._ensure_block(i):
+                    if not self._preempt_youngest(exclude=i):
+                        raise RuntimeError(
+                            "paged KV pool cannot hold even one request; "
+                            "raise num_blocks")
+            active = [i for i, s in enumerate(self.slots)
+                      if s is not None and s.tokens]
+            scan = False
+            if active and self._fused:
+                if self._spec_k:
+                    # speculative ticks ARE multi-token dispatches: they
+                    # replace the scan fusion (see __init__)
+                    self._spec_headroom(active)
+                else:
+                    scan = self._ticks_per_dispatch > 1 \
+                        and self._scan_ticks(active)
         if not active:
             return
-        if self._fused:
-            if self._spec_k:
-                # speculative ticks ARE multi-token dispatches: they
-                # replace the scan fusion (see __init__)
-                self._spec_headroom(active)
-                return self._decode_fused_spec(active)
-            scan = self._ticks_per_dispatch > 1 \
-                and self._scan_ticks(active)
-            return self._decode_fused(active, scan=scan)
-        return self._decode_host(active)
+        self.decode_ticks += 1
+        if not self._fused:
+            return self._decode_host(active)
+        if self._spec_k:
+            return self._decode_fused_spec(active)
+        return self._decode_fused(active, scan=scan)
 
     def _drain_pending(self):
         """Consume the outstanding ring dispatch (ring mode): fetch the
@@ -2838,42 +3008,40 @@ class PagedEngine:
         if not all(a.is_ready() for a in arrs):
             self.ring_blocking_drains += 1
             self.d2h_syncs += 1
-        prof = self._prof
-        if prof is not None:
-            # device-wait vs D2H split (ISSUE 20): block-until-ready is
-            # the program-bound wait; the device_get after it is pure
-            # drain. Semantically free — device_get blocks on readiness
-            # anyway — so profile-on streams stay bitwise identical.
-            tp = prof.clock()
-            try:
-                jax.block_until_ready(arrs)
-            except Exception:
-                pass
-            tr = prof.clock()
-            prof.add("device", (tr - tp) * 1e3)
-        t0 = time.perf_counter()
-        vals = jax.device_get(arrs)
-        # ring mode's decode-step histogram window is the drain wait —
-        # the only host-visible program-bound time left on the path
-        self._h_decode.observe((time.perf_counter() - t0) * 1e3)
-        if prof is not None:
-            prof.add("drain", (prof.clock() - tr) * 1e3)
-        ring, rlps, wcur, act_now = vals[:4]
-        kprop = macc = None
-        if spec:
-            kprop, macc = vals[4], vals[5]
-            prop = int(kprop[p["rows"]].sum())
-            if prop:
-                self._count("spec_proposed", prop)
-                acc = int(macc[p["rows"]].sum())
-                if acc:
-                    self._count("spec_accepted", acc)
-        lag = self.dispatch_count - p["seq"] + 1   # dispatches until drain
-        for i in p["rows"]:
-            self._commit_row_drain(
-                i, ring[i], rlps[i], wcur[i], act_now[i],
-                int(kprop[i]) if spec else 0,
-                int(macc[i]) if spec else 0, lag)
+        with self._phase("device") as br:
+            if br.on:
+                # device-wait vs D2H split (ISSUE 20): block-until-ready
+                # is the program-bound wait; the device_get after it is
+                # pure drain. Semantically free — device_get blocks on
+                # readiness anyway — so profile-on streams stay bitwise
+                # identical.
+                try:
+                    jax.block_until_ready(arrs)
+                except Exception:
+                    pass
+                br.switch("drain")
+            t0 = time.perf_counter()
+            vals = jax.device_get(arrs)
+            # ring mode's decode-step histogram window is the drain wait —
+            # the only host-visible program-bound time left on the path
+            self._h_decode.observe((time.perf_counter() - t0) * 1e3)
+            br.switch("commit")
+            ring, rlps, wcur, act_now = vals[:4]
+            kprop = macc = None
+            if spec:
+                kprop, macc = vals[4], vals[5]
+                prop = int(kprop[p["rows"]].sum())
+                if prop:
+                    self._count("spec_proposed", prop)
+                    acc = int(macc[p["rows"]].sum())
+                    if acc:
+                        self._count("spec_accepted", acc)
+            lag = self.dispatch_count - p["seq"] + 1   # dispatches until drain
+            for i in p["rows"]:
+                self._commit_row_drain(
+                    i, ring[i], rlps[i], wcur[i], act_now[i],
+                    int(kprop[i]) if spec else 0,
+                    int(macc[i]) if spec else 0, lag)
 
     def _commit_row_drain(self, i, ring_i, rlps_i, wc, act_i,
                           kp, ma, lag) -> bool:
@@ -2951,38 +3119,35 @@ class PagedEngine:
         if not all(a.is_ready() for a in base_arrs):
             self.ring_blocking_drains += 1
             self.d2h_syncs += 1
-        prof = self._prof
-        if prof is not None:
-            # same device/drain bracketing as the global drain; outside
-            # an open tick (cancel/expiry between steps) the windows
-            # feed totals + histograms only
-            tp = prof.clock()
-            try:
-                jax.block_until_ready(base_arrs)
-            except Exception:
-                pass
-            tr = prof.clock()
-            prof.add("device", (tr - tp) * 1e3)
-        t0 = time.perf_counter()
-        vals = jax.device_get([a[i] for a in base_arrs])
-        # same histogram window as the global drain: in ring mode the
-        # drain wait is the program-bound time, scoped drains included
-        self._h_decode.observe((time.perf_counter() - t0) * 1e3)
-        if prof is not None:
-            prof.add("drain", (prof.clock() - tr) * 1e3)
-        ring_i, rlps_i, wc, act_i = vals[:4]
-        p["rows"].remove(i)
-        if not p["rows"]:
-            self._pending = None
-        kp = ma = 0
-        if spec:
-            kp, ma = int(vals[4]), int(vals[5])
-        if self._commit_row_drain(
-                i, ring_i, rlps_i, wc, act_i, kp, ma,
-                self.dispatch_count - p["seq"] + 1) and kp:
-            self._count("spec_proposed", kp)
-            if ma:
-                self._count("spec_accepted", ma)
+        # same device/drain/commit bracketing as the global drain;
+        # outside an open tick (cancel/expiry between steps) the
+        # windows feed totals + histograms only
+        with self._phase("device") as br:
+            if br.on:
+                try:
+                    jax.block_until_ready(base_arrs)
+                except Exception:
+                    pass
+                br.switch("drain")
+            t0 = time.perf_counter()
+            vals = jax.device_get([a[i] for a in base_arrs])
+            # same histogram window as the global drain: in ring mode the
+            # drain wait is the program-bound time, scoped drains included
+            self._h_decode.observe((time.perf_counter() - t0) * 1e3)
+            br.switch("commit")
+            ring_i, rlps_i, wc, act_i = vals[:4]
+            p["rows"].remove(i)
+            if not p["rows"]:
+                self._pending = None
+            kp = ma = 0
+            if spec:
+                kp, ma = int(vals[4]), int(vals[5])
+            if self._commit_row_drain(
+                    i, ring_i, rlps_i, wc, act_i, kp, ma,
+                    self.dispatch_count - p["seq"] + 1) and kp:
+                self._count("spec_proposed", kp)
+                if ma:
+                    self._count("spec_accepted", ma)
 
     def _drain_slot(self, i: int):
         """Drain before mutating slot ``i``'s mirrors out-of-band:
@@ -3027,13 +3192,8 @@ class PagedEngine:
         self.h2d_upload_bytes += x.nbytes
         self._count("h2d_upload_bytes", x.nbytes)
         self._h_bytes.observe(x.nbytes)
-        prof = self._prof
-        if prof is not None:
-            t = prof.clock()
-            out = self._put(x)
-            prof.add("h2d", (prof.clock() - t) * 1e3)
-            return out
-        return self._put(x)
+        with self._phase("h2d"):
+            return self._put(x)
 
     def _decode_host(self, active):
         """The pre-fusion per-tick path: re-uploads every mirror and
@@ -3048,68 +3208,61 @@ class PagedEngine:
         self.dispatch_count += 1
         self._count("dispatches")
         self.d2h_syncs += 1
-        prof = self._prof
-        if prof is not None:
-            # the jit-call expression below interleaves _up uploads
-            # with the dispatch; deduct the h2d time _up already
-            # counted so the two phases don't double-bill
-            tp = prof.clock()
-            h0 = prof.acc("h2d")
-        if np.all(self.temps[active] <= 0.0):
-            # all-greedy tick: the argmax-only executable
-            nxt, lps, self.seen, self.pools = self._decode_greedy_jit(
-                self.params, self.pools, self._up(self.block_tables),
-                self._up(self.seq_lens), self._up(last),
-                self.seen, self._up(self.reps), self._up(act_mask))
-        else:
-            nxt, lps, new_keys, self.seen, self.pools = self._decode_jit(
-                self.params, self.pools, self._up(self.block_tables),
-                self._up(self.seq_lens), self._up(last),
-                self._up(self.keys), self._up(self.temps),
-                self._up(self.top_ks), self._up(self.top_ps),
-                self.seen, self._up(self.reps), self._up(act_mask))
-            self.keys = np.array(new_keys)  # copy: jax views read-only
-        if prof is not None:
-            prof.add("dispatch", (prof.clock() - tp) * 1e3
-                     - (prof.acc("h2d") - h0))
-            tp = prof.clock()
-            try:
-                jax.block_until_ready((nxt, lps))
-            except Exception:
-                pass
-            tr = prof.clock()
-            prof.add("device", (tr - tp) * 1e3)
-        nxt = np.asarray(nxt)
-        lps = np.asarray(lps)
-        if prof is not None:
-            prof.add("drain", (prof.clock() - tr) * 1e3)
-        # the np.asarray above synced the device, so this is the REAL
-        # per-tick latency (dispatch + compute), not just dispatch
-        self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
-        self._count("decode_steps")
-        self._count("slot_steps", self.R)
-        self._count("active_slot_steps", len(active))
-        sink = self.trace_sink
-        for i in active:
-            slot = self.slots[i]
-            self.seq_lens[i] += 1   # the decode wrote last token's K/V
-            tok = int(nxt[i])
-            slot.tokens.append(tok)
-            slot.lps.append(float(lps[i]))
-            slot.key = self.keys[i].copy()
-            if sink is not None:
-                ev = dict(n=1)
-                ph = self._tick_phase_fields()
-                if ph is not None:
-                    ev["phase"] = ph
-                sink(slot.request_id, "tick", **ev)
-            done = self._stop_hit(slot) or \
-                len(slot.tokens) >= slot.max_new or \
-                (slot.eos is not None and tok == slot.eos)
-            if done:
-                # the final token's K/V was never written - fine, it is
-                # never attended to
-                self._finish(i)
+        # the jit-call expression below interleaves _up uploads with
+        # the dispatch: their h2d brackets nest in this one and take
+        # their time out of it
+        with self._phase("dispatch") as br:
+            if np.all(self.temps[active] <= 0.0):
+                # all-greedy tick: the argmax-only executable
+                nxt, lps, self.seen, self.pools = self._decode_greedy_jit(
+                    self.params, self.pools, self._up(self.block_tables),
+                    self._up(self.seq_lens), self._up(last),
+                    self.seen, self._up(self.reps), self._up(act_mask))
+            else:
+                nxt, lps, new_keys, self.seen, self.pools = self._decode_jit(
+                    self.params, self.pools, self._up(self.block_tables),
+                    self._up(self.seq_lens), self._up(last),
+                    self._up(self.keys), self._up(self.temps),
+                    self._up(self.top_ks), self._up(self.top_ps),
+                    self.seen, self._up(self.reps), self._up(act_mask))
+                self.keys = np.array(new_keys)  # copy: jax views read-only
+            if br.on:
+                br.switch("device")
+                try:
+                    jax.block_until_ready((nxt, lps))
+                except Exception:
+                    pass
+                br.switch("drain")
+            nxt = np.asarray(nxt)
+            lps = np.asarray(lps)
+            br.switch("commit")
+            # the np.asarray above synced the device, so this is the REAL
+            # per-tick latency (dispatch + compute), not just dispatch
+            self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
+            self._count("decode_steps")
+            self._count("slot_steps", self.R)
+            self._count("active_slot_steps", len(active))
+            sink = self.trace_sink
+            for i in active:
+                slot = self.slots[i]
+                self.seq_lens[i] += 1   # the decode wrote last token's K/V
+                tok = int(nxt[i])
+                slot.tokens.append(tok)
+                slot.lps.append(float(lps[i]))
+                slot.key = self.keys[i].copy()
+                if sink is not None:
+                    ev = dict(n=1)
+                    ph = self._tick_phase_fields()
+                    if ph is not None:
+                        ev["phase"] = ph
+                    sink(slot.request_id, "tick", **ev)
+                done = self._stop_hit(slot) or \
+                    len(slot.tokens) >= slot.max_new or \
+                    (slot.eos is not None and tok == slot.eos)
+                if done:
+                    # the final token's K/V was never written - fine, it is
+                    # never attended to
+                    self._finish(i)
         return True
 
     def _decode_fused(self, active, scan: bool = False):
@@ -3123,24 +3276,21 @@ class PagedEngine:
         readback, and the decode-step histogram then records the whole
         dispatch wall (divide by ticks_per_dispatch for per-token)."""
         K = self._ticks_per_dispatch if scan else 1
-        self._sync_dev()
-        t_decode = time.perf_counter()
-        self.dispatch_count += 1
-        self._count("dispatches")
-        greedy = np.all(self.temps[active] <= 0.0)
-        if scan:
-            fn = self._scan_greedy_jit if greedy else self._scan_jit
-        else:
-            fn = self._tick_greedy_jit if greedy else self._tick_jit
-        prof = self._prof
-        if prof is not None:
-            tp = prof.clock()
-        nxt, lps, done, self.seen, self.pools, self._dev = fn(
-            self.params, self.pools, self.seen, self._dev)
-        if prof is not None:
+        with self._phase("stage") as br:
+            self._sync_dev()
+            t_decode = time.perf_counter()
+            self.dispatch_count += 1
+            self._count("dispatches")
+            greedy = np.all(self.temps[active] <= 0.0)
+            if scan:
+                fn = self._scan_greedy_jit if greedy else self._scan_jit
+            else:
+                fn = self._tick_greedy_jit if greedy else self._tick_jit
             # dispatch = the program CALL (enqueue; async under ring
             # mode) — compute lands in the drain boundary's device wait
-            prof.add("dispatch", (prof.clock() - tp) * 1e3)
+            br.switch("dispatch")
+            nxt, lps, done, self.seen, self.pools, self._dev = fn(
+                self.params, self.pools, self.seen, self._dev)
         if not greedy:
             self._dev_keys_dirty = True
         if self._ring:
@@ -3155,39 +3305,37 @@ class PagedEngine:
             self._count("slot_steps", self.R * K)
             return True
         self.d2h_syncs += 1
-        if prof is not None:
-            tp = prof.clock()
-            try:
-                jax.block_until_ready((nxt, lps, done))
-            except Exception:
-                pass
-            tr = prof.clock()
-            prof.add("device", (tr - tp) * 1e3)
-        nxt, lps, done = jax.device_get((nxt, lps, done))
-        if prof is not None:
-            prof.add("drain", (prof.clock() - tr) * 1e3)
-        if not scan:                     # [R] -> [1, R]: one tick loop
-            nxt, lps, done = nxt[None], lps[None], done[None]
-        self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
-        self._count("decode_steps", K)
-        self._count("slot_steps", self.R * K)
-        sink = self.trace_sink
-        for i in active:
-            slot = self.slots[i]
-            # scan ticks past a row's done flag are garbage the
-            # consume cut never reads (the device active mask froze
-            # them)
-            appended, finished = self._consume_row(
-                i, ((nxt[k, i], lps[k, i], bool(done[k, i]))
-                    for k in range(K)))
-            if sink is not None:
-                ev = dict(n=appended)
-                ph = self._tick_phase_fields()
-                if ph is not None:
-                    ev["phase"] = ph
-                sink(slot.request_id, "tick", **ev)
-            if finished:
-                self._finish(i)
+        with self._phase("device") as br:
+            if br.on:
+                try:
+                    jax.block_until_ready((nxt, lps, done))
+                except Exception:
+                    pass
+                br.switch("drain")
+            nxt, lps, done = jax.device_get((nxt, lps, done))
+            br.switch("commit")
+            if not scan:                     # [R] -> [1, R]: one tick loop
+                nxt, lps, done = nxt[None], lps[None], done[None]
+            self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
+            self._count("decode_steps", K)
+            self._count("slot_steps", self.R * K)
+            sink = self.trace_sink
+            for i in active:
+                slot = self.slots[i]
+                # scan ticks past a row's done flag are garbage the
+                # consume cut never reads (the device active mask froze
+                # them)
+                appended, finished = self._consume_row(
+                    i, ((nxt[k, i], lps[k, i], bool(done[k, i]))
+                        for k in range(K)))
+                if sink is not None:
+                    ev = dict(n=appended)
+                    ph = self._tick_phase_fields()
+                    if ph is not None:
+                        ev["phase"] = ph
+                    sink(slot.request_id, "tick", **ev)
+                if finished:
+                    self._finish(i)
         return True
 
     def _spec_headroom(self, active):
@@ -3223,19 +3371,16 @@ class PagedEngine:
         slot's release), and honoring the device done flag. Mirrors
         re-upload only on slot transitions, exactly like the plain
         fused tick."""
-        self._sync_dev()
-        t_decode = time.perf_counter()
-        self.dispatch_count += 1
-        self._count("dispatches")
-        greedy = np.all(self.temps[active] <= 0.0)
-        fn = self._tick_spec_greedy_jit if greedy else self._tick_spec_jit
-        prof = self._prof
-        if prof is not None:
-            tp = prof.clock()
-        (nxt, lps, nacc, kprop, macc, done, self.seen, self.pools,
-         self._dev) = fn(self.params, self.pools, self.seen, self._dev)
-        if prof is not None:
-            prof.add("dispatch", (prof.clock() - tp) * 1e3)
+        with self._phase("stage") as br:
+            self._sync_dev()
+            t_decode = time.perf_counter()
+            self.dispatch_count += 1
+            self._count("dispatches")
+            greedy = np.all(self.temps[active] <= 0.0)
+            fn = self._tick_spec_greedy_jit if greedy else self._tick_spec_jit
+            br.switch("dispatch")
+            (nxt, lps, nacc, kprop, macc, done, self.seen, self.pools,
+             self._dev) = fn(self.params, self.pools, self.seen, self._dev)
         if not greedy:
             self._dev_keys_dirty = True
         if self._ring:
@@ -3248,51 +3393,49 @@ class PagedEngine:
             self._count("slot_steps", self.R)
             return True
         self.d2h_syncs += 1
-        if prof is not None:
-            tp = prof.clock()
-            try:
-                jax.block_until_ready((nxt, lps, nacc, kprop, macc,
-                                       done))
-            except Exception:
-                pass
-            tr = prof.clock()
-            prof.add("device", (tr - tp) * 1e3)
-        nxt, lps, nacc, kprop, macc, done = jax.device_get(
-            (nxt, lps, nacc, kprop, macc, done))
-        if prof is not None:
-            prof.add("drain", (prof.clock() - tr) * 1e3)
-        self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
-        self._count("decode_steps")
-        self._count("slot_steps", self.R)
-        prop = int(kprop[active].sum())
-        if prop:
-            self._count("spec_proposed", prop)
-            acc = int(macc[active].sum())
-            if acc:
-                self._count("spec_accepted", acc)
-        sink = self.trace_sink
-        for i in active:
-            slot = self.slots[i]
-            n = int(nacc[i])
-            self._h_tpf.observe(n)
-            if kprop[i]:
-                # host mirror of the device EMA (same update; the
-                # authority switch happens at the next refresh upload)
-                slot.spec_ema = ((1.0 - _SPEC_EMA_ALPHA) * slot.spec_ema
-                                 + _SPEC_EMA_ALPHA
-                                 * (float(macc[i]) / float(kprop[i])))
-            appended, finished = self._consume_row(
-                i, ((nxt[i, j], lps[i, j], False) for j in range(n)))
-            if sink is not None:
-                ev = dict(n=appended, proposed=int(kprop[i]),
-                          accepted=int(macc[i]))
-                ph = self._tick_phase_fields()
-                if ph is not None:
-                    ev["phase"] = ph
-                sink(slot.request_id, "tick", **ev)
-            if finished or bool(done[i]):
-                # host stop, or the device finish flag (eos/budget)
-                self._finish(i)
+        with self._phase("device") as br:
+            if br.on:
+                try:
+                    jax.block_until_ready((nxt, lps, nacc, kprop, macc,
+                                           done))
+                except Exception:
+                    pass
+                br.switch("drain")
+            nxt, lps, nacc, kprop, macc, done = jax.device_get(
+                (nxt, lps, nacc, kprop, macc, done))
+            br.switch("commit")
+            self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
+            self._count("decode_steps")
+            self._count("slot_steps", self.R)
+            prop = int(kprop[active].sum())
+            if prop:
+                self._count("spec_proposed", prop)
+                acc = int(macc[active].sum())
+                if acc:
+                    self._count("spec_accepted", acc)
+            sink = self.trace_sink
+            for i in active:
+                slot = self.slots[i]
+                n = int(nacc[i])
+                self._h_tpf.observe(n)
+                if kprop[i]:
+                    # host mirror of the device EMA (same update; the
+                    # authority switch happens at the next refresh upload)
+                    slot.spec_ema = ((1.0 - _SPEC_EMA_ALPHA) * slot.spec_ema
+                                     + _SPEC_EMA_ALPHA
+                                     * (float(macc[i]) / float(kprop[i])))
+                appended, finished = self._consume_row(
+                    i, ((nxt[i, j], lps[i, j], False) for j in range(n)))
+                if sink is not None:
+                    ev = dict(n=appended, proposed=int(kprop[i]),
+                              accepted=int(macc[i]))
+                    ph = self._tick_phase_fields()
+                    if ph is not None:
+                        ev["phase"] = ph
+                    sink(slot.request_id, "tick", **ev)
+                if finished or bool(done[i]):
+                    # host stop, or the device finish flag (eos/budget)
+                    self._finish(i)
         return True
 
     def _scan_ticks(self, active) -> bool:

@@ -99,21 +99,22 @@ def sample_token_rows(logits, keys, temperature, top_k, top_p):
     Returns (tokens [R] i32, logprobs [R] f32, new_keys [R, 2]).
     Logprobs are of the CHOSEN token under the unfiltered softmax (what
     serving APIs report), greedy rows included."""
-    raw = logits.astype(jnp.float32)
-    temperature = jnp.asarray(temperature, jnp.float32)
-    lt = filter_logits_rows(raw, temperature, top_k, top_p)
+    with jax.named_scope("sample"):     # obs.TICK_SCOPES
+        raw = logits.astype(jnp.float32)
+        temperature = jnp.asarray(temperature, jnp.float32)
+        lt = filter_logits_rows(raw, temperature, top_k, top_p)
 
-    keys = jnp.asarray(keys, jnp.uint32)
-    pairs = jax.vmap(lambda k: jax.random.split(
-        jax.random.wrap_key_data(k, impl="threefry2x32")))(keys)
-    carry = jax.vmap(jax.random.key_data)(pairs[:, 0])
-    sampled = jax.vmap(
-        lambda k, l: jax.random.categorical(k, l))(pairs[:, 1], lt)
-    tokens = jnp.where(temperature <= 0.0,
-                       jnp.argmax(raw, axis=-1), sampled).astype(jnp.int32)
-    logprobs = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
-                                   tokens[:, None].astype(jnp.int32),
-                                   axis=-1)[:, 0]
+        keys = jnp.asarray(keys, jnp.uint32)
+        pairs = jax.vmap(lambda k: jax.random.split(
+            jax.random.wrap_key_data(k, impl="threefry2x32")))(keys)
+        carry = jax.vmap(jax.random.key_data)(pairs[:, 0])
+        sampled = jax.vmap(
+            lambda k, l: jax.random.categorical(k, l))(pairs[:, 1], lt)
+        tokens = jnp.where(temperature <= 0.0, jnp.argmax(raw, axis=-1),
+                           sampled).astype(jnp.int32)
+        logprobs = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
+                                       tokens[:, None].astype(jnp.int32),
+                                       axis=-1)[:, 0]
     return tokens, logprobs, carry
 
 
@@ -269,6 +270,7 @@ def repetition_penalty_rows(logits, seen, penalties):
     penalties [R] (1.0 = off). Rows at 1.0 pass through BIT-exactly
     (jnp.where with a false mask), preserving the engine's greedy
     exactness guarantee."""
-    p = jnp.asarray(penalties, jnp.float32)[:, None]
-    pen = jnp.where(logits > 0, logits / p, logits * p)
-    return jnp.where(seen & (p != 1.0), pen, logits)
+    with jax.named_scope("penalty"):    # obs.TICK_SCOPES
+        p = jnp.asarray(penalties, jnp.float32)[:, None]
+        pen = jnp.where(logits > 0, logits / p, logits * p)
+        return jnp.where(seen & (p != 1.0), pen, logits)

@@ -264,30 +264,36 @@ class LlamaAttention(Layer):
         b, s, _ = x.shape
         nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
-        if hasattr(self, "qkv_proj"):
-            # serving fusion (nn.fuse.fuse_projections): ONE matmul. The
-            # fused columns are rank-interleaved [q_t|k_t|v_t per tp rank
-            # t] so this split is shard-local under a tp mesh: expose the
-            # T axis, slice heads inside each rank's chunk, merge back
-            # (T == 1 degenerates to the plain [q|k|v] split).
-            qkv = self.qkv_proj(x)
-            T = getattr(self, "_fused_tp", 1)
-            qkv = qkv.reshape(b, s, T, (nh + 2 * kvh) // T, d)
-            q = qkv[:, :, :, :nh // T].reshape(b, s, nh, d)
-            k = qkv[:, :, :, nh // T:(nh + kvh) // T].reshape(b, s, kvh, d)
-            v = qkv[:, :, :, (nh + kvh) // T:].reshape(b, s, kvh, d)
-        else:
-            q = self.q_proj(x).reshape(b, s, nh, d)
-            k = self.k_proj(x).reshape(b, s, kvh, d)
-            v = self.v_proj(x).reshape(b, s, kvh, d)
-        cos, sin = rotary_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                                  q.dtype, inv_freq=self._inv_freq,
-                                  attention_scaling=self._attn_scaling)
-        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        # heads sharded over tp
-        q = constraint(q, None, None, "tp", None)
-        k = constraint(k, None, None, "tp", None)
-        v = constraint(v, None, None, "tp", None)
+        # the named scopes here and below are obs.TICK_SCOPES: what a
+        # device trace calls the parts of a serving tick
+        with jax.named_scope("qkv"):
+            if hasattr(self, "qkv_proj"):
+                # serving fusion (nn.fuse.fuse_projections): ONE matmul.
+                # The fused columns are rank-interleaved [q_t|k_t|v_t per
+                # tp rank t] so this split is shard-local under a tp
+                # mesh: expose the T axis, slice heads inside each rank's
+                # chunk, merge back (T == 1 degenerates to the plain
+                # [q|k|v] split).
+                qkv = self.qkv_proj(x)
+                T = getattr(self, "_fused_tp", 1)
+                qkv = qkv.reshape(b, s, T, (nh + 2 * kvh) // T, d)
+                q = qkv[:, :, :, :nh // T].reshape(b, s, nh, d)
+                k = qkv[:, :, :, nh // T:(nh + kvh) // T] \
+                    .reshape(b, s, kvh, d)
+                v = qkv[:, :, :, (nh + kvh) // T:].reshape(b, s, kvh, d)
+            else:
+                q = self.q_proj(x).reshape(b, s, nh, d)
+                k = self.k_proj(x).reshape(b, s, kvh, d)
+                v = self.v_proj(x).reshape(b, s, kvh, d)
+            cos, sin = rotary_cos_sin(positions, cfg.head_dim,
+                                      cfg.rope_theta, q.dtype,
+                                      inv_freq=self._inv_freq,
+                                      attention_scaling=self._attn_scaling)
+            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+            # heads sharded over tp
+            q = constraint(q, None, None, "tp", None)
+            k = constraint(k, None, None, "tp", None)
+            v = constraint(v, None, None, "tp", None)
 
         new_cache = None
         if kv_cache is not None:
@@ -411,8 +417,9 @@ class LlamaAttention(Layer):
         else:
             out = dense_attention(q, k, v, causal=attn_mask is None,
                                   attn_mask=attn_mask)
-        out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
-        out = self.o_proj(out)
+        with jax.named_scope("o_proj"):
+            out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
+            out = self.o_proj(out)
         return (out, new_cache) if kv_cache is not None else out
 
 
@@ -457,7 +464,9 @@ class LlamaDecoderLayer(Layer):
     def forward(self, x, positions, kv_cache=None, cache_index=None,
                 attn_mask=None, attn_start=None, segment_ids=None,
                 paged_chunk: bool = False, paged_decode: bool = False):
-        attn_out = self.self_attn(self.input_layernorm(x), positions,
+        with jax.named_scope("norm"):
+            h = self.input_layernorm(x)
+        attn_out = self.self_attn(h, positions,
                                   kv_cache=kv_cache, cache_index=cache_index,
                                   attn_mask=attn_mask, attn_start=attn_start,
                                   segment_ids=segment_ids,
@@ -466,8 +475,13 @@ class LlamaDecoderLayer(Layer):
         new_cache = None
         if kv_cache is not None:
             attn_out, new_cache = attn_out
-        x = x + attn_out
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        # each residual add under the scope of the block it closes
+        with jax.named_scope("o_proj"):
+            x = x + attn_out
+        with jax.named_scope("norm"):
+            h = self.post_attention_layernorm(x)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(h)
         x = constraint(x, ("dp", "fsdp"), "sp", None)
         return (x, new_cache) if kv_cache is not None else x
 
@@ -503,7 +517,8 @@ class LlamaModel(Layer):
                 # left-padded rows: RoPE position 0 sits at each row's
                 # first REAL token, not at the pad prefix
                 positions = jnp.maximum(positions - attn_start[:, None], 0)
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            x = self.embed_tokens(input_ids)
         x = constraint(x, ("dp", "fsdp"), "sp", None)
         new_caches = [] if kv_caches is not None else None
         for i, layer in enumerate(self.layers):
@@ -525,7 +540,8 @@ class LlamaModel(Layer):
                 new_caches.append(nc)
             else:
                 x = out
-        x = self.norm(x)
+        with jax.named_scope("head"):
+            x = self.norm(x)
         return (x, new_caches) if kv_caches is not None else x
 
 
@@ -562,12 +578,14 @@ class LlamaForCausalLM(CausalLMBase):
         caches = None
         if kv_caches is not None:
             out, caches = out
-        if self.config.tie_word_embeddings:
-            logits = parallel_matmul(out, self.model.embed_tokens.weight,
-                                     transpose_y=True)
-        else:
-            logits = self.lm_head(out)
-        logits = logits.astype(jnp.float32)  # CE in fp32 for stability
+        with jax.named_scope("head"):
+            if self.config.tie_word_embeddings:
+                logits = parallel_matmul(out,
+                                         self.model.embed_tokens.weight,
+                                         transpose_y=True)
+            else:
+                logits = self.lm_head(out)
+            logits = logits.astype(jnp.float32)  # CE in fp32 for stability
         return (logits, caches) if kv_caches is not None else logits
 
 

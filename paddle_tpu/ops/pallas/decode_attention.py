@@ -189,6 +189,7 @@ def decode_attention_pallas(q, k_cache, v_cache, cache_index, scale,
                                nt=nt, window=window)
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, nc, nt),

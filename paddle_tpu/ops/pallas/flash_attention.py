@@ -182,6 +182,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(bh, q_blocks, kv_blocks),
         in_specs=in_specs,
         out_specs=[
@@ -377,6 +378,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                           block_q=block_q, block_k=block_k,
                           kv_blocks=kv_blocks, causal_offset=offset,
                           has_seg=has_seg, window=window),
+        name="flash_attention_dq",
         grid=(bh, q_blocks, kv_blocks),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
@@ -391,6 +393,7 @@ def _flash_bwd(q, k, v, out, lse, g, scale, causal, block_q, block_k,
                           block_q=block_q, block_k=block_k, group=group,
                           q_blocks=q_blocks, causal_offset=offset,
                           has_seg=has_seg, window=window),
+        name="flash_attention_dkv",
         grid=(bh_kv, kv_blocks, group, q_blocks),
         in_specs=dkv_in_specs,
         out_specs=[

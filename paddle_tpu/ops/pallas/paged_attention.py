@@ -119,10 +119,12 @@ def paged_attention_pallas(q, kp, vp, block_tables, seq_lens, scale,
 
     kernel = functools.partial(_paged_kernel, scale=scale, bs=B, nm=M,
                                gp=gp, window=window)
-    kc = kp.reshape(P, B, kvh * d)
-    vc = vp.reshape(P, B, kvh * d)
+    with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
+        kc = kp.reshape(P, B, kvh * d)
+        vc = vp.reshape(P, B, kvh * d)
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R, kvh, M),

@@ -99,6 +99,7 @@ def quant_matmul_pallas(x, qweight, scales, bits: int = 8,
     kernel = functools.partial(_qmm_kernel, bits=bits, bk=bk, bn=bn, nin=nin)
     out = pl.pallas_call(
         kernel,
+        name=f"quant_matmul_int{bits}",
         grid=(nout, nin),
         in_specs=[
             pl.BlockSpec((mp, bk), lambda no, ni: (0, ni)),

@@ -211,10 +211,12 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
 
     kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, S=S,
                                window=window, group=group)
-    kc = kp.reshape(P, B, kvh * d)
-    vc = vp.reshape(P, B, kvh * d)
+    with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
+        kc = kp.reshape(P, B, kvh * d)
+        vc = vp.reshape(P, B, kvh * d)
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(kvh, S),

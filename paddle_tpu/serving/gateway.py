@@ -296,7 +296,15 @@ class _ReplicaWorker(threading.Thread):
 
     # ------------------------------------------------------------ tick loop
     def run(self):
+        """The tick loop. Every stretch of it lies in a named phase of
+        the engine's tick profiler (``obs.LOOP_PHASES`` through
+        ``engine.loop_phase``: ``sched``, ``lock``, ``emit``, ``idle``;
+        ``engine.step()`` accounts for itself), because with one
+        dispatch in flight at a time all of it is on the device's
+        critical path. With the profiler off a phase is a shared
+        no-op."""
         eng = self.engine
+        phase = eng.loop_phase
         rname = self.replica.name
         while True:
             if self.abandoned:
@@ -311,24 +319,25 @@ class _ReplicaWorker(threading.Thread):
                                                       replica=rname):
                 return        # hard exit, NO cleanup: the supervisor
                               # finds the corpse and fails over
-            while self._ops:
-                op = self._ops.popleft()
-                try:
-                    op()
-                except Exception as e:   # a bad op must not kill serving
-                    obs.record_event("gateway_op_error",
-                                     gateway=self.gw.name, err=repr(e))
-            now = time.monotonic()
-            for req in self.sched.reap(now):
-                # satellite: expired in QUEUE — cancelled before it
-                # ever took a slot; the scheduler already counted it
-                _release_probe(req, self.replica)
-                self._emit(req, ("done", {"tokens": [],
-                                          "finish_reason": "timeout"}))
-                self._trace_finish(req, "expired")
-            while (req := self._pop_admissible()) is not None:
-                self._admit(req, time.monotonic())
-            self._set_capacity_gauges()
+            with phase("sched"):
+                while self._ops:
+                    op = self._ops.popleft()
+                    try:
+                        op()
+                    except Exception as e:  # a bad op must not kill serving
+                        obs.record_event("gateway_op_error",
+                                         gateway=self.gw.name, err=repr(e))
+                now = time.monotonic()
+                for req in self.sched.reap(now):
+                    # satellite: expired in QUEUE — cancelled before it
+                    # ever took a slot; the scheduler already counted it
+                    _release_probe(req, self.replica)
+                    self._emit(req, ("done", {"tokens": [],
+                                              "finish_reason": "timeout"}))
+                    self._trace_finish(req, "expired")
+                while (req := self._pop_admissible()) is not None:
+                    self._admit(req, time.monotonic())
+                self._set_capacity_gauges()
             if eng.queue or any(s is not None for s in eng.slots):
                 chaos, self._chaos = self._chaos, None
                 try:
@@ -348,7 +357,9 @@ class _ReplicaWorker(threading.Thread):
                         # failed over, the engine was rebuilt for a
                         # replacement worker — touch NOTHING
                         return
-                    with self._tick_lock:
+                    with phase("lock"):
+                        self._tick_lock.acquire()
+                    try:
                         # the dispatch-to-drain watchdog window opens
                         # INSIDE the lock: waiting for a shared-model
                         # sibling's tick is not THIS replica's hang,
@@ -360,10 +371,12 @@ class _ReplicaWorker(threading.Thread):
                         # as the chaos loadgen does)
                         self.t_busy = time.monotonic()
                         eng.step()
+                    finally:
+                        self._tick_lock.release()
                 except Exception as e:
                     self._fail_all(e)
                     return
-                with self._io_lock:
+                with phase("emit"), self._io_lock:
                     if self.abandoned:
                         # a slow-but-not-hung step outlived the
                         # watchdog: the failover path owns every live
@@ -377,13 +390,15 @@ class _ReplicaWorker(threading.Thread):
                 self.warmed = True
                 # post-tick refresh: a scrape between ticks sees the
                 # capacity the step just freed, not last tick's view
-                self._set_capacity_gauges()
+                with phase("sched"):
+                    self._set_capacity_gauges()
             else:
                 if self.draining and self.sched.depth() == 0 \
                         and not self._live:
                     return
-                self._wake.wait(0.005)
-                self._wake.clear()
+                with phase("idle"):
+                    self._wake.wait(0.005)
+                    self._wake.clear()
 
     def _pop_admissible(self) -> Optional[ServeRequest]:
         """Hand the engine up to FREE-SLOT-many requests per tick (its
@@ -1354,15 +1369,14 @@ class Gateway:
         os.makedirs(directory, exist_ok=True)
         out = []
         for w in self._workers:
-            dump = getattr(w.engine, "dump_tick_profile", None)
-            if dump is None or getattr(w.engine, "_prof", None) is None:
-                continue
             try:
-                out.append(dump(os.path.join(
+                path = w.engine.dump_tick_profile(os.path.join(
                     directory,
-                    f"tickphase_{self.name}_{w.replica.name}.json")))
+                    f"tickphase_{self.name}_{w.replica.name}.json"))
             except Exception:
-                pass     # a failed dump only costs the phase artifact
+                continue     # a failed dump only costs the phase artifact
+            if path is not None:        # None: the profiler is off
+                out.append(path)
         return out
 
     def prefix_digest_summary(self) -> Dict[str, Any]:
@@ -1488,7 +1502,7 @@ class Gateway:
             tp = rep["engine"].get("tick_profile") \
                 if isinstance(rep["engine"], dict) else None
             rep["tick_profile"] = tp if tp is not None else {
-                "enabled": getattr(w.engine, "_prof", None) is not None}
+                "enabled": w.engine.tick_profile}
             reps[w.replica.name] = rep
         sup = None
         if self._supervisor is not None:
@@ -1717,12 +1731,8 @@ class Gateway:
                 if run_dir else None
             prof = Profiler(logdir=jax_dir or "",
                             timer_only=jax_dir is None)
-            before = {}
-            for w in self._workers:
-                p = getattr(w.engine, "_prof", None)
-                if p is not None:
-                    before[w.replica.name] = (
-                        p.ticks, dict(p.totals), p.wall_total_ms)
+            before = {w.replica.name: w.engine.tick_profile_summary()
+                      for w in self._workers}
             traced = False
             try:
                 prof.start()
@@ -1740,20 +1750,24 @@ class Gateway:
                         traced = False
             reps: Dict[str, Any] = {}
             for w in self._workers:
-                p = getattr(w.engine, "_prof", None)
-                if p is None:
+                b = w.engine.tick_profile_summary()
+                a = before.get(w.replica.name)
+                if b is None or a is None:
                     reps[w.replica.name] = {"enabled": False}
                     continue
-                t0, tot0, w0 = before.get(
-                    w.replica.name, (0, {}, 0.0))
                 reps[w.replica.name] = {
                     "enabled": True,
-                    "ticks_in_window": p.ticks - t0,
+                    "ticks_in_window": b["ticks"] - a["ticks"],
                     "wall_ms_in_window": round(
-                        p.wall_total_ms - w0, 3),
+                        b["wall_total_ms"] - a["wall_total_ms"], 3),
+                    "thread_wall_ms_in_window": round(
+                        b["thread_wall_ms"] - a["thread_wall_ms"], 3),
                     "phase_ms_in_window": {
-                        k: round(v - tot0.get(k, 0.0), 3)
-                        for k, v in p.totals.items()},
+                        k: round(v - a["phase_totals_ms"][k], 3)
+                        for k, v in b["phase_totals_ms"].items()},
+                    "loop_ms_in_window": {
+                        k: round(v - a["loop_totals_ms"][k], 3)
+                        for k, v in b["loop_totals_ms"].items()},
                 }
             files = self.dump_tick_profiles(run_dir) if run_dir else []
             obs.record_event("profilez_capture", gateway=self.name,

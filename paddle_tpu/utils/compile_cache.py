@@ -21,6 +21,19 @@ calls ``enable()``. ``distributed.elastic.supervise`` hands its
 children the directory through the same standard variable
 (``child_env``), so the supervisor itself never imports jax.
 
+The key of an entry covers the program's op metadata too (named
+scopes, source lines): ``enable()`` turns on
+``jax_compilation_cache_include_metadata_in_key`` unless
+``$JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY`` is set, in which case
+the operator's value stands. jax's default leaves metadata out, and a
+program whose ``jax.named_scope`` names alone changed is then restored
+from disk with its OLD names: a profiler trace read by scope
+(``obs.TICK_SCOPES``, docs/OBSERVABILITY.md section 7) would put the
+device's time under names the tree no longer has. The price is that the
+same program traced from another call site is another entry; the test
+suite, which wants exactly that sharing and reads no names off a
+compiled program, sets the variable to false (tests/conftest.py).
+
 ``entries()`` lists the cache's program keys (the ``*-cache`` payload
 files, not the ``-atime`` access-time markers) so tests and tools can
 assert "the second startup hit the cache" by set equality on keys —
@@ -32,10 +45,11 @@ import os
 import threading
 from typing import Dict, List, Optional
 
-__all__ = ["ENV_VAR", "DEFAULT_DIR", "enable", "active_dir",
+__all__ = ["ENV_VAR", "META_ENV_VAR", "DEFAULT_DIR", "enable", "active_dir",
            "resolve_dir", "entries", "child_env"]
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+META_ENV_VAR = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
@@ -56,7 +70,9 @@ def enable(min_compile_time_s: Optional[float] = None) -> str:
     None keeps jax's own setting (1.0s unless
     ``$JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise — a
     train step or a serving tick is far above it, per-op jits mostly
-    below)."""
+    below). Op metadata goes into the cache key unless
+    ``$JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY`` says otherwise
+    (module docstring)."""
     cache_dir = resolve_dir()
     import jax
     with _lock:
@@ -67,6 +83,9 @@ def enable(min_compile_time_s: Optional[float] = None) -> str:
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",
                 float(min_compile_time_s))
+        if META_ENV_VAR not in os.environ:
+            jax.config.update(
+                "jax_compilation_cache_include_metadata_in_key", True)
         _reset_latched_cache(cache_dir)
     return cache_dir
 

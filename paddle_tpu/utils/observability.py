@@ -66,7 +66,8 @@ __all__ = [
     "SpanTracer", "FlightRecorder",
     "MetricsTimeSeries", "SERIES_SCHEMA",
     "quantile_from_bucket_counts", "validate_series_doc",
-    "TICKPHASE_SCHEMA", "TICK_PHASES", "validate_tickphase_doc",
+    "TICKPHASE_SCHEMA", "TICK_PHASES", "LOOP_PHASES", "TICK_SCOPES",
+    "validate_tickphase_doc",
     "register_flusher", "unregister_flusher",
     "registry", "tracer", "recorder",
     "counter", "gauge", "histogram", "span", "record_event",
@@ -380,7 +381,11 @@ class MetricsRegistry:
 _TRACE_ANNOTATION: Any = None   # cached class; False = jax unavailable
 
 
-def _trace_annotation(name: str):
+def _trace_annotation(name: str, **attrs):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` (``attrs`` become
+    the event's stats in the profiler's trace), not yet entered; None
+    where jax is missing. Outside a profiler trace entering one costs
+    about a microsecond and records nothing."""
     global _TRACE_ANNOTATION
     if _TRACE_ANNOTATION is None:
         try:
@@ -391,7 +396,7 @@ def _trace_annotation(name: str):
     if _TRACE_ANNOTATION is False:
         return None
     try:
-        return _TRACE_ANNOTATION(name)
+        return _TRACE_ANNOTATION(name, **attrs)
     except Exception:
         return None
 
@@ -908,11 +913,56 @@ def validate_series_doc(doc: Any) -> List[str]:
 # ``tools/trace_export.py``. The validator lives HERE — dependency-free
 # — so the tools can check documents without importing jax.
 TICKPHASE_SCHEMA = "tickphase/1"
-# phase order is the tick's own: host staging/patch-pack → H2D upload
-# → dispatch call → device wait (block-until-ready on the drain
-# boundary) → D2H drain. ``host`` is the RESIDUAL (tick wall minus the
-# explicitly bracketed phases), so the five always sum to the wall.
-TICK_PHASES = ("host", "h2d", "dispatch", "device", "drain")
+# One vocabulary for both sides of a tick (docs/OBSERVABILITY.md
+# section 7). TICK_PHASES: where the tick thread's time goes INSIDE
+# ``PagedEngine.step()``, in the tick's own order. Every phase but
+# ``host`` is bracketed; ``host`` is the RESIDUAL (tick wall minus the
+# brackets), so the phases always sum to the wall. A bracket opened
+# inside another (an upload inside ``stage``) takes its time out of
+# the outer one. While a bracket is open the profiler's trace carries a
+# ``tick/<phase>`` host span, and the whole step one ``tick`` span.
+TICK_PHASES = (
+    "host",       # residual: what no bracket below covers
+    "commit",     # drained tokens -> requests: appends, stops, events
+    "expire",     # deadline sweep
+    "admit",      # queue -> slot, its eager device programs included
+    "chunk",      # a prefill chunk's host work around its program
+    "stage",      # block growth, preemption, descriptor packing
+    "h2d",        # mirror / patch-queue / chunk-input uploads
+    "dispatch",   # a compiled program's CALL (enqueue, not compute)
+    "device",     # blocked until the device finished
+    "drain",      # D2H copy after readiness
+)
+# LOOP_PHASES: what the thread that owns the engine does BETWEEN two
+# steps (``serving/gateway.py:_ReplicaWorker.run``), reported through
+# ``PagedEngine.loop_phase``. They feed the same totals and histograms
+# but no tick record, and with the ticks they cover the thread's wall.
+LOOP_PHASES = (
+    "sched",      # posted ops, queue reap, admission, capacity gauges
+    "lock",       # waiting for the model's tick lock
+    "emit",       # tokens pushed to their client sinks
+    "idle",       # nothing to serve: waiting to be woken
+)
+# TICK_SCOPES: the ``jax.named_scope`` names inside the tick and chunk
+# programs (``_fused_tick*``, ``_chunk_prefill``) — what the device
+# runs inside a tick. An op's scope is the last component of its
+# ``op_name`` that is one of these.
+TICK_SCOPES = (
+    "patch",       # staged slot transitions scattered into the state
+    "embed",
+    "norm",        # both RMSNorms of a layer
+    "qkv",         # projections, biases, rope
+    "kv_write",    # the new rows scattered into the pool
+    "kv_layout",   # the pool viewed [P, B, kvh*d] for the kernel
+    "attn",        # decode: schedule build and kernel
+    "chunk_attn",  # chunk: gather of the row's blocks, masked attention
+    "o_proj",
+    "mlp",
+    "head",        # final norm and lm_head
+    "penalty",     # repetition penalty
+    "sample",      # sample_token_rows / the greedy argmax + log-softmax
+    "epilogue",    # lengths, budgets, done flags, token ring
+)
 
 
 def validate_tickphase_doc(doc: Any) -> List[str]:
@@ -936,6 +986,10 @@ def validate_tickphase_doc(doc: Any) -> List[str]:
     if not isinstance(totals, dict) \
             or set(totals) != set(TICK_PHASES):
         bad.append("phase_totals_ms missing or wrong phase set")
+    loop = doc.get("loop_totals_ms")
+    if loop is not None and (not isinstance(loop, dict)
+                             or set(loop) != set(LOOP_PHASES)):
+        bad.append("loop_totals_ms has the wrong phase set")
     entries = doc.get("entries")
     if not isinstance(entries, list):
         return bad + ["entries is not a list"]

@@ -26,6 +26,10 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                       "/tmp/paddle_tpu_test_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
+# compile_cache.enable() would put op metadata (source lines, named
+# scopes) into the cache key; the sharing above needs it left out, and
+# no test reads a name off a compiled program
+os.environ.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "0")
 
 import jax
 

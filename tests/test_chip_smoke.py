@@ -43,6 +43,11 @@ def test_smoke_at_tiny_size_and_four_engines_on_four_devices(monkeypatch):
     from paddle_tpu.generation.paged import PagedEngine
     from paddle_tpu.models.qwen2 import Qwen2ForCausalLM
     from paddle_tpu.serving import Gateway
+    from paddle_tpu.utils import observability as obs
+    # the smoke reads process-wide totals ("failovers are 0") as a fresh
+    # process has them; whichever test files ran before in this worker
+    # (the order follows the set of files) left theirs in the registry
+    obs.reset()
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     cfg = qwen2_tiny(num_hidden_layers=1)
     devices = jax.devices()[:4]

@@ -436,6 +436,42 @@ class TestCompileCache:
         assert set(compile_cache.entries(_isolated_cache)) == populated
         assert hits                           # executables restored, not rebuilt
 
+    @pytest.mark.parametrize("env,added", [(None, 2), ("0", 1)])
+    def test_a_scope_only_edit_is_its_own_entry(self, monkeypatch,
+                                                _isolated_cache, env, added):
+        """enable() puts op metadata into the cache key, so a program
+        whose named scopes alone changed is compiled again and a trace
+        read by scope never shows the old names; the operator's
+        variable (the suite sets it to 0) keeps jax's default, under
+        which both spellings are one entry."""
+        flag = "jax_compilation_cache_include_metadata_in_key"
+        prev = getattr(jax.config, flag)
+        suite = os.environ[compile_cache.META_ENV_VAR]     # conftest's
+        if env is None:
+            monkeypatch.delenv(compile_cache.META_ENV_VAR, raising=False)
+        else:
+            monkeypatch.setenv(compile_cache.META_ENV_VAR, env)
+
+        def scoped(name):
+            def f(x):
+                with jax.named_scope(name):
+                    return jnp.tanh(x) @ x
+            return jax.jit(f)
+
+        x = jnp.ones((16, 16))
+        try:
+            compile_cache.enable(min_compile_time_s=0.0)
+            assert getattr(jax.config, flag) is (env is None)
+            before = len(compile_cache.entries(_isolated_cache))
+            for name in ("old", "new"):
+                scoped(name)(x).block_until_ready()
+            n = len(compile_cache.entries(_isolated_cache)) - before
+        finally:
+            # before the fixture's own enable() runs at teardown
+            monkeypatch.setenv(compile_cache.META_ENV_VAR, suite)
+            jax.config.update(flag, prev)
+        assert n == added
+
     def test_entries_and_child_env(self, monkeypatch, tmp_path):
         """(The resolver's own order — the standard variable over every
         argument, else the fixed in-checkout path — is pinned in

@@ -2,11 +2,19 @@
 
 Contracts pinned here:
 
-- PHASE SUM == WALL: under an injected clock the five phases (host,
-  h2d, dispatch, device, drain) sum EXACTLY to the measured tick wall
-  — host is the residual of the bracketed phases, so there is no
-  unexplained remainder for ``phase_breakdown``/``phase_decompose``
-  to mis-attribute.
+- PHASE SUM == WALL: under an injected clock the in-tick phases
+  (``obs.TICK_PHASES``) sum EXACTLY to the measured tick wall — host
+  is the residual of the bracketed phases, so there is no unexplained
+  remainder for ``phase_breakdown``/``phase_decompose`` to
+  mis-attribute. Brackets nest: an upload inside ``stage`` or a
+  program call inside ``chunk`` takes its time out of the outer one.
+- A CHUNK IS NOT HOST TIME (ISSUE 24): the prefill chunk's program
+  call lands in ``dispatch`` and the blocking read of its first token
+  in ``device``; before, both fell into the ``host`` residual.
+- LOOP PHASES (ISSUE 24): what the worker thread does between steps
+  (``engine.loop_phase``: ``obs.LOOP_PHASES``) feeds the totals, the
+  histograms and the thread's wall, and no tick record; the serving
+  gateway's worker reports all four.
 - BITWISE OFF==ON: profile-on greedy+sampled streams are bit-identical
   to profile-off across engine modes (default fused ring, sync
   readback, unfused tick, multi-tick dispatch) — the profiler reads
@@ -93,12 +101,15 @@ def test_phase_sum_equals_wall_injected_clock():
     _drain(eng)
     prof = eng._prof
     assert prof.ticks > 0
-    # the residual construction: five phases sum to the wall EXACTLY
-    assert sum(prof.totals.values()) == pytest.approx(
-        prof.wall_total_ms, rel=1e-9)
-    # every bracketed phase actually ran under the fake clock
-    for p in ("h2d", "dispatch", "device", "drain"):
-        assert prof.totals[p] > 0.0, p
+    # the residual construction: the in-tick phases sum to the wall
+    # EXACTLY, nested brackets included
+    assert sum(prof.totals[p] for p in obs.TICK_PHASES) \
+        == pytest.approx(prof.wall_total_ms, rel=1e-9)
+    # every bracketed phase this engine reaches ran under the fake
+    # clock (it prefills whole prompts: no chunk)
+    for p in obs.TICK_PHASES:
+        assert (prof.totals[p] > 0.0) == (p != "chunk"), p
+    assert all(prof.totals[p] == 0.0 for p in obs.LOOP_PHASES)
     # per-entry exactness too, and the engine-facing aggregates agree
     doc = eng.tick_profile_doc()
     assert obs.validate_tickphase_doc(doc) == []
@@ -107,6 +118,121 @@ def test_phase_sum_equals_wall_injected_clock():
             == pytest.approx(rec["wall_ms"], rel=1e-9)
     assert eng.tick_phase_totals == prof.totals
     assert eng.tick_wall_ms_total == prof.wall_total_ms
+    assert doc["phase_totals_ms"] == pytest.approx(
+        {p: prof.totals[p] for p in obs.TICK_PHASES}, abs=1e-3)
+    # the thread's wall covers the ticks and the gaps between them
+    assert doc["thread_wall_ms"] >= doc["wall_total_ms"]
+
+
+class SlowCalls(FakeClock):
+    """A FakeClock on which the chunk program's call takes 100 ms and
+    every wait for the device 50 ms."""
+
+    def install(self, eng, monkeypatch):
+        from paddle_tpu.generation import paged
+        chunk_jit, ready = eng._chunk_jit, paged.jax.block_until_ready
+
+        def slow_chunk(*a, **kw):
+            self.t += 0.100
+            return chunk_jit(*a, **kw)
+
+        def slow_ready(x):
+            self.t += 0.050
+            return ready(x)
+        eng._chunk_jit = slow_chunk
+        monkeypatch.setattr(paged.jax, "block_until_ready", slow_ready)
+
+
+def test_chunk_call_and_read_are_not_host_time(monkeypatch):
+    clock = SlowCalls()
+    eng = _engine(tick_profile=True, profile_clock=clock,
+                  chunk_prefill_tokens=8)
+    clock.install(eng, monkeypatch)
+    eng.submit("c", _cyc(12), max_new_tokens=3)     # two chunks
+    eng.step()          # admit + first chunk: a call, no read
+    eng.step()          # last chunk: a call and the first token's read
+    first, last = list(eng._prof.ring)
+    assert first["dispatch_ms"] == pytest.approx(101.0)
+    assert first["device_ms"] == 0.0
+    # the last chunk's tick also dispatches the first decode tick
+    assert last["dispatch_ms"] == pytest.approx(102.0)
+    assert last["device_ms"] == pytest.approx(51.0)
+    for rec in (first, last):
+        assert rec["chunk_ms"] > 0.0 and rec["h2d_ms"] > 0.0
+        assert rec["host_ms"] < 10.0        # the slow calls are not here
+        assert sum(rec[f"{p}_ms"] for p in obs.TICK_PHASES) \
+            == pytest.approx(rec["wall_ms"], rel=1e-9)
+    assert eng.stats["decode_ticks"] == 1
+    assert eng.stats["prefill_chunks"] == 2
+    eng.run()
+    h = obs.registry().snapshot()
+    key = f"paged_prefill_chunk_ms{{engine=\"{eng._obs_labels['engine']}\"}}"
+    assert h[key]["count"] == 2
+
+
+def test_loop_phases_feed_totals_and_no_tick_record():
+    eng = _engine(tick_profile=True, profile_clock=FakeClock())
+    with eng.loop_phase("sched"):
+        eng.submit("a", _cyc(6), max_new_tokens=4)
+    eng.step()
+    with eng.loop_phase("emit"):
+        pass
+    with eng.loop_phase("idle"):
+        with pytest.raises(KeyError):       # not a phase: the bracket
+            eng.loop_phase("nap")           # around it still closes
+    summ = eng.tick_profile_summary()
+    assert summ["ticks"] == 1 and len(eng._prof.ring) == 1
+    assert summ["loop_totals_ms"] == {"sched": 1.0, "lock": 0.0,
+                                      "emit": 1.0, "idle": 1.0}
+    assert set(eng._prof.ring[0]) >= {f"{p}_ms" for p in obs.TICK_PHASES}
+    assert not any(f"{p}_ms" in eng._prof.ring[0] for p in obs.LOOP_PHASES)
+    # every clock tick from the first bracket to the last is under a
+    # name but the ones BETWEEN brackets: 3 gaps of 1 ms here
+    named = sum(eng.tick_phase_totals.values())
+    assert summ["thread_wall_ms"] - named == pytest.approx(3.0)
+    assert obs.validate_tickphase_doc(eng.tick_profile_doc()) == []
+    # profiler off: one shared no-op, no summary
+    off = _engine()
+    assert off.loop_phase("emit") is off.loop_phase("idle")
+    with off.loop_phase("emit"):
+        pass
+    assert off.tick_profile_summary() is None
+
+
+def test_gateway_worker_reports_every_loop_phase():
+    """The serving gateway's tick thread through real HTTP: all four
+    loop phases show up, and with the ticks they cover the thread."""
+    from paddle_tpu.serving import Gateway
+    from test_gateway import _sse
+    eng = _engine(tick_profile=True, chunk_prefill_tokens=8)
+
+    async def run():
+        gw = Gateway(eng, name="t-loop")
+        await gw.start()
+        await asyncio.sleep(0.05)                   # an idle stretch
+        await _sse(gw.port, {"prompt": list(range(1, 10)),
+                             "max_new_tokens": 6, "temperature": 0.0})
+        await gw.drain()
+    asyncio.run(run())
+    summ = eng.tick_profile_summary()
+    loop = summ["loop_totals_ms"]
+    assert all(loop[p] > 0.0 for p in ("sched", "emit", "idle")), loop
+    assert loop["lock"] >= 0.0
+    named = summ["wall_total_ms"] + sum(loop.values())
+    assert 0.9 * summ["thread_wall_ms"] < named \
+        <= summ["thread_wall_ms"] * (1 + 1e-6)
+
+
+def test_dash_letters_follow_the_vocabulary():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "fleet_dash", os.path.join(os.path.dirname(__file__), "..",
+                                   "tools", "fleet_dash.py"))
+    dash = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(dash)
+    assert tuple(dash.PHASE_LETTERS) == obs.TICK_PHASES + obs.LOOP_PHASES
+    letters = list(dash.PHASE_LETTERS.values())
+    assert len(set(letters)) == len(letters)
 
 
 def test_real_clock_sum_within_validator_tolerance():
@@ -128,7 +254,8 @@ def test_real_clock_sum_within_validator_tolerance():
     {"ring_mode": False},              # sync per-tick readback
     {"fused_tick": False},             # unfused decode path
     {"ticks_per_dispatch": 4},         # multi-tick dispatch
-], ids=["fused-ring", "sync", "unfused", "multi-tick"])
+    {"chunk_prefill_tokens": 8},       # chunked prefill (ISSUE 24)
+], ids=["fused-ring", "sync", "unfused", "multi-tick", "chunked"])
 def test_profile_on_off_bitwise(mode_kw):
     res_off, lp_off = _drain(_engine(**mode_kw))
     res_on, lp_on = _drain(_engine(tick_profile=True, **mode_kw))
@@ -213,7 +340,8 @@ def test_trace_events_carry_phase_and_share():
     with_phase = [f["phase"] for f in ticks if "phase" in f]
     assert with_phase                # at least the post-first ticks
     for ph in with_phase:
-        assert set(ph) == {"wall_ms"} | {
+        # the tick's index is the ``n`` of its span in a profiler trace
+        assert set(ph) == {"tick", "wall_ms"} | {
             f"{p}_ms" for p in obs.TICK_PHASES}
 
     # profiler OFF: tick events stay phase-free (no schema surprise)

@@ -1,0 +1,138 @@
+"""ISSUE 24: what the device runs inside a tick has names.
+
+Contracts pinned here, at tiny widths and without compiling anything
+(the programs are only traced and lowered):
+
+- SCOPES: every op of ``_fused_tick``, ``_fused_tick_greedy`` and
+  ``_chunk_prefill`` that a ``jax.named_scope`` covers carries a name
+  of ``obs.TICK_SCOPES`` in its ``op_name``; each program uses exactly
+  the scopes its stages have, and the three together use the whole
+  vocabulary, so a scope that is renamed or dropped in the program
+  fails here before a device trace loses it.
+- KERNEL NAMES: the Pallas kernel on each serving route (ragged,
+  grid) is a ``pallas_call`` with a ``name``: what a profiler trace
+  calls it.
+- SCOPES CHANGE NOTHING: a program lowered with ``jax.named_scope``
+  patched to a null context HERE has the same histogram of opcodes. The
+  program has no switch for this.
+
+The kernel routes are taken in interpret mode: only the tracing of the
+dispatch glue matters here.
+"""
+import collections
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+from paddle_tpu.utils import observability as obs
+
+CHUNK = 16
+ATTN = {"attn", "kv_layout"}        # the decode side; a chunk has its own
+PROGRAMS = {
+    "_fused_tick": set(obs.TICK_SCOPES) - {"chunk_attn"},
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch"},
+}
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = PagedEngine(LlamaForCausalLM(llama_tiny()), max_slots=4,
+                      num_blocks=32, block_size=8, max_blocks_per_seq=8,
+                      chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()          # the tick's device state, nothing run
+    return eng
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+def _args(eng, program):
+    if program != "_chunk_prefill":
+        return (eng.params, eng.pools, eng.seen, eng._dev), {}
+    return ((eng.params, eng.pools, jnp.zeros((eng.M,), jnp.int32),
+             jnp.zeros((1, CHUNK), jnp.int32), np.int32(0),
+             np.int32(CHUNK), jnp.zeros((2,), jnp.uint32), np.float32(0.8),
+             np.int32(20), np.float32(0.95), np.float32(1.1), eng.seen[0]),
+            {"bucket": CHUNK})
+
+
+def _trace(eng, program):
+    """A fresh trace of the program (a new callable, so no cache of an
+    earlier trace answers for it)."""
+    fn = getattr(eng, program)
+    args, kw = _args(eng, program)
+    return jax.jit(lambda *a: fn(*a, **kw)).trace(*args)
+
+
+def _scope(op_name):
+    found = [p for p in op_name.split("/") if p in obs.TICK_SCOPES]
+    return found[-1] if found else None
+
+
+def test_the_programs_use_the_whole_vocabulary():
+    assert set().union(*PROGRAMS.values()) == set(obs.TICK_SCOPES)
+    assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
+    assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES
+                                          + obs.LOOP_PHASES)
+
+
+def _kernel_names(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                out += _kernel_names(getattr(inner, "jaxpr", inner))
+    return out
+
+
+@pytest.mark.parametrize("route, name", [
+    ("ragged", "ragged_paged_attention"), ("grid", "paged_attention")])
+def test_serving_kernels_are_named(engine, kernels, monkeypatch, route,
+                                   name):
+    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", route)
+    assert engine.decode_route() == route
+    names = _kernel_names(_trace(engine, "_fused_tick_greedy").jaxpr.jaxpr)
+    layers = engine.model.config.num_hidden_layers
+    assert names == [name] * layers
+
+
+def _lowered(eng, program):
+    """(opcode histogram, how many ops each scope covers; None for the
+    ops under no scope) of a fresh trace."""
+    low = _trace(eng, program).lower()
+    names = re.findall(r'loc\("(jit\([^"]*)"', low.as_text(debug_info=True))
+    return (collections.Counter(re.findall(
+        r"\b(?:stablehlo|chlo|func)\.[\w.]+", low.as_text())),
+        collections.Counter(_scope(n) for n in names))
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_program_ops_carry_their_scopes(engine, kernels, program):
+    _, scopes = _lowered(engine, program)
+    assert set(scopes) - {None} == PROGRAMS[program]
+    # what no scope covers is index plumbing between the stages
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_scopes_do_not_change_the_program(engine, kernels, monkeypatch,
+                                          program):
+    scoped, _ = _lowered(engine, program)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, scopes = _lowered(engine, program)
+    assert set(scopes) == {None}    # the patch took: no scope was traced
+    assert sum(scoped.values()) > 100
+    assert plain == scoped

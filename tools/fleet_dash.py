@@ -144,19 +144,24 @@ def _phase_share_points(doc: dict) -> Dict[str, List[Tuple[float,
     return {p: sorted(m.items()) for p, m in sorted(by_phase.items())}
 
 
-# one unambiguous letter per phase (first letters collide:
-# host/h2d, dispatch/device/drain)
-PHASE_LETTERS = {"host": "H", "h2d": "U", "dispatch": "D",
-                 "device": "C", "drain": "R"}
+# one unambiguous letter per phase of obs.TICK_PHASES and
+# obs.LOOP_PHASES (first letters collide: host/h2d, dispatch/device/
+# drain, commit/chunk, expire/emit, stage/sched); a test pins the keys
+# to the vocabulary
+PHASE_LETTERS = {"host": "H", "commit": "M", "expire": "X", "admit": "A",
+                 "chunk": "K", "stage": "S", "h2d": "U", "dispatch": "D",
+                 "device": "C", "drain": "R",
+                 "sched": "Q", "lock": "L", "emit": "E", "idle": "I"}
+PHASE_LEGEND = " ".join(f"{c} {p}" for p, c in PHASE_LETTERS.items())
 
 
 def _phase_row(d: dict, t0: float, t1: float,
                width: int) -> Optional[str]:
     """The stacked phase-share row (ISSUE 20): per time bin, the
-    DOMINANT phase's letter (H host, U h2d upload, D dispatch,
-    C device compute, R drain readback) — uppercase when it holds a
-    majority of the tick wall, lowercase for a mere plurality. One
-    glance says "this replica went dispatch-bound at t=40s"."""
+    DOMINANT phase's letter (``PHASE_LETTERS``: C is the wait for the
+    device, I an idle worker) — uppercase when it holds a majority of
+    the tick thread's time, lowercase for a mere plurality. One glance
+    says "this replica went dispatch-bound at t=40s"."""
     shares = _phase_share_points(d)
     if not shares:
         return None
@@ -307,8 +312,7 @@ def render(docs: Dict[str, dict], events: Optional[List[dict]] = None,
         ph = _phase_row(d, t0, t1, width)
         if ph is not None:
             lines.append(f"{name[:12]:<12s} {ph} "
-                         f"phase (H host U h2d D dispatch C device "
-                         f"R drain; UPPER = majority)")
+                         f"phase ({PHASE_LEGEND}; UPPER = majority)")
         lines.append("")
     marks = list(events or ())
     if marks:
@@ -446,7 +450,7 @@ def live(host: str, port: int, watch_s: float, window_s: float,
                 if any(c != " " for c in h["phase"]):
                     print(f"{'':<12s} phase "
                           f"{''.join(h['phase']):<{width}s} "
-                          f"(H host U h2d D disp C dev R drain)")
+                          f"({PHASE_LEGEND})")
             sys.stdout.flush()
         if now >= t_end:
             return 0

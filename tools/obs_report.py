@@ -142,9 +142,8 @@ def _load_tickphase(run_dir: str) -> List[dict]:
 
 def phase_decompose(docs: List[dict]) -> Optional[Dict[str, Any]]:
     """The ``phase_decompose`` view (ISSUE 20): split tick wall time —
-    and therefore tok/s — into host / h2d / dispatch / device / drain
-    SHARES per profiled engine and fleet-wide, and name the dominant
-    term. This is the slope-vs-intercept read ROADMAP item 1 needs:
+    and therefore tok/s — into the SHARES of ``obs.TICK_PHASES`` per
+    profiled engine and fleet-wide, and name the dominant term. This is the slope-vs-intercept read ROADMAP item 1 needs:
     device share is the slope (model compute), dispatch+host share is
     the intercept (per-tick machinery) — a tok/s gap attributed to the
     intercept is a tick-machinery problem, not a kernel problem."""
@@ -519,20 +518,23 @@ def self_check() -> int:
         # tick-phase ring (ISSUE 20): synthesize one with the library's
         # validator vocabulary, re-validate, and pin the decompose math
         from paddle_tpu.utils.observability import (
-            TICK_PHASES, validate_tickphase_doc)
+            LOOP_PHASES, TICK_PHASES, validate_tickphase_doc)
+        per_tick = dict({p: 0.0 for p in TICK_PHASES}, host=0.5,
+                        commit=0.5, h2d=0.5, dispatch=2.5, device=0.75,
+                        drain=0.25)
         tp_doc = {
             "schema": "tickphase/1", "engine": "chk-e0",
             "dumped_wall": 1000.0, "clock_now": 10.0, "capacity": 8,
             "ticks": 2, "wall_total_ms": 10.0,
-            "phase_totals_ms": {"host": 2.0, "h2d": 1.0,
-                                "dispatch": 5.0, "device": 1.5,
-                                "drain": 0.5},
+            "phase_totals_ms": {p: 2 * v for p, v in per_tick.items()},
+            "loop_totals_ms": dict({p: 0.0 for p in LOOP_PHASES},
+                                   emit=1.0, idle=3.0),
+            "thread_wall_ms": 14.5,
             "entries": [
-                {"tick": k, "t": 9.0 + k, "wall_ms": 5.0,
-                 "host_ms": 1.0, "h2d_ms": 0.5, "dispatch_ms": 2.5,
-                 "device_ms": 0.75, "drain_ms": 0.25,
-                 "dispatches": 1, "uploads": 0, "bytes": 0,
-                 "patches": 0, "active": 2} for k in range(2)],
+                dict({f"{p}_ms": v for p, v in per_tick.items()},
+                     tick=k, t=9.0 + k, wall_ms=5.0, dispatches=1,
+                     uploads=0, bytes=0, patches=0, active=2)
+                for k in range(2)],
         }
         problems = validate_tickphase_doc(tp_doc)
         expect(not problems,

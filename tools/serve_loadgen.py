@@ -1094,20 +1094,24 @@ async def run_loadgen(ns) -> dict:
             wall = 0.0
             ticks_p = 0
             for e in engines:
-                pt = e.tick_phase_totals
-                if pt is None:
+                summ = e.tick_profile_summary()
+                if summ is None:
                     continue
-                for p, v in pt.items():
+                # the in-tick phases only: they sum to the tick wall
+                # (the worker loop's are shares of the thread's)
+                for p, v in summ["phase_totals_ms"].items():
                     totals[p] = totals.get(p, 0.0) + v
-                wall += e.tick_wall_ms_total
-                ticks_p += e._prof.ticks
+                wall += summ["wall_total_ms"]
+                ticks_p += summ["ticks"]
             phase_sum = sum(totals.values())
             rung["phase_breakdown"] = {
                 "ticks": ticks_p,
                 "wall_ms": round(wall, 3),
+                # host work by any name: everything that is neither
+                # the program's call, the wait for it, nor the D2H
                 "host_frac": round(
-                    (totals.get("host", 0.0)
-                     + totals.get("h2d", 0.0)) / wall, 4)
+                    sum(v for p, v in totals.items() if p not in
+                        ("dispatch", "device", "drain")) / wall, 4)
                 if wall else 0.0,
                 "h2d_frac": round(
                     totals.get("h2d", 0.0) / wall, 4) if wall else 0.0,

@@ -27,8 +27,8 @@ frontend -> gwA -> gwB mid-stream failover renders as one left-to-
 right waterfall across three process lanes with no clock fixup.
 
 Tick-phase rings ride in as one extra process per source engine: each
-recorded tick is a span on a per-phase thread lane (host / h2d /
-dispatch / device / drain stacked under the tick wall), wall-anchored
+recorded tick is a span on a per-phase thread lane (the phases of
+``obs.TICK_PHASES`` stacked under the tick wall), wall-anchored
 via the dump's ``dumped_wall - clock_now`` offset, the same mapping
 ``fleet_dash`` uses for flight-recorder markers.
 
@@ -58,7 +58,7 @@ INSTANT_KINDS = (
 )
 
 # per-source cap on exported tick spans: a long soak's 1024-deep ring
-# x 5 phases would dwarf the request lanes; the newest ticks are the
+# x 10 phases would dwarf the request lanes; the newest ticks are the
 # ones a capture just profiled
 MAX_TICKS_PER_SOURCE = 256
 
@@ -184,6 +184,7 @@ def _tickphase_events(doc: dict) -> List[dict]:
     mapped to wall time with the dump-instant offset
     (``dumped_wall - clock_now``) — exact for the monotonic default
     clock, best-effort for an injected one."""
+    from paddle_tpu.utils.observability import TICK_PHASES
     src = doc["_file"].replace("tickphase_", "").replace(".json", "")
     pid = f"tickphase:{src}"
     offset = float(doc.get("dumped_wall", 0.0)) \
@@ -196,8 +197,7 @@ def _tickphase_events(doc: dict) -> List[dict]:
               f"{MAX_TICKS_PER_SOURCE} of {len(entries)} ticks "
               f"({dropped} older dropped)", file=sys.stderr)
         entries = entries[-MAX_TICKS_PER_SOURCE:]
-    for lane in ("tick",) + tuple(
-            k for k in ("host", "h2d", "dispatch", "device", "drain")):
+    for lane in ("tick",) + TICK_PHASES:
         out.append(_meta("thread_name", pid, lane, lane))
     for rec in entries:
         t_end = offset + float(rec["t"])
@@ -212,7 +212,7 @@ def _tickphase_events(doc: dict) -> List[dict]:
         # phases stacked left-to-right inside the tick window (the
         # real interleave is finer; the widths are exact)
         cur = t0
-        for p in ("host", "h2d", "dispatch", "device", "drain"):
+        for p in TICK_PHASES:
             d_ms = float(rec.get(f"{p}_ms", 0.0))
             if d_ms <= 0.0:
                 continue
