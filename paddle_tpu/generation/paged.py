@@ -258,7 +258,8 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
 def paged_decode_route(q, kp) -> str:
     """Which attention path ``paged_decode_attention`` takes for q
     [R, T, h, d] against pools shaped like ``kp`` [P, B, kvh, d]:
-    ``"ragged"`` (the schedule-driven Pallas kernel, the default),
+    ``"ragged"`` (the Pallas kernel that walks each row's own pages, the
+    default),
     ``"grid"`` (the grid-per-row Pallas kernel, single-query only) or
     ``"dense"`` (XLA whole-table gather). Only shapes are read, so a
     caller can ask with the engine's geometry (``PagedEngine.
@@ -267,10 +268,11 @@ def paged_decode_route(q, kp) -> str:
     import os
 
     from ..ops.pallas.paged_attention import use_paged_kernel
+    from ..ops.pallas.ragged_paged_attention import pages_fill_lanes
     mode = os.environ.get("PADDLE_TPU_PAGED_ATTN", "ragged")
     if mode == "dense" or not use_paged_kernel(q, kp):
         return "dense"
-    if mode == "grid":
+    if mode == "grid" or not pages_fill_lanes(kp):
         return "grid" if q.shape[1] == 1 else "dense"
     return "ragged"
 
@@ -283,10 +285,10 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     decode tick; T > 1 is the speculative verify's multi-query rows
     (ISSUE 7) — per-position causal masking inside the row.
 
-    Fast path (default "ragged"): the schedule-driven ragged kernel —
-    one grid over the batch's ACTUAL live blocks, packed live-first, no
-    per-request padding (ISSUE 6); it serves both T == 1 and the
-    multi-query rows. ``PADDLE_TPU_PAGED_ATTN=grid`` keeps the
+    Fast path (default "ragged"): the ragged kernel — one step per row,
+    which walks that row's LIVE pages, a run of them per compute block,
+    all kv heads at once; it serves both T == 1 and the multi-query
+    rows. ``PADDLE_TPU_PAGED_ATTN=grid`` keeps the
     grid-per-row kernel (single-query only — multi-query falls through
     to dense under it); ``=dense`` forces the fallback. Fallback (CPU
     tests / odd shapes): dense whole-table gather — the math is

@@ -24,10 +24,12 @@ live blocks:
   padded to the 8-sublane minimum) and each KV block is read once per
   KV head, never per query head.
 
-Pool layout note: the [P, B, kvh, d] pools are viewed [P, B, kvh*d]
-(free reshape — contiguous) so the last-two block dims (B, d) satisfy
-Mosaic's (8, 128) tiling with the column block selecting the kv head,
-the same trick as ``decode_attention.py``.
+Pool layout note: the [P, B, kvh, d] pools are viewed [P, B, kvh*d] so
+the last-two block dims (B, d) satisfy Mosaic's (8, 128) tiling with
+the column block selecting the kv head, the same trick as
+``decode_attention.py``. The view is not free on the chip: XLA copies
+the pool into that layout for every call (PERF.md section 5, scope
+``kv_layout``: 3.1 ms of a 16-layer tick at a 1 GB pool).
 """
 from __future__ import annotations
 

@@ -1,40 +1,36 @@
-"""Ragged paged-attention Pallas kernel (ISSUE 6 tentpole; reference:
-PAPERS.md "Ragged Paged Attention" — ONE kernel over variable-length
-requests with no per-request padding in the work schedule).
+"""Ragged paged-attention Pallas kernel (reference: PAPERS.md "Ragged
+Paged Attention" and jax's own `paged_attention` kernel — the kernel
+gathers a row's pages itself, a run of them per compute step).
 
-The grid-per-row kernel (`paged_attention.py`) runs a fixed ``(R, kvh,
-M)`` grid: every row pays M grid steps whether it holds 1 live block or
-M. Dead steps clamp their index maps (no copy, no compute), but they
-still occupy the scalar core and fragment Mosaic's pipeline R times per
-kv head. This kernel flattens the work into a single SCHEDULE of (row,
-logical block) pairs, packed live-first:
+One grid step per ROW; nothing is sized by ``R*M``. Inside a step the
+row walks its own block table to its own length:
 
-- the schedule is computed from ``seq_lens``/``block_tables`` with jnp
-  ops (cumsum + searchsorted over per-row live-block counts) INSIDE the
-  caller's jit — in the fused decode tick it is traced once per program
-  and XLA CSE-dedups it across layers. No host round-trip per tick.
-- schedule capacity ``S`` is static ``R*M`` (every row's table can be
-  fully live; a physical-pool bound would under-count when prefix
-  caching shares blocks across rows — see ``schedule_capacity``). The
-  live work is packed contiguous at the front, so the dead tail is ONE
-  run of clamped (copy-free, predicated-off) steps instead of R of
-  them.
-- grid ``(kvh, S)``; the fp32 accumulator scratch carries the online
-  softmax across a row's consecutive schedule steps; `first`/`last`
-  steps of each row's run are detected from the prefetched schedule
-  (init / finalize). The output index map repeats a row's index across
-  its run, so Mosaic flushes each row's output exactly once.
-- dead steps (s >= total live) clamp row/block to the last live step:
-  the repeated index skips the HBM→VMEM copy and `@pl.when` skips the
-  compute, so the tail costs only scalar-core index math.
-- GQA rides the matmul M dim exactly like `paged_attention.py`: q is
-  viewed [R, kvh, group(padded to 8), d], each KV block is read once
-  per KV head. The pool is viewed [P, B, kvh*d] so KV blocks are
-  (B, d) with the column block selecting the head — (8, 128)-tilable
-  for the gated shapes.
+- ``block_tables`` [R, M] and ``seq_lens`` [R] ride scalar prefetch
+  (SMEM). A row's first and last live page come from its length (and the
+  sliding window, and ``q_len`` for multi-query rows); the number of
+  compute blocks is a ``lax.fori_loop`` trip count read from them. An
+  empty slot walks one page, a full table walks M. No schedule is built
+  outside the kernel, and rows that share prefix blocks simply name the
+  same physical page in their own tables.
+- the pools stay in HBM (``pl.ANY``). A compute block is
+  ``pages_per_step`` pages (`_pages_per_step`: about 256 tokens, inside a
+  VMEM budget); the kernel issues one ``make_async_copy`` per LIVE page
+  of K and of V into a double-buffered VMEM scratch and computes block
+  ``i`` while block ``i+1`` lands. A page arrives once, as ``(B,
+  kvh*d)``, for all kv heads.
+- the kv heads are a static inner loop over column slices of that
+  buffer. GQA rides the matmul M dim: q is viewed [R, kvh, group (padded
+  to 8), d]; the fp32 online softmax (running max, sum, accumulator per
+  head) is carried through the loop and written once per row.
+- tokens of a block past the row's length (the rest of its last page,
+  pages not fetched) are masked by position. Their K may be anything;
+  their V meets a zero weight, so it only has to be finite: the V
+  scratch is zeroed at the first row and afterwards holds pool data.
 
-Sliding windows schedule only the in-band blocks per row (the front
-clamp moves into the schedule itself instead of the index map).
+Pool layout: the [P, B, kvh, d] pools are handed over as [P, B, kvh*d],
+so a page is one contiguous ``(B, kvh*d)`` slab. On the chip that
+reshape is a real copy of the pool (scope ``kv_layout``: PERF.md section
+5), not a free view.
 """
 from __future__ import annotations
 
@@ -50,129 +46,132 @@ from . import interpret_enabled as _interpret
 
 NEG_INF = -1e30
 
-
-def schedule_capacity(R: int, M: int, P: int) -> int:
-    """Static schedule length: every row can contribute up to M live
-    LOGICAL blocks, so the schedule must hold R*M. A pool-derived bound
-    (P-1 allocatable + one write block per row) would be tighter for
-    block-constrained configs but is WRONG under prefix caching: shared
-    physical blocks count once against the pool yet appear in every
-    borrowing row's table, so the sum of logical live blocks can exceed
-    any physical-pool bound — a truncated schedule cuts a row's run
-    mid-stride and its output block is never finalized (garbage
-    attention for that row and every row after it). The dead tail is
-    copy-free and predicated off, so the R*M worst case costs only
-    scalar-core index math per unused step."""
-    del P
-    return R * M
+# K and V compute blocks, two of each in flight
+_VMEM_BUDGET = 4 << 20
+_BLOCK_TOKENS = 256
 
 
-def build_schedule(block_tables, seq_lens, S: int, block_size: int,
-                   window=None, q_len: int = 1):
-    """Flattened live-first schedule. Returns int32 arrays
-    (row[S], blk[S], live[S]) where (row, blk) index ``block_tables``
-    and live flags steps < total. Dead steps repeat the LAST live step's
-    (row, blk) so their block indices never change (copy-free). All jnp
-    — traceable inside the decode tick's jit.
-
-    ``q_len`` > 1 (ISSUE 7 multi-query verify rows): each row carries
-    q_len query positions seq_len .. seq_len+q_len-1, so live blocks
-    must cover the LAST query's window (lens + q_len attendable tokens)
-    while a sliding window's front clamp follows the FIRST query."""
-    R, M = block_tables.shape
-    B = block_size
-    lens = jnp.asarray(seq_lens, jnp.int32)
-    valid = lens + q_len                              # attendable tokens
-    nb = jnp.clip((valid + B - 1) // B, 1, M)         # last live block + 1
-    if window is None:
-        lo = jnp.zeros((R,), jnp.int32)
-    else:
-        lo = jnp.maximum(lens + 1 - window, 0) // B   # first in-band block
-    cnt = nb - lo                                     # >= 1 per row
-    cum = jnp.cumsum(cnt)
-    total = cum[-1]
-    starts = cum - cnt
-    s = jnp.arange(S, dtype=jnp.int32)
-    row = jnp.searchsorted(cum, s, side="right").astype(jnp.int32)
-    rowc = jnp.clip(row, 0, R - 1)
-    blk = lo[rowc] + (s - starts[rowc])
-    live = s < total
-    li = jnp.clip(total - 1, 0, S - 1)
-    row_s = jnp.where(live, rowc, rowc[li])
-    blk_s = jnp.where(live, blk, blk[li])
-    return row_s, blk_s, live.astype(jnp.int32)
+def pages_fill_lanes(kp) -> bool:
+    """Whether a page of pool ``kp`` [P, B, kvh, d] can be fetched as one
+    ``(B, kvh*d)`` slab: Mosaic slices HBM in whole 128-lane tiles, so
+    one kv head of 64 columns cannot, and ``paged_decode_route`` sends it
+    elsewhere. The interpreter takes any width."""
+    return _interpret() or (kp.shape[2] * kp.shape[3]) % 128 == 0
 
 
-def _ragged_kernel(tbl_ref, len_ref, row_ref, blk_ref, live_ref,
-                   q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr, *,
-                   scale, bs, S, window, group):
-    si = pl.program_id(1)
-    r = row_ref[si]
-    b = blk_ref[si]
-    live = live_ref[si] == 1
-    prv = jnp.maximum(si - 1, 0)
-    nxt = jnp.minimum(si + 1, S - 1)
-    prev_same = (si > 0) & (row_ref[prv] == r) & (live_ref[prv] == 1)
-    next_same = (si < S - 1) & (row_ref[nxt] == r) & (live_ref[nxt] == 1)
-    first = live & jnp.logical_not(prev_same)
-    last = live & jnp.logical_not(next_same)
+def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:
+    """Pages of ``B`` tokens x ``width`` = kvh*d columns in one compute
+    block: about ``_BLOCK_TOKENS`` tokens, halved until K and V, double
+    buffered, fit ``_VMEM_BUDGET``; never more than a row's table."""
+    pps = max(1, _BLOCK_TOKENS // B)
+    while pps > 1 and 4 * pps * B * width * itemsize > _VMEM_BUDGET:
+        pps //= 2
+    return min(pps, M)
 
-    @pl.when(first)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
 
-    @pl.when(live)
-    def _compute():
-        valid = len_ref[r] + 1          # tokens [0, seq_len] attendable
-        q = q_ref[0, 0, :, :]                        # [gp, d]
-        k = k_ref[0, :, :]                           # [bs, d]
-        v = v_ref[0, :, :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        gp = q.shape[0]
-        k_ids = lax.broadcasted_iota(jnp.int32, (gp, bs), 1) + b * bs
-        # multi-query rows (ISSUE 7): the q tile packs q_len positions x
-        # `group` query heads, so sublane j belongs to verify position
-        # t = j // group and attends causally up to seq_len + t. Single-
-        # query calls have every real sublane at t == 0 — the original
-        # mask; padded sublanes see a wider mask but their rows are
-        # sliced off by the caller.
-        t_of = lax.broadcasted_iota(jnp.int32, (gp, bs), 0) // group
+def _ragged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, *, scale, bs, pps, window, group,
+                   q_len):
+    r = pl.program_id(0)
+    M = tbl_ref.shape[1]
+    kvh, gp, d = q_ref.shape[1:]
+    tc = pps * bs
+    # query t of the row sits at seq_len + t: live pages cover the LAST
+    # query's tokens, a sliding window's front follows the FIRST
+    valid = len_ref[r] + 1
+    hi = jnp.clip((valid + q_len - 1 + bs - 1) // bs, 1, M)
+    lo = 0 if window is None else jnp.maximum(valid - window, 0) // bs
+    n_blocks = (hi - lo + pps - 1) // pps
+
+    @pl.when(r == 0)
+    def _finite_v():
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    def for_each_live_page(i, slot, fn):
+        """fn(copy) over the K and V copies of compute block i's live
+        pages; the same descriptors start a copy and wait for it."""
+        first = lo + i * pps
+
+        def page(j, carry):
+            phys = tbl_ref[r, first + j]
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            fn(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, dst],
+                                     sems.at[slot, 0]))
+            fn(pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[slot, dst],
+                                     sems.at[slot, 1]))
+            return carry
+        lax.fori_loop(0, jnp.minimum(hi - first, pps), page, 0)
+
+    for_each_live_page(0, 0, lambda c: c.start())
+
+    def block(i, carry):
+        slot = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch():
+            for_each_live_page(i + 1, 1 - slot, lambda c: c.start())
+
+        for_each_live_page(i, slot, lambda c: c.wait())
+        k_ids = lax.broadcasted_iota(jnp.int32, (gp, tc), 1) \
+            + (lo + i * pps) * bs
+        # multi-query rows: the q tile packs q_len positions x `group`
+        # query heads, so sublane j belongs to verify position
+        # t = j // group and attends causally up to seq_len + t. Padded
+        # sublanes see a wider mask; the caller slices them off.
+        t_of = lax.broadcasted_iota(jnp.int32, (gp, tc), 0) // group
         keep = k_ids < valid + t_of
         if window is not None:
             keep &= k_ids >= valid + t_of - window
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:, :1] = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1,
-                                                      keepdims=True)
-        acc[:] = acc[:] * alpha + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:, :1] = m_new
+        out = []
+        for h, (m_prev, l_prev, acc) in enumerate(carry):
+            cols = slice(h * d, (h + 1) * d)
+            k = k_buf[slot, :, cols]                     # [tc, d]
+            v = v_buf[slot, :, cols]
+            s = lax.dot_general(q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            out.append((
+                m_new,
+                alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)))
+        return tuple(out)
 
-    @pl.when(last)
-    def _finalize():
-        safe_l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0, :, :] = (acc[:] / safe_l).astype(o_ref.dtype)
+    init = (jnp.full((gp, 1), NEG_INF, jnp.float32),
+            jnp.zeros((gp, 1), jnp.float32),
+            jnp.zeros((gp, d), jnp.float32))
+    heads = lax.fori_loop(0, n_blocks, block, (init,) * kvh)
+    for h, (_, l, acc) in enumerate(heads):
+        o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
                                   scale, window=None):
     """q [R, h, d] (single-query decode) OR [R, T, h, d] (multi-query
-    speculative verify rows, ISSUE 7: query t of row r sits at position
+    speculative verify rows: query t of row r sits at position
     seq_lens[r] + t and attends tokens 0..seq_lens[r]+t); kp/vp
     [P, B, kvh, d] physical pools; block_tables [R, M]; seq_lens [R].
     Returns q's shape.
 
-    Multi-query rides the SAME (kvh, S) schedule grid: the q tile packs
-    T positions x `group` heads into the sublane dim (padded to 8), so
-    each KV block is still read once per kv head per row — the verify's
-    extra queries are matmul rows, not extra HBM traffic."""
+    Multi-query rides the same walk: the q tile packs T positions x
+    `group` heads into the sublane dim (padded to 8), so each page is
+    still read once per row — the verify's extra queries are matmul
+    rows, not extra HBM traffic."""
+    return _attend(q, kp, vp, block_tables, seq_lens, scale=float(scale),
+                   window=window, interpret=_interpret())
+
+
+# jitted so that a program of L layers traces the kernel body once, not
+# L times: set-up pays that on every start, warm compile cache or not.
+# Inlined into the caller's jaxpr, so the lowered program and the names
+# of its ops (obs.TICK_SCOPES) are what they are without the jit.
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("scale", "window", "interpret"))
+def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret):
     multi = q.ndim == 4
     if multi:
         R, T, h, d = q.shape
@@ -184,7 +183,7 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
     group = h // kvh
     rows = T * group
     gp = max(8, -(-rows // 8) * 8)
-    S = schedule_capacity(R, M, P)
+    pps = _pages_per_step(B, kvh * d, kp.dtype.itemsize, M)
 
     if multi:
         # [R, T, kvh, group, d] -> [R, kvh, T*group, d]: position-major
@@ -196,45 +195,35 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
     if gp != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
 
-    tbl = jnp.asarray(block_tables, jnp.int32)
-    lens = jnp.asarray(seq_lens, jnp.int32)
-    row_s, blk_s, live = build_schedule(tbl, lens, S, B, window=window,
-                                        q_len=T)
-
-    def q_index(ki, si, tbl, lens, row, blk, live):
-        return (row[si], ki, 0, 0)
-
-    def kv_index(ki, si, tbl, lens, row, blk, live):
-        # dead steps carry the last live step's (row, blk): the repeated
-        # physical index skips the copy
-        return (tbl[row[si], blk[si]], 0, ki)
-
-    kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, S=S,
-                               window=window, group=group)
+    kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, pps=pps,
+                               window=window, group=group, q_len=T)
     with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
         kc = kp.reshape(P, B, kvh * d)
         vc = vp.reshape(P, B, kvh * d)
+    row_block = pl.BlockSpec((1, kvh, gp, d),
+                             lambda r, tbl, lens: (r, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(kvh, S),
+            num_scalar_prefetch=2,
+            grid=(R,),
             in_specs=[
-                pl.BlockSpec((1, 1, gp, d), q_index),
-                pl.BlockSpec((1, B, d), kv_index),
-                pl.BlockSpec((1, B, d), kv_index),
+                row_block,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, 1, gp, d), q_index),
+            out_specs=row_block,
             scratch_shapes=[
-                pltpu.VMEM((gp, d), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
-                pltpu.VMEM((gp, 128), jnp.float32),
+                pltpu.VMEM((2, pps * B, kvh * d), kp.dtype),
+                pltpu.VMEM((2, pps * B, kvh * d), vp.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((R, kvh, gp, d), q.dtype),
-        interpret=_interpret(),
-    )(tbl, lens, row_s, blk_s, live, qg, kc, vc)
+        interpret=interpret,
+    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
+      qg, kc, vc)
     out = out[:, :, :rows, :]
     if not multi:
         return out.reshape(R, h, d)

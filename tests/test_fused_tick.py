@@ -9,9 +9,10 @@ Three contracts, each pinned against an independent reference:
   with ZERO host->device mirror uploads; ``ticks_per_dispatch=K``
   amortizes that one dispatch over K tokens when provably safe and
   falls back to per-tick scheduling when not.
-- KERNEL PARITY: the ragged schedule-driven kernel matches the dense
-  whole-table gather across uneven ``seq_lens`` (single-token rows,
-  block-boundary lengths, windows), and the re-blocked decode kernel's
+- KERNEL PARITY: the ragged kernel (each row walks its own pages, a
+  run of them per compute block) matches the dense whole-table gather
+  across uneven ``seq_lens`` (single-token rows, block-boundary
+  lengths, full tables, windows), and the re-blocked decode kernel's
   BlockSpecs are strictly (8, 128)-tiled at the BENCH_SELF_r05 failing
   shape so the hardware lowering failure cannot regress silently on a
   CPU-only image.
@@ -327,8 +328,8 @@ class TestRaggedKernel:
     @pytest.mark.parametrize("window", [None, 12])
     def test_parity_uneven_and_boundary_lens(self, window):
         """seq_lens 0 (single attendable token), B-1, B (block
-        boundary), and a mid-block length — one schedule, no
-        per-request padding, exact vs the dense gather."""
+        boundary), and a mid-block length — no per-request padding,
+        exact vs the dense gather."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention_pallas
         rs = np.random.RandomState(7)
@@ -367,53 +368,129 @@ class TestRaggedKernel:
         np.testing.assert_allclose(outs["grid"], outs["dense"],
                                    atol=2e-5, rtol=2e-5)
 
-    def test_build_schedule_packs_live_first(self):
-        """Schedule properties the kernel relies on: per-row runs are
-        contiguous and live-first; dead tail repeats the LAST live
-        (row, blk) so its block index never changes; windowed rows
-        schedule only in-band blocks."""
+    @pytest.mark.parametrize("B,M,kvh,h,d,lens,window", [
+        # 16 pages a compute block (256 tokens / B 16), 40 a table: rows
+        # of 1, 16, 17 and 33 live pages -- a whole block, one page over,
+        # two blocks and one page -- and the full table, lens = M*B - 1
+        (16, 40, 2, 4, 64, [0, 255, 256, 527, 639], None),
+        # an empty slot beside a full one, and only those
+        (16, 40, 2, 4, 64, [0, 639, 0, 639], None),
+        # a window that starts the walk mid-table: pages 19-39, 0-1, 9-16
+        (16, 40, 2, 4, 64, [639, 20, 260], 330),
+        # the 1.5B's and the 7B's heads at head 128: kvh 2 x group 6,
+        # kvh 4 x group 7 (padded to 8 sublanes)
+        (16, 20, 2, 12, 128, [0, 100, 319, 17], None),
+        (16, 20, 4, 28, 128, [0, 100, 319, 17], None),
+    ])
+    def test_parity_blocks_of_pages(self, B, M, kvh, h, d, lens, window):
+        """The walk in compute blocks of several pages: live pages that
+        are not a multiple of `pages_per_step`, a row at the full table,
+        empty slots, the cells' head geometries."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            build_schedule, schedule_capacity)
-        R, M, P, B = 3, 4, 32, 8
-        tables = jnp.arange(R * M, dtype=jnp.int32).reshape(R, M)
-        lens = jnp.asarray([0, 17, 30], jnp.int32)
-        S = schedule_capacity(R, M, P)
-        row, blk, live = (np.asarray(x) for x in
-                          build_schedule(tables, lens, S, B))
-        # live-block counts: ceil((len+1)/B) -> 1, 3, 4
-        total = 8
-        assert live.sum() == total
-        assert (live[:total] == 1).all() and (live[total:] == 0).all()
-        np.testing.assert_array_equal(row[:total],
-                                      [0, 1, 1, 1, 2, 2, 2, 2])
-        np.testing.assert_array_equal(blk[:total],
-                                      [0, 0, 1, 2, 0, 1, 2, 3])
-        assert (row[total:] == 2).all() and (blk[total:] == 3).all()
-        # window: only blocks touching [valid-window, valid) remain
-        row_w, blk_w, live_w = (np.asarray(x) for x in
-                                build_schedule(tables, lens, S, B,
-                                               window=8))
-        assert live_w.sum() == 1 + 2 + 2  # rows: blk0; blk1-2; blk2-3
-        np.testing.assert_array_equal(blk_w[:5], [0, 1, 2, 2, 3])
+            _pages_per_step, ragged_paged_attention_pallas)
+        rs = np.random.RandomState(11)
+        R = len(lens)
+        P = R * M + 1
+        assert _pages_per_step(B, kvh * d, 4, M) == min(256 // B, M) < M
+        q = jnp.asarray(rs.randn(R, h, d), jnp.float32)
+        kp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+        vp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+        tables = jnp.asarray(
+            1 + rs.permutation(P - 1)[:R * M].reshape(R, M), jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
+        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
+                                            d ** -0.5, window=window)
+        ref = _dense_paged_reference(q, kp, vp, tables, lens,
+                                     window=window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
 
-    def test_schedule_capacity_ignores_pool_bound(self):
-        """The capacity must be R*M, never a physical-pool bound: prefix
-        caching shares physical blocks across rows, so summed LOGICAL
-        live blocks can exceed P-1+R and a pool-bounded schedule would
-        truncate a row's run mid-stride (unfinalized output block =
-        garbage attention)."""
+    def test_dead_pages_never_reach_the_output(self):
+        """Pages past a row's length are neither fetched nor trusted:
+        NaN in every pool page a row does not hold live tokens in (the
+        rest of its table included) leaves the output as it was."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
-            schedule_capacity
-        assert schedule_capacity(4, 8, 64) == 32
-        assert schedule_capacity(16, 16, 33) == 256    # NOT 32+16
-        assert schedule_capacity(8, 4, 9) == 32        # NOT 8+8
+            ragged_paged_attention_pallas
+        rs = np.random.RandomState(12)
+        R, B, M, kvh, h, d = 3, 16, 40, 2, 4, 64
+        P = R * M + 1
+        q = jnp.asarray(rs.randn(R, h, d), jnp.float32)
+        kp = rs.randn(P, B, kvh, d).astype(np.float32)
+        vp = rs.randn(P, B, kvh, d).astype(np.float32)
+        tables = 1 + rs.permutation(P - 1)[:R * M].reshape(R, M)
+        lens = np.asarray([0, 300, 527])
+        ref = _dense_paged_reference(q, jnp.asarray(kp), jnp.asarray(vp),
+                                     jnp.asarray(tables, jnp.int32),
+                                     jnp.asarray(lens, jnp.int32))
+        live = np.zeros(P, bool)
+        for r in range(R):
+            live[tables[r, :lens[r] // B + 1]] = True
+        kp[~live] = np.nan
+        vp[~live] = np.nan
+        got = ragged_paged_attention_pallas(
+            q, jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32),
+            d ** -0.5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_wrapper_builds_no_schedule(self):
+        """Nothing outside the kernel is sized by R*M: the wrapper's
+        jaxpr holds no rank-1 array of that length (the old
+        `build_schedule` made three per program, by cumsum and
+        searchsorted), only the [R, M] table itself."""
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention_pallas
+        R, P, B, M, kvh, h, d = 4, 24, 8, 5, 2, 4, 64
+        jaxpr = jax.make_jaxpr(
+            lambda q, kp, vp, tbl, lens: ragged_paged_attention_pallas(
+                q, kp, vp, tbl, lens, d ** -0.5))(
+            jnp.zeros((R, h, d)), jnp.zeros((P, B, kvh, d)),
+            jnp.zeros((P, B, kvh, d)), jnp.zeros((R, M), jnp.int32),
+            jnp.zeros((R,), jnp.int32)).jaxpr
+        shapes = [v.aval.shape for eqn in jaxpr.eqns
+                  for v in list(eqn.invars) + list(eqn.outvars)
+                  if hasattr(v.aval, "shape")]
+        assert (R, M) in shapes
+        assert not [sh for sh in shapes if sh == (R * M,)]
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert call.params["grid_mapping"].grid == (R,)
+
+    @pytest.mark.parametrize("B,width,itemsize,M,pps", [
+        (16, 512, 2, 128, 16),      # qwen2-7b-d16: 256 tokens, 1 MB
+        (16, 256, 2, 128, 16),      # qwen2-1.5b
+        (8, 128, 4, 4, 4),          # a table shorter than a block
+        (16, 2048, 4, 128, 8),      # 8 MB at 16 pages: halved
+        (512, 2048, 4, 128, 1),     # one page is the least
+    ])
+    def test_pages_per_step_follows_shapes(self, B, width, itemsize, M,
+                                           pps):
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            _pages_per_step
+        assert _pages_per_step(B, width, itemsize, M) == pps
+
+    def test_narrow_pages_take_another_route(self, monkeypatch):
+        """On the chip a page is fetched as one (B, kvh*d) slab, which
+        Mosaic slices only in whole 128-lane tiles: one kv head of 64
+        columns keeps the grid kernel (single-query) or the dense
+        gather."""
+        from paddle_tpu.generation.paged import paged_decode_route
+        from paddle_tpu.ops import pallas
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
+        monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+        pool = lambda kvh, d: jnp.zeros((8, 16, kvh, d), jnp.bfloat16)
+        q = lambda T, h, d: jnp.zeros((2, T, h, d), jnp.bfloat16)
+        assert paged_decode_route(q(1, 28, 128), pool(4, 128)) == "ragged"
+        assert paged_decode_route(q(3, 28, 128), pool(4, 128)) == "ragged"
+        assert paged_decode_route(q(1, 8, 64), pool(1, 64)) == "grid"
+        assert paged_decode_route(q(3, 8, 64), pool(1, 64)) == "dense"
 
     def test_parity_shared_blocks_exceeding_pool_bound(self):
-        """Prefix-cache shape: rows share most physical blocks, and the
-        total of logical live blocks (16) exceeds the old pool-derived
-        capacity min(R*M, P-1+R) = 11 — every row must still finalize
-        and match the dense gather (regression for the schedule
-        truncation bug)."""
+        """Prefix-cache shape: rows share most physical blocks, so the
+        total of logical live blocks (16) exceeds any bound derived from
+        the pool (P-1+R = 11). Each row walks its own table, so every
+        row must match the dense gather (an earlier kernel's schedule
+        was cut at such a bound and left rows unfinished)."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention_pallas
         rs = np.random.RandomState(17)

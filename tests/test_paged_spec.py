@@ -427,6 +427,35 @@ class TestMultiQueryRagged:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("kvh,h,d,lens,window", [
+        # T=4 verify rows over several compute blocks of 16 pages: a row
+        # whose last query crosses into a new page AND a new block
+        # (len 253 + 3 = 256), an empty slot, the table's end
+        # (len + T = M*B), a two-block row
+        (2, 4, 64, [253, 0, 636, 300], None),
+        # the 7B's heads: 4 x 7 sublanes at head 128, windowed so the
+        # walk starts mid-table and the front follows query 0
+        (4, 28, 128, [253, 0, 636, 300], 280),
+    ])
+    def test_multi_query_blocks_of_pages(self, kvh, h, d, lens, window):
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention_pallas
+        rs = np.random.RandomState(13)
+        R, B, M, T = len(lens), 16, 40, 4
+        P = R * M + 1
+        q = jnp.asarray(rs.randn(R, T, h, d), jnp.float32)
+        kp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+        vp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+        tables = jnp.asarray(
+            1 + rs.permutation(P - 1)[:R * M].reshape(R, M), jnp.int32)
+        lens = jnp.asarray(lens, jnp.int32)
+        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
+                                            d ** -0.5, window=window)
+        ref = _dense_multi_reference(q, kp, vp, tables, lens,
+                                     window=window)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
     def test_paged_decode_attention_routes_multi_query(self, monkeypatch):
         """The dispatch layer: ragged and dense modes agree on T>1;
         grid mode (single-query kernel) falls back to dense."""

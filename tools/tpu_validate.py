@@ -140,20 +140,28 @@ def paged_kernel():
     assert err < 3e-2, err
 check("paged_attention_kernel", paged_kernel)
 
+def ragged_cell_inputs(T=None):
+    # the serving cell's geometry (qwen2-7b-d16: 8 slots, 128 blocks of
+    # 16 tokens, 4 kv heads, group 7, head 128, bf16, a 2049-block pool)
+    # with ragged lengths from an empty slot to a full table
+    R, P, B, M, kvh2, h2, d2 = 8, 2049, 16, 128, 4, 28, 128
+    qq = jnp.asarray(rs.randn(*((R, h2, d2) if T is None
+                                else (R, T, h2, d2))), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
+    vp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
+    tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
+                         .reshape(R, M), jnp.int32)
+    lens = jnp.asarray([0, 15, 16, 2040, 100, 576, 1023, 300], jnp.int32)
+    return qq, kp, vp, tables, lens
+
 def ragged_paged_kernel():
-    # ISSUE 6: the schedule-driven ragged kernel (the serving default)
-    # must compile and match the dense gather on hardware, same ragged
-    # rows as the grid kernel check above
+    # the ragged kernel (the serving default) must compile and match the
+    # dense gather on hardware at the cell's geometry
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
     from paddle_tpu.ops.attention import dense_attention as da
-    R, P, B, M, kvh2, h2, d2 = 4, 64, 16, 16, 4, 8, 128
-    qq = jnp.asarray(rs.randn(R, h2, d2), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    tables = jnp.asarray(rs.permutation(np.arange(P))[:R * M]
-                         .reshape(R, M), jnp.int32)
-    lens = jnp.asarray([0, 31, 100, 255], jnp.int32)
+    qq, kp, vp, tables, lens = ragged_cell_inputs()
+    R, kvh2, d2 = qq.shape[0], kp.shape[2], kp.shape[3]
     out = ragged_paged_attention_pallas(qq, kp, vp, tables, lens,
                                         d2 ** -0.5)
     ks = kp[tables].reshape(R, -1, kvh2, d2)
@@ -174,13 +182,9 @@ def ragged_paged_multiquery_kernel():
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
     from paddle_tpu.ops.attention import dense_attention as da
-    R, P, B, M, kvh2, h2, d2, T = 4, 64, 16, 16, 4, 8, 128, 5
-    qq = jnp.asarray(rs.randn(R, T, h2, d2), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    tables = jnp.asarray(rs.permutation(np.arange(P))[:R * M]
-                         .reshape(R, M), jnp.int32)
-    lens = jnp.asarray([0, 31, 100, 250], jnp.int32)
+    T = 5
+    qq, kp, vp, tables, lens = ragged_cell_inputs(T)
+    R, kvh2, d2 = qq.shape[0], kp.shape[2], kp.shape[3]
     out = ragged_paged_attention_pallas(qq, kp, vp, tables, lens,
                                         d2 ** -0.5)
     ks = kp[tables].reshape(R, -1, kvh2, d2)
