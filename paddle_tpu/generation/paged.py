@@ -97,11 +97,13 @@ block 0) so they can never corrupt a live block; it is never allocated.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import json
 import os
 import time
+import types
 from collections import deque
 from typing import Any, Dict, List, NamedTuple, Optional
 
@@ -118,6 +120,7 @@ __all__ = ["PagedKV", "PagedEngine"]
 # global observability registry (scrapeable), while `stats`/`health()`
 # keep their per-instance semantics
 _engine_ids = itertools.count()
+_NO_COUNTS = types.SimpleNamespace(total=None)   # a model with no counters
 
 # --- adaptive-k policy for the fused speculative tick (ISSUE 7). Per
 # request, an EMA of the accepted-draft fraction decides how hard to
@@ -166,7 +169,11 @@ def _on_device(method):
 class PagedKV(NamedTuple):
     """Per-layer paged cache view handed to the attention modules.
 
-    kp/vp: [P, B, kvh, d] physical block pools (this layer's).
+    kp/vp: [P, B, kvh, d] physical block pools (this layer's). A
+    LATENT pool (multi-head latent attention) is ``kp`` alone,
+    [P, B, 1, W]: one row a token (compressed latent, shared rope key,
+    zero padding to whole 128-lane tiles) that is key and value to every
+    head; ``vp`` is None.
     block_tables: [R, M] physical block id per (slot, logical block).
     seq_lens: [R] tokens already cached per slot == this step's write
     position. Shared across layers; XLA dedups the copies.
@@ -180,9 +187,26 @@ class PagedKV(NamedTuple):
     def block_size(self) -> int:
         return self.kp.shape[1]
 
+    @property
+    def pool(self) -> tuple:
+        """This layer's pool arrays, as the engine holds them."""
+        return (self.kp,) if self.vp is None else (self.kp, self.vp)
 
-def paged_decode_write(pk: PagedKV, k, v):
-    """Scatter each row's new K/V (k [R, T, kvh, d]) into its blocks at
+    def scatter(self, bidx, boff, k, v, sel=None):
+        """New rows ``k[sel]`` (and ``v[sel]``; ``v`` None for a latent
+        pool) written at block ``bidx``, offset ``boff``."""
+        def rows(a, pool):
+            return (a if sel is None else a[sel]).astype(pool.dtype)
+        kp = self.kp.at[bidx, boff].set(rows(k, self.kp))
+        if self.vp is None:
+            return self._replace(kp=kp)
+        vp = self.vp.at[bidx, boff].set(rows(v, self.vp))
+        return self._replace(kp=kp, vp=vp)
+
+
+def paged_decode_write(pk: PagedKV, k, v=None):
+    """Scatter each row's new K/V (k [R, T, kvh, d]; ``v`` None for a
+    latent pool, here and in the prefill write) into its blocks at
     positions seq_len .. seq_len+T-1. T == 1 is the plain decode tick;
     T > 1 is the speculative verify (ISSUE 7) writing the probe token
     plus T-1 drafts in one scatter. Positions past a row's ALLOCATED
@@ -198,9 +222,7 @@ def paged_decode_write(pk: PagedKV, k, v):
             r = jnp.arange(R)
             bidx = pk.block_tables[r, pk.seq_lens // B]      # [R]
             boff = pk.seq_lens % B
-            kp = pk.kp.at[bidx, boff].set(k[:, 0].astype(pk.kp.dtype))
-            vp = pk.vp.at[bidx, boff].set(v[:, 0].astype(pk.vp.dtype))
-            return pk._replace(kp=kp, vp=vp)
+            return pk.scatter(bidx, boff, k, v, sel=(slice(None), 0))
         M = pk.block_tables.shape[1]
         r = jnp.arange(R)[:, None]                           # [R, 1]
         pos = pk.seq_lens[:, None] + jnp.arange(T)[None, :]  # [R, T]
@@ -208,12 +230,10 @@ def paged_decode_write(pk: PagedKV, k, v):
         bidx = jnp.where(lb < M,
                          pk.block_tables[r, jnp.clip(lb, 0, M - 1)], 0)
         boff = pos % B
-        kp = pk.kp.at[bidx, boff].set(k.astype(pk.kp.dtype))
-        vp = pk.vp.at[bidx, boff].set(v.astype(pk.vp.dtype))
-        return pk._replace(kp=kp, vp=vp)
+        return pk.scatter(bidx, boff, k, v)
 
 
-def paged_prefill_write(pk: PagedKV, k, v, positions=None,
+def paged_prefill_write(pk: PagedKV, k, v=None, positions=None,
                         garbage_block: int = 0):
     """Scatter a [1, s, kvh, d] prompt's (or prompt chunk's) K/V into
     row 0's blocks; pad positions (>= seq_lens[0]) go to the garbage
@@ -228,9 +248,7 @@ def paged_prefill_write(pk: PagedKV, k, v, positions=None,
         bidx = jnp.where(live, pk.block_tables[0, pos // B],
                          garbage_block)
         boff = pos % B
-        kp = pk.kp.at[bidx, boff].set(k[0].astype(pk.kp.dtype))
-        vp = pk.vp.at[bidx, boff].set(v[0].astype(pk.vp.dtype))
-        return pk._replace(kp=kp, vp=vp)
+        return pk.scatter(bidx, boff, k, v, sel=0)
 
 
 def paged_chunk_attention(q, pk: PagedKV, positions,
@@ -255,7 +273,7 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
         return dense_attention(q, ks, vs, attn_mask=keep[None, None])
 
 
-def paged_decode_route(q, kp) -> str:
+def paged_decode_route(q, kp, latent: bool = False) -> str:
     """Which attention path ``paged_decode_attention`` takes for q
     [R, T, h, d] against pools shaped like ``kp`` [P, B, kvh, d]:
     ``"ragged"`` (the Pallas kernel that walks each row's own pages, the
@@ -264,7 +282,8 @@ def paged_decode_route(q, kp) -> str:
     ``"dense"`` (XLA whole-table gather). Only shapes are read, so a
     caller can ask with the engine's geometry (``PagedEngine.
     decode_route``) and see the choice the traced program made — the
-    shape gates drop to dense silently otherwise."""
+    shape gates drop to dense silently otherwise. ``latent``: ``kp`` is
+    a latent pool (no V pool beside it)."""
     import os
 
     from ..ops.pallas.paged_attention import use_paged_kernel
@@ -273,7 +292,8 @@ def paged_decode_route(q, kp) -> str:
     if mode == "dense" or not use_paged_kernel(q, kp):
         return "dense"
     if mode == "grid" or not pages_fill_lanes(kp):
-        return "grid" if q.shape[1] == 1 else "dense"
+        # the grid kernel is single-query and knows K and V pools only
+        return "grid" if q.shape[1] == 1 and not latent else "dense"
     return "ragged"
 
 
@@ -327,6 +347,42 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
             keep &= kpos > qpos - window
         return dense_attention(q, ks, vs, attn_mask=keep[:, None],
                                scale=scale)
+
+
+def paged_latent_attention(q, pk: PagedKV, v_width: int, scale: float):
+    """Absorbed latent attention of decode (T == 1) and verify (T > 1)
+    rows against a latent pool: q [R, T, h, W] in the pool's own
+    columns (the queries folded through ``W_uk``, the roped part, zeros
+    over the padding), keys the pool's rows, values their first
+    ``v_width`` columns. Returns [R, T, h, v_width], still latent. The
+    ragged kernel walks each row's live pages once; the fallback is the
+    dense whole-table gather, as in ``paged_decode_attention``."""
+    with jax.named_scope("attn"):           # obs.TICK_SCOPES
+        R, T = q.shape[0], q.shape[1]
+        if paged_decode_route(q, pk.kp, latent=True) == "ragged":
+            from ..ops.pallas.ragged_paged_attention import \
+                ragged_paged_attention_pallas
+            out = ragged_paged_attention_pallas(
+                q if T > 1 else q[:, 0], pk.kp, None, pk.block_tables,
+                pk.seq_lens, scale, v_width=v_width)
+            return out if T > 1 else out[:, None]
+        # every head reads the one row: no per-head copy of the keys
+        ks = pk.kp[pk.block_tables]                  # [R, M, B, 1, W]
+        ks = ks.reshape(R, -1, ks.shape[-1])
+        kpos = jnp.arange(ks.shape[1])[None, None, :]
+        qpos = pk.seq_lens[:, None, None] + jnp.arange(T)[None, :, None]
+        scores = jnp.einsum("rthw,rkw->rhtk", q, ks).astype(jnp.float32) \
+            * scale
+        scores = jnp.where((kpos <= qpos)[:, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("rhtk,rkv->rthv", probs, ks[..., :v_width])
+
+
+def paged_chunk_rows(pk: PagedKV):
+    """Row 0's cached rows in order, [1, M*B, kvh, d] of ``kp``: what a
+    prompt chunk attends over after its own rows were written."""
+    rows = pk.kp[pk.block_tables[0]]
+    return rows.reshape(1, -1, *rows.shape[2:])
 
 
 class _Request:
@@ -597,7 +653,13 @@ class _TickPhaseProfile:
 
 
 class PagedEngine:
-    """Continuous-batching serving engine for Llama-family CausalLMs.
+    """Continuous-batching serving engine for causal LMs whose attention
+    takes a ``PagedKV``: the Llama family (a K and a V pool, kv heads x
+    head_dim a token) and the DeepSeek-V2/V3 family (one latent pool,
+    ``kv_lora_rank + qk_rope_head_dim`` columns a token padded to whole
+    lanes). The model says what a cached row is (``paged_cache_rows``);
+    allocation, writes, prefix adoption, spill, upload and reset are one
+    code path over a layer's tuple of pool arrays.
 
     submit() enqueues requests at any time; each step() admits what
     fits (slot + blocks), prefills at most one queued request, and
@@ -712,6 +774,14 @@ class PagedEngine:
         # a fresh engine starts every counter at 0.
         self._obs_labels = {"engine": f"paged{next(_engine_ids)}"}
         reg = obs.registry()
+        # counters the model's layers add up INSIDE a tick (an expert
+        # layer's assignments and experts hit): they ride a spare row of
+        # the token ring, so the drain fetches nothing more for them,
+        # and are counted in ring mode only
+        self._tick_counter_names = tuple(
+            getattr(model, "tick_counters", tuple)())
+        self._tick_counts_seen = np.zeros(
+            (len(self._tick_counter_names),), np.int64)
         # spec_proposed/spec_accepted (ISSUE 7): drafted vs accepted
         # draft tokens — `health()` derives the accept rate from the
         # SAME registry objects a /metrics scrape exports
@@ -736,7 +806,8 @@ class PagedEngine:
                       "ring_cursor_rollovers",
                       "spill_spans", "spill_restores",
                       "spill_restored_tokens",
-                      "spill_restore_failures")}
+                      "spill_restore_failures")
+            + self._tick_counter_names}
         # paged_decode_step_ms is what the host can see of one decode
         # dispatch: with a readback in the tick (host path, ring_mode
         # off) the program's whole run, call to tokens on the host; in
@@ -995,16 +1066,27 @@ class PagedEngine:
     def _zeros(self, shape, dtype):
         return jnp.zeros(shape, dtype, device=self.device)
 
-    def _fresh_device_arrays(self):
-        """New KV pools (one [P, B, kvh, d] K/V pair per layer) and the
-        per-row seen-token masks for the repetition penalty (seeded by
-        the prefill scatter, updated inside the jitted decode step).
-        ``hard_reset`` takes fresh ones too: the old arrays may be
-        donated into a dead or in-flight program."""
+    def _cache_rows(self):
+        """What one cached token is in one layer, asked of the model
+        (``paged_cache_rows``): the (heads, width) of each pool array.
+        Models without the method cache K and V, kv heads x head_dim
+        each."""
+        ask = getattr(self.model, "paged_cache_rows", None)
+        if ask is not None:
+            return ask()
         cfg = self.model.config
-        shape = (self.P, self.B, cfg.num_key_value_heads, cfg.head_dim)
-        pools = [(self._zeros(shape, cfg.dtype),
-                  self._zeros(shape, cfg.dtype))
+        return ((cfg.num_key_value_heads, cfg.head_dim),) * 2
+
+    def _fresh_device_arrays(self):
+        """New pools (per layer one [P, B, heads, width] array for each
+        entry of the model's cached row: a K/V pair, or one latent
+        array) and the per-row seen-token masks for the repetition
+        penalty (seeded by the prefill scatter, updated inside the
+        jitted decode step). ``hard_reset`` takes fresh ones too: the
+        old arrays may be donated into a dead or in-flight program."""
+        cfg = self.model.config
+        pools = [tuple(self._zeros((self.P, self.B) + tuple(r), cfg.dtype)
+                       for r in self._cache_rows())
                  for _ in range(cfg.num_hidden_layers)]
         return pools, self._zeros((self.R, cfg.vocab_size), bool)
 
@@ -1014,10 +1096,12 @@ class PagedEngine:
         shapes): "ragged" or "grid" is a Pallas kernel, "dense" the XLA
         whole-table gather."""
         cfg = self.model.config
+        kp = self.pools[0][0]       # a query is as wide as a cached row
         q = jax.ShapeDtypeStruct(
             (self.R, self._spec_k + 1, cfg.num_attention_heads,
-             cfg.head_dim), cfg.dtype)
-        return paged_decode_route(q, self.pools[0][0])
+             kp.shape[-1]), cfg.dtype)
+        return paged_decode_route(q, kp,
+                                  latent=len(self.pools[0]) == 1)
 
     # ------------------------------------------------------ tick profiler
     @property
@@ -1109,7 +1193,8 @@ class PagedEngine:
 
     # ------------------------------------------------------------ jitted
     def _paged_caches(self, pools, tables, lens):
-        return [PagedKV(kp, vp, tables, lens) for kp, vp in pools]
+        return [PagedKV(p[0], p[1] if len(p) > 1 else None, tables, lens)
+                for p in pools]
 
     def _decode_step(self, params, pools, tables, lens, last_tokens,
                      keys, temps, tks, tps, seen, reps, active):
@@ -1126,7 +1211,7 @@ class PagedEngine:
         # the seen analogue of the authoritative req.key protection
         seen = seen.at[jnp.arange(self.R), nxt].max(active)
         return (nxt, lps, new_keys, seen,
-                [(c.kp, c.vp) for c in new_caches])
+                [c.pool for c in new_caches])
 
     def _decode_step_greedy(self, params, pools, tables, lens,
                             last_tokens, seen, reps, active):
@@ -1146,10 +1231,28 @@ class PagedEngine:
         lps = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
                                   nxt[:, None], axis=-1)[:, 0]
         seen = seen.at[jnp.arange(self.R), nxt].max(active)
-        return nxt, lps, seen, [(c.kp, c.vp) for c in new_caches]
+        return nxt, lps, seen, [c.pool for c in new_caches]
 
     # ------------------------------------------- fused device-resident tick
-    def _fused_epilogue(self, st, new_caches, seen, nxt, lps, new_keys):
+    def _tick_counts(self, st):
+        """The model's own collector (``count_tick``) of the counters it
+        names (``tick_counters``), over the forward traced inside the
+        ``with``; ``.total`` is None for a model that names none, and
+        the program is then what it was."""
+        if not self._tick_counter_names:
+            return contextlib.nullcontext(_NO_COUNTS)
+        return self.model.count_tick(st["active"])
+
+    def _ring_counts(self, ring, counts):
+        """The tick's counters added into the ring's spare row, which
+        the drain reads with the tokens: no array of their own."""
+        if counts.total is None:
+            return ring
+        n = len(self._tick_counter_names)
+        return ring.at[self.R, :n].add(counts.total.astype(ring.dtype))
+
+    def _fused_epilogue(self, st, new_caches, seen, nxt, lps, new_keys,
+                        counts):
         """Device-side tick bookkeeping: advance active rows' lengths /
         last tokens / budgets, fold the emitted token into the seen
         mask, and derive the done flag (eos hit or budget exhausted —
@@ -1174,13 +1277,13 @@ class PagedEngine:
             r = jnp.arange(self.R)
             idx = st["wcur"] % st["ring"].shape[1]
             new_st.update(
-                ring=st["ring"].at[r, idx].set(
-                    jnp.where(act, nxt, st["ring"][r, idx])),
+                ring=self._ring_counts(st["ring"].at[r, idx].set(
+                    jnp.where(act, nxt, st["ring"][r, idx])), counts),
                 rlps=st["rlps"].at[r, idx].set(
                     jnp.where(act, lps, st["rlps"][r, idx])),
                 wcur=st["wcur"] + acti)
         return (nxt, lps, done, seen,
-                [(c.kp, c.vp) for c in new_caches], new_st)
+                [c.pool for c in new_caches], new_st)
 
     def _fused_tick(self, params, pools, seen, st):
         """ONE compiled program for a mixed greedy/sampled tick:
@@ -1194,9 +1297,10 @@ class PagedEngine:
         with jax.named_scope("patch"):
             st = self._apply_patch_queue(st)
         caches = self._paged_caches(pools, st["tables"], st["lens"])
-        logits, new_caches = self.fn(params, st["last"][:, None],
-                                     kv_caches=caches,
-                                     positions=st["lens"][:, None])
+        with self._tick_counts(st) as counts:
+            logits, new_caches = self.fn(params, st["last"][:, None],
+                                         kv_caches=caches,
+                                         positions=st["lens"][:, None])
         with jax.named_scope("penalty"):    # the last position's slice too
             last = logits[:, -1].astype(jnp.float32)
         raw = repetition_penalty_rows(last, seen, st["reps"])
@@ -1205,7 +1309,7 @@ class PagedEngine:
                                                st["tps"])
         with jax.named_scope("epilogue"):
             return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                        new_keys)
+                                        new_keys, counts)
 
     def _fused_tick_greedy(self, params, pools, seen, st):
         """Argmax-only fused tick (same specialization contract as
@@ -1217,9 +1321,10 @@ class PagedEngine:
         with jax.named_scope("patch"):
             st = self._apply_patch_queue(st)
         caches = self._paged_caches(pools, st["tables"], st["lens"])
-        logits, new_caches = self.fn(params, st["last"][:, None],
-                                     kv_caches=caches,
-                                     positions=st["lens"][:, None])
+        with self._tick_counts(st) as counts:
+            logits, new_caches = self.fn(params, st["last"][:, None],
+                                         kv_caches=caches,
+                                         positions=st["lens"][:, None])
         with jax.named_scope("penalty"):    # the last position's slice too
             last = logits[:, -1].astype(jnp.float32)
         raw = repetition_penalty_rows(last, seen, st["reps"])
@@ -1229,7 +1334,7 @@ class PagedEngine:
                                       nxt[:, None], axis=-1)[:, 0]
         with jax.named_scope("epilogue"):
             return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                        st["keys"])
+                                        st["keys"], counts)
 
     def _fused_scan(self, params, pools, seen, st, *, greedy: bool,
                     K: int):
@@ -1328,9 +1433,10 @@ class PagedEngine:
                                jnp.maximum(drafts, 0)], axis=1)
         positions = lens[:, None] + jnp.arange(T)[None, :]
         caches = self._paged_caches(pools, tables, lens)
-        logits, new_caches = self.fn(params, ids, kv_caches=caches,
-                                     positions=positions,
-                                     paged_decode=True)
+        with self._tick_counts(st) as counts:
+            logits, new_caches = self.fn(params, ids, kv_caches=caches,
+                                         positions=positions,
+                                         paged_decode=True)
         logits = logits.astype(jnp.float32)
         if greedy:
             new_keys = subs = st["keys"]
@@ -1405,16 +1511,18 @@ class PagedEngine:
             idx = (st["wcur"][:, None] + jnp.arange(T)[None, :]) % Lr
             emit_win = jnp.arange(T)[None, :] < n_eff[:, None]
             new_st.update(
-                ring=st["ring"].at[r_idx[:, None], idx].set(
-                    jnp.where(emit_win, G, st["ring"][r_idx[:, None],
-                                                      idx])),
+                ring=self._ring_counts(
+                    st["ring"].at[r_idx[:, None], idx].set(
+                        jnp.where(emit_win, G,
+                                  st["ring"][r_idx[:, None], idx])),
+                    counts),
                 rlps=st["rlps"].at[r_idx[:, None], idx].set(
                     jnp.where(emit_win, LP, st["rlps"][r_idx[:, None],
                                                        idx])),
                 wcur=st["wcur"] + n_eff,
                 kprop_last=kprop, macc_last=m)
         return (G, LP, n_eff, kprop, m, done, seen,
-                [(c.kp, c.vp) for c in new_caches], new_st)
+                [c.pool for c in new_caches], new_st)
 
     # --------------------------------- delta slot transitions (ISSUE 14)
     def _mark_dirty(self, slot_id: int):
@@ -1727,8 +1835,12 @@ class PagedEngine:
                 # refresh — a refresh only ever runs with the ring fully
                 # drained (every transition drains first), so resetting
                 # the write cursors cannot lose entries
+                # (a model with tick counters gets one spare row: theirs)
+                spare = 1 if self._tick_counter_names else 0
+                self._tick_counts_seen[:] = 0
                 self._dev.update(
-                    ring=self._zeros((self.R, self._ring_len), jnp.int32),
+                    ring=self._zeros((self.R + spare, self._ring_len),
+                                     jnp.int32),
                     rlps=self._zeros((self.R, self._ring_len), jnp.float32),
                     wcur=self._zeros((self.R,), jnp.int32))
                 if self._spec_k:
@@ -1774,7 +1886,7 @@ class PagedEngine:
                                               tp[None])
         seen_row = seen_row.at[nxt[0]].set(True)
         return (nxt[0], lps[0], new_key[0], seen_row,
-                [(c.kp, c.vp) for c in new_caches])
+                [c.pool for c in new_caches])
 
     def _chunk_prefill(self, params, pools, table_row, ids, start,
                        total_len, key, temp, tk, tp, rep, seen_row, *,
@@ -1807,7 +1919,7 @@ class PagedEngine:
         with jax.named_scope("epilogue"):
             seen_out = seen_row.at[nxt[0]].set(True)
         return (nxt[0], lps[0], new_key[0], seen_row, seen_out,
-                [(c.kp, c.vp) for c in new_caches])
+                [c.pool for c in new_caches])
 
     # ------------------------------------------------------------- host
     @_on_device
@@ -1998,10 +2110,10 @@ class PagedEngine:
                 str(kp.dtype), self.chunk)
 
     def _spill_fetch(self, entry) -> bytes:
-        """D2H gather of a span's KV: every layer's K and V rows for
-        ``entry``'s blocks, packed as one ``(2L, n, B, kvh, d)`` buffer
-        (layer-major, K before V) — the byte layout ``_arena_restore``
-        reverses."""
+        """D2H gather of a span's KV: every layer's pool rows for
+        ``entry``'s blocks, packed as one ``(A*L, n, B, kvh, d)`` buffer
+        (layer-major; A arrays a layer: K before V, or the one latent
+        array) — the byte layout ``_arena_restore`` reverses."""
         idx = np.asarray(entry, np.int32)
         stacked = jnp.stack([p[idx] for pair in self.pools
                              for p in pair])
@@ -2094,13 +2206,12 @@ class PagedEngine:
 
     def _spill_upload(self, pools, idx, data):
         """spill_reupload_program: scatter a restored span's packed KV
-        ``(2L, npad, B, kvh, d)`` into block rows ``idx`` of every
+        ``(A*L, npad, B, kvh, d)`` into block rows ``idx`` of every
         layer's pools. Pad rows target the garbage block 0."""
-        out = []
-        for l, (kp, vp) in enumerate(pools):
-            out.append((kp.at[idx].set(data[2 * l]),
-                        vp.at[idx].set(data[2 * l + 1])))
-        return out
+        A = len(pools[0])
+        return [tuple(p.at[idx].set(data[A * l + a])
+                      for a, p in enumerate(layer))
+                for l, layer in enumerate(pools)]
 
     def _arena_restore(self, ids: List[int]):
         """Admission-side arena probe: if the arena holds a strictly
@@ -2141,14 +2252,14 @@ class PagedEngine:
         payload, rec_tokens = got
         kp = self.pools[0][0]
         _, B, kvh, d = kp.shape
-        L = len(self.pools)
+        L = len(self.pools) * len(self.pools[0])     # arrays in all
         rec_blocks = rec_tokens // B
-        expect = 2 * L * rec_blocks * B * kvh * d * kp.dtype.itemsize
+        expect = L * rec_blocks * B * kvh * d * kp.dtype.itemsize
         if len(payload) != expect or rec_blocks < n_blocks:
             self._count("spill_restore_failures")  # tokens/geometry skew
             return False
         data = np.frombuffer(payload, dtype=kp.dtype).reshape(
-            2 * L, rec_blocks, B, kvh, d)[:, :n_blocks]
+            L, rec_blocks, B, kvh, d)[:, :n_blocks]
         blocks: List[int] = []
         for _ in range(n_blocks):
             b = self._alloc_block()      # may cascade-spill more spans
@@ -2163,7 +2274,7 @@ class PagedEngine:
             npad *= 2
         idx = np.zeros((npad,), np.int32)          # pad -> garbage block
         idx[:n_blocks] = blocks
-        padded = np.zeros((2 * L, npad, B, kvh, d), kp.dtype)
+        padded = np.zeros((L, npad, B, kvh, d), kp.dtype)
         padded[:, :n_blocks] = data
         self.dispatch_count += 1
         self._count("dispatches")
@@ -3029,6 +3140,16 @@ class PagedEngine:
             self._h_decode.observe((time.perf_counter() - t0) * 1e3)
             br.switch("commit")
             ring, rlps, wcur, act_now = vals[:4]
+            if self._tick_counter_names:
+                # cumulative on the device, in int32: count what was
+                # added since the last drain, whatever has wrapped
+                now = ring[self.R, :len(self._tick_counter_names)] \
+                    .astype(np.int64)
+                for name, d in zip(self._tick_counter_names,
+                                   (now - self._tick_counts_seen)
+                                   % (1 << 32)):
+                    self._count(name, int(d))
+                self._tick_counts_seen = now
             kprop = macc = None
             if spec:
                 kprop, macc = vals[4], vals[5]

@@ -14,6 +14,14 @@ MLA, TPU-native:
   chip hold long contexts, and the whole point of MLA.
 - RoPE uses DeepSeek's INTERLEAVED (complex-pair) convention, applied
   only to the decoupled q_pe / single-head k_pe dims.
+- SERVING (``PagedEngine``): the cache is a LATENT PAGED POOL, one row a
+  token and layer: latent, roped key, zeros up to whole 128-lane tiles
+  (576 -> 640 columns at the published widths), laid out as the ragged
+  kernel reads it. A prompt chunk writes its rows and attends in the
+  expanded form over the row's gathered latents; decode and verify rows
+  attend in the absorbed form through the kernel. With
+  ``experts_held`` set the expert layers are one expert-parallel rank's
+  share (``parallel.moe.ExpertShareMLP``) and nothing is dropped.
 """
 from __future__ import annotations
 
@@ -27,7 +35,8 @@ from .. import nn
 from ..nn.layer import Layer
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding)
-from ..parallel.moe import MoEMLP
+from ..parallel.moe import (SERVING_COUNTERS, ExpertShareMLP, MoEMLP,
+                            collect_counts)
 from ..parallel.sharding import constraint
 from .base import CausalLMBase
 from .llama import (LlamaConfig, LlamaMLP, causal_lm_loss,  # noqa: F401
@@ -68,6 +77,11 @@ class DeepseekV2Config(LlamaConfig):
     norm_topk_prob: bool = False           # normalize selected gates to 1
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.001
+    # serving one expert-parallel rank: the routed experts
+    # first_expert .. first_expert + experts_held - 1 live here (None =
+    # the whole layer, with training's capacity dispatch)
+    first_expert: int = 0
+    experts_held: Optional[int] = None
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-6
     attention_bias: bool = False
@@ -80,6 +94,13 @@ class DeepseekV2Config(LlamaConfig):
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_row_width(self) -> int:
+        """Columns of one cached row in the paged pool: the latent and
+        the roped key, padded with zeros to whole 128-lane tiles (the
+        kernel fetches a page as one slab of whole tiles)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
 def deepseek_v2_tiny(**overrides) -> DeepseekV2Config:
@@ -193,15 +214,91 @@ class MLAttention(Layer):
             c.shape[0], c.shape[1], h, cfg.qk_nope_head_dim + cfg.v_head_dim)
         return kv[..., :cfg.qk_nope_head_dim], kv[..., cfg.qk_nope_head_dim:]
 
+    def _absorbed(self, q_nope, attend):
+        """Attention in the ABSORBED form: the queries folded through
+        ``W_uk`` into latent space, ``attend(q_lat) -> o_lat`` against
+        cached latents (a static cache or the paged pool), the result
+        through ``W_uv``. q_nope [b, s, h, nope] -> [b, s, h, v]."""
+        cfg = self.config
+        wkv = self.kv_b_proj.weight.reshape(
+            cfg.kv_lora_rank, cfg.num_attention_heads,
+            cfg.qk_nope_head_dim + cfg.v_head_dim)
+        with jax.named_scope("absorb"):     # obs.TICK_SCOPES
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope,
+                               wkv[..., :cfg.qk_nope_head_dim])
+        o_lat = attend(q_lat)
+        with jax.named_scope("absorb"):
+            return jnp.einsum("bshr,rhv->bshv", o_lat,
+                              wkv[..., cfg.qk_nope_head_dim:])
+
+    def _expanded(self, q_nope, q_pe, c, k_pe, **mask):
+        """Attention in the EXPANDED form: per-head keys and values made
+        from the latents c [b, t, r] and the shared roped key k_pe
+        [b, t, rope]."""
+        from ..ops.attention import dense_attention
+        k_nope, v = self._expand(c)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
+                                      k_nope.shape[:3] + k_pe.shape[-1:])],
+            axis=-1)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        return dense_attention(q, k, v, scale=self.scale, **mask)
+
+    def _paged(self, pk, q_nope, q_pe, c, k_pe, positions, paged_chunk,
+               paged_decode):
+        """Serving over the latent paged pool (generation/paged.py)."""
+        from ..generation.paged import (paged_chunk_rows,
+                                        paged_decode_write,
+                                        paged_latent_attention,
+                                        paged_prefill_write)
+        cfg = self.config
+        r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        pad = cfg.latent_row_width - r - rope
+
+        def row(lat, pe):       # the pool's columns: latent, key, zeros
+            parts = [lat, pe.astype(lat.dtype)]
+            if pad:
+                parts.append(jnp.zeros(lat.shape[:-1] + (pad,), lat.dtype))
+            return jnp.concatenate(parts, axis=-1)
+
+        new = row(c, k_pe)[:, :, None, :]                   # [b, s, 1, W]
+        if q_nope.shape[1] == 1 or paged_decode:
+            pk = paged_decode_write(pk, new)
+            out = self._absorbed(
+                q_nope, lambda q_lat: paged_latent_attention(
+                    row(q_lat, q_pe), pk, r, self.scale))
+        elif paged_chunk:
+            pk = paged_prefill_write(pk, new, positions=positions[0])
+            with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+                rows = paged_chunk_rows(pk)[:, :, 0]        # [1, T, W]
+                keep = jnp.arange(rows.shape[1])[None, :] \
+                    <= positions[0][:, None]                # [s, T]
+                out = self._expanded(q_nope, q_pe, rows[..., :r],
+                                     rows[..., r:r + rope],
+                                     attn_mask=keep[None, None])
+        else:
+            pk = paged_prefill_write(pk, new)
+            out = self._expanded(q_nope, q_pe, c, k_pe, causal=True)
+        return out, pk
+
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None):
+                attn_mask=None, attn_start=None, paged_chunk: bool = False,
+                paged_decode: bool = False):
         cfg = self.config
         b, s, _ = x.shape
         h = cfg.num_attention_heads
-        q_nope, q_pe = self._queries(x, positions)
-        c, k_pe = self._latents(x, positions)
+        with jax.named_scope("qkv"):        # obs.TICK_SCOPES
+            q_nope, q_pe = self._queries(x, positions)
+            c, k_pe = self._latents(x, positions)
 
+        new_cache = None
         if kv_cache is not None:
+            from ..generation.paged import PagedKV
+        if kv_cache is not None and isinstance(kv_cache, PagedKV):
+            out, new_cache = self._paged(kv_cache, q_nope, q_pe, c, k_pe,
+                                         positions, paged_chunk,
+                                         paged_decode)
+        elif kv_cache is not None:
             cc, cpe = kv_cache  # [b, T, r], [b, T, rope_d]
             cc = jax.lax.dynamic_update_slice(cc, c.astype(cc.dtype),
                                               (0, cache_index, 0))
@@ -209,16 +306,6 @@ class MLAttention(Layer):
                                                (0, cache_index, 0))
             new_cache = (cc, cpe)
             T = cc.shape[1]
-            wkv = self.kv_b_proj.weight.reshape(
-                cfg.kv_lora_rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim)
-            w_uk = wkv[..., :cfg.qk_nope_head_dim]   # [r, h, nope]
-            w_uv = wkv[..., cfg.qk_nope_head_dim:]   # [r, h, v]
-            # ABSORBED decode: queries project into latent space once,
-            # attention runs over the compressed cache directly
-            q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_uk)
-            scores = (jnp.einsum("bshr,btr->bhst", q_lat, cc)
-                      + jnp.einsum("bshd,btd->bhst", q_pe, cpe)
-                      ).astype(jnp.float32) * self.scale
             kpos = jnp.arange(T)[None, None, None, :]
             qpos = cache_index + jnp.arange(s)[None, None, :, None]
             keep = kpos <= qpos
@@ -229,22 +316,23 @@ class MLAttention(Layer):
                 pad_ok = kpos >= attn_start[:, None, None, None]
                 self_ok = kpos == qpos
                 keep = keep & (pad_ok | self_ok)
-            scores = jnp.where(keep, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            o_lat = jnp.einsum("bhst,btr->bshr", probs, cc)
-            out = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
+
+            def attend(q_lat):
+                # attention runs over the compressed cache directly
+                scores = (jnp.einsum("bshr,btr->bhst", q_lat, cc)
+                          + jnp.einsum("bshd,btd->bhst", q_pe, cpe)
+                          ).astype(jnp.float32) * self.scale
+                scores = jnp.where(keep, scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                return jnp.einsum("bhst,btr->bshr", probs, cc)
+
+            out = self._absorbed(q_nope, attend)
         else:
-            new_cache = None
-            k_nope, v = self._expand(c)
-            k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
-                                          (b, s, h, cfg.qk_rope_head_dim))],
-                axis=-1)
-            q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            from ..ops.attention import dense_attention
-            out = dense_attention(q, k, v, causal=attn_mask is None,
-                                  attn_mask=attn_mask, scale=self.scale)
-        out = self.o_proj(out.reshape(b, s, h * cfg.v_head_dim))
+            out = self._expanded(q_nope, q_pe, c, k_pe,
+                                 causal=attn_mask is None,
+                                 attn_mask=attn_mask)
+        with jax.named_scope("o_proj"):     # obs.TICK_SCOPES
+            out = self.o_proj(out.reshape(b, s, h * cfg.v_head_dim))
         return (out, new_cache) if kv_cache is not None else out
 
 
@@ -258,34 +346,50 @@ class DeepseekV2DecoderLayer(Layer):
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size,
                                                    config.rms_norm_eps)
         self.is_dense = layer_idx < config.first_k_dense_replace
+        moe = dict(num_experts=config.num_experts,
+                   top_k=config.num_experts_per_tok,
+                   num_shared_experts=config.num_shared_experts,
+                   shared_intermediate_size=(config.moe_intermediate_size
+                                             * config.num_shared_experts),
+                   routed_scaling_factor=config.routed_scaling_factor,
+                   norm_topk_prob=config.norm_topk_prob,
+                   n_group=config.n_group, topk_group=config.topk_group,
+                   scoring=config.scoring,
+                   group_score_mode=config.group_score_mode)
         if self.is_dense:
             self.mlp = LlamaMLP(config)
+        elif config.experts_held is not None:
+            self.mlp = ExpertShareMLP(
+                config.hidden_size, config.moe_intermediate_size,
+                first_expert=config.first_expert,
+                experts_held=config.experts_held, **moe)
         else:
             self.mlp = MoEMLP(
                 config.hidden_size, config.moe_intermediate_size,
-                num_experts=config.num_experts,
-                top_k=config.num_experts_per_tok,
                 capacity_factor=config.capacity_factor,
-                num_shared_experts=config.num_shared_experts,
-                shared_intermediate_size=(config.moe_intermediate_size
-                                          * config.num_shared_experts),
-                aux_loss_weight=config.aux_loss_weight,
-                routed_scaling_factor=config.routed_scaling_factor,
-                norm_topk_prob=config.norm_topk_prob,
-                n_group=config.n_group, topk_group=config.topk_group,
-                scoring=config.scoring,
-                group_score_mode=config.group_score_mode)
+                aux_loss_weight=config.aux_loss_weight, **moe)
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None):
-        attn = self.self_attn(self.input_layernorm(x), positions,
+                attn_mask=None, attn_start=None, paged_chunk: bool = False,
+                paged_decode: bool = False):
+        # the named scopes are obs.TICK_SCOPES, as in llama.py
+        with jax.named_scope("norm"):
+            h = self.input_layernorm(x)
+        attn = self.self_attn(h, positions,
                               kv_cache=kv_cache, cache_index=cache_index,
-                              attn_mask=attn_mask, attn_start=attn_start)
+                              attn_mask=attn_mask, attn_start=attn_start,
+                              paged_chunk=paged_chunk,
+                              paged_decode=paged_decode)
         new_cache = None
         if kv_cache is not None:
             attn, new_cache = attn
-        x = x + attn
-        x = x + self.mlp(self.post_attention_layernorm(x))
+        with jax.named_scope("o_proj"):
+            x = x + attn
+        with jax.named_scope("norm"):
+            h = self.post_attention_layernorm(x)
+        # an expert layer's parts have scopes of their own inside this
+        with jax.named_scope("mlp"):
+            x = x + self.mlp(h)
         x = constraint(x, ("dp", "fsdp"), "sp", None)
         return (x, new_cache) if kv_cache is not None else x
 
@@ -343,7 +447,8 @@ class DeepseekV2Model(Layer):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                return_prenorm: bool = False):
+                return_prenorm: bool = False, paged_chunk: bool = False,
+                paged_decode: bool = False):
         b, s = input_ids.shape
         if positions is None:
             start = cache_index if cache_index is not None else 0
@@ -351,19 +456,23 @@ class DeepseekV2Model(Layer):
             if attn_start is not None:
                 # RoPE position 0 sits at each row's first REAL token
                 positions = jnp.maximum(positions - attn_start[:, None], 0)
-        x = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):      # obs.TICK_SCOPES
+            x = self.embed_tokens(input_ids)
         x = constraint(x, ("dp", "fsdp"), "sp", None)
         new_caches = [] if kv_caches is not None else None
         for i, layer in enumerate(self.layers):
             if kv_caches is not None:
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
                               cache_index=cache_index, attn_mask=attn_mask,
-                              attn_start=attn_start)
+                              attn_start=attn_start,
+                              paged_chunk=paged_chunk,
+                              paged_decode=paged_decode)
                 new_caches.append(nc)
             else:
                 x = layer(x, positions, attn_mask=attn_mask)
         pre = x  # the MTP modules consume the PRE-final-norm hidden
-        x = self.norm(x)
+        with jax.named_scope("head"):
+            x = self.norm(x)
         if return_prenorm:
             return (x, pre, new_caches) if kv_caches is not None \
                 else (x, pre)
@@ -406,9 +515,26 @@ class DeepseekV2ForCausalLM(CausalLMBase):
                 jnp.zeros((batch_size, max_len, cfg.qk_rope_head_dim),
                           dtype))
 
+    def paged_cache_rows(self):
+        """What ``PagedEngine`` caches a token and layer, as (heads,
+        width) of each pool array: ONE latent row."""
+        return ((1, self.config.latent_row_width),)
+
+    def tick_counters(self):
+        """Counters the expert layers add up inside a serving tick."""
+        return SERVING_COUNTERS if self.config.experts_held is not None \
+            else ()
+
+    def count_tick(self, rows):
+        """Context manager: ``.total`` is the ``tick_counters`` of the
+        forward traced inside it, in that order, counting the live
+        ``rows`` [b] only."""
+        return collect_counts(rows)
+
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                return_mtp: bool = False, return_prenorm: bool = False):
+                return_mtp: bool = False, return_prenorm: bool = False,
+                paged_chunk: bool = False, paged_decode: bool = False):
         """``return_mtp`` (training-time, no cache): additionally return
         the list of MTP depth logits — depth k's logits[:, i] predict
         token i+2+k. The MTP chain consumes the pre-final-norm hidden
@@ -454,7 +580,8 @@ class DeepseekV2ForCausalLM(CausalLMBase):
             return logits, mtp_logits
         out = self.model(input_ids, positions, kv_caches, cache_index,
                          attn_mask, attn_start=attn_start,
-                         return_prenorm=return_prenorm)
+                         return_prenorm=return_prenorm,
+                         paged_chunk=paged_chunk, paged_decode=paged_decode)
         caches = None
         pre = None
         if kv_caches is not None:
@@ -464,7 +591,8 @@ class DeepseekV2ForCausalLM(CausalLMBase):
                 out, caches = out
         elif return_prenorm:
             out, pre = out
-        logits = self.lm_head(out).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = self.lm_head(out).astype(jnp.float32)
         if return_prenorm:
             # decode-time MTP-as-draft needs the pre-final-norm hidden
             # alongside the logits (generation/speculative.py)

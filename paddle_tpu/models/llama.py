@@ -169,7 +169,10 @@ def yarn_params(dim: int, theta: float, rope_scaling: "Dict[str, Any]",
     inv_inter = 1.0 / (factor * pos_freqs)
     extra_factor = 1.0 - ramp
     inv_freq = inv_inter * (1 - extra_factor) + inv_extra * extra_factor
-    return jnp.asarray(inv_freq), float(attention_factor)
+    # numpy, not a jax array: a model may be constructed under a trace
+    # (jax.eval_shape, to learn its parameter shapes), and a constant
+    # kept on the layer must not be that trace's
+    return inv_freq.astype(np.float32), float(attention_factor)
 
 
 ROPE_SCALING_TYPES = ("llama3", "yarn", "linear", "default")

@@ -166,5 +166,8 @@ def use_paged_kernel(q, kp) -> bool:
         return False
     if interpret_enabled():
         return True
-    return d in (64, 128, 256) and B % 8 == 0 and (
-        d % 128 == 0 or kvh == 1)
+    if B % 8:
+        return False
+    if kvh == 1 and d % 128 == 0:
+        return True     # incl. a latent pool: one wide row a token
+    return d in (64, 128, 256) and (d % 128 == 0 or kvh == 1)

@@ -31,6 +31,15 @@ Pool layout: the [P, B, kvh, d] pools are handed over as [P, B, kvh*d],
 so a page is one contiguous ``(B, kvh*d)`` slab. On the chip that
 reshape is a real copy of the pool (scope ``kv_layout``: PERF.md section
 5), not a free view.
+
+Latent mode (``vp`` None, ``v_width`` given): absorbed multi-head latent
+attention. The pool holds ONE row a token, ``[P, B, 1, W]`` (the
+compressed latent, the shared rope key, zero padding up to a multiple of
+128 lanes), which every query head reads: one kv "head", the query heads
+its group. The values are the first ``v_width`` columns of the keys, so
+the kernel keeps no V scratch and a page is fetched once. The pool is
+allocated in the kernel's layout (kvh = 1: the reshape is a bitcast),
+so nothing is copied under ``kv_layout``.
 """
 from __future__ import annotations
 
@@ -69,12 +78,17 @@ def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:
     return min(pps, M)
 
 
-def _ragged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sems, *, scale, bs, pps, window, group,
-                   q_len):
+def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
+                   group, q_len, v_width):
+    if v_width is None:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+    else:       # latent mode: values are a column prefix of the keys
+        k_hbm, o_ref, k_buf, sems = refs
+        v_hbm, v_buf = None, k_buf
     r = pl.program_id(0)
     M = tbl_ref.shape[1]
     kvh, gp, d = q_ref.shape[1:]
+    dv = d if v_width is None else v_width
     tc = pps * bs
     # query t of the row sits at seq_len + t: live pages cover the LAST
     # query's tokens, a sliding window's front follows the FIRST
@@ -97,8 +111,10 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
             fn(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, dst],
                                      sems.at[slot, 0]))
-            fn(pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[slot, dst],
-                                     sems.at[slot, 1]))
+            if v_hbm is not None:
+                fn(pltpu.make_async_copy(v_hbm.at[phys],
+                                         v_buf.at[slot, dst],
+                                         sems.at[slot, 1]))
             return carry
         lax.fori_loop(0, jnp.minimum(hi - first, pps), page, 0)
 
@@ -126,7 +142,8 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         for h, (m_prev, l_prev, acc) in enumerate(carry):
             cols = slice(h * d, (h + 1) * d)
             k = k_buf[slot, :, cols]                     # [tc, d]
-            v = v_buf[slot, :, cols]
+            v = v_buf[slot, :, cols] if v_width is None \
+                else k[:, :dv]
             s = lax.dot_general(q_ref[0, h], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
             s = jnp.where(keep, s, NEG_INF)
@@ -143,26 +160,30 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     init = (jnp.full((gp, 1), NEG_INF, jnp.float32),
             jnp.zeros((gp, 1), jnp.float32),
-            jnp.zeros((gp, d), jnp.float32))
+            jnp.zeros((gp, dv), jnp.float32))
     heads = lax.fori_loop(0, n_blocks, block, (init,) * kvh)
     for h, (_, l, acc) in enumerate(heads):
         o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
-                                  scale, window=None):
+                                  scale, window=None, v_width=None):
     """q [R, h, d] (single-query decode) OR [R, T, h, d] (multi-query
     speculative verify rows: query t of row r sits at position
     seq_lens[r] + t and attends tokens 0..seq_lens[r]+t); kp/vp
     [P, B, kvh, d] physical pools; block_tables [R, M]; seq_lens [R].
-    Returns q's shape.
+    Returns q's shape. Latent mode: ``vp`` None and ``v_width`` the
+    number of leading key columns that are the values; returns
+    [..., h, v_width].
 
     Multi-query rides the same walk: the q tile packs T positions x
     `group` heads into the sublane dim (padded to 8), so each page is
     still read once per row — the verify's extra queries are matmul
     rows, not extra HBM traffic."""
+    if (vp is None) != (v_width is not None):
+        raise ValueError("latent mode takes vp=None AND v_width")
     return _attend(q, kp, vp, block_tables, seq_lens, scale=float(scale),
-                   window=window, interpret=_interpret())
+                   window=window, interpret=_interpret(), v_width=v_width)
 
 
 # jitted so that a program of L layers traces the kernel body once, not
@@ -170,8 +191,10 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
 # Inlined into the caller's jaxpr, so the lowered program and the names
 # of its ops (obs.TICK_SCOPES) are what they are without the jit.
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("scale", "window", "interpret"))
-def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret):
+                   static_argnames=("scale", "window", "interpret",
+                                    "v_width"))
+def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret,
+            v_width=None):
     multi = q.ndim == 4
     if multi:
         R, T, h, d = q.shape
@@ -180,6 +203,8 @@ def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret):
         T = 1
     P, B, kvh, _ = kp.shape
     M = block_tables.shape[1]
+    latent = v_width is not None
+    dv = v_width if latent else d
     group = h // kvh
     rows = T * group
     gp = max(8, -(-rows // 8) * 8)
@@ -196,36 +221,32 @@ def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret):
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
 
     kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, pps=pps,
-                               window=window, group=group, q_len=T)
+                               window=window, group=group, q_len=T,
+                               v_width=v_width)
     with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
-        kc = kp.reshape(P, B, kvh * d)
-        vc = vp.reshape(P, B, kvh * d)
-    row_block = pl.BlockSpec((1, kvh, gp, d),
-                             lambda r, tbl, lens: (r, 0, 0, 0))
+        pools = [p.reshape(P, B, kvh * d)
+                 for p in ((kp,) if latent else (kp, vp))]
     out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R,),
-            in_specs=[
-                row_block,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=row_block,
-            scratch_shapes=[
-                pltpu.VMEM((2, pps * B, kvh * d), kp.dtype),
-                pltpu.VMEM((2, pps * B, kvh * d), vp.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ],
+            in_specs=[pl.BlockSpec((1, kvh, gp, d),
+                                   lambda r, tbl, lens: (r, 0, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((1, kvh, gp, dv),
+                                   lambda r, tbl, lens: (r, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, pps * B, kvh * d), p.dtype)
+                            for p in pools]
+            + [pltpu.SemaphoreType.DMA((2, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((R, kvh, gp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, kvh, gp, dv), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
-      qg, kc, vc)
+      qg, *pools)
     out = out[:, :, :rows, :]
     if not multi:
-        return out.reshape(R, h, d)
-    return out.reshape(R, kvh, T, group, d).transpose(0, 2, 1, 3, 4) \
-              .reshape(R, T, h, d)
+        return out.reshape(R, h, dv)
+    return out.reshape(R, kvh, T, group, dv).transpose(0, 2, 1, 3, 4) \
+              .reshape(R, T, h, dv)

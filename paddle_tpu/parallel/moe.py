@@ -14,7 +14,9 @@ x E) plus optional router z-loss; or "loss-free" bias balancing
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -214,3 +216,137 @@ class MoEMLP(Layer):
         err = load - self.top_k / self.num_experts
         self._buffers["expert_bias"] = self.expert_bias - lr * jnp.sign(err)
         return self.expert_bias
+
+
+# ------------------------------------------------------------------ serving
+# what an ExpertShareMLP counts inside a serving tick, in this order
+SERVING_COUNTERS = ("moe_layer_ticks", "moe_local_assignments",
+                    "moe_experts_hit")
+_collecting = threading.local()     # engines trace on threads of their own
+
+
+class _Counts:
+    def __init__(self, rows):
+        self.rows, self.total = rows, None
+
+    def add(self, v):
+        self.total = v if self.total is None else self.total + v
+
+
+@contextlib.contextmanager
+def collect_counts(rows):
+    """Sum, over the expert layers traced inside the ``with``, their
+    ``SERVING_COUNTERS`` of this forward (``.total``: an int32 vector,
+    or None where no such layer ran). ``rows`` [b] says which rows of
+    the batch are live; the others are computed and not counted."""
+    prev = getattr(_collecting, "box", None)
+    box = _collecting.box = _Counts(rows)
+    try:
+        yield box
+    finally:
+        _collecting.box = prev
+
+
+class ExpertShareMLP(Layer):
+    """``experts_held`` routed experts, ``first_expert`` onwards, of a
+    layer of ``num_experts``, plus the shared experts: what one rank of
+    an expert-parallel deployment holds. Parameter names are
+    ``MoEMLP``'s; the router and its selection bias keep all
+    ``num_experts`` columns, the stacked expert weights hold the share.
+
+    ``routed`` is the sum over each token's chosen experts THAT ARE HELD,
+    gated as the whole layer gates them (normalised over all ``top_k``
+    chosen, held or not). What the absent experts add is left out:
+    summed over the ranks' ``routed`` parts, plus ``shared_out`` once,
+    it is the whole layer (tests/test_expert_share.py)."""
+
+    def __init__(self, hidden_size: int, intermediate_size: int,
+                 num_experts: int, top_k: int, first_expert: int,
+                 experts_held: int, num_shared_experts: int = 0,
+                 shared_intermediate_size: Optional[int] = None,
+                 norm_topk_prob: bool = False,
+                 routed_scaling_factor: float = 1.0,
+                 n_group: int = 1, topk_group: int = 1,
+                 scoring: str = "softmax",
+                 group_score_mode: str = "max", name=None):
+        super().__init__(name)
+        if not 0 <= first_expert <= num_experts - experts_held:
+            raise ValueError(
+                f"experts {first_expert}..{first_expert + experts_held - 1}"
+                f" are not inside a layer of {num_experts}")
+        self.hidden_size = hidden_size
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first_expert, self.experts_held = first_expert, experts_held
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.n_group, self.topk_group = n_group, topk_group
+        self.scoring, self.group_score_mode = scoring, group_score_mode
+        E, n, h, m = num_experts, experts_held, hidden_size, \
+            intermediate_size
+        init = I.XavierNormal()
+        self.gate = Parameter(init(next_key(), (h, E)))
+        self.w_gate = Parameter(init(next_key(), (n, h, m)))
+        self.w_up = Parameter(init(next_key(), (n, h, m)))
+        self.w_down = Parameter(init(next_key(), (n, m, h)))
+        # a parameter here (MoEMLP's buffer of the same name): serving
+        # has no gradient path to keep it out of, and the served
+        # weights are then ONE dict, selection bias included
+        self.expert_bias = Parameter(jnp.zeros((E,)))
+        self.shared = bool(num_shared_experts)
+        if self.shared:
+            sm = shared_intermediate_size or m * num_shared_experts
+            self.shared_gate_proj = Parameter(init(next_key(), (h, sm)))
+            self.shared_up_proj = Parameter(init(next_key(), (h, sm)))
+            self.shared_down_proj = Parameter(init(next_key(), (sm, h)))
+
+    def route(self, xt):
+        """xt [T, h] -> (expert ids [T, k], gates [T, k] float32). The
+        router runs in float32 at the highest matmul precision from the
+        hidden state it is given: a token's 8th and 9th scores are often
+        closer than a bfloat16 product resolves."""
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         self.gate.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs, ids = _select_topk(
+            logits, self.top_k, self.expert_bias.astype(jnp.float32),
+            self.n_group, self.topk_group, self.scoring,
+            self.group_score_mode)
+        gates = jnp.take_along_axis(probs, ids, axis=-1)
+        if self.norm_topk_prob:
+            gates = gates / jnp.maximum(
+                jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+        return ids, gates * self.routed_scaling_factor
+
+    def routed(self, xt, ids, gates):
+        """The held experts' part for tokens xt [T, h]."""
+        held = jnp.arange(self.first_expert,
+                          self.first_expert + self.experts_held)
+        chose = ids[:, :, None] == held[None, None, :]       # [T, k, n]
+        w = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
+        box = getattr(_collecting, "box", None)
+        if box is not None:
+            live = jnp.repeat(box.rows, xt.shape[0] // box.rows.shape[0])
+            chose = chose & live[:, None, None]
+            box.add(jnp.stack([
+                jnp.int32(1), jnp.sum(chose, dtype=jnp.int32),
+                jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32)]))
+        g = jnp.einsum("th,nhm->ntm", xt, self.w_gate)
+        u = jnp.einsum("th,nhm->ntm", xt, self.w_up)
+        a = (F.silu(g) * u).astype(jnp.float32) * w.T[:, :, None]
+        return jnp.einsum("ntm,nmh->th", a.astype(xt.dtype), self.w_down)
+
+    def shared_out(self, xt):
+        sg = F.silu(xt @ self.shared_gate_proj) * (xt @ self.shared_up_proj)
+        return sg @ self.shared_down_proj
+
+    def forward(self, x):
+        # the scopes are obs.TICK_SCOPES
+        xt = x.reshape(-1, self.hidden_size)
+        with jax.named_scope("router"):
+            ids, gates = self.route(xt)
+        with jax.named_scope("experts"):
+            y = self.routed(xt, ids, gates)
+        if self.shared:
+            with jax.named_scope("shared_expert"):
+                y = y + self.shared_out(xt)
+        return y.reshape(x.shape)

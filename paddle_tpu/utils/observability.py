@@ -951,13 +951,20 @@ TICK_SCOPES = (
     "patch",       # staged slot transitions scattered into the state
     "embed",
     "norm",        # both RMSNorms of a layer
-    "qkv",         # projections, biases, rope
+    "qkv",         # projections, biases, rope (latent attention: the
+                   # low-rank q and kv projections and their norms)
+    "absorb",      # latent attention: queries through W_uk, output
+                   # through W_uv, either side of the kernel
     "kv_write",    # the new rows scattered into the pool
     "kv_layout",   # the pool viewed [P, B, kvh*d] for the kernel
     "attn",        # decode: schedule build and kernel
     "chunk_attn",  # chunk: gather of the row's blocks, masked attention
+                   # (latent attention: their expansion to K and V too)
     "o_proj",
-    "mlp",
+    "mlp",         # a dense FFN; of an expert layer the residual add
+    "router",      # expert layer: float32 scores, groups, top-k, gates
+    "experts",     # expert layer: the held routed experts
+    "shared_expert",
     "head",        # final norm and lm_head
     "penalty",     # repetition penalty
     "sample",      # sample_token_rows / the greedy argmax + log-softmax

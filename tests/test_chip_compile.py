@@ -62,3 +62,24 @@ def test_ragged_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
         q, arr((P, B, kvh, d)), arr((P, B, kvh, d)),
         arr((R, M), jnp.int32), arr((R,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("R,T", [(64, 1), (64, 2)])
+def test_latent_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
+                                          monkeypatch, R, T):
+    """The latent mode at GigaChat3.1 / DeepSeek-V3's geometry: 64 query
+    heads over one 640-column row a token (512 latent + 64 roped + 64
+    zeros), values the first 512 columns, 8193 pages of 16."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_pallas
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q = arr((R, 64, 640) if T == 1 else (R, T, 64, 640))
+    compiled = jax.jit(
+        lambda q, kp, tbl, lens: ragged_paged_attention_pallas(
+            q, kp, None, tbl, lens, 192 ** -0.5, v_width=512)).lower(
+        q, arr((8193, 16, 1, 640)), arr((R, 128), jnp.int32),
+        arr((R,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

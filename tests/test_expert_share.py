@@ -1,0 +1,163 @@
+"""ISSUE 26: the expert layer of one expert-parallel rank, for serving
+(``parallel.moe.ExpertShareMLP``), at tiny widths in float32.
+
+- THE SHARES ADD UP: for 4 shares of a 16-expert layer, the routed
+  parts each share computes, plus the shared expert counted once, equal
+  the uncut layer: the layer written out plainly here, the one share
+  that holds all 16, and training's ``MoEMLP`` made dropless.
+- NOTHING IS DROPPED: with every token sent to one expert the share
+  still matches the plain layer, where the capacity dispatch does not.
+- the counters a serving tick reads count what they say.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.parallel.moe import (SERVING_COUNTERS, ExpertShareMLP,
+                                     MoEMLP, collect_counts)
+
+H, M, E, K, T = 32, 16, 16, 4, 24
+ROUTERS = {
+    "softmax": dict(scoring="softmax", norm_topk_prob=False),
+    "sigmoid-groups-bias": dict(scoring="sigmoid", n_group=4, topk_group=2,
+                                group_score_mode="top2_sum",
+                                norm_topk_prob=True,
+                                routed_scaling_factor=2.5),
+}
+
+
+def whole(router, seed=0, **kw):
+    pt.seed(seed)
+    layer = ExpertShareMLP(H, M, E, K, 0, E, num_shared_experts=1,
+                           **dict(ROUTERS[router], **kw))
+    rs = np.random.RandomState(seed)
+    layer.gate = jnp.asarray(rs.randn(H, E), jnp.float32)
+    if ROUTERS[router]["scoring"] == "sigmoid":
+        layer.expert_bias = jnp.asarray(0.3 * rs.randn(E), jnp.float32)
+    return layer
+
+
+def share(full, first, held, router):
+    """The rank that holds experts first .. first+held-1 of ``full``."""
+    pt.seed(1)
+    part = ExpertShareMLP(H, M, E, K, first, held, num_shared_experts=1,
+                          **ROUTERS[router])
+    state = dict(full.state_dict())
+    for k in ("w_gate", "w_up", "w_down"):
+        state[k] = state[k][first:first + held]
+    part.set_state_dict(state)
+    return part
+
+
+def plain(layer, x, router):
+    """The uncut layer written out token by token, in numpy float64."""
+    r = ROUTERS[router]
+    p = {k: np.asarray(v, np.float64) for k, v in layer.state_dict().items()}
+    silu = lambda a: a / (1 + np.exp(-a))                   # noqa: E731
+    x = np.asarray(x, np.float64)
+    logits = x @ p["gate"]
+    if r["scoring"] == "sigmoid":
+        scores = 1 / (1 + np.exp(-logits))
+    else:
+        z = np.exp(logits - logits.max(-1, keepdims=True))
+        scores = z / z.sum(-1, keepdims=True)
+    out = np.zeros_like(x)
+    for t in range(len(x)):
+        choice = scores[t] + p["expert_bias"]
+        G = r.get("n_group", 1)
+        if G > 1:
+            g = choice.reshape(G, E // G)
+            best = np.argsort(-np.sort(g, -1)[:, -2:].sum(-1))[
+                :r["topk_group"]]
+            ok = np.repeat(np.isin(np.arange(G), best), E // G)
+            choice = np.where(ok, choice, -np.inf)
+        chosen = np.argsort(-choice)[:K]
+        gates = scores[t, chosen]
+        if r["norm_topk_prob"]:
+            gates = gates / gates.sum()
+        gates = gates * r.get("routed_scaling_factor", 1.0)
+        for e, g in zip(chosen, gates):
+            out[t] += g * (silu(x[t] @ p["w_gate"][e])
+                           * (x[t] @ p["w_up"][e])) @ p["w_down"][e]
+        out[t] += (silu(x[t] @ p["shared_gate_proj"])
+                   * (x[t] @ p["shared_up_proj"])) @ p["shared_down_proj"]
+    return out
+
+
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_shares_add_up(router):
+    full = whole(router)
+    x = jnp.asarray(np.random.RandomState(2).randn(T, H), jnp.float32)
+    want = plain(full, x, router)
+    np.testing.assert_allclose(full(x), want, atol=2e-5)
+    total = np.asarray(full.shared_out(x), np.float64)      # counted once
+    for first in range(0, E, 4):
+        part = share(full, first, 4, router)
+        ids, gates = part.route(x)
+        total += np.asarray(part.routed(x, ids, gates), np.float64)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    # training's capacity dispatch, with room for every token, is the
+    # same layer: what serving leaves alone
+    pt.seed(3)
+    train = MoEMLP(H, M, E, top_k=K, capacity_factor=E / K,
+                   num_shared_experts=1, **ROUTERS[router])
+    train.set_state_dict(full.state_dict())
+    np.testing.assert_allclose(train(x), want, atol=2e-5)
+
+
+def test_nothing_is_dropped_whatever_the_imbalance():
+    full = whole("sigmoid-groups-bias")
+    bias = np.asarray(full.expert_bias).copy()
+    bias[5] = 100.0                     # every token's first choice
+    full.expert_bias = jnp.asarray(bias)
+    x = jnp.asarray(np.random.RandomState(4).randn(T, H), jnp.float32)
+    ids, _ = full.route(x)
+    assert np.all(np.any(np.asarray(ids) == 5, axis=-1))
+    want = plain(full, x, "sigmoid-groups-bias")
+    part = share(full, 4, 4, "sigmoid-groups-bias")
+    pids, pg = part.route(x)
+    rest = np.zeros_like(want)
+    for first in (0, 8, 12):
+        other = share(full, first, 4, "sigmoid-groups-bias")
+        rest += np.asarray(other.routed(x, *other.route(x)), np.float64)
+    got = (np.asarray(part.routed(x, pids, pg), np.float64) + rest
+           + np.asarray(full.shared_out(x), np.float64))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the capacity dispatch at its default factor does drop here
+    pt.seed(3)
+    train = MoEMLP(H, M, E, top_k=K, num_shared_experts=1,
+                   **ROUTERS["sigmoid-groups-bias"])
+    train.set_state_dict(full.state_dict())
+    assert np.abs(np.asarray(train(x)) - want).max() > 1e-2
+
+
+def test_a_share_outside_the_layer_is_refused():
+    with pytest.raises(ValueError):
+        ExpertShareMLP(H, M, E, K, 14, 4)
+
+
+def test_the_counters_count_live_rows_only():
+    full = whole("softmax")
+    part = share(full, 4, 4, "softmax")
+    x = jnp.asarray(np.random.RandomState(5).randn(6, 2, H), jnp.float32)
+    live = np.array([1, 0, 1, 1, 0, 0], bool)
+
+    @jax.jit
+    def run(x, live):
+        with collect_counts(live) as box:
+            part(x)
+            part(x)                     # two layers of one tick
+        return box.total
+
+    ticks, assigned, hit = np.asarray(run(x, jnp.asarray(live)))
+    ids = np.asarray(part.route(x.reshape(-1, H))[0]).reshape(6, 2, K)
+    mine = (ids >= 4) & (ids < 8) & live[:, None, None]
+    assert SERVING_COUNTERS == ("moe_layer_ticks", "moe_local_assignments",
+                                "moe_experts_hit")
+    assert ticks == 2 and assigned == 2 * mine.sum()
+    assert hit == 2 * len(set(ids[mine]))
+    with collect_counts(jnp.asarray(live)) as box:
+        pass
+    assert box.total is None            # no expert layer: nothing rides

@@ -34,10 +34,20 @@ from paddle_tpu.utils import observability as obs
 
 CHUNK = 16
 ATTN = {"attn", "kv_layout"}        # the decode side; a chunk has its own
+# the parts only an expert layer and latent attention have (ISSUE 26)
+MOE_MLA = {"router", "experts", "shared_expert", "absorb"}
+LLAMA = set(obs.TICK_SCOPES) - MOE_MLA
 PROGRAMS = {
-    "_fused_tick": set(obs.TICK_SCOPES) - {"chunk_attn"},
+    "_fused_tick": LLAMA - {"chunk_attn"},
+    "_fused_tick_greedy": LLAMA - {"chunk_attn"},
+    "_chunk_prefill": LLAMA - ATTN - {"patch"},
+}
+# DeepSeek-V3's block, both kinds of layer. Its latent pool is allocated
+# as the kernel reads it, so `kv_layout` names a reshape that moves
+# nothing; a chunk attends in the expanded form, so it has no `absorb`
+DEEPSEEK = {
     "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb"},
 }
 
 
@@ -78,8 +88,31 @@ def _scope(op_name):
     return found[-1] if found else None
 
 
+@pytest.fixture(scope="module")
+def deepseek_engine():
+    from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
+                                               deepseek_v2_tiny)
+    cfg = deepseek_v2_tiny(num_hidden_layers=2, num_experts=8,
+                           scoring="sigmoid", experts_held=4)
+    eng = PagedEngine(DeepseekV2ForCausalLM(cfg), max_slots=4,
+                      num_blocks=32, block_size=8, max_blocks_per_seq=8,
+                      chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(DEEPSEEK))
+def test_an_expert_and_latent_model_carries_its_scopes(deepseek_engine,
+                                                       kernels, program):
+    assert deepseek_engine.decode_route() == "ragged"
+    _, scopes = _lowered(deepseek_engine, program)
+    assert set(scopes) - {None} == DEEPSEEK[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+
+
 def test_the_programs_use_the_whole_vocabulary():
-    assert set().union(*PROGRAMS.values()) == set(obs.TICK_SCOPES)
+    assert set().union(*PROGRAMS.values(), *DEEPSEEK.values()) \
+        == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES
                                           + obs.LOOP_PHASES)

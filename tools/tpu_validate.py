@@ -197,6 +197,38 @@ def ragged_paged_multiquery_kernel():
     assert err < 3e-2, err
 check("ragged_paged_multiquery_kernel", ragged_paged_multiquery_kernel)
 
+def latent_paged_kernel():
+    # the ragged kernel's latent mode (DeepSeek-V3 / GigaChat3.1 widths:
+    # 64 query heads over one 640-column row a token, values its first
+    # 512 columns, 64 rows, an 8193-block pool), single-query and
+    # multi-query, against the dense gather
+    import os
+    from paddle_tpu.generation.paged import PagedKV, paged_latent_attention
+    R, P, B, M, h2, W, dv = 64, 8193, 16, 128, 64, 640, 512
+    kp = jnp.asarray(rs.randn(P, B, 1, W), jnp.bfloat16)
+    tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
+                         .reshape(R, M), jnp.int32)
+    lens = jnp.asarray(([0, 15, 16, 2040, 100, 576, 1023, 300]
+                        + list(rs.randint(0, 2040, R - 8))), jnp.int32)
+
+    def attend(route):
+        def run(q):
+            os.environ["PADDLE_TPU_PAGED_ATTN"] = route     # when traced
+            try:
+                return paged_latent_attention(
+                    q, PagedKV(kp, None, tables, lens), dv, 192 ** -0.5)
+            finally:
+                del os.environ["PADDLE_TPU_PAGED_ATTN"]
+        return jax.jit(run)
+
+    for T in (1, 3):
+        qq = jnp.asarray(rs.randn(R, T, h2, W) * 0.3, jnp.bfloat16)
+        got, ref = attend("ragged")(qq), attend("dense")(qq)
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                    - ref.astype(jnp.float32))))
+        assert got.shape == (R, T, h2, dv) and err < 3e-2, (T, err)
+check("latent_paged_kernel", latent_paged_kernel)
+
 def ring_tick_program():
     # ISSUE 11: the ring-mode fused tick program (device-resident ring
     # buffer + write cursors carried in the tick state, no per-tick
