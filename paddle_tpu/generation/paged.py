@@ -5,11 +5,13 @@ scheduling).
 TPU-native design — everything the XLA program sees is STATIC:
 
 - The KV cache is a fixed pool of ``num_blocks`` physical blocks of
-  ``block_size`` tokens per layer (``[P, B, kvh, d]``). A request owns a
-  row of the ``[R, M]`` block table mapping its logical blocks to
-  physical ones. Memory per request grows in block quanta, so one long
-  request no longer pins a whole max-length buffer and the pool holds
-  as many mixed-length requests as actually fit.
+  ``block_size`` tokens per layer (``[P, B, kvh*d]``: a page is one
+  contiguous ``(B, kvh*d)`` slab, the form the kernels fetch, so no
+  program copies the pool). A request owns a row of the ``[R, M]``
+  block table mapping its logical blocks to physical ones. Memory per
+  request grows in block quanta, so one long request no longer pins a
+  whole max-length buffer and the pool holds as many mixed-length
+  requests as actually fit.
 - One jitted ``decode_step`` advances EVERY active slot one token:
   per-row scatter-write of the new K/V into the row's current block,
   gather of the row's blocks ``kp[block_tables]``, masked attention up
@@ -169,23 +171,34 @@ def _on_device(method):
 class PagedKV(NamedTuple):
     """Per-layer paged cache view handed to the attention modules.
 
-    kp/vp: [P, B, kvh, d] physical block pools (this layer's). A
-    LATENT pool (multi-head latent attention) is ``kp`` alone,
-    [P, B, 1, W]: one row a token (compressed latent, shared rope key,
-    zero padding to whole 128-lane tiles) that is key and value to every
-    head; ``vp`` is None.
+    kp/vp: [P, B, heads*width] physical block pools (this layer's): a
+    token's heads side by side in one row, so a page is one contiguous
+    ``(B, heads*width)`` slab. The pools are allocated, written, donated
+    and kept in this form because it is the one the kernels fetch pages
+    in: handing a pool to a kernel moves nothing. A LATENT pool
+    (multi-head latent attention) is ``kp`` alone, [P, B, W]: one row a
+    token (compressed latent, shared rope key, zero padding to whole
+    128-lane tiles) that is key and value to every head; ``vp`` is None.
     block_tables: [R, M] physical block id per (slot, logical block).
     seq_lens: [R] tokens already cached per slot == this step's write
     position. Shared across layers; XLA dedups the copies.
+    heads: how many heads share a pool row (the shape no longer says):
+    a Python int, static under every transform.
     """
     kp: Any
     vp: Any
     block_tables: Any
     seq_lens: Any
+    heads: int = 1
 
     @property
     def block_size(self) -> int:
         return self.kp.shape[1]
+
+    @property
+    def width(self) -> int:
+        """Columns of one head in a pool row."""
+        return self.kp.shape[2] // self.heads
 
     @property
     def pool(self) -> tuple:
@@ -193,21 +206,37 @@ class PagedKV(NamedTuple):
         return (self.kp,) if self.vp is None else (self.kp, self.vp)
 
     def scatter(self, bidx, boff, k, v, sel=None):
-        """New rows ``k[sel]`` (and ``v[sel]``; ``v`` None for a latent
-        pool) written at block ``bidx``, offset ``boff``."""
+        """New rows ``k[sel]`` [..., heads, width] (and ``v[sel]``; ``v``
+        None for a latent pool) written at block ``bidx``, offset
+        ``boff``. The new rows are flattened to the pool's, never the
+        pool split to theirs."""
         def rows(a, pool):
-            return (a if sel is None else a[sel]).astype(pool.dtype)
+            a = a if sel is None else a[sel]
+            return a.reshape(a.shape[:-2] + (-1,)).astype(pool.dtype)
         kp = self.kp.at[bidx, boff].set(rows(k, self.kp))
         if self.vp is None:
             return self._replace(kp=kp)
         vp = self.vp.at[bidx, boff].set(rows(v, self.vp))
         return self._replace(kp=kp, vp=vp)
 
+    def split(self, rows):
+        """Rows GATHERED from a pool, [..., heads*width], with their heads
+        apart: [..., heads, width]."""
+        return rows.reshape(rows.shape[:-1] + (self.heads, self.width))
+
+
+# ``heads`` is structure, not data: a PagedKV that crosses a transform
+# (jit, remat, scan) keeps it a Python int
+jax.tree_util.register_pytree_node(
+    PagedKV, lambda pk: (pk[:4], pk.heads),
+    lambda heads, leaves: PagedKV(*leaves, heads))
+
 
 def paged_decode_write(pk: PagedKV, k, v=None):
-    """Scatter each row's new K/V (k [R, T, kvh, d]; ``v`` None for a
-    latent pool, here and in the prefill write) into its blocks at
-    positions seq_len .. seq_len+T-1. T == 1 is the plain decode tick;
+    """Scatter each row's new K/V (k [R, T, kvh, d], written as rows of
+    kvh*d columns; ``v`` None for a latent pool, here and in the
+    prefill write) into its blocks at positions seq_len ..
+    seq_len+T-1. T == 1 is the plain decode tick;
     T > 1 is the speculative verify (ISSUE 7) writing the probe token
     plus T-1 drafts in one scatter. Positions past a row's ALLOCATED
     blocks divert to the garbage block automatically (unallocated table
@@ -251,6 +280,15 @@ def paged_prefill_write(pk: PagedKV, k, v=None, positions=None,
         return pk.scatter(bidx, boff, k, v, sel=0)
 
 
+def paged_chunk_rows(pk: PagedKV, pool=None):
+    """Row 0's cached rows in order, [1, M*B, heads, width] of ``pool``
+    (``kp`` unless given): what a prompt chunk attends over after its
+    own rows were written. Only the row's own pages are gathered and
+    split into heads."""
+    rows = (pk.kp if pool is None else pool)[pk.block_tables[0]]
+    return pk.split(rows.reshape(1, -1, rows.shape[-1]))
+
+
 def paged_chunk_attention(q, pk: PagedKV, positions,
                           window: Optional[int] = None):
     """Chunked-prefill attention: q [1, s, h, d] chunk queries at global
@@ -261,10 +299,9 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
     in unallocated garbage-block slots) and are masked by the causal
     compare."""
     from ..ops.attention import dense_attention
-    kvh, d = pk.kp.shape[2], pk.kp.shape[3]
     with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
-        ks = pk.kp[pk.block_tables[0]].reshape(1, -1, kvh, d)  # [1, T, ..]
-        vs = pk.vp[pk.block_tables[0]].reshape(1, -1, kvh, d)
+        ks = paged_chunk_rows(pk)                   # [1, T, kvh, d]
+        vs = paged_chunk_rows(pk, pk.vp)
         kpos = jnp.arange(ks.shape[1])[None, :]             # [1, T]
         qpos = positions[0][:, None]                        # [s, 1]
         keep = kpos <= qpos                                 # [s, T]
@@ -273,9 +310,9 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
         return dense_attention(q, ks, vs, attn_mask=keep[None, None])
 
 
-def paged_decode_route(q, kp, latent: bool = False) -> str:
+def paged_decode_route(q, kp, kv_heads: int, latent: bool = False) -> str:
     """Which attention path ``paged_decode_attention`` takes for q
-    [R, T, h, d] against pools shaped like ``kp`` [P, B, kvh, d]:
+    [R, T, h, d] against pools shaped like ``kp`` [P, B, kv_heads*d]:
     ``"ragged"`` (the Pallas kernel that walks each row's own pages, the
     default),
     ``"grid"`` (the grid-per-row Pallas kernel, single-query only) or
@@ -289,7 +326,7 @@ def paged_decode_route(q, kp, latent: bool = False) -> str:
     from ..ops.pallas.paged_attention import use_paged_kernel
     from ..ops.pallas.ragged_paged_attention import pages_fill_lanes
     mode = os.environ.get("PADDLE_TPU_PAGED_ATTN", "ragged")
-    if mode == "dense" or not use_paged_kernel(q, kp):
+    if mode == "dense" or not use_paged_kernel(q, kp, kv_heads):
         return "dense"
     if mode == "grid" or not pages_fill_lanes(kp):
         # the grid kernel is single-query and knows K and V pools only
@@ -317,28 +354,28 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     with jax.named_scope("attn"):           # obs.TICK_SCOPES
         from ..ops.attention import dense_attention
         R, T = q.shape[0], q.shape[1]
-        kvh, d = pk.kp.shape[2], pk.kp.shape[3]
-        route = paged_decode_route(q, pk.kp)
+        route = paged_decode_route(q, pk.kp, pk.heads)
         if route != "dense":
-            sc = scale if scale is not None else d ** -0.5
+            sc = scale if scale is not None else pk.width ** -0.5
             if route == "grid":
                 from ..ops.pallas.paged_attention import \
                     paged_attention_pallas
                 out = paged_attention_pallas(q[:, 0], pk.kp, pk.vp,
                                              pk.block_tables, pk.seq_lens,
-                                             sc, window=window)
+                                             sc, pk.heads, window=window)
                 return out[:, None]
             from ..ops.pallas.ragged_paged_attention import \
                 ragged_paged_attention_pallas
             out = ragged_paged_attention_pallas(
                 q if T > 1 else q[:, 0], pk.kp, pk.vp, pk.block_tables,
-                pk.seq_lens, sc, window=window)
+                pk.seq_lens, sc, pk.heads, window=window)
             return out if T > 1 else out[:, None]
-        ks = pk.kp[pk.block_tables]                  # [R, M, B, kvh, d]
-        vs = pk.vp[pk.block_tables]
+        # the heads come apart in the rows GATHERED, not in the pool
+        ks = pk.split(pk.kp[pk.block_tables])        # [R, M, B, kvh, d]
+        vs = pk.split(pk.vp[pk.block_tables])
         Tk = ks.shape[1] * ks.shape[2]
-        ks = ks.reshape(R, Tk, kvh, d)
-        vs = vs.reshape(R, Tk, kvh, d)
+        ks = ks.reshape((R, Tk) + ks.shape[3:])
+        vs = vs.reshape((R, Tk) + vs.shape[3:])
         kpos = jnp.arange(Tk)[None, None, :]                  # [1, 1, Tk]
         qpos = pk.seq_lens[:, None, None] + \
             jnp.arange(T)[None, :, None]                      # [R, T, 1]
@@ -359,15 +396,15 @@ def paged_latent_attention(q, pk: PagedKV, v_width: int, scale: float):
     dense whole-table gather, as in ``paged_decode_attention``."""
     with jax.named_scope("attn"):           # obs.TICK_SCOPES
         R, T = q.shape[0], q.shape[1]
-        if paged_decode_route(q, pk.kp, latent=True) == "ragged":
+        if paged_decode_route(q, pk.kp, pk.heads, latent=True) == "ragged":
             from ..ops.pallas.ragged_paged_attention import \
                 ragged_paged_attention_pallas
             out = ragged_paged_attention_pallas(
                 q if T > 1 else q[:, 0], pk.kp, None, pk.block_tables,
-                pk.seq_lens, scale, v_width=v_width)
+                pk.seq_lens, scale, pk.heads, v_width=v_width)
             return out if T > 1 else out[:, None]
         # every head reads the one row: no per-head copy of the keys
-        ks = pk.kp[pk.block_tables]                  # [R, M, B, 1, W]
+        ks = pk.kp[pk.block_tables]                  # [R, M, B, W]
         ks = ks.reshape(R, -1, ks.shape[-1])
         kpos = jnp.arange(ks.shape[1])[None, None, :]
         qpos = pk.seq_lens[:, None, None] + jnp.arange(T)[None, :, None]
@@ -376,13 +413,6 @@ def paged_latent_attention(q, pk: PagedKV, v_width: int, scale: float):
         scores = jnp.where((kpos <= qpos)[:, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("rhtk,rkv->rthv", probs, ks[..., :v_width])
-
-
-def paged_chunk_rows(pk: PagedKV):
-    """Row 0's cached rows in order, [1, M*B, kvh, d] of ``kp``: what a
-    prompt chunk attends over after its own rows were written."""
-    rows = pk.kp[pk.block_tables[0]]
-    return rows.reshape(1, -1, *rows.shape[2:])
 
 
 class _Request:
@@ -1078,15 +1108,17 @@ class PagedEngine:
         return ((cfg.num_key_value_heads, cfg.head_dim),) * 2
 
     def _fresh_device_arrays(self):
-        """New pools (per layer one [P, B, heads, width] array for each
+        """New pools (per layer one [P, B, heads*width] array for each
         entry of the model's cached row: a K/V pair, or one latent
-        array) and the per-row seen-token masks for the repetition
-        penalty (seeded by the prefill scatter, updated inside the
+        array; the page-slab form the kernels fetch, so that no program
+        ever copies a pool) and the per-row seen-token masks for the
+        repetition penalty (seeded by the prefill scatter, updated inside the
         jitted decode step). ``hard_reset`` takes fresh ones too: the
         old arrays may be donated into a dead or in-flight program."""
         cfg = self.model.config
-        pools = [tuple(self._zeros((self.P, self.B) + tuple(r), cfg.dtype)
-                       for r in self._cache_rows())
+        pools = [tuple(self._zeros((self.P, self.B, heads * width),
+                                   cfg.dtype)
+                       for heads, width in self._cache_rows())
                  for _ in range(cfg.num_hidden_layers)]
         return pools, self._zeros((self.R, cfg.vocab_size), bool)
 
@@ -1096,12 +1128,13 @@ class PagedEngine:
         shapes): "ragged" or "grid" is a Pallas kernel, "dense" the XLA
         whole-table gather."""
         cfg = self.model.config
-        kp = self.pools[0][0]       # a query is as wide as a cached row
+        rows = self._cache_rows()
+        heads, width = rows[0]      # a query is as wide as a cached head
         q = jax.ShapeDtypeStruct(
-            (self.R, self._spec_k + 1, cfg.num_attention_heads,
-             kp.shape[-1]), cfg.dtype)
-        return paged_decode_route(q, kp,
-                                  latent=len(self.pools[0]) == 1)
+            (self.R, self._spec_k + 1, cfg.num_attention_heads, width),
+            cfg.dtype)
+        return paged_decode_route(q, self.pools[0][0], heads,
+                                  latent=len(rows) == 1)
 
     # ------------------------------------------------------ tick profiler
     @property
@@ -1193,7 +1226,9 @@ class PagedEngine:
 
     # ------------------------------------------------------------ jitted
     def _paged_caches(self, pools, tables, lens):
-        return [PagedKV(p[0], p[1] if len(p) > 1 else None, tables, lens)
+        heads = self._cache_rows()[0][0]
+        return [PagedKV(p[0], p[1] if len(p) > 1 else None, tables, lens,
+                        heads)
                 for p in pools]
 
     def _decode_step(self, params, pools, tables, lens, last_tokens,
@@ -2104,16 +2139,17 @@ class PagedEngine:
         skew (different model depth/heads/dims, block size, dtype, or
         chunk grid) makes the bytes meaningless — the arena refuses the
         restore and the request re-prefills."""
-        kp = self.pools[0][0]
-        _, B, kvh, d = kp.shape
-        return (len(self.pools), int(B), int(kvh), int(d),
-                str(kp.dtype), self.chunk)
+        kvh, d = self._cache_rows()[0]
+        return (len(self.pools), int(self.B), int(kvh), int(d),
+                str(self.pools[0][0].dtype), self.chunk)
 
     def _spill_fetch(self, entry) -> bytes:
         """D2H gather of a span's KV: every layer's pool rows for
-        ``entry``'s blocks, packed as one ``(A*L, n, B, kvh, d)`` buffer
+        ``entry``'s blocks, packed as one ``(A*L, n, B, kvh*d)`` buffer
         (layer-major; A arrays a layer: K before V, or the one latent
-        array) — the byte layout ``_arena_restore`` reverses."""
+        array) — the byte layout ``_arena_restore`` reverses. In host
+        order these are the bytes of ``(A*L, n, B, kvh, d)``: a record
+        banked from a pool that kept its heads apart restores here."""
         idx = np.asarray(entry, np.int32)
         stacked = jnp.stack([p[idx] for pair in self.pools
                              for p in pair])
@@ -2206,7 +2242,7 @@ class PagedEngine:
 
     def _spill_upload(self, pools, idx, data):
         """spill_reupload_program: scatter a restored span's packed KV
-        ``(A*L, npad, B, kvh, d)`` into block rows ``idx`` of every
+        ``(A*L, npad, B, kvh*d)`` into block rows ``idx`` of every
         layer's pools. Pad rows target the garbage block 0."""
         A = len(pools[0])
         return [tuple(p.at[idx].set(data[A * l + a])
@@ -2251,15 +2287,15 @@ class PagedEngine:
             return False
         payload, rec_tokens = got
         kp = self.pools[0][0]
-        _, B, kvh, d = kp.shape
+        _, B, row = kp.shape
         L = len(self.pools) * len(self.pools[0])     # arrays in all
         rec_blocks = rec_tokens // B
-        expect = L * rec_blocks * B * kvh * d * kp.dtype.itemsize
+        expect = L * rec_blocks * B * row * kp.dtype.itemsize
         if len(payload) != expect or rec_blocks < n_blocks:
             self._count("spill_restore_failures")  # tokens/geometry skew
             return False
         data = np.frombuffer(payload, dtype=kp.dtype).reshape(
-            L, rec_blocks, B, kvh, d)[:, :n_blocks]
+            L, rec_blocks, B, row)[:, :n_blocks]
         blocks: List[int] = []
         for _ in range(n_blocks):
             b = self._alloc_block()      # may cascade-spill more spans
@@ -2274,7 +2310,7 @@ class PagedEngine:
             npad *= 2
         idx = np.zeros((npad,), np.int32)          # pad -> garbage block
         idx[:n_blocks] = blocks
-        padded = np.zeros((L, npad, B, kvh, d), kp.dtype)
+        padded = np.zeros((L, npad, B, row), kp.dtype)
         padded[:, :n_blocks] = data
         self.dispatch_count += 1
         self._count("dispatches")

@@ -24,12 +24,11 @@ live blocks:
   padded to the 8-sublane minimum) and each KV block is read once per
   KV head, never per query head.
 
-Pool layout note: the [P, B, kvh, d] pools are viewed [P, B, kvh*d] so
-the last-two block dims (B, d) satisfy Mosaic's (8, 128) tiling with
-the column block selecting the kv head, the same trick as
-``decode_attention.py``. The view is not free on the chip: XLA copies
-the pool into that layout for every call (PERF.md section 5, scope
-``kv_layout``: 3.1 ms of a 16-layer tick at a 1 GB pool).
+Pool layout note: the pools are [P, B, kvh*d] (allocated and kept so,
+``generation/paged.py``), so the last-two block dims (B, d) satisfy
+Mosaic's (8, 128) tiling with the column block selecting the kv head,
+the same trick as ``decode_attention.py``, and the pool is the kernel's
+operand as it is.
 """
 from __future__ import annotations
 
@@ -92,12 +91,15 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention_pallas(q, kp, vp, block_tables, seq_lens, scale,
-                           window=None):
-    """q [R, h, d]; kp/vp [P, B, kvh, d] physical pools;
+                           kv_heads, window=None):
+    """q [R, h, d]; kp/vp [P, B, kv_heads*d] physical pools;
     block_tables [R, M]; seq_lens [R] (position written this step —
     tokens 0..seq_lens[r] attend). Returns [R, h, d]."""
     R, h, d = q.shape
-    P, B, kvh, _ = kp.shape
+    kvh, B = kv_heads, kp.shape[1]
+    if kp.ndim != 3 or kp.shape[2] != kvh * d:
+        raise ValueError(f"pool {kp.shape} is not [P, B, {kvh} kv heads "
+                         f"x {d} columns]")
     M = block_tables.shape[1]
     group = h // kvh
     gp = max(8, -(-group // 8) * 8)
@@ -121,9 +123,6 @@ def paged_attention_pallas(q, kp, vp, block_tables, seq_lens, scale,
 
     kernel = functools.partial(_paged_kernel, scale=scale, bs=B, nm=M,
                                gp=gp, window=window)
-    with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
-        kc = kp.reshape(P, B, kvh * d)
-        vc = vp.reshape(P, B, kvh * d)
     out = pl.pallas_call(
         kernel,
         name="paged_attention",
@@ -146,11 +145,11 @@ def paged_attention_pallas(q, kp, vp, block_tables, seq_lens, scale,
         ),
         out_shape=jax.ShapeDtypeStruct((R, kvh, gp, d), q.dtype),
         interpret=_interpret(),
-    )(tbl, lens, qg, kc, vc)
+    )(tbl, lens, qg, kp, vp)
     return out[:, :, :group, :].reshape(R, h, d)
 
 
-def use_paged_kernel(q, kp) -> bool:
+def use_paged_kernel(q, kp, kv_heads: int) -> bool:
     """Same gating policy as the other kernels: TPU backend (or interpret
     mode so CI drives the dispatch glue), MXU-friendly head_dim, whole
     query-head groups, 8-sublane-aligned block_size. ``s > 1`` (the
@@ -159,7 +158,7 @@ def use_paged_kernel(q, kp) -> bool:
     stays single-query (its caller falls back to dense)."""
     from . import interpret_enabled, kernels_enabled
     R, s, h, d = q.shape
-    B, kvh = kp.shape[1], kp.shape[2]
+    B, kvh = kp.shape[1], kv_heads
     if h % kvh:
         return False
     if not kernels_enabled():

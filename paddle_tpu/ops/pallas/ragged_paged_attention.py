@@ -27,19 +27,19 @@ row walks its own block table to its own length:
   their V meets a zero weight, so it only has to be finite: the V
   scratch is zeroed at the first row and afterwards holds pool data.
 
-Pool layout: the [P, B, kvh, d] pools are handed over as [P, B, kvh*d],
-so a page is one contiguous ``(B, kvh*d)`` slab. On the chip that
-reshape is a real copy of the pool (scope ``kv_layout``: PERF.md section
-5), not a free view.
+Pool layout: the pools ARE [P, B, kvh*d] (``generation/paged.py``
+allocates, writes and keeps them so), a page one contiguous ``(B,
+kvh*d)`` slab, and the caller says how many kv heads share a row. The
+kernel's operand is the pool itself: viewing a [P, B, kvh, d] pool this
+way changed its tiling on the chip, a copy of the whole pool a layer
+and tick (tests/test_chip_compile.py keeps it gone).
 
 Latent mode (``vp`` None, ``v_width`` given): absorbed multi-head latent
-attention. The pool holds ONE row a token, ``[P, B, 1, W]`` (the
+attention. The pool holds ONE row a token, ``[P, B, W]`` (the
 compressed latent, the shared rope key, zero padding up to a multiple of
 128 lanes), which every query head reads: one kv "head", the query heads
 its group. The values are the first ``v_width`` columns of the keys, so
-the kernel keeps no V scratch and a page is fetched once. The pool is
-allocated in the kernel's layout (kvh = 1: the reshape is a bitcast),
-so nothing is copied under ``kv_layout``.
+the kernel keeps no V scratch and a page is fetched once.
 """
 from __future__ import annotations
 
@@ -61,11 +61,11 @@ _BLOCK_TOKENS = 256
 
 
 def pages_fill_lanes(kp) -> bool:
-    """Whether a page of pool ``kp`` [P, B, kvh, d] can be fetched as one
+    """Whether a page of pool ``kp`` [P, B, kvh*d] can be fetched as one
     ``(B, kvh*d)`` slab: Mosaic slices HBM in whole 128-lane tiles, so
     one kv head of 64 columns cannot, and ``paged_decode_route`` sends it
     elsewhere. The interpreter takes any width."""
-    return _interpret() or (kp.shape[2] * kp.shape[3]) % 128 == 0
+    return _interpret() or kp.shape[2] % 128 == 0
 
 
 def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:
@@ -167,12 +167,13 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
 
 
 def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
-                                  scale, window=None, v_width=None):
+                                  scale, kv_heads, window=None,
+                                  v_width=None):
     """q [R, h, d] (single-query decode) OR [R, T, h, d] (multi-query
     speculative verify rows: query t of row r sits at position
     seq_lens[r] + t and attends tokens 0..seq_lens[r]+t); kp/vp
-    [P, B, kvh, d] physical pools; block_tables [R, M]; seq_lens [R].
-    Returns q's shape. Latent mode: ``vp`` None and ``v_width`` the
+    [P, B, kv_heads*d] physical pools; block_tables [R, M]; seq_lens
+    [R]. Returns q's shape. Latent mode: ``vp`` None and ``v_width`` the
     number of leading key columns that are the values; returns
     [..., h, v_width].
 
@@ -182,8 +183,12 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
     rows, not extra HBM traffic."""
     if (vp is None) != (v_width is not None):
         raise ValueError("latent mode takes vp=None AND v_width")
+    if kp.ndim != 3 or kp.shape[2] != kv_heads * q.shape[-1]:
+        raise ValueError(f"pool {kp.shape} is not [P, B, {kv_heads} kv "
+                         f"heads x {q.shape[-1]} columns]")
     return _attend(q, kp, vp, block_tables, seq_lens, scale=float(scale),
-                   window=window, interpret=_interpret(), v_width=v_width)
+                   kvh=int(kv_heads), window=window,
+                   interpret=_interpret(), v_width=v_width)
 
 
 # jitted so that a program of L layers traces the kernel body once, not
@@ -191,17 +196,17 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
 # Inlined into the caller's jaxpr, so the lowered program and the names
 # of its ops (obs.TICK_SCOPES) are what they are without the jit.
 @functools.partial(jax.jit, inline=True,
-                   static_argnames=("scale", "window", "interpret",
+                   static_argnames=("scale", "kvh", "window", "interpret",
                                     "v_width"))
-def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret,
-            v_width=None):
+def _attend(q, kp, vp, block_tables, seq_lens, *, scale, kvh, window,
+            interpret, v_width=None):
     multi = q.ndim == 4
     if multi:
         R, T, h, d = q.shape
     else:
         R, h, d = q.shape
         T = 1
-    P, B, kvh, _ = kp.shape
+    B = kp.shape[1]
     M = block_tables.shape[1]
     latent = v_width is not None
     dv = v_width if latent else d
@@ -223,9 +228,7 @@ def _attend(q, kp, vp, block_tables, seq_lens, *, scale, window, interpret,
     kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, pps=pps,
                                window=window, group=group, q_len=T,
                                v_width=v_width)
-    with jax.named_scope("kv_layout"):      # obs.TICK_SCOPES
-        pools = [p.reshape(P, B, kvh * d)
-                 for p in ((kp,) if latent else (kp, vp))]
+    pools = (kp,) if latent else (kp, vp)
     out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",

@@ -956,7 +956,6 @@ TICK_SCOPES = (
     "absorb",      # latent attention: queries through W_uk, output
                    # through W_uv, either side of the kernel
     "kv_write",    # the new rows scattered into the pool
-    "kv_layout",   # the pool viewed [P, B, kvh*d] for the kernel
     "attn",        # decode: schedule build and kernel
     "chunk_attn",  # chunk: gather of the row's blocks, masked attention
                    # (latent attention: their expansion to K and V too)

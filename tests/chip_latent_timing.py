@@ -57,7 +57,7 @@ def main(argv=None) -> int:
 
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(R, H, W) * 0.3, jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, 1, W), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, W), jnp.bfloat16)
     tbl = jnp.asarray(1 + rs.permutation(P - 1)[:R * M].reshape(R, M),
                       jnp.int32)
 

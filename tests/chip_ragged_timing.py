@@ -8,15 +8,15 @@ At the serving cell's geometry (8 rows, 128 blocks of 16 tokens, 4 kv
 heads, group 7, head 128, bf16, a 2049-block pool) every row holds the
 same context; one JSON line per context with the time of ONE kernel
 call. ``--parent DIR`` (a checkout of another commit, e.g. `git archive`
-into a directory `.gitignore` lists) times that commit's kernel beside
-this one in the same process, and the dense whole-table gather
+into a directory `.gitignore` lists; PR 27 or later, whose kernel takes
+the [P, B, kvh*d] pools and the head count) times that commit's kernel
+beside this one in the same process, and the dense whole-table gather
 (`PADDLE_TPU_PAGED_ATTN=dense`) is timed too: it reads all M*B positions
 whatever the context.
 
 A call's time is a two-point fit: one jitted program chains `n` calls
 (each call's output is the next one's query, so none is deduplicated)
-and (t(40) - t(8)) / 32 leaves out the dispatch and the pool's
-relayout copy, which the program pays once. Each kernel's output is
+and (t(40) - t(8)) / 32 leaves out the dispatch. Each kernel's output is
 compared with the dense gather's before it is timed. Not a pytest file;
 it refuses to run without a TPU.
 """
@@ -65,11 +65,11 @@ def main(argv=None) -> int:
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
 
-    def dense(q, kp, vp, tbl, lens, scale):
+    def dense(q, kp, vp, tbl, lens, scale, kv_heads):
         os.environ["PADDLE_TPU_PAGED_ATTN"] = "dense"   # read when traced
         try:
-            return paged_decode_attention(
-                q[:, None], PagedKV(kp, vp, tbl, lens), scale)[:, 0]
+            pk = PagedKV(kp, vp, tbl, lens, kv_heads)
+            return paged_decode_attention(q[:, None], pk, scale)[:, 0]
         finally:
             del os.environ["PADDLE_TPU_PAGED_ATTN"]
 
@@ -80,15 +80,15 @@ def main(argv=None) -> int:
     rs = np.random.RandomState(0)
     h = KVH * GROUP
     q = jnp.asarray(rs.randn(R, h, D), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, KVH, D), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, KVH, D), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, KVH * D), jnp.bfloat16)
+    vp = jnp.asarray(rs.randn(P, B, KVH * D), jnp.bfloat16)
     tbl = jnp.asarray(1 + rs.permutation(P - 1)[:R * M].reshape(R, M),
                       jnp.int32)
 
     def chain(fn, n):
         def run(q, kp, vp, tbl, lens):
             for _ in range(n):
-                q = fn(q, kp, vp, tbl, lens, D ** -0.5)
+                q = fn(q, kp, vp, tbl, lens, D ** -0.5, KVH)
             return q
         return jax.jit(run)
 

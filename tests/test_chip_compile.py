@@ -58,8 +58,8 @@ def test_ragged_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
     q = arr((R, h, d) if T == 1 else (R, T, h, d))
     compiled = jax.jit(
         lambda q, kp, vp, tbl, lens: ragged_paged_attention_pallas(
-            q, kp, vp, tbl, lens, d ** -0.5, window=window)).lower(
-        q, arr((P, B, kvh, d)), arr((P, B, kvh, d)),
+            q, kp, vp, tbl, lens, d ** -0.5, kvh, window=window)).lower(
+        q, arr((P, B, kvh * d)), arr((P, B, kvh * d)),
         arr((R, M), jnp.int32), arr((R,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -79,7 +79,75 @@ def test_latent_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
     q = arr((R, 64, 640) if T == 1 else (R, T, 64, 640))
     compiled = jax.jit(
         lambda q, kp, tbl, lens: ragged_paged_attention_pallas(
-            q, kp, None, tbl, lens, 192 ** -0.5, v_width=512)).lower(
-        q, arr((8193, 16, 1, 640)), arr((R, 128), jnp.int32),
+            q, kp, None, tbl, lens, 192 ** -0.5, 1, v_width=512)).lower(
+        q, arr((8193, 16, 640)), arr((R, 128), jnp.int32),
         arr((R,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_COPIES = ("reshape", "copy", "copy-start", "transpose")
+
+
+def _pool_instructions(text, P):
+    """``{name: (type with layout, opcode)}`` of every instruction of a
+    compiled module whose result is shaped like a pool of ``P`` pages."""
+    import re
+    found = re.findall(
+        rf"%(\S+) = (\w+\[{P},16,[\d,]*\]\{{[^}}]*\}}) ([\w-]+)\(", text)
+    return {name: (kind, op) for name, kind, op in found}
+
+
+@pytest.mark.parametrize("P,R,h,kvh,d,T,v_width", [
+    (2049, 8, 28, 4, 128, 1, None),     # qwen2-7b-d16, a tick
+    (2049, 8, 28, 4, 128, 4, None),     # its speculative verify
+    (8193, 64, 64, 1, 640, 1, 512),     # gigachat3.1's latent row
+])
+def test_write_and_attend_compile_with_no_pool_sized_copy(
+        one_chip, no_persistent_cache, monkeypatch, P, R, h, kvh, d, T,
+        v_width):
+    """One layer's part of a tick, ``paged_decode_write`` and the decode
+    attention over DONATED pools: the pool the program is handed is the
+    pool the write updates in place and the kernel reads. A [P, B, kvh,
+    d] pool viewed [P, B, kvh*d] for the kernel changed its tiling
+    (T(4,128) -> T(8,128)): a copy of every pool in every tick, a fifth
+    of the Qwen cells' tick (PERF.md section 6, PR 27)."""
+    import re
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.generation.paged import (PagedKV,
+                                             paged_decode_attention,
+                                             paged_decode_write,
+                                             paged_latent_attention)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN", raising=False)
+    # the route asks jax for its backend, which is the CPU here
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    latent = v_width is not None
+
+    def layer(pools, tbl, lens, q, new):
+        pk = PagedKV(pools[0], None if latent else pools[1], tbl, lens,
+                     kvh)
+        pk = paged_decode_write(pk, *new)
+        out = paged_latent_attention(q, pk, v_width, 192 ** -0.5) \
+            if latent else paged_decode_attention(q, pk)
+        return out, pk.pool
+
+    n = 1 if latent else 2
+    text = jax.jit(layer, donate_argnums=0).lower(
+        (arr((P, 16, kvh * d)),) * n, arr((R, 128), jnp.int32),
+        arr((R,), jnp.int32), arr((R, T, h, d)),
+        (arr((R, T, kvh, d)),) * n).compile().as_text()
+
+    pools = _pool_instructions(text, P)
+    copies = {name: op for name, (_, op) in pools.items() if op in _COPIES}
+    assert not copies, f"pool-sized copies: {copies}"
+    params = {kind for kind, op in pools.values() if op == "parameter"}
+    call = re.search(r"custom-call\(([^)]*)\), "
+                     r'custom_call_target="tpu_custom_call"', text)
+    assert call, "no tpu_custom_call in the compiled module"
+    read = {pools[name.strip().lstrip("%")][0]
+            for name in call.group(1).split(",")
+            if name.strip().lstrip("%") in pools}
+    assert read and read == params and len(params) == 1, (read, params)

@@ -146,6 +146,11 @@ def test_pallas_decode_group_not_multiple_of_8():
                                atol=2e-5, rtol=2e-5)
 
 
+def _flat(pool):
+    """A [P, B, kvh, d] pool as the engine holds it: [P, B, kvh*d]."""
+    return pool.reshape(pool.shape[:2] + (-1,))
+
+
 def _dense_paged_reference(q, kp, vp, tables, lens, window=None):
     """The dense whole-table gather path (generation/paged.py fallback)."""
     R = q.shape[0]
@@ -177,9 +182,9 @@ def test_pallas_paged_kernel_matches_dense_gather(h, kvh, d, window):
     lens = np.asarray([0, 17, 63, 127], np.int32)
     tables = rs.permutation(np.arange(P)).reshape(1, -1)[0][:R * M] \
         .reshape(R, M).astype(np.int32)
-    got = paged_attention_pallas(q, kp, vp, jnp.asarray(tables),
-                                 jnp.asarray(lens), 1.0 / np.sqrt(d),
-                                 window=window)
+    got = paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                 jnp.asarray(tables), jnp.asarray(lens),
+                                 1.0 / np.sqrt(d), kvh, window=window)
     ref = _dense_paged_reference(q, kp, vp, jnp.asarray(tables),
                                  jnp.asarray(lens), window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -193,14 +198,15 @@ def test_paged_decode_attention_routes_to_kernel():
     from paddle_tpu.ops.pallas import paged_attention as pa
     rs = np.random.RandomState(3)
     R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
-    pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
-                 jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
+    kp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+    vp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
+    pk = PagedKV(_flat(kp), _flat(vp),
                  jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
-                 jnp.asarray([3, 30, 60], jnp.int32))
+                 jnp.asarray([3, 30, 60], jnp.int32), kvh)
     q = jnp.asarray(rs.randn(R, 1, h, d), jnp.float32)
-    assert pa.use_paged_kernel(q, pk.kp)
+    assert pa.use_paged_kernel(q, pk.kp, kvh)
     got = paged_decode_attention(q, pk)
-    ref = _dense_paged_reference(q[:, 0], pk.kp, pk.vp, pk.block_tables,
+    ref = _dense_paged_reference(q[:, 0], kp, vp, pk.block_tables,
                                  pk.seq_lens)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)

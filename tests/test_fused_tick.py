@@ -33,6 +33,8 @@ from paddle_tpu.generation.paged import (PagedEngine, PagedKV,
 from paddle_tpu.models import LlamaForCausalLM
 from paddle_tpu.models.llama import llama_tiny
 
+from test_decode_kernels import _flat
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -341,8 +343,9 @@ class TestRaggedKernel:
             rs.permutation(np.arange(P))[:R * M].reshape(R, M),
             jnp.int32)
         lens = jnp.asarray([0, B - 1, B, 2 * B + 3], jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_paged_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -354,10 +357,10 @@ class TestRaggedKernel:
         all three agree."""
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
-        pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
-                     jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
+        pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
+                     jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
                      jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
-                     jnp.asarray([3, 30, 60], jnp.int32))
+                     jnp.asarray([3, 30, 60], jnp.int32), kvh)
         q = jnp.asarray(rs.randn(R, 1, h, d), jnp.float32)
         outs = {}
         for mode in ("ragged", "grid", "dense"):
@@ -398,8 +401,9 @@ class TestRaggedKernel:
         tables = jnp.asarray(
             1 + rs.permutation(P - 1)[:R * M].reshape(R, M), jnp.int32)
         lens = jnp.asarray(lens, jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_paged_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -428,9 +432,9 @@ class TestRaggedKernel:
         kp[~live] = np.nan
         vp[~live] = np.nan
         got = ragged_paged_attention_pallas(
-            q, jnp.asarray(kp), jnp.asarray(vp),
+            q, _flat(jnp.asarray(kp)), _flat(jnp.asarray(vp)),
             jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32),
-            d ** -0.5)
+            d ** -0.5, kvh)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -444,9 +448,9 @@ class TestRaggedKernel:
         R, P, B, M, kvh, h, d = 4, 24, 8, 5, 2, 4, 64
         jaxpr = jax.make_jaxpr(
             lambda q, kp, vp, tbl, lens: ragged_paged_attention_pallas(
-                q, kp, vp, tbl, lens, d ** -0.5))(
-            jnp.zeros((R, h, d)), jnp.zeros((P, B, kvh, d)),
-            jnp.zeros((P, B, kvh, d)), jnp.zeros((R, M), jnp.int32),
+                q, kp, vp, tbl, lens, d ** -0.5, kvh))(
+            jnp.zeros((R, h, d)), jnp.zeros((P, B, kvh * d)),
+            jnp.zeros((P, B, kvh * d)), jnp.zeros((R, M), jnp.int32),
             jnp.zeros((R,), jnp.int32)).jaxpr
         shapes = [v.aval.shape for eqn in jaxpr.eqns
                   for v in list(eqn.invars) + list(eqn.outvars)
@@ -478,12 +482,14 @@ class TestRaggedKernel:
         from paddle_tpu.ops import pallas
         monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
         monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
-        pool = lambda kvh, d: jnp.zeros((8, 16, kvh, d), jnp.bfloat16)
+        pool = lambda kvh, d: jnp.zeros((8, 16, kvh * d), jnp.bfloat16)
         q = lambda T, h, d: jnp.zeros((2, T, h, d), jnp.bfloat16)
-        assert paged_decode_route(q(1, 28, 128), pool(4, 128)) == "ragged"
-        assert paged_decode_route(q(3, 28, 128), pool(4, 128)) == "ragged"
-        assert paged_decode_route(q(1, 8, 64), pool(1, 64)) == "grid"
-        assert paged_decode_route(q(3, 8, 64), pool(1, 64)) == "dense"
+        assert paged_decode_route(q(1, 28, 128), pool(4, 128), 4) \
+            == "ragged"
+        assert paged_decode_route(q(3, 28, 128), pool(4, 128), 4) \
+            == "ragged"
+        assert paged_decode_route(q(1, 8, 64), pool(1, 64), 1) == "grid"
+        assert paged_decode_route(q(3, 8, 64), pool(1, 64), 1) == "dense"
 
     def test_parity_shared_blocks_exceeding_pool_bound(self):
         """Prefix-cache shape: rows share most physical blocks, so the
@@ -503,8 +509,8 @@ class TestRaggedKernel:
                               [1, 2, 3, 6], [1, 2, 3, 7]], jnp.int32)
         lens = jnp.asarray([4 * B - 2, 3 * B, 4 * B - 1, 3 * B + 5],
                            jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh)
         ref = _dense_paged_reference(q, kp, vp, tables, lens)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
@@ -529,8 +535,9 @@ class TestRaggedKernel:
             rs.permutation(np.arange(P))[:R * M].reshape(R, M),
             jnp.int32)
         lens = jnp.asarray([0, 15, 16, 63, 100, 127], jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_paged_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

@@ -274,6 +274,42 @@ class TestWarmRestart:
         np.testing.assert_array_equal(np.asarray(e1.logprobs["b"]),
                                       lp_ref)
 
+    def test_a_record_banked_with_the_heads_apart_restores(self, model):
+        """The pools are [P, B, kvh*d]; until PR 27 they were [P, B,
+        kvh, d] and a span was packed ``(2L, n, B, kvh, d)``. In host
+        order these are the same bytes under the same geometry tuple:
+        a record packed the old way from a pool's values is, byte for
+        byte, what ``_spill_fetch`` packs, and a fresh engine restores
+        it and serves the original stream."""
+        e0 = _engine(model, None, num_blocks=32)
+        rs = np.random.RandomState(55)
+        prompt = np.asarray([rs.randint(1, 256, 33)])
+        e0.submit("a", prompt, max_new_tokens=4)
+        ref = np.asarray(e0.run()["a"])
+        lp_ref = np.asarray(e0.logprobs["a"])
+        cfg = model.config
+        kvh, d = cfg.num_key_value_heads, cfg.head_dim
+        geo = (cfg.num_hidden_layers, 8, kvh, d, str(e0.pools[0][0].dtype),
+               16)
+        assert e0._spill_geometry() == geo
+        key = bytes.fromhex(e0.prefix_digest(prompt))
+        entry = e0.prefix_cache[key]
+        apart = [np.asarray(p).reshape(32, 8, kvh, d)
+                 for layer in e0.pools for p in layer]
+        banked = np.stack([p[np.asarray(entry)] for p in apart]).tobytes()
+        assert np.stack(apart).any() and kvh > 1
+        assert e0._spill_fetch(entry) == banked
+        arena = KVSpillArena(64 << 20, name="apart")
+        assert arena.put(key, banked, len(entry) * 8, geo)
+        e1 = _engine(model, arena, num_blocks=32)
+        e1.submit("b", prompt, max_new_tokens=4)
+        out = e1.run()
+        assert e1.stats["spill_restores"] == 1, e1.stats
+        assert e1.stats["prefix_hit_tokens"] >= 16, e1.stats
+        np.testing.assert_array_equal(np.asarray(out["b"]), ref)
+        np.testing.assert_array_equal(np.asarray(e1.logprobs["b"]),
+                                      lp_ref)
+
     def test_geometry_skew_falls_back_to_prefill(self, model):
         """An arena fed by one block geometry attached to an engine
         with another: the take-side geometry check refuses the

@@ -111,7 +111,7 @@ def test_prefill_then_decode_agrees_with_the_full_forward(model, chunk):
     eng = engine(model, chunk_prefill_tokens=chunk,
                  enable_prefix_cache=chunk is not None)
     assert [tuple(p.shape for p in layer) for layer in eng.pools] == \
-        [((64, 8, 1, 128),)] * 3       # 32 + 8 columns in one 128-lane row
+        [((64, 8, 128),)] * 3       # 32 + 8 columns in one 128-lane row
     ps = prompts(0, (5, 20, 37))
     toks, lps = serve(eng, ps)
     assert_greedy(model, ps, toks, lps)
@@ -215,7 +215,7 @@ def test_the_latent_kernel_matches_the_dense_gather(kernels, T):
     R, P, B, M, h, W, dv = 5, 48, 8, 8, 64, 640, 512
     rs = np.random.RandomState(T)
     q = jnp.asarray(rs.randn(R, T, h, W) * 0.2, jnp.float32)
-    kp = jnp.asarray(rs.randn(P, B, 1, W), jnp.float32)
+    kp = jnp.asarray(rs.randn(P, B, W), jnp.float32)
     tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M].reshape(R, M),
                          jnp.int32)
     lens = jnp.asarray([0, 7, 8, 61 - T, 30], jnp.int32)

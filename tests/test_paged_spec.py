@@ -39,6 +39,8 @@ from paddle_tpu.generation.prompt_lookup import (accept_length,
 from paddle_tpu.models import LlamaForCausalLM
 from paddle_tpu.models.llama import llama_tiny
 
+from test_decode_kernels import _flat
+
 
 @pytest.fixture(scope="module")
 def model():
@@ -420,8 +422,9 @@ class TestMultiQueryRagged:
             rs.permutation(np.arange(P))[:R * M].reshape(R, M),
             jnp.int32)
         lens = jnp.asarray([0, B - 1, B, 2 * B + 3], jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_multi_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -449,8 +452,9 @@ class TestMultiQueryRagged:
         tables = jnp.asarray(
             1 + rs.permutation(P - 1)[:R * M].reshape(R, M), jnp.int32)
         lens = jnp.asarray(lens, jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_multi_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -461,10 +465,10 @@ class TestMultiQueryRagged:
         grid mode (single-query kernel) falls back to dense."""
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d, T = 3, 16, 16, 4, 2, 4, 64, 3
-        pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
-                     jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32),
+        pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
+                     jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
                      jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
-                     jnp.asarray([3, 30, 57], jnp.int32))
+                     jnp.asarray([3, 30, 57], jnp.int32), kvh)
         q = jnp.asarray(rs.randn(R, T, h, d), jnp.float32)
         outs = {}
         for mode in ("ragged", "grid", "dense"):
@@ -493,8 +497,9 @@ class TestMultiQueryRagged:
             rs.permutation(np.arange(P))[:R * M].reshape(R, M),
             jnp.int32)
         lens = jnp.asarray([0, 15, 16, 63, 100, 120], jnp.int32)
-        got = ragged_paged_attention_pallas(q, kp, vp, tables, lens,
-                                            d ** -0.5, window=window)
+        got = ragged_paged_attention_pallas(q, _flat(kp), _flat(vp),
+                                            tables, lens, d ** -0.5, kvh,
+                                            window=window)
         ref = _dense_multi_reference(q, kp, vp, tables, lens,
                                      window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -531,9 +536,9 @@ class TestPromptLookupHelpers:
         entry 0) must scatter into the garbage block, never clamp onto
         a live block."""
         P, B, M, kvh, d = 4, 4, 2, 1, 8
-        kp = jnp.zeros((P, B, kvh, d))
+        kp = jnp.zeros((P, B, kvh * d))
         pk = PagedKV(kp, kp, jnp.asarray([[1, 2]], jnp.int32),
-                     jnp.asarray([6], jnp.int32))
+                     jnp.asarray([6], jnp.int32), kvh)
         k = jnp.ones((1, 4, kvh, d))           # positions 6..9; cap = 8
         out = paged_decode_write(pk, k, k)
         got = np.asarray(out.kp)
@@ -541,6 +546,44 @@ class TestPromptLookupHelpers:
         assert (got[2, 2:] == 1).all()         # positions 6, 7 landed
         assert (got[3] == 0).all()             # never allocated
         assert (got[0, :2] == 1).all()         # 8, 9 -> garbage block
+
+    @pytest.mark.parametrize("writer", ["tick", "verify", "chunk"])
+    def test_writers_put_a_row_where_a_heads_apart_scatter_would(
+            self, writer):
+        """The pool is [P, B, kvh*d]; the new K/V arrive [.., kvh, d].
+        Every writer (the tick's T == 1, the verify's T > 1, a prompt
+        chunk at its global positions) leaves the bytes a scatter into
+        a [P, B, kvh, d] pool leaves: head h of a token in columns
+        h*d .. (h+1)*d of its row."""
+        from paddle_tpu.generation.paged import paged_prefill_write
+        rs = np.random.RandomState(5)
+        P, B, M, kvh, d = 9, 4, 4, 3, 8
+        old = rs.randn(2, P, B, kvh, d).astype(np.float32)
+        tables = np.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+        lens = np.asarray([5, 10], np.int32)
+        T = {"tick": 1, "verify": 3, "chunk": 6}[writer]
+        rows = 1 if writer == "chunk" else 2
+        new = rs.randn(2, rows, T, kvh, d).astype(np.float32)
+        pk = PagedKV(*(jnp.asarray(a.reshape(P, B, kvh * d)) for a in old),
+                     jnp.asarray(tables), jnp.asarray(lens), kvh)
+        if writer == "chunk":       # tokens 3..8 of row 0, 5 of them live
+            pk = pk._replace(seq_lens=jnp.asarray([8, 0], jnp.int32))
+            positions = np.arange(3, 3 + T)
+            out = paged_prefill_write(pk, *jnp.asarray(new),
+                                      positions=jnp.asarray(positions))
+            where = [(0, t, int(p)) for t, p in enumerate(positions)
+                     if p < 8]
+        else:
+            out = paged_decode_write(pk, *jnp.asarray(new))
+            where = [(r, t, int(lens[r]) + t) for r in range(rows)
+                     for t in range(T)]
+        want = old.copy()
+        for r, t, pos in where:
+            want[:, tables[r, pos // B], pos % B] = new[:, r, t]
+        got = np.stack([np.asarray(p).reshape(P, B, kvh, d)
+                        for p in out.pool])
+        assert out.heads == kvh and out.kp.shape == (P, B, kvh * d)
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
 
 
 # --------------------------------------------------------------- slow tier

@@ -33,7 +33,7 @@ from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.utils import observability as obs
 
 CHUNK = 16
-ATTN = {"attn", "kv_layout"}        # the decode side; a chunk has its own
+ATTN = {"attn"}             # the decode side; a chunk has its own
 # the parts only an expert layer and latent attention have (ISSUE 26)
 MOE_MLA = {"router", "experts", "shared_expert", "absorb"}
 LLAMA = set(obs.TICK_SCOPES) - MOE_MLA
@@ -42,9 +42,8 @@ PROGRAMS = {
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
     "_chunk_prefill": LLAMA - ATTN - {"patch"},
 }
-# DeepSeek-V3's block, both kinds of layer. Its latent pool is allocated
-# as the kernel reads it, so `kv_layout` names a reshape that moves
-# nothing; a chunk attends in the expanded form, so it has no `absorb`
+# DeepSeek-V3's block, both kinds of layer. A chunk attends in the
+# expanded form, so it has no `absorb`
 DEEPSEEK = {
     "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn"},
     "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb"},

@@ -124,12 +124,13 @@ def paged_kernel():
     from paddle_tpu.ops.attention import dense_attention as da
     R, P, B, M, kvh2, h2, d2 = 4, 64, 16, 16, 4, 8, 128
     qq = jnp.asarray(rs.randn(R, h2, d2), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
+    vp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
     tables = jnp.asarray(rs.permutation(np.arange(P))[:R * M]
                          .reshape(R, M), jnp.int32)
     lens = jnp.asarray([0, 31, 100, 255], jnp.int32)
-    out = paged_attention_pallas(qq, kp, vp, tables, lens, d2 ** -0.5)
+    out = paged_attention_pallas(qq, kp, vp, tables, lens, d2 ** -0.5,
+                                 kvh2)
     ks = kp[tables].reshape(R, -1, kvh2, d2)
     vs = vp[tables].reshape(R, -1, kvh2, d2)
     kpos = jnp.arange(ks.shape[1])[None, :]
@@ -142,17 +143,18 @@ check("paged_attention_kernel", paged_kernel)
 
 def ragged_cell_inputs(T=None):
     # the serving cell's geometry (qwen2-7b-d16: 8 slots, 128 blocks of
-    # 16 tokens, 4 kv heads, group 7, head 128, bf16, a 2049-block pool)
-    # with ragged lengths from an empty slot to a full table
+    # 16 tokens, 4 kv heads, group 7, head 128, bf16, a 2049-block pool
+    # of [P, B, kvh*d] rows) with ragged lengths from an empty slot to a
+    # full table
     R, P, B, M, kvh2, h2, d2 = 8, 2049, 16, 128, 4, 28, 128
     qq = jnp.asarray(rs.randn(*((R, h2, d2) if T is None
                                 else (R, T, h2, d2))), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, kvh2, d2), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
+    vp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
     tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
                          .reshape(R, M), jnp.int32)
     lens = jnp.asarray([0, 15, 16, 2040, 100, 576, 1023, 300], jnp.int32)
-    return qq, kp, vp, tables, lens
+    return qq, kp, vp, tables, lens, kvh2
 
 def ragged_paged_kernel():
     # the ragged kernel (the serving default) must compile and match the
@@ -160,10 +162,10 @@ def ragged_paged_kernel():
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
     from paddle_tpu.ops.attention import dense_attention as da
-    qq, kp, vp, tables, lens = ragged_cell_inputs()
-    R, kvh2, d2 = qq.shape[0], kp.shape[2], kp.shape[3]
+    qq, kp, vp, tables, lens, kvh2 = ragged_cell_inputs()
+    R, d2 = qq.shape[0], qq.shape[-1]
     out = ragged_paged_attention_pallas(qq, kp, vp, tables, lens,
-                                        d2 ** -0.5)
+                                        d2 ** -0.5, kvh2)
     ks = kp[tables].reshape(R, -1, kvh2, d2)
     vs = vp[tables].reshape(R, -1, kvh2, d2)
     kpos = jnp.arange(ks.shape[1])[None, :]
@@ -183,10 +185,10 @@ def ragged_paged_multiquery_kernel():
         ragged_paged_attention_pallas
     from paddle_tpu.ops.attention import dense_attention as da
     T = 5
-    qq, kp, vp, tables, lens = ragged_cell_inputs(T)
-    R, kvh2, d2 = qq.shape[0], kp.shape[2], kp.shape[3]
+    qq, kp, vp, tables, lens, kvh2 = ragged_cell_inputs(T)
+    R, d2 = qq.shape[0], qq.shape[-1]
     out = ragged_paged_attention_pallas(qq, kp, vp, tables, lens,
-                                        d2 ** -0.5)
+                                        d2 ** -0.5, kvh2)
     ks = kp[tables].reshape(R, -1, kvh2, d2)
     vs = vp[tables].reshape(R, -1, kvh2, d2)
     kpos = jnp.arange(ks.shape[1])[None, None, :]
@@ -205,7 +207,7 @@ def latent_paged_kernel():
     import os
     from paddle_tpu.generation.paged import PagedKV, paged_latent_attention
     R, P, B, M, h2, W, dv = 64, 8193, 16, 128, 64, 640, 512
-    kp = jnp.asarray(rs.randn(P, B, 1, W), jnp.bfloat16)
+    kp = jnp.asarray(rs.randn(P, B, W), jnp.bfloat16)
     tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
                          .reshape(R, M), jnp.int32)
     lens = jnp.asarray(([0, 15, 16, 2040, 100, 576, 1023, 300]
