@@ -22,27 +22,53 @@ TPU-native design — everything the XLA program sees is STATIC:
   bookkeeping between jitted calls — numpy lists, no recompiles. New
   requests are admitted mid-decode the moment a slot and blocks free
   up: the bucketed Predictor's whole-batch barrier is gone.
-- The decode tick itself is DEVICE-RESIDENT (ISSUE 6): block tables,
-  seq lens, per-row sampling params, PRNG keys, token budgets and the
-  active mask live on device as engine state advanced INSIDE the one
-  compiled tick program (attention → repetition penalty → sampling →
-  eos/budget done flags); the host reads back only (next_token,
-  logprob, done) per tick and re-uploads its numpy mirrors only on
-  slot transitions. Steady-state decode is therefore exactly one
-  dispatch + one small D2H per token — none of the per-tick
-  ``jnp.asarray`` uploads and Python stop/eos bookkeeping of the
-  original per-tick host path. ``fused_tick=False`` restores the
-  per-tick host path (the bit-exactness reference).
+- The served decode tick is DEVICE-RESIDENT (``fused_tick=True``, the
+  default): block tables, seq lens, per-row sampling params, PRNG keys,
+  token budgets and the active mask live on device as engine state
+  advanced INSIDE the one compiled tick program (staged transitions →
+  attention → repetition penalty → sampling → eos/budget done flags →
+  token-ring append). It is one path, always all of it:
+
+  * TRANSITIONS ARE STAGED. Each slot transition — admit, finish,
+    chunked-prefill advance, preempt, cancel, expiry, block growth —
+    packs ONE per-slot descriptor (``_pack_descriptor``: row index,
+    table row, lens/budget/eos, sampling params, PRNG key, spec state);
+    the pending descriptors, coalesced per slot, go to a device-resident
+    queue of ``max_slots`` rows in one plain upload (``_flush_patches``)
+    and the NEXT tick's program scatters them into its state before it
+    computes (``_apply_patch_queue``). Churn costs no dispatch of its
+    own; a steady tick uploads nothing.
+  * TOKENS RIDE A RING. The program appends what it commits to a
+    device-resident ring ([R, ring_len] with per-slot monotone write
+    cursors carried in the tick state); the host consumes the PREVIOUS
+    dispatch's slice at the top of the next ``step()``
+    (``_drain_pending``), so dispatches issue back to back. Stream
+    writes, stop matching, finishes and trace events are driven off
+    drained entries, one step behind the device. ``step()`` drains
+    before it touches any slot; an out-of-band cancel or expiry drains
+    only its own row (``_drain_row``), so the mirrors a transition
+    reads are never stale.
+  * A FULL REBUILD of the device state from the host mirrors
+    (``_refresh_dev``) happens at the first dispatch, after
+    ``hard_reset`` and when a ring cursor nears the end of int32
+    (``_RING_CURSOR_LIMIT``); ``full_rebuilds`` counts them.
+
+- ``fused_tick=False`` is the REFERENCE, not a served path: the plain
+  per-tick host loop (``_decode_host``) that uploads every mirror each
+  tick and does stop/eos/budget bookkeeping in Python. It is the one
+  thing the fused engine's streams are compared with: greedy and seeded
+  sampled streams are bitwise equal per request (tests/
+  test_fused_tick.py::TestFusedTickParity).
 - ``spec_tokens=k`` (ISSUE 7) turns each fused tick into a speculative
   MULTI-token tick: a device-resident prompt-lookup proposer (shared
   with ``ngram_speculative_generate``) drafts up to k tokens per slot
   from that request's own committed stream, one forward verifies all
   k+1 positions through the multi-query paged attention, and the
-  accepted length commits in-program — still one dispatch and one
-  small D2H per tick, with eos/stop/budget honored inside the accepted
-  window. Per-request adaptive k (device-resident accept-rate EMA) and
-  per-row headroom checks fall individual rows back to the 1-token
-  tick without leaving the program.
+  accepted length commits in-program — still one dispatch per tick,
+  with eos/stop/budget honored inside the accepted window. Per-request
+  adaptive k (device-resident accept-rate EMA) and per-row headroom
+  checks fall individual rows back to the 1-token tick without
+  leaving the program.
 - The verify is REJECTION-SAMPLED (ISSUE 11, Leviathan-style): every
   active row is spec-eligible, not just greedy+penalty-free ones.
   Greedy rows keep the bitwise longest-argmax-prefix rule; sampled
@@ -54,45 +80,6 @@ TPU-native design — everything the XLA program sees is STATIC:
   penalized rows compose — the repetition penalty is applied to each
   verify position over the window's own committed prefix (a
   sequential in-program scan over the k+1 positions).
-- ``ring_mode`` (ISSUE 11, default on with the fused tick) removes the
-  last per-tick host synchronization: instead of a blocking D2H of
-  (next_token, logprob, done) per dispatch, the tick program appends
-  committed tokens into a device-resident RING BUFFER ([R, ring_len]
-  with per-slot monotone write cursors carried in the tick state), and
-  the host consumes the PREVIOUS dispatch's ring slice at the top of
-  the next ``step()`` — by then the program has had a full host
-  iteration to complete, so the ``jax.device_get`` finds the data
-  ready (double-buffered, non-blocking D2H) and dispatches issue
-  back-to-back. Stream writes, stop matching, finishes and trace
-  events are driven off drained ring entries, one step behind the
-  device; every slot transition (admit / finish / chunk / preempt /
-  cancel / expire / block growth) drains fully first, so the host
-  mirrors a transition reads are never stale. ``ring_mode=False``
-  keeps the synchronous per-tick readback as the bit-exactness
-  reference — drained streams are pinned BITWISE identical to it.
-
-- ``delta_transitions`` (ISSUE 14, default on with the fused tick)
-  makes slot TRANSITIONS survive the dispatch pipeline: instead of
-  marking the whole device state dirty and rebuilding + re-uploading
-  every mirror (the ``_refresh_dev`` full rebuild, now the
-  ``delta_transitions=False`` reference path), each transition —
-  admit, finish, chunked-prefill advance, preempt, cancel, block
-  growth — packs ONE small per-slot descriptor (row index, tokens
-  head, table row, lens/budget/eos config, sampling params, PRNG key,
-  spec EMA) and a tiny compiled PATCH program scatters it into the
-  device-resident tick state in-program. Steady decode keeps issuing
-  back-to-back dispatches while churn costs one descriptor-sized H2D
-  (``h2d_upload_bytes`` counts the difference; ``full_rebuilds`` /
-  ``delta_patches`` count the events), and out-of-band transitions
-  (cancel, expiry) drain only the affected slot's pending ring
-  entries (``_drain_row``) instead of forcing a global drain.
-  Streams stay BITWISE identical to the full-rebuild reference per
-  request across every transition kind, ring on or off — with one
-  carve-out: sampled rows under ``spec_tokens>0`` are distribution-
-  preserving rather than bitwise (drafts may read the committed-token
-  buffer's uncommitted tail, which a rebuild zeroes and a patch
-  preserves; greedy spec stays bitwise — the argmax-prefix accept
-  rule is draft-invariant. See docs/PERFORMANCE.md).
 
 Padded prompt positions scatter into a reserved GARBAGE block (physical
 block 0) so they can never corrupt a live block; it is never allocated.
@@ -134,6 +121,11 @@ _NO_COUNTS = types.SimpleNamespace(total=None)   # a model with no counters
 _SPEC_EMA_ALPHA = 0.3      # EMA step toward this tick's accept fraction
 _SPEC_EMA_FLOOR = 0.25     # below: stop drafting (probes only)
 _SPEC_PROBE_EVERY = 16     # collapsed rows re-probe with k=1 this often
+
+# The token ring's write cursors are int32 and only a full rebuild of
+# the device state zeroes them: once a row has drained this many tokens
+# the next transition rebuilds (`_flush_patches`), long before the wrap.
+_RING_CURSOR_LIMIT = 2 ** 30
 
 
 def _home_device(params):
@@ -310,28 +302,64 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
         return dense_attention(q, ks, vs, attn_mask=keep[None, None])
 
 
-def paged_decode_route(q, kp, kv_heads: int, latent: bool = False) -> str:
-    """Which attention path ``paged_decode_attention`` takes for q
-    [R, T, h, d] against pools shaped like ``kp`` [P, B, kv_heads*d]:
-    ``"ragged"`` (the Pallas kernel that walks each row's own pages, the
-    default),
-    ``"grid"`` (the grid-per-row Pallas kernel, single-query only) or
-    ``"dense"`` (XLA whole-table gather). Only shapes are read, so a
+def paged_decode_route(q, kp, kv_heads: int) -> str:
+    """Which attention path ``paged_decode_attention`` and
+    ``paged_latent_attention`` take for q [R, T, h, d] against pools
+    shaped like ``kp`` [P, B, kv_heads*d]: ``"ragged"`` (the Pallas
+    kernel that walks each row's own pages) or ``"dense"`` (the XLA
+    whole-table gather). Shapes and the platform decide
+    (``use_ragged_kernel`` is the one gate) and nothing else does, so a
     caller can ask with the engine's geometry (``PagedEngine.
-    decode_route``) and see the choice the traced program made — the
-    shape gates drop to dense silently otherwise. ``latent``: ``kp`` is
-    a latent pool (no V pool beside it)."""
-    import os
+    decode_route``) and see the choice the traced program made. On the
+    chip a pool of one kv head x 64 columns takes the dense gather: its
+    page is not a whole 128-lane tile, and the grid-per-row kernel that
+    used to serve that one geometry is gone (no model or cell has it)."""
+    from ..ops.pallas.ragged_paged_attention import use_ragged_kernel
+    return "ragged" if use_ragged_kernel(q, kp, kv_heads) else "dense"
 
-    from ..ops.pallas.paged_attention import use_paged_kernel
-    from ..ops.pallas.ragged_paged_attention import pages_fill_lanes
-    mode = os.environ.get("PADDLE_TPU_PAGED_ATTN", "ragged")
-    if mode == "dense" or not use_paged_kernel(q, kp, kv_heads):
-        return "dense"
-    if mode == "grid" or not pages_fill_lanes(kp):
-        # the grid kernel is single-query and knows K and V pools only
-        return "grid" if q.shape[1] == 1 and not latent else "dense"
-    return "ragged"
+
+def _row_positions(pk: PagedKV, T: int, Tk: int):
+    """(key positions [1, 1, Tk], query positions [R, T, 1]): query t of
+    row r sits at ``seq_lens[r] + t``."""
+    return (jnp.arange(Tk)[None, None, :],
+            pk.seq_lens[:, None, None] + jnp.arange(T)[None, :, None])
+
+
+def paged_decode_attention_dense(q, pk: PagedKV,
+                                 scale: Optional[float] = None,
+                                 window: Optional[int] = None):
+    """``paged_decode_attention`` by the dense whole-table gather: every
+    row gathers all M of its table's pages and masks by position. The
+    math is dense_attention's; only the gather and the per-(row,
+    position) mask live here. It is the fallback where the kernel does
+    not serve (the CPU, odd shapes) and the reference a test or a
+    timing script compares the kernel with, by calling it."""
+    from ..ops.attention import dense_attention
+    R, T = q.shape[0], q.shape[1]
+    # the heads come apart in the rows GATHERED, not in the pool
+    ks = pk.split(pk.kp[pk.block_tables])        # [R, M, B, kvh, d]
+    vs = pk.split(pk.vp[pk.block_tables])
+    Tk = ks.shape[1] * ks.shape[2]
+    ks = ks.reshape((R, Tk) + ks.shape[3:])
+    vs = vs.reshape((R, Tk) + vs.shape[3:])
+    kpos, qpos = _row_positions(pk, T, Tk)
+    keep = kpos <= qpos                                   # [R, T, Tk]
+    if window is not None:
+        keep &= kpos > qpos - window
+    return dense_attention(q, ks, vs, attn_mask=keep[:, None],
+                           scale=scale)
+
+
+def _attend_ragged(q, pk: PagedKV, vp, scale: float, **kw):
+    """The ragged kernel over q [R, T, h, d] (its single-query form
+    takes [R, h, d]) and ``pk.kp`` with ``vp`` (None: a latent pool)."""
+    from ..ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_pallas
+    T = q.shape[1]
+    out = ragged_paged_attention_pallas(
+        q if T > 1 else q[:, 0], pk.kp, vp, pk.block_tables, pk.seq_lens,
+        scale, pk.heads, **kw)
+    return out if T > 1 else out[:, None]
 
 
 def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
@@ -342,48 +370,32 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     decode tick; T > 1 is the speculative verify's multi-query rows
     (ISSUE 7) — per-position causal masking inside the row.
 
-    Fast path (default "ragged"): the ragged kernel — one step per row,
-    which walks that row's LIVE pages, a run of them per compute block,
-    all kv heads at once; it serves both T == 1 and the multi-query
-    rows. ``PADDLE_TPU_PAGED_ATTN=grid`` keeps the
-    grid-per-row kernel (single-query only — multi-query falls through
-    to dense under it); ``=dense`` forces the fallback. Fallback (CPU
-    tests / odd shapes): dense whole-table gather — the math is
-    dense_attention's, only the gather and the per-(row, position) mask
-    live here. ``paged_decode_route`` is the one place that chooses."""
+    The ragged kernel — one step per row, which walks that row's LIVE
+    pages, a run of them per compute block, all kv heads at once —
+    serves both; where ``paged_decode_route`` says it does not,
+    ``paged_decode_attention_dense`` does."""
     with jax.named_scope("attn"):           # obs.TICK_SCOPES
-        from ..ops.attention import dense_attention
-        R, T = q.shape[0], q.shape[1]
-        route = paged_decode_route(q, pk.kp, pk.heads)
-        if route != "dense":
-            sc = scale if scale is not None else pk.width ** -0.5
-            if route == "grid":
-                from ..ops.pallas.paged_attention import \
-                    paged_attention_pallas
-                out = paged_attention_pallas(q[:, 0], pk.kp, pk.vp,
-                                             pk.block_tables, pk.seq_lens,
-                                             sc, pk.heads, window=window)
-                return out[:, None]
-            from ..ops.pallas.ragged_paged_attention import \
-                ragged_paged_attention_pallas
-            out = ragged_paged_attention_pallas(
-                q if T > 1 else q[:, 0], pk.kp, pk.vp, pk.block_tables,
-                pk.seq_lens, sc, pk.heads, window=window)
-            return out if T > 1 else out[:, None]
-        # the heads come apart in the rows GATHERED, not in the pool
-        ks = pk.split(pk.kp[pk.block_tables])        # [R, M, B, kvh, d]
-        vs = pk.split(pk.vp[pk.block_tables])
-        Tk = ks.shape[1] * ks.shape[2]
-        ks = ks.reshape((R, Tk) + ks.shape[3:])
-        vs = vs.reshape((R, Tk) + vs.shape[3:])
-        kpos = jnp.arange(Tk)[None, None, :]                  # [1, 1, Tk]
-        qpos = pk.seq_lens[:, None, None] + \
-            jnp.arange(T)[None, :, None]                      # [R, T, 1]
-        keep = kpos <= qpos                                   # [R, T, Tk]
-        if window is not None:
-            keep &= kpos > qpos - window
-        return dense_attention(q, ks, vs, attn_mask=keep[:, None],
-                               scale=scale)
+        if paged_decode_route(q, pk.kp, pk.heads) == "dense":
+            return paged_decode_attention_dense(q, pk, scale, window)
+        return _attend_ragged(
+            q, pk, pk.vp, scale if scale is not None else pk.width ** -0.5,
+            window=window)
+
+
+def paged_latent_attention_dense(q, pk: PagedKV, v_width: int,
+                                 scale: float):
+    """``paged_latent_attention`` by the dense whole-table gather:
+    fallback and reference, as ``paged_decode_attention_dense``."""
+    R, T = q.shape[0], q.shape[1]
+    # every head reads the one row: no per-head copy of the keys
+    ks = pk.kp[pk.block_tables]                  # [R, M, B, W]
+    ks = ks.reshape(R, -1, ks.shape[-1])
+    kpos, qpos = _row_positions(pk, T, ks.shape[1])
+    scores = jnp.einsum("rthw,rkw->rhtk", q, ks).astype(jnp.float32) \
+        * scale
+    scores = jnp.where((kpos <= qpos)[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("rhtk,rkv->rthv", probs, ks[..., :v_width])
 
 
 def paged_latent_attention(q, pk: PagedKV, v_width: int, scale: float):
@@ -392,27 +404,12 @@ def paged_latent_attention(q, pk: PagedKV, v_width: int, scale: float):
     columns (the queries folded through ``W_uk``, the roped part, zeros
     over the padding), keys the pool's rows, values their first
     ``v_width`` columns. Returns [R, T, h, v_width], still latent. The
-    ragged kernel walks each row's live pages once; the fallback is the
-    dense whole-table gather, as in ``paged_decode_attention``."""
+    ragged kernel walks each row's live pages once; the fallback is
+    ``paged_latent_attention_dense``."""
     with jax.named_scope("attn"):           # obs.TICK_SCOPES
-        R, T = q.shape[0], q.shape[1]
-        if paged_decode_route(q, pk.kp, pk.heads, latent=True) == "ragged":
-            from ..ops.pallas.ragged_paged_attention import \
-                ragged_paged_attention_pallas
-            out = ragged_paged_attention_pallas(
-                q if T > 1 else q[:, 0], pk.kp, None, pk.block_tables,
-                pk.seq_lens, scale, pk.heads, v_width=v_width)
-            return out if T > 1 else out[:, None]
-        # every head reads the one row: no per-head copy of the keys
-        ks = pk.kp[pk.block_tables]                  # [R, M, B, W]
-        ks = ks.reshape(R, -1, ks.shape[-1])
-        kpos = jnp.arange(ks.shape[1])[None, None, :]
-        qpos = pk.seq_lens[:, None, None] + jnp.arange(T)[None, :, None]
-        scores = jnp.einsum("rthw,rkw->rhtk", q, ks).astype(jnp.float32) \
-            * scale
-        scores = jnp.where((kpos <= qpos)[:, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        return jnp.einsum("rhtk,rkv->rthv", probs, ks[..., :v_width])
+        if paged_decode_route(q, pk.kp, pk.heads) == "dense":
+            return paged_latent_attention_dense(q, pk, v_width, scale)
+        return _attend_ragged(q, pk, None, scale, v_width=v_width)
 
 
 class _Request:
@@ -710,11 +707,6 @@ class PagedEngine:
                  ticks_per_dispatch: int = 1,
                  spec_tokens: int = 0,
                  spec_ngram: int = 2,
-                 ring_mode: Optional[bool] = None,
-                 ring_len: Optional[int] = None,
-                 delta_transitions: Optional[bool] = None,
-                 patch_fuse: Optional[bool] = None,
-                 patch_queue_len: Optional[int] = None,
                  tick_profile: bool = False,
                  profile_clock=None,
                  profile_ring_len: int = 1024):
@@ -806,8 +798,8 @@ class PagedEngine:
         reg = obs.registry()
         # counters the model's layers add up INSIDE a tick (an expert
         # layer's assignments and experts hit): they ride a spare row of
-        # the token ring, so the drain fetches nothing more for them,
-        # and are counted in ring mode only
+        # the token ring, so the drain fetches nothing more for them
+        # (the host reference has no ring and does not count them)
         self._tick_counter_names = tuple(
             getattr(model, "tick_counters", tuple)())
         self._tick_counts_seen = np.zeros(
@@ -815,12 +807,10 @@ class PagedEngine:
         # spec_proposed/spec_accepted (ISSUE 7): drafted vs accepted
         # draft tokens — `health()` derives the accept rate from the
         # SAME registry objects a /metrics scrape exports
-        # full_rebuilds / delta_patches / h2d_upload_bytes (ISSUE 14):
-        # the transition-cost trio — how often the whole device state
-        # was rebuilt, how often a one-row delta patch sufficed, and
-        # the actual bytes that crossed H2D either way (the event
-        # counter ``h2d_uploads`` weights both the same; the bytes
-        # counter is what the delta path shrinks)
+        # full_rebuilds / patches_fused / h2d_upload_bytes (ISSUE 14,
+        # 19): what transitions cost — how often the whole device state
+        # was rebuilt, how many descriptors rode the staged queue, and
+        # the bytes that crossed H2D either way
         self._counters = {
             k: reg.counter(f"paged_{k}_total", **self._obs_labels)
             for k in ("decode_steps", "prefills", "preemptions",
@@ -829,22 +819,20 @@ class PagedEngine:
                       "prefix_adopted_blocks", "timeouts",
                       "cancellations", "rejected",
                       "spec_proposed", "spec_accepted",
-                      "full_rebuilds", "delta_patches",
-                      "h2d_upload_bytes",
+                      "full_rebuilds", "h2d_upload_bytes",
                       "dispatches", "patches_fused",
-                      "patch_queue_overflows",
                       "ring_cursor_rollovers",
                       "spill_spans", "spill_restores",
                       "spill_restored_tokens",
                       "spill_restore_failures")
             + self._tick_counter_names}
         # paged_decode_step_ms is what the host can see of one decode
-        # dispatch: with a readback in the tick (host path, ring_mode
-        # off) the program's whole run, call to tokens on the host; in
-        # ring mode the tick returns without reading, so the window is
+        # dispatch: on the host reference path, which reads back in the
+        # tick, the program's whole run, call to tokens on the host; the
+        # fused tick returns without reading, so the window is
         # the next step's D2H read of the token ring (short when the
         # program finished while the host worked). The program's time
-        # on the device is the profiler trace's, in either mode.
+        # on the device is the profiler trace's, on either path.
         self._h_decode = reg.histogram("paged_decode_step_ms",
                                        buckets=obs.SERVING_MS_BUCKETS,
                                        **self._obs_labels)
@@ -859,8 +847,8 @@ class PagedEngine:
                                      **self._obs_labels)
         self._h_tpf = reg.histogram("paged_tokens_per_forward",
                                     **self._obs_labels)
-        # per-upload H2D size distribution (ISSUE 14): a one-row patch
-        # and a full-state rebuild land in very different buckets
+        # per-upload H2D size distribution (ISSUE 14): a staged queue,
+        # a full-state rebuild, one mirror of the host path
         self._h_bytes = reg.histogram("paged_h2d_bytes",
                                       buckets=obs.BYTES_BUCKETS,
                                       **self._obs_labels)
@@ -893,13 +881,14 @@ class PagedEngine:
         self._spill_upload_jit = jax.jit(self._spill_upload,
                                          donate_argnums=(0,))
         # --- device-resident fused tick (ISSUE 6 tentpole) ------------
-        # fused_tick=True keeps block tables / seq lens / sampling params
-        # / PRNG keys / done-bookkeeping ON DEVICE as engine state
-        # mutated by one compiled program per tick; the host reads back
-        # only (next_token, logprob, done) and re-uploads mirrors on
-        # SLOT TRANSITIONS (admit / finish / chunk / preempt / new
-        # block). fused_tick=False keeps the per-tick host path — the
-        # parity reference the fused stream must match bit-exactly.
+        # fused_tick=True (the served path) keeps block tables / seq
+        # lens / sampling params / PRNG keys / done-bookkeeping ON
+        # DEVICE as engine state mutated by one compiled program per
+        # tick; SLOT TRANSITIONS (admit / finish / chunk / preempt / new
+        # block) reach it as staged descriptors and tokens leave it
+        # through the ring (both below). fused_tick=False is the per-
+        # tick host path: the reference the fused streams must match
+        # bit-exactly, not a served mode.
         self._fused = bool(fused_tick)
         self._dev: Optional[Dict[str, Any]] = None   # device state dict
         self._dev_dirty = True          # host mirrors changed since build
@@ -910,8 +899,8 @@ class PagedEngine:
         # transition scatters on `seen` are not counted — they are slot-
         # transition work, not steady-state ticks). h2d_upload_bytes
         # (ISSUE 14 satellite) weighs each upload event by its actual
-        # size: a full-state rebuild and a one-row delta patch are both
-        # ONE h2d_uploads event but differ by orders of magnitude here.
+        # size: a full-state rebuild and a staged queue are both ONE
+        # h2d_uploads event.
         self.dispatch_count = 0
         # steps that dispatched a decode program: what a per-tick
         # figure divides by (``decode_steps`` counts K device ticks
@@ -921,9 +910,7 @@ class PagedEngine:
         self.h2d_uploads = 0
         self.h2d_upload_bytes = 0
         self.full_rebuilds = 0
-        self.delta_patches = 0
         self.patches_fused = 0
-        self.patch_queue_overflows = 0
         self.ring_cursor_rollovers = 0
         # NOTE: the small state dict is NOT donated — donating leaves
         # that pass through unchanged (tables, temps, ...) makes XLA
@@ -983,54 +970,36 @@ class PagedEngine:
             self._tick_spec_greedy_jit = jax.jit(
                 functools.partial(self._fused_tick_spec, greedy=True),
                 donate_argnums=(1, 2))
-        # --- async token ring (ISSUE 11 tentpole) ---------------------
-        # ring_mode=True (the default whenever the tick is fused): the
-        # tick program appends committed (token, logprob) pairs into a
-        # device-resident ring carried in the tick state; the host
-        # consumes the PREVIOUS dispatch's slice at the top of the next
-        # step() instead of blocking on a per-dispatch readback.
-        # ring_mode=False keeps the synchronous readback (the bit-
-        # exactness reference). The ring must hold every entry one
-        # dispatch can commit with double-buffer slack, so its length
-        # is floored at twice the largest per-dispatch advance
-        # (scan K ticks, or the spec window k+1).
-        self._ring = bool(fused_tick) if ring_mode is None \
-            else bool(ring_mode)
-        if self._ring and not self._fused:
-            raise ValueError(
-                "ring_mode requires fused_tick=True: the ring is "
-                "carried in the fused tick's device state")
+        # --- async token ring (ISSUE 11) ------------------------------
+        # the fused tick program appends committed (token, logprob)
+        # pairs into a device-resident ring carried in the tick state;
+        # the host consumes the PREVIOUS dispatch's slice at the top of
+        # the next step() (_drain_pending). The ring must hold every
+        # entry one dispatch can commit with double-buffer slack: twice
+        # the largest per-dispatch advance (scan K ticks, or the spec
+        # window k+1).
         maxadv = max(self._ticks_per_dispatch, self._spec_k + 1)
-        self._ring_len = max(16, 2 * maxadv) if ring_len is None \
-            else max(int(ring_len), 2 * maxadv)
+        self._ring_len = max(16, 2 * maxadv)
         self._pending: Optional[Dict[str, Any]] = None  # outstanding tick
         self._drained = np.zeros((self.R,), np.int64)   # consumed cursors
-        # readback instrumentation for the amortization contract:
-        # d2h_syncs counts BLOCKING readbacks (one per sync-mode tick;
-        # in ring mode only drains that actually had to wait),
-        # ring_drains counts pipelined ring consumptions and
-        # ring_scoped_drains the per-row out-of-band consumptions the
-        # delta path uses for cancel/expiry (ISSUE 14)
+        # readback instrumentation: d2h_syncs counts BLOCKING readbacks
+        # (every host-path tick; on the fused path only drains that had
+        # to wait, also counted in ring_blocking_drains), ring_drains
+        # every ring consumption and ring_scoped_drains the per-row
+        # out-of-band ones of cancel/expiry
         self.d2h_syncs = 0
         self.ring_drains = 0
         self.ring_blocking_drains = 0
         self.ring_scoped_drains = 0
-        # --- delta slot transitions (ISSUE 14 tentpole) ---------------
-        # delta_transitions=True (the default whenever the tick is
-        # fused): a slot transition packs ONE per-slot descriptor
-        # (_pack_descriptor) and a tiny compiled patch program
-        # (_apply_patch) scatters it into the device tick state —
-        # admits and finishes edit one row, block growth rewrites one
-        # table row — instead of marking the whole state dirty for a
-        # full _refresh_dev rebuild + re-upload. False keeps the
-        # all-or-nothing rebuild as the bit-exactness reference;
-        # streams are pinned BITWISE identical across both modes.
-        self._delta = bool(fused_tick) if delta_transitions is None \
-            else bool(delta_transitions)
-        if self._delta and not self._fused:
-            raise ValueError(
-                "delta_transitions requires fused_tick=True: patches "
-                "edit the fused tick's device-resident state")
+        # --- staged slot transitions (ISSUE 14, ISSUE 19) -------------
+        # a slot transition packs ONE per-slot descriptor
+        # (_pack_descriptor); the pending ones are staged into a
+        # device-resident queue ([R, desc_len] int32 + count, carried
+        # in the tick state) by a plain H2D upload — no dispatch — and
+        # the NEXT tick's program applies them all in a masked batched
+        # scatter before computing. One executable, one dispatch,
+        # whether the tick carries 0 or R transitions: descriptors
+        # coalesce per slot, so R rows always suffice.
         self._delta_rows: set = set()   # slots awaiting a patch flush
         # descriptor layout (int32 vector; floats/keys ride as raw
         # bits): [0]=row [1]=lens [2]=last [3]=eos [4]=rem [5]=active
@@ -1039,28 +1008,6 @@ class PagedEngine:
         # [15:15+M]=block-table row [15+M:]=committed-token row (spec)
         self._desc_len = 15 + self.M + (
             (self.M * self.B + self._spec_k + 1) if self._spec_k else 0)
-        if self._delta:
-            self._patch_jit = jax.jit(self._apply_patch)
-        # --- fused patch+tick program (ISSUE 19 tentpole) -------------
-        # patch_fuse=True (the default whenever delta transitions are
-        # on): pending descriptors are STAGED into a bounded
-        # device-resident queue ([Q, desc_len] int32 + count, carried
-        # in the tick state) by a plain H2D upload — no dispatch — and
-        # the NEXT tick's program applies them all in a masked batched
-        # scatter before computing. One executable, one dispatch,
-        # whether the tick carries 0 or R transitions; the standalone
-        # ``_apply_patch`` program survives only as the queue-overflow
-        # fallback (impossible at the default queue length Q=R, since
-        # descriptors coalesce per slot). False keeps the PR 12
-        # one-patch-one-dispatch path as a parity reference.
-        self._fuse_patches = self._delta if patch_fuse is None \
-            else bool(patch_fuse)
-        if self._fuse_patches and not self._delta:
-            raise ValueError(
-                "patch_fuse requires delta_transitions=True: the fused "
-                "queue stages the delta path's descriptors")
-        self._pq_len = self.R if patch_queue_len is None \
-            else max(1, int(patch_queue_len))
         # --- tick-phase profiler (ISSUE 20 tentpole) ------------------
         # tick_profile=True times each tick's phases (host staging /
         # H2D / dispatch / device wait / D2H drain) into per-phase
@@ -1125,16 +1072,14 @@ class PagedEngine:
     def decode_route(self) -> str:
         """The attention path this engine's decode tick takes
         (``paged_decode_route`` asked with the tick's own q and pool
-        shapes): "ragged" or "grid" is a Pallas kernel, "dense" the XLA
+        shapes): "ragged" is the Pallas kernel, "dense" the XLA
         whole-table gather."""
         cfg = self.model.config
-        rows = self._cache_rows()
-        heads, width = rows[0]      # a query is as wide as a cached head
-        q = jax.ShapeDtypeStruct(
+        heads, width = self._cache_rows()[0]    # a query is as wide as
+        q = jax.ShapeDtypeStruct(               # a cached head
             (self.R, self._spec_k + 1, cfg.num_attention_heads, width),
             cfg.dtype)
-        return paged_decode_route(q, self.pools[0][0], heads,
-                                  latent=len(rows) == 1)
+        return paged_decode_route(q, self.pools[0][0], heads)
 
     # ------------------------------------------------------ tick profiler
     @property
@@ -1305,18 +1250,17 @@ class PagedEngine:
         new_st.update(lens=st["lens"] + acti,
                       last=jnp.where(act, nxt, st["last"]),
                       keys=new_keys, rem=rem, active=act & ~done)
-        if "ring" in st:
-            # async token ring (ISSUE 11): append this tick's committed
-            # token into each active row's ring slot (write cursor mod
-            # ring length); inactive rows keep their current entry
-            r = jnp.arange(self.R)
-            idx = st["wcur"] % st["ring"].shape[1]
-            new_st.update(
-                ring=self._ring_counts(st["ring"].at[r, idx].set(
-                    jnp.where(act, nxt, st["ring"][r, idx])), counts),
-                rlps=st["rlps"].at[r, idx].set(
-                    jnp.where(act, lps, st["rlps"][r, idx])),
-                wcur=st["wcur"] + acti)
+        # async token ring (ISSUE 11): append this tick's committed
+        # token into each active row's ring slot (write cursor mod
+        # ring length); inactive rows keep their current entry
+        r = jnp.arange(self.R)
+        idx = st["wcur"] % st["ring"].shape[1]
+        new_st.update(
+            ring=self._ring_counts(st["ring"].at[r, idx].set(
+                jnp.where(act, nxt, st["ring"][r, idx])), counts),
+            rlps=st["rlps"].at[r, idx].set(
+                jnp.where(act, lps, st["rlps"][r, idx])),
+            wcur=st["wcur"] + acti)
         return (nxt, lps, done, seen,
                 [c.pool for c in new_caches], new_st)
 
@@ -1537,36 +1481,33 @@ class PagedEngine:
                       rem=rem - n_eff, active=active & ~done,
                       toks=toks, ema=ema,
                       tickc=st["tickc"] + active.astype(jnp.int32))
-        if "ring" in st:
-            # ring append of the emitted window (ISSUE 11): entries
-            # wcur..wcur+n_eff-1 mod ring_len; non-emitted positions
-            # keep the current ring contents. T <= ring_len/2, so the
-            # window's indices never collide within a row.
-            Lr = st["ring"].shape[1]
-            idx = (st["wcur"][:, None] + jnp.arange(T)[None, :]) % Lr
-            emit_win = jnp.arange(T)[None, :] < n_eff[:, None]
-            new_st.update(
-                ring=self._ring_counts(
-                    st["ring"].at[r_idx[:, None], idx].set(
-                        jnp.where(emit_win, G,
-                                  st["ring"][r_idx[:, None], idx])),
-                    counts),
-                rlps=st["rlps"].at[r_idx[:, None], idx].set(
-                    jnp.where(emit_win, LP, st["rlps"][r_idx[:, None],
-                                                       idx])),
-                wcur=st["wcur"] + n_eff,
-                kprop_last=kprop, macc_last=m)
+        # ring append of the emitted window (ISSUE 11): entries
+        # wcur..wcur+n_eff-1 mod ring_len; non-emitted positions
+        # keep the current ring contents. T <= ring_len/2, so the
+        # window's indices never collide within a row.
+        Lr = st["ring"].shape[1]
+        idx = (st["wcur"][:, None] + jnp.arange(T)[None, :]) % Lr
+        emit_win = jnp.arange(T)[None, :] < n_eff[:, None]
+        new_st.update(
+            ring=self._ring_counts(
+                st["ring"].at[r_idx[:, None], idx].set(
+                    jnp.where(emit_win, G,
+                              st["ring"][r_idx[:, None], idx])),
+                counts),
+            rlps=st["rlps"].at[r_idx[:, None], idx].set(
+                jnp.where(emit_win, LP, st["rlps"][r_idx[:, None], idx])),
+            wcur=st["wcur"] + n_eff,
+            kprop_last=kprop, macc_last=m)
         return (G, LP, n_eff, kprop, m, done, seen,
                 [c.pool for c in new_caches], new_st)
 
-    # --------------------------------- delta slot transitions (ISSUE 14)
+    # ------------------------------- staged slot transitions (ISSUE 14, 19)
     def _mark_dirty(self, slot_id: int):
-        """A slot transition touched ``slot_id``'s mirrors. Delta mode
-        queues a one-row patch (flushed immediately before the next
-        dispatch; multiple transitions of one slot coalesce into its
-        final state); rebuild mode (or no device state yet) falls back
-        to the all-or-nothing ``_dev_dirty`` -> ``_refresh_dev``."""
-        if self._delta and self._dev is not None and not self._dev_dirty:
+        """A slot transition touched ``slot_id``'s mirrors: queue its
+        descriptor for the next flush (several transitions of one slot
+        coalesce into its final state). Before there is a device state
+        to patch, the rebuild that makes it reads the mirrors whole."""
+        if self._dev is not None and not self._dev_dirty:
             self._delta_rows.add(slot_id)
         else:
             self._dev_dirty = True
@@ -1575,9 +1516,9 @@ class PagedEngine:
     def _slot_row_fields(s):
         """The (last, eos, rem, active) scalars ONE slot contributes
         to the device tick state — shared by the full rebuild (which
-        stacks R of them) and the delta descriptor (which uploads
+        stacks R of them) and the slot's descriptor (which carries
         exactly one), like ``token_buffer_row``/``seed_key_row``, so
-        the two upload paths cannot drift apart."""
+        the two uploads cannot drift apart."""
         eos = -1
         rem = last = act = 0
         if s is not None:
@@ -1595,8 +1536,7 @@ class PagedEngine:
         bits). Field values follow ``_refresh_dev``'s per-row rules
         exactly (``_slot_row_fields`` is the shared rule), so a
         patched row is byte-for-byte what a full rebuild would have
-        uploaded for it — the bitwise-parity contract between the two
-        modes is structural, not incidental. The PRNG key is flagged
+        uploaded for it. The PRNG key is flagged
         authoritative only for rows the HOST re-keyed (fresh admits,
         chunk-final): for every other row the device key stream —
         possibly advanced by sampled ticks since the last rebuild —
@@ -1624,52 +1564,18 @@ class PagedEngine:
         d[15:15 + self.M] = self.block_tables[i]
         return d
 
-    def _apply_patch(self, st, desc):
-        """ONE compiled program scattering a packed per-slot descriptor
-        into the device tick state: the in-program slot transition.
-        Ring arrays and write cursors are deliberately untouched — the
-        cursors are monotone and the host's drained cursor already
-        equals the row's device cursor whenever a transition patches
-        it (every deactivation passes through a drain first), so a
-        readmitted slot simply continues the ring where the previous
-        tenant stopped."""
-        M = self.M
-        r = desc[0]
-
-        def f32(x):
-            return jax.lax.bitcast_convert_type(x, jnp.float32)
-
-        new = dict(st)
-        new["tables"] = st["tables"].at[r].set(desc[15:15 + M])
-        new["lens"] = st["lens"].at[r].set(desc[1])
-        new["last"] = st["last"].at[r].set(desc[2])
-        new["eos"] = st["eos"].at[r].set(desc[3])
-        new["rem"] = st["rem"].at[r].set(desc[4])
-        new["active"] = st["active"].at[r].set(desc[5] != 0)
-        new["temps"] = st["temps"].at[r].set(f32(desc[7]))
-        new["tks"] = st["tks"].at[r].set(desc[8])
-        new["tps"] = st["tps"].at[r].set(f32(desc[9]))
-        new["reps"] = st["reps"].at[r].set(f32(desc[10]))
-        from .sampling import override_key_rows
-        key = jax.lax.bitcast_convert_type(desc[11:13], jnp.uint32)
-        new["keys"] = override_key_rows(st["keys"], desc[0:1],
-                                        key[None], desc[6:7])
-        if "toks" in st:
-            new["toks"] = st["toks"].at[r].set(desc[15 + M:])
-            new["ema"] = st["ema"].at[r].set(f32(desc[13]))
-            new["tickc"] = st["tickc"].at[r].set(desc[14])
-        return new
-
     def _apply_patch_queue(self, st):
-        """The fused patch stage (ISSUE 19): ONE masked batched scatter
+        """The patch stage (ISSUE 19): ONE masked batched scatter
         applying every staged descriptor in ``st["pq"]`` (valid rows:
         index < ``st["pqn"]``) to the device tick state, traced at the
         TOP of every fused tick program — the queue drains in the same
         dispatch that computes the tick, so a transition wave of any
-        size up to Q costs zero extra dispatches. Field ops mirror
-        ``_apply_patch`` one for one (same descriptor layout, same
-        ``override_key_rows`` key rule), so a queued patch lands
-        byte-identically to a standalone patch of the same descriptor.
+        size costs zero extra dispatches. Ring arrays and write cursors
+        are deliberately untouched — the cursors are monotone and the
+        host's drained cursor already equals the row's device cursor
+        whenever a transition patches it (every deactivation passes
+        through a drain first), so a readmitted slot simply continues
+        the ring where the previous tenant stopped.
         Invalid queue entries are routed to the out-of-bounds row index
         R and dropped (``mode="drop"``): a zero-count queue makes every
         scatter a bitwise no-op, which is what lets the stage ride
@@ -1677,8 +1583,6 @@ class PagedEngine:
         coalescing keys the pending set by slot), so scatter order
         never matters. ``pqn`` resets to 0 in-program; the staged
         ``pq`` array itself is replaced host-side at the next flush."""
-        if "pq" not in st:
-            return st
         from .sampling import override_key_rows
         pq, pqn = st["pq"], st["pqn"]
         M = self.M
@@ -1715,81 +1619,48 @@ class PagedEngine:
     def _flush_patches(self):
         """Hand every pending transition to the device (immediately
         before a dispatch, after the step's drain — so host mirrors and
-        device state agree for every untouched row).
-
-        Fused mode (ISSUE 19, the default): the coalesced descriptors
-        are STAGED into the device-resident patch queue with one plain
-        H2D upload — no dispatch — and the imminent tick program's
-        ``_apply_patch_queue`` stage applies them all in its batched
-        scatter. One executable, one dispatch, whether the tick carries
-        0 or R transitions: the synchronized-wave trade-off the old
-        per-row path documented is gone. The standalone ``_apply_patch``
-        program survives only as the queue-overflow fallback below
-        (impossible at the default Q=R — descriptors coalesce per slot
-        — and counter-pinned rare when a smaller queue is configured).
-
-        Non-fused delta mode: each patch is one descriptor-sized H2D +
-        one tiny compiled dispatch, the PR 12 parity reference.
+        device state agree for every untouched row): the coalesced
+        descriptors are STAGED into the device-resident patch queue
+        with one plain H2D upload — no dispatch — and the imminent tick
+        program's ``_apply_patch_queue`` stage applies them all in its
+        batched scatter.
 
         The caller contract that makes staging safe: `_sync_dev` is
-        only ever invoked by `_decode_fused`/`_decode_fused_spec`
-        immediately before their dispatch, so a staged queue is always
-        consumed by the very next program — key overrides can be
-        discarded at staging time exactly as the standalone patch path
-        discards them at patch time."""
-        if self._ring and int(self._drained.max(initial=0)) > 2 ** 30:
-            # int32 ring-cursor headroom guard: without periodic
-            # rebuilds the device write cursors grow forever; force
-            # one rebuild (which zeroes them) long before wraparound.
-            # Counted (ISSUE 19 satellite) so a long-lived replica's
-            # lone rebuild reads as cursor hygiene, not a bug.
+        only ever invoked by `_decode_fused` immediately before its
+        dispatch, so a staged queue is always consumed by the very next
+        program and key overrides can be discarded at staging time."""
+        if int(self._drained.max(initial=0)) > _RING_CURSOR_LIMIT:
+            # int32 ring-cursor headroom guard: the device write
+            # cursors grow until a rebuild zeroes them. Counted, so a
+            # long-lived replica's lone rebuild reads as cursor
+            # hygiene, not a bug.
             self.ring_cursor_rollovers += 1
             self._count("ring_cursor_rollovers")
             self._refresh_dev()
             return
         rows = sorted(self._delta_rows)
-        if self._fuse_patches and len(rows) <= self._pq_len:
-            pq = np.zeros((self._pq_len, self._desc_len), np.int32)
-            for j, i in enumerate(rows):
-                pq[j] = self._pack_descriptor(i)
-                self._key_overrides.discard(i)
-            with self._phase("h2d"):
-                self._dev["pq"] = self._put(pq)
-                self._dev["pqn"] = self._put(np.int32(len(rows)))
-            nbytes = pq.nbytes + 4
-            self.h2d_uploads += 1
-            self.h2d_upload_bytes += nbytes
-            self.patches_fused += len(rows)
-            self._count("patches_fused", len(rows))
-            self._count("h2d_upload_bytes", nbytes)
-            self._h_bytes.observe(nbytes)
-            self._delta_rows.clear()
-            return
-        if self._fuse_patches:
-            self.patch_queue_overflows += 1
-            self._count("patch_queue_overflows")
-        for i in rows:
-            desc = self._pack_descriptor(i)
-            self.h2d_uploads += 1
-            self.h2d_upload_bytes += desc.nbytes
-            self.delta_patches += 1
-            self.dispatch_count += 1
-            self._count("dispatches")
-            self._count("delta_patches")
-            self._count("h2d_upload_bytes", desc.nbytes)
-            self._h_bytes.observe(desc.nbytes)
-            self._dev = self._patch_jit(self._dev, self._put(desc))
-            # the device now holds this row's authoritative key (the
-            # patch either uploaded the host's override or preserved
-            # the device stream), same as a rebuild's upload
+        # the queue has a row a slot and descriptors coalesce per slot
+        assert len(rows) <= self.R, rows
+        pq = np.zeros((self.R, self._desc_len), np.int32)
+        for j, i in enumerate(rows):
+            pq[j] = self._pack_descriptor(i)
             self._key_overrides.discard(i)
+        with self._phase("h2d"):
+            self._dev["pq"] = self._put(pq)
+            self._dev["pqn"] = self._put(np.int32(len(rows)))
+        nbytes = pq.nbytes + 4
+        self.h2d_uploads += 1
+        self.h2d_upload_bytes += nbytes
+        self.patches_fused += len(rows)
+        self._count("patches_fused", len(rows))
+        self._count("h2d_upload_bytes", nbytes)
+        self._h_bytes.observe(nbytes)
         self._delta_rows.clear()
 
     def _sync_dev(self):
         """Bring the device tick state up to date before a dispatch:
-        full rebuild when forced (first dispatch, ``hard_reset``,
-        ``delta_transitions=False``), else flush pending one-row
-        patches."""
+        full rebuild when forced (first dispatch, ``hard_reset``), else
+        stage the pending descriptors."""
         if self._dev is None or self._dev_dirty:
             self._refresh_dev()
         elif self._delta_rows:
@@ -1811,12 +1682,9 @@ class PagedEngine:
 
     def _refresh_dev(self):
         """FULL rebuild of the device-resident tick state from the host
-        mirrors. With ``delta_transitions=False`` this runs on every
-        slot transition (admissions, finishes, chunk advances,
-        preemptions, block growth — never on a steady-state tick); in
-        delta mode it is the forced-rebuild path only (first dispatch,
-        ``hard_reset``, ring-cursor headroom guard) and transitions
-        ride one-row ``_apply_patch`` programs instead."""
+        mirrors: the first dispatch, the one after ``hard_reset`` and
+        the ring-cursor headroom guard. Every other transition rides
+        the staged queue (``_flush_patches``)."""
         self._sync_keys_from_dev()
         self._key_overrides.clear()
         eos = np.full((self.R,), -1, np.int32)
@@ -1865,36 +1733,32 @@ class PagedEngine:
                 nbytes += tk.nbytes + ema.nbytes
                 self._dev.update(toks=self._put(tk), ema=self._put(ema),
                                  tickc=self._zeros((self.R,), jnp.int32))
-            if self._ring:
-                # async token ring (ISSUE 11): rebuilt empty on every
-                # refresh — a refresh only ever runs with the ring fully
-                # drained (every transition drains first), so resetting
-                # the write cursors cannot lose entries
-                # (a model with tick counters gets one spare row: theirs)
-                spare = 1 if self._tick_counter_names else 0
-                self._tick_counts_seen[:] = 0
+            # async token ring (ISSUE 11): rebuilt empty on every
+            # refresh — a refresh only ever runs with the ring fully
+            # drained (every transition drains first), so resetting
+            # the write cursors cannot lose entries
+            # (a model with tick counters gets one spare row: theirs)
+            spare = 1 if self._tick_counter_names else 0
+            self._tick_counts_seen[:] = 0
+            self._dev.update(
+                ring=self._zeros((self.R + spare, self._ring_len),
+                                 jnp.int32),
+                rlps=self._zeros((self.R, self._ring_len), jnp.float32),
+                wcur=self._zeros((self.R,), jnp.int32))
+            if self._spec_k:
+                # per-dispatch proposer stats ride the state so the
+                # drain can count spec_proposed/accepted without a
+                # second readback
                 self._dev.update(
-                    ring=self._zeros((self.R + spare, self._ring_len),
-                                     jnp.int32),
-                    rlps=self._zeros((self.R, self._ring_len), jnp.float32),
-                    wcur=self._zeros((self.R,), jnp.int32))
-                if self._spec_k:
-                    # per-dispatch proposer stats ride the state so the
-                    # drain can count spec_proposed/accepted without a
-                    # second readback
-                    self._dev.update(
-                        kprop_last=self._zeros((self.R,), jnp.int32),
-                        macc_last=self._zeros((self.R,), jnp.int32))
-                self._drained[:] = 0
-            if self._fuse_patches:
-                # empty staged-patch queue: a rebuild by definition leaves
-                # nothing pending (bytes not counted — zeros carry no
-                # host-side payload, and the tests pin the rebuild byte
-                # cost as the non-fused reference)
-                self._dev.update(
-                    pq=self._zeros((self._pq_len, self._desc_len),
-                                   jnp.int32),
-                    pqn=self._zeros((), jnp.int32))
+                    kprop_last=self._zeros((self.R,), jnp.int32),
+                    macc_last=self._zeros((self.R,), jnp.int32))
+            self._drained[:] = 0
+            # empty staged-patch queue: a rebuild by definition leaves
+            # nothing pending (bytes not counted — zeros carry no
+            # host-side payload)
+            self._dev.update(
+                pq=self._zeros((self.R, self._desc_len), jnp.int32),
+                pqn=self._zeros((), jnp.int32))
         self.h2d_upload_bytes += nbytes
         self._count("h2d_upload_bytes", nbytes)
         self._h_bytes.observe(nbytes)
@@ -2052,7 +1916,7 @@ class PagedEngine:
         if self.trace_sink is not None:
             self.trace_sink(request_id, "engine_queue",
                             queued=len(self.queue))
-        if self._fuse_patches and self.chunk is not None:
+        if self._fused and self.chunk is not None:
             # ROADMAP 4(b), first rung: a warm replica admits eagerly
             # at submit time. Chunked admission is dispatch-free — it
             # claims a slot, allocates blocks and marks the row dirty;
@@ -2757,10 +2621,9 @@ class PagedEngine:
         """Abort queued and running requests whose deadline passed (the
         per-request timeout contract: checked once per scheduler tick —
         a jitted call is never interrupted mid-flight). A running
-        expiry drains first (ring mode: never abort against a stale
-        mirror / in-flight dispatch) — scoped to the expiring row in
-        delta mode, so a queue-capacity reap on the submit path no
-        longer forces a global drain."""
+        expiry drains first (never abort against a stale mirror / an
+        in-flight dispatch), its own row only: a queue-capacity reap on
+        the submit path forces no global drain."""
         now = time.monotonic()
         for req in [r for r in self.queue
                     if r.deadline is not None and now > r.deadline]:
@@ -2770,7 +2633,7 @@ class PagedEngine:
             s = self.slots[i]
             if s is not None and s.deadline is not None \
                     and now > s.deadline:
-                self._drain_slot(i)
+                self._drain_row(i)
                 s = self.slots[i]   # the drain may have finished it
                 if s is not None and s.deadline is not None \
                         and now > s.deadline:
@@ -2785,9 +2648,8 @@ class PagedEngine:
         A RUNNING cancel racing an in-flight dispatch drains that
         slot's undrained ring entries first, so the release below
         cannot orphan ring tokens or free blocks the in-flight program
-        still writes — scoped to the cancelled row in delta mode
-        (ISSUE 14: the siblings' pending tokens stay pending), the
-        global drain in rebuild mode."""
+        still writes — scoped to the cancelled row (ISSUE 14: the
+        siblings' pending tokens stay pending)."""
         for req in self.queue:
             if req.request_id == request_id:
                 self.queue.remove(req)
@@ -2796,7 +2658,7 @@ class PagedEngine:
         for i in range(self.R):
             s = self.slots[i]
             if s is not None and s.request_id == request_id:
-                self._drain_slot(i)
+                self._drain_row(i)
                 s = self.slots[i]
                 if s is None or s.request_id != request_id:
                     return False   # finished in the drained entries
@@ -2813,8 +2675,7 @@ class PagedEngine:
             snap.get("spec_accepted", 0) / prop, 4) if prop else 0.0
         # the one-dispatch-per-tick claim (ISSUE 19), observable
         # fleet-wide: a steady fused replica reads ~1.0 plus the
-        # amortized prefill share; standalone patches and rebuilds
-        # push it above
+        # amortized prefill share
         ticks = snap.get("decode_steps", 0)
         snap["dispatches_per_tick"] = round(
             snap.get("dispatches", 0) / ticks, 4) if ticks else 0.0
@@ -2905,23 +2766,18 @@ class PagedEngine:
                        for r in list(self.queue)[:max_digests]],
             "spec": {"enabled": bool(self._spec_k), "k": self._spec_k,
                      "ngram": self._spec_ngram if self._spec_k else 0},
-            "ring": {"enabled": self._ring, "ring_len": self._ring_len,
+            "ring": {"ring_len": self._ring_len,
                      "outstanding": self._pending is not None,
                      "drains": self.ring_drains,
                      "blocking_drains": self.ring_blocking_drains,
                      "scoped_drains": self.ring_scoped_drains,
                      "d2h_syncs": self.d2h_syncs},
             # slot-transition cost accounting (ISSUE 14): how churn is
-            # being paid for — one-row patches vs full-state rebuilds,
-            # and the H2D bytes either way
+            # being paid for — descriptors staged vs full-state
+            # rebuilds, and the H2D bytes either way
             "transitions": {
-                "delta_enabled": self._delta,
-                "patch_fuse_enabled": self._fuse_patches,
-                "patch_queue_len": self._pq_len,
                 "full_rebuilds": self.full_rebuilds,
-                "delta_patches": self.delta_patches,
                 "patches_fused": self.patches_fused,
-                "patch_queue_overflows": self.patch_queue_overflows,
                 "ring_cursor_rollovers": self.ring_cursor_rollovers,
                 "pending_patch_rows": pending,
                 "h2d_uploads": self.h2d_uploads,
@@ -2948,7 +2804,7 @@ class PagedEngine:
         calls, so it works whatever state the accelerator is in).
 
         The host mirrors advance only when tokens are DRAINED
-        (``_consume_row``), so an in-flight ring/fused dispatch's
+        (``_commit_row_drain``), so an in-flight ring/fused dispatch's
         uncommitted tokens are invisible here and simply die with the
         replica — exactly the tokens no client ever saw. Each
         descriptor is the ``_preempt_youngest`` transform, ready for
@@ -2962,8 +2818,8 @@ class PagedEngine:
         def _desc(s: "_Request") -> Dict[str, Any]:
             # one consistent snapshot of the (tokens, lps) pair: a
             # SLOW-but-alive tick can still be appending (tokens
-            # first, then lps — see _consume_row), so read lps first
-            # and truncate both to the paired length; every derived
+            # first, then lps — see _commit_row_drain), so read lps
+            # first and truncate both to the paired length; every derived
             # field below uses the SAME n, keeping committed a strict
             # tail of prompt and remaining consistent with it
             lps = list(s.lps)
@@ -3054,12 +2910,12 @@ class PagedEngine:
 
     @_on_device
     def step(self):
-        """One scheduler tick: drain the previous ring dispatch (ring
-        mode — its tokens land here, one step behind the device),
-        expire overdue requests, admit EVERY queued request that fits
-        (slots + blocks), advance one prefill chunk per prefilling
-        slot, then one decode for all prefill-complete slots (ring
-        mode dispatches WITHOUT a readback and returns).
+        """One scheduler tick: drain the previous dispatch's ring slice
+        (its tokens land here, one step behind the device), expire
+        overdue requests, admit EVERY queued request that fits (slots +
+        blocks), advance one prefill chunk per prefilling slot, then
+        one decode for all prefill-complete slots (dispatched WITHOUT a
+        readback).
 
         With ``tick_profile`` on, the whole tick runs inside one
         profiler window: every bracketed phase of ``obs.TICK_PHASES``
@@ -3122,14 +2978,12 @@ class PagedEngine:
         self.decode_ticks += 1
         if not self._fused:
             return self._decode_host(active)
-        if self._spec_k:
-            return self._decode_fused_spec(active)
         return self._decode_fused(active, scan=scan)
 
     def _drain_pending(self):
-        """Consume the outstanding ring dispatch (ring mode): fetch the
-        ring entries committed since the last drain and run the host
-        bookkeeping the sync path did inline — token/logprob appends,
+        """Consume the outstanding dispatch: fetch the ring entries
+        committed since the last drain and run the host bookkeeping
+        the reference path does inline — token/logprob appends,
         stop matching (a stop completing from a DRAINED token finishes
         the request; tokens the device committed past it die with the
         slot release), device finish flags, spec counters/EMA mirrors,
@@ -3171,8 +3025,8 @@ class PagedEngine:
                 br.switch("drain")
             t0 = time.perf_counter()
             vals = jax.device_get(arrs)
-            # ring mode's decode-step histogram window is the drain wait —
-            # the only host-visible program-bound time left on the path
+            # the decode-step histogram's window is the drain wait — the
+            # only host-visible program-bound time left on the path
             self._h_decode.observe((time.perf_counter() - t0) * 1e3)
             br.switch("commit")
             ring, rlps, wcur, act_now = vals[:4]
@@ -3207,11 +3061,17 @@ class PagedEngine:
         """Per-row host bookkeeping shared by the global drain's loop
         and the scoped drain (ISSUE 14) — one implementation so the
         two paths cannot drift: advance the drained cursor, mirror the
-        device spec EMA, append/stop-match via ``_consume_row``, emit
-        the trace tick event, honor the device finish flag.
-        ``ring_i``/``rlps_i`` are this row's ring slices; ``kp``/``ma``
-        its spec counters (0 when spec is off). Returns False for rows
-        released out-of-band since dispatch (cursor still advanced)."""
+        device spec EMA, append the row's new ring entries — stop check
+        FIRST, so a stop completing on the final budgeted (or eos)
+        token still records its trim length; tokens the device
+        committed past a stop die with the slot release (the
+        scan/spec/ring over-commit contract) — emit the trace tick
+        event, then finish on a host stop or the device finish flag
+        (the tick -> engine_finish event order the reqtrace pins rely
+        on). ``ring_i``/``rlps_i`` are this row's ring slices;
+        ``kp``/``ma`` its spec counters (0 when spec is off). Returns
+        False for rows released out-of-band since dispatch (cursor
+        still advanced)."""
         slot = self.slots[i]
         base = int(self._drained[i])
         n_new = int(wc) - base
@@ -3226,23 +3086,29 @@ class PagedEngine:
                 slot.spec_ema = ((1.0 - _SPEC_EMA_ALPHA) * slot.spec_ema
                                  + _SPEC_EMA_ALPHA
                                  * (float(ma) / float(kp)))
-        Lr = self._ring_len
-        appended, finished = self._consume_row(
-            i, ((ring_i[(base + j) % Lr], rlps_i[(base + j) % Lr],
-                 False) for j in range(n_new)))
+        appended = 0
+        stopped = False
+        for j in range(base, base + n_new):
+            self._count("active_slot_steps")
+            self.seq_lens[i] += 1   # device advanced its copy too
+            slot.tokens.append(int(ring_i[j % self._ring_len]))
+            slot.lps.append(float(rlps_i[j % self._ring_len]))
+            appended += 1
+            if self._stop_hit(slot):
+                stopped = True
+                break
         if self.trace_sink is not None:
             ev = dict(n=appended, ring_lag=lag)
             if self._spec_k:
                 ev.update(proposed=int(kp), accepted=int(ma))
-            if self._prof is not None:
-                # ring drains commit one dispatch behind — this is the
-                # LAST COMPLETED tick's split, the one whose tokens are
-                # being committed here
-                ph = self._prof.last_phases()
-                if ph is not None:
-                    ev["phase"] = ph
+            # ring drains commit one dispatch behind — this is the LAST
+            # COMPLETED tick's split, the one whose tokens are being
+            # committed here
+            ph = self._tick_phase_fields()
+            if ph is not None:
+                ev["phase"] = ph
             self.trace_sink(slot.request_id, "tick", **ev)
-        if finished or not bool(act_i):
+        if stopped or not bool(act_i):
             # host stop, or the device finish flag (eos/budget)
             self._finish(i)
         return True
@@ -3290,8 +3156,7 @@ class PagedEngine:
                 br.switch("drain")
             t0 = time.perf_counter()
             vals = jax.device_get([a[i] for a in base_arrs])
-            # same histogram window as the global drain: in ring mode the
-            # drain wait is the program-bound time, scoped drains included
+            # same histogram window as the global drain
             self._h_decode.observe((time.perf_counter() - t0) * 1e3)
             br.switch("commit")
             ring_i, rlps_i, wc, act_i = vals[:4]
@@ -3308,41 +3173,6 @@ class PagedEngine:
                 if ma:
                     self._count("spec_accepted", ma)
 
-    def _drain_slot(self, i: int):
-        """Drain before mutating slot ``i``'s mirrors out-of-band:
-        scoped to the row in delta mode, the full global drain in
-        rebuild mode (whose transition semantics it preserves)."""
-        if self._delta:
-            self._drain_row(i)
-        else:
-            self._drain_pending()
-
-    def _consume_row(self, i, entries):
-        """Shared per-row commit bookkeeping for every readback flavor
-        (sync tick/scan loop, sync spec window, ring drain): append
-        each ``(token, logprob, device_done)`` entry onto the slot —
-        stop check FIRST so a stop completing on the final budgeted
-        (or eos) token still records its trim length — and stop
-        consuming at a host stop or an entry's device done flag.
-        Tokens past the cut die with the slot release (the
-        scan/spec/ring over-commit contract). Returns
-        ``(appended, finished)``; the CALLER emits its trace event and
-        then finishes, keeping the tick -> engine_finish event order
-        the reqtrace pins rely on."""
-        slot = self.slots[i]
-        appended = 0
-        finished = False
-        for tok, lp, dflag in entries:
-            self._count("active_slot_steps")
-            self.seq_lens[i] += 1   # device advanced its copy too
-            slot.tokens.append(int(tok))
-            slot.lps.append(float(lp))
-            appended += 1
-            if self._stop_hit(slot) or dflag:
-                finished = True
-                break
-        return appended, finished
-
     def _up(self, x):
         """Host-mirror upload on the per-tick host path (counted so the
         fused path's zero-upload steady state is testable; bytes too —
@@ -3355,9 +3185,11 @@ class PagedEngine:
             return self._put(x)
 
     def _decode_host(self, active):
-        """The pre-fusion per-tick path: re-uploads every mirror and
-        runs all stop/eos/budget bookkeeping in Python. Kept as the
-        bit-exactness reference for the fused tick."""
+        """The REFERENCE tick (``fused_tick=False``), not a served path:
+        re-uploads every mirror, reads (next_token, logprob) back in
+        the tick and runs all stop/eos/budget bookkeeping in Python.
+        It is the one bit-exactness reference: every stream of the
+        fused tick is compared with this one's."""
         t_decode = time.perf_counter()
         last = np.zeros((self.R,), np.int32)
         for i in active:
@@ -3425,76 +3257,41 @@ class PagedEngine:
         return True
 
     def _decode_fused(self, active, scan: bool = False):
-        """Steady-state fused tick: ONE compiled dispatch advancing every
-        active slot (attention → penalty → sampling → done flags, all
-        device-state mutations inside the program) and one small D2H
-        readback of (next_token, logprob, done). Mirrors re-upload only
-        when a slot transition dirtied them. With ``scan=True`` (caller
-        proved eligibility via _scan_ticks) the one dispatch is the
-        K-tick lax.scan program — same host bookkeeping, a [K, R]
-        readback, and the decode-step histogram then records the whole
-        dispatch wall (divide by ticks_per_dispatch for per-token)."""
+        """The served tick's host half: ONE compiled dispatch advancing
+        every active slot (staged transitions → attention → penalty →
+        sampling → done flags → ring append, all device-state mutations
+        inside the program) and NO readback: the committed tokens land
+        in the device ring and the next ``step()``'s drain consumes them
+        while this program runs. Host bookkeeping (appends, stops
+        inside a speculative window, finishes, spec counters and the
+        EMA mirror, traces) happens there, one step behind the device.
+        The program is the speculative tick under ``spec_tokens``, the
+        K-tick lax.scan with ``scan=True`` (caller proved eligibility
+        via _scan_ticks), else the plain tick; its token outputs are
+        not fetched."""
         K = self._ticks_per_dispatch if scan else 1
         with self._phase("stage") as br:
             self._sync_dev()
-            t_decode = time.perf_counter()
             self.dispatch_count += 1
             self._count("dispatches")
             greedy = np.all(self.temps[active] <= 0.0)
-            if scan:
+            if self._spec_k:
+                fn = self._tick_spec_greedy_jit if greedy \
+                    else self._tick_spec_jit
+            elif scan:
                 fn = self._scan_greedy_jit if greedy else self._scan_jit
             else:
                 fn = self._tick_greedy_jit if greedy else self._tick_jit
-            # dispatch = the program CALL (enqueue; async under ring
-            # mode) — compute lands in the drain boundary's device wait
+            # dispatch = the program CALL (enqueue; asynchronous) —
+            # compute lands in the drain boundary's device wait
             br.switch("dispatch")
-            nxt, lps, done, self.seen, self.pools, self._dev = fn(
+            *_, self.seen, self.pools, self._dev = fn(
                 self.params, self.pools, self.seen, self._dev)
         if not greedy:
             self._dev_keys_dirty = True
-        if self._ring:
-            # async ring (ISSUE 11): NO readback — the program's
-            # committed tokens land in the device ring; the next
-            # step()'s drain consumes them while this program runs.
-            # Host bookkeeping (appends, stops, finishes, traces)
-            # happens there, one step behind the device.
-            self._pending = dict(rows=list(active),
-                                 seq=self.dispatch_count)
-            self._count("decode_steps", K)
-            self._count("slot_steps", self.R * K)
-            return True
-        self.d2h_syncs += 1
-        with self._phase("device") as br:
-            if br.on:
-                try:
-                    jax.block_until_ready((nxt, lps, done))
-                except Exception:
-                    pass
-                br.switch("drain")
-            nxt, lps, done = jax.device_get((nxt, lps, done))
-            br.switch("commit")
-            if not scan:                     # [R] -> [1, R]: one tick loop
-                nxt, lps, done = nxt[None], lps[None], done[None]
-            self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
-            self._count("decode_steps", K)
-            self._count("slot_steps", self.R * K)
-            sink = self.trace_sink
-            for i in active:
-                slot = self.slots[i]
-                # scan ticks past a row's done flag are garbage the
-                # consume cut never reads (the device active mask froze
-                # them)
-                appended, finished = self._consume_row(
-                    i, ((nxt[k, i], lps[k, i], bool(done[k, i]))
-                        for k in range(K)))
-                if sink is not None:
-                    ev = dict(n=appended)
-                    ph = self._tick_phase_fields()
-                    if ph is not None:
-                        ev["phase"] = ph
-                    sink(slot.request_id, "tick", **ev)
-                if finished:
-                    self._finish(i)
+        self._pending = dict(rows=list(active), seq=self.dispatch_count)
+        self._count("decode_steps", K)
+        self._count("slot_steps", self.R * K)
         return True
 
     def _spec_headroom(self, active):
@@ -3519,83 +3316,6 @@ class PagedEngine:
                 self.M)
             if not self._grow_blocks(i, need, reserve=len(active)):
                 return
-
-    def _decode_fused_spec(self, active):
-        """The speculative fused tick's host half: ONE dispatch, one
-        small D2H of (candidates [R, k+1], logprobs, accepted length,
-        proposed/accepted counts, done), then per-row bookkeeping over
-        each row's ACCEPTED window — appending tokens, checking stop
-        sequences inside the window (a stop mid-window finishes the
-        request; the tokens the device committed past it die with the
-        slot's release), and honoring the device done flag. Mirrors
-        re-upload only on slot transitions, exactly like the plain
-        fused tick."""
-        with self._phase("stage") as br:
-            self._sync_dev()
-            t_decode = time.perf_counter()
-            self.dispatch_count += 1
-            self._count("dispatches")
-            greedy = np.all(self.temps[active] <= 0.0)
-            fn = self._tick_spec_greedy_jit if greedy else self._tick_spec_jit
-            br.switch("dispatch")
-            (nxt, lps, nacc, kprop, macc, done, self.seen, self.pools,
-             self._dev) = fn(self.params, self.pools, self.seen, self._dev)
-        if not greedy:
-            self._dev_keys_dirty = True
-        if self._ring:
-            # async ring (ISSUE 11): the accepted window rides the
-            # device ring; next step()'s drain appends it (spec
-            # counters/EMA from the kprop_last/macc_last state slots)
-            self._pending = dict(rows=list(active),
-                                 seq=self.dispatch_count)
-            self._count("decode_steps")
-            self._count("slot_steps", self.R)
-            return True
-        self.d2h_syncs += 1
-        with self._phase("device") as br:
-            if br.on:
-                try:
-                    jax.block_until_ready((nxt, lps, nacc, kprop, macc,
-                                           done))
-                except Exception:
-                    pass
-                br.switch("drain")
-            nxt, lps, nacc, kprop, macc, done = jax.device_get(
-                (nxt, lps, nacc, kprop, macc, done))
-            br.switch("commit")
-            self._h_decode.observe((time.perf_counter() - t_decode) * 1e3)
-            self._count("decode_steps")
-            self._count("slot_steps", self.R)
-            prop = int(kprop[active].sum())
-            if prop:
-                self._count("spec_proposed", prop)
-                acc = int(macc[active].sum())
-                if acc:
-                    self._count("spec_accepted", acc)
-            sink = self.trace_sink
-            for i in active:
-                slot = self.slots[i]
-                n = int(nacc[i])
-                self._h_tpf.observe(n)
-                if kprop[i]:
-                    # host mirror of the device EMA (same update; the
-                    # authority switch happens at the next refresh upload)
-                    slot.spec_ema = ((1.0 - _SPEC_EMA_ALPHA) * slot.spec_ema
-                                     + _SPEC_EMA_ALPHA
-                                     * (float(macc[i]) / float(kprop[i])))
-                appended, finished = self._consume_row(
-                    i, ((nxt[i, j], lps[i, j], False) for j in range(n)))
-                if sink is not None:
-                    ev = dict(n=appended, proposed=int(kprop[i]),
-                              accepted=int(macc[i]))
-                    ph = self._tick_phase_fields()
-                    if ph is not None:
-                        ev["phase"] = ph
-                    sink(slot.request_id, "tick", **ev)
-                if finished or bool(done[i]):
-                    # host stop, or the device finish flag (eos/budget)
-                    self._finish(i)
-        return True
 
     def _scan_ticks(self, active) -> bool:
         """True when the next ``ticks_per_dispatch`` ticks may run inside

@@ -18,8 +18,8 @@ escape hatch that lowering refused for (kv, d) = (4, 64):
   blocked ``(1, bt, cw)`` where the column width ``cw`` covers one kv
   head when ``d % 128 == 0`` and a PAIR of heads when ``d == 64`` —
   ``cw`` is always a 128 multiple and ``bt`` always an 8 multiple. The
-  same trick ``paged_attention.py`` used passed that window's compile
-  check while this kernel's rank-4 spec failed it.
+  same trick ``paged_attention.py`` (since deleted) used passed that
+  window's compile check while this kernel's rank-4 spec failed it.
 - the grid is ``(b, nc, nt)`` with the KV-length dim innermost so the
   fp32 accumulator scratch carries the online softmax across blocks;
   ``nc = kv / heads_per_block`` column blocks replace the old in-kernel

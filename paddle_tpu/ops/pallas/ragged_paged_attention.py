@@ -60,12 +60,27 @@ _VMEM_BUDGET = 4 << 20
 _BLOCK_TOKENS = 256
 
 
-def pages_fill_lanes(kp) -> bool:
-    """Whether a page of pool ``kp`` [P, B, kvh*d] can be fetched as one
-    ``(B, kvh*d)`` slab: Mosaic slices HBM in whole 128-lane tiles, so
-    one kv head of 64 columns cannot, and ``paged_decode_route`` sends it
-    elsewhere. The interpreter takes any width."""
-    return _interpret() or kp.shape[2] % 128 == 0
+def use_ragged_kernel(q, kp, kv_heads: int) -> bool:
+    """Whether this kernel serves q [R, T, h, d] (T == 1 or the
+    speculative verify's multi-query rows) against a pool ``kp`` [P, B,
+    kv_heads*d]; ``generation/paged.py:paged_decode_route`` sends every
+    other shape to the dense gather. The policy of the other kernels: a
+    TPU backend, or the interpreter so that CI drives the dispatch glue
+    (it takes any shape with whole query-head groups). On the chip, an
+    8-sublane-aligned block size; a page that can be fetched as one
+    ``(B, kv_heads*d)`` slab, which Mosaic slices from HBM in whole
+    128-lane tiles (one kv head of 64 columns cannot); and heads of 128
+    or 256 columns, or one head of any whole number of tiles (a latent
+    pool: one wide row a token)."""
+    from . import kernels_enabled
+    h, d = q.shape[2:]
+    if h % kv_heads or not kernels_enabled():
+        return False
+    if _interpret():
+        return True
+    if kp.shape[1] % 8 or kp.shape[2] % 128 or d % 128:
+        return False
+    return kv_heads == 1 or d in (128, 256)
 
 
 def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:

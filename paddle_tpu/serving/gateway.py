@@ -28,16 +28,15 @@ Architecture (one process, N replicas):
   reordered or shed), then one ``engine.step()`` and a token dispatch
   that mirrors ``PagedEngine.stream()``'s hold-back semantics, so a
   gateway SSE stream is BIT-IDENTICAL to a direct engine stream (a
-  yielded token is never retracted by a stop trim). Ring-mode engines
-  (ISSUE 11, the default) surface each dispatch's tokens on the NEXT
-  ``step()`` — the tick thread consumes drained ring entries exactly
-  as it consumed the synchronous readback, so the dispatch loop below
-  is readback-architecture agnostic: against a ``ring_mode=False``
-  engine the SSE byte stream is bitwise the pre-ring one, and in ring
-  mode each request's byte stream is identical with token batches
-  landing one tick later (cancels posted to the tick thread drain the
-  in-flight dispatch before releasing the slot — ``/debugz`` shows
-  per-engine ring drain/blocking counters).
+  yielded token is never retracted by a stop trim). An engine
+  commits tokens to a device ring (ISSUE 11) and surfaces each
+  dispatch's tokens on the NEXT ``step()``; the dispatch loop below
+  only reads what ``step()`` left on the slots, so each request's
+  byte stream is what the engine's host reference (``fused_tick=
+  False``) gives, with token batches landing one tick later (cancels
+  posted to the tick thread drain the in-flight dispatch's row before
+  releasing the slot — ``/debugz`` shows per-engine ring
+  drain/blocking counters).
 - **Router** — :class:`PrefixAffinityRouter` keyed by
   ``PagedEngine.prefix_digest()`` picks the replica whose prefix cache
   already holds the prompt's shared span (least-loaded fallback,
@@ -1476,20 +1475,9 @@ class Gateway:
                 rep["engine"] = {"error": repr(e)}
             # slot-transition cost counters (ISSUE 14), surfaced at the
             # replica top level so a fleet poller need not dig into the
-            # engine snapshot — the snapshot's own block when it read
-            # cleanly, rebuilt from the engine counters when it tore
-            tr = rep["engine"].get("transitions") \
-                if isinstance(rep["engine"], dict) else None
-            rep["transitions"] = tr if tr is not None else {
-                "delta_enabled": getattr(w.engine, "_delta", None),
-                "patch_fuse_enabled": getattr(w.engine, "_fuse_patches",
-                                              None),
-                **{k: getattr(w.engine, k, None)
-                   for k in ("full_rebuilds", "delta_patches",
-                             "patches_fused", "patch_queue_overflows",
-                             "ring_cursor_rollovers",
-                             "h2d_uploads", "h2d_upload_bytes",
-                             "dispatch_count")}}
+            # engine snapshot: the snapshot's own block (None when the
+            # snapshot tore)
+            rep["transitions"] = rep["engine"].get("transitions")
             try:
                 rep["scheduler"] = w.sched.debug_snapshot()
             except Exception as e:

@@ -42,16 +42,16 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("chip_latent_timing: needs a TPU", file=sys.stderr)
         return 1
-    from paddle_tpu.generation.paged import PagedKV, paged_latent_attention
+    from paddle_tpu.generation.paged import (PagedKV,
+                                             paged_latent_attention,
+                                             paged_latent_attention_dense)
+    routes = {"ragged": paged_latent_attention,       # the chip's route
+              "dense": paged_latent_attention_dense}
 
     def attend(route):
         def fn(q, kp, tbl, lens):
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = route   # read when traced
-            try:
-                out = paged_latent_attention(
-                    q[:, None], PagedKV(kp, None, tbl, lens), DV, SCALE)
-            finally:
-                del os.environ["PADDLE_TPU_PAGED_ATTN"]
+            out = routes[route](
+                q[:, None], PagedKV(kp, None, tbl, lens), DV, SCALE)
             return jnp.pad(out[:, 0], ((0, 0), (0, 0), (0, W - DV)))
         return fn
 

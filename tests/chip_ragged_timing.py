@@ -11,8 +11,8 @@ call. ``--parent DIR`` (a checkout of another commit, e.g. `git archive`
 into a directory `.gitignore` lists; PR 27 or later, whose kernel takes
 the [P, B, kvh*d] pools and the head count) times that commit's kernel
 beside this one in the same process, and the dense whole-table gather
-(`PADDLE_TPU_PAGED_ATTN=dense`) is timed too: it reads all M*B positions
-whatever the context.
+(`paged_decode_attention_dense`) is timed too: it reads all M*B
+positions whatever the context.
 
 A call's time is a two-point fit: one jitted program chains `n` calls
 (each call's output is the next one's query, so none is deduplicated)
@@ -61,17 +61,14 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("chip_ragged_timing: needs a TPU", file=sys.stderr)
         return 1
-    from paddle_tpu.generation.paged import PagedKV, paged_decode_attention
+    from paddle_tpu.generation.paged import (PagedKV,
+                                             paged_decode_attention_dense)
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
 
     def dense(q, kp, vp, tbl, lens, scale, kv_heads):
-        os.environ["PADDLE_TPU_PAGED_ATTN"] = "dense"   # read when traced
-        try:
-            pk = PagedKV(kp, vp, tbl, lens, kv_heads)
-            return paged_decode_attention(q[:, None], pk, scale)[:, 0]
-        finally:
-            del os.environ["PADDLE_TPU_PAGED_ATTN"]
+        pk = PagedKV(kp, vp, tbl, lens, kv_heads)
+        return paged_decode_attention_dense(q[:, None], pk, scale)[:, 0]
 
     kernels = {"change": ragged_paged_attention_pallas, "dense": dense}
     if args.parent:

@@ -118,7 +118,6 @@ def test_write_and_attend_compile_with_no_pool_sized_copy(
                                              paged_decode_write,
                                              paged_latent_attention)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    monkeypatch.delenv("PADDLE_TPU_PAGED_ATTN", raising=False)
     # the route asks jax for its backend, which is the CPU here
     monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
 

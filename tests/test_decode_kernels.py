@@ -168,10 +168,13 @@ def _dense_paged_reference(q, kp, vp, tables, lens, window=None):
 @pytest.mark.parametrize("h,kvh,d", [(8, 4, 64), (16, 2, 128), (4, 4, 64)])
 @pytest.mark.parametrize("window", [None, 20])
 def test_pallas_paged_kernel_matches_dense_gather(h, kvh, d, window):
-    """VERDICT-r4 missing #2: the scalar-prefetched paged kernel must be
-    exact vs the dense whole-pool gather on ragged rows — including rows
-    whose tables hold garbage beyond their live blocks."""
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention_pallas
+    """VERDICT-r4 missing #2: the paged kernel (the ragged one: each
+    row walks its own pages) must be exact vs the dense whole-pool
+    gather on ragged rows — including rows whose tables hold garbage
+    beyond their live blocks — at head shapes ``TestRaggedKernel`` does
+    not hold: group 2 of 64, group 8 of 128, no grouping."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_pallas
     rs = np.random.RandomState(2)
     R, P, B, M = 4, 32, 16, 8
     q = jnp.asarray(rs.randn(R, h, d), jnp.float32)
@@ -182,9 +185,9 @@ def test_pallas_paged_kernel_matches_dense_gather(h, kvh, d, window):
     lens = np.asarray([0, 17, 63, 127], np.int32)
     tables = rs.permutation(np.arange(P)).reshape(1, -1)[0][:R * M] \
         .reshape(R, M).astype(np.int32)
-    got = paged_attention_pallas(q, _flat(kp), _flat(vp),
-                                 jnp.asarray(tables), jnp.asarray(lens),
-                                 1.0 / np.sqrt(d), kvh, window=window)
+    got = ragged_paged_attention_pallas(
+        q, _flat(kp), _flat(vp), jnp.asarray(tables), jnp.asarray(lens),
+        1.0 / np.sqrt(d), kvh, window=window)
     ref = _dense_paged_reference(q, kp, vp, jnp.asarray(tables),
                                  jnp.asarray(lens), window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -194,8 +197,9 @@ def test_pallas_paged_kernel_matches_dense_gather(h, kvh, d, window):
 def test_paged_decode_attention_routes_to_kernel():
     """generation/paged.py dispatch: interpret mode must route through
     the Pallas kernel and agree with the explicit fallback."""
-    from paddle_tpu.generation.paged import PagedKV, paged_decode_attention
-    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.generation.paged import (PagedKV,
+                                             paged_decode_attention,
+                                             paged_decode_route)
     rs = np.random.RandomState(3)
     R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
     kp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)
@@ -204,7 +208,7 @@ def test_paged_decode_attention_routes_to_kernel():
                  jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
                  jnp.asarray([3, 30, 60], jnp.int32), kvh)
     q = jnp.asarray(rs.randn(R, 1, h, d), jnp.float32)
-    assert pa.use_paged_kernel(q, pk.kp, kvh)
+    assert paged_decode_route(q, pk.kp, kvh) == "ragged"
     got = paged_decode_attention(q, pk)
     ref = _dense_paged_reference(q[:, 0], kp, vp, pk.block_tables,
                                  pk.seq_lens)

@@ -143,13 +143,12 @@ class TestFusedTickParity:
 
     def test_midstream_submit_bit_identical(self, model):
         """A submit() landing mid-decode (the continuous-batching case)
-        triggers a slot-transition mirror refresh; the joined request's
-        stream and the already-running streams stay exact. The sync
-        (ring_mode=False) fused tick pins the exact cross-request
-        EMISSION INTERLEAVE against the host path; ring mode drains one
-        step behind the device, so the submit's admission tick shifts —
-        its pin is per-request content and order (batch composition
-        independence keeps each stream bitwise anyway)."""
+        stages a slot transition; the joined request's stream and the
+        already-running streams stay exact. The fused tick drains its
+        token ring one step behind the device, so the submit's
+        admission tick shifts against the host path's: the pin is
+        per-request content and order (batch composition independence
+        keeps each stream bitwise)."""
         rs = np.random.RandomState(13)
         first = rs.randint(1, 200, (1, 6))
         late = rs.randint(1, 200, (1, 10))
@@ -167,10 +166,7 @@ class TestFusedTickParity:
             return out, dict(eng.results), dict(eng.logprobs)
 
         sh, rh, lh = run(fused_tick=False)
-        sf, rf, lf = run(ring_mode=False)
-        assert sh == sf          # emission order too, not just results
-        assert rh == rf and lh == lf
-        sr, rr, lr = run()       # ring mode (the default)
+        sr, rr, lr = run()
         assert rh == rr and lh == lr
         for rid in rh:           # per-request emission order exact
             assert [t for r, t in sr if r == rid] == \
@@ -322,6 +318,20 @@ def _dense_paged_reference(q, kp, vp, tables, lens, window=None):
                            attn_mask=keep[:, None, None, :])[:, 0]
 
 
+def _kernel_calls(fn, *args):
+    """The ``pallas_call`` equations of ``fn(*args)``'s jaxpr, nested
+    ones included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", None)
+                if inner is not None:
+                    yield from walk(getattr(inner, "jaxpr", inner))
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
 class TestRaggedKernel:
     @pytest.fixture(autouse=True)
     def _interpret(self, monkeypatch):
@@ -351,10 +361,12 @@ class TestRaggedKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_engine_routes_through_ragged_kernel(self, monkeypatch):
-        """paged_decode_attention's default mode is the ragged kernel;
-        grid/dense modes stay reachable via PADDLE_TPU_PAGED_ATTN and
-        all three agree."""
+    def test_engine_routes_through_ragged_kernel(self):
+        """paged_decode_attention takes the ragged kernel where it
+        serves; the dense gather is a function of its own, and the two
+        agree."""
+        from paddle_tpu.generation.paged import (
+            paged_decode_attention_dense, paged_decode_route)
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
         pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
@@ -362,14 +374,24 @@ class TestRaggedKernel:
                      jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
                      jnp.asarray([3, 30, 60], jnp.int32), kvh)
         q = jnp.asarray(rs.randn(R, 1, h, d), jnp.float32)
-        outs = {}
-        for mode in ("ragged", "grid", "dense"):
-            monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", mode)
-            outs[mode] = np.asarray(paged_decode_attention(q, pk))
-        np.testing.assert_allclose(outs["ragged"], outs["dense"],
-                                   atol=2e-5, rtol=2e-5)
-        np.testing.assert_allclose(outs["grid"], outs["dense"],
-                                   atol=2e-5, rtol=2e-5)
+        assert paged_decode_route(q, pk.kp, kvh) == "ragged"
+        kernels = _kernel_calls(lambda q: paged_decode_attention(q, pk), q)
+        assert [k.params["name"] for k in kernels] \
+            == ["ragged_paged_attention"]
+        assert not _kernel_calls(
+            lambda q: paged_decode_attention_dense(q, pk), q)
+        np.testing.assert_allclose(
+            np.asarray(paged_decode_attention(q, pk)),
+            np.asarray(paged_decode_attention_dense(q, pk)),
+            atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("value", ["dense", "grid"])
+    def test_no_variable_chooses_the_route(self, model, monkeypatch,
+                                           value):
+        """The variable that used to pick the kernel when a program was
+        traced is not read: shapes and the platform decide."""
+        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", value)
+        assert _engine(model).decode_route() == "ragged"
 
     @pytest.mark.parametrize("B,M,kvh,h,d,lens,window", [
         # 16 pages a compute block (256 tokens / B 16), 40 a table: rows
@@ -476,8 +498,7 @@ class TestRaggedKernel:
     def test_narrow_pages_take_another_route(self, monkeypatch):
         """On the chip a page is fetched as one (B, kvh*d) slab, which
         Mosaic slices only in whole 128-lane tiles: one kv head of 64
-        columns keeps the grid kernel (single-query) or the dense
-        gather."""
+        columns takes the dense gather."""
         from paddle_tpu.generation.paged import paged_decode_route
         from paddle_tpu.ops import pallas
         monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
@@ -488,7 +509,7 @@ class TestRaggedKernel:
             == "ragged"
         assert paged_decode_route(q(3, 28, 128), pool(4, 128), 4) \
             == "ragged"
-        assert paged_decode_route(q(1, 8, 64), pool(1, 64), 1) == "grid"
+        assert paged_decode_route(q(1, 8, 64), pool(1, 64), 1) == "dense"
         assert paged_decode_route(q(3, 8, 64), pool(1, 64), 1) == "dense"
 
     def test_parity_shared_blocks_exceeding_pool_bound(self):

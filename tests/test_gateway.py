@@ -624,9 +624,8 @@ def _loadgen_ns(**kw):
 
 def test_loadgen_inprocess_smoke():
     """The bench rung contract: one run emits every key bench.py's
-    gateway ingestion promotes, with sane values — and the --ring A/B
-    (ISSUE 11) serves the same workload to completion in both modes,
-    recording ring drains when on."""
+    gateway ingestion promotes, with sane values, and records the
+    engines' ring drains."""
     slg = _load_loadgen()
     rung = asyncio.run(slg.run_loadgen(_loadgen_ns()))
     for key in ("gateway_tokens_per_sec", "gateway_p50_ttft_ms",
@@ -637,10 +636,7 @@ def test_loadgen_inprocess_smoke():
     assert rung["completed"] == 6 and rung["shed"] == 0
     assert rung["gateway_tokens_per_sec"] > 0
     assert rung["gateway_p99_ttft_ms"] >= rung["gateway_p50_ttft_ms"]
-    assert rung["ring"] == "on" and rung["ring_drains"] > 0
-    off = asyncio.run(slg.run_loadgen(_loadgen_ns(ring="off")))
-    assert off["completed"] == 6 and off["ring"] == "off"
-    assert "ring_drains" not in off
+    assert rung["ring_drains"] > 0 and rung["patches_fused"] > 0
 
 
 @pytest.mark.slow

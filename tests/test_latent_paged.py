@@ -139,7 +139,8 @@ def test_the_route_is_ragged_and_the_streams_are_the_dense_gathers(
     eng = engine(model)
     assert eng.decode_route() == "ragged"
     got = serve(eng, ps)
-    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", "dense")
+    # without the interpreter no kernel runs here: the dense gather
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
     dense = engine(model)
     assert dense.decode_route() == "dense"
     want = serve(dense, ps)
@@ -211,7 +212,9 @@ def test_a_hard_reset_takes_fresh_latent_pools(model):
 def test_the_latent_kernel_matches_the_dense_gather(kernels, T):
     """The published widths: 64 heads over one row of 512 + 64 columns
     padded to 640, values the first 512."""
-    from paddle_tpu.generation.paged import PagedKV, paged_latent_attention
+    from paddle_tpu.generation.paged import (PagedKV,
+                                             paged_latent_attention,
+                                             paged_latent_attention_dense)
     R, P, B, M, h, W, dv = 5, 48, 8, 8, 64, 640, 512
     rs = np.random.RandomState(T)
     q = jnp.asarray(rs.randn(R, T, h, W) * 0.2, jnp.float32)
@@ -222,10 +225,5 @@ def test_the_latent_kernel_matches_the_dense_gather(kernels, T):
     pk = PagedKV(kp, None, tables, lens)
     got = paged_latent_attention(q, pk, dv, 192 ** -0.5)
     assert got.shape == (R, T, h, dv)
-    import os
-    os.environ["PADDLE_TPU_PAGED_ATTN"] = "dense"
-    try:
-        want = paged_latent_attention(q, pk, dv, 192 ** -0.5)
-    finally:
-        del os.environ["PADDLE_TPU_PAGED_ATTN"]
+    want = paged_latent_attention_dense(q, pk, dv, 192 ** -0.5)
     np.testing.assert_allclose(got, want, atol=2e-5)

@@ -460,9 +460,11 @@ class TestMultiQueryRagged:
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_paged_decode_attention_routes_multi_query(self, monkeypatch):
-        """The dispatch layer: ragged and dense modes agree on T>1;
-        grid mode (single-query kernel) falls back to dense."""
+    def test_paged_decode_attention_routes_multi_query(self):
+        """The dispatch layer: T>1 rows take the ragged kernel too, and
+        it agrees with the dense gather."""
+        from paddle_tpu.generation.paged import (
+            paged_decode_attention_dense, paged_decode_route)
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d, T = 3, 16, 16, 4, 2, 4, 64, 3
         pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
@@ -470,13 +472,11 @@ class TestMultiQueryRagged:
                      jnp.asarray(rs.randint(0, P, (R, M)), jnp.int32),
                      jnp.asarray([3, 30, 57], jnp.int32), kvh)
         q = jnp.asarray(rs.randn(R, T, h, d), jnp.float32)
-        outs = {}
-        for mode in ("ragged", "grid", "dense"):
-            monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", mode)
-            outs[mode] = np.asarray(paged_decode_attention(q, pk))
-        np.testing.assert_allclose(outs["ragged"], outs["dense"],
-                                   atol=2e-5, rtol=2e-5)
-        np.testing.assert_array_equal(outs["grid"], outs["dense"])
+        assert paged_decode_route(q, pk.kp, kvh) == "ragged"
+        np.testing.assert_allclose(
+            np.asarray(paged_decode_attention(q, pk)),
+            np.asarray(paged_decode_attention_dense(q, pk)),
+            atol=2e-5, rtol=2e-5)
 
     @pytest.mark.slow
     @pytest.mark.parametrize("h,kvh,d,T,window",

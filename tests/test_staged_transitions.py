@@ -1,47 +1,44 @@
-"""ISSUE 14 + 19: persistent decode program — in-program slot
-transitions, as delta mirror patches (ISSUE 14) fused into the tick
-program itself (ISSUE 19).
+"""ISSUE 14 + 19: persistent decode program — slot transitions as
+per-slot descriptors staged into the tick program itself.
 
-Three transition modes, pinned against each other:
-
-- REBUILD  (``delta_transitions=False``): full-state refresh per
-  transition, the pre-ISSUE-14 reference kept verbatim.
-- DELTA    (``patch_fuse=False``): each transition is a one-row
-  descriptor patch — its own tiny dispatch (the PR 12 path).
-- FUSED    (the default): descriptors are STAGED into a bounded
-  device-resident queue by a plain H2D upload and the NEXT tick's
-  program applies them all in one masked batched scatter — one
-  executable, one dispatch, whether a tick carries 0 or R
-  transitions.
+The served engine (the default) packs each transition into a
+descriptor, stages the pending ones into a device-resident queue of
+``max_slots`` rows by a plain H2D upload, and the NEXT tick's program
+applies them all in one masked batched scatter — one executable, one
+dispatch, whether a tick carries 0 or R transitions. What it is
+compared with is the host tick (``fused_tick=False``), the engine's one
+reference; for a speculative engine, greedy streams against the
+non-speculative host tick (tokens bitwise, logprobs to float rounding:
+its forward scores k+1 positions at once).
 
 Contracts:
 
 - STREAM PARITY: greedy and seeded-sampled token/logprob streams are
-  BITWISE identical across all three modes and every transition kind
+  BITWISE the host tick's, per request, across every transition kind
   — admit, finish, chunked-prefill advance, preempt, cancel, block
-  growth — with the ring on and off.
-- ONE DISPATCH PER TICK (ISSUE 19 acceptance): steady churn in fused
-  mode runs N ticks in exactly N dispatches — 0 standalone patch
-  dispatches, 0 full rebuilds — including an R-row synchronized
-  finish wave; standalone ``_apply_patch`` survives only as the
-  queue-overflow fallback (explicit ``patch_queue_len < R``) and is
-  counter-pinned when it fires.
+  growth. (Tokens leave the fused engine one step behind the device,
+  so the interleave BETWEEN requests may differ; each request's own
+  stream may not.)
+- ONE DISPATCH PER TICK (ISSUE 19 acceptance): steady churn runs N
+  ticks in exactly N dispatches and 0 full rebuilds, including an
+  R-row synchronized finish wave, which fits the queue.
 - WARM ADMIT (ROADMAP 4(b) first rung): ``submit()`` on a warm
-  chunked fused engine claims the slot eagerly and issues ZERO
-  dispatches until the next tick.
+  chunked engine claims the slot eagerly and issues ZERO dispatches
+  until the next tick.
 - SCOPED DRAIN: an out-of-band transition (cancel/expiry) consumes
   only the affected slot's pending ring entries; untouched siblings'
   pending tokens survive and land at the next step()'s normal drain.
-- UPLOAD ACCOUNTING: steady churn runs 0 full-state rebuilds in
-  delta/fused modes, and the byte counter — the ISSUE 14 small-fix
-  satellite — shows the one-row patch path moving far fewer H2D
-  bytes than the rebuild path for the same workload (pinned on
-  explicit delta mode: the fused queue trades a few padded bytes per
-  staging upload for the dispatch it eliminates).
+- UPLOAD ACCOUNTING: steady churn runs 0 full-state rebuilds; a
+  staging upload is ``R * desc_len * 4 + 4`` bytes and a steady tick
+  uploads nothing.
 - FAILOVER: ``export_resumable()`` descriptors, read off host mirrors
-  that advance via scoped drains, stay equal across modes, and a
-  resume from them continues the stream bitwise.
+  that advance via drains, hold a prefix of the reference's stream,
+  and a resume from them continues it bitwise.
+- THE SWITCHES ARE GONE: the constructor refuses the five deleted
+  options.
 """
+import inspect
+
 import numpy as np
 import pytest
 
@@ -60,14 +57,11 @@ def _engine(**kw):
     return PagedEngine(TickStubModel(), **base)
 
 
-# the three transition modes as engine kwargs: the matrix every parity
-# test sweeps (fused is the default — {} — and must stay bitwise with
-# both ancestors)
-MODES = {
-    "rebuild": dict(delta_transitions=False),
-    "delta": dict(patch_fuse=False),
-    "fused": {},
-}
+def _reference(**kw):
+    """The host tick: the one reference. It does not speculate, so a
+    speculative engine's GREEDY streams are compared with it."""
+    kw.pop("spec_tokens", None)
+    return _engine(fused_tick=False, **kw)
 
 
 def _drain(eng, submits):
@@ -88,15 +82,31 @@ MIXED_SUBS = [
 ]
 
 
+def _lps_close(a, b):
+    """A speculative tick scores a window of k+1 positions in one
+    forward, the host tick one position: the same tokens, logprobs
+    equal to float rounding."""
+    assert a.keys() == b.keys()
+    for rid in a:
+        np.testing.assert_allclose(a[rid], b[rid], rtol=0, atol=1e-5)
+
+
+def _per_request(pairs):
+    """A ``stream()``'s (request, token) pairs, per request in order."""
+    out = {}
+    for rid, tok in pairs:
+        out.setdefault(rid, []).append(tok)
+    return out
+
+
 class TestDeltaParity:
-    @pytest.mark.parametrize("ring", [True, False])
-    def test_transition_matrix_bitwise(self, ring):
+    def test_transition_matrix_bitwise(self):
         """Admit/finish/growth/stop/eos churn + a mid-run second wave
-        (admits into slots whose previous tenants finished): fused,
-        delta and rebuild modes agree on every token and every logprob
-        float."""
-        def run(mode):
-            eng = _engine(ring_mode=ring, **MODES[mode])
+        (admits into slots whose previous tenants finished): the
+        staged-queue engine and the host tick agree on every token and
+        every logprob float."""
+        def run(make):
+            eng = make()
             res, lps = _drain(eng, MIXED_SUBS)
             # second wave: readmits into released rows (the ring
             # cursors continue where the previous tenant stopped)
@@ -109,28 +119,22 @@ class TestDeltaParity:
             lps.update(lps2)
             return eng, res, lps
 
-        er, rr, lr = run("rebuild")
-        ed, rd, ld = run("delta")
-        ef, rf, lf = run("fused")
-        assert rr == rd == rf
-        assert lr == ld == lf
-        assert er.full_rebuilds > 1          # reference churned rebuilds
-        assert ed.full_rebuilds == 1         # delta paid the first only
-        assert ed.delta_patches > 0
-        # fused: same zero-rebuild contract, but transitions rode the
-        # staged queue — no standalone patch program ever dispatched
+        er, rr, lr = run(_reference)
+        ef, rf, lf = run(_engine)
+        assert rr == rf
+        assert lr == lf
+        assert er.full_rebuilds == 0         # the host tick has no state
+        # one rebuild, the first; every transition after it rode the
+        # staged queue
         assert ef.full_rebuilds == 1
-        assert ef.delta_patches == 0
         assert ef.patches_fused > 0
-        assert ef.patch_queue_overflows == 0
 
-    @pytest.mark.parametrize("ring", [True, False])
-    def test_midstream_admit_interleave_exact(self, ring):
-        """A submit() landing mid-decode rides a one-row patch; the
-        per-request emission interleave matches the rebuild reference
-        exactly (same ring mode on both sides)."""
-        def run(delta):
-            eng = _engine(ring_mode=ring, delta_transitions=delta)
+    def test_midstream_admit_interleave_exact(self):
+        """A submit() landing mid-decode rides a staged descriptor;
+        each request's emission order is the host tick's exactly (the
+        interleave between the two is one step apart: the ring)."""
+        def run(make):
+            eng = make()
             eng.submit("r0", _cyc(6), max_new_tokens=18)
             out = []
             for n, pair in enumerate(eng.stream()):
@@ -140,9 +144,9 @@ class TestDeltaParity:
                                temperature=0.8, seed=3)
             return out, dict(eng.results), dict(eng.logprobs)
 
-        sr, rr, lr = run(delta=False)
-        sd, rd, ld = run(delta=True)
-        assert sr == sd          # emission order too, not just results
+        sr, rr, lr = run(_reference)
+        sd, rd, ld = run(_engine)
+        assert _per_request(sr) == _per_request(sd) == rd
         assert rr == rd and lr == ld
 
     def test_chunked_prefill_and_prefix_cache_parity(self):
@@ -151,11 +155,9 @@ class TestDeltaParity:
         pointing at shared physical blocks) stays bitwise too."""
         sys_p = list(range(1, 17))
 
-        def run(delta):
-            eng = _engine(max_slots=2, chunk_prefill_tokens=8,
-                          enable_prefix_cache=True,
-                          prefill_buckets=(8,),
-                          delta_transitions=delta)
+        def run(make):
+            eng = make(max_slots=2, chunk_prefill_tokens=8,
+                       enable_prefix_cache=True, prefill_buckets=(8,))
             r1, l1 = _drain(eng, [
                 ("x", np.asarray(sys_p + [20, 21])[None],
                  dict(max_new_tokens=10)),
@@ -169,8 +171,8 @@ class TestDeltaParity:
             l1.update(l2)
             return eng, r1, l1
 
-        er, rr, lr = run(False)
-        ed, rd, ld = run(True)
+        er, rr, lr = run(_reference)
+        ed, rd, ld = run(_engine)
         assert rr == rd and lr == ld
         assert ed.stats["prefix_hit_tokens"] == \
             er.stats["prefix_hit_tokens"] > 0
@@ -178,26 +180,25 @@ class TestDeltaParity:
 
     def test_preemption_parity(self):
         """Block-pool pressure forces recompute-mode preemption (a
-        release patch + a requeue) mid-run; streams and preemption
-        counts match the rebuild reference, sampled victim included."""
+        release patch + a requeue) mid-run; streams match the host
+        tick's, sampled victim included."""
         kw = dict(max_slots=2, num_blocks=6, block_size=8,
                   max_blocks_per_seq=4, prefill_buckets=(16,))
         subs = [("p", _cyc(8), dict(max_new_tokens=14)),
                 ("q", _cyc(11, 2), dict(max_new_tokens=14,
                                         temperature=0.9, seed=5))]
-        er, rr, lr = (lambda e: (e, *_drain(e, subs)))(
-            _engine(delta_transitions=False, **kw))
-        ed, rd, ld = (lambda e: (e, *_drain(e, subs)))(
-            _engine(**kw))
+        er, rr, lr = (lambda e: (e, *_drain(e, subs)))(_reference(**kw))
+        ed, rd, ld = (lambda e: (e, *_drain(e, subs)))(_engine(**kw))
         assert rr == rd and lr == ld
-        assert er.stats["preemptions"] == ed.stats["preemptions"] > 0
+        assert er.stats["preemptions"] > 0
+        assert ed.stats["preemptions"] > 0
 
     def test_cancel_race_parity(self):
-        """cancel() between steps (in-flight dispatch in ring mode):
-        the survivor's stream matches the rebuild-mode run token for
-        token, and the cancel lands identically."""
-        def run(delta):
-            eng = _engine(delta_transitions=delta)
+        """cancel() between steps (a dispatch in flight): the
+        survivor's stream matches the host tick's run token for token,
+        and the cancel lands identically."""
+        def run(make):
+            eng = make()
             eng.submit("keep", _cyc(6), max_new_tokens=20)
             eng.submit("kill", _cyc(9, 3), max_new_tokens=20)
             for _ in range(4):
@@ -206,8 +207,8 @@ class TestDeltaParity:
             res = eng.run()
             return eng, res, dict(eng.logprobs)
 
-        er, rr, lr = run(False)
-        ed, rd, ld = run(True)
+        er, rr, lr = run(_reference)
+        ed, rd, ld = run(_engine)
         assert rr == rd and lr == ld
         assert er.cancelled == ed.cancelled == {"kill": "cancelled"}
         assert len(ed.free_blocks) == ed.P - 1
@@ -215,11 +216,10 @@ class TestDeltaParity:
     def test_spec_greedy_parity(self):
         """Speculative ticks: the descriptor carries the committed-
         token row, accept EMA and probe counter, so greedy spec
-        streams (draft-invariant by the argmax-prefix rule) stay
-        bitwise across modes through admit/finish churn."""
-        def run(mode):
-            eng = _engine(prefill_buckets=(8,), spec_tokens=3,
-                          **MODES[mode])
+        tokens (draft-invariant by the argmax-prefix rule) are the
+        host tick's through admit/finish churn."""
+        def run(make):
+            eng = make(prefill_buckets=(8,), spec_tokens=3)
             res, lps = _drain(eng, [
                 ("g", _cyc(6), dict(max_new_tokens=15)),
                 ("h", _cyc(8, 2), dict(max_new_tokens=10)),
@@ -230,23 +230,24 @@ class TestDeltaParity:
             lps.update(lps2)
             return eng, res, lps
 
-        er, rr, lr = run("rebuild")
-        ed, rd, ld = run("delta")
-        ef, rf, lf = run("fused")
-        assert rr == rd == rf and lr == ld == lf
-        assert ed.full_rebuilds == 1 and ed.delta_patches > 0
-        assert ef.full_rebuilds == 1 and ef.delta_patches == 0
-        assert ef.patches_fused > 0
+        _, rr, lr = run(_reference)
+        ef, rf, lf = run(_engine)
+        assert rr == rf
+        _lps_close(lr, lf)
+        assert ef.full_rebuilds == 1 and ef.patches_fused > 0
+        assert ef.stats["spec_accepted"] > 0
 
-    def test_delta_requires_fused_tick(self):
-        with pytest.raises(ValueError):
-            _engine(fused_tick=False, delta_transitions=True)
-
-    def test_patch_fuse_requires_delta(self):
-        """The fused queue stages the delta path's descriptors — there
-        is nothing to stage in rebuild mode."""
-        with pytest.raises(ValueError):
-            _engine(delta_transitions=False, patch_fuse=True)
+    @pytest.mark.parametrize("name", [
+        "ring_mode", "ring_len", "delta_transitions", "patch_fuse",
+        "patch_queue_len"])
+    def test_the_mode_switches_are_gone(self, name):
+        """The served path has no variants: the constructor has none of
+        the five options PR 28 deleted, and refuses each by name."""
+        params = inspect.signature(PagedEngine.__init__).parameters
+        assert name not in params
+        assert len(params) == 18        # self, the model, 16 options
+        with pytest.raises(TypeError, match=name):
+            _engine(**{name: None})
 
 
 class TestScopedDrain:
@@ -269,7 +270,7 @@ class TestScopedDrain:
         assert len(keep_slot.tokens) == n_keep
         assert eng.ring_scoped_drains == 1
         res = eng.run()
-        ref = _engine(ring_mode=False, delta_transitions=False)
+        ref = _reference()
         ref.submit("keep", _cyc(6), max_new_tokens=20)
         assert res["keep"] == ref.run()["keep"]
 
@@ -287,7 +288,7 @@ class TestScopedDrain:
         assert eng.cancel("kill")
         assert eng.ring_scoped_drains == 1
         res = eng.run()
-        ref = _engine(ring_mode=False, delta_transitions=False, **kw)
+        ref = _reference(**kw)
         ref.submit("keep", _cyc(6), max_new_tokens=20)
         assert res["keep"] == ref.run()["keep"]
 
@@ -315,7 +316,7 @@ class TestScopedDrain:
         assert eng._pending is not None
         res = eng.run()
         assert eng.cancelled.get("doomed") == "timeout"
-        ref = _engine(ring_mode=False, delta_transitions=False)
+        ref = _reference()
         ref.submit("keep", _cyc(6), max_new_tokens=16)
         assert res["keep"] == ref.run()["keep"]
 
@@ -324,32 +325,24 @@ class TestUploadAccounting:
     def test_zero_rebuilds_steady_churn(self):
         """THE ISSUE 14 acceptance counter: a churny stream (short
         requests, a finish + admit every few ticks) runs ZERO
-        full-state rebuilds after the first dispatch in delta mode —
-        every transition rides a one-row patch — while the rebuild
-        reference pays one full rebuild per churn tick."""
-        def churn(mode):
-            eng = _engine(**MODES[mode])
-            eng.submit("w", _cyc(4), max_new_tokens=2)
-            eng.run()                       # compile + first rebuild
-            fr0, dp0 = eng.full_rebuilds, eng.delta_patches
-            b0 = eng.h2d_upload_bytes
-            for i in range(12):
-                eng.submit(i, _cyc(4 + i % 3), max_new_tokens=4)
-            eng.run()
-            return (eng, eng.full_rebuilds - fr0,
-                    eng.delta_patches - dp0, eng.h2d_upload_bytes - b0)
-
-        _, fr_d, dp_d, bytes_d = churn("delta")
-        _, fr_r, dp_r, bytes_r = churn("rebuild")
-        ef, fr_f, dp_f, _ = churn("fused")
-        assert fr_d == 0 and dp_d > 0       # steady churn: patches only
-        assert fr_r >= 6 and dp_r == 0      # reference: rebuild storm
-        assert fr_f == 0 and dp_f == 0      # fused: staged queue only
-        assert ef.patches_fused > 0
-        # the small-fix satellite: bytes weigh what events hide.
-        # Pinned on explicit delta mode — the fused queue pads each
-        # staging upload to [Q, D] and buys back the dispatch instead
-        assert 0 < bytes_d < bytes_r
+        full-state rebuilds after the first dispatch — every transition
+        rides the staged queue — and the bytes say what an upload
+        weighs: each is one whole queue, ``R * desc_len`` int32 and the
+        count."""
+        eng = _engine()
+        eng.submit("w", _cyc(4), max_new_tokens=2)
+        eng.run()                       # compile + first rebuild
+        fr0, pf0 = eng.full_rebuilds, eng.patches_fused
+        u0, b0 = eng.h2d_uploads, eng.h2d_upload_bytes
+        for i in range(12):
+            eng.submit(i, _cyc(4 + i % 3), max_new_tokens=4)
+        eng.run()
+        assert eng.full_rebuilds - fr0 == 0
+        assert eng.patches_fused - pf0 >= 12    # an admit a request
+        uploads = eng.h2d_uploads - u0
+        assert uploads > 0
+        assert eng.h2d_upload_bytes - b0 == \
+            uploads * (eng.R * eng._desc_len * 4 + 4)
 
     def test_steady_ticks_no_patches_no_bytes(self):
         """Between transitions nothing is uploaded at all: the
@@ -361,16 +354,16 @@ class TestUploadAccounting:
         for _ in range(6):
             eng.step()
         d0, u0 = eng.dispatch_count, eng.h2d_uploads
-        b0, p0 = eng.h2d_upload_bytes, eng.delta_patches
+        b0, p0 = eng.h2d_upload_bytes, eng.patches_fused
         for _ in range(20):
             eng.step()
         assert eng.dispatch_count - d0 == 20
         assert eng.h2d_uploads - u0 == 0
         assert eng.h2d_upload_bytes - b0 == 0
-        assert eng.delta_patches - p0 == 0
+        assert eng.patches_fused - p0 == 0
 
     def test_counters_flow_to_stats_health_and_snapshot(self):
-        """full_rebuilds / delta_patches / h2d_upload_bytes reach the
+        """full_rebuilds / patches_fused / h2d_upload_bytes reach the
         registry-backed stats (and so health() and a /metrics scrape)
         and the debug_snapshot transitions block, equal to the plain
         attributes the tests and tools read."""
@@ -379,22 +372,21 @@ class TestUploadAccounting:
         eng.run()
         st = eng.stats
         assert st["full_rebuilds"] == eng.full_rebuilds == 1
-        assert st["delta_patches"] == eng.delta_patches
+        assert "delta_patches" not in st
+        assert "patch_queue_overflows" not in st
         assert st["h2d_upload_bytes"] == eng.h2d_upload_bytes > 0
         # the registry twin of dispatch_count (ISSUE 19): every
         # dispatch site counts both, so /metricsz sees what tests pin
         assert st["dispatches"] == eng.dispatch_count > 0
         assert st["patches_fused"] == eng.patches_fused
-        assert st["patch_queue_overflows"] == 0
         assert st["ring_cursor_rollovers"] == 0
         snap = eng.debug_snapshot()["transitions"]
-        assert snap["delta_enabled"] is True
-        assert snap["patch_fuse_enabled"] is True
-        assert snap["patch_queue_len"] == eng.R
+        assert set(snap) == {
+            "full_rebuilds", "patches_fused", "ring_cursor_rollovers",
+            "pending_patch_rows", "h2d_uploads", "h2d_upload_bytes",
+            "dispatches", "dispatches_per_tick"}
         assert snap["full_rebuilds"] == eng.full_rebuilds
-        assert snap["delta_patches"] == eng.delta_patches
         assert snap["patches_fused"] == eng.patches_fused
-        assert snap["patch_queue_overflows"] == 0
         assert snap["ring_cursor_rollovers"] == 0
         assert snap["h2d_upload_bytes"] == eng.h2d_upload_bytes
         assert snap["dispatches"] == eng.dispatch_count
@@ -415,7 +407,7 @@ class TestFusedPatchQueue:
     def test_steady_churn_one_dispatch_per_tick(self):
         """THE acceptance counter: after warmup, N churny ticks
         (staggered finishes, every transition staged) run in EXACTLY N
-        dispatches — 0 standalone patch dispatches, 0 full rebuilds."""
+        dispatches and 0 full rebuilds."""
         eng = _engine()
         for i in range(4):
             # consecutive budgets: once the shortest finishes, some
@@ -429,58 +421,32 @@ class TestFusedPatchQueue:
         ticks = eng.stats["decode_steps"] - t0
         assert ticks > 0
         assert eng.dispatch_count - d0 == ticks     # N ticks, N dispatches
-        assert eng.delta_patches == 0               # no standalone patches
         assert eng.full_rebuilds == 1               # no churn rebuilds
         assert eng.patches_fused >= 3               # staged waves carried it
-        assert eng.patch_queue_overflows == 0
 
     def test_synchronized_wave_single_dispatch(self):
-        """R=8 simultaneous finishes — the wave the old per-row path
-        paid 8 standalone patch dispatches for — is absorbed by ONE
-        staged upload consumed in the next tick's program: the
-        follow-up request costs exactly 1 prefill + its ticks."""
+        """R=8 simultaneous finishes — a wave as wide as the queue: a
+        row a slot, so it fits — is absorbed by ONE staged upload
+        consumed in the next tick's program: the follow-up request
+        costs exactly 1 prefill + its ticks."""
         eng = _engine(max_slots=8, num_blocks=64)
         for i in range(8):
             eng.submit(f"w{i}", _cyc(6), max_new_tokens=4)
         eng.run()        # same budgets: all 8 rows finish the same tick
-        assert eng.delta_patches == 0
-        assert eng.patch_queue_overflows == 0
-        d0 = eng.dispatch_count
+        d0, u0 = eng.dispatch_count, eng.h2d_uploads
         t0 = eng.stats["decode_steps"]
         pf0 = eng.patches_fused
         eng.submit("s", _cyc(5, 1), max_new_tokens=3)
         eng.run()
         ticks = eng.stats["decode_steps"] - t0
         # 1 prefill + N ticks — the 8-row release wave plus s's admit
-        # rode one staged queue, zero standalone patch programs
+        # rode one staged queue
         assert eng.dispatch_count - d0 == ticks + 1
-        assert eng.delta_patches == 0
         assert eng.full_rebuilds == 1
         # all 8 releases + the admit coalesced into s's slot: >= 8 rows
         assert eng.patches_fused - pf0 >= 8
-        assert eng.patch_queue_overflows == 0
-
-    def test_queue_overflow_falls_back_to_standalone_patches(self):
-        """An explicit patch_queue_len below the wave size takes the
-        standalone-patch fallback — counted, and still bitwise."""
-        def run(**kw):
-            eng = _engine(**kw)
-            res, lps = _drain(eng, [
-                (f"r{i}", _cyc(6), dict(max_new_tokens=3))
-                for i in range(4)])          # 4-row synchronized wave
-            res2, lps2 = _drain(eng, [
-                ("t", _cyc(5, 1), dict(max_new_tokens=4))])
-            res.update(res2)
-            lps.update(lps2)
-            return eng, res, lps
-
-        ef, rf, lf = run()
-        eo, ro, lo = run(patch_queue_len=2)
-        assert ro == rf and lo == lf         # fallback stays bitwise
-        assert ef.patch_queue_overflows == 0 and ef.delta_patches == 0
-        assert eo.patch_queue_overflows >= 1
-        assert eo.delta_patches > 0          # the wave went standalone
-        assert eo.full_rebuilds == 1         # but never forced a rebuild
+        # the wave's upload, then one for s's own finish at most
+        assert 1 <= eng.h2d_uploads - u0 <= ticks
 
     def test_warm_admit_is_dispatch_free(self):
         """ROADMAP 4(b) first rung: submit() on a warm (chunked, fused)
@@ -498,7 +464,7 @@ class TestFusedPatchQueue:
         assert any(s is not None and s.request_id == "a"
                    for s in eng.slots)       # ...but the slot is claimed
         assert not eng.queue
-        ref = _engine(patch_fuse=False, **kw)
+        ref = _reference(**kw)
         ref.submit("w", _cyc(4), max_new_tokens=2)
         ref.run()
         ref.submit("a", _cyc(6), max_new_tokens=4)
@@ -507,42 +473,43 @@ class TestFusedPatchQueue:
 
 class TestFailoverParity:
     def test_export_resumable_parity_and_bitwise_resume(self):
-        """Mirrors advanced by (scoped) drains export the same resume
-        descriptors as the rebuild reference, and a resume from them
-        continues the stream bitwise (the ISSUE 12/13 failover gate
-        with delta mode default-on)."""
-        def partial(delta):
-            eng = _engine(max_slots=2, delta_transitions=delta)
+        """Mirrors advanced by drains export resume descriptors that
+        hold a prefix of the host tick's streams (one dispatch is in
+        flight: its tokens are not committed), and a resume from them
+        continues the stream bitwise (the ISSUE 12/13 failover gate)."""
+        def start(make):
+            eng = make(max_slots=2)
             eng.submit("r1", _cyc(6), max_new_tokens=30)
             eng.submit("r2", _cyc(7, 1), max_new_tokens=30,
                        temperature=0.7, seed=2)
-            for _ in range(9):
-                eng.step()
-            return eng.export_resumable()
+            return eng
 
-        exp_d = partial(True)
-        assert exp_d == partial(False)
-        # greedy resume on a fresh delta engine == uninterrupted run
-        d = exp_d["r1"]
+        eng = start(_engine)
+        for _ in range(9):
+            eng.step()
+        exp = eng.export_resumable()
+        full = start(_reference).run()
+        for rid in ("r1", "r2"):
+            n = len(exp[rid]["committed"])
+            assert 0 < n < 30 and exp[rid]["remaining"] == 30 - n
+            assert exp[rid]["committed"] == full[rid][:n]
+        # greedy resume on a fresh engine == the uninterrupted run
+        d = exp["r1"]
         fresh = _engine(max_slots=2)
         fresh.submit("r1", np.asarray(d["prompt"])[None],
                      max_new_tokens=d["remaining"],
                      resume_tokens=d["committed"],
                      resume_lps=d["committed_lps"])
-        resumed = fresh.run()["r1"]
-        ref = _engine(max_slots=2)
-        ref.submit("r1", _cyc(6), max_new_tokens=30)
-        assert resumed == ref.run()["r1"]
+        assert fresh.run()["r1"] == full["r1"]
 
 
 @pytest.mark.slow
 class TestDeltaSweep:
-    @pytest.mark.parametrize("ring", [True, False])
     @pytest.mark.parametrize("chunk", [None, 8])
     @pytest.mark.parametrize("spec", [0, 3])
-    def test_parity_sweep(self, ring, chunk, spec):
-        """Heavy matrix: ring x chunked-prefill x speculative, longer
-        budgets, staggered second wave — fused vs delta vs rebuild
+    def test_parity_sweep(self, chunk, spec):
+        """Heavy matrix: chunked-prefill x speculative, longer budgets,
+        staggered second wave — the served engine vs the host tick,
         bitwise. (Tier-1 keeps the single-combination pins above.)"""
         if spec and chunk:
             kw = dict(chunk_prefill_tokens=chunk, spec_tokens=spec,
@@ -553,26 +520,26 @@ class TestDeltaSweep:
             kw = dict(spec_tokens=spec, prefill_buckets=(8,))
         else:
             kw = {}
-        # sampled rows join only the non-spec combos: sampled + spec
-        # across modes is distribution-preserving, not bitwise (the
-        # drafts read the uncommitted buffer tail, which rebuilds zero
-        # and patches preserve — documented in PERFORMANCE.md)
+        # sampled rows join only the non-spec combos: a sampled row
+        # under spec is equal to the reference in distribution, not
+        # bitwise (rejection sampling consumes its keys differently)
         subs = [(f"r{j}", _cyc(5 + j % 4, j), dict(
             max_new_tokens=10 + 3 * (j % 3),
             **({} if (j % 2 == 0 or spec) else
                dict(temperature=0.7, seed=j, top_k=12))))
             for j in range(6)]
 
-        def run(mode):
-            eng = _engine(ring_mode=ring, **MODES[mode], **kw)
+        def run(make):
+            eng = make(**kw)
             res, lps = _drain(eng, subs[:4])
             res2, lps2 = _drain(eng, subs[4:])
             res.update(res2)
             lps.update(lps2)
             return res, lps
 
-        rr, lr = run("rebuild")
-        rd, ld = run("delta")
-        rf, lf = run("fused")
-        assert rr == rd == rf
-        assert lr == ld == lf
+        (rr, lr), (rf, lf) = run(_reference), run(_engine)
+        assert rr == rf
+        if spec:
+            _lps_close(lr, lf)
+        else:
+            assert lr == lf

@@ -16,8 +16,9 @@ Contracts pinned here:
   histograms and the thread's wall, and no tick record; the serving
   gateway's worker reports all four.
 - BITWISE OFF==ON: profile-on greedy+sampled streams are bit-identical
-  to profile-off across engine modes (default fused ring, sync
-  readback, unfused tick, multi-tick dispatch) — the profiler reads
+  to profile-off across the engine's paths (the served fused tick,
+  its speculative and multi-tick dispatches, chunked prefill, and the
+  unfused host reference) — the profiler reads
   clocks and calls ``block_until_ready`` on arrays the next statement
   would block on anyway; it never changes what the device computes.
 - STEADY CONTRACT UNTOUCHED: with the profiler ON, steady decode
@@ -250,12 +251,12 @@ def test_real_clock_sum_within_validator_tolerance():
 
 # ====================================================== bitwise pins
 @pytest.mark.parametrize("mode_kw", [
-    {},                                # fused ring (default)
-    {"ring_mode": False},              # sync per-tick readback
-    {"fused_tick": False},             # unfused decode path
+    {},                                # the served fused tick
+    {"spec_tokens": 2},                # speculative tick
+    {"fused_tick": False},             # the host reference
     {"ticks_per_dispatch": 4},         # multi-tick dispatch
     {"chunk_prefill_tokens": 8},       # chunked prefill (ISSUE 24)
-], ids=["fused-ring", "sync", "unfused", "multi-tick", "chunked"])
+], ids=["fused-ring", "spec", "unfused", "multi-tick", "chunked"])
 def test_profile_on_off_bitwise(mode_kw):
     res_off, lp_off = _drain(_engine(**mode_kw))
     res_on, lp_on = _drain(_engine(tick_profile=True, **mode_kw))

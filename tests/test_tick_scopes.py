@@ -9,9 +9,8 @@ Contracts pinned here, at tiny widths and without compiling anything
   the scopes its stages have, and the three together use the whole
   vocabulary, so a scope that is renamed or dropped in the program
   fails here before a device trace loses it.
-- KERNEL NAMES: the Pallas kernel on each serving route (ragged,
-  grid) is a ``pallas_call`` with a ``name``: what a profiler trace
-  calls it.
+- KERNEL NAMES: the Pallas kernel of the serving route is a
+  ``pallas_call`` with a ``name``: what a profiler trace calls it.
 - SCOPES CHANGE NOTHING: a program lowered with ``jax.named_scope``
   patched to a null context HERE has the same histogram of opcodes. The
   program has no switch for this.
@@ -130,10 +129,8 @@ def _kernel_names(jaxpr):
 
 
 @pytest.mark.parametrize("route, name", [
-    ("ragged", "ragged_paged_attention"), ("grid", "paged_attention")])
-def test_serving_kernels_are_named(engine, kernels, monkeypatch, route,
-                                   name):
-    monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", route)
+    ("ragged", "ragged_paged_attention")])
+def test_serving_kernels_are_named(engine, kernels, route, name):
     assert engine.decode_route() == route
     names = _kernel_names(_trace(engine, "_fused_tick_greedy").jaxpr.jaxpr)
     layers = engine.model.config.num_hidden_layers

@@ -53,9 +53,9 @@ BUDGETS = (
     # (ISSUE 13's test_fleet.py sorting ahead of it shifted the bill)
     (r"test_mixed_spec_sampled_penalized_slots_one_tick", 16.0),
     # ~12s in-suite: the llama spec-tick twin pays the k+1 verify
-    # forward's compile; suite-order cache shifts (ISSUE 14's
-    # test_delta_transitions.py sorts ahead of test_paged_spec.py)
-    # push it over the default by a hair
+    # forward's compile; suite-order cache shifts (which test files
+    # sort ahead of test_paged_spec.py) push it over the default by a
+    # hair
     (r"test_llama_tokens_exact_logprobs_close", 16.0),
 )
 
@@ -138,10 +138,11 @@ MUST_BE_SLOW = (
     # pass's conftest _SLOW demotions (each names its surviving tier-1
     # representative in conftest.py)
     r"test_ring_spec\.py.*distribution_parity_sweep",
-    # ISSUE 14: the delta-transition ring x chunk x spec parity matrix
-    # (tier-1 keeps the single-combination transition-matrix, scoped-
-    # drain and upload-counter pins in test_delta_transitions.py)
-    r"test_delta_transitions\.py.*parity_sweep",
+    # ISSUE 14: the staged-transition chunk x spec parity matrix
+    # against the host tick (tier-1 keeps the single-combination
+    # transition-matrix, scoped-drain and upload-counter pins in
+    # test_staged_transitions.py)
+    r"test_staged_transitions\.py.*parity_sweep",
     # ISSUE 15: the multi-window burn-rate sweep (seeded outcome
     # streams x window scales x thresholds), the multi-PROCESS fleet
     # federation e2e (real replica subprocesses, cold jax import

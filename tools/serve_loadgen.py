@@ -55,14 +55,10 @@ zero corrupted streams with the tier on.
 ``--churn`` (ISSUE 14) swaps in a transition-heavy mix — short,
 staggered per-request budgets so replica slots finish and readmit
 every few ticks — and the rung records ``full_rebuilds`` /
-``delta_patches`` / ``h2d_upload_bytes`` from the engines;
-``--delta off`` keeps the full-rebuild transition path as the A/B
-reference (pair them to see what slot churn costs each way).
-``--patch-fuse off`` (ISSUE 19) keeps the standalone-patch-dispatch
-reference instead; the default fuses pending transition descriptors
-into the next tick's program, and the rung's ``patches_fused`` /
-``patch_queue_overflows`` / ``dispatches_per_tick`` fields show churn
-riding one dispatch per tick fleet-wide.
+``patches_fused`` / ``h2d_upload_bytes`` / ``dispatches_per_tick``
+from the engines: pending transition descriptors are staged into the
+next tick's program (ISSUE 19), so churn rides one dispatch per tick
+fleet-wide.
 
 Fleet mode (ISSUE 13): ``--url`` may repeat (client-side round-robin
 over several fleet front doors), ``--diurnal`` replaces the flat
@@ -347,21 +343,6 @@ def _build_gateway(ns):
 
         def _model():
             return model
-    # --ring off: the synchronous-readback reference engines (ISSUE 11
-    # A/B — same workload, same gateway, only the tick readback
-    # architecture differs); --delta off likewise keeps the full-
-    # rebuild transition reference (ISSUE 14 A/B)
-    engine_kw["ring_mode"] = getattr(ns, "ring", "on") == "on"
-    engine_kw["delta_transitions"] = \
-        getattr(ns, "delta", "on") == "on"
-    # --patch-fuse off: the standalone-patch-dispatch reference
-    # (ISSUE 19 A/B — same descriptors, dispatched one tiny program
-    # per transition instead of staged into the tick). Only the "off"
-    # side is passed through: the default (None) lets the engine fuse
-    # whenever delta transitions are on.
-    if getattr(ns, "patch_fuse", "on") == "off" \
-            and engine_kw["delta_transitions"]:
-        engine_kw["patch_fuse"] = False
     # --tick-profile on: per-tick phase attribution (ISSUE 20) — the
     # rung banks phase_breakdown from the engines' phase totals
     engine_kw["tick_profile"] = \
@@ -732,19 +713,6 @@ async def run_loadgen(ns) -> dict:
     fleet = int(getattr(ns, "fleet", 0) or 0)
     urls = ns.url if isinstance(ns.url, list) \
         else ([ns.url] if ns.url else [])
-    if (urls or fleet) and getattr(ns, "delta", "on") == "off":
-        # --fleet replica processes and external --url servers run
-        # their own engine defaults (replica_main has no --delta);
-        # silently recording "delta": "off" would mislabel a delta-on
-        # run as the full-rebuild reference in the A/B rung
-        raise SystemExit("--delta off requires in-process replicas "
-                         "(no --fleet / --url): fleet peers and "
-                         "external servers don't receive it")
-    if (urls or fleet) and getattr(ns, "patch_fuse", "on") == "off":
-        # same mislabeling hazard as --delta off: the knob only
-        # reaches engines this process constructs
-        raise SystemExit("--patch-fuse off requires in-process "
-                         "replicas (no --fleet / --url)")
     if (urls or fleet) and getattr(ns, "tick_profile", "off") == "on":
         # phase_breakdown is summed from THIS process's engine
         # objects; fleet replica processes and external servers never
@@ -1032,9 +1000,6 @@ async def run_loadgen(ns) -> dict:
         "policy": ns.policy,
         "replicas": ns.replicas,
         "model": ns.model if not urls else "external",
-        "ring": getattr(ns, "ring", "on"),
-        "delta": getattr(ns, "delta", "on"),
-        "patch_fuse": getattr(ns, "patch_fuse", "on"),
         "tick_profile": getattr(ns, "tick_profile", "off"),
         "churn": bool(getattr(ns, "churn", False)),
         "targets": len(targets),
@@ -1060,23 +1025,19 @@ async def run_loadgen(ns) -> dict:
         rung["peak_burn_rate"] = max(
             snap["peak_burn"].values(), default=0.0)
         rung["peak_burn_by_class"] = snap["peak_burn"]
-    if engines is not None and getattr(ns, "ring", "on") == "on":
+    if engines is not None:
         rung["ring_drains"] = sum(e.ring_drains for e in engines)
         rung["ring_blocking_drains"] = sum(e.ring_blocking_drains
                                            for e in engines)
-    if engines is not None:
-        # ISSUE 14: how the run's slot churn was paid for — one-row
-        # patches vs full-state rebuilds, and the H2D bytes either way
+        # ISSUE 14: how the run's slot churn was paid for — staged
+        # descriptors vs full-state rebuilds, and the H2D bytes
         rung["full_rebuilds"] = sum(e.full_rebuilds for e in engines)
-        rung["delta_patches"] = sum(e.delta_patches for e in engines)
         rung["h2d_upload_bytes"] = sum(e.h2d_upload_bytes
                                        for e in engines)
         # ISSUE 19: the fleet-level one-dispatch-per-tick evidence —
         # staged rows carried the churn, dispatches/tick stays ~1 plus
         # the run's prefill share
         rung["patches_fused"] = sum(e.patches_fused for e in engines)
-        rung["patch_queue_overflows"] = sum(
-            e.patch_queue_overflows for e in engines)
         ticks = sum(e.stats["decode_steps"] for e in engines)
         rung["dispatches_per_tick"] = round(
             sum(e.dispatch_count for e in engines) / ticks, 3) \
@@ -1430,27 +1391,11 @@ def main(argv=None) -> int:
     ap.add_argument("--max-queue", type=int, default=256)
     ap.add_argument("--model", default="tiny",
                     choices=("tiny", "stub"))
-    ap.add_argument("--ring", default="on", choices=("on", "off"),
-                    help="async token-ring decode on the replica "
-                         "engines (off = synchronous per-tick "
-                         "readback, the ISSUE 11 A/B reference)")
-    ap.add_argument("--delta", default="on", choices=("on", "off"),
-                    help="delta slot transitions on the replica "
-                         "engines (off = full mirror rebuild per "
-                         "transition, the ISSUE 14 A/B reference)")
     ap.add_argument("--churn", action="store_true",
                     help="transition-heavy workload mix (ISSUE 14): "
                          "short staggered max-new budgets so slots "
                          "finish + readmit every few ticks; the rung "
-                         "records full_rebuilds/delta_patches")
-    ap.add_argument("--patch-fuse", dest="patch_fuse", default="on",
-                    choices=("on", "off"),
-                    help="fused patch+tick program (ISSUE 19): stage "
-                         "transition descriptors into the device "
-                         "queue the next tick applies in-program (off "
-                         "= one standalone patch dispatch per "
-                         "transition, the PR 12 A/B reference); the "
-                         "rung records patches_fused and "
+                         "records full_rebuilds, patches_fused and "
                          "dispatches_per_tick")
     ap.add_argument("--tick-profile", dest="tick_profile",
                     default="off", choices=("on", "off"),

@@ -7,9 +7,9 @@ Runs every Pallas kernel and every engine program that the CPU suite
 only ever sees through the interpreter once through the real compiler
 on whatever backend jax selects, each against its XLA reference: the
 flash kernel's segment / window / backward variants, ``quant_matmul``,
-the decode re-block, the grid and ragged paged-attention kernels
-(single- and multi-query), and the tick / patch / restore programs of
-the serving engine. ``chip_smoke.py`` covers the default serving route
+the decode re-block, the ragged paged-attention kernel (single- and
+multi-query, K/V and latent pools), and the tick / patch / restore
+programs of the serving engine. ``chip_smoke.py`` covers the default serving route
 end to end; this covers the kernels and programs off that route.
 
 The checks run in ONE child process (a chip belongs to one process;
@@ -119,28 +119,6 @@ def deco():
     assert err < 3e-2, err
 check("decode_kernel", deco)
 
-def paged_kernel():
-    from paddle_tpu.ops.pallas.paged_attention import paged_attention_pallas
-    from paddle_tpu.ops.attention import dense_attention as da
-    R, P, B, M, kvh2, h2, d2 = 4, 64, 16, 16, 4, 8, 128
-    qq = jnp.asarray(rs.randn(R, h2, d2), jnp.bfloat16)
-    kp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
-    vp = jnp.asarray(rs.randn(P, B, kvh2 * d2), jnp.bfloat16)
-    tables = jnp.asarray(rs.permutation(np.arange(P))[:R * M]
-                         .reshape(R, M), jnp.int32)
-    lens = jnp.asarray([0, 31, 100, 255], jnp.int32)
-    out = paged_attention_pallas(qq, kp, vp, tables, lens, d2 ** -0.5,
-                                 kvh2)
-    ks = kp[tables].reshape(R, -1, kvh2, d2)
-    vs = vp[tables].reshape(R, -1, kvh2, d2)
-    kpos = jnp.arange(ks.shape[1])[None, :]
-    ref = da(qq[:, None], ks, vs,
-             attn_mask=(kpos <= lens[:, None])[:, None, None, :])[:, 0]
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
-                                - ref.astype(jnp.float32))))
-    assert err < 3e-2, err
-check("paged_attention_kernel", paged_kernel)
-
 def ragged_cell_inputs(T=None):
     # the serving cell's geometry (qwen2-7b-d16: 8 slots, 128 blocks of
     # 16 tokens, 4 kv heads, group 7, head 128, bf16, a 2049-block pool
@@ -204,8 +182,9 @@ def latent_paged_kernel():
     # 64 query heads over one 640-column row a token, values its first
     # 512 columns, 64 rows, an 8193-block pool), single-query and
     # multi-query, against the dense gather
-    import os
-    from paddle_tpu.generation.paged import PagedKV, paged_latent_attention
+    from paddle_tpu.generation.paged import (PagedKV, paged_decode_route,
+                                             paged_latent_attention,
+                                             paged_latent_attention_dense)
     R, P, B, M, h2, W, dv = 64, 8193, 16, 128, 64, 640, 512
     kp = jnp.asarray(rs.randn(P, B, W), jnp.bfloat16)
     tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
@@ -213,26 +192,24 @@ def latent_paged_kernel():
     lens = jnp.asarray(([0, 15, 16, 2040, 100, 576, 1023, 300]
                         + list(rs.randint(0, 2040, R - 8))), jnp.int32)
 
-    def attend(route):
-        def run(q):
-            os.environ["PADDLE_TPU_PAGED_ATTN"] = route     # when traced
-            try:
-                return paged_latent_attention(
-                    q, PagedKV(kp, None, tables, lens), dv, 192 ** -0.5)
-            finally:
-                del os.environ["PADDLE_TPU_PAGED_ATTN"]
-        return jax.jit(run)
+    pk = PagedKV(kp, None, tables, lens)
+
+    def attend(fn):
+        return jax.jit(lambda q: fn(q, pk, dv, 192 ** -0.5))
 
     for T in (1, 3):
         qq = jnp.asarray(rs.randn(R, T, h2, W) * 0.3, jnp.bfloat16)
-        got, ref = attend("ragged")(qq), attend("dense")(qq)
+        assert dev.platform != "tpu" \
+            or paged_decode_route(qq, kp, 1) == "ragged"
+        got = attend(paged_latent_attention)(qq)
+        ref = attend(paged_latent_attention_dense)(qq)
         err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                     - ref.astype(jnp.float32))))
         assert got.shape == (R, T, h2, dv) and err < 3e-2, (T, err)
 check("latent_paged_kernel", latent_paged_kernel)
 
 def ring_tick_program():
-    # ISSUE 11: the ring-mode fused tick program (device-resident ring
+    # ISSUE 11: the fused tick program's token ring (device-resident ring
     # buffer + write cursors carried in the tick state, no per-tick
     # readback) must compile and stream correctly on hardware. The
     # negligible-compute stub keeps this a TICK-MACHINERY check, like
@@ -242,7 +219,6 @@ def ring_tick_program():
     eng = PagedEngine(TickStubModel(), max_slots=4, num_blocks=32,
                       block_size=8, max_blocks_per_seq=8,
                       prefill_buckets=(8,))
-    assert eng._ring
     for i in range(3):
         eng.submit(i, np.arange(1, 6)[None], max_new_tokens=12)
     res = eng.run()
@@ -274,31 +250,31 @@ def rejection_spec_tick():
 check("rejection_spec_tick", rejection_spec_tick)
 
 def delta_patch_program():
-    # ISSUE 14: the delta-transition patch program — admit-row scatter
-    # plus table-row append into the device-resident tick state — must
+    # ISSUE 14: slot transitions as descriptors — admit-row scatter plus
+    # table-row append into the device-resident tick state — must
     # compile and stream correctly on hardware at a serving block
-    # geometry (block_size 16 x 16 blocks/seq). Churny short
-    # requests (more requests than slots, budgets crossing the block
-    # grid) force admit/finish/growth patches; after the first
-    # dispatch's rebuild, every transition must ride a patch.
-    # patch_fuse=False pins the STANDALONE per-row program — since
-    # ISSUE 19 it is the fused queue's overflow fallback, so it must
-    # keep compiling on hardware even though the default never uses it.
+    # geometry (block_size 16 x 16 blocks/seq), in the MIXED tick
+    # program too (a sampled row's key rides the descriptor; the check
+    # below is all greedy). Churny short requests (more requests than
+    # slots, budgets crossing the block grid) force admit/finish/growth
+    # descriptors; every transition after the first rebuild rides the
+    # queue.
     from paddle_tpu.generation.paged import PagedEngine
     from paddle_tpu.generation.stub import TickStubModel
     eng = PagedEngine(TickStubModel(), max_slots=4, num_blocks=64,
                       block_size=16, max_blocks_per_seq=16,
-                      prefill_buckets=(16,), patch_fuse=False)
-    assert eng._delta
-    eng.submit("w", np.arange(1, 6)[None], max_new_tokens=2)
+                      prefill_buckets=(16,))
+    eng.submit("w", np.arange(1, 6)[None], max_new_tokens=2,
+               temperature=0.8, seed=9)
     eng.run()
     fr0 = eng.full_rebuilds
     for i in range(8):
         # 9 + 24 = 33 tokens: crosses two block boundaries -> growth
-        eng.submit(i, np.arange(1, 10)[None], max_new_tokens=24)
+        eng.submit(i, np.arange(1, 10)[None], max_new_tokens=24,
+                   temperature=0.8 * (i & 1), seed=i)
     res = eng.run()
     assert all(len(v) == 24 for k, v in res.items() if k != "w"), res
-    assert eng.delta_patches > 0
+    assert eng.patches_fused > 0
     assert eng.full_rebuilds == fr0, (eng.full_rebuilds, fr0)
 check("delta_patch_program", delta_patch_program)
 
@@ -307,7 +283,7 @@ def fused_patch_tick_program():
     # scatter stage prepended to the tick, fed by the device-resident
     # [Q, D] descriptor queue — must compile as ONE executable on
     # hardware at the same geometry and absorb churn with zero
-    # post-warmup standalone patch dispatches and zero rebuilds: the
+    # rebuilds after the warm-up and no dispatch of its own: the
     # dispatch counter must advance exactly once per tick + once per
     # prefill across a churny run.
     from paddle_tpu.generation.paged import PagedEngine
@@ -315,7 +291,6 @@ def fused_patch_tick_program():
     eng = PagedEngine(TickStubModel(), max_slots=4, num_blocks=64,
                       block_size=16, max_blocks_per_seq=16,
                       prefill_buckets=(16,))
-    assert eng._fuse_patches
     eng.submit("w", np.arange(1, 6)[None], max_new_tokens=2)
     eng.run()                      # warmup: compiles tick + prefill
     fr0, d0 = eng.full_rebuilds, eng.dispatch_count
@@ -325,8 +300,6 @@ def fused_patch_tick_program():
     res = eng.run()
     assert all(len(v) == 24 for k, v in res.items() if k != "w"), res
     assert eng.patches_fused > 0
-    assert eng.delta_patches == 0, eng.delta_patches
-    assert eng.patch_queue_overflows == 0
     assert eng.full_rebuilds == fr0, (eng.full_rebuilds, fr0)
     ticks = eng.stats["decode_steps"] - t0
     prefills = eng.stats["prefills"] - p0
