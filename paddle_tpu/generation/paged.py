@@ -40,14 +40,35 @@ TPU-native design — everything the XLA program sees is STATIC:
     own; a steady tick uploads nothing.
   * TOKENS RIDE A RING. The program appends what it commits to a
     device-resident ring ([R, ring_len] with per-slot monotone write
-    cursors carried in the tick state); the host consumes the PREVIOUS
-    dispatch's slice at the top of the next ``step()``
-    (``_drain_pending``), so dispatches issue back to back. Stream
+    cursors carried in the tick state) and the host reads a
+    dispatch's slice one step later (``_drain_oldest``). Stream
     writes, stop matching, finishes and trace events are driven off
-    drained entries, one step behind the device. ``step()`` drains
-    before it touches any slot; an out-of-band cancel or expiry drains
-    only its own row (``_drain_row``), so the mirrors a transition
-    reads are never stale.
+    drained entries, one step behind the device. An out-of-band cancel
+    or expiry drains only its own row (``_drain_row``), from every
+    outstanding dispatch, so the mirrors a transition reads are never
+    stale.
+  * WHAT IS IN FLIGHT WHEN. Between steps ONE dispatch is outstanding.
+    A step with something to decide (a free slot, a row mid-prefill, a
+    finished, cancelled or expired row, pool pressure) drains it
+    FIRST, so every transition reads current mirrors, then expires,
+    admits, chunks, stages and dispatches the next tick: the device
+    waits for the host in between. A step with nothing to decide
+    (``_may_run_ahead``: every slot holds a decoding request, the
+    pool serves the next blocks) dispatches tick N+1 FIRST and drains
+    tick N after it: TWO are outstanding inside such a step, the chip
+    always has its next program queued behind the running one, and
+    the host's whole round (the wait for N, drain, commit, the
+    caller's emit and scheduling, stage, call) runs under tick N+1.
+    The only transition such a step makes is block growth, one
+    position past the undrained tick's; its patch carries the table
+    row alone (``_DESC_TABLE_ONLY``), because the other mirrors lag
+    the device by that tick. What the device commits for a row after
+    the host ended its request (a stop matched at the drain, an eos
+    the host could not foresee) is never read: the drained cursor
+    steps over it and the K/V write dies with the released blocks.
+    ``runahead_ticks`` counts the decode dispatches made with one
+    undrained; ``health()["outstanding_dispatches"]`` says how many
+    are in flight.
   * A FULL REBUILD of the device state from the host mirrors
     (``_refresh_dev``) happens at the first dispatch, after
     ``hard_reset`` and when a ring cursor nears the end of int32
@@ -126,6 +147,11 @@ _SPEC_PROBE_EVERY = 16     # collapsed rows re-probe with k=1 this often
 # the device state zeroes them: once a row has drained this many tokens
 # the next transition rebuilds (`_flush_patches`), long before the wrap.
 _RING_CURSOR_LIMIT = 2 ** 30
+
+# flags of a staged descriptor (its word 6): the PRNG key it carries is
+# authoritative; it patches the block-table row and nothing else
+_DESC_KEY_OVERRIDE = 1
+_DESC_TABLE_ONLY = 2
 
 
 def _home_device(params):
@@ -704,7 +730,6 @@ class PagedEngine:
                  max_queue: Optional[int] = None,
                  default_timeout_s: Optional[float] = None,
                  fused_tick: bool = True,
-                 ticks_per_dispatch: int = 1,
                  spec_tokens: int = 0,
                  spec_ngram: int = 2,
                  tick_profile: bool = False,
@@ -804,6 +829,9 @@ class PagedEngine:
             getattr(model, "tick_counters", tuple)())
         self._tick_counts_seen = np.zeros(
             (len(self._tick_counter_names),), np.int64)
+        # runahead_ticks: decode dispatches made while another was
+        # undrained (_may_run_ahead); over decode_steps, the share of
+        # ticks the device had its next program queued behind
         # spec_proposed/spec_accepted (ISSUE 7): drafted vs accepted
         # draft tokens — `health()` derives the accept rate from the
         # SAME registry objects a /metrics scrape exports
@@ -820,7 +848,7 @@ class PagedEngine:
                       "cancellations", "rejected",
                       "spec_proposed", "spec_accepted",
                       "full_rebuilds", "h2d_upload_bytes",
-                      "dispatches", "patches_fused",
+                      "dispatches", "runahead_ticks", "patches_fused",
                       "ring_cursor_rollovers",
                       "spill_spans", "spill_restores",
                       "spill_restored_tokens",
@@ -903,8 +931,7 @@ class PagedEngine:
         # h2d_uploads event.
         self.dispatch_count = 0
         # steps that dispatched a decode program: what a per-tick
-        # figure divides by (``decode_steps`` counts K device ticks
-        # for a K-tick scan; a chunk figure divides by the
+        # figure divides by (a chunk figure divides by the
         # ``prefill_chunks`` counter)
         self.decode_ticks = 0
         self.h2d_uploads = 0
@@ -923,20 +950,6 @@ class PagedEngine:
                                  donate_argnums=(1, 2))
         self._tick_greedy_jit = jax.jit(self._fused_tick_greedy,
                                         donate_argnums=(1, 2))
-        # MPK-style multi-tick fusion: lax.scan K device-resident ticks
-        # inside ONE compiled program, amortizing the per-dispatch floor
-        # over K tokens. Only taken when provably stream-exact (see
-        # _scan_ticks); K=1 (default) keeps strict per-tick scheduling.
-        self._ticks_per_dispatch = max(1, int(ticks_per_dispatch))
-        if self._ticks_per_dispatch > 1:
-            self._scan_greedy_jit = jax.jit(
-                functools.partial(self._fused_scan, greedy=True,
-                                  K=self._ticks_per_dispatch),
-                donate_argnums=(1, 2))
-            self._scan_jit = jax.jit(
-                functools.partial(self._fused_scan, greedy=False,
-                                  K=self._ticks_per_dispatch),
-                donate_argnums=(1, 2))
         # --- prompt-lookup speculative ticks (ISSUE 7 tentpole) -------
         # spec_tokens=k > 0: every fused tick drafts up to k tokens per
         # eligible slot from that request's OWN committed stream (no
@@ -949,9 +962,7 @@ class PagedEngine:
         # by the rejection rule, penalized rows via the per-position
         # penalty scan); a row falls back to the 1-token tick
         # per-request (inside the same program) when block headroom is
-        # missing or its accept-rate EMA collapses. Takes precedence
-        # over ticks_per_dispatch scanning: a spec tick is already a
-        # multi-token dispatch.
+        # missing or its accept-rate EMA collapses.
         self._spec_k = int(spec_tokens)
         self._spec_ngram = int(spec_ngram)
         if self._spec_k:
@@ -973,14 +984,19 @@ class PagedEngine:
         # --- async token ring (ISSUE 11) ------------------------------
         # the fused tick program appends committed (token, logprob)
         # pairs into a device-resident ring carried in the tick state;
-        # the host consumes the PREVIOUS dispatch's slice at the top of
-        # the next step() (_drain_pending). The ring must hold every
-        # entry one dispatch can commit with double-buffer slack: twice
-        # the largest per-dispatch advance (scan K ticks, or the spec
-        # window k+1).
-        maxadv = max(self._ticks_per_dispatch, self._spec_k + 1)
-        self._ring_len = max(16, 2 * maxadv)
-        self._pending: Optional[Dict[str, Any]] = None  # outstanding tick
+        # the host consumes a dispatch's slice one step later
+        # (_drain_oldest). The ring must hold every entry the
+        # outstanding dispatches can commit with double-buffer slack:
+        # twice a dispatch's advance (the spec window k+1; a plain tick
+        # commits 1 and at most two of them are outstanding).
+        self._ring_len = max(16, 2 * (self._spec_k + 1))
+        # the dispatches not yet drained, oldest first: one between
+        # steps, two inside a run-ahead step (_may_run_ahead). Each
+        # record keeps ITS program's ring / cursor / active outputs
+        # (the state dict is not donated, so they stay readable after
+        # the next tick took them as input) and the request each of its
+        # rows served, so a drain never credits a slot's next tenant
+        self._pending: deque = deque()
         self._drained = np.zeros((self.R,), np.int64)   # consumed cursors
         # readback instrumentation: d2h_syncs counts BLOCKING readbacks
         # (every host-path tick; on the fused path only drains that had
@@ -1000,10 +1016,16 @@ class PagedEngine:
         # scatter before computing. One executable, one dispatch,
         # whether the tick carries 0 or R transitions: descriptors
         # coalesce per slot, so R rows always suffice.
-        self._delta_rows: set = set()   # slots awaiting a patch flush
+        # slots awaiting a patch flush -> whether the patch is the
+        # whole descriptor. False: the table row grew and nothing else
+        # changed, so the patch carries the table row only
+        # (_DESC_TABLE_ONLY): a decoding row's other mirrors may lag
+        # the device by a tick
+        self._delta_rows: Dict[int, bool] = {}
         # descriptor layout (int32 vector; floats/keys ride as raw
         # bits): [0]=row [1]=lens [2]=last [3]=eos [4]=rem [5]=active
-        # [6]=key_override [7]=temp [8]=top_k [9]=top_p [10]=rep
+        # [6]=flags (_DESC_KEY_OVERRIDE, _DESC_TABLE_ONLY)
+        # [7]=temp [8]=top_k [9]=top_p [10]=rep
         # [11:13]=PRNG key [13]=spec ema [14]=spec tick counter
         # [15:15+M]=block-table row [15+M:]=committed-token row (spec)
         self._desc_len = 15 + self.M + (
@@ -1315,34 +1337,6 @@ class PagedEngine:
             return self._fused_epilogue(st, new_caches, seen, nxt, lps,
                                         st["keys"], counts)
 
-    def _fused_scan(self, params, pools, seen, st, *, greedy: bool,
-                    K: int):
-        """K fused ticks inside ONE compiled program (``lax.scan`` over
-        the single-tick core — the MPK "as few programs as possible"
-        endpoint). Each iteration is the SAME traced computation as the
-        K=1 executable, so the emitted stream is bit-identical to K
-        single dispatches; the per-dispatch floor is amortized over K
-        tokens. Rows that finish (eos/budget) mid-scan deactivate via
-        the device active mask and stop advancing; their later (nxt,
-        lps) slots are garbage the host never reads past the first done
-        flag. Returns (nxt[K,R], lps[K,R], done[K,R], seen, pools, st).
-
-        The fused patch stage rides the tick core: iteration 0 applies
-        the staged queue and zeroes ``pqn`` in the carry, so iterations
-        1..K-1 re-trace the stage as an all-dropped (bitwise no-op)
-        scatter — staged transitions land exactly once per dispatch."""
-        tick = self._fused_tick_greedy if greedy else self._fused_tick
-
-        def body(carry, _):
-            pools, seen, st = carry
-            nxt, lps, done, seen, pools, st = tick(params, pools, seen,
-                                                   st)
-            return (pools, seen, st), (nxt, lps, done)
-
-        (pools, seen, st), (nxt, lps, done) = jax.lax.scan(
-            body, (pools, seen, st), None, length=K)
-        return nxt, lps, done, seen, pools, st
-
     def _fused_tick_spec(self, params, pools, seen, st, *, greedy: bool):
         """ONE compiled program for a speculative multi-token tick
         (ISSUE 7, rejection-sampled verify ISSUE 11): per-row
@@ -1502,15 +1496,18 @@ class PagedEngine:
                 [c.pool for c in new_caches], new_st)
 
     # ------------------------------- staged slot transitions (ISSUE 14, 19)
-    def _mark_dirty(self, slot_id: int):
+    def _mark_dirty(self, slot_id: int, table_only: bool = False):
         """A slot transition touched ``slot_id``'s mirrors: queue its
         descriptor for the next flush (several transitions of one slot
-        coalesce into its final state). Before there is a device state
-        to patch, the rebuild that makes it reads the mirrors whole."""
-        if self._dev is not None and not self._dev_dirty:
-            self._delta_rows.add(slot_id)
-        else:
+        coalesce into its final state; ``table_only`` is block growth,
+        which any other transition of the slot subsumes). Before there
+        is a device state to patch, the rebuild that makes it reads the
+        mirrors whole."""
+        if self._dev is None or self._dev_dirty:
             self._dev_dirty = True
+        else:
+            self._delta_rows[slot_id] = not table_only \
+                or self._delta_rows.get(slot_id, False)
 
     @staticmethod
     def _slot_row_fields(s):
@@ -1530,7 +1527,8 @@ class PagedEngine:
                 last = s.tokens[-1]
         return last, eos, rem, act
 
-    def _pack_descriptor(self, i: int) -> np.ndarray:
+    def _pack_descriptor(self, i: int,
+                         table_only: bool = False) -> np.ndarray:
         """Pack slot ``i``'s CURRENT host-mirror state into one int32
         descriptor vector (floats and the uint32 PRNG key ride as raw
         bits). Field values follow ``_refresh_dev``'s per-row rules
@@ -1540,13 +1538,21 @@ class PagedEngine:
         authoritative only for rows the HOST re-keyed (fresh admits,
         chunk-final): for every other row the device key stream —
         possibly advanced by sampled ticks since the last rebuild —
-        must survive the patch untouched."""
+        must survive the patch untouched. ``table_only`` (a decoding
+        row whose table grew) carries the table row and the flag that
+        makes the program leave everything else alone: with a tick
+        undrained, ``seq_lens`` and ``tokens[-1]`` are one behind the
+        device's ``lens`` and ``last``."""
         s = self.slots[i]
         d = np.zeros((self._desc_len,), np.int32)
         d[0] = i
+        d[15:15 + self.M] = self.block_tables[i]
+        if table_only:
+            d[6] = _DESC_TABLE_ONLY
+            return d
         d[1] = self.seq_lens[i]
         d[2], d[3], d[4], d[5] = self._slot_row_fields(s)
-        d[6] = 1 if i in self._key_overrides else 0
+        d[6] = _DESC_KEY_OVERRIDE if i in self._key_overrides else 0
         d[7] = np.float32(self.temps[i]).view(np.int32)
         d[8] = self.top_ks[i]
         d[9] = np.float32(self.top_ps[i]).view(np.int32)
@@ -1561,7 +1567,6 @@ class PagedEngine:
             d[15 + self.M:] = token_buffer_row(
                 s.prompt + s.tokens if s is not None else (),
                 self._desc_len - 15 - self.M)
-        d[15:15 + self.M] = self.block_tables[i]
         return d
 
     def _apply_patch_queue(self, st):
@@ -1579,24 +1584,28 @@ class PagedEngine:
         Invalid queue entries are routed to the out-of-bounds row index
         R and dropped (``mode="drop"``): a zero-count queue makes every
         scatter a bitwise no-op, which is what lets the stage ride
-        steady ticks for free. Descriptor rows are unique (host
-        coalescing keys the pending set by slot), so scatter order
-        never matters. ``pqn`` resets to 0 in-program; the staged
-        ``pq`` array itself is replaced host-side at the next flush."""
+        steady ticks for free; a ``_DESC_TABLE_ONLY`` entry is valid for
+        the table scatter and dropped by every other. Descriptor rows
+        are unique (host coalescing keys the pending set by slot), so
+        scatter order never matters. ``pqn`` resets to 0 in-program;
+        the staged ``pq`` array itself is replaced host-side at the
+        next flush."""
         from .sampling import override_key_rows
         pq, pqn = st["pq"], st["pqn"]
         M = self.M
         valid = jnp.arange(pq.shape[0]) < pqn
-        rows = jnp.where(valid, pq[:, 0], self.R)
+        whole = valid & ((pq[:, 6] & _DESC_TABLE_ONLY) == 0)
+        rows = jnp.where(whole, pq[:, 0], self.R)
 
         def f32(x):
             return jax.lax.bitcast_convert_type(x, jnp.float32)
 
-        def scat(arr, vals):
-            return arr.at[rows].set(vals, mode="drop")
+        def scat(arr, vals, at=rows):
+            return arr.at[at].set(vals, mode="drop")
 
         new = dict(st)
-        new["tables"] = scat(st["tables"], pq[:, 15:15 + M])
+        new["tables"] = scat(st["tables"], pq[:, 15:15 + M],
+                             jnp.where(valid, pq[:, 0], self.R))
         new["lens"] = scat(st["lens"], pq[:, 1])
         new["last"] = scat(st["last"], pq[:, 2])
         new["eos"] = scat(st["eos"], pq[:, 3])
@@ -1607,8 +1616,9 @@ class PagedEngine:
         new["tps"] = scat(st["tps"], f32(pq[:, 9]))
         new["reps"] = scat(st["reps"], f32(pq[:, 10]))
         keys = jax.lax.bitcast_convert_type(pq[:, 11:13], jnp.uint32)
-        new["keys"] = override_key_rows(st["keys"], pq[:, 0], keys,
-                                        valid & (pq[:, 6] != 0))
+        new["keys"] = override_key_rows(
+            st["keys"], pq[:, 0], keys,
+            whole & ((pq[:, 6] & _DESC_KEY_OVERRIDE) != 0))
         if "toks" in st:
             new["toks"] = scat(st["toks"], pq[:, 15 + M:])
             new["ema"] = scat(st["ema"], f32(pq[:, 13]))
@@ -1617,21 +1627,25 @@ class PagedEngine:
         return new
 
     def _flush_patches(self):
-        """Hand every pending transition to the device (immediately
-        before a dispatch, after the step's drain — so host mirrors and
-        device state agree for every untouched row): the coalesced
-        descriptors are STAGED into the device-resident patch queue
-        with one plain H2D upload — no dispatch — and the imminent tick
-        program's ``_apply_patch_queue`` stage applies them all in its
-        batched scatter.
+        """Hand every pending transition to the device, immediately
+        before a dispatch: the coalesced descriptors are STAGED into
+        the device-resident patch queue with one plain H2D upload — no
+        dispatch — and the imminent tick program's
+        ``_apply_patch_queue`` stage applies them all in its batched
+        scatter. A whole descriptor is packed from mirrors the step's
+        drain made current; with a tick undrained (a run-ahead step)
+        only table-only ones are pending.
 
         The caller contract that makes staging safe: `_sync_dev` is
         only ever invoked by `_decode_fused` immediately before its
         dispatch, so a staged queue is always consumed by the very next
         program and key overrides can be discarded at staging time."""
-        if int(self._drained.max(initial=0)) > _RING_CURSOR_LIMIT:
+        if not self._pending and \
+                int(self._drained.max(initial=0)) > _RING_CURSOR_LIMIT:
             # int32 ring-cursor headroom guard: the device write
-            # cursors grow until a rebuild zeroes them. Counted, so a
+            # cursors grow until a rebuild zeroes them, which needs
+            # the ring drained (a run-ahead step leaves it to the next
+            # transition that is not growth). Counted, so a
             # long-lived replica's lone rebuild reads as cursor
             # hygiene, not a bug.
             self.ring_cursor_rollovers += 1
@@ -1643,8 +1657,10 @@ class PagedEngine:
         assert len(rows) <= self.R, rows
         pq = np.zeros((self.R, self._desc_len), np.int32)
         for j, i in enumerate(rows):
-            pq[j] = self._pack_descriptor(i)
-            self._key_overrides.discard(i)
+            whole = self._delta_rows[i]
+            pq[j] = self._pack_descriptor(i, table_only=not whole)
+            if whole:
+                self._key_overrides.discard(i)
         with self._phase("h2d"):
             self._dev["pq"] = self._put(pq)
             self._dev["pqn"] = self._put(np.int32(len(rows)))
@@ -2488,7 +2504,7 @@ class PagedEngine:
     def _grow_blocks(self, slot_id: int, need: int,
                      reserve: int = 0) -> bool:
         """Grow a slot's table to ``need`` blocks from the allocator
-        (one shared implementation for decode growth, scan and spec
+        (one shared implementation for decode growth and spec
         headroom). ``reserve`` refuses to dip the allocatable pool
         (free + parked) at or below that count — speculative callers
         use it so their grabs can never starve `_ensure_block`.
@@ -2503,13 +2519,15 @@ class PagedEngine:
                 return False
             slot.blocks.append(b)
             self.block_tables[slot_id, len(slot.blocks) - 1] = b
-            self._mark_dirty(slot_id)   # table row grew: patch/re-upload
+            self._mark_dirty(slot_id, table_only=True)
         return True
 
-    def _ensure_block(self, slot_id: int) -> bool:
-        """The next decode writes at seq_lens[slot_id]; allocate the
-        covering block if the row hasn't got it yet."""
-        need = self._blocks_needed(int(self.seq_lens[slot_id]) + 1)
+    def _ensure_block(self, slot_id: int, ahead: int = 0) -> bool:
+        """The next decode writes at seq_lens[slot_id], ``ahead`` past
+        it when that many ticks of the row are undrained (the mirror
+        lags the device's ``lens`` by one each); allocate the covering
+        block if the row hasn't got it yet."""
+        need = self._blocks_needed(int(self.seq_lens[slot_id]) + ahead + 1)
         return self._grow_blocks(slot_id, need)
 
     @staticmethod
@@ -2679,6 +2697,9 @@ class PagedEngine:
         ticks = snap.get("decode_steps", 0)
         snap["dispatches_per_tick"] = round(
             snap.get("dispatches", 0) / ticks, 4) if ticks else 0.0
+        # decode dispatches not yet drained: 1 between the steps of a
+        # decoding engine, 0 when it idles
+        snap["outstanding_dispatches"] = len(self._pending)
         dev = self.device or jax.devices()[0]
         snap.update(
             device={"platform": dev.platform, "kind": dev.device_kind},
@@ -2767,7 +2788,7 @@ class PagedEngine:
             "spec": {"enabled": bool(self._spec_k), "k": self._spec_k,
                      "ngram": self._spec_ngram if self._spec_k else 0},
             "ring": {"ring_len": self._ring_len,
-                     "outstanding": self._pending is not None,
+                     "outstanding": len(self._pending),
                      "drains": self.ring_drains,
                      "blocking_drains": self.ring_blocking_drains,
                      "scoped_drains": self.ring_scoped_drains,
@@ -2885,8 +2906,8 @@ class PagedEngine:
         self._dev = None
         self._dev_dirty = True
         self._dev_keys_dirty = False
-        self._delta_rows = set()
-        self._pending = None
+        self._delta_rows = {}
+        self._pending.clear()
         self._drained[:] = 0
         obs.record_event("paged_hard_reset",
                          engine=self._obs_labels["engine"])
@@ -2910,12 +2931,16 @@ class PagedEngine:
 
     @_on_device
     def step(self):
-        """One scheduler tick: drain the previous dispatch's ring slice
-        (its tokens land here, one step behind the device), expire
-        overdue requests, admit EVERY queued request that fits (slots +
-        blocks), advance one prefill chunk per prefilling slot, then
-        one decode for all prefill-complete slots (dispatched WITHOUT a
-        readback).
+        """One scheduler tick. With something to decide: drain every
+        outstanding dispatch's ring slice (its tokens land here, one
+        step behind the device), expire overdue requests, admit EVERY
+        queued request that fits (slots + blocks), advance one prefill
+        chunk per prefilling slot, then one decode for all
+        prefill-complete slots (dispatched WITHOUT a readback). With
+        nothing to decide (``_may_run_ahead``: a full house of decoding
+        rows) the same two halves in the other order: the next decode
+        is dispatched first, behind the tick still running, and that
+        tick is drained under it.
 
         With ``tick_profile`` on, the whole tick runs inside one
         profiler window: every bracketed phase of ``obs.TICK_PHASES``
@@ -2941,6 +2966,16 @@ class PagedEngine:
                     active=sum(1 for s in self.slots if s is not None))
 
     def _step_inner(self):
+        with self._phase("stage"):
+            ahead = self._may_run_ahead()
+        if ahead:
+            # tick N+1 queues behind tick N on the device; the wait for
+            # N, its drain and commit, and the caller's emit and next
+            # round of scheduling all run under N+1
+            self.decode_ticks += 1
+            self._decode_fused(list(range(self.R)))
+            self._drain_oldest()
+            return True
         self._drain_pending()
         with self._phase("expire") as br:
             self._expire()
@@ -2964,49 +2999,80 @@ class PagedEngine:
                             "raise num_blocks")
             active = [i for i, s in enumerate(self.slots)
                       if s is not None and s.tokens]
-            scan = False
-            if active and self._fused:
-                if self._spec_k:
-                    # speculative ticks ARE multi-token dispatches: they
-                    # replace the scan fusion (see __init__)
-                    self._spec_headroom(active)
-                else:
-                    scan = self._ticks_per_dispatch > 1 \
-                        and self._scan_ticks(active)
+            if active and self._spec_k:
+                self._spec_headroom(active)
         if not active:
             return
         self.decode_ticks += 1
         if not self._fused:
             return self._decode_host(active)
-        return self._decode_fused(active, scan=scan)
+        return self._decode_fused(active)
+
+    def _may_run_ahead(self) -> bool:
+        """True when this step has nothing to decide before the next
+        decode tick, read off the slots: ONE dispatch is outstanding
+        and it served every row; every slot holds a decoding request
+        (no free slot, none mid-prefill: an arrival could not be
+        admitted before a row finishes anyway) that the outstanding
+        tick leaves budget and whose deadline has not passed; no
+        transition is staged; the pool serves the blocks the next tick
+        writes (``_ensure_block`` one position past the undrained
+        tick's, the only transition such a step makes). Anything else
+        (a finished, cancelled or expired row, an admission, a chunk,
+        pool pressure and its preemption, a rebuild, a speculative
+        engine, whose acceptance mirror is read at the drain) takes the
+        drain-first order, so a row that finishes collapses the
+        pipeline for the steps that refill its slot and it fills again
+        by itself. An eos or a stop sequence is not foreseen: the next
+        tick then runs with that row already finished on the device
+        (it advances nothing) or still active (its token and K/V write
+        die with the release, the over-commit contract)."""
+        if len(self._pending) != 1 or self._spec_k or self._dev_dirty \
+                or any(self._delta_rows.values()) \
+                or len(self._pending[0]["rows"]) != self.R:
+            return False
+        now = time.monotonic()
+        for s in self.slots:
+            if s is None or not s.tokens \
+                    or s.prefill_pos < len(s.prompt) \
+                    or s.max_new - len(s.tokens) < 2 \
+                    or (s.deadline is not None and now > s.deadline):
+                return False
+        if any(r.deadline is not None and now > r.deadline
+               for r in self.queue):
+            return False
+        # a block a row got before the pool ran dry is the block its
+        # next drain-first tick asks for: falling back wastes nothing
+        return all(self._ensure_block(i, ahead=1) for i in range(self.R))
 
     def _drain_pending(self):
-        """Consume the outstanding dispatch: fetch the ring entries
-        committed since the last drain and run the host bookkeeping
-        the reference path does inline — token/logprob appends,
-        stop matching (a stop completing from a DRAINED token finishes
-        the request; tokens the device committed past it die with the
-        slot release), device finish flags, spec counters/EMA mirrors,
-        trace events. Called at the top of every step() and by every
-        out-of-band mutation path (cancel / close / submit-side
-        expiry), so slot transitions never run against a stale mirror.
-        No-op when nothing is outstanding.
+        """Consume EVERY outstanding dispatch, oldest first: the top of
+        a drain-first step() and every out-of-band path that touches
+        all slots (``close``), so slot transitions never run against a
+        stale mirror. No-op when nothing is outstanding."""
+        while self._pending:
+            self._drain_oldest()
 
-        The D2H here is the double-buffered read: the dispatch being
-        drained was issued one host iteration ago (dispatches N and
-        N+1 bracket it), so on hardware the transfer overlaps the
-        in-flight program and the wait is ~zero — instrumented via
+    def _drain_oldest(self):
+        """Consume the oldest outstanding dispatch: fetch the ring
+        entries it committed since the last drain and run the host
+        bookkeeping the reference path does inline — token/logprob
+        appends, stop matching (a stop completing from a DRAINED token
+        finishes the request; tokens the device committed past it die
+        with the slot release), device finish flags, spec counters/EMA
+        mirrors, trace events.
+
+        The D2H here is the double-buffered read: in a drain-first step
+        the dispatch was issued one host iteration ago and the wait is
+        what is left of its program; in a run-ahead step the next
+        program is already queued behind it, so the wait (phase
+        ``device``) and everything the host does until the step after
+        next run under that program — instrumented via
         ``ring_blocking_drains`` (drains whose arrays were not yet
         ready) against ``ring_drains`` (all of them)."""
-        p = self._pending
-        if p is None:
-            return
-        self._pending = None
-        st = self._dev
-        arrs = [st["ring"], st["rlps"], st["wcur"], st["active"]]
+        p = self._pending.popleft()
+        arrs = p["arrs"]
         spec = self._spec_k > 0
-        if spec:
-            arrs += [st["kprop_last"], st["macc_last"]]
         self.ring_drains += 1
         if not all(a.is_ready() for a in arrs):
             self.ring_blocking_drains += 1
@@ -3043,20 +3109,21 @@ class PagedEngine:
             kprop = macc = None
             if spec:
                 kprop, macc = vals[4], vals[5]
-                prop = int(kprop[p["rows"]].sum())
+                rows = list(p["rows"])
+                prop = int(kprop[rows].sum())
                 if prop:
                     self._count("spec_proposed", prop)
-                    acc = int(macc[p["rows"]].sum())
+                    acc = int(macc[rows].sum())
                     if acc:
                         self._count("spec_accepted", acc)
             lag = self.dispatch_count - p["seq"] + 1   # dispatches until drain
-            for i in p["rows"]:
+            for i, req in p["rows"].items():
                 self._commit_row_drain(
-                    i, ring[i], rlps[i], wcur[i], act_now[i],
+                    i, req, ring[i], rlps[i], wcur[i], act_now[i],
                     int(kprop[i]) if spec else 0,
                     int(macc[i]) if spec else 0, lag)
 
-    def _commit_row_drain(self, i, ring_i, rlps_i, wc, act_i,
+    def _commit_row_drain(self, i, req, ring_i, rlps_i, wc, act_i,
                           kp, ma, lag) -> bool:
         """Per-row host bookkeeping shared by the global drain's loop
         and the scoped drain (ISSUE 14) — one implementation so the
@@ -3065,18 +3132,21 @@ class PagedEngine:
         FIRST, so a stop completing on the final budgeted (or eos)
         token still records its trim length; tokens the device
         committed past a stop die with the slot release (the
-        scan/spec/ring over-commit contract) — emit the trace tick
+        spec/run-ahead over-commit contract) — emit the trace tick
         event, then finish on a host stop or the device finish flag
         (the tick -> engine_finish event order the reqtrace pins rely
-        on). ``ring_i``/``rlps_i`` are this row's ring slices;
-        ``kp``/``ma`` its spec counters (0 when spec is off). Returns
-        False for rows released out-of-band since dispatch (cursor
-        still advanced)."""
+        on). ``req`` is the request the dispatch served in this row;
+        ``ring_i``/``rlps_i`` are the row's ring slices; ``kp``/``ma``
+        its spec counters (0 when spec is off). Returns False for rows
+        released since dispatch, out of band or by the drain of an
+        older dispatch (cursor still advanced: what the device
+        committed for the row after its request ended is never read,
+        and the slot's next tenant starts at the device's cursor)."""
         slot = self.slots[i]
         base = int(self._drained[i])
         n_new = int(wc) - base
         self._drained[i] = int(wc)
-        if slot is None:        # released out-of-band since dispatch
+        if slot is not req:     # released since dispatch
             return False
         if self._spec_k:
             self._h_tpf.observe(n_new)
@@ -3115,23 +3185,21 @@ class PagedEngine:
 
     def _drain_row(self, i: int):
         """SCOPED ring drain (ISSUE 14): consume ONLY slot ``i``'s
-        pending entries from the outstanding dispatch. An out-of-band
-        transition (cancel, deadline expiry) synchronizes with the
-        in-flight program through this row's output slices alone — the
-        ``device_get`` still waits for the whole program, so releasing
-        the row's blocks afterwards can never race an in-flight write
-        — while the SIBLING rows' entries stay pending for the next
-        ``step()``'s normal drain: their mirrors are untouched, their
-        tokens survive. No-op when nothing is outstanding or the row
-        was not part of the dispatch."""
-        p = self._pending
-        if p is None or i not in p["rows"]:
-            return
-        st = self._dev
-        base_arrs = [st["ring"], st["rlps"], st["wcur"], st["active"]]
+        pending entries, from every outstanding dispatch that served
+        it, oldest first. An out-of-band transition (cancel, deadline
+        expiry) synchronizes with the in-flight programs through this
+        row's output slices alone — the ``device_get`` still waits for
+        the whole program, so releasing the row's blocks afterwards
+        can never race an in-flight write — while the SIBLING rows'
+        entries stay pending for the next ``step()``'s normal drain:
+        their mirrors are untouched, their tokens survive. No-op when
+        nothing is outstanding or the row was in no dispatch."""
+        for p in [p for p in self._pending if i in p["rows"]]:
+            self._drain_row_of(p, i)
+
+    def _drain_row_of(self, p, i: int):
+        base_arrs = p["arrs"]
         spec = self._spec_k > 0
-        if spec:
-            base_arrs += [st["kprop_last"], st["macc_last"]]
         # a scoped drain IS a ring drain: counting it in both keeps
         # the blocking/all ratio a profiler reads <= 1
         self.ring_drains += 1
@@ -3160,14 +3228,14 @@ class PagedEngine:
             self._h_decode.observe((time.perf_counter() - t0) * 1e3)
             br.switch("commit")
             ring_i, rlps_i, wc, act_i = vals[:4]
-            p["rows"].remove(i)
+            req = p["rows"].pop(i)
             if not p["rows"]:
-                self._pending = None
+                self._pending.remove(p)
             kp = ma = 0
             if spec:
                 kp, ma = int(vals[4]), int(vals[5])
             if self._commit_row_drain(
-                    i, ring_i, rlps_i, wc, act_i, kp, ma,
+                    i, req, ring_i, rlps_i, wc, act_i, kp, ma,
                     self.dispatch_count - p["seq"] + 1) and kp:
                 self._count("spec_proposed", kp)
                 if ma:
@@ -3256,20 +3324,18 @@ class PagedEngine:
                     self._finish(i)
         return True
 
-    def _decode_fused(self, active, scan: bool = False):
+    def _decode_fused(self, active):
         """The served tick's host half: ONE compiled dispatch advancing
         every active slot (staged transitions → attention → penalty →
         sampling → done flags → ring append, all device-state mutations
         inside the program) and NO readback: the committed tokens land
-        in the device ring and the next ``step()``'s drain consumes them
-        while this program runs. Host bookkeeping (appends, stops
-        inside a speculative window, finishes, spec counters and the
-        EMA mirror, traces) happens there, one step behind the device.
-        The program is the speculative tick under ``spec_tokens``, the
-        K-tick lax.scan with ``scan=True`` (caller proved eligibility
-        via _scan_ticks), else the plain tick; its token outputs are
-        not fetched."""
-        K = self._ticks_per_dispatch if scan else 1
+        in the device ring and a later drain consumes them (the next
+        ``step()``'s, or this one's own second half when it runs
+        ahead). Host bookkeeping (appends, stops inside a speculative
+        window, finishes, spec counters and the EMA mirror, traces)
+        happens there, one step behind the device. The program is the
+        speculative tick under ``spec_tokens``, else the plain tick;
+        its token outputs are not fetched."""
         with self._phase("stage") as br:
             self._sync_dev()
             self.dispatch_count += 1
@@ -3278,20 +3344,26 @@ class PagedEngine:
             if self._spec_k:
                 fn = self._tick_spec_greedy_jit if greedy \
                     else self._tick_spec_jit
-            elif scan:
-                fn = self._scan_greedy_jit if greedy else self._scan_jit
             else:
                 fn = self._tick_greedy_jit if greedy else self._tick_jit
             # dispatch = the program CALL (enqueue; asynchronous) —
             # compute lands in the drain boundary's device wait
             br.switch("dispatch")
-            *_, self.seen, self.pools, self._dev = fn(
+            *_, self.seen, self.pools, st = fn(
                 self.params, self.pools, self.seen, self._dev)
+        self._dev = st
         if not greedy:
             self._dev_keys_dirty = True
-        self._pending = dict(rows=list(active), seq=self.dispatch_count)
-        self._count("decode_steps", K)
-        self._count("slot_steps", self.R * K)
+        if self._pending:
+            self._count("runahead_ticks")
+        arrs = [st["ring"], st["rlps"], st["wcur"], st["active"]]
+        if self._spec_k:
+            arrs += [st["kprop_last"], st["macc_last"]]
+        self._pending.append(dict(
+            rows={i: self.slots[i] for i in active},
+            seq=self.dispatch_count, arrs=arrs))
+        self._count("decode_steps")
+        self._count("slot_steps", self.R)
         return True
 
     def _spec_headroom(self, active):
@@ -3316,56 +3388,6 @@ class PagedEngine:
                 self.M)
             if not self._grow_blocks(i, need, reserve=len(active)):
                 return
-
-    def _scan_ticks(self, active) -> bool:
-        """True when the next ``ticks_per_dispatch`` ticks may run inside
-        one compiled program with NO stream-observable difference from
-        K single ticks. Conservative by construction — any condition a
-        single tick would re-evaluate between tokens falls back to K=1:
-
-        - an empty queue (a scan must not delay an admission a
-          single-tick schedule would have made after token 1);
-        - every occupied slot decode-active (no mid-chunk prefill
-          interleaving, which runs between ticks);
-        - block headroom for each row's next min(K, remaining-budget)
-          writes, preallocated here. Preallocation failure falls back
-          to the single-tick path and its preemption logic rather than
-          preempting for speculative capacity.
-
-        Stop sequences and deadlines no longer disqualify (ISSUE 11
-        widening): eos/budget finishes are in-program flags, a stop
-        completing mid-scan finishes the request at the host loop and
-        the tokens the device committed past it die with the slot
-        release (the speculative tick's contract), and deadline expiry
-        was always a per-step() check — a K-tick program coarsens its
-        granularity exactly like a long prefill chunk does."""
-        K = self._ticks_per_dispatch
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            if i not in active:
-                return False          # occupied but not decode-active
-        if self.queue:
-            return False
-        # pre-check the WHOLE speculative demand against what
-        # _alloc_block could actually serve (free list + evictable
-        # parked blocks) BEFORE allocating anything: a partial grab that
-        # fails on a later row would leave earlier rows holding
-        # speculative blocks, and the single-tick fallback would then
-        # preempt under pressure this method itself created
-        needs = []
-        for i in active:
-            s = self.slots[i]
-            a = min(K, max(s.max_new - len(s.tokens), 1))
-            need = self._blocks_needed(int(self.seq_lens[i]) + a)
-            needs.append((i, need))
-        fresh = sum(max(n - len(self.slots[i].blocks), 0)
-                    for i, n in needs)
-        if fresh > len(self.free_blocks) + len(self.cached_free):
-            return False              # pressure: single-tick handles it
-        for i, need in needs:
-            self._grow_blocks(i, need)   # pre-checked: cannot fail
-        return True
 
     def run(self) -> Dict[Any, List[int]]:
         """Drive until queue and slots drain; returns request_id ->

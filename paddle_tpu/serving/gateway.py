@@ -298,10 +298,15 @@ class _ReplicaWorker(threading.Thread):
         """The tick loop. Every stretch of it lies in a named phase of
         the engine's tick profiler (``obs.LOOP_PHASES`` through
         ``engine.loop_phase``: ``sched``, ``lock``, ``emit``, ``idle``;
-        ``engine.step()`` accounts for itself), because with one
-        dispatch in flight at a time all of it is on the device's
-        critical path. With the profiler off a phase is a shared
-        no-op."""
+        ``engine.step()`` accounts for itself). Between two drain-first
+        steps one dispatch is in flight and all of it is on the
+        device's critical path: the chip idles from the end of tick N
+        until ``step()`` calls tick N+1. A full engine's ``step()``
+        dispatches tick N+1 before it drains tick N
+        (``PagedEngine._may_run_ahead``), so what this loop does with
+        N's tokens (``emit``, ``sched``) runs under N+1 and costs the
+        device nothing while the round stays shorter than a tick. With
+        the profiler off a phase is a shared no-op."""
         eng = self.engine
         phase = eng.loop_phase
         rname = self.replica.name

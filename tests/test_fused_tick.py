@@ -2,13 +2,13 @@
 
 Three contracts, each pinned against an independent reference:
 
-- STREAM PARITY: the fused tick (and the multi-tick scan) must emit
-  BIT-IDENTICAL token/logprob streams to the per-tick host path
+- STREAM PARITY: the fused tick (run ahead of its drain on a full
+  house, ISSUE 29, or drained first) must emit BIT-IDENTICAL
+  token/logprob streams to the per-tick host path
   (``fused_tick=False``), which test_paged.py pins against generate().
 - DISPATCH: a steady-state fused tick is exactly ONE compiled dispatch
-  with ZERO host->device mirror uploads; ``ticks_per_dispatch=K``
-  amortizes that one dispatch over K tokens when provably safe and
-  falls back to per-tick scheduling when not.
+  with ZERO host->device mirror uploads and one token a row, whether
+  the step drains first or dispatches first.
 - KERNEL PARITY: the ragged kernel (each row walks its own pages, a
   run of them per compute block) matches the dense whole-table gather
   across uneven ``seq_lens`` (single-token rows, block-boundary
@@ -17,8 +17,6 @@ Three contracts, each pinned against an independent reference:
   shape so the hardware lowering failure cannot regress silently on a
   CPU-only image.
 """
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,10 +170,13 @@ class TestFusedTickParity:
             assert [t for r, t in sr if r == rid] == \
                 [t for r, t in sh if r == rid]
 
-    def test_scan_ticks_bit_identical_with_fewer_dispatches(self, model):
-        """ticks_per_dispatch=4: same streams, ~K fewer dispatches. The
-        workload is scan-eligible (no stops/deadlines) only after the
-        queue drains, so admission still interleaves exactly."""
+    def test_run_ahead_full_house_bit_identical(self, model):
+        """ISSUE 29: three requests fill a three-slot engine, so once
+        all decode every step dispatches the next tick before it
+        drains the last (greedy and seeded sampled rows, budgets that
+        end on different ticks, block boundaries crossed under the
+        lag). Same streams as the host tick, still a dispatch a tick,
+        and the run-ahead engaged."""
         rs = np.random.RandomState(14)
         subs = [
             ("a", rs.randint(1, 200, (1, 4)), dict(max_new_tokens=25)),
@@ -183,32 +184,38 @@ class TestFusedTickParity:
              dict(max_new_tokens=21, temperature=0.8, seed=2)),
             ("c", rs.randint(1, 200, (1, 14)), dict(max_new_tokens=17)),
         ]
-        eng_h = _engine(model, fused_tick=False)
+        eng_h = _engine(model, max_slots=3, fused_tick=False)
         r_host, lp_host = _drain(eng_h, subs)
-        eng_s = _engine(model, ticks_per_dispatch=4)
-        r_scan, lp_scan = _drain(eng_s, subs)
-        assert r_host == r_scan
-        assert lp_host == lp_scan
-        assert eng_s.dispatch_count < eng_h.dispatch_count / 2
+        eng_s = _engine(model, max_slots=3)
+        r_ahead, lp_ahead = _drain(eng_s, subs)
+        assert r_host == r_ahead
+        assert lp_host == lp_ahead
+        # the house is full until "c" ends its budget: those ticks ran
+        # ahead, each foreseen budget end and the ticks after it did not
+        assert 10 <= eng_s.stats["runahead_ticks"] <= 15
+        assert eng_s.stats["decode_steps"] == eng_h.stats["decode_steps"]
 
-    def test_scan_runs_with_stop_rows_and_stays_exact(self, model):
-        """ISSUE 11 widening: stop sequences no longer disqualify the
-        K-tick scan — a stop completing mid-scan finishes the request
-        at the host loop (checked on every drained/committed token)
-        and the tokens the device committed past it die with the slot
-        release. The trimmed result stays exact AND the dispatches
-        actually amortize (the old behavior fell back to K=1)."""
+    def test_run_ahead_with_a_stop_row_stays_exact(self, model):
+        """A stop sequence is matched at the drain, when the next tick
+        already runs with the row active: that tick's token and K/V
+        write die with the slot release. The trimmed result is the
+        host tick's, and so is the stream of the row beside it."""
         rs = np.random.RandomState(15)
-        subs = [("x", rs.randint(1, 200, (1, 7)),
-                 dict(max_new_tokens=20, stop_sequences=[[9]]))]
-        r_host, lp_host = _drain(_engine(model, fused_tick=False), subs)
-        eng = _engine(model, ticks_per_dispatch=4)
-        r_scan, lp_scan = _drain(eng, subs)
-        assert r_host == r_scan and lp_host == lp_scan
-        # the scan ran: decode dispatches ~= tokens/K, not ~= tokens
-        n_dec = eng.stats["decode_steps"]
-        assert n_dec >= len(r_scan["x"]) - 1   # ticks counted per-K
-        assert eng.dispatch_count < len(r_scan["x"]) + 2
+        ids = rs.randint(1, 200, (1, 7))
+        other = ("y", rs.randint(1, 200, (1, 5)), dict(max_new_tokens=20))
+        free, _ = _drain(_engine(model, max_slots=2, fused_tick=False),
+                         [("x", ids, dict(max_new_tokens=20)), other])
+        stop = [free["x"][8:10]]        # matched ten tokens in
+        subs = [("x", ids, dict(max_new_tokens=20, stop_sequences=stop)),
+                other]
+        r_host, lp_host = _drain(
+            _engine(model, max_slots=2, fused_tick=False), subs)
+        eng = _engine(model, max_slots=2)
+        r_ahead, lp_ahead = _drain(eng, subs)
+        assert r_host == r_ahead and lp_host == lp_ahead
+        assert len(r_ahead["x"]) <= 8 and len(r_ahead["y"]) == 20
+        assert eng.stats["runahead_ticks"] >= 6
+        assert len(eng.free_blocks) == eng.P - 1
 
 
 # --------------------------------------------------------- dispatch contract
@@ -248,59 +255,27 @@ class TestDispatchContract:
         assert host.h2d_upload_bytes - b0 >= \
             host.block_tables.nbytes + host.seq_lens.nbytes
 
-    def test_scan_amortizes_dispatches(self):
-        """K=8: one dispatch advances all slots 8 tokens."""
-        eng = _stub_engine(ticks_per_dispatch=8)
+    def test_run_ahead_keeps_a_dispatch_and_a_token_a_tick(self):
+        """A full house runs ahead: each step is still one dispatch and
+        hands the host one token a row (the tick before the one it
+        dispatched), one dispatch stays outstanding between steps, and
+        ``runahead_ticks`` counts those steps."""
+        eng = _stub_engine()
         for i in range(8):
             eng.submit(f"r{i}", np.arange(1, 9)[None],
                        max_new_tokens=200)
         for _ in range(4):
             eng.step()
-        d0 = eng.dispatch_count
+        d0, a0 = eng.dispatch_count, eng.stats["runahead_ticks"]
         tok0 = sum(len(s.tokens) for s in eng.slots if s is not None)
         for _ in range(5):
             eng.step()
+            assert len(eng._pending) == 1
         toks = sum(len(s.tokens) for s in eng.slots
                    if s is not None) - tok0
         assert eng.dispatch_count - d0 == 5
-        assert toks == 5 * 8 * 8        # 5 dispatches x K=8 x 8 rows
-
-    @pytest.mark.slow
-    def test_microbench_scan_5x_over_host_tick(self):
-        """ISSUE 6 acceptance: the device-resident scan tick >= 5x the
-        pre-fusion host tick per token on CPU (median of 3 windows;
-        the stub model isolates tick machinery from model compute).
-        Wall-clock-bound -> slow tier; the dispatch-count contracts
-        above are the tier-1 regression guards."""
-        R = 16
-
-        def per_token_ms(**kw):
-            # small pool so the stub's whole-table gather is cheap and
-            # the measurement is DISPATCH-dominated (the quantity under
-            # test); min-of-3 windows since container noise only ever
-            # adds time
-            K = max(1, kw.get("ticks_per_dispatch", 1))
-            eng = _stub_engine(R=R, num_blocks=64, block_size=32, **kw)
-            for i in range(R):
-                eng.submit(f"r{i}", np.arange(1, 9)[None],
-                           max_new_tokens=230)
-            for _ in range(20 // K + 4):
-                eng.step()
-            n = max(1, 48 // K)
-            vals = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                for _ in range(n):
-                    eng.step()
-                vals.append((time.perf_counter() - t0)
-                            / (n * K * R) * 1e3)
-            return min(vals)
-
-        host = per_token_ms(fused_tick=False)
-        scan = per_token_ms(ticks_per_dispatch=16)
-        assert host / scan >= 5.0, \
-            f"host {host:.4f} ms/tok vs scan16 {scan:.4f} ms/tok " \
-            f"= {host / scan:.1f}x (need >= 5x)"
+        assert eng.stats["runahead_ticks"] - a0 == 5
+        assert toks == 5 * 8            # 5 dispatches x 8 rows
 
 
 # ------------------------------------------------------- ragged kernel parity

@@ -122,14 +122,18 @@ def test_prefill_then_decode_agrees_with_the_full_forward(model, chunk):
         "moe_experts_hit"]
 
 
-def test_ticks_scanned_in_one_dispatch_count_their_layers_too(model):
-    """``ticks_per_dispatch`` > 1 runs the same tick core inside a scan:
-    the counters ride the ring in its carry, one addition a tick."""
-    eng = engine(model, ticks_per_dispatch=2)
+def test_ticks_run_ahead_of_their_drain_count_their_layers_too(model):
+    """Four rows fill the four slots, so each tick is dispatched with
+    its predecessor undrained (ISSUE 29), over the latent pool as over
+    K/V pools: rows of 9 + 12 tokens cross a block boundary under the
+    lag, and the experts' counters, cumulative in the ring's spare row
+    and differenced at each drain, lose and double nothing."""
+    eng = engine(model)
     ps = prompts(4, (9, 9, 9, 9))
-    toks, lps = serve(eng, ps, n=8)
+    toks, lps = serve(eng, ps, n=12)
     assert_greedy(model, ps, toks, lps)
-    assert eng.decode_ticks < eng.stats["decode_steps"]     # scans ran
+    assert eng.stats["runahead_ticks"] >= 8
+    assert eng.stats["decode_steps"] == 11 and not eng._pending
     assert eng.stats["moe_layer_ticks"] == 2 * eng.stats["decode_steps"]
 
 

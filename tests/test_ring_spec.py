@@ -8,14 +8,15 @@ Contracts, each against an independent reference:
   identical to the host tick's (``fused_tick=False``, the engine's one
   reference, which reads every token back in its tick) — across ring
   wrap-around (streams longer than the ring), stops completing from a
-  DRAINED (not live-read) token, scan/spec composition,
+  DRAINED (not live-read) token, run-ahead/spec composition,
   cancel/preempt racing an in-flight dispatch with undrained entries,
   and the rebuild that zeroes the ring's cursors before int32 ends.
 - READBACK AMORTIZATION: steady decode issues one dispatch a tick and
   uploads nothing, every dispatch is drained exactly once, a drain
   counts as a blocking readback only when it had to wait (whether it
   does is read on the chip: ``tick_host_share.*``,
-  ``device_idle_share.*``), and ring+scan drains once per K ticks.
+  ``device_idle_share.*``), and a step that runs ahead still drains
+  one dispatch.
 - REJECTION SAMPLING: ``sampling.residual_resample_rows`` preserves
   the per-position distribution exactly (unit: empirical marginal ==
   filtered softmax, whatever the draft), sampled rows ride speculative
@@ -47,10 +48,9 @@ def _engine(period=7, **kw):
 
 
 def _reference(**kw):
-    """The host tick: the one reference. It neither scans nor
-    speculates; greedy streams do not depend on either."""
-    for k in ("ticks_per_dispatch", "spec_tokens"):
-        kw.pop(k, None)
+    """The host tick: the one reference. It does not speculate;
+    greedy streams do not depend on it."""
+    kw.pop("spec_tokens", None)
     return _engine(fused_tick=False, **kw)
 
 
@@ -132,27 +132,38 @@ class TestRingParity:
         assert r_sync == r_ring and lp_sync == lp_ring
         assert tuple(r_ring["s"][-2:]) != (3, 4)
 
-    def test_ring_composes_with_scan_and_spec(self):
-        """ring + ticks_per_dispatch and ring + spec_tokens: one drain
-        consumes the whole multi-token dispatch; streams stay exact."""
+    @pytest.mark.parametrize("kw, ahead", [
+        (dict(), True), (dict(spec_tokens=4), False)],
+        ids=["run-ahead", "spec"])
+    def test_ring_composes_with_run_ahead_and_spec(self, kw, ahead):
+        """The four requests fill the four slots. ring + run-ahead: a
+        slice is drained while the next tick, dispatched before it,
+        runs. ring + spec_tokens: one drain consumes the whole
+        multi-token dispatch, and a speculative engine keeps the
+        drain-first order (its acceptance mirror is read at the
+        drain). Streams stay exact."""
         r_sync, lp_sync = _drain(_reference(), GREEDY_SUBS)
-        for kw in (dict(ticks_per_dispatch=4), dict(spec_tokens=4)):
-            r, lp = _drain(_engine(**kw), GREEDY_SUBS)
-            assert r == r_sync and lp == lp_sync, kw
+        eng = _engine(**kw)
+        r, lp = _drain(eng, GREEDY_SUBS)
+        assert r == r_sync and lp == lp_sync
+        assert (eng.stats["runahead_ticks"] > 0) == ahead
 
-    def test_scan_with_stops_widened_eligibility(self):
-        """ISSUE 11 widening: stop/deadline rows no longer force the
-        K=1 fallback — the scan runs and amortizes dispatches while
-        the stream (trim included) stays exact."""
+    def test_stop_and_deadline_rows_run_ahead(self):
+        """A row with stop sequences and a deadline that is not due
+        does not hold the engine to the drain-first order: the
+        run-ahead engages on the one-slot full house while the stream
+        (trim included) stays exact."""
         subs = [("s", _cyc(7), dict(max_new_tokens=24,
                                     stop_sequences=[[3, 4]],
                                     timeout_s=60.0))]
-        r_sync, lp_sync = _drain(_reference(), subs)
-        eng = _engine(ticks_per_dispatch=4)
+        r_sync, lp_sync = _drain(_reference(max_slots=1), subs)
+        eng = _engine(max_slots=1)
         r, lp = _drain(eng, subs)
         assert r == r_sync and lp == lp_sync
-        # fewer dispatches than tokens: the scan actually ran
-        assert eng.dispatch_count < len(r["s"]) + 2
+        # every decode tick after the first was dispatched with its
+        # predecessor undrained, the one past the stop included
+        assert eng.stats["runahead_ticks"] \
+            == eng.stats["decode_steps"] - 1 > 0
 
     def test_cancel_races_inflight_dispatch(self):
         """cancel() landing between steps — an undrained dispatch in
@@ -167,10 +178,11 @@ class TestRingParity:
         eng.submit("kill", _cyc(9, start=3), max_new_tokens=20)
         for _ in range(4):
             eng.step()
-        assert eng._pending is not None      # dispatch in flight
+        assert len(eng._pending) == 1        # dispatch in flight
         assert eng.cancel("kill")
         # scoped: the survivor's entries are still outstanding
-        assert eng._pending is not None
+        assert list(eng._pending[0]["rows"].values()) \
+            == [s for s in eng.slots if s is not None]
         assert eng.ring_scoped_drains == 1
         assert eng.cancelled["kill"] == "cancelled"
         res = eng.run()
@@ -234,20 +246,28 @@ class TestReadbackAmortization:
         assert eng.ring_drains - r0 >= 20
         assert eng.d2h_syncs == eng.ring_blocking_drains
 
-    def test_scan_ring_one_drain_per_k_ticks(self):
-        """ring + ticks_per_dispatch=K: one drain per K ticks — the
-        '<= 1 blocking D2H per K ticks' acceptance row."""
-        eng = _engine(ticks_per_dispatch=4)
+    def test_run_ahead_one_drain_per_tick(self):
+        """The pins above restated for the order of a full house
+        (dispatch first, drain after): over N run-ahead steps N
+        dispatches, N drains (each dispatch drained exactly once, one
+        left outstanding), no upload, and the only blocking readbacks
+        counted are drains that had to wait."""
+        # block_size=64: no block boundary, so no growth patch uploads
+        eng = _engine(block_size=64, max_blocks_per_seq=2)
         for i in range(4):
             eng.submit(f"r{i}", _cyc(6), max_new_tokens=100)
         for _ in range(4):
             eng.step()
         d0, r0 = eng.stats["decode_steps"], eng.ring_drains
+        a0, u0 = eng.stats["runahead_ticks"], eng.h2d_uploads
         for _ in range(10):
             eng.step()
-        ticks = eng.stats["decode_steps"] - d0
-        drains = eng.ring_drains - r0
-        assert ticks == 40 and drains == 10  # 1 drain per K=4 ticks
+        assert eng.stats["decode_steps"] - d0 == 10
+        assert eng.stats["runahead_ticks"] - a0 == 10
+        assert eng.ring_drains - r0 == 10
+        assert eng.h2d_uploads - u0 == 0
+        assert len(eng._pending) == 1
+        assert eng.d2h_syncs == eng.ring_blocking_drains
 
 
 # ------------------------------------------------- rejection sampling unit

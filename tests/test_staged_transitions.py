@@ -239,13 +239,14 @@ class TestDeltaParity:
 
     @pytest.mark.parametrize("name", [
         "ring_mode", "ring_len", "delta_transitions", "patch_fuse",
-        "patch_queue_len"])
+        "patch_queue_len", "ticks_per_dispatch"])
     def test_the_mode_switches_are_gone(self, name):
         """The served path has no variants: the constructor has none of
-        the five options PR 28 deleted, and refuses each by name."""
+        the five options PR 28 deleted nor the scan PR 29 replaced, and
+        refuses each by name."""
         params = inspect.signature(PagedEngine.__init__).parameters
         assert name not in params
-        assert len(params) == 18        # self, the model, 16 options
+        assert len(params) == 17        # self, the model, 15 options
         with pytest.raises(TypeError, match=name):
             _engine(**{name: None})
 
@@ -260,13 +261,13 @@ class TestScopedDrain:
         eng.submit("kill", _cyc(9, 3), max_new_tokens=20)
         for _ in range(4):
             eng.step()
-        assert eng._pending is not None
+        assert len(eng._pending) == 1
         keep_slot = next(s for s in eng.slots
                          if s is not None and s.request_id == "keep")
         n_keep = len(keep_slot.tokens)
         assert eng.cancel("kill")
         # the survivor's entries were NOT consumed by the cancel
-        assert eng._pending is not None
+        assert len(eng._pending) == 1
         assert len(keep_slot.tokens) == n_keep
         assert eng.ring_scoped_drains == 1
         res = eng.run()
@@ -284,7 +285,7 @@ class TestScopedDrain:
         eng.submit("kill", _cyc(9, 3), max_new_tokens=20)
         for _ in range(4):
             eng.step()
-        assert eng._pending is not None
+        assert len(eng._pending) == 1
         assert eng.cancel("kill")
         assert eng.ring_scoped_drains == 1
         res = eng.run()
@@ -303,7 +304,7 @@ class TestScopedDrain:
         eng.submit("doomed", _cyc(7, 2), max_new_tokens=50)
         for _ in range(4):
             eng.step()
-        assert eng._pending is not None
+        assert len(eng._pending) == 1
         doomed = next(s for s in eng.slots
                       if s is not None and s.request_id == "doomed")
         doomed.deadline = 0.0      # already past on the monotonic clock
@@ -313,7 +314,7 @@ class TestScopedDrain:
         eng.submit("late", _cyc(4), max_new_tokens=4)
         assert eng.cancelled.get("doomed") == "timeout"
         assert eng.ring_scoped_drains == sc0 + 1
-        assert eng._pending is not None
+        assert len(eng._pending) == 1
         res = eng.run()
         assert eng.cancelled.get("doomed") == "timeout"
         ref = _reference()

@@ -17,8 +17,8 @@ Contracts pinned here:
   gateway's worker reports all four.
 - BITWISE OFF==ON: profile-on greedy+sampled streams are bit-identical
   to profile-off across the engine's paths (the served fused tick,
-  its speculative and multi-tick dispatches, chunked prefill, and the
-  unfused host reference) — the profiler reads
+  its speculative dispatches, its run-ahead across block growth,
+  chunked prefill, and the unfused host reference) — the profiler reads
   clocks and calls ``block_until_ready`` on arrays the next statement
   would block on anyway; it never changes what the device computes.
 - STEADY CONTRACT UNTOUCHED: with the profiler ON, steady decode
@@ -254,9 +254,9 @@ def test_real_clock_sum_within_validator_tolerance():
     {},                                # the served fused tick
     {"spec_tokens": 2},                # speculative tick
     {"fused_tick": False},             # the host reference
-    {"ticks_per_dispatch": 4},         # multi-tick dispatch
+    {"block_size": 4, "max_blocks_per_seq": 16},   # growth under lag
     {"chunk_prefill_tokens": 8},       # chunked prefill (ISSUE 24)
-], ids=["fused-ring", "spec", "unfused", "multi-tick", "chunked"])
+], ids=["fused-ring", "spec", "unfused", "run-ahead-growth", "chunked"])
 def test_profile_on_off_bitwise(mode_kw):
     res_off, lp_off = _drain(_engine(**mode_kw))
     res_on, lp_on = _drain(_engine(tick_profile=True, **mode_kw))

@@ -88,9 +88,9 @@ def audit_durations(lines):
 # of the tier-1 run. Keep in sync with tests/conftest.py's _SLOW list
 # and per-test @pytest.mark.slow decorations.
 MUST_BE_SLOW = (
-    # ISSUE 6: wall-clock micro-bench + sweep matrices + the 14s
-    # full-batch interpret parity (each keeps a tier-1 representative)
-    r"test_fused_tick\.py.*microbench",
+    # ISSUE 6: sweep matrices + the 14s full-batch interpret parity
+    # (each keeps a tier-1 representative; the scan's wall-clock
+    # micro-bench went with the scan, PR 29)
     r"test_fused_tick\.py.*parity_sweep",
     r"test_fused_tick\.py.*full_batch",
     # ISSUE 7: spec k/ngram + multi-query kernel sweeps and the
