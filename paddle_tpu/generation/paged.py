@@ -710,9 +710,12 @@ class PagedEngine:
     takes a ``PagedKV``: the Llama family (a K and a V pool, kv heads x
     head_dim a token) and the DeepSeek-V2/V3 family (one latent pool,
     ``kv_lora_rank + qk_rope_head_dim`` columns a token padded to whole
-    lanes). The model says what a cached row is (``paged_cache_rows``);
+    lanes). The model says what a cached row is (``paged_cache_rows``)
+    and, where a layer has more than one attention, how many of them a
+    token has (``paged_cache_layers``: LongCat-Flash's two latent
+    attentions a layer are two cache layers);
     allocation, writes, prefix adoption, spill, upload and reset are one
-    code path over a layer's tuple of pool arrays.
+    code path over a cache layer's tuple of pool arrays.
 
     submit() enqueues requests at any time; each step() admits what
     fits (slot + blocks), prefills at most one queued request, and
@@ -1076,9 +1079,18 @@ class PagedEngine:
         cfg = self.model.config
         return ((cfg.num_key_value_heads, cfg.head_dim),) * 2
 
+    def _cache_layers(self) -> int:
+        """How many cached rows a token has through the model, asked of
+        it too (``paged_cache_layers``): one per attention sublayer,
+        which is one per layer unless the model says otherwise (a layer
+        with two attentions presents two)."""
+        ask = getattr(self.model, "paged_cache_layers", None)
+        return ask() if ask is not None \
+            else self.model.config.num_hidden_layers
+
     def _fresh_device_arrays(self):
-        """New pools (per layer one [P, B, heads*width] array for each
-        entry of the model's cached row: a K/V pair, or one latent
+        """New pools (per cache layer one [P, B, heads*width] array for
+        each entry of the model's cached row: a K/V pair, or one latent
         array; the page-slab form the kernels fetch, so that no program
         ever copies a pool) and the per-row seen-token masks for the
         repetition penalty (seeded by the prefill scatter, updated inside the
@@ -1088,7 +1100,7 @@ class PagedEngine:
         pools = [tuple(self._zeros((self.P, self.B, heads * width),
                                    cfg.dtype)
                        for heads, width in self._cache_rows())
-                 for _ in range(cfg.num_hidden_layers)]
+                 for _ in range(self._cache_layers())]
         return pools, self._zeros((self.R, cfg.vocab_size), bool)
 
     def decode_route(self) -> str:

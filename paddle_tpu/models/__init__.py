@@ -12,6 +12,8 @@ from .qwen2 import (Qwen2Config, Qwen2ForCausalLM, Qwen2Model, qwen2_7b,
                     qwen2_tiny)
 from .deepseek_v2 import (DeepseekV2Config, DeepseekV2ForCausalLM,
                           DeepseekV2Model, deepseek_v2_tiny)
+from .longcat_flash import (LongcatFlashConfig, LongcatFlashForCausalLM,
+                            LongcatFlashModel, longcat_flash_tiny)
 from .qwen2_moe import (DeepseekMoeConfig, DeepseekMoeForCausalLM,
                         Qwen2MoeConfig, Qwen2MoeForCausalLM, Qwen2MoeModel,
                         deepseek_moe_tiny, moe_lm_loss, qwen2_moe_tiny)

@@ -54,6 +54,10 @@ class DeepseekV2Config(LlamaConfig):
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # LongCat-Flash: the query after q_b times sqrt(hidden / q_lora_rank),
+    # the normed latent before kv_b times sqrt(hidden / kv_lora_rank)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # ---- MoE (DeepSeek fine-grained + shared)
     num_experts: int = 64                  # n_routed_experts
     num_experts_per_tok: int = 6
@@ -188,6 +192,8 @@ class MLAttention(Layer):
         else:
             q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
         q = q.reshape(b, s, h, cfg.qk_head_dim)
+        if cfg.mla_scale_q_lora:        # nope and rope parts alike
+            q = q * (cfg.hidden_size / cfg.q_lora_rank) ** 0.5
         q_nope = q[..., :cfg.qk_nope_head_dim]
         q_pe = rope_interleaved(q[..., cfg.qk_nope_head_dim:], positions,
                                 cfg.rope_theta, self._inv_freq,
@@ -201,6 +207,11 @@ class MLAttention(Layer):
         c, k_pe = (ckv[..., :cfg.kv_lora_rank],
                    ckv[..., cfg.kv_lora_rank:])
         c = self.kv_a_layernorm(c)
+        if cfg.mla_scale_kv_lora:
+            # the latent is cached scaled: keys' nope part and values
+            # carry the factor through kv_b (expanded) and through the
+            # absorbed products alike; the roped key does not
+            c = c * (cfg.hidden_size / cfg.kv_lora_rank) ** 0.5
         k_pe = rope_interleaved(k_pe[:, :, None, :], positions,
                                 cfg.rope_theta, self._inv_freq,
                                 self._rope_af)[:, :, 0]

@@ -222,6 +222,10 @@ class MoEMLP(Layer):
 # what an ExpertShareMLP counts inside a serving tick, in this order
 SERVING_COUNTERS = ("moe_layer_ticks", "moe_local_assignments",
                     "moe_experts_hit")
+# and, after those, where the router has zero-compute columns: the live
+# rows' choices (rows x top_k, a layer and tick) and those of them that
+# fell on a zero column
+ZERO_COUNTERS = ("moe_live_choices", "moe_zero_choices")
 _collecting = threading.local()     # engines trace on threads of their own
 
 
@@ -258,7 +262,14 @@ class ExpertShareMLP(Layer):
     gated as the whole layer gates them (normalised over all ``top_k``
     chosen, held or not). What the absent experts add is left out:
     summed over the ranks' ``routed`` parts, plus ``shared_out`` once,
-    it is the whole layer (tests/test_expert_share.py)."""
+    it is the whole layer (tests/test_expert_share.py).
+
+    ``zero_experts`` more router columns, after the experts', are
+    LongCat-Flash's zero-compute experts of the identity kind: a choice
+    that falls on one adds ``gate * x`` and reads no weight. In a
+    deployment the token's own rank computes that part, so every rank
+    computes ``zero_out`` alike and, like ``shared_out``, it counts
+    once in the sum over shares."""
 
     def __init__(self, hidden_size: int, intermediate_size: int,
                  num_experts: int, top_k: int, first_expert: int,
@@ -268,7 +279,8 @@ class ExpertShareMLP(Layer):
                  routed_scaling_factor: float = 1.0,
                  n_group: int = 1, topk_group: int = 1,
                  scoring: str = "softmax",
-                 group_score_mode: str = "max", name=None):
+                 group_score_mode: str = "max", zero_experts: int = 0,
+                 name=None):
         super().__init__(name)
         if not 0 <= first_expert <= num_experts - experts_held:
             raise ValueError(
@@ -281,8 +293,9 @@ class ExpertShareMLP(Layer):
         self.routed_scaling_factor = routed_scaling_factor
         self.n_group, self.topk_group = n_group, topk_group
         self.scoring, self.group_score_mode = scoring, group_score_mode
-        E, n, h, m = num_experts, experts_held, hidden_size, \
-            intermediate_size
+        self.zero_experts = zero_experts
+        E, n, h, m = num_experts + zero_experts, experts_held, \
+            hidden_size, intermediate_size
         init = I.XavierNormal()
         self.gate = Parameter(init(next_key(), (h, E)))
         self.w_gate = Parameter(init(next_key(), (n, h, m)))
@@ -327,9 +340,15 @@ class ExpertShareMLP(Layer):
         if box is not None:
             live = jnp.repeat(box.rows, xt.shape[0] // box.rows.shape[0])
             chose = chose & live[:, None, None]
-            box.add(jnp.stack([
+            counts = [
                 jnp.int32(1), jnp.sum(chose, dtype=jnp.int32),
-                jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32)]))
+                jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32)]
+            if self.zero_experts:       # ZERO_COUNTERS
+                counts += [
+                    jnp.sum(live, dtype=jnp.int32) * self.top_k,
+                    jnp.sum((ids >= self.num_experts) & live[:, None],
+                            dtype=jnp.int32)]
+            box.add(jnp.stack(counts))
         g = jnp.einsum("th,nhm->ntm", xt, self.w_gate)
         u = jnp.einsum("th,nhm->ntm", xt, self.w_up)
         a = (F.silu(g) * u).astype(jnp.float32) * w.T[:, :, None]
@@ -339,6 +358,12 @@ class ExpertShareMLP(Layer):
         sg = F.silu(xt @ self.shared_gate_proj) * (xt @ self.shared_up_proj)
         return sg @ self.shared_down_proj
 
+    def zero_out(self, xt, ids, gates):
+        """The identity experts' part: each token times the sum of its
+        gates that fell on a zero column."""
+        w = jnp.sum(jnp.where(ids >= self.num_experts, gates, 0.0), axis=-1)
+        return (xt.astype(jnp.float32) * w[:, None]).astype(xt.dtype)
+
     def forward(self, x):
         # the scopes are obs.TICK_SCOPES
         xt = x.reshape(-1, self.hidden_size)
@@ -346,6 +371,9 @@ class ExpertShareMLP(Layer):
             ids, gates = self.route(xt)
         with jax.named_scope("experts"):
             y = self.routed(xt, ids, gates)
+        if self.zero_experts:
+            with jax.named_scope("zero_experts"):
+                y = y + self.zero_out(xt, ids, gates)
         if self.shared:
             with jax.named_scope("shared_expert"):
                 y = y + self.shared_out(xt)

@@ -946,7 +946,9 @@ LOOP_PHASES = (
 # TICK_SCOPES: the ``jax.named_scope`` names inside the tick and chunk
 # programs (``_fused_tick*``, ``_chunk_prefill``) — what the device
 # runs inside a tick. An op's scope is the last component of its
-# ``op_name`` that is one of these.
+# ``op_name`` that is one of these. (A LongCat-Flash layer has two
+# attentions and two dense FFNs: its norm / qkv / absorb / kv_write /
+# attn / o_proj / mlp scopes occur twice a layer.)
 TICK_SCOPES = (
     "patch",       # staged slot transitions scattered into the state
     "embed",
@@ -963,6 +965,8 @@ TICK_SCOPES = (
     "mlp",         # a dense FFN; of an expert layer the residual add
     "router",      # expert layer: float32 scores, groups, top-k, gates
     "experts",     # expert layer: the held routed experts
+    "zero_experts",    # expert layer: the identity part of the choices
+                       # that fell on zero-compute columns (LongCat-Flash)
     "shared_expert",
     "head",        # final norm and lm_head
     "penalty",     # repetition penalty

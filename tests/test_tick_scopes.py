@@ -33,8 +33,9 @@ from paddle_tpu.utils import observability as obs
 
 CHUNK = 16
 ATTN = {"attn"}             # the decode side; a chunk has its own
-# the parts only an expert layer and latent attention have (ISSUE 26)
-MOE_MLA = {"router", "experts", "shared_expert", "absorb"}
+# the parts only an expert layer and latent attention have (ISSUE 26),
+# and the identity part of a router wider than its experts (ISSUE 30)
+MOE_MLA = {"router", "experts", "shared_expert", "absorb", "zero_experts"}
 LLAMA = set(obs.TICK_SCOPES) - MOE_MLA
 PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
@@ -44,9 +45,14 @@ PROGRAMS = {
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
 DEEPSEEK = {
-    "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb"},
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn",
+                                                  "zero_experts"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb",
+                                                     "zero_experts"},
 }
+# LongCat-Flash's double layer: zero-compute experts, no shared expert
+LONGCAT = {k: v - {"shared_expert"} | {"zero_experts"}
+           for k, v in DEEPSEEK.items()}
 
 
 @pytest.fixture(scope="module")
@@ -108,9 +114,33 @@ def test_an_expert_and_latent_model_carries_its_scopes(deepseek_engine,
     assert scopes[None] < 0.1 * sum(scopes.values()), scopes
 
 
+@pytest.fixture(scope="module")
+def longcat_engine():
+    from paddle_tpu.models.longcat_flash import (LongcatFlashForCausalLM,
+                                                 longcat_flash_tiny)
+    cfg = longcat_flash_tiny(num_hidden_layers=1, experts_held=4)
+    eng = PagedEngine(LongcatFlashForCausalLM(cfg), max_slots=4,
+                      num_blocks=32, block_size=8, max_blocks_per_seq=8,
+                      chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(LONGCAT))
+def test_a_double_layer_carries_its_scopes_and_two_kernels(longcat_engine,
+                                                           kernels, program):
+    assert longcat_engine.decode_route() == "ragged"
+    _, scopes = _lowered(longcat_engine, program)
+    assert set(scopes) - {None} == LONGCAT[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+    if program != "_chunk_prefill":     # one latent kernel an attention
+        names = _kernel_names(_trace(longcat_engine, program).jaxpr.jaxpr)
+        assert names == ["ragged_paged_attention"] * 2
+
+
 def test_the_programs_use_the_whole_vocabulary():
-    assert set().union(*PROGRAMS.values(), *DEEPSEEK.values()) \
-        == set(obs.TICK_SCOPES)
+    assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
+                       *LONGCAT.values()) == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES
                                           + obs.LOOP_PHASES)
