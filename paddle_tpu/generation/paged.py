@@ -153,6 +153,12 @@ _RING_CURSOR_LIMIT = 2 ** 30
 _DESC_KEY_OVERRIDE = 1
 _DESC_TABLE_ONLY = 2
 
+# a packed prefill call's words a segment, behind its block-table row
+# (``_pack_call``): length, slot, the call's index of its last live
+# position, last-chunk flag, top_k; temperature, top_p, repetition
+# penalty as raw bits; the PRNG key
+_SEG_WORDS = 10
+
 
 def _home_device(params):
     """Where an engine over ``params`` lives: the one device every
@@ -280,19 +286,29 @@ def paged_decode_write(pk: PagedKV, k, v=None):
         return pk.scatter(bidx, boff, k, v)
 
 
+# The prefill write and the two chunk attentions below are jitted and
+# inlined, as the decode kernel's wrapper is
+# (``ragged_paged_attention._attend``): a program of L layers traces
+# each once, not L times (set-up pays tracing on every start), and
+# lowers to what it lowered to without
+@functools.partial(jax.jit, inline=True, static_argnames=("garbage_block",))
 def paged_prefill_write(pk: PagedKV, k, v=None, positions=None,
-                        garbage_block: int = 0):
+                        segments=None, garbage_block: int = 0):
     """Scatter a [1, s, kvh, d] prompt's (or prompt chunk's) K/V into
     row 0's blocks; pad positions (>= seq_lens[0]) go to the garbage
     block. ``positions`` [s] are the tokens' GLOBAL positions (default
     0..s-1 — the whole-prompt case); a chunk passes start..start+s-1
-    and seq_lens[0] = start + live-chunk-length."""
+    and seq_lens[0] = start + live-chunk-length. ``segments`` [s]
+    (a PACKED call: several prompts side by side, each from its
+    position 0) names every token's row of the table in place of row
+    0; its pads ride behind the last prompt, past that row's length."""
     B = pk.block_size
     s = k.shape[1]
     with jax.named_scope("kv_write"):       # obs.TICK_SCOPES
         pos = positions if positions is not None else jnp.arange(s)
-        live = pos < pk.seq_lens[0]
-        bidx = jnp.where(live, pk.block_tables[0, pos // B],
+        row = segments if segments is not None else 0
+        live = pos < pk.seq_lens[row]
+        bidx = jnp.where(live, pk.block_tables[row, pos // B],
                          garbage_block)
         boff = pos % B
         return pk.scatter(bidx, boff, k, v, sel=0)
@@ -307,6 +323,7 @@ def paged_chunk_rows(pk: PagedKV, pool=None):
     return pk.split(rows.reshape(1, -1, rows.shape[-1]))
 
 
+@functools.partial(jax.jit, inline=True, static_argnames=("window",))
 def paged_chunk_attention(q, pk: PagedKV, positions,
                           window: Optional[int] = None):
     """Chunked-prefill attention: q [1, s, h, d] chunk queries at global
@@ -326,6 +343,24 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
         if window is not None:
             keep &= qpos - kpos < window
         return dense_attention(q, ks, vs, attn_mask=keep[None, None])
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("window",))
+def paged_packed_attention(q, k, v, segment_ids,
+                           window: Optional[int] = None):
+    """Attention of a PACKED prefill call: q/k/v [1, s, h, d] are the
+    call's own freshly computed rows, several prompts side by side,
+    each from its position 0 with nothing cached behind it, so nothing
+    is gathered from a pool. A query sees the keys of its own prompt at
+    or before it: prompts are contiguous in the call, so that is causal
+    over the call's index within one segment (``segment_ids`` [1, s]),
+    and a window counts the same way. Dense, as every chunk's attention
+    is: the scores are [h, s, s] over one chunk, a fraction of what the
+    gather over a row's whole table scores."""
+    from ..ops.attention import dense_attention, segment_mask
+    with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+        return dense_attention(q, k, v, causal=True, window=window,
+                               attn_mask=segment_mask(segment_ids))
 
 
 def paged_decode_route(q, kp, kv_heads: int) -> str:
@@ -705,6 +740,21 @@ class _TickPhaseProfile:
                     capacity=self.capacity, entries=list(self.ring))
 
 
+class _ChunkPrograms:
+    """The engine's two prompt-chunk programs under its one attribute
+    ``_chunk_jit``: ``packed`` serves the prompts that start at
+    position 0, several a call (``_chunk_prefill_packed``), ``alone``
+    one slot's chunk that has cached context behind it
+    (``_chunk_prefill``). ``_cache_size`` is what a "nothing was traced
+    again" check reads: both programs' traces."""
+
+    def __init__(self, packed, alone):
+        self.packed, self.alone = packed, alone
+
+    def _cache_size(self) -> int:
+        return self.packed._cache_size() + self.alone._cache_size()
+
+
 class PagedEngine:
     """Continuous-batching serving engine for causal LMs whose attention
     takes a ``PagedKV``: the Llama family (a K and a V pool, kv heads x
@@ -765,6 +815,11 @@ class PagedEngine:
                 block_size,
                 -(-chunk_prefill_tokens // block_size) * block_size)
         self.chunk = chunk_prefill_tokens
+        # a packed prefill call holds up to this many prompts (segments)
+        # in its ``chunk`` positions: the engine's geometry decides, a
+        # slot each and a block each at the least
+        self._pack_segments = None if self.chunk is None \
+            else min(max_slots, self.chunk // block_size)
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -845,7 +900,7 @@ class PagedEngine:
         self._counters = {
             k: reg.counter(f"paged_{k}_total", **self._obs_labels)
             for k in ("decode_steps", "prefills", "preemptions",
-                      "prefill_chunks", "slot_steps",
+                      "prefill_chunks", "prefill_segments", "slot_steps",
                       "active_slot_steps", "prefix_hit_tokens",
                       "prefix_adopted_blocks", "timeouts",
                       "cancellations", "rejected",
@@ -902,8 +957,10 @@ class PagedEngine:
                                           donate_argnums=(1, 5))
         self._prefill_jit = jax.jit(self._prefill, donate_argnums=(1,),
                                     static_argnames=("bucket",))
-        self._chunk_jit = jax.jit(self._chunk_prefill, donate_argnums=(1,),
-                                  static_argnames=("bucket",))
+        self._chunk_jit = _ChunkPrograms(
+            jax.jit(self._chunk_prefill_packed, donate_argnums=(1, 2)),
+            jax.jit(self._chunk_prefill, donate_argnums=(1,),
+                    static_argnames=("bucket",)))
         # spill_reupload_program (ISSUE 17): one batched H2D scatter
         # landing a restored span's KV into freshly allocated blocks.
         # Pools are donated (alias-in-place like the decode scatters);
@@ -1848,6 +1905,53 @@ class PagedEngine:
         return (nxt[0], lps[0], new_key[0], seen_row, seen_out,
                 [c.pool for c in new_caches])
 
+    def _chunk_prefill_packed(self, params, pools, seen, call):
+        """One call of up to ``_pack_segments`` prompts side by side,
+        each from its position 0 with nothing cached behind it (the
+        first ``chunk`` tokens of a longer one). ONE executable whatever
+        the number of segments: ``call`` is the int32 vector
+        ``_pack_call`` lays out, dead segments have length 0 and slot
+        ``R``. Every token's K/V goes to its own prompt's blocks, the
+        attention runs over the call's own rows under the segment-causal
+        mask (no page gathered), and each segment's row of ``seen`` is
+        built here from its own ids and written back by slot. The row
+        of every segment's last live position is penalised and sampled
+        as ``_chunk_prefill`` does it; only a segment that samples pays
+        the filter's sort (``sample_token_segments``). Returns (int32
+        [S, 4]: token, logprob bits, the advanced key; ``seen``; the
+        pools): one array for the host to read."""
+        from .sampling import repetition_penalty_rows, sample_token_segments
+        C, S, M = self.chunk, self._pack_segments, self.M
+        with jax.named_scope("patch"):      # the upload taken apart
+            ids, seg, pos = call[:3 * C].reshape(3, C)
+            sg = call[3 * C:].reshape(S, M + _SEG_WORDS)
+            tables = sg[:, :M]
+            lens, slots, last, final, tks = (sg[:, M + w] for w in range(5))
+            temps, tps, reps = jax.lax.bitcast_convert_type(
+                sg[:, M + 5:M + 8], jnp.float32).T
+            keys = jax.lax.bitcast_convert_type(sg[:, M + 8:], jnp.uint32)
+        caches = self._paged_caches(pools, tables, lens)
+        logits, new_caches = self.fn(params, ids[None], kv_caches=caches,
+                                     positions=pos[None],
+                                     segment_ids=seg[None])
+        with jax.named_scope("penalty"):
+            seen_rows = jnp.zeros((S, seen.shape[1]), bool) \
+                .at[seg, ids].max(pos < lens[seg])
+            rows = logits[0, last].astype(jnp.float32)
+        rows = repetition_penalty_rows(rows, seen_rows, reps)
+        nxt, lps, new_keys = sample_token_segments(rows, keys, temps, tks,
+                                                   tps, lens > 0)
+        with jax.named_scope("epilogue"):
+            # a prompt's last chunk commits its sample to the mask; a
+            # longer prompt's first chunk keeps the ids alone
+            seen_rows = seen_rows.at[jnp.arange(S), nxt].max(final > 0)
+            seen = seen.at[slots].set(seen_rows, mode="drop")
+            out = jnp.concatenate(
+                [nxt[:, None], jax.lax.bitcast_convert_type(lps, jnp.int32)
+                 [:, None], jax.lax.bitcast_convert_type(new_keys,
+                                                         jnp.int32)], axis=1)
+        return out, seen, [c.pool for c in new_caches]
+
     # ------------------------------------------------------------- host
     @_on_device
     def submit(self, request_id, input_ids, max_new_tokens: int = 32,
@@ -2406,11 +2510,13 @@ class PagedEngine:
             req.prefill_pos = cached
             self.seq_lens[slot_id] = cached
             # seed the seen mask with prefix-cache-skipped tokens (their
-            # chunks never run); later chunks scatter their own ids
-            seen0 = self._zeros((self.seen.shape[1],), bool)
+            # chunks never run); later chunks scatter their own ids. A
+            # prompt that starts at 0 gets its whole row from the packed
+            # call that serves it
             if cached:
-                seen0 = seen0.at[np.asarray(ids[:cached])].set(True)
-            self.seen = self.seen.at[slot_id].set(seen0)
+                seen0 = self._zeros((self.seen.shape[1],), bool) \
+                    .at[np.asarray(ids[:cached])].set(True)
+                self.seen = self.seen.at[slot_id].set(seen0)
             return True
 
         bucket = next((b for b in self.prefill_buckets if b >= len(ids)),
@@ -2449,9 +2555,99 @@ class PagedEngine:
             self._finish(slot_id)
         return True
 
+    def _pack_calls(self, slot_ids: List[int]) -> List[List[int]]:
+        """The packed prefill calls of one step: the slots whose prompt
+        starts at position 0, in admission order, placed FIRST-FIT into
+        open calls of at most ``chunk`` positions and ``_pack_segments``
+        segments; a call is dispatched in the order of its first member.
+        A slot's live length is ``min(chunk, len(prompt))``, which an
+        empty call always holds, so every one of them is served this
+        step."""
+        calls: List[List[int]] = []
+        room: List[int] = []
+        for i in sorted(slot_ids, key=lambda i: self.slots[i].admit_seq):
+            live = min(self.chunk, len(self.slots[i].prompt))
+            at = next((c for c, left in enumerate(room) if left >= live
+                       and len(calls[c]) < self._pack_segments), None)
+            if at is None:
+                calls.append([])
+                room.append(self.chunk)
+                at = len(calls) - 1
+            calls[at].append(i)
+            room[at] -= live
+        return calls
+
+    def _pack_call(self, slot_ids: List[int]):
+        """The one upload of a packed call (``_chunk_prefill_packed``
+        reads it back apart): int32 [3 * chunk] token ids, segment index
+        and position, then a row a segment of its block-table row and
+        ``_SEG_WORDS`` words. The pads ride behind the last segment,
+        past its length; a dead segment has length 0 and slot ``R``.
+        Returns (the vector, each segment's live length)."""
+        C, S, M = self.chunk, self._pack_segments, self.M
+        tok = np.zeros((3, C), np.int32)
+        sg = np.zeros((S, M + _SEG_WORDS), np.int32)
+        sg[:, M + 1] = self.R
+        floats = sg[:, M + 5:M + 8].view(np.float32)
+        lives, at = [], 0
+        for j, i in enumerate(slot_ids):
+            req = self.slots[i]
+            live = min(C, len(req.prompt))
+            tok[0, at:at + live] = req.prompt[:live]
+            tok[1, at:] = j
+            tok[2, at:] = np.arange(C - at)
+            at += live
+            sg[j, :M] = self.block_tables[i]
+            sg[j, M:M + 5] = (live, i, at - 1, live == len(req.prompt),
+                              req.top_k)
+            floats[j] = (req.temperature, req.top_p, req.rep)
+            sg[j, M + 8:] = req.key.view(np.int32)
+            lives.append(live)
+        return np.concatenate([tok.ravel(), sg.ravel()]), lives
+
+    def _advance_packed(self, slot_ids: List[int]):
+        """Run ONE packed call: the first chunk of every slot in
+        ``slot_ids`` (``_pack_calls``), one upload, one program, one
+        readback; then each segment's bookkeeping (``_chunk_served``)."""
+        t_chunk = time.perf_counter()
+        with self._phase("chunk") as br:
+            call, lives = self._pack_call(slot_ids)
+            for i in slot_ids:
+                self._mark_dirty(i)     # lens/activation change this tick
+            self.dispatch_count += 1
+            self._count("dispatches")
+            with self._phase("h2d") as up:
+                call = self._put(call)
+                up.switch("dispatch")
+                out, self.seen, self.pools = self._chunk_jit.packed(
+                    self.params, self.pools, self.seen, call)
+            self._count("prefill_chunks")
+            self._count("prefill_segments", len(slot_ids))
+            # the segments whose prompt ends in this call: their first
+            # token comes back with it
+            done = [live == len(self.slots[i].prompt)
+                    for i, live in zip(slot_ids, lives)]
+            if any(done):
+                if br.on:
+                    # the read below waits for the call's program
+                    with self._phase("device"):
+                        try:
+                            jax.block_until_ready(out)
+                        except Exception:
+                            pass
+                out = np.asarray(out)
+            for j, (i, live) in enumerate(zip(slot_ids, lives)):
+                first = (int(out[j, 0]),
+                         float(out[j, 1:2].view(np.float32)[0]),
+                         out[j, 2:].view(np.uint32)) if done[j] else None
+                self._chunk_served(i, live, first)
+        self._h_chunk.observe((time.perf_counter() - t_chunk) * 1e3)
+
     def _advance_chunk(self, slot_id: int):
-        """Run ONE chunk of slot's prompt prefill; on the final chunk the
-        first generated token materializes and the slot joins decode."""
+        """Run ONE chunk of a slot's prompt that has cached context
+        behind it (earlier chunks, an adopted prefix), alone; on the
+        final chunk the first generated token materializes and the slot
+        joins decode."""
         t_chunk = time.perf_counter()
         # everything here that is not an upload, the program's call or
         # the wait for its result is the chunk's own host work
@@ -2472,26 +2668,21 @@ class PagedEngine:
                                     self._put(req.key))
                 call.switch("dispatch")
                 (nxt, lp, new_key, seen_mid, seen_fin,
-                 self.pools) = self._chunk_jit(
+                 self.pools) = self._chunk_jit.alone(
                     self.params, self.pools, row, padded, np.int32(start),
                     np.int32(start + live), key,
                     np.float32(req.temperature), np.int32(req.top_k),
                     np.float32(req.top_p), np.float32(req.rep),
                     self.seen[slot_id], bucket=self.chunk)
             self._count("prefill_chunks")
-            if self.trace_sink is not None:
-                self.trace_sink(req.request_id, "prefill_chunk",
-                                start=start, tokens=live)
-            req.prefill_pos = start + live
-            self.seq_lens[slot_id] = req.prefill_pos
+            self._count("prefill_segments")
             # mid chunks keep the ids-only mask; the final chunk's
             # committed sample rides in seen_fin (mirrors the PRNG-key
             # protocol)
             self.seen = self.seen.at[slot_id].set(seen_fin if last
                                                   else seen_mid)
+            first = None
             if last:
-                self._count("prefills")
-                self._register_prefix(req)
                 if br.on:
                     # the reads below wait for the chunk's program
                     with self._phase("device"):
@@ -2499,19 +2690,37 @@ class PagedEngine:
                             jax.block_until_ready((new_key, nxt, lp))
                         except Exception:
                             pass
-                self.keys[slot_id] = np.array(new_key)
-                self._key_overrides.add(slot_id)
-                req.key = self.keys[slot_id].copy()
-                first = int(nxt)
-                req.tokens.append(first)
-                req.lps.append(float(lp))
-                if self.trace_sink is not None:
-                    self.trace_sink(req.request_id, "prefill_done",
-                                    tokens=len(ids))
-                if self._stop_hit(req) or req.max_new <= 1 \
-                        or (req.eos is not None and first == req.eos):
-                    self._finish(slot_id)
+                first = (int(nxt), float(lp), np.array(new_key))
+            self._chunk_served(slot_id, live, first)
         self._h_chunk.observe((time.perf_counter() - t_chunk) * 1e3)
+
+    def _chunk_served(self, slot_id: int, live: int, first=None):
+        """What the host does for ONE slot after the call that served
+        ``live`` tokens of its prompt, packed or alone. ``first`` (the
+        prompt's last chunk): the first generated token, its logprob
+        and the advanced key, read back from the call."""
+        req = self.slots[slot_id]
+        if self.trace_sink is not None:
+            self.trace_sink(req.request_id, "prefill_chunk",
+                            start=req.prefill_pos, tokens=live)
+        req.prefill_pos += live
+        self.seq_lens[slot_id] = req.prefill_pos
+        if first is None:
+            return
+        token, lp, key = first
+        self._count("prefills")
+        self._register_prefix(req)
+        self.keys[slot_id] = key
+        self._key_overrides.add(slot_id)
+        req.key = self.keys[slot_id].copy()
+        req.tokens.append(token)
+        req.lps.append(lp)
+        if self.trace_sink is not None:
+            self.trace_sink(req.request_id, "prefill_done",
+                            tokens=len(req.prompt))
+        if self._stop_hit(req) or req.max_new <= 1 \
+                or (req.eos is not None and token == req.eos):
+            self._finish(slot_id)
 
     def _grow_blocks(self, slot_id: int, need: int,
                      reserve: int = 0) -> bool:
@@ -2995,10 +3204,17 @@ class PagedEngine:
             while self._try_admit():
                 pass
         if self.chunk is not None:
-            for i in range(self.R):
-                s = self.slots[i]
-                if s is not None and s.prefill_pos < len(s.prompt):
-                    self._advance_chunk(i)
+            # one chunk a prefilling slot and step: the prompts that
+            # start at position 0 share calls (_pack_calls), a chunk
+            # with cached context behind it runs alone
+            todo = [i for i, s in enumerate(self.slots)
+                    if s is not None and s.prefill_pos < len(s.prompt)]
+            fresh = [i for i in todo if self.slots[i].prefill_pos == 0]
+            rest = [i for i in todo if self.slots[i].prefill_pos > 0]
+            for call in self._pack_calls(fresh):
+                self._advance_packed(call)
+            for i in rest:
+                self._advance_chunk(i)
         with self._phase("stage"):
             for i in range(self.R):
                 if self.slots[i] is None or \

@@ -118,6 +118,38 @@ def sample_token_rows(logits, keys, temperature, top_k, top_p):
     return tokens, logprobs, carry
 
 
+def sample_token_segments(logits, keys, temperature, top_k, top_p, live):
+    """:func:`sample_token_rows` for the rows of a packed prefill call,
+    of which few are live and fewer sample: the same tokens, logprobs
+    and key chain, but the filter (a sort of the whole row) and the draw
+    run one row at a time under a ``lax.cond``, so what a call pays
+    follows its live sampled rows and not their number (``live`` [R]
+    bool; a dead or greedy row's ``sampled`` entry is never looked
+    at). Greedy rows are the argmax of the raw fp32 row, bit-exact."""
+    with jax.named_scope("sample"):     # obs.TICK_SCOPES
+        raw = logits.astype(jnp.float32)
+        temperature = jnp.asarray(temperature, jnp.float32)
+        carry, sub = split_key_rows(keys)
+
+        def draw(row):
+            lt = filter_logits_rows(*(x[None] for x in row[:4]))[0]
+            return jax.random.categorical(
+                jax.random.wrap_key_data(row[4], impl="threefry2x32"),
+                lt).astype(jnp.int32)
+
+        sampled = jax.lax.map(
+            lambda row: jax.lax.cond(row[-1], draw,
+                                     lambda row: jnp.int32(0), row[:-1]),
+            (raw, temperature, jnp.asarray(top_k, jnp.int32),
+             jnp.asarray(top_p, jnp.float32), sub,
+             live & (temperature > 0.0)))
+        tokens = jnp.where(temperature <= 0.0, jnp.argmax(raw, axis=-1),
+                           sampled).astype(jnp.int32)
+        logprobs = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
+                                       tokens[:, None], axis=-1)[:, 0]
+    return tokens, logprobs, carry
+
+
 def seed_key_row(seed: int):
     """The [2] uint32 raw key data for ONE row's PRNG stream, seeded by
     ``seed`` — the row-scoped key init shared by ``PagedEngine.submit``

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from .paged import (paged_chunk_attention, paged_decode_attention,
-                    paged_decode_write, paged_prefill_write)
+                    paged_decode_write, paged_packed_attention,
+                    paged_prefill_write)
 
 __all__ = ["TickStubConfig", "TickStubModel"]
 
@@ -41,7 +42,7 @@ class TickStubModel:
                       out=jax.random.normal(k, (d, V)))
 
         def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False, paged_decode=False):
+               paged_chunk=False, paged_decode=False, segment_ids=None):
             x = params["emb"][tokens]              # [R, s, d]
             kv = x[:, :, None, :]                  # [R, s, 1, d]
             pk = kv_caches[0]
@@ -51,6 +52,15 @@ class TickStubModel:
                 # write/attention helpers handle T >= 1 natively
                 pk = paged_decode_write(pk, kv, kv)
                 o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
+            elif segment_ids is not None:
+                # a packed call: several prompts from position 0, each
+                # token into its own prompt's row, attention over the
+                # call's own rows
+                pk = paged_prefill_write(pk, kv, kv,
+                                         positions=positions[0],
+                                         segments=segment_ids[0])
+                o = paged_packed_attention(kv, kv, kv,
+                                           segment_ids)[:, :, 0]
             else:                                  # (chunk) prefill
                 # chunk K/V lands at its GLOBAL positions — a chunk at
                 # start > 0 written at 0..s-1 reads stale data later

@@ -256,8 +256,9 @@ class MLAttention(Layer):
         return dense_attention(q, k, v, scale=self.scale, **mask)
 
     def _paged(self, pk, q_nope, q_pe, c, k_pe, positions, paged_chunk,
-               paged_decode):
+               paged_decode, segment_ids=None):
         """Serving over the latent paged pool (generation/paged.py)."""
+        from ..ops.attention import segment_mask
         from ..generation.paged import (paged_chunk_rows,
                                         paged_decode_write,
                                         paged_latent_attention,
@@ -278,6 +279,15 @@ class MLAttention(Layer):
             out = self._absorbed(
                 q_nope, lambda q_lat: paged_latent_attention(
                     row(q_lat, q_pe), pk, r, self.scale))
+        elif segment_ids is not None:
+            # a PACKED call: several prompts side by side, each from
+            # its position 0, so the call's own latents are all a query
+            # can see and no page is gathered or expanded
+            pk = paged_prefill_write(pk, new, positions=positions[0],
+                                     segments=segment_ids[0])
+            with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+                out = self._expanded(q_nope, q_pe, c, k_pe, causal=True,
+                                     attn_mask=segment_mask(segment_ids))
         elif paged_chunk:
             pk = paged_prefill_write(pk, new, positions=positions[0])
             with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
@@ -293,8 +303,8 @@ class MLAttention(Layer):
         return out, pk
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                attn_mask=None, attn_start=None, segment_ids=None,
+                paged_chunk: bool = False, paged_decode: bool = False):
         cfg = self.config
         b, s, _ = x.shape
         h = cfg.num_attention_heads
@@ -308,7 +318,7 @@ class MLAttention(Layer):
         if kv_cache is not None and isinstance(kv_cache, PagedKV):
             out, new_cache = self._paged(kv_cache, q_nope, q_pe, c, k_pe,
                                          positions, paged_chunk,
-                                         paged_decode)
+                                         paged_decode, segment_ids)
         elif kv_cache is not None:
             cc, cpe = kv_cache  # [b, T, r], [b, T, rope_d]
             cc = jax.lax.dynamic_update_slice(cc, c.astype(cc.dtype),
@@ -381,14 +391,15 @@ class DeepseekV2DecoderLayer(Layer):
                 aux_loss_weight=config.aux_loss_weight, **moe)
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                attn_mask=None, attn_start=None, segment_ids=None,
+                paged_chunk: bool = False, paged_decode: bool = False):
         # the named scopes are obs.TICK_SCOPES, as in llama.py
         with jax.named_scope("norm"):
             h = self.input_layernorm(x)
         attn = self.self_attn(h, positions,
                               kv_cache=kv_cache, cache_index=cache_index,
                               attn_mask=attn_mask, attn_start=attn_start,
+                              segment_ids=segment_ids,
                               paged_chunk=paged_chunk,
                               paged_decode=paged_decode)
         new_cache = None
@@ -459,7 +470,7 @@ class DeepseekV2Model(Layer):
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
                 return_prenorm: bool = False, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                paged_decode: bool = False, segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             start = cache_index if cache_index is not None else 0
@@ -476,6 +487,7 @@ class DeepseekV2Model(Layer):
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
                               cache_index=cache_index, attn_mask=attn_mask,
                               attn_start=attn_start,
+                              segment_ids=segment_ids,
                               paged_chunk=paged_chunk,
                               paged_decode=paged_decode)
                 new_caches.append(nc)
@@ -545,7 +557,8 @@ class DeepseekV2ForCausalLM(CausalLMBase):
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
                 return_mtp: bool = False, return_prenorm: bool = False,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                paged_chunk: bool = False, paged_decode: bool = False,
+                segment_ids=None):
         """``return_mtp`` (training-time, no cache): additionally return
         the list of MTP depth logits — depth k's logits[:, i] predict
         token i+2+k. The MTP chain consumes the pre-final-norm hidden
@@ -592,7 +605,8 @@ class DeepseekV2ForCausalLM(CausalLMBase):
         out = self.model(input_ids, positions, kv_caches, cache_index,
                          attn_mask, attn_start=attn_start,
                          return_prenorm=return_prenorm,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode)
+                         paged_chunk=paged_chunk, paged_decode=paged_decode,
+                         segment_ids=segment_ids)
         caches = None
         pre = None
         if kv_caches is not None:

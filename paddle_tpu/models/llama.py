@@ -303,6 +303,7 @@ class LlamaAttention(Layer):
             from ..generation.paged import (PagedKV, paged_chunk_attention,
                                             paged_decode_attention,
                                             paged_decode_write,
+                                            paged_packed_attention,
                                             paged_prefill_write)
         if kv_cache is not None and isinstance(kv_cache, PagedKV):
             # paged serving (generation/paged.py): block-table cache.
@@ -315,11 +316,22 @@ class LlamaAttention(Layer):
             # prompt itself (pad tail lands in the garbage block and
             # produces discarded rows), while a CHUNK (paged_chunk=
             # True, positions carry the global offset) must also attend
-            # to the earlier chunks already in the row's blocks.
+            # to the earlier chunks already in the row's blocks. A
+            # PACKED call (segment_ids: several prompts side by side,
+            # each from position 0) writes each token into its own
+            # prompt's row and attends over the call's own rows.
             if s == 1 or paged_decode:
                 new_cache = paged_decode_write(kv_cache, k, v)
                 out = paged_decode_attention(q, new_cache,
                                              window=self.window)
+            elif segment_ids is not None:
+                new_cache = paged_prefill_write(kv_cache, k, v,
+                                                positions=positions[0],
+                                                segments=segment_ids[0])
+                out = paged_packed_attention(
+                    q, k.astype(kv_cache.kp.dtype),
+                    v.astype(kv_cache.vp.dtype), segment_ids,
+                    window=self.window)
             elif paged_chunk:
                 new_cache = paged_prefill_write(kv_cache, k, v,
                                                 positions=positions[0])

@@ -167,7 +167,8 @@ class LongcatFlashModel(Layer):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                paged_chunk: bool = False, paged_decode: bool = False,
+                segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             start = cache_index if cache_index is not None else 0
@@ -188,6 +189,7 @@ class LongcatFlashModel(Layer):
                                 kv_caches=kv_caches[2 * i:2 * i + 2],
                                 cache_index=cache_index,
                                 attn_mask=attn_mask, attn_start=attn_start,
+                                segment_ids=segment_ids,
                                 paged_chunk=paged_chunk,
                                 paged_decode=paged_decode)
                 new_caches += pair
@@ -241,10 +243,12 @@ class LongcatFlashForCausalLM(CausalLMBase):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                paged_chunk: bool = False, paged_decode: bool = False,
+                segment_ids=None):
         out = self.model(input_ids, positions, kv_caches, cache_index,
                          attn_mask, attn_start=attn_start,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode)
+                         paged_chunk=paged_chunk, paged_decode=paged_decode,
+                         segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:
             out, caches = out

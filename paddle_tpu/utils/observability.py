@@ -944,13 +944,14 @@ LOOP_PHASES = (
     "idle",       # nothing to serve: waiting to be woken
 )
 # TICK_SCOPES: the ``jax.named_scope`` names inside the tick and chunk
-# programs (``_fused_tick*``, ``_chunk_prefill``) — what the device
+# programs (``_fused_tick*``, ``_chunk_prefill*``) — what the device
 # runs inside a tick. An op's scope is the last component of its
 # ``op_name`` that is one of these. (A LongCat-Flash layer has two
 # attentions and two dense FFNs: its norm / qkv / absorb / kv_write /
 # attn / o_proj / mlp scopes occur twice a layer.)
 TICK_SCOPES = (
-    "patch",       # staged slot transitions scattered into the state
+    "patch",       # staged slot transitions scattered into the state;
+                   # a packed prefill call's one upload taken apart
     "embed",
     "norm",        # both RMSNorms of a layer
     "qkv",         # projections, biases, rope (latent attention: the

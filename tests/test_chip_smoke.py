@@ -58,7 +58,9 @@ def test_smoke_at_tiny_size_and_four_engines_on_four_devices(monkeypatch):
     # share ONE trace of each program
     jits = (warmed[0]._chunk_jit, warmed[0]._tick_greedy_jit,
             warmed[0]._tick_jit)
-    assert [j._cache_size() for j in jits] == [1, 1, 1]
+    # two chunk programs: the packed call from position 0 and the
+    # continuation that runs alone
+    assert [j._cache_size() for j in jits] == [2, 1, 1]
     facts = chip_smoke.serve_and_verify(cfg, TINY_GEOMETRY, warmed,
                                         devices[1:2], atol=1e-3)
     assert facts["decode_route"] == "ragged"
@@ -67,7 +69,7 @@ def test_smoke_at_tiny_size_and_four_engines_on_four_devices(monkeypatch):
     # prefix-b adopts both shared chunks of prefix-a
     assert facts["prefix_hit_tokens"] == 32
     assert facts["max_logprob_diff"] < 1e-3
-    assert [j._cache_size() for j in jits] == [1, 1, 1]
+    assert [j._cache_size() for j in jits] == [2, 1, 1]
     with pytest.raises(chip_smoke.SmokeFailure, match="replica 0: params"):
         chip_smoke.check_placement(warmed, devices[:1])
 

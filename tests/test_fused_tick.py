@@ -27,6 +27,7 @@ from paddle_tpu.generation.paged import (PagedEngine, PagedKV,
                                          paged_chunk_attention,
                                          paged_decode_attention,
                                          paged_decode_write,
+                                         paged_packed_attention,
                                          paged_prefill_write)
 from paddle_tpu.models import LlamaForCausalLM
 from paddle_tpu.models.llama import llama_tiny
@@ -70,13 +71,19 @@ class StubModel:
                       out=jax.random.normal(k, (d, V)))
 
         def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False):
+               paged_chunk=False, segment_ids=None):
             x = params["emb"][tokens]              # [R, s, d]
             kv = x[:, :, None, :]                  # [R, s, 1, d]
             pk = kv_caches[0]
             if tokens.shape[1] == 1:               # decode tick
                 pk = paged_decode_write(pk, kv, kv)
                 o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
+            elif segment_ids is not None:          # a packed call
+                pk = paged_prefill_write(pk, kv, kv,
+                                         positions=positions[0],
+                                         segments=segment_ids[0])
+                o = paged_packed_attention(kv, kv, kv,
+                                           segment_ids)[:, :, 0]
             else:                                  # (chunk) prefill
                 pk = paged_prefill_write(pk, kv, kv)
                 o = paged_chunk_attention(x[:, :, None, :], pk,

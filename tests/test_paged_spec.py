@@ -32,6 +32,7 @@ from paddle_tpu.generation.paged import (PagedEngine, PagedKV,
                                          paged_chunk_attention,
                                          paged_decode_attention,
                                          paged_decode_write,
+                                         paged_packed_attention,
                                          paged_prefill_write)
 from paddle_tpu.generation.prompt_lookup import (accept_length,
                                                  propose_ngram,
@@ -89,13 +90,19 @@ class LookupStub:
         params = dict(emb=emb, table=table)
 
         def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False, paged_decode=False):
+               paged_chunk=False, paged_decode=False, segment_ids=None):
             x = params["emb"][tokens]              # [R, s, d]
             kv = x[:, :, None, :]
             pk = kv_caches[0]
             if tokens.shape[1] == 1 or paged_decode:
                 pk = paged_decode_write(pk, kv, kv)
                 o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
+            elif segment_ids is not None:          # a packed call
+                pk = paged_prefill_write(pk, kv, kv,
+                                         positions=positions[0],
+                                         segments=segment_ids[0])
+                o = paged_packed_attention(kv, kv, kv,
+                                           segment_ids)[:, :, 0]
             else:
                 pk = paged_prefill_write(
                     pk, kv, kv,

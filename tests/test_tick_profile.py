@@ -131,16 +131,21 @@ class SlowCalls(FakeClock):
 
     def install(self, eng, monkeypatch):
         from paddle_tpu.generation import paged
-        chunk_jit, ready = eng._chunk_jit, paged.jax.block_until_ready
+        ready = paged.jax.block_until_ready
 
-        def slow_chunk(*a, **kw):
-            self.t += 0.100
-            return chunk_jit(*a, **kw)
+        def slow(program):
+            def slow_chunk(*a, **kw):
+                self.t += 0.100
+                return program(*a, **kw)
+            return slow_chunk
 
         def slow_ready(x):
             self.t += 0.050
             return ready(x)
-        eng._chunk_jit = slow_chunk
+        # both chunk programs: the call that starts at 0 and the one
+        # that continues a prompt
+        eng._chunk_jit = paged._ChunkPrograms(
+            slow(eng._chunk_jit.packed), slow(eng._chunk_jit.alone))
         monkeypatch.setattr(paged.jax, "block_until_ready", slow_ready)
 
 

@@ -3,10 +3,11 @@
 Contracts pinned here, at tiny widths and without compiling anything
 (the programs are only traced and lowered):
 
-- SCOPES: every op of ``_fused_tick``, ``_fused_tick_greedy`` and
-  ``_chunk_prefill`` that a ``jax.named_scope`` covers carries a name
+- SCOPES: every op of ``_fused_tick``, ``_fused_tick_greedy``,
+  ``_chunk_prefill`` and ``_chunk_prefill_packed`` that a
+  ``jax.named_scope`` covers carries a name
   of ``obs.TICK_SCOPES`` in its ``op_name``; each program uses exactly
-  the scopes its stages have, and the three together use the whole
+  the scopes its stages have, and together they use the whole
   vocabulary, so a scope that is renamed or dropped in the program
   fails here before a device trace loses it.
 - KERNEL NAMES: the Pallas kernel of the serving route is a
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.generation import paged
 from paddle_tpu.generation.paged import PagedEngine
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
 from paddle_tpu.utils import observability as obs
@@ -41,6 +43,7 @@ PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
     "_chunk_prefill": LLAMA - ATTN - {"patch"},
+    "_chunk_prefill_packed": LLAMA - ATTN,
 }
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
@@ -50,6 +53,7 @@ DEEPSEEK = {
     "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb",
                                                      "zero_experts"},
 }
+DEEPSEEK["_chunk_prefill_packed"] = DEEPSEEK["_chunk_prefill"] | {"patch"}
 # LongCat-Flash's double layer: zero-compute experts, no shared expert
 LONGCAT = {k: v - {"shared_expert"} | {"zero_experts"}
            for k, v in DEEPSEEK.items()}
@@ -70,6 +74,10 @@ def kernels(monkeypatch):
 
 
 def _args(eng, program):
+    if program == "_chunk_prefill_packed":
+        words = 3 * CHUNK + eng._pack_segments * (eng.M + paged._SEG_WORDS)
+        return (eng.params, eng.pools, eng.seen,
+                jnp.zeros((words,), jnp.int32)), {}
     if program != "_chunk_prefill":
         return (eng.params, eng.pools, eng.seen, eng._dev), {}
     return ((eng.params, eng.pools, jnp.zeros((eng.M,), jnp.int32),
@@ -133,7 +141,8 @@ def test_a_double_layer_carries_its_scopes_and_two_kernels(longcat_engine,
     _, scopes = _lowered(longcat_engine, program)
     assert set(scopes) - {None} == LONGCAT[program]
     assert scopes[None] < 0.1 * sum(scopes.values()), scopes
-    if program != "_chunk_prefill":     # one latent kernel an attention
+    if not program.startswith("_chunk_prefill"):
+        # one latent kernel an attention
         names = _kernel_names(_trace(longcat_engine, program).jaxpr.jaxpr)
         assert names == ["ragged_paged_attention"] * 2
 
@@ -192,13 +201,18 @@ def test_scopes_do_not_change_the_program(engine, kernels, monkeypatch,
     scoped, _ = _lowered(engine, program)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    # the kernel's wrapper is jitted: it keeps the jaxpr it traced with
-    # the scopes on, and would keep the one traced here without them
-    ragged._attend.clear_cache()
+    # the kernel's wrapper and the chunk programs' write and attentions
+    # are jitted: each keeps the jaxpr it traced with the scopes on, and
+    # would keep the one traced here without them
+    jitted = (ragged._attend, paged.paged_prefill_write,
+              paged.paged_chunk_attention, paged.paged_packed_attention)
+    for fn in jitted:
+        fn.clear_cache()
     try:
         plain, scopes = _lowered(engine, program)
     finally:
-        ragged._attend.clear_cache()
+        for fn in jitted:
+            fn.clear_cache()
     assert set(scopes) == {None}    # the patch took: no scope was traced
     assert sum(scoped.values()) > 100
     assert plain == scoped
