@@ -124,7 +124,7 @@ import numpy as np
 from ..utils import observability as obs
 from ..utils.faults import BackpressureError
 
-__all__ = ["PagedKV", "PagedEngine"]
+__all__ = ["PagedKV", "CacheLayer", "PagedEngine"]
 
 # unique per-process engine label: every engine's counters live in the
 # global observability registry (scrapeable), while `stats`/`health()`
@@ -192,6 +192,25 @@ def _on_device(method):
     return scoped
 
 
+class CacheLayer(NamedTuple):
+    """What one cache layer of a model is (``paged_cache_layers``, where
+    a model's layers differ): ``rows`` the (heads, width) of each pool
+    array of a cached token (K and V, whose widths may differ; or one
+    latent row), and ``window``: None for a layer that keeps every
+    block of a sequence, else the sliding window of a layer that keeps
+    only the band its queries still reach (``PagedKV.ring``)."""
+    rows: tuple
+    window: Optional[int] = None
+
+
+# what a band-keeping engine adds up inside a tick, over the live rows
+# and the layers of each kind, beside the model's own tick counters:
+# pages inside the band / of the whole context, the tokens the two kinds
+# of kernel call read, and the pages that fell behind a band
+_BAND_COUNTERS = ("kv_window_blocks", "kv_full_blocks", "kv_window_tokens",
+                  "kv_context_tokens", "kv_window_blocks_released")
+
+
 class PagedKV(NamedTuple):
     """Per-layer paged cache view handed to the attention modules.
 
@@ -207,13 +226,21 @@ class PagedKV(NamedTuple):
     seq_lens: [R] tokens already cached per slot == this step's write
     position. Shared across layers; XLA dedups the copies.
     heads: how many heads share a pool row (the shape no longer says):
-    a Python int, static under every transform.
+    a Python int, static under every transform. ``vp``'s heads may be
+    narrower than ``kp``'s (MiMo-V2: keys of 192 columns, values of 128).
+    ring: the layer keeps only a BAND of each sequence (a sliding-window
+    layer). Its ``block_tables`` [R, Mw] is then a ring over the row's
+    logical blocks, logical block b in entry ``b % Mw``, and its pool
+    holds ``Mw`` pages a slot and no more; positions still count from
+    the sequence's start, and what a ring entry holds is the newest
+    logical block written to it (``_table_positions``). Static too.
     """
     kp: Any
     vp: Any
     block_tables: Any
     seq_lens: Any
     heads: int = 1
+    ring: bool = False
 
     @property
     def block_size(self) -> int:
@@ -221,8 +248,13 @@ class PagedKV(NamedTuple):
 
     @property
     def width(self) -> int:
-        """Columns of one head in a pool row."""
+        """Columns of one (key) head in a pool row."""
         return self.kp.shape[2] // self.heads
+
+    def table_entry(self, logical):
+        """The table column of logical block(s) ``logical``."""
+        return logical % self.block_tables.shape[1] if self.ring \
+            else logical
 
     @property
     def pool(self) -> tuple:
@@ -245,15 +277,30 @@ class PagedKV(NamedTuple):
 
     def split(self, rows):
         """Rows GATHERED from a pool, [..., heads*width], with their heads
-        apart: [..., heads, width]."""
-        return rows.reshape(rows.shape[:-1] + (self.heads, self.width))
+        apart: [..., heads, width] (the width is the gathered pool's)."""
+        return rows.reshape(rows.shape[:-1] + (self.heads, -1))
 
 
-# ``heads`` is structure, not data: a PagedKV that crosses a transform
-# (jit, remat, scan) keeps it a Python int
+# ``heads`` and ``ring`` are structure, not data: a PagedKV that crosses
+# a transform (jit, remat, scan) keeps them Python values
 jax.tree_util.register_pytree_node(
-    PagedKV, lambda pk: (pk[:4], pk.heads),
-    lambda heads, leaves: PagedKV(*leaves, heads))
+    PagedKV, lambda pk: (pk[:4], pk[4:]),
+    lambda aux, leaves: PagedKV(*leaves, *aux))
+
+
+def _table_positions(pk: PagedKV, newest):
+    """The sequence position of every token slot of each row's table,
+    [rows, M*B], given the ``newest`` position [rows] written so far: a
+    plain table holds logical block j in entry j; a ring entry j holds
+    the newest logical block congruent to j that has been started,
+    and reads negative where none has (the caller masks it)."""
+    M, B = pk.block_tables.shape[1], pk.block_size
+    lb = jnp.arange(M)[None, :]
+    if pk.ring:
+        top = (newest // B)[:, None]
+        lb = top - (top - lb) % M
+    pos = lb[:, :, None] * B + jnp.arange(B)[None, None, :]
+    return pos.reshape(pos.shape[0], M * B)
 
 
 def paged_decode_write(pk: PagedKV, k, v=None):
@@ -273,15 +320,18 @@ def paged_decode_write(pk: PagedKV, k, v=None):
     with jax.named_scope("kv_write"):       # obs.TICK_SCOPES
         if T == 1:
             r = jnp.arange(R)
-            bidx = pk.block_tables[r, pk.seq_lens // B]      # [R]
+            bidx = pk.block_tables[r, pk.table_entry(pk.seq_lens // B)]
             boff = pk.seq_lens % B
             return pk.scatter(bidx, boff, k, v, sel=(slice(None), 0))
         M = pk.block_tables.shape[1]
         r = jnp.arange(R)[:, None]                           # [R, 1]
         pos = pk.seq_lens[:, None] + jnp.arange(T)[None, :]  # [R, T]
         lb = pos // B
-        bidx = jnp.where(lb < M,
-                         pk.block_tables[r, jnp.clip(lb, 0, M - 1)], 0)
+        if pk.ring:         # a ring always has the next entry: the
+            bidx = pk.block_tables[r, pk.table_entry(lb)]   # one behind
+        else:                                               # the band
+            bidx = jnp.where(lb < M,
+                             pk.block_tables[r, jnp.clip(lb, 0, M - 1)], 0)
         boff = pos % B
         return pk.scatter(bidx, boff, k, v)
 
@@ -301,14 +351,20 @@ def paged_prefill_write(pk: PagedKV, k, v=None, positions=None,
     and seq_lens[0] = start + live-chunk-length. ``segments`` [s]
     (a PACKED call: several prompts side by side, each from its
     position 0) names every token's row of the table in place of row
-    0; its pads ride behind the last prompt, past that row's length."""
+    0; its pads ride behind the last prompt, past that row's length.
+    Into a ring go only the positions that no later one of the same
+    call overwrites (the last ring's worth before the row's length)."""
     B = pk.block_size
     s = k.shape[1]
     with jax.named_scope("kv_write"):       # obs.TICK_SCOPES
         pos = positions if positions is not None else jnp.arange(s)
         row = segments if segments is not None else 0
         live = pos < pk.seq_lens[row]
-        bidx = jnp.where(live, pk.block_tables[row, pos // B],
+        if pk.ring:
+            live &= pos >= pk.seq_lens[row] \
+                - pk.block_tables.shape[1] * B
+        bidx = jnp.where(live,
+                         pk.block_tables[row, pk.table_entry(pos // B)],
                          garbage_block)
         boff = pos % B
         return pk.scatter(bidx, boff, k, v, sel=0)
@@ -323,31 +379,46 @@ def paged_chunk_rows(pk: PagedKV, pool=None):
     return pk.split(rows.reshape(1, -1, rows.shape[-1]))
 
 
+def _window_scope(name: str, ring: bool) -> str:
+    """obs.TICK_SCOPES: a band-keeping layer's attention has a scope of
+    its own beside the whole-context layers' (``attn_window`` beside
+    ``attn``, ``chunk_attn_window`` beside ``chunk_attn``)."""
+    return name + "_window" if ring else name
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=("window",))
 def paged_chunk_attention(q, pk: PagedKV, positions,
-                          window: Optional[int] = None):
+                          window: Optional[int] = None, sink=None):
     """Chunked-prefill attention: q [1, s, h, d] chunk queries at global
     positions [1, s] attend over row 0's gathered blocks — the
     previously cached chunks AND (causally) this chunk's own tokens,
     which ``paged_prefill_write`` scattered in just before. Stale or
     never-written table positions sit beyond every query's position (or
     in unallocated garbage-block slots) and are masked by the causal
-    compare."""
+    compare. A band-keeping layer (``pk.ring``) gathers its ring, the
+    band behind the chunk and the chunk, not the row's whole table.
+    ``sink`` [h]: see ``dense_attention``."""
     from ..ops.attention import dense_attention
-    with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+    with jax.named_scope(_window_scope("chunk_attn", pk.ring)):
         ks = paged_chunk_rows(pk)                   # [1, T, kvh, d]
         vs = paged_chunk_rows(pk, pk.vp)
-        kpos = jnp.arange(ks.shape[1])[None, :]             # [1, T]
+        kpos = _table_positions(pk, pk.seq_lens[:1] - 1) if pk.ring \
+            else jnp.arange(ks.shape[1])[None, :]           # [1, T]
         qpos = positions[0][:, None]                        # [s, 1]
         keep = kpos <= qpos                                 # [s, T]
+        if pk.ring:         # an entry no block has been written to yet
+            keep &= kpos >= 0
         if window is not None:
             keep &= qpos - kpos < window
-        return dense_attention(q, ks, vs, attn_mask=keep[None, None])
+        return dense_attention(q, ks, vs, attn_mask=keep[None, None],
+                               sink=sink)
 
 
-@functools.partial(jax.jit, inline=True, static_argnames=("window",))
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("window", "band"))
 def paged_packed_attention(q, k, v, segment_ids,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None, sink=None,
+                           band: bool = False):
     """Attention of a PACKED prefill call: q/k/v [1, s, h, d] are the
     call's own freshly computed rows, several prompts side by side,
     each from its position 0 with nothing cached behind it, so nothing
@@ -356,11 +427,13 @@ def paged_packed_attention(q, k, v, segment_ids,
     over the call's index within one segment (``segment_ids`` [1, s]),
     and a window counts the same way. Dense, as every chunk's attention
     is: the scores are [h, s, s] over one chunk, a fraction of what the
-    gather over a row's whole table scores."""
+    gather over a row's whole table scores. ``band`` names the scope of
+    a band-keeping layer's call (``_window_scope``)."""
     from ..ops.attention import dense_attention, segment_mask
-    with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
+    with jax.named_scope(_window_scope("chunk_attn", band)):
         return dense_attention(q, k, v, causal=True, window=window,
-                               attn_mask=segment_mask(segment_ids))
+                               attn_mask=segment_mask(segment_ids),
+                               sink=sink)
 
 
 def paged_decode_route(q, kp, kv_heads: int) -> str:
@@ -388,7 +461,7 @@ def _row_positions(pk: PagedKV, T: int, Tk: int):
 
 def paged_decode_attention_dense(q, pk: PagedKV,
                                  scale: Optional[float] = None,
-                                 window: Optional[int] = None):
+                                 window: Optional[int] = None, sink=None):
     """``paged_decode_attention`` by the dense whole-table gather: every
     row gathers all M of its table's pages and masks by position. The
     math is dense_attention's; only the gather and the per-(row,
@@ -405,10 +478,13 @@ def paged_decode_attention_dense(q, pk: PagedKV,
     vs = vs.reshape((R, Tk) + vs.shape[3:])
     kpos, qpos = _row_positions(pk, T, Tk)
     keep = kpos <= qpos                                   # [R, T, Tk]
+    if pk.ring:     # what each ring entry holds after this step's write
+        kpos = _table_positions(pk, pk.seq_lens + T - 1)[:, None, :]
+        keep = (kpos <= qpos) & (kpos >= 0)
     if window is not None:
         keep &= kpos > qpos - window
     return dense_attention(q, ks, vs, attn_mask=keep[:, None],
-                           scale=scale)
+                           scale=scale, sink=sink)
 
 
 def _attend_ragged(q, pk: PagedKV, vp, scale: float, **kw):
@@ -424,7 +500,7 @@ def _attend_ragged(q, pk: PagedKV, vp, scale: float, **kw):
 
 
 def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None, sink=None):
     """q [R, T, h, d] against each row's blocks: query t of row r sits
     at position seq_lens[r] + t and attends tokens 0..seq_lens[r]+t
     (inclusive of the tokens written this step). T == 1 is the plain
@@ -434,13 +510,16 @@ def paged_decode_attention(q, pk: PagedKV, scale: Optional[float] = None,
     The ragged kernel — one step per row, which walks that row's LIVE
     pages, a run of them per compute block, all kv heads at once —
     serves both; where ``paged_decode_route`` says it does not,
-    ``paged_decode_attention_dense`` does."""
-    with jax.named_scope("attn"):           # obs.TICK_SCOPES
+    ``paged_decode_attention_dense`` does. The values may be narrower
+    than the keys (returns [R, T, h, d_v]); ``sink`` [h] joins each
+    head's softmax denominator; a band-keeping layer's ``pk.ring`` table
+    is walked as the ring it is, under a scope of its own."""
+    with jax.named_scope(_window_scope("attn", pk.ring)):
         if paged_decode_route(q, pk.kp, pk.heads) == "dense":
-            return paged_decode_attention_dense(q, pk, scale, window)
+            return paged_decode_attention_dense(q, pk, scale, window, sink)
         return _attend_ragged(
             q, pk, pk.vp, scale if scale is not None else pk.width ** -0.5,
-            window=window)
+            window=window, sink=sink, ring=pk.ring)
 
 
 def paged_latent_attention_dense(q, pk: PagedKV, v_width: int,
@@ -763,9 +842,15 @@ class PagedEngine:
     lanes). The model says what a cached row is (``paged_cache_rows``)
     and, where a layer has more than one attention, how many of them a
     token has (``paged_cache_layers``: LongCat-Flash's two latent
-    attentions a layer are two cache layers);
+    attentions a layer are two cache layers), or, where its layers
+    differ, what each is (a list of ``CacheLayer``: MiMo-V2's full
+    layers cache 4 kv heads, its window layers 8, keys wider than
+    values, and a window layer keeps its band only);
     allocation, writes, prefix adoption, spill, upload and reset are one
-    code path over a cache layer's tuple of pool arrays.
+    code path over a cache layer's tuple of pool arrays. A band-keeping
+    layer owns no block of the allocator's: its pool is a ring of
+    ``_ring_blocks`` pages a slot (window + chunk), so its pages are
+    released and reused by position alone (docs/SERVING.md).
 
     submit() enqueues requests at any time; each step() admits what
     fits (slot + blocks), prefills at most one queued request, and
@@ -820,6 +905,13 @@ class PagedEngine:
         # slot each and a block each at the least
         self._pack_segments = None if self.chunk is None \
             else min(max_slots, self.chunk // block_size)
+        self._spec_k = int(spec_tokens)
+        # what each cache layer is; the windows of those that keep a
+        # band only (none in most models: everything below that reads
+        # ``_windows`` is then what it was)
+        self._layout = self._cache_layout()
+        self._windows = tuple(l.window for l in self._layout
+                              if l.window is not None)
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -835,6 +927,13 @@ class PagedEngine:
                 "enable_prefix_cache requires chunk_prefill_tokens: "
                 "chunk-grid-aligned recompute is what makes reused and "
                 "freshly computed K/V bit-identical")
+        if enable_prefix_cache and self._windows:
+            raise ValueError(
+                "enable_prefix_cache: this model has layers that keep "
+                "only their window's band of a sequence; a prefix's "
+                "blocks there were released as the prompt advanced, so "
+                "there is nothing to adopt (adoption over band-keeping "
+                "layers is not built)")
         self.prefix_caching = bool(enable_prefix_cache)
         self.prefix_cache: Dict[tuple, tuple] = {}   # key -> block ids
         self._prefix_rev: Dict[int, set] = {}        # block -> keys
@@ -883,8 +982,10 @@ class PagedEngine:
         # layer's assignments and experts hit): they ride a spare row of
         # the token ring, so the drain fetches nothing more for them
         # (the host reference has no ring and does not count them)
-        self._tick_counter_names = tuple(
+        self._model_counter_names = tuple(
             getattr(model, "tick_counters", tuple)())
+        self._tick_counter_names = self._model_counter_names + (
+            _BAND_COUNTERS if self._windows else ())
         self._tick_counts_seen = np.zeros(
             (len(self._tick_counter_names),), np.int64)
         # runahead_ticks: decode dispatches made while another was
@@ -1023,7 +1124,6 @@ class PagedEngine:
         # penalty scan); a row falls back to the 1-token tick
         # per-request (inside the same program) when block headroom is
         # missing or its accept-rate EMA collapses.
-        self._spec_k = int(spec_tokens)
         self._spec_ngram = int(spec_ngram)
         if self._spec_k:
             if self._spec_k < 1:
@@ -1049,7 +1149,8 @@ class PagedEngine:
         # outstanding dispatches can commit with double-buffer slack:
         # twice a dispatch's advance (the spec window k+1; a plain tick
         # commits 1 and at most two of them are outstanding).
-        self._ring_len = max(16, 2 * (self._spec_k + 1))
+        self._ring_len = max(16, 2 * (self._spec_k + 1),
+                             len(self._tick_counter_names))
         # the dispatches not yet drained, oldest first: one between
         # steps, two inside a run-ahead step (_may_run_ahead). Each
         # record keeps ITS program's ring / cursor / active outputs
@@ -1136,14 +1237,37 @@ class PagedEngine:
         cfg = self.model.config
         return ((cfg.num_key_value_heads, cfg.head_dim),) * 2
 
-    def _cache_layers(self) -> int:
-        """How many cached rows a token has through the model, asked of
-        it too (``paged_cache_layers``): one per attention sublayer,
-        which is one per layer unless the model says otherwise (a layer
-        with two attentions presents two)."""
+    def _cache_layout(self) -> List[CacheLayer]:
+        """The model's cache layers in order, asked of it too
+        (``paged_cache_layers``): a count (one per attention sublayer,
+        which is one per layer unless the model says otherwise: a layer
+        with two attentions presents two) of layers that all cache
+        ``_cache_rows``, or, where they differ, what each one is."""
         ask = getattr(self.model, "paged_cache_layers", None)
-        return ask() if ask is not None \
+        layers = ask() if ask is not None \
             else self.model.config.num_hidden_layers
+        if isinstance(layers, int):
+            return [CacheLayer(tuple(self._cache_rows()))] * layers
+        return [CacheLayer(*layer) for layer in layers]
+
+    def _ring_blocks(self, window: int) -> int:
+        """Pages a slot of a band-keeping layer's ring: the window, what
+        one call writes ahead of it (a prompt chunk, a speculative
+        tick's positions) and the page the band's front shares; never
+        more than a sequence has."""
+        ahead = max(self.chunk or 0, self._spec_k + 1)
+        return min(self.M, -(-window // self.B) + -(-ahead // self.B) + 1)
+
+    def _band_behind(self, cached: int) -> int:
+        """Pages, over the band-keeping layers, that lie wholly behind
+        the band of a row's next query when ``cached`` tokens are in."""
+        return sum(max(cached + 1 - w, 0) // self.B for w in self._windows)
+
+    def _band_live(self, cached: int) -> int:
+        """Pages, over the band-keeping layers, still inside the bands
+        of a row that has ``cached`` tokens in."""
+        return len(self._windows) * self._blocks_needed(cached) \
+            - self._band_behind(cached)
 
     def _fresh_device_arrays(self):
         """New pools (per cache layer one [P, B, heads*width] array for
@@ -1154,23 +1278,31 @@ class PagedEngine:
         jitted decode step). ``hard_reset`` takes fresh ones too: the
         old arrays may be donated into a dead or in-flight program."""
         cfg = self.model.config
-        pools = [tuple(self._zeros((self.P, self.B, heads * width),
-                                   cfg.dtype)
-                       for heads, width in self._cache_rows())
-                 for _ in range(self._cache_layers())]
+        pools = []
+        for layer in self._layout:
+            # a band-keeping layer: a ring a slot and the garbage block
+            P = self.P if layer.window is None \
+                else self.R * self._ring_blocks(layer.window) + 1
+            pools.append(tuple(
+                self._zeros((P, self.B, heads * width), cfg.dtype)
+                for heads, width in layer.rows))
         return pools, self._zeros((self.R, cfg.vocab_size), bool)
 
     def decode_route(self) -> str:
         """The attention path this engine's decode tick takes
-        (``paged_decode_route`` asked with the tick's own q and pool
-        shapes): "ragged" is the Pallas kernel, "dense" the XLA
-        whole-table gather."""
+        (``paged_decode_route`` asked with the tick's own q and every
+        cache layer's pool shapes): "ragged" is the Pallas kernel,
+        "dense" the XLA whole-table gather, which is the answer as soon
+        as ONE layer takes it."""
         cfg = self.model.config
-        heads, width = self._cache_rows()[0]    # a query is as wide as
-        q = jax.ShapeDtypeStruct(               # a cached head
-            (self.R, self._spec_k + 1, cfg.num_attention_heads, width),
-            cfg.dtype)
-        return paged_decode_route(q, self.pools[0][0], heads)
+        routes = set()
+        for layer, pool in zip(self._layout, self.pools):
+            heads, width = layer.rows[0]        # a query is as wide as
+            q = jax.ShapeDtypeStruct(           # a cached (key) head
+                (self.R, self._spec_k + 1, cfg.num_attention_heads, width),
+                cfg.dtype)
+            routes.add(paged_decode_route(q, pool[0], heads))
+        return "ragged" if routes == {"ragged"} else "dense"
 
     # ------------------------------------------------------ tick profiler
     @property
@@ -1261,11 +1393,24 @@ class PagedEngine:
         self._counters[key].inc(n)
 
     # ------------------------------------------------------------ jitted
-    def _paged_caches(self, pools, tables, lens):
-        heads = self._cache_rows()[0][0]
-        return [PagedKV(p[0], p[1] if len(p) > 1 else None, tables, lens,
-                        heads)
-                for p in pools]
+    def _paged_caches(self, pools, tables, lens, slots=None):
+        """Each cache layer's view of ``pools`` for the rows of
+        ``tables`` [rows, M], which are the engine's slots in order
+        unless ``slots`` [rows] names them. A band-keeping layer's table
+        is not the allocator's: it is the ring of pages its slot owns
+        by position, block 0 of its pool being the garbage block."""
+        out = []
+        for layer, p in zip(self._layout, pools):
+            tbl = tables
+            if layer.window is not None:
+                Mw = self._ring_blocks(layer.window)
+                rows = jnp.arange(tables.shape[0]) if slots is None \
+                    else slots
+                tbl = 1 + rows[:, None] * Mw + jnp.arange(Mw)[None, :]
+            out.append(PagedKV(p[0], p[1] if len(p) > 1 else None, tbl,
+                               lens, layer.rows[0][0],
+                               layer.window is not None))
+        return out
 
     def _decode_step(self, params, pools, tables, lens, last_tokens,
                      keys, temps, tks, tps, seen, reps, active):
@@ -1310,17 +1455,43 @@ class PagedEngine:
         names (``tick_counters``), over the forward traced inside the
         ``with``; ``.total`` is None for a model that names none, and
         the program is then what it was."""
-        if not self._tick_counter_names:
+        if not self._model_counter_names:
             return contextlib.nullcontext(_NO_COUNTS)
         return self.model.count_tick(st["active"])
 
-    def _ring_counts(self, ring, counts):
-        """The tick's counters added into the ring's spare row, which
-        the drain reads with the tokens: no array of their own."""
-        if counts.total is None:
+    def _band_counts(self, st):
+        """``_BAND_COUNTERS`` of this tick (None for an engine without
+        band-keeping layers): a live row's query at position ``lens``
+        sees ``lens + 1`` tokens in a whole-context layer and at most
+        the window of them in a band-keeping one."""
+        if not self._windows:
+            return None
+        seen = st["lens"] + 1
+        pages = (seen + self.B - 1) // self.B
+        zero = jnp.zeros_like(seen)
+        wb, fb, wt, ft, rel = zero, zero, zero, zero, zero
+        for layer in self._layout:
+            w = layer.window
+            if w is None:
+                fb, ft = fb + pages, ft + seen
+                continue
+            behind = jnp.maximum(seen - w, 0) // self.B
+            wb, wt = wb + pages - behind, wt + jnp.minimum(seen, w)
+            rel = rel + jnp.maximum(seen + 1 - w, 0) // self.B - behind
+        live = st["active"].astype(seen.dtype)
+        return jnp.stack([jnp.sum(c * live) for c in (wb, fb, wt, ft, rel)])
+
+    def _ring_counts(self, ring, counts, st):
+        """The tick's counters (the model's, then the engine's own of a
+        band-keeping cache) added into the ring's spare row, which the
+        drain reads with the tokens: no array of their own."""
+        parts = [c.astype(ring.dtype)
+                 for c in (counts.total, self._band_counts(st))
+                 if c is not None]
+        if not parts:
             return ring
-        n = len(self._tick_counter_names)
-        return ring.at[self.R, :n].add(counts.total.astype(ring.dtype))
+        total = jnp.concatenate(parts)
+        return ring.at[self.R, :total.shape[0]].add(total)
 
     def _fused_epilogue(self, st, new_caches, seen, nxt, lps, new_keys,
                         counts):
@@ -1348,7 +1519,7 @@ class PagedEngine:
         idx = st["wcur"] % st["ring"].shape[1]
         new_st.update(
             ring=self._ring_counts(st["ring"].at[r, idx].set(
-                jnp.where(act, nxt, st["ring"][r, idx])), counts),
+                jnp.where(act, nxt, st["ring"][r, idx])), counts, st),
             rlps=st["rlps"].at[r, idx].set(
                 jnp.where(act, lps, st["rlps"][r, idx])),
             wcur=st["wcur"] + acti)
@@ -1556,7 +1727,7 @@ class PagedEngine:
                 st["ring"].at[r_idx[:, None], idx].set(
                     jnp.where(emit_win, G,
                               st["ring"][r_idx[:, None], idx])),
-                counts),
+                counts, st),
             rlps=st["rlps"].at[r_idx[:, None], idx].set(
                 jnp.where(emit_win, LP, st["rlps"][r_idx[:, None], idx])),
             wcur=st["wcur"] + n_eff,
@@ -1851,11 +2022,12 @@ class PagedEngine:
         self._dev_dirty = False
 
     def _prefill(self, params, pools, table_row, ids, length, key,
-                 temp, tk, tp, rep, *, bucket: int):
+                 temp, tk, tp, rep, slot, *, bucket: int):
         from .sampling import repetition_penalty_rows, sample_token_rows
         tables = jnp.broadcast_to(table_row[None], (1, self.M))
         lens = jnp.asarray([length], jnp.int32)
-        caches = self._paged_caches(pools, tables, lens)
+        caches = self._paged_caches(pools, tables, lens,
+                                    jnp.asarray(slot, jnp.int32)[None])
         positions = jnp.arange(bucket)[None, :]
         logits, new_caches = self.fn(params, ids, kv_caches=caches,
                                      positions=positions)
@@ -1873,8 +2045,8 @@ class PagedEngine:
                 [c.pool for c in new_caches])
 
     def _chunk_prefill(self, params, pools, table_row, ids, start,
-                       total_len, key, temp, tk, tp, rep, seen_row, *,
-                       bucket: int):
+                       total_len, key, temp, tk, tp, rep, seen_row,
+                       slot=np.int32(0), *, bucket: int):
         """One prompt chunk at global positions [start, start+bucket):
         writes its K/V (live = positions < total_len) and attends to the
         already-cached chunks. The chosen-token sample at the last live
@@ -1882,11 +2054,13 @@ class PagedEngine:
         keeps it — and the advanced key — for the final chunk, so a
         request still consumes exactly one split per emitted token. The
         seen mask accumulates each chunk's live ids (prefix-cache-skipped
-        chunks were seeded at admission)."""
+        chunks were seeded at admission). ``slot`` is read by a
+        band-keeping layer alone: its ring is its slot's."""
         from .sampling import repetition_penalty_rows, sample_token_rows
         tables = jnp.broadcast_to(table_row[None], (1, self.M))
         lens = jnp.asarray([total_len], jnp.int32)
-        caches = self._paged_caches(pools, tables, lens)
+        caches = self._paged_caches(pools, tables, lens,
+                                    jnp.asarray(slot, jnp.int32)[None])
         positions = start + jnp.arange(bucket)[None, :]
         logits, new_caches = self.fn(params, ids, kv_caches=caches,
                                      positions=positions,
@@ -1930,7 +2104,7 @@ class PagedEngine:
             temps, tps, reps = jax.lax.bitcast_convert_type(
                 sg[:, M + 5:M + 8], jnp.float32).T
             keys = jax.lax.bitcast_convert_type(sg[:, M + 8:], jnp.uint32)
-        caches = self._paged_caches(pools, tables, lens)
+        caches = self._paged_caches(pools, tables, lens, slots)
         logits, new_caches = self.fn(params, ids[None], kv_caches=caches,
                                      positions=pos[None],
                                      segment_ids=seg[None])
@@ -2127,7 +2301,14 @@ class PagedEngine:
         :class:`~..serving.kvspill.KVSpillArena`. Called by the owner of
         the arena — the gateway worker — at engine construction AND
         after every supervisor rebuild, which is the whole point: the
-        arena's spans outlive this engine."""
+        arena's spans outlive this engine. Refused for a model with
+        band-keeping layers, as prefix adoption is: a span's pages there
+        were released as its prompt advanced."""
+        if arena is not None and self._windows:
+            raise ValueError(
+                "attach_spill: this model has layers that keep only "
+                "their window's band of a sequence; a span's blocks "
+                "there are gone before it could be spilled")
         self._spill = arena
 
     def _spill_geometry(self) -> tuple:
@@ -2534,7 +2715,7 @@ class PagedEngine:
             self._put(padded), np.int32(len(ids)),
             self._put(req.key), np.float32(req.temperature),
             np.int32(req.top_k), np.float32(req.top_p),
-            np.float32(req.rep), bucket=bucket)
+            np.float32(req.rep), np.int32(slot_id), bucket=bucket)
         self.seen = self.seen.at[slot_id].set(seen_row)
         self._count("prefills")
         first = int(nxt)
@@ -2673,7 +2854,8 @@ class PagedEngine:
                     np.int32(start + live), key,
                     np.float32(req.temperature), np.int32(req.top_k),
                     np.float32(req.top_p), np.float32(req.rep),
-                    self.seen[slot_id], bucket=self.chunk)
+                    self.seen[slot_id], np.int32(slot_id),
+                    bucket=self.chunk)
             self._count("prefill_chunks")
             self._count("prefill_segments")
             # mid chunks keep the ids-only mask; the final chunk's
@@ -2703,6 +2885,10 @@ class PagedEngine:
         if self.trace_sink is not None:
             self.trace_sink(req.request_id, "prefill_chunk",
                             start=req.prefill_pos, tokens=live)
+        if self._windows:       # pages the prompt's advance left behind
+            self._count("kv_window_blocks_released",
+                        self._band_behind(req.prefill_pos + live)
+                        - self._band_behind(req.prefill_pos))
         req.prefill_pos += live
         self.seq_lens[slot_id] = req.prefill_pos
         if first is None:
@@ -2787,6 +2973,9 @@ class PagedEngine:
     def _release(self, slot_id: int):
         for b in self.slots[slot_id].blocks:
             self._release_block(b)
+        if self._windows:       # what was still inside the bands
+            self._count("kv_window_blocks_released",
+                        self._band_live(int(self.seq_lens[slot_id])))
         self.block_tables[slot_id] = 0
         self.seq_lens[slot_id] = 0
         self.temps[slot_id] = 0.0
@@ -2931,6 +3120,12 @@ class PagedEngine:
             free_blocks=len(self.free_blocks),
             cached_free_blocks=len(self.cached_free),
             total_blocks=self.P - 1,
+            # pages inside the bands of the band-keeping layers (0
+            # without such layers), off the host's mirrors
+            window_blocks_live=sum(
+                self._band_live(int(n))
+                for n, s in zip(self.seq_lens, self.slots)
+                if s is not None) if self._windows else 0,
             spill_attached=self._spill is not None,
             results_pending=len(self.results),
             aborted=len(self.cancelled))

@@ -64,12 +64,15 @@ def segment_mask(segment_ids):
 
 def dense_attention(query, key, value, attn_mask=None, dropout_p=0.0,
                     causal=False, scale=None, dropout_key=None,
-                    window=None):
+                    window=None, sink=None):
     """XLA-fused dense path, [b, s, h, d]; fp32 softmax; GQA-aware.
     Single source of truth for the non-flash math (nn.functional's
     scaled_dot_product_attention fallback routes here). ``window``
     (with causal) keeps only the trailing ``window`` keys per query —
-    sliding-window attention (Qwen2/Mistral)."""
+    sliding-window attention (Qwen2/Mistral). ``sink`` [h]: a learned
+    score a head that joins its softmax's denominator and carries no
+    value (an attention sink: one more key whose value is zero). The
+    values may be narrower or wider than the keys."""
     b, sq, h, d = query.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     q = jnp.swapaxes(query, 1, 2)
@@ -96,7 +99,14 @@ def dense_attention(query, key, value, attn_mask=None, dropout_p=0.0,
             scores = jnp.where(attn_mask, scores, -jnp.inf)
         else:
             scores = scores + attn_mask.astype(scores.dtype)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None],
+            scores.shape[:-1] + (1,))
+        scores = jnp.concatenate([scores, col], axis=-1)
     probs = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
+    if sink is not None:
+        probs = probs[..., :-1]
     if dropout_p > 0.0 and dropout_key is not None:
         keep = 1.0 - dropout_p
         dmask = jax.random.bernoulli(dropout_key, keep, probs.shape)

@@ -40,6 +40,24 @@ compressed latent, the shared rope key, zero padding up to a multiple of
 128 lanes), which every query head reads: one kv "head", the query heads
 its group. The values are the first ``v_width`` columns of the keys, so
 the kernel keeps no V scratch and a page is fetched once.
+
+Three more things a call may say (MiMo-V2's two layer kinds; a call
+that says none of them lowers to what it lowered to before):
+
+- the value heads may be narrower than the key heads (``vp`` [P, B,
+  kvh*d_v]); a key head of 192 columns, one and a half lane tiles, is
+  read as the ALIGNED 256-column span of the page that holds it, and the
+  query arrives padded with zeros over the span's other 64 columns (they
+  belong to the neighbouring head): no unaligned slice, no padding in
+  the pool, 64 columns more in each score product.
+- ``sink`` [h]: one learned score a query head that joins the softmax's
+  denominator and carries no value. It is the online softmax's START:
+  running max ``sink``, sum 1, accumulator 0 is the state after one key
+  of that score and a zero value.
+- ``ring``: the table is a RING of M pages over the row's logical
+  blocks (a layer that keeps only its window's band: logical block b
+  lives in ``table[r, b % M]``); positions still count from the
+  sequence's start.
 """
 from __future__ import annotations
 
@@ -71,16 +89,19 @@ def use_ragged_kernel(q, kp, kv_heads: int) -> bool:
     ``(B, kv_heads*d)`` slab, which Mosaic slices from HBM in whole
     128-lane tiles (one kv head of 64 columns cannot); and heads of 128
     or 256 columns, or one head of any whole number of tiles (a latent
-    pool: one wide row a token)."""
+    pool: one wide row a token), or an even number of heads of 192 (each
+    head then lies inside an aligned 256-column span of the page)."""
     from . import kernels_enabled
     h, d = q.shape[2:]
     if h % kv_heads or not kernels_enabled():
         return False
     if _interpret():
         return True
-    if kp.shape[1] % 8 or kp.shape[2] % 128 or d % 128:
+    if kp.shape[1] % 8 or kp.shape[2] % 128:
         return False
-    return kv_heads == 1 or d in (128, 256)
+    if d == 192:
+        return kv_heads % 2 == 0
+    return d % 128 == 0 and (kv_heads == 1 or d in (128, 256))
 
 
 def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:
@@ -93,8 +114,20 @@ def _pages_per_step(B: int, width: int, itemsize: int, M: int) -> int:
     return min(pps, M)
 
 
+def _key_span(h: int, d_k: int, dq: int) -> slice:
+    """The columns of a page that kv head ``h``'s query is multiplied
+    with: the head's own ``d_k`` where that is whole lane tiles, else
+    the aligned ``dq``-column span that contains it."""
+    start = h * d_k // 128 * 128 if dq != d_k else h * d_k
+    return slice(start, start + dq)
+
+
 def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
-                   group, q_len, v_width):
+                   group, q_len, v_width, d_k=None, ring=False,
+                   sink=False):
+    sink_ref = None
+    if sink:
+        sink_ref, *refs = refs
     if v_width is None:
         k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
     else:       # latent mode: values are a column prefix of the keys
@@ -103,12 +136,15 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
     r = pl.program_id(0)
     M = tbl_ref.shape[1]
     kvh, gp, d = q_ref.shape[1:]
-    dv = d if v_width is None else v_width
+    d_k = d if d_k is None else d_k
+    dv = o_ref.shape[-1]
     tc = pps * bs
     # query t of the row sits at seq_len + t: live pages cover the LAST
     # query's tokens, a sliding window's front follows the FIRST
     valid = len_ref[r] + 1
-    hi = jnp.clip((valid + q_len - 1 + bs - 1) // bs, 1, M)
+    hi = jnp.maximum((valid + q_len - 1 + bs - 1) // bs, 1)
+    if not ring:            # a ring's logical blocks run past its pages
+        hi = jnp.minimum(hi, M)
     lo = 0 if window is None else jnp.maximum(valid - window, 0) // bs
     n_blocks = (hi - lo + pps - 1) // pps
 
@@ -122,7 +158,7 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
         first = lo + i * pps
 
         def page(j, carry):
-            phys = tbl_ref[r, first + j]
+            phys = tbl_ref[r, (first + j) % M if ring else first + j]
             dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
             fn(pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, dst],
                                      sems.at[slot, 0]))
@@ -155,9 +191,8 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
             keep &= k_ids >= valid + t_of - window
         out = []
         for h, (m_prev, l_prev, acc) in enumerate(carry):
-            cols = slice(h * d, (h + 1) * d)
-            k = k_buf[slot, :, cols]                     # [tc, d]
-            v = v_buf[slot, :, cols] if v_width is None \
+            k = k_buf[slot, :, _key_span(h, d_k, d)]     # [tc, d]
+            v = v_buf[slot, :, h * dv:(h + 1) * dv] if v_width is None \
                 else k[:, :dv]
             s = lax.dot_general(q_ref[0, h], k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -173,24 +208,35 @@ def _ragged_kernel(tbl_ref, len_ref, q_ref, *refs, scale, bs, pps, window,
                     preferred_element_type=jnp.float32)))
         return tuple(out)
 
-    init = (jnp.full((gp, 1), NEG_INF, jnp.float32),
-            jnp.zeros((gp, 1), jnp.float32),
-            jnp.zeros((gp, dv), jnp.float32))
-    heads = lax.fori_loop(0, n_blocks, block, (init,) * kvh)
+    def init(h):
+        acc = jnp.zeros((gp, dv), jnp.float32)
+        if sink_ref is None:
+            return (jnp.full((gp, 1), NEG_INF, jnp.float32),
+                    jnp.zeros((gp, 1), jnp.float32), acc)
+        # the state after one key whose score is the sink, value zero
+        return sink_ref[h][:, :1], jnp.ones((gp, 1), jnp.float32), acc
+
+    heads = lax.fori_loop(0, n_blocks, block,
+                          tuple(init(h) for h in range(kvh)))
     for h, (_, l, acc) in enumerate(heads):
         o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
                                   scale, kv_heads, window=None,
-                                  v_width=None):
+                                  v_width=None, sink=None,
+                                  ring: bool = False):
     """q [R, h, d] (single-query decode) OR [R, T, h, d] (multi-query
     speculative verify rows: query t of row r sits at position
     seq_lens[r] + t and attends tokens 0..seq_lens[r]+t); kp/vp
     [P, B, kv_heads*d] physical pools; block_tables [R, M]; seq_lens
     [R]. Returns q's shape. Latent mode: ``vp`` None and ``v_width`` the
     number of leading key columns that are the values; returns
-    [..., h, v_width].
+    [..., h, v_width]. ``vp`` may hold narrower heads than ``kp``
+    (returns [..., h, d_v]); ``sink`` [h] float32 joins each head's
+    softmax denominator; ``ring`` reads ``block_tables`` [R, M] as a ring
+    over each row's logical blocks (it needs a ``window`` no longer than
+    the ring holds).
 
     Multi-query rides the same walk: the q tile packs T positions x
     `group` heads into the sublane dim (padded to 8), so each page is
@@ -201,9 +247,11 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
     if kp.ndim != 3 or kp.shape[2] != kv_heads * q.shape[-1]:
         raise ValueError(f"pool {kp.shape} is not [P, B, {kv_heads} kv "
                          f"heads x {q.shape[-1]} columns]")
-    return _attend(q, kp, vp, block_tables, seq_lens, scale=float(scale),
-                   kvh=int(kv_heads), window=window,
-                   interpret=_interpret(), v_width=v_width)
+    if ring and window is None:
+        raise ValueError("a ring table holds a window's band: give window")
+    return _attend(q, kp, vp, block_tables, seq_lens, sink,
+                   scale=float(scale), kvh=int(kv_heads), window=window,
+                   interpret=_interpret(), v_width=v_width, ring=bool(ring))
 
 
 # jitted so that a program of L layers traces the kernel body once, not
@@ -212,9 +260,9 @@ def ragged_paged_attention_pallas(q, kp, vp, block_tables, seq_lens,
 # of its ops (obs.TICK_SCOPES) are what they are without the jit.
 @functools.partial(jax.jit, inline=True,
                    static_argnames=("scale", "kvh", "window", "interpret",
-                                    "v_width"))
-def _attend(q, kp, vp, block_tables, seq_lens, *, scale, kvh, window,
-            interpret, v_width=None):
+                                    "v_width", "ring"))
+def _attend(q, kp, vp, block_tables, seq_lens, sink=None, *, scale, kvh,
+            window, interpret, v_width=None, ring=False):
     multi = q.ndim == 4
     if multi:
         R, T, h, d = q.shape
@@ -224,12 +272,21 @@ def _attend(q, kp, vp, block_tables, seq_lens, *, scale, kvh, window,
     B = kp.shape[1]
     M = block_tables.shape[1]
     latent = v_width is not None
-    dv = v_width if latent else d
+    dv = v_width if latent else vp.shape[2] // kvh
     group = h // kvh
     rows = T * group
     gp = max(8, -(-rows // 8) * 8)
     pps = _pages_per_step(B, kvh * d, kp.dtype.itemsize, M)
-
+    # a key head that is not whole lane tiles is read as the aligned
+    # span of the page that holds it (`_key_span`), the query padded to
+    # it, where every head lies in such a span (192 columns, an even
+    # number of heads). Other odd widths reach here in interpret mode
+    # alone (`use_ragged_kernel`) and are sliced as they are.
+    dq = d if latent else -(-d // 128) * 128
+    front = [hd * d - _key_span(hd, d, dq).start for hd in range(kvh)]
+    if dq != d and (max(front) + d > dq
+                    or _key_span(kvh - 1, d, dq).stop > kvh * d):
+        dq = d
     if multi:
         # [R, T, kvh, group, d] -> [R, kvh, T*group, d]: position-major
         # sublanes so the kernel's t = sublane // group mapping holds
@@ -239,30 +296,45 @@ def _attend(q, kp, vp, block_tables, seq_lens, *, scale, kvh, window,
         qg = q.reshape(R, kvh, group, d)
     if gp != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - rows), (0, 0)))
+    if dq != d:
+        qg = jnp.stack([jnp.pad(qg[:, hd], ((0, 0), (0, 0),
+                                            (f, dq - d - f)))
+                        for hd, f in enumerate(front)], axis=1)
 
     kernel = functools.partial(_ragged_kernel, scale=scale, bs=B, pps=pps,
                                window=window, group=group, q_len=T,
-                               v_width=v_width)
+                               v_width=v_width, d_k=d, ring=ring,
+                               sink=sink is not None)
     pools = (kp,) if latent else (kp, vp)
+    extra, extra_specs = (), []
+    if sink is not None:
+        # [h] -> a row a sublane of the q tile (position-major, as q),
+        # broadcast over one lane tile
+        sk = jnp.tile(sink.astype(jnp.float32).reshape(kvh, group), (1, T))
+        sk = jnp.pad(sk, ((0, 0), (0, gp - rows)))
+        extra = (jnp.broadcast_to(sk[:, :, None], (kvh, gp, 128)),)
+        extra_specs = [pl.BlockSpec((kvh, gp, 128),
+                                    lambda r, tbl, lens: (0, 0, 0))]
     out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R,),
-            in_specs=[pl.BlockSpec((1, kvh, gp, d),
+            in_specs=[pl.BlockSpec((1, kvh, gp, dq),
                                    lambda r, tbl, lens: (r, 0, 0, 0))]
+            + extra_specs
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec((1, kvh, gp, dv),
                                    lambda r, tbl, lens: (r, 0, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, pps * B, kvh * d), p.dtype)
+            scratch_shapes=[pltpu.VMEM((2, pps * B, p.shape[2]), p.dtype)
                             for p in pools]
             + [pltpu.SemaphoreType.DMA((2, 2))],
         ),
         out_shape=jax.ShapeDtypeStruct((R, kvh, gp, dv), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
-      qg, *pools)
+      qg, *extra, *pools)
     out = out[:, :, :rows, :]
     if not multi:
         return out.reshape(R, h, dv)

@@ -959,9 +959,14 @@ TICK_SCOPES = (
     "absorb",      # latent attention: queries through W_uk, output
                    # through W_uv, either side of the kernel
     "kv_write",    # the new rows scattered into the pool
-    "attn",        # decode: schedule build and kernel
+    "attn",        # decode: schedule build and kernel (in a model
+                   # with band-keeping layers: its whole-context layers')
+    "attn_window",     # decode: the kernel over a band-keeping (sliding
+                       # window) layer's ring, its sink folded in
     "chunk_attn",  # chunk: gather of the row's blocks, masked attention
                    # (latent attention: their expansion to K and V too)
+    "chunk_attn_window",   # chunk: the same over a band-keeping layer's
+                           # ring: the band behind the chunk and the chunk
     "o_proj",
     "mlp",         # a dense FFN; of an expert layer the residual add
     "router",      # expert layer: float32 scores, groups, top-k, gates

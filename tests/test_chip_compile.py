@@ -64,6 +64,35 @@ def test_ragged_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("R,M,P,kvh,window,ring,T", [
+    (64, 128, 8193, 4, None, False, 1),     # a full layer's tick
+    (64, 25, 1601, 8, 128, True, 1),        # a window layer's, its ring
+    (64, 25, 1601, 8, 128, True, 3),        # and a speculative verify
+])
+def test_unequal_head_kernel_compiles_for_a_v5e(
+        one_chip, no_persistent_cache, monkeypatch, R, M, P, kvh, window,
+        ring, T):
+    """MiMo-V2's two layer kinds: 64 query heads, key heads of 192
+    columns (read as aligned 256-column spans of the page) and value
+    heads of 128; a window layer adds its sink and walks its band's
+    ring of 25 pages a slot."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_pallas
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q = arr((R, 64, 192) if T == 1 else (R, T, 64, 192))
+    sink = arr((64,), jnp.float32) if ring else None
+    compiled = jax.jit(
+        lambda q, kp, vp, tbl, lens, sink: ragged_paged_attention_pallas(
+            q, kp, vp, tbl, lens, 192 ** -0.5, kvh, window=window,
+            sink=sink, ring=ring)).lower(
+        q, arr((P, 16, kvh * 192)), arr((P, 16, kvh * 128)),
+        arr((R, M), jnp.int32), arr((R,), jnp.int32), sink).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("R,T", [(64, 1), (64, 2)])
 def test_latent_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
                                           monkeypatch, R, T):

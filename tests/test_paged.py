@@ -700,3 +700,77 @@ class TestStreaming:
         assert set(got) == {"b"}, got.keys()
         np.testing.assert_array_equal(np.asarray(got["b"]),
                                       _greedy_new(model, b, 6))
+
+
+class TestCacheLayers:
+    """ISSUE 32: the model says what each cache layer is. The families
+    that answered before get the pools they got (one shape every layer,
+    ``num_blocks`` pages, a K/V pair or one latent row); a model whose
+    layers differ gets a pool a layer, a band-keeping layer's a ring of
+    pages a slot; ``decode_route`` answers for every layer's shapes."""
+
+    @staticmethod
+    def _built(family):
+        from paddle_tpu.models.deepseek_v2 import (DeepseekV2ForCausalLM,
+                                                   deepseek_v2_tiny)
+        from paddle_tpu.models.longcat_flash import (
+            LongcatFlashForCausalLM, longcat_flash_tiny)
+        from paddle_tpu.models.mimo_v2 import (MiMoV2ForCausalLM,
+                                               mimo_v2_tiny)
+        pt.seed(0)
+        return {
+            "llama": lambda: LlamaForCausalLM(llama_tiny()),
+            "deepseek": lambda: DeepseekV2ForCausalLM(deepseek_v2_tiny()),
+            "longcat": lambda: LongcatFlashForCausalLM(
+                longcat_flash_tiny(num_hidden_layers=1)),
+            "mimo": lambda: MiMoV2ForCausalLM(mimo_v2_tiny()),
+        }[family]()
+
+    @pytest.mark.parametrize("family,pools", [
+        # 2 layers x (K, V) of 2 kv heads x 16 columns
+        ("llama", [((32, 8, 32), (32, 8, 32))] * 2),
+        # 2 layers x one latent row: 32 + 8 columns padded to 128
+        ("deepseek", [((32, 8, 128),)] * 2),
+        # one double layer: two latent rows
+        ("longcat", [((32, 8, 128),)] * 2),
+        # a full layer (1 kv head; keys 24, values 16) by the allocator's
+        # pages; two window layers (2 kv heads) by a ring of window 2 +
+        # chunk 2 + 1 pages a slot and the garbage block
+        ("mimo", [((32, 8, 24), (32, 8, 16)),
+                  ((21, 8, 48), (21, 8, 32)), ((21, 8, 48), (21, 8, 32))]),
+    ])
+    def test_each_familys_pools(self, family, pools):
+        eng = _engine(self._built(family), chunk_prefill_tokens=16)
+        assert [tuple(p.shape for p in layer) for layer in eng.pools] \
+            == pools
+        assert [layer.window for layer in eng._layout] \
+            == ([None, 12, 12] if family == "mimo" else [None] * len(pools))
+        # the extra tick counters exist only where a band is kept
+        assert ("kv_window_blocks" in eng.stats) == (family == "mimo")
+
+    def test_decode_route_answers_for_every_layer(self, monkeypatch):
+        """"ragged" only if EVERY cache layer's shapes take the kernel:
+        an engine whose window layers did not would serve them by the
+        dense gather while its first layer said "ragged"."""
+        from paddle_tpu.generation import paged
+        eng = _engine(self._built("mimo"), chunk_prefill_tokens=16)
+        asked = []
+
+        def route(q, kp, kv_heads):
+            asked.append((q.shape[-1], kp.shape, kv_heads))
+            return "dense" if kv_heads == 2 else "ragged"
+        monkeypatch.setattr(paged, "paged_decode_route", route)
+        assert eng.decode_route() == "dense"
+        assert asked == [(24, (32, 8, 24), 1), (24, (21, 8, 48), 2),
+                         (24, (21, 8, 48), 2)]
+        monkeypatch.setattr(paged, "paged_decode_route",
+                            lambda q, kp, kv_heads: "ragged")
+        assert eng.decode_route() == "ragged"
+
+    def test_a_ring_is_never_longer_than_a_sequence(self):
+        """Where the window and a chunk already cover a whole sequence
+        the ring is the sequence's own pages and never wraps."""
+        eng = _engine(self._built("mimo"), max_blocks_per_seq=4,
+                      chunk_prefill_tokens=16)
+        assert eng._ring_blocks(12) == 4
+        assert eng.pools[1][0].shape[0] == 4 * 4 + 1

@@ -38,7 +38,10 @@ ATTN = {"attn"}             # the decode side; a chunk has its own
 # the parts only an expert layer and latent attention have (ISSUE 26),
 # and the identity part of a router wider than its experts (ISSUE 30)
 MOE_MLA = {"router", "experts", "shared_expert", "absorb", "zero_experts"}
-LLAMA = set(obs.TICK_SCOPES) - MOE_MLA
+# a band-keeping (sliding-window) layer's attention, beside the
+# whole-context layers' (ISSUE 32)
+WINDOW = {"attn_window", "chunk_attn_window"}
+LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW
 PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
@@ -48,15 +51,25 @@ PROGRAMS = {
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
 DEEPSEEK = {
-    "_fused_tick_greedy": set(obs.TICK_SCOPES) - {"chunk_attn",
-                                                  "zero_experts"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - ATTN - {"patch", "absorb",
-                                                     "zero_experts"},
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - {"chunk_attn",
+                                                           "zero_experts"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - ATTN - {
+        "patch", "absorb", "zero_experts"},
 }
 DEEPSEEK["_chunk_prefill_packed"] = DEEPSEEK["_chunk_prefill"] | {"patch"}
 # LongCat-Flash's double layer: zero-compute experts, no shared expert
 LONGCAT = {k: v - {"shared_expert"} | {"zero_experts"}
            for k, v in DEEPSEEK.items()}
+# MiMo-V2's two layer kinds: K/V attention (no `absorb`), full layers
+# under `attn` / `chunk_attn`, window layers under the scopes of their
+# own, experts without a shared one
+MIMO = {
+    "_fused_tick_greedy": LLAMA - {"chunk_attn"} | {
+        "router", "experts", "attn_window"},
+    "_chunk_prefill": LLAMA - ATTN - {"patch"} | {
+        "router", "experts", "chunk_attn_window"},
+}
+MIMO["_chunk_prefill_packed"] = MIMO["_chunk_prefill"] | {"patch"}
 
 
 @pytest.fixture(scope="module")
@@ -147,9 +160,36 @@ def test_a_double_layer_carries_its_scopes_and_two_kernels(longcat_engine,
         assert names == ["ragged_paged_attention"] * 2
 
 
+@pytest.fixture(scope="module")
+def mimo_engine():
+    from paddle_tpu.models.mimo_v2 import MiMoV2ForCausalLM, mimo_v2_tiny
+    eng = PagedEngine(MiMoV2ForCausalLM(mimo_v2_tiny(experts_held=4)),
+                      max_slots=4, num_blocks=32, block_size=8,
+                      max_blocks_per_seq=8, chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(MIMO))
+def test_window_and_full_layers_carry_scopes_of_their_own(mimo_engine,
+                                                          kernels, program):
+    """One full layer and two window layers: the window layers' kernel
+    calls (and their chunk attention over the ring) are named apart
+    from the full layer's, so a device trace can tell the band's read
+    from the whole context's."""
+    assert mimo_engine.decode_route() == "ragged"
+    _, scopes = _lowered(mimo_engine, program)
+    assert set(scopes) - {None} == MIMO[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+    if not program.startswith("_chunk_prefill"):
+        names = _kernel_names(_trace(mimo_engine, program).jaxpr.jaxpr)
+        assert names == ["ragged_paged_attention"] * 3
+
+
 def test_the_programs_use_the_whole_vocabulary():
     assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
-                       *LONGCAT.values()) == set(obs.TICK_SCOPES)
+                       *LONGCAT.values(), *MIMO.values()) \
+        == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES
                                           + obs.LOOP_PHASES)

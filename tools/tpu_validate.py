@@ -208,6 +208,42 @@ def latent_paged_kernel():
         assert got.shape == (R, T, h2, dv) and err < 3e-2, (T, err)
 check("latent_paged_kernel", latent_paged_kernel)
 
+def unequal_head_paged_kernel():
+    # the ragged kernel at MiMo-V2's two layer kinds (64 query heads,
+    # key heads of 192 columns read as aligned 256-column spans, value
+    # heads of 128, 64 rows): a full layer over the allocator's table,
+    # and a window layer's sink and ring of 25 pages a slot written
+    # round, single-query and multi-query, against the dense gather
+    from paddle_tpu.generation.paged import (PagedKV, paged_decode_attention,
+                                             paged_decode_attention_dense,
+                                             paged_decode_route)
+    R, B, h2, dk, dv = 64, 16, 64, 192, 128
+    lens = jnp.asarray(([0, 15, 16, 2040, 100, 576, 1023, 300]
+                        + list(rs.randint(0, 2040, R - 8))), jnp.int32)
+    for kvh2, M, window in ((4, 128, None), (8, 25, 128)):
+        ring = window is not None
+        P = R * M + 1
+        kp = jnp.asarray(rs.randn(P, B, kvh2 * dk), jnp.bfloat16)
+        vp = jnp.asarray(rs.randn(P, B, kvh2 * dv), jnp.bfloat16)
+        tables = jnp.asarray(1 + np.arange(R * M).reshape(R, M), jnp.int32)
+        sink = jnp.asarray(rs.randn(h2), jnp.float32) if ring else None
+        pk = PagedKV(kp, vp, tables, lens, kvh2, ring)
+
+        def attend(fn):
+            return jax.jit(lambda q: fn(q, pk, dk ** -0.5, window, sink))
+
+        for T in (1, 3):
+            qq = jnp.asarray(rs.randn(R, T, h2, dk) * 0.3, jnp.bfloat16)
+            assert dev.platform != "tpu" \
+                or paged_decode_route(qq, kp, kvh2) == "ragged"
+            got = attend(paged_decode_attention)(qq)
+            ref = attend(paged_decode_attention_dense)(qq)
+            err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                        - ref.astype(jnp.float32))))
+            assert got.shape == (R, T, h2, dv) and err < 3e-2, \
+                (kvh2, T, err)
+check("unequal_head_paged_kernel", unequal_head_paged_kernel)
+
 def ring_tick_program():
     # ISSUE 11: the fused tick program's token ring (device-resident ring
     # buffer + write cursors carried in the tick state, no per-tick
