@@ -220,8 +220,10 @@ class MoEMLP(Layer):
 
 # ------------------------------------------------------------------ serving
 # what an ExpertShareMLP counts inside a serving tick, in this order
+# (experts_read: the held experts whose weights the forward READ, the
+# kernel's list of experts hit by any row or, on the einsums, all held)
 SERVING_COUNTERS = ("moe_layer_ticks", "moe_local_assignments",
-                    "moe_experts_hit")
+                    "moe_experts_hit", "moe_experts_read")
 # and, after those, where the router has zero-compute columns: the live
 # rows' choices (rows x top_k, a layer and tick) and those of them that
 # fell on a zero column
@@ -331,24 +333,37 @@ class ExpertShareMLP(Layer):
         return ids, gates * self.routed_scaling_factor
 
     def routed(self, xt, ids, gates):
-        """The held experts' part for tokens xt [T, h]."""
+        """The held experts' part for tokens xt [T, h]. A forward of few
+        tokens (``use_expert_kernel``: a tick's rows) reads the weights
+        of the experts that a row of it chose and of no other, through
+        the kernel; any other multiplies every token through every held
+        expert, where nearly all are hit anyway. The same sum either
+        way: every chosen held expert of every row, live or not."""
         held = jnp.arange(self.first_expert,
                           self.first_expert + self.experts_held)
         chose = ids[:, :, None] == held[None, None, :]       # [T, k, n]
         w = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
+        from ..ops.pallas import expert_mlp
+        kernel = expert_mlp.use_expert_kernel(xt, self.w_gate)
+        if kernel:
+            order, read = expert_mlp.hit_list(jnp.any(chose, axis=(0, 1)))
         box = getattr(_collecting, "box", None)
         if box is not None:
             live = jnp.repeat(box.rows, xt.shape[0] // box.rows.shape[0])
             chose = chose & live[:, None, None]
             counts = [
                 jnp.int32(1), jnp.sum(chose, dtype=jnp.int32),
-                jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32)]
+                jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32),
+                read if kernel else jnp.int32(self.experts_held)]
             if self.zero_experts:       # ZERO_COUNTERS
                 counts += [
                     jnp.sum(live, dtype=jnp.int32) * self.top_k,
                     jnp.sum((ids >= self.num_experts) & live[:, None],
                             dtype=jnp.int32)]
             box.add(jnp.stack(counts))
+        if kernel:
+            return expert_mlp.expert_share_mlp_pallas(
+                xt, w, order, read, self.w_gate, self.w_up, self.w_down)
         g = jnp.einsum("th,nhm->ntm", xt, self.w_gate)
         u = jnp.einsum("th,nhm->ntm", xt, self.w_up)
         a = (F.silu(g) * u).astype(jnp.float32) * w.T[:, :, None]

@@ -114,6 +114,49 @@ def test_latent_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("h,T", [
+    (7168, 64),     # gigachat3.1-702b-ep16-d6, a tick's 64 rows
+    (6144, 64),     # longcat-flash-omni-ep32-d4
+    (4096, 64),     # mimo-v2.5-ep16-d7
+    (7168, 128),    # the most rows that take the kernel
+])
+def test_expert_kernel_reads_the_stacked_weights_as_they_lie(
+        one_chip, no_persistent_cache, monkeypatch, h, T):
+    """One rank's 16 experts of width 2048 through ``ExpertShareMLP``
+    at the three expert configurations' hidden sizes: the held experts'
+    part compiles to ONE ``tpu_custom_call`` whose weight operands are
+    the program's parameters themselves. No copy, transpose or reshape
+    of a stack (a "view" of a pool whose tiling differed cost a copy of
+    it every tick: PERF.md section 6, PR 27), and no product over one."""
+    import re
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.parallel.moe import ExpertShareMLP
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    # the gate asks jax for its backend, which is the CPU here
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+    n, m = 16, 2048
+    layer = ExpertShareMLP.__new__(ExpertShareMLP)      # shapes only
+    layer.first_expert, layer.experts_held, layer.zero_experts = 16, n, 0
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def routed(xt, ids, gates, w_gate, w_up, w_down):
+        layer.__dict__.update(w_gate=w_gate, w_up=w_up, w_down=w_down)
+        return ExpertShareMLP.routed(layer, xt, ids, gates)
+
+    text = jax.jit(routed).lower(
+        arr((T, h)), arr((T, 8), jnp.int32), arr((T, 8), jnp.float32),
+        arr((n, h, m)), arr((n, h, m)), arr((n, m, h))).compile().as_text()
+    stacks = re.findall(
+        rf"%(\S+) = \w+\[{n},(?:{h},{m}|{m},{h})\]\S* ([\w-]+)\(", text)
+    assert sorted(op for _, op in stacks) == ["parameter"] * 3, stacks
+    call, = re.findall(r"custom-call\(([^)]*)\), "
+                       r'custom_call_target="tpu_custom_call"', text)
+    operands = set(re.findall(r"%([\w.]+)", call))
+    assert {name for name, _ in stacks} <= operands
+
+
 _COPIES = ("reshape", "copy", "copy-start", "transpose")
 
 

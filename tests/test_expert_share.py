@@ -107,6 +107,26 @@ def test_the_shares_add_up(router):
     np.testing.assert_allclose(train(x), want, atol=2e-5)
 
 
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_shares_add_up_on_the_kernels_path(router, monkeypatch):
+    """ISSUE 33: 24 tokens are few, so under the interpreter each share
+    reads only the experts its tokens hit; summed over the ranks, plus
+    the shared expert once, it is still the whole layer."""
+    from paddle_tpu.ops.pallas.expert_mlp import use_expert_kernel
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    full = whole(router)
+    x = jnp.asarray(np.random.RandomState(2).randn(T, H), jnp.float32)
+    assert use_expert_kernel(x, full.w_gate)
+    want = plain(full, x, router)
+    np.testing.assert_allclose(full(x), want, atol=2e-5)
+    total = np.asarray(full.shared_out(x), np.float64)      # counted once
+    for first in range(0, E, 4):
+        part = share(full, first, 4, router)
+        ids, gates = part.route(x)
+        total += np.asarray(part.routed(x, ids, gates), np.float64)
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
 def test_nothing_is_dropped_whatever_the_imbalance():
     full = whole("sigmoid-groups-bias")
     bias = np.asarray(full.expert_bias).copy()
@@ -138,7 +158,8 @@ def test_a_share_outside_the_layer_is_refused():
         ExpertShareMLP(H, M, E, K, 14, 4)
 
 
-def test_the_counters_count_live_rows_only():
+def test_the_counters_count_live_rows_only(monkeypatch):
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     full = whole("softmax")
     part = share(full, 4, 4, "softmax")
     x = jnp.asarray(np.random.RandomState(5).randn(6, 2, H), jnp.float32)
@@ -151,13 +172,14 @@ def test_the_counters_count_live_rows_only():
             part(x)                     # two layers of one tick
         return box.total
 
-    ticks, assigned, hit = np.asarray(run(x, jnp.asarray(live)))
+    ticks, assigned, hit, read = np.asarray(run(x, jnp.asarray(live)))
     ids = np.asarray(part.route(x.reshape(-1, H))[0]).reshape(6, 2, K)
     mine = (ids >= 4) & (ids < 8) & live[:, None, None]
     assert SERVING_COUNTERS == ("moe_layer_ticks", "moe_local_assignments",
-                                "moe_experts_hit")
+                                "moe_experts_hit", "moe_experts_read")
     assert ticks == 2 and assigned == 2 * mine.sum()
     assert hit == 2 * len(set(ids[mine]))
+    assert read == 2 * 4        # the einsums read every held expert
     with collect_counts(jnp.asarray(live)) as box:
         pass
     assert box.total is None            # no expert layer: nothing rides

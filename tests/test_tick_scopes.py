@@ -155,9 +155,11 @@ def test_a_double_layer_carries_its_scopes_and_two_kernels(longcat_engine,
     assert set(scopes) - {None} == LONGCAT[program]
     assert scopes[None] < 0.1 * sum(scopes.values()), scopes
     if not program.startswith("_chunk_prefill"):
-        # one latent kernel an attention
+        # one latent kernel an attention, and the held experts' kernel
+        # (4 rows are few tokens: ISSUE 33) where their result joins
         names = _kernel_names(_trace(longcat_engine, program).jaxpr.jaxpr)
-        assert names == ["ragged_paged_attention"] * 2
+        assert sorted(names) == ["expert_share_mlp"] \
+            + ["ragged_paged_attention"] * 2
 
 
 @pytest.fixture(scope="module")
@@ -183,7 +185,9 @@ def test_window_and_full_layers_carry_scopes_of_their_own(mimo_engine,
     assert scopes[None] < 0.1 * sum(scopes.values()), scopes
     if not program.startswith("_chunk_prefill"):
         names = _kernel_names(_trace(mimo_engine, program).jaxpr.jaxpr)
-        assert names == ["ragged_paged_attention"] * 3
+        # the dense leading layer has no experts; the two others do
+        assert names == ["ragged_paged_attention"] \
+            + ["ragged_paged_attention", "expert_share_mlp"] * 2
 
 
 def test_the_programs_use_the_whole_vocabulary():

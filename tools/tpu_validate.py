@@ -208,6 +208,40 @@ def latent_paged_kernel():
         assert got.shape == (R, T, h2, dv) and err < 3e-2, (T, err)
 check("latent_paged_kernel", latent_paged_kernel)
 
+def expert_share_kernel():
+    # the held experts' weight-streaming kernel through
+    # ExpertShareMLP.routed (one rank's 16 experts of width 2048 at
+    # MiMo-V2.5's hidden size, a tick's 64 rows, 10 of 16 hit) against
+    # the einsums over all held experts
+    import paddle_tpu as pt
+    from paddle_tpu.ops.pallas import expert_mlp
+    from paddle_tpu.parallel import moe
+    pt.seed(0)
+    h2, m2, held, K = 4096, 2048, 16, 8
+    layer = moe.ExpertShareMLP(h2, m2, 256, K, 16, held)
+    params = {k: v.astype(jnp.bfloat16) for k, v in layer.named_parameters()}
+    x = jnp.asarray(rs.randn(64, h2), jnp.bfloat16)
+    ids = jnp.asarray(16 + (np.arange(64 * K).reshape(64, K) %% 10), jnp.int32)
+    gates = jnp.asarray(rs.rand(64, K) * 0.25, jnp.float32)
+
+    def routed(params, x, ids, gates):
+        with layer.bound(params):
+            return layer.routed(x, ids, gates)
+    assert dev.platform != "tpu" \
+        or expert_mlp.use_expert_kernel(x, params["w_gate"])
+    got = jax.jit(routed)(params, x, ids, gates)
+    gate = expert_mlp.use_expert_kernel
+    expert_mlp.use_expert_kernel = lambda *_: False
+    try:
+        ref = jax.jit(routed)(params, x, ids, gates)
+    finally:
+        expert_mlp.use_expert_kernel = gate
+    scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - ref.astype(jnp.float32))))
+    assert got.shape == (64, h2) and err < 2e-2 * scale, (err, scale)
+check("expert_share_kernel", expert_share_kernel)
+
 def unequal_head_paged_kernel():
     # the ragged kernel at MiMo-V2's two layer kinds (64 query heads,
     # key heads of 192 columns read as aligned 256-column spans, value
