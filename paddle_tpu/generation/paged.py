@@ -112,6 +112,7 @@ import functools
 import itertools
 import json
 import os
+import sys
 import time
 import types
 from collections import deque
@@ -680,17 +681,34 @@ class _TickPhaseProfile:
     identical to profile-off, and the steady-tick 1-dispatch/0-upload
     contract is untouched.
 
-    ``clock`` is injectable (tests pin the phase math deterministically
-    the way ``MetricsTimeSeries(clock=...)`` does)."""
+    Beside the clock a bracket reads the thread's CPU time
+    (``time.thread_time``), inside its clock readings, and
+    ``cpu_totals`` keeps each phase's CPU milliseconds beside its wall
+    (nesting and the ``host`` residual as for the wall). Wall less
+    CPU, over the phases that are not waits by design (``device``,
+    ``idle``, ``lock``), is the time the thread wanted a CPU and had none: the interpreter
+    lock, which the event loop's thread holds while it writes tokens,
+    or the scheduler. Nothing holds a bracket's CPU under its wall: a
+    sandboxed kernel may advance a thread's CPU clock in 10 ms steps,
+    sampled (the benchmark's machines do), and a step then lands whole
+    in whichever bracket reads it. One bracket's CPU means nothing
+    there; the totals over a stretch of seconds are an estimate whose
+    noise is the step times the root of the steps counted.
+
+    ``clock`` and ``cpu_clock`` are injectable (tests pin the phase math
+    deterministically the way ``MetricsTimeSeries(clock=...)`` does)."""
 
     PHASES = obs.TICK_PHASES + obs.LOOP_PHASES
 
     def __init__(self, labels: Dict[str, str], clock=None,
-                 capacity: int = 1024):
+                 capacity: int = 1024, cpu_clock=None):
         self.clock = clock if clock is not None else time.perf_counter
+        self.cpu_clock = cpu_clock if cpu_clock is not None \
+            else time.thread_time
         self.capacity = max(int(capacity), 1)
         self.ring: deque = deque(maxlen=self.capacity)
         self.totals = {p: 0.0 for p in self.PHASES}
+        self.cpu_totals = {p: 0.0 for p in self.PHASES}
         self.wall_total_ms = 0.0
         self.ticks = 0
         reg = obs.registry()
@@ -704,11 +722,14 @@ class _TickPhaseProfile:
                                      **labels)
         self._span_names = {p: "tick/" + p for p in self.PHASES}
         # open brackets, outermost first: [phase, start, seconds its
-        # inner brackets took, the trace annotation it holds]
+        # inner brackets took, the trace annotation it holds, CPU at
+        # the start, CPU seconds of its inner brackets]
         self._stack: List[list] = []
         self._acc: Optional[Dict[str, float]] = None
+        self._acc_cpu: Optional[Dict[str, float]] = None
         self._tick_ann = None
         self._t0 = 0.0
+        self._c0 = 0.0
         self._t_first: Optional[float] = None
         self._t_last = 0.0
         self._last: Optional[Dict[str, float]] = None
@@ -731,21 +752,26 @@ class _TickPhaseProfile:
         t = self.clock()
         if self._t_first is None:
             self._t_first = t
-        self._stack.append([phase, t, 0.0, ann])
+        self._stack.append([phase, t, 0.0, ann, self.cpu_clock(), 0.0])
 
     def close(self):
-        phase, t0, inner, ann = self._stack.pop()
+        phase, t0, inner, ann, c0, cpu_inner = self._stack.pop()
+        c1 = self.cpu_clock()
         t1 = self.clock()
         if ann is not None:
             ann.__exit__(None, None, None)
         self._t_last = t1
         if self._stack:
             self._stack[-1][2] += t1 - t0
+            self._stack[-1][5] += c1 - c0
         dt_ms = max((t1 - t0 - inner) * 1e3, 0.0)
+        cpu_ms = max((c1 - c0 - cpu_inner) * 1e3, 0.0)
         if self._acc is not None and phase in self._acc:
             self._acc[phase] += dt_ms
+            self._acc_cpu[phase] += cpu_ms
         else:
             self.totals[phase] += dt_ms
+            self.cpu_totals[phase] += cpu_ms
             self._hists[phase].observe(dt_ms)
 
     def begin(self):
@@ -754,14 +780,18 @@ class _TickPhaseProfile:
         if self._tick_ann is not None:
             self._tick_ann.__enter__()
         self._acc = {p: 0.0 for p in obs.TICK_PHASES if p != "host"}
+        self._acc_cpu = dict(self._acc)
         self._t0 = self.clock()
+        self._c0 = self.cpu_clock()
         if self._t_first is None:
             self._t_first = self._t0
 
     def end(self, *, dispatches: int, uploads: int, nbytes: int,
             patches: int, active: int):
         """Close the tick: host = wall - bracketed phases (clamped at
-        0), observe histograms, append the ring record."""
+        0), in CPU as in wall; observe histograms, append the ring
+        record."""
+        c1 = self.cpu_clock()
         t1 = self.clock()
         self._t_last = t1
         if self._tick_ann is not None:
@@ -771,6 +801,11 @@ class _TickPhaseProfile:
         phases = self._acc or {}
         self._acc = None
         phases["host"] = max(wall - sum(phases.values()), 0.0)
+        cpu = self._acc_cpu or {}
+        self._acc_cpu = None
+        cpu["host"] = max((c1 - self._c0) * 1e3 - sum(cpu.values()), 0.0)
+        for p, v in cpu.items():
+            self.cpu_totals[p] += v
         rec: Dict[str, Any] = {
             "tick": self.ticks, "t": round(float(t1), 6),
             "wall_ms": round(wall, 4),
@@ -800,22 +835,31 @@ class _TickPhaseProfile:
         return dict(self._last) if self._last is not None else None
 
     def summary(self) -> Dict[str, Any]:
-        """Lifetime totals: the tick side, the loop side, and the
-        thread's wall they are shares of."""
+        """Lifetime totals: the tick side, the loop side, each in wall
+        and in the thread's CPU, and the thread's wall they are shares
+        of."""
         return {"ticks": self.ticks,
                 "wall_total_ms": round(self.wall_total_ms, 4),
                 "phase_totals_ms": {p: round(self.totals[p], 4)
                                     for p in obs.TICK_PHASES},
                 "loop_totals_ms": {p: round(self.totals[p], 4)
                                    for p in obs.LOOP_PHASES},
+                "phase_cpu_ms": {p: round(self.cpu_totals[p], 4)
+                                 for p in obs.TICK_PHASES},
+                "loop_phase_cpu_ms": {p: round(self.cpu_totals[p], 4)
+                                      for p in obs.LOOP_PHASES},
                 "thread_wall_ms": round(self.thread_wall_ms, 4)}
 
     def to_doc(self, engine: str) -> Dict[str, Any]:
         """The ``tickphase/1`` document
-        (``obs.validate_tickphase_doc`` checks it)."""
+        (``obs.validate_tickphase_doc`` checks it). The interpreter's
+        switch interval is how long a thread that wants the lock may
+        wait for the one that holds it: the scale of a phase's wall
+        less its CPU."""
         return dict(self.summary(), schema=obs.TICKPHASE_SCHEMA,
                     engine=engine, dumped_wall=time.time(),
                     clock_now=float(self.clock()),
+                    switch_interval_s=sys.getswitchinterval(),
                     capacity=self.capacity, entries=list(self.ring))
 
 
@@ -1203,7 +1247,8 @@ class PagedEngine:
         # steady-tick 1-dispatch/0-upload pins stay green with the
         # profiler running (tests/test_tick_profile.py).
         # profile_clock: injectable clock for deterministic phase-math
-        # tests (same idiom as MetricsTimeSeries(clock=...)).
+        # tests (same idiom as MetricsTimeSeries(clock=...)); they set
+        # the profile's ``cpu_clock`` beside it.
         self.tick_profile = bool(tick_profile)
         self._prof: Optional[_TickPhaseProfile] = None
         if self.tick_profile:
@@ -1384,9 +1429,16 @@ class PagedEngine:
     def stats(self) -> Dict[str, int]:
         """Scheduler-counter snapshot (pre-migration dict shape; the
         values now come from the observability registry), plus
-        ``decode_ticks``, the count per-tick figures divide by."""
+        ``decode_ticks``, the count per-tick figures divide by. With
+        the tick profiler on, also the thread's CPU under each phase so
+        far, whole microseconds, as ``phase_cpu_us.<phase>`` (the wall
+        is ``tick_phase_totals``): a snapshot of the counters then
+        holds both sides of the thread's time."""
         out = {k: int(c.value) for k, c in self._counters.items()}
         out["decode_ticks"] = self.decode_ticks
+        if self._prof is not None:
+            for p, ms in self._prof.cpu_totals.items():
+                out["phase_cpu_us." + p] = int(ms * 1e3)
         return out
 
     def _count(self, key: str, n: int = 1):

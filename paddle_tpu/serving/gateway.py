@@ -57,6 +57,7 @@ stream never strands a slot).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import itertools
 import json
 import os
@@ -127,6 +128,76 @@ def _query_param(query: str, key: str, conv=float):
     return out
 
 
+class _StreamTimes:
+    """The event loop's half of a decode round, timed where it runs
+    (ISSUE 35): plain sums over the tokens ``_stream_sse`` wrote for a
+    replica whose engine runs its tick profiler. Such a token's event
+    carries the tick thread's ``perf_counter`` at its push
+    (``_token_out``), and per token written the loop adds: push ->
+    ``writer.write`` returned (the wait in the loop's queue, for the
+    interpreter lock, and the write); dequeue -> ``writer.write``
+    returned (the coroutine's own stretch; ``drain`` yields and is not
+    in it); and stamps its thread's ``time.thread_time()``, cumulative,
+    whose difference over a stretch is ALL the CPU the loop's thread
+    used there: wake-ups, ``asyncio.wait``, ``json.dumps``, the writes.
+    Only the loop's thread writes here; ``health()`` shows the sums as
+    whole microseconds and ``gateway_emit_to_wire_ms`` holds the first
+    for a scrape. With every engine's profiler off nothing is counted."""
+
+    def __init__(self, labels: Dict[str, str]):
+        self.tokens = 0
+        self.emit_to_wire_s = 0.0
+        self.loop_write_s = 0.0
+        self.loop_cpu_s = 0.0
+        self._hist = obs.registry().histogram(
+            "gateway_emit_to_wire_ms", buckets=obs.SERVING_MS_BUCKETS,
+            **labels)
+
+    def wrote(self, t_push: float, t_dequeue: float, now: float):
+        self.tokens += 1
+        self.emit_to_wire_s += now - t_push
+        self.loop_write_s += now - t_dequeue
+        self.loop_cpu_s = time.thread_time()
+        self._hist.observe((now - t_push) * 1e3)
+
+    def snapshot(self) -> Dict[str, int]:
+        return {"stream_tokens": self.tokens,
+                "emit_to_wire_us": int(self.emit_to_wire_s * 1e6),
+                "loop_write_us": int(self.loop_write_s * 1e6),
+                "event_loop_cpu_us": int(self.loop_cpu_s * 1e6)}
+
+
+class _WriteSpan:
+    """One timed token's write, as a ``with`` block opened at its
+    dequeue. While open it holds a ``TraceAnnotation("loop/write")``,
+    so a profiler trace shows the loop's line beside the tick thread's
+    ``tick/<phase>`` spans (not a ``tick/`` name: those mark a tick
+    thread's line). A write that raised is not counted."""
+    __slots__ = ("_times", "_t_push", "_t_dequeue", "_ann")
+
+    def __init__(self, times: _StreamTimes, t_push: float):
+        self._times, self._t_push = times, t_push
+        self._t_dequeue = time.perf_counter()
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = obs._trace_annotation("loop/write")
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        now = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if exc[0] is None:
+            self._times.wrote(self._t_push, self._t_dequeue, now)
+        return False
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
 def _release_probe(req: ServeRequest, replica, success=None):
     """Report a probation probe's terminal outcome to its breaker.
     EVERY path that terminates a probe request must come through here
@@ -190,6 +261,9 @@ class _ReplicaWorker(threading.Thread):
         # from its first request.
         self.warmed = getattr(replica.engine, "dispatch_count", 0) > 0
         self._chaos: Optional[str] = None
+        # a profiled engine's tokens carry the clock at their push
+        # (ISSUE 35; ``_StreamTimes``)
+        self._stamp = bool(getattr(replica.engine, "tick_profile", False))
         # orders token emission against the failover snapshot: the
         # tick thread holds it across _dispatch, the failover path
         # holds it while latching ``abandoned`` and snapshotting/
@@ -519,8 +593,10 @@ class _ReplicaWorker(threading.Thread):
         # to null — json.dumps would otherwise emit invalid JSON.
         if lp is not None and lp != lp:
             lp = None
-        self._emit(req, ("token", int(tok),
-                         float(lp) if lp is not None else None))
+        ev = ("token", int(tok), float(lp) if lp is not None else None)
+        if self._stamp:
+            ev += (time.perf_counter(),)
+        self._emit(req, ev)
 
     def _finish(self, req: ServeRequest, payload: Dict[str, Any],
                 now: float):
@@ -725,6 +801,7 @@ class Gateway:
                                           **self._labels)
         self._g_goodput = reg.gauge("gateway_goodput_frac",
                                     **self._labels)
+        self._stream_times = _StreamTimes(self._labels)
         # fleet fault tolerance (ISSUE 12): the failover accounting
         # the supervisor/crash paths share. _fo_lock serializes the
         # per-worker failure latch and the worker-list swap.
@@ -1562,6 +1639,9 @@ class Gateway:
                 / max(self._c_tokens.value, 1.0), 4),
             "ttft_ms": self._h_ttft.stats(),
             "tpot_ms": self._h_tpot.stats(),
+            # the event loop's half of a round (ISSUE 35): zeros unless
+            # an engine runs its tick profiler
+            "stream": self._stream_times.snapshot(),
             "router": self._router.snapshot(),
             "replicas": {
                 w.replica.name: dict(
@@ -1708,9 +1788,16 @@ class Gateway:
         DURING THE WINDOW, so a caller gets the slope-vs-intercept
         split inline even with no run dir configured. One capture at a
         time (409 otherwise); duration is clamped to 30 s — this is a
-        tap on a serving process, not a profiling session."""
+        tap on a serving process, not a profiling session.
+
+        The trace is taken without jax's Python tracer, which would
+        triple a tick for the length of the capture (ISSUE 35): the
+        ``tick/<phase>`` and ``loop/write`` spans name the host's time.
+        ``&python_tracer=1`` asks for it; the answer says which it
+        was."""
         dur = _query_param(query, "duration_s")
         dur = 1.0 if dur is None else max(0.05, min(float(dur), 30.0))
+        python_tracer = bool(_query_param(query, "python_tracer", int))
         if self._profilez_busy:
             writer.write(_json_response(
                 409, {"error": "capture already in progress"}))
@@ -1723,9 +1810,11 @@ class Gateway:
             jax_dir = os.path.join(run_dir, f"jaxprof_{self.name}") \
                 if run_dir else None
             prof = Profiler(logdir=jax_dir or "",
-                            timer_only=jax_dir is None)
+                            timer_only=jax_dir is None,
+                            python_tracer=python_tracer)
             before = {w.replica.name: w.engine.tick_profile_summary()
                       for w in self._workers}
+            stream = self._stream_times.snapshot()
             traced = False
             try:
                 prof.start()
@@ -1761,6 +1850,12 @@ class Gateway:
                     "loop_ms_in_window": {
                         k: round(v - a["loop_totals_ms"][k], 3)
                         for k, v in b["loop_totals_ms"].items()},
+                    "phase_cpu_ms_in_window": {
+                        k: round(v - a["phase_cpu_ms"][k], 3)
+                        for k, v in b["phase_cpu_ms"].items()},
+                    "loop_cpu_ms_in_window": {
+                        k: round(v - a["loop_phase_cpu_ms"][k], 3)
+                        for k, v in b["loop_phase_cpu_ms"].items()},
                 }
             files = self.dump_tick_profiles(run_dir) if run_dir else []
             obs.record_event("profilez_capture", gateway=self.name,
@@ -1770,8 +1865,12 @@ class Gateway:
                 "gateway": self.name,
                 "duration_s": dur,
                 "jax_trace": jax_dir if traced else None,
+                "python_tracer": python_tracer and traced,
                 "tickphase_files": files,
                 "replicas": reps,
+                "stream_in_window": {
+                    k: v - stream[k] for k, v in
+                    self._stream_times.snapshot().items()},
             }))
             await writer.drain()
         finally:
@@ -2051,6 +2150,10 @@ class Gateway:
                         eof = None
                         continue
                     ev = get.result()
+                # a profiled replica's token carries the clock at its
+                # push: its way to the socket is timed from here
+                span = _NO_SPAN if len(ev) < 4 \
+                    else _WriteSpan(self._stream_times, ev[3])
                 try:
                     if ev[0] == "token":
                         payload = {"token": ev[1], "lp": ev[2]}
@@ -2065,8 +2168,10 @@ class Gateway:
                         payload = dict(ev[1], done=True)
                     else:
                         payload = {"error": ev[2], "done": True}
-                    writer.write(b"data: " + json.dumps(payload).encode()
-                                 + b"\n\n")
+                    with span:
+                        writer.write(b"data: "
+                                     + json.dumps(payload).encode()
+                                     + b"\n\n")
                     await writer.drain()
                 except (ConnectionError, OSError):
                     self._on_disconnect(worker, req)

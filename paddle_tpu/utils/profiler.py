@@ -52,11 +52,21 @@ _trace_owner: Optional["Profiler"] = None
 
 
 class Profiler:
-    """paddle.profiler.Profiler-shaped facade over jax.profiler."""
+    """paddle.profiler.Profiler-shaped facade over jax.profiler.
 
-    def __init__(self, logdir: str = "runs/profile", timer_only: bool = False):
+    A trace is taken WITHOUT jax's Python tracer unless
+    ``python_tracer=True``: the tracer hooks every Python call of every
+    thread, which triples a serving tick for the length of the capture
+    and fills the host lines with its own events (PERF.md section 6, PR
+    35). The program's own spans (``TraceAnnotation``: ``tick/<phase>``,
+    ``loop/write``, ``annotate``) and the device's ops are in the trace
+    either way; the tracer is for someone hunting a Python function."""
+
+    def __init__(self, logdir: str = "runs/profile", timer_only: bool = False,
+                 python_tracer: bool = False):
         self.logdir = logdir
         self.timer_only = timer_only
+        self.python_tracer = bool(python_tracer)
         self._active = False
 
     def start(self):
@@ -76,7 +86,10 @@ class Profiler:
                       f"back to timer-only for this profiler",
                       file=sys.stderr, flush=True)
             else:
-                jax.profiler.start_trace(self.logdir)
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = int(self.python_tracer)
+                jax.profiler.start_trace(self.logdir,
+                                         profiler_options=opts)
                 _trace_owner = self
         self._active = True
 

@@ -15,6 +15,12 @@ Contracts pinned here:
   with least-loaded fallback and health eviction; affinity measurably
   raises ``prefix_hit_tokens`` over round-robin on a shared-system-
   prompt workload.
+- THE LOOP'S HALF OF A ROUND (ISSUE 35): behind a profiled engine
+  every streamed token is counted once in ``health()["stream"]``, its
+  way from the tick thread's push to the socket and the loop's own
+  stretch are summed (they grow with an injected ``stream_stall``),
+  ``/metrics`` holds the same sums, and with the profiler off the token
+  event, the SSE bytes and the counters are what they were.
 - LIFECYCLE: SIGTERM drains (finish in-flight, 503 new work, flush
   metrics); an SSE client dropping mid-stream frees its slot/blocks
   via ``PagedEngine.cancel`` (no stranded slots); saturation sheds
@@ -405,6 +411,117 @@ def test_gateway_nonstream_healthz_metrics_pinned():
     assert float(line.split()[-1]) == health["tokens"]
     assert health["replicas"]["r0"]["engine"]["prefills"] == 1
     assert 'gateway_ttft_ms_bucket' in prom
+
+
+async def _raw_sse(port, payload) -> bytes:
+    """The response's bytes after the HTTP head, as they came."""
+    reader, writer = await asyncio.open_connection("127.0.0.1", port)
+    body = json.dumps(payload).encode()
+    writer.write((f"POST /v1/generate HTTP/1.1\r\nHost: t\r\n"
+                  f"Content-Length: {len(body)}\r\n\r\n").encode() + body)
+    await writer.drain()
+    raw = await reader.read()
+    writer.close()
+    return raw.partition(b"\r\n\r\n")[2]
+
+
+STREAM_REQS = [
+    dict(prompt=list(range(1, 13)), max_new_tokens=8),
+    dict(prompt=[5, 9, 2, 7, 7, 1, 3, 8, 4], max_new_tokens=10,
+         temperature=0.9, top_k=20, seed=7),
+    dict(prompt=[3, 1, 4, 1, 5, 9, 2, 6], max_new_tokens=5),
+]
+
+
+def _stream_run(name, stall_s=None, **engine_kw):
+    """STREAM_REQS through a gateway over real HTTP: the raw SSE bodies,
+    ``health()`` and the ``/metrics`` text after them."""
+    async def run():
+        gw = Gateway(_engine(**engine_kw), name=name)
+        await gw.start()
+        try:
+            raws = await asyncio.gather(
+                *[_raw_sse(gw.port, dict(r, stream=True))
+                  for r in STREAM_REQS])
+            _, _, prom = await _http(gw.port, "GET", "/metrics")
+            return raws, gw.health(), prom.decode()
+        finally:
+            await gw.drain()
+    if stall_s is None:
+        return asyncio.run(run())
+    from paddle_tpu.utils import faults
+    os.environ[faults.STREAM_STALL_ENV_VAR] = str(stall_s)
+    try:
+        with faults.scoped("stream_stall@0+"):
+            return asyncio.run(run())
+    finally:
+        del os.environ[faults.STREAM_STALL_ENV_VAR]
+
+
+def _prom_value(prom, name, gateway):
+    line = next(ln for ln in prom.splitlines()
+                if ln.startswith(name + "{") and f'gateway="{gateway}"' in ln)
+    return float(line.split()[-1])
+
+
+@pytest.mark.parametrize("stall_s", [None, 0.02], ids=["plain", "stalled"])
+def test_stream_times_count_each_token_once_and_grow_with_a_stall(stall_s):
+    name = f"t-wire-{stall_s}"
+    raws, health, prom = _stream_run(name, stall_s, tick_profile=True)
+    tokens = sum(r["max_new_tokens"] for r in STREAM_REQS)
+    assert sum(raw.count(b'data: {"token"') for raw in raws) == tokens
+    st = health["stream"]
+    assert set(st) == {"stream_tokens", "emit_to_wire_us", "loop_write_us",
+                       "event_loop_cpu_us"}
+    assert all(isinstance(v, int) for v in st.values())
+    assert st["stream_tokens"] == tokens == health["tokens"]
+    # a token is pushed before the loop takes it up
+    assert st["emit_to_wire_us"] >= st["loop_write_us"] > 0
+    assert st["event_loop_cpu_us"] > 0
+    if stall_s is None:
+        assert st["loop_write_us"] < tokens * 0.02e6
+    else:           # the stall lies between the dequeue and the write
+        assert st["loop_write_us"] >= tokens * stall_s * 1e6
+        assert st["emit_to_wire_us"] >= tokens * stall_s * 1e6
+    # health() and the scrape hold the same sums
+    assert _prom_value(prom, "gateway_emit_to_wire_ms_count", name) \
+        == st["stream_tokens"]
+    assert _prom_value(prom, "gateway_emit_to_wire_ms_sum", name) \
+        == pytest.approx(st["emit_to_wire_us"] / 1e3, abs=0.01 * tokens)
+    assert "gateway_emit_to_wire_ms_bucket" in prom
+
+
+def test_profile_off_the_token_event_the_bytes_and_the_counters_are_the_parents():
+    raws_off, health, prom = _stream_run("t-wire-off")
+    raws_on, _, _ = _stream_run("t-wire-on", tick_profile=True)
+    assert raws_on == raws_off          # the stamp never reaches the wire
+    assert set(health["stream"].values()) == {0}
+    assert _prom_value(prom, "gateway_emit_to_wire_ms_count",
+                       "t-wire-off") == 0
+    # the bytes, spelt out: what the parent's writer wrote
+    eng = _engine()
+    for i, r in enumerate(STREAM_REQS):
+        eng.submit(i, np.asarray([r["prompt"]], np.int32),
+                   **{k: v for k, v in r.items() if k != "prompt"})
+    direct = eng.run()
+    for i, raw in enumerate(raws_off):
+        want = b"".join(
+            b"data: " + json.dumps({"token": int(t), "lp": float(lp)}
+                                   ).encode() + b"\n\n"
+            for t, lp in zip(direct[i], eng.logprobs[i]))
+        assert raw.startswith(want)
+        assert json.loads(raw[len(want):][6:])["done"] is True
+    # and the event that crosses the threads
+    for profiled, width in ((False, 3), (True, 4)):
+        gw = Gateway(_engine(tick_profile=profiled),
+                     name=f"t-wire-ev-{profiled}")
+        worker, got = gw._workers[0], []
+        worker._emit = lambda req, ev: got.append(ev)
+        t0 = time.perf_counter()
+        worker._token_out(_req("a"), 5, time.monotonic(), lp=-0.5)
+        assert got[0][:3] == ("token", 5, -0.5) and len(got[0]) == width
+        if profiled:
+            assert t0 <= got[0][3] <= time.perf_counter()
 
 
 def test_gateway_sheds_429_with_retry_after():

@@ -172,7 +172,7 @@ class TestSatellites:
         from paddle_tpu.utils import profiler as prof
         calls = []
         monkeypatch.setattr(prof.jax.profiler, "start_trace",
-                            lambda d: calls.append(("start", d)))
+                            lambda d, **kw: calls.append(("start", d)))
         monkeypatch.setattr(prof.jax.profiler, "stop_trace",
                             lambda: calls.append(("stop", None)))
         p = prof.Profiler(logdir="x")
@@ -188,6 +188,32 @@ class TestSatellites:
         assert not [c for c in calls if c[0] == "stop"]
         p.stop()
         assert [c for c in calls if c[0] == "stop"]
+
+    @pytest.mark.parametrize("asked", [False, True],
+                             ids=["default", "python-tracer"])
+    def test_profiler_traces_without_the_python_tracer_unless_asked(
+            self, monkeypatch, asked):
+        """ISSUE 35: a capture must not bend what it measures. The
+        Python tracer triples a serving tick; the program's own spans
+        name the host's time without it."""
+        from paddle_tpu.utils import profiler as prof
+        got = []
+        monkeypatch.setattr(
+            prof.jax.profiler, "start_trace",
+            lambda d, profiler_options=None: got.append(
+                (d, profiler_options)))
+        monkeypatch.setattr(prof.jax.profiler, "stop_trace", lambda: None)
+        p = prof.Profiler(logdir="x", python_tracer=True) if asked \
+            else prof.Profiler(logdir="x")
+        with p:
+            pass
+        (d, opts), = got
+        assert d == "x"
+        assert opts.python_tracer_level == int(asked)
+        # the rest of the options are jax's defaults: the host's
+        # TraceAnnotations and the device's ops stay in the trace
+        fresh = prof.jax.profiler.ProfileOptions()
+        assert opts.host_tracer_level == fresh.host_tracer_level > 0
 
     def test_steptimer_stop_without_start_raises(self):
         from paddle_tpu.utils.profiler import StepTimer

@@ -15,6 +15,12 @@ Contracts pinned here:
   (``engine.loop_phase``: ``obs.LOOP_PHASES``) feeds the totals, the
   histograms and the thread's wall, and no tick record; the serving
   gateway's worker reports all four.
+- CPU BESIDE WALL (ISSUE 35): a bracket reads the thread's CPU clock
+  inside its wall readings; per-phase CPU totals follow the wall's
+  nesting and residual rules, never exceed the wall, and come out in
+  ``tick_profile_summary()`` and, as whole microseconds, in
+  ``PagedEngine.stats``; with the profiler off ``stats`` has the
+  parent's keys.
 - BITWISE OFF==ON: profile-on greedy+sampled streams are bit-identical
   to profile-off across the engine's paths (the served fused tick,
   its speculative dispatches, its run-ahead across block growth,
@@ -42,11 +48,12 @@ import asyncio
 import glob
 import json
 import os
+import sys
 
 import numpy as np
 import pytest
 
-from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.generation.paged import PagedEngine, _TickPhaseProfile
 from paddle_tpu.generation.stub import TickStubModel
 from paddle_tpu.serving.reqtrace import (RequestTrace, RequestTraceRing,
                                          decode_phase_share)
@@ -123,6 +130,143 @@ def test_phase_sum_equals_wall_injected_clock():
         {p: prof.totals[p] for p in obs.TICK_PHASES}, abs=1e-3)
     # the thread's wall covers the ticks and the gaps between them
     assert doc["thread_wall_ms"] >= doc["wall_total_ms"]
+
+
+# ===================================================== CPU beside wall
+class HandClock:
+    """A clock the test moves by hand."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def test_cpu_totals_follow_the_walls_nesting_and_residual():
+    wall, cpu = HandClock(), HandClock()
+
+    def spend(wall_ms, cpu_ms):
+        wall.t += wall_ms * 1e-3
+        cpu.t += cpu_ms * 1e-3
+
+    prof = _TickPhaseProfile({"engine": "t-cpu"}, clock=wall,
+                             cpu_clock=cpu)
+    prof.begin()
+    spend(2, 1)                         # no bracket: the host residual
+    with prof.span("stage"):
+        spend(4, 4)
+        with prof.span("h2d"):          # taken out of ``stage``
+            spend(3, 1)
+    with prof.span("device"):
+        spend(10, 0)                    # a wait: wall and no CPU
+    with prof.span("commit"):
+        spend(1, 0.5)
+    with prof.span("expire"):
+        spend(1, 10)                    # a stepping CPU clock's step,
+                                        # whole in the bracket that reads it
+    prof.end(dispatches=1, uploads=1, nbytes=8, patches=0, active=1)
+    with prof.span("emit"):             # a loop phase, outside a tick
+        spend(5, 2)
+    want_wall = dict(host=2, stage=4, h2d=3, device=10, commit=1, expire=1,
+                     emit=5)
+    want_cpu = dict(host=1, stage=4, h2d=1, device=0, commit=0.5, expire=10,
+                    emit=2)
+    for p in prof.PHASES:
+        assert prof.totals[p] == pytest.approx(want_wall.get(p, 0)), p
+        assert prof.cpu_totals[p] == pytest.approx(want_cpu.get(p, 0)), p
+    assert sum(prof.totals[p] for p in obs.TICK_PHASES) \
+        == pytest.approx(prof.wall_total_ms, rel=1e-9) == 21.0
+    summ = prof.summary()
+    assert summ["phase_cpu_ms"] == pytest.approx(
+        {p: want_cpu.get(p, 0) for p in obs.TICK_PHASES})
+    assert summ["loop_phase_cpu_ms"] == pytest.approx(
+        {p: want_cpu.get(p, 0) for p in obs.LOOP_PHASES})
+    # what the thread wanted a CPU for and did not get: the wall less
+    # the CPU of the phases that are not waits by design
+    work = [p for p in prof.PHASES
+            if p not in ("device", "idle", "lock", "expire")]
+    assert sum(prof.totals[p] - prof.cpu_totals[p] for p in work) \
+        == pytest.approx(6.5)
+
+
+class Ticking(FakeClock):
+    def __init__(self, step_s):
+        super().__init__()
+        self.step_s = step_s
+
+    def __call__(self):
+        self.t += self.step_s
+        return self.t
+
+
+def test_engine_cpu_never_exceeds_wall_and_reaches_stats():
+    eng = _engine(tick_profile=True, profile_clock=FakeClock())
+    eng._prof.cpu_clock = Ticking(0.00025)
+    with eng.loop_phase("sched"):
+        pass
+    _drain(eng)
+    prof = eng._prof
+    assert sum(prof.totals[p] for p in obs.TICK_PHASES) \
+        == pytest.approx(prof.wall_total_ms, rel=1e-9)
+    for p in prof.PHASES:
+        assert 0.0 <= prof.cpu_totals[p] <= prof.totals[p], p
+        assert (prof.cpu_totals[p] > 0.0) == (prof.totals[p] > 0.0), p
+    # a quarter of the wall, by the two clocks' steps
+    assert prof.cpu_totals["sched"] == pytest.approx(0.25)
+    assert prof.cpu_totals["device"] == pytest.approx(
+        prof.totals["device"] / 4)
+    stats = eng.stats
+    for p in prof.PHASES:
+        assert stats["phase_cpu_us." + p] == int(prof.cpu_totals[p] * 1e3)
+    summ = eng.tick_profile_summary()
+    assert set(summ["phase_cpu_ms"]) == set(obs.TICK_PHASES)
+    assert set(summ["loop_phase_cpu_ms"]) == set(obs.LOOP_PHASES)
+    # the wall's dict stays the wall's: serve_loadgen and fleet_dash
+    # sum it
+    assert set(eng.tick_phase_totals) == set(prof.PHASES)
+    doc = eng.tick_profile_doc()
+    assert obs.validate_tickphase_doc(doc) == []
+    assert doc["switch_interval_s"] == sys.getswitchinterval()
+
+
+def test_a_cpu_clock_that_steps_loses_nothing_in_the_brackets():
+    """The benchmark's machines advance a thread's CPU clock in 10 ms
+    steps: a step lands whole in the bracket that reads it, so a small
+    phase may hold more CPU than wall, and the phases together hold
+    every step that fell inside a tick or a loop bracket."""
+    wall = FakeClock()
+
+    def stepped():                      # half the wall, in 10 ms steps
+        return int(wall.t * 0.5 / 0.010) * 0.010
+
+    eng = _engine(tick_profile=True, profile_clock=wall)
+    eng._prof.cpu_clock = stepped
+    _drain(eng)
+    prof = eng._prof
+    held = sum(prof.cpu_totals.values())
+    # only a step read between two ticks is under no name
+    assert 20.0 <= held <= stepped() * 1e3 + 1e-6
+    assert held >= 0.7 * stepped() * 1e3
+    assert all(v >= 0.0 and round(v, 6) % 10 == 0
+               for v in prof.cpu_totals.values())
+    assert sum(prof.totals[p] for p in obs.TICK_PHASES) \
+        == pytest.approx(prof.wall_total_ms, rel=1e-9)
+
+
+def test_profile_off_stats_keys_are_the_parents():
+    off, on = _engine(), _engine(tick_profile=True)
+    _drain(off)
+    _drain(on)
+    cpu_keys = {"phase_cpu_us." + p for p in _TickPhaseProfile.PHASES}
+    assert not any(k.startswith("phase_cpu_us") for k in off.stats)
+    assert set(on.stats) == set(off.stats) | cpu_keys
+    assert {k: v for k, v in on.stats.items() if k not in cpu_keys} \
+        == off.stats
+    assert (on.dispatch_count, on.h2d_uploads, on.h2d_upload_bytes) \
+        == (off.dispatch_count, off.h2d_uploads, off.h2d_upload_bytes)
+    assert "stats" not in off.health() and all(
+        k in on.health() for k in cpu_keys)
 
 
 class SlowCalls(FakeClock):
