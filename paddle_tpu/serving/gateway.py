@@ -47,12 +47,16 @@ Architecture (one process, N replicas):
   (``observability.flush()``), the listener closes. Rolling restarts
   lose nothing that already got a slot.
 
-Token events cross from tick threads to the asyncio loop via
-``loop.call_soon_threadsafe`` onto per-request queues; a client that
-disconnects mid-stream is detected at the SSE writer (EOF watch or a
-failed ``drain()``) and its request is cancelled ON THE TICK THREAD
-(``engine.cancel`` frees the slot and blocks immediately — a dropped
-stream never strands a slot).
+Stream events cross from a tick thread to the asyncio loop a TICK at
+a time (ISSUE 36): the worker buffers what a tick made and hands the
+list over with one ``loop.call_soon_threadsafe``; the loop's callback
+(``Gateway._deliver``) writes each token's frame to its stream's
+transport itself, and leaves to the stream's coroutine what needs one
+(the head, ``done`` / ``error``, a stalled stream). A client that
+disconnects mid-stream is detected at the SSE writer (EOF watch, a
+transport that closed under a write, or a failed ``drain()``) and its
+request is cancelled ON THE TICK THREAD (``engine.cancel`` frees the
+slot and blocks immediately — a dropped stream never strands a slot).
 """
 from __future__ import annotations
 
@@ -129,55 +133,71 @@ def _query_param(query: str, key: str, conv=float):
 
 
 class _StreamTimes:
-    """The event loop's half of a decode round, timed where it runs
-    (ISSUE 35): plain sums over the tokens ``_stream_sse`` wrote for a
-    replica whose engine runs its tick profiler. Such a token's event
-    carries the tick thread's ``perf_counter`` at its push
-    (``_token_out``), and per token written the loop adds: push ->
-    ``writer.write`` returned (the wait in the loop's queue, for the
-    interpreter lock, and the write); dequeue -> ``writer.write``
-    returned (the coroutine's own stretch; ``drain`` yields and is not
-    in it); and stamps its thread's ``time.thread_time()``, cumulative,
-    whose difference over a stretch is ALL the CPU the loop's thread
-    used there: wake-ups, ``asyncio.wait``, ``json.dumps``, the writes.
-    Only the loop's thread writes here; ``health()`` shows the sums as
-    whole microseconds and ``gateway_emit_to_wire_ms`` holds the first
-    for a scrape. With every engine's profiler off nothing is counted."""
+    """The event loop's half of a decode round, counted where it runs.
+
+    Always: the hand-overs the loop ran and the events they carried
+    (ISSUE 36; ``stream_batch_events / stream_batches`` is what a tick
+    sends across at once, and reads 1 for a program that crosses a
+    token at a time). Behind an engine that runs its tick profiler
+    (ISSUE 35) a token's event carries the tick thread's
+    ``perf_counter`` at its push (``_token_out``), and per token written
+    the loop adds: push -> its write returned (the wait for the loop and
+    for the interpreter lock, and the write); taken up -> its write
+    returned (a hand-over's first token at the callback's start, a later
+    one at the return of the write before it; in a stream's coroutine at
+    its pop, so an injected stall lies inside); and stamps its thread's
+    ``time.thread_time()``, cumulative, whose difference over a stretch
+    is ALL the CPU the loop's thread used there. Only the loop's thread
+    writes here; ``health()`` shows the sums as whole microseconds and
+    ``gateway_emit_to_wire_ms`` holds the first for a scrape. With every
+    engine's profiler off nothing is timed."""
 
     def __init__(self, labels: Dict[str, str]):
+        self.batch_events = 0
         self.tokens = 0
         self.emit_to_wire_s = 0.0
         self.loop_write_s = 0.0
         self.loop_cpu_s = 0.0
-        self._hist = obs.registry().histogram(
+        reg = obs.registry()
+        self._c_batches = reg.counter("gateway_emit_batches_total",
+                                      **labels)
+        self._hist = reg.histogram(
             "gateway_emit_to_wire_ms", buckets=obs.SERVING_MS_BUCKETS,
             **labels)
 
-    def wrote(self, t_push: float, t_dequeue: float, now: float):
+    def handed_over(self, events: int):
+        self._c_batches.inc()
+        self.batch_events += events
+
+    def wrote(self, t_push: float, t_up: float, now: float):
         self.tokens += 1
         self.emit_to_wire_s += now - t_push
-        self.loop_write_s += now - t_dequeue
-        self.loop_cpu_s = time.thread_time()
+        self.loop_write_s += now - t_up
         self._hist.observe((now - t_push) * 1e3)
 
     def snapshot(self) -> Dict[str, int]:
-        return {"stream_tokens": self.tokens,
+        return {"stream_batches": int(self._c_batches.value),
+                "stream_batch_events": self.batch_events,
+                "stream_tokens": self.tokens,
                 "emit_to_wire_us": int(self.emit_to_wire_s * 1e6),
                 "loop_write_us": int(self.loop_write_s * 1e6),
                 "event_loop_cpu_us": int(self.loop_cpu_s * 1e6)}
 
 
 class _WriteSpan:
-    """One timed token's write, as a ``with`` block opened at its
-    dequeue. While open it holds a ``TraceAnnotation("loop/write")``,
-    so a profiler trace shows the loop's line beside the tick thread's
-    ``tick/<phase>`` spans (not a ``tick/`` name: those mark a tick
-    thread's line). A write that raised is not counted."""
-    __slots__ = ("_times", "_t_push", "_t_dequeue", "_ann")
+    """The timed token writes of one stretch on the loop, as a ``with``
+    block: a hand-over's (``Gateway._deliver``: one span for all its
+    tokens) or, in a stream's coroutine, one token's. While open it
+    holds a ``TraceAnnotation("loop/write")``, so a profiler trace shows
+    the loop's line beside the tick thread's ``tick/<phase>`` spans (not
+    a ``tick/`` name: those mark a tick thread's line); at its close it
+    stamps the loop thread's CPU. ``wrote`` counts a token whose write
+    returned, from ``t_up`` (when the loop took it up) or from the
+    token before it; a write that raised is not counted."""
+    __slots__ = ("_times", "_t_up", "_ann")
 
-    def __init__(self, times: _StreamTimes, t_push: float):
-        self._times, self._t_push = times, t_push
-        self._t_dequeue = time.perf_counter()
+    def __init__(self, times: _StreamTimes, t_up: float):
+        self._times, self._t_up = times, t_up
         self._ann = None
 
     def __enter__(self):
@@ -186,16 +206,63 @@ class _WriteSpan:
             self._ann.__enter__()
         return self
 
-    def __exit__(self, *exc):
+    def wrote(self, t_push: float):
         now = time.perf_counter()
+        self._times.wrote(t_push, self._t_up, now)
+        self._t_up = now
+
+    def __exit__(self, *exc):
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        if exc[0] is None:
-            self._times.wrote(self._t_push, self._t_dequeue, now)
+        self._times.loop_cpu_s = time.thread_time()
         return False
 
 
 _NO_SPAN = contextlib.nullcontext()
+
+
+def _sse_frame(ev) -> bytes:
+    """A stream event's bytes on the wire."""
+    if ev[0] == "token":
+        payload = {"token": ev[1], "lp": ev[2]}
+    elif ev[0] == "done":
+        payload = dict(ev[1], done=True)
+    else:
+        payload = {"error": ev[2], "done": True}
+    return b"data: " + json.dumps(payload).encode() + b"\n\n"
+
+
+class _Stream:
+    """A request's end of the hand-over (``ServeRequest.sink``); only
+    the loop's thread touches it. While ``direct``, the hand-over's
+    callback writes the request's token frames to ``transport`` itself.
+    Every other event, and every event behind one, waits in ``pending``
+    (with whether it drew a ``stream_stall``) for the request's
+    coroutine, which ``wake`` rouses and which turns ``direct`` back on
+    once it holds nothing. A slow client's bytes therefore wait in the
+    transport's buffer, a stalled or not yet started stream's events
+    here; neither has a bound (nor had the queue they replace)."""
+    __slots__ = ("pending", "wake", "transport", "direct", "lost")
+
+    def __init__(self):
+        self.pending: deque = deque()
+        self.wake = asyncio.Event()
+        self.transport: Optional[asyncio.Transport] = None
+        self.direct = False
+        self.lost = False
+
+    def write(self, frame: bytes):
+        self.transport.write(frame)
+        if self.transport.is_closing():
+            # a transport's write does not raise on a lost connection:
+            # it closes itself, and the coroutine frees the slot
+            self.direct, self.lost = False, True
+            self.wake.set()
+
+    def hold(self, ev, stall: bool = False):
+        self.direct = False
+        self.pending.append((ev, stall))
+        self.wake.set()
 
 
 def _release_probe(req: ServeRequest, replica, success=None):
@@ -264,6 +331,12 @@ class _ReplicaWorker(threading.Thread):
         # a profiled engine's tokens carry the clock at their push
         # (ISSUE 35; ``_StreamTimes``)
         self._stamp = bool(getattr(replica.engine, "tick_profile", False))
+        # the stream events made since the last hand-over, in order
+        # (ISSUE 36): THE way from this worker to the loop. The lock is
+        # for a failover that emits from the supervisor's thread beside
+        # a wedged dispatch; nothing else ever contends for it
+        self._out: List[Any] = []
+        self._out_lock = threading.Lock()
         # orders token emission against the failover snapshot: the
         # tick thread holds it across _dispatch, the failover path
         # holds it while latching ``abandoned`` and snapshotting/
@@ -360,12 +433,29 @@ class _ReplicaWorker(threading.Thread):
             self._trace_finish(req, "disconnect")
 
     def _emit(self, req: ServeRequest, ev):
+        """Buffer a stream event; ``_flush`` hands the buffer over.
+        Whoever emits flushes before it returns to anything that may
+        block."""
         if req.sink is None:
             return
-        try:
-            self.gw._loop.call_soon_threadsafe(req.sink.put_nowait, ev)
-        except RuntimeError:   # loop already closed (teardown)
-            pass
+        with self._out_lock:
+            self._out.append((req, ev))
+
+    def _flush(self):
+        """Hand every buffered event to the loop at once: one
+        ``call_soon_threadsafe``, so one write to the loop's wake-up
+        pipe, whatever the tick made (``Gateway._deliver`` takes it
+        from there). Made under the buffer's lock, so hand-overs reach
+        the loop in the order of their events."""
+        with self._out_lock:
+            if not self._out:
+                return
+            batch, self._out = self._out, []
+            try:
+                self.gw._loop.call_soon_threadsafe(
+                    self.gw._deliver, batch, self._stamp)
+            except RuntimeError:   # loop already closed (teardown)
+                pass
 
     # ------------------------------------------------------------ tick loop
     def run(self):
@@ -416,6 +506,9 @@ class _ReplicaWorker(threading.Thread):
                 while (req := self._pop_admissible()) is not None:
                     self._admit(req, time.monotonic())
                 self._set_capacity_gauges()
+                # what expiry, a refused admission or a posted op
+                # emitted must not wait for a tick
+                self._flush()
             if eng.queue or any(s is not None for s in eng.slots):
                 chaos, self._chaos = self._chaos, None
                 try:
@@ -570,6 +663,7 @@ class _ReplicaWorker(threading.Thread):
             _release_probe(req, self.replica)
             self._emit(req, ("error", status, msg))
             self._trace_finish(req, "error")
+        self._flush()
 
     # ------------------------------------------------------------ dispatch
     def _token_out(self, req: ServeRequest, tok: int, now: float,
@@ -647,7 +741,8 @@ class _ReplicaWorker(threading.Thread):
 
     def _dispatch(self):
         """Push this tick's newly emitted tokens (stream()'s hold-back
-        rule, verbatim) and resolve finished / aborted requests."""
+        rule, verbatim) and resolve finished / aborted requests; then
+        hand all of it to the loop at once."""
         eng = self.engine
         now = time.monotonic()
         for s in eng.slots:
@@ -692,6 +787,7 @@ class _ReplicaWorker(threading.Thread):
             reason = eng.cancelled.pop(rid)
             self._finish(req, {"tokens": [],
                                "finish_reason": reason}, now)
+        self._flush()
 
 
 class Gateway:
@@ -1050,6 +1146,7 @@ class Gateway:
                           "finish_reason": "stop"}, now)
                 continue
             self._resubmit(req, desc.get(req.request_id), worker)
+        worker._flush()
         obs.record_event("gateway_replica_fail", gateway=self.name,
                          replica=worker.replica.name, reason=reason,
                          moved=len(live) + len(queued),
@@ -1395,6 +1492,7 @@ class Gateway:
                 pass
             worker._live.pop(rid, None)
             self._c_migrated.inc()
+        worker._flush()
         obs.record_event("gateway_migrate_out", gateway=self.name,
                          replica=worker.replica.name,
                          moved=int(self._c_migrated.value))
@@ -1639,8 +1737,9 @@ class Gateway:
                 / max(self._c_tokens.value, 1.0), 4),
             "ttft_ms": self._h_ttft.stats(),
             "tpot_ms": self._h_tpot.stats(),
-            # the event loop's half of a round (ISSUE 35): zeros unless
-            # an engine runs its tick profiler
+            # the event loop's half of a round: the hand-overs it ran
+            # and their events (ISSUE 36) and, zeros unless an engine
+            # runs its tick profiler, the token writes' times (ISSUE 35)
             "stream": self._stream_times.snapshot(),
             "router": self._router.snapshot(),
             "replicas": {
@@ -1952,7 +2051,7 @@ class Gateway:
             tenant=str(spec.get("tenant", "default")),
             priority=int(spec.get("priority", 0)),
             deadline=deadline, digest=digest,
-            sink=asyncio.Queue(), stream=bool(spec.get("stream", True)))
+            sink=_Stream(), stream=bool(spec.get("stream", True)))
 
     def _consume_resume_kv(self, ref: str):
         """Make a ``resume_kv`` span arena-resident BEFORE admission,
@@ -2115,70 +2214,96 @@ class Gateway:
         w = req.owner or worker
         w.post(lambda: w.cancel_request(req.request_id, req))
 
+    def _deliver(self, batch, timed: bool):
+        """A worker's hand-over, on the loop (ISSUE 36): what a tick
+        made, in the worker's order. A token of a stream that is
+        ``direct`` is framed and written to its transport here: no
+        Task, no Future, no ``asyncio.wait`` a token. Any other event
+        waits for its request's coroutine (``_Stream``): a ``done`` or
+        ``error``, the events of a stream not yet started or behind a
+        ``stream_stall``, which holds back THAT stream only. A JSON
+        client reads the final list and gets no token. ``timed``: the
+        worker's engine runs its profiler and its tokens carry their
+        push time."""
+        times = self._stream_times
+        times.handed_over(len(batch))
+        span = _WriteSpan(times, time.perf_counter()) if timed \
+            else _NO_SPAN
+        with span:
+            for req, ev in batch:
+                stream = req.sink
+                if ev[0] != "token":
+                    stream.hold(ev)
+                elif req.stream:
+                    stall = faults.inject("stream_stall",
+                                          request=str(req.request_id))
+                    if stall or not stream.direct:
+                        stream.hold(ev, stall)
+                    else:
+                        stream.write(_sse_frame(ev))
+                        if timed:
+                            span.wrote(ev[3])
+
     async def _stream_sse(self, worker, req, reader, writer):
+        """A stream's coroutine: everything of the stream but a running
+        stream's tokens, which ``_deliver`` writes. It sends the head,
+        watches for the peer's EOF, and writes in order whatever waits
+        in ``stream.pending``; holding nothing, it lets the callback
+        write (``direct``) and sleeps until ``stream.wake``."""
+        stream = req.sink
         try:
             writer.write(_SSE_HEAD)
             await writer.drain()
         except (ConnectionError, OSError):
             self._on_disconnect(worker, req)
             return
+        stream.transport = writer.transport
         eof = asyncio.ensure_future(reader.read())
+        eof.add_done_callback(lambda _: stream.wake.set())
         try:
             while True:
-                get = asyncio.ensure_future(req.sink.get())
-                if eof is None:
-                    ev = await get
-                else:
-                    done, _ = await asyncio.wait(
-                        {get, eof},
-                        return_when=asyncio.FIRST_COMPLETED)
-                    if get not in done:
-                        # read side closed. A dropped client AND a
-                        # legal HTTP half-close (shutdown(SHUT_WR)
-                        # after the body, still reading the response)
-                        # both look like EOF here — probe with an SSE
-                        # comment: only a truly dead peer fails the
-                        # write. Later token writes keep catching
-                        # disconnects once the watch is off.
-                        get.cancel()
-                        try:
-                            writer.write(b": half-close probe\n\n")
-                            await writer.drain()
-                        except (ConnectionError, OSError):
-                            self._on_disconnect(worker, req)
-                            return
-                        eof = None
-                        continue
-                    ev = get.result()
-                # a profiled replica's token carries the clock at its
-                # push: its way to the socket is timed from here
-                span = _NO_SPAN if len(ev) < 4 \
-                    else _WriteSpan(self._stream_times, ev[3])
-                try:
-                    if ev[0] == "token":
-                        payload = {"token": ev[1], "lp": ev[2]}
-                        if faults.inject("stream_stall",
-                                         request=str(req.request_id)):
-                            # slow client / congested wire stand-in:
-                            # stalls THIS coroutine only — the tick
-                            # loop and sibling streams keep moving
-                            await asyncio.sleep(
-                                faults.stream_stall_seconds())
-                    elif ev[0] == "done":
-                        payload = dict(ev[1], done=True)
-                    else:
-                        payload = {"error": ev[2], "done": True}
+                stream.wake.clear()
+                while stream.pending:
+                    ev, stall = stream.pending.popleft()
+                    # a profiled replica's token carries the clock at
+                    # its push: its way to the socket is timed from here
+                    timed = len(ev) > 3
+                    span = _WriteSpan(self._stream_times,
+                                      time.perf_counter()) if timed \
+                        else _NO_SPAN
+                    if stall:
+                        # slow client / congested wire stand-in: stalls
+                        # THIS stream only — the tick loop and sibling
+                        # streams keep moving
+                        await asyncio.sleep(faults.stream_stall_seconds())
                     with span:
-                        writer.write(b"data: "
-                                     + json.dumps(payload).encode()
-                                     + b"\n\n")
+                        writer.write(_sse_frame(ev))
+                        if timed:
+                            span.wrote(ev[3])
                     await writer.drain()
-                except (ConnectionError, OSError):
+                    if ev[0] != "token":
+                        return
+                if stream.lost:
                     self._on_disconnect(worker, req)
                     return
-                if ev[0] != "token":
-                    return
+                if eof is not None and eof.done():
+                    # read side closed. A dropped client AND a legal
+                    # HTTP half-close (shutdown(SHUT_WR) after the
+                    # body, still reading the response) both look like
+                    # EOF here — probe with an SSE comment: only a
+                    # truly dead peer fails the write. Later token
+                    # writes keep catching disconnects once the watch
+                    # is off.
+                    eof = None
+                    writer.write(b": half-close probe\n\n")
+                    await writer.drain()
+                    continue
+                stream.direct = True
+                await stream.wake.wait()
+        except (ConnectionError, OSError):
+            self._on_disconnect(worker, req)
         finally:
+            stream.direct = False
             if eof is not None and not eof.done():
                 eof.cancel()
 
@@ -2186,28 +2311,28 @@ class Gateway:
         # no EOF watch here: a JSON response can't carry a mid-wait
         # probe, and a legal half-closing client must still get its
         # response — a vanished one costs only the final failed write
-        while True:
-            ev = await req.sink.get()
-            if ev[0] == "token":
-                continue
-            try:
-                if ev[0] == "error":
+        stream = req.sink
+        while not stream.pending:
+            stream.wake.clear()
+            await stream.wake.wait()
+        ev, _ = stream.pending.popleft()
+        try:
+            if ev[0] == "error":
+                writer.write(_json_response(
+                    ev[1], {"error": ev[2],
+                            "request_id": req.request_id}))
+            else:
+                info = ev[1]
+                reason = info.get("finish_reason", "stop")
+                if reason == "timeout":
                     writer.write(_json_response(
-                        ev[1], {"error": ev[2],
-                                "request_id": req.request_id}))
+                        504, {"error": "deadline exceeded",
+                              "request_id": req.request_id,
+                              "finish_reason": reason}))
                 else:
-                    info = ev[1]
-                    reason = info.get("finish_reason", "stop")
-                    if reason == "timeout":
-                        writer.write(_json_response(
-                            504, {"error": "deadline exceeded",
-                                  "request_id": req.request_id,
-                                  "finish_reason": reason}))
-                    else:
-                        writer.write(_json_response(
-                            200, dict(info,
-                                      request_id=req.request_id)))
-                await writer.drain()
-            except (ConnectionError, OSError):
-                pass
-            return
+                    writer.write(_json_response(
+                        200, dict(info,
+                                  request_id=req.request_id)))
+            await writer.drain()
+        except (ConnectionError, OSError):
+            pass

@@ -139,7 +139,7 @@ SITES: Dict[str, Tuple[str, str]] = {
         "kill stand-in; exercises the supervisor's dead-thread "
         "detection + failover — nothing on the dying thread runs)"),
     "stream_stall": (
-        "paddle_tpu/serving/gateway.py:Gateway._stream_sse",
+        "paddle_tpu/serving/gateway.py:Gateway._deliver",
         "sleep PADDLE_TPU_FAULT_STREAM_STALL_S (default 5) in the SSE "
         "writer before the next token event (slow client / congested "
         "wire stand-in; one stalled stream must not stall the replica "

@@ -21,6 +21,12 @@ Contracts pinned here:
   stretch are summed (they grow with an injected ``stream_stall``),
   ``/metrics`` holds the same sums, and with the profiler off the token
   event, the SSE bytes and the counters are what they were.
+- ONE HAND-OVER A TICK (ISSUE 36): what a tick made crosses to the
+  event loop in one ``call_soon_threadsafe`` whatever the rows, an emit
+  outside a tick reaches its client without one, a request's order
+  (tokens, then ``done``) holds across hand-overs and beside a stalled
+  sibling, and ``stream_batches`` / ``stream_batch_events`` count the
+  hand-overs and their events with the profiler on or off.
 - LIFECYCLE: SIGTERM drains (finish in-flight, 503 new work, flush
   metrics); an SSE client dropping mid-stream frees its slot/blocks
   via ``PagedEngine.cancel`` (no stranded slots); saturation sheds
@@ -433,18 +439,26 @@ STREAM_REQS = [
 ]
 
 
-def _stream_run(name, stall_s=None, **engine_kw):
+def _stream_run(name, stall_s=None, stall="stream_stall@0+", **engine_kw):
     """STREAM_REQS through a gateway over real HTTP: the raw SSE bodies,
-    ``health()`` and the ``/metrics`` text after them."""
+    ``health()`` and the ``/metrics`` text after them, and when each
+    body ended (seconds after the requests were sent)."""
     async def run():
         gw = Gateway(_engine(**engine_kw), name=name)
         await gw.start()
+        ended = {}
+        t0 = time.monotonic()
+
+        async def one(i, r):
+            raw = await _raw_sse(gw.port, dict(r, stream=True))
+            ended[i] = time.monotonic() - t0
+            return raw
         try:
             raws = await asyncio.gather(
-                *[_raw_sse(gw.port, dict(r, stream=True))
-                  for r in STREAM_REQS])
+                *[one(i, r) for i, r in enumerate(STREAM_REQS)])
             _, _, prom = await _http(gw.port, "GET", "/metrics")
-            return raws, gw.health(), prom.decode()
+            health = dict(gw.health(), ended=ended)
+            return raws, health, prom.decode()
         finally:
             await gw.drain()
     if stall_s is None:
@@ -452,10 +466,14 @@ def _stream_run(name, stall_s=None, **engine_kw):
     from paddle_tpu.utils import faults
     os.environ[faults.STREAM_STALL_ENV_VAR] = str(stall_s)
     try:
-        with faults.scoped("stream_stall@0+"):
+        with faults.scoped(stall):
             return asyncio.run(run())
     finally:
         del os.environ[faults.STREAM_STALL_ENV_VAR]
+
+
+TIMED_KEYS = {"stream_tokens", "emit_to_wire_us", "loop_write_us",
+              "event_loop_cpu_us"}
 
 
 def _prom_value(prom, name, gateway):
@@ -471,8 +489,7 @@ def test_stream_times_count_each_token_once_and_grow_with_a_stall(stall_s):
     tokens = sum(r["max_new_tokens"] for r in STREAM_REQS)
     assert sum(raw.count(b'data: {"token"') for raw in raws) == tokens
     st = health["stream"]
-    assert set(st) == {"stream_tokens", "emit_to_wire_us", "loop_write_us",
-                       "event_loop_cpu_us"}
+    assert set(st) == TIMED_KEYS | {"stream_batches", "stream_batch_events"}
     assert all(isinstance(v, int) for v in st.values())
     assert st["stream_tokens"] == tokens == health["tokens"]
     # a token is pushed before the loop takes it up
@@ -495,7 +512,7 @@ def test_profile_off_the_token_event_the_bytes_and_the_counters_are_the_parents(
     raws_off, health, prom = _stream_run("t-wire-off")
     raws_on, _, _ = _stream_run("t-wire-on", tick_profile=True)
     assert raws_on == raws_off          # the stamp never reaches the wire
-    assert set(health["stream"].values()) == {0}
+    assert {health["stream"][k] for k in TIMED_KEYS} == {0}
     assert _prom_value(prom, "gateway_emit_to_wire_ms_count",
                        "t-wire-off") == 0
     # the bytes, spelt out: what the parent's writer wrote
@@ -522,6 +539,223 @@ def test_profile_off_the_token_event_the_bytes_and_the_counters_are_the_parents(
         assert got[0][:3] == ("token", 5, -0.5) and len(got[0]) == width
         if profiled:
             assert t0 <= got[0][3] <= time.perf_counter()
+
+
+class _RecordingLoop:
+    """Stands where the gateway's event loop would: keeps what the tick
+    thread hands over; ``run()`` is the loop's turn."""
+
+    def __init__(self):
+        self.calls = []
+
+    def call_soon_threadsafe(self, fn, *args):
+        self.calls.append((fn, args))
+
+    def run(self):
+        calls, self.calls = self.calls, []
+        for fn, args in calls:
+            fn(*args)
+
+
+def _streamed(rid, prompt=(1, 2, 3, 4, 5), **gen):
+    from paddle_tpu.serving.gateway import _Stream
+    return ServeRequest(rid, list(prompt), dict({"max_new_tokens": 4}, **gen),
+                        sink=_Stream(), stream=True)
+
+
+@pytest.mark.parametrize("profiled", [False, True], ids=["off", "profiled"])
+@pytest.mark.parametrize("rows", [1, 8])
+def test_a_dispatch_hands_a_tick_over_once_whatever_the_rows(rows, profiled):
+    gw = Gateway(_engine(max_slots=rows, tick_profile=profiled),
+                 name=f"t-once-{rows}-{profiled}")
+    loop = gw._loop = _RecordingLoop()
+    worker, eng = gw._workers[0], gw._workers[0].engine
+    reqs = [_streamed(f"r{i}", prompt=range(1 + i, 7 + i))
+            for i in range(rows)]
+    for req in reqs:
+        worker._admit(req, time.monotonic())
+    seen = {r.request_id: [] for r in reqs}
+    ticks = 0
+    while worker._live:
+        eng.step()
+        worker._dispatch()
+        # ONE crossing a dispatch that made anything, none for one that
+        # made nothing
+        assert len(loop.calls) <= 1
+        for fn, (batch, timed) in loop.calls:
+            assert fn == gw._deliver and timed is profiled
+            ticks += 1
+            if ticks > 2:       # past the prefill steps every row decodes
+                assert sum(ev[0] == "token" for _, ev in batch) >= rows - 1
+            for req, ev in batch:
+                seen[req.request_id].append(ev)
+        before = gw.health()["stream"]
+        loop.run()
+        after = gw.health()["stream"]
+        assert after["stream_batches"] - before["stream_batches"] <= 1
+    for req in reqs:
+        evs = seen[req.request_id]
+        # a request's order: its tokens, then one done that lists them
+        assert [e[0] for e in evs] == ["token"] * 4 + ["done"]
+        assert [e[1] for e in evs[:4]] == evs[4][1]["tokens"]
+        assert all(len(e) == (4 if profiled else 3) for e in evs[:4])
+        # a stream whose coroutine has not started holds them, in order
+        assert [ev for ev, _ in req.sink.pending] == evs
+    st = gw.health()["stream"]
+    assert st["stream_batches"] == ticks
+    assert st["stream_batch_events"] == 5 * rows
+    assert st["stream_tokens"] == 0         # nothing was written
+    if rows == 8:
+        assert st["stream_batch_events"] / st["stream_batches"] > 4
+
+
+def test_concurrent_emitters_lose_and_reorder_nothing():
+    """The buffer's lock: a failover may emit from the supervisor's
+    thread beside a dispatch. Many threads emit and flush through ONE
+    worker: every event crosses once, and each thread's events, and the
+    hand-overs that carry them, keep their order."""
+    import sys
+    import threading
+    gw = Gateway(_engine(), name="t-emit-race")
+    loop = gw._loop = _RecordingLoop()
+    worker = gw._workers[0]
+    n_threads, n_events = 16, 400
+    reqs = [_streamed(f"e{i}") for i in range(n_threads)]
+
+    def emitter(i):
+        for k in range(n_events):
+            worker._emit(reqs[i], ("token", k, None))
+            if k % 7 == i % 7:
+                worker._flush()
+        worker._flush()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=emitter, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert worker._out == []
+    seen = {r.request_id: [] for r in reqs}
+    for fn, (batch, _) in loop.calls:
+        assert batch                        # no empty hand-over
+        for req, ev in batch:
+            seen[req.request_id].append(ev[1])
+    assert all(v == list(range(n_events)) for v in seen.values())
+
+
+def test_an_emit_outside_a_tick_reaches_its_client_without_one():
+    """An admission the engine refuses and a dead worker's queue flush
+    emit outside ``_dispatch``: the client is answered though no tick
+    runs."""
+    async def refused():
+        eng = _engine()
+        gw = Gateway(eng, name="t-notick")
+        await gw.start()
+        try:
+            out = await asyncio.wait_for(_sse(
+                gw.port, dict(prompt=[1, 2, 3], max_new_tokens=4,
+                              repetition_penalty=0.0)), 10)
+            return out, eng.stats["decode_steps"], gw.health()["stream"]
+        finally:
+            await gw.drain()
+
+    (status, _, toks, fin), steps, st = asyncio.run(refused())
+    assert status == 200 and toks == [] and fin["done"] and fin["error"]
+    assert steps == 0
+    assert st["stream_batches"] == 1 and st["stream_batch_events"] == 1
+
+    async def flushed():
+        gw = Gateway(_engine(), name="t-notick-flush")
+        gw._loop = asyncio.get_running_loop()     # its workers never run
+        worker = gw._workers[0]
+        reqs = [_streamed("q0"), _streamed("q1")]
+        for req in reqs:
+            worker.sched.enqueue(req)
+        worker.flush_queue(503, "dead worker")
+        await asyncio.sleep(0)
+        return [list(r.sink.pending) for r in reqs], gw.health()["stream"]
+
+    pending, st = asyncio.run(flushed())
+    assert pending == [[(("error", 503, "dead worker"), False)]] * 2
+    assert st["stream_batches"] == 1 and st["stream_batch_events"] == 2
+
+
+def test_a_stalled_stream_holds_no_sibling_back_and_keeps_its_bytes():
+    """ONE token draws a ``stream_stall``: its stream waits it out, its
+    siblings end before it does, and every stream's bytes, the stalled
+    one's too, are what they are with no stall."""
+    stall_s = 2.0
+    plain, _, _ = _stream_run("t-stall-plain")
+    raws, health, _ = _stream_run("t-stall-one", stall_s,
+                                  stall="stream_stall@0")
+    assert raws == plain
+    # the siblings are done about a stall before the stalled stream is
+    # (not "inside a stall of the start": a loaded machine's first tick
+    # is slow for all three)
+    ended = sorted(health["ended"].values())
+    assert ended[-1] >= stall_s and ended[-1] - ended[-2] > stall_s / 2
+    for raw in raws:            # tokens, then done, on every stream
+        kinds = [b'"done": true' in ln for ln in raw.split(b"\n\n") if ln]
+        assert kinds == [False] * (len(kinds) - 1) + [True]
+
+
+@pytest.mark.parametrize("profiled", [False, True], ids=["off", "profiled"])
+def test_hand_overs_and_their_events_are_counted_profiler_on_or_off(profiled):
+    name = f"t-batches-{profiled}"
+    raws, health, prom = _stream_run(name, tick_profile=profiled)
+    st = health["stream"]
+    tokens = sum(r["max_new_tokens"] for r in STREAM_REQS)
+    # every event crossed in a hand-over: the tokens and a done a stream
+    assert st["stream_batch_events"] == tokens + len(STREAM_REQS)
+    # and a hand-over is a tick's, not a token's: three streams decode
+    # side by side, so there are far fewer hand-overs than events
+    longest = max(r["max_new_tokens"] for r in STREAM_REQS)
+    assert longest <= st["stream_batches"] <= longest + 6
+    assert _prom_value(prom, "gateway_emit_batches_total", name) \
+        == st["stream_batches"]
+    assert st["stream_tokens"] == (tokens if profiled else 0)
+
+
+def test_the_benchmarks_reader_divides_events_by_hand_overs():
+    """``benchmarks/layer_metrics/emit_batch_events.sat.py`` over the
+    window's two snapshots; nothing, and no raise, for a program whose
+    ``health()["stream"]`` lacks the counters (the parent)."""
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    spec = importlib.util.spec_from_file_location(
+        "emit_batch_events_sat", os.path.join(
+            root, "benchmarks", "layer_metrics", "emit_batch_events.sat.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    def sources(a, b):
+        return {"snaps": {"w0": {"health": {"stream": a}},
+                          "w1": {"health": {"stream": b}}}}
+    old = {"stream_tokens": 5, "emit_to_wire_us": 0, "loop_write_us": 0,
+           "event_loop_cpu_us": 0}
+    assert reader.reduce(sources(old, old)) is None
+    assert reader.reduce({"snaps": {"w0": {"health": {}},
+                                    "w1": {"health": {}}}}) is None
+    a = dict(old, stream_batches=10, stream_batch_events=100)
+    assert reader.reduce(sources(a, a)) is None       # no hand-over
+    b = dict(old, stream_batches=110, stream_batch_events=6400)
+    assert reader.reduce(sources(a, b)) == pytest.approx(63.0)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    entry = manifest["per_layer"][-1]
+    assert (entry["name"], entry["unit"], entry["better"], entry["source"],
+            entry["layer"], entry["moves"]) == (
+        reader.NAME, reader.UNIT, "higher", reader.SOURCE, reader.LAYER,
+        reader.MOVES)
+    assert entry["workloads"] == [
+        w["name"] for w in manifest["workloads"]
+        if w["name"] != "qwen2-7b-d16.chat"]
 
 
 def test_gateway_sheds_429_with_retry_after():
