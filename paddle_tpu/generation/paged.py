@@ -125,7 +125,8 @@ import numpy as np
 from ..utils import observability as obs
 from ..utils.faults import BackpressureError
 
-__all__ = ["PagedKV", "CacheLayer", "PagedEngine"]
+__all__ = ["PagedKV", "CacheLayer", "StateLayer", "SlotState",
+           "PagedEngine"]
 
 # unique per-process engine label: every engine's counters live in the
 # global observability registry (scrapeable), while `stats`/`health()`
@@ -202,6 +203,59 @@ class CacheLayer(NamedTuple):
     only the band its queries still reach (``PagedKV.ring``)."""
     rows: tuple
     window: Optional[int] = None
+
+
+class StateLayer(NamedTuple):
+    """``CacheLayer``'s sibling (``paged_cache_layers``): a layer that
+    caches no token's rows but keeps recurrent STATE, which belongs to a
+    SLOT and not to a block. ``arrays``: the (shape, dtype) of each
+    array ONE slot keeps (a linear-attention layer: its heads' matrix
+    state, its convolution's last inputs); the engine holds each as
+    ``[max_slots, *shape]`` beside the pools and hands the layer a
+    ``SlotState``. Such state does not grow with the context, is
+    addressed by no block table, starts from zero with every request
+    and cannot be adopted, spilled, shipped or rolled back by block."""
+    arrays: tuple
+    rows = ()           # no pool array of cached tokens
+    window = None       # and no band of them
+
+
+class SlotState(NamedTuple):
+    """Per-layer view of a ``StateLayer``'s arrays handed to the model
+    where a K/V layer gets a ``PagedKV``.
+
+    arrays: the engine's ``[max_slots, ...]`` arrays of this layer, whole.
+    slots: [rows] the slot of each row of the call (a packed call's
+    segments; a chunk's one slot; ``max_slots`` for a dead segment,
+    whose result is dropped). None in a decode tick: row r is slot r.
+    seq_lens: [rows], as ``PagedKV.seq_lens``: a position at or past its
+    row's is padding and must change nothing.
+    live: decode ticks, [max_slots] bool: the rows that advance; any
+    other row's state stays as it is (a free slot, a row mid-prefill,
+    a row that finished on the device).
+    fresh: prompt calls, [rows] bool: the row starts from ZERO state,
+    whatever its slot held; else from the state its slot holds (the
+    chunk before it left it there). Only row 0 may carry; the later
+    segments of a packed call start at position 0."""
+    arrays: tuple
+    slots: Any = None
+    seq_lens: Any = None
+    live: Any = None
+    fresh: Any = None
+
+    @property
+    def pool(self) -> tuple:
+        """This layer's arrays, as the engine holds them."""
+        return tuple(self.arrays)
+
+
+# what an engine over state layers adds up inside its programs beside
+# the model's own tick counters: state layers x decode ticks, live rows
+# x state layers, and the prompt segments that started from zero / from
+# the state the chunk before them left (counted in the chunk programs
+# into ``pools[-1]`` and moved to the token ring by the next tick)
+_STATE_COUNTERS = ("state_layer_ticks", "state_rows_updated",
+                   "state_resets", "state_carries")
 
 
 # what a band-keeping engine adds up inside a tick, over the live rows
@@ -956,6 +1010,10 @@ class PagedEngine:
         self._layout = self._cache_layout()
         self._windows = tuple(l.window for l in self._layout
                               if l.window is not None)
+        # how many layers keep state by slot (``StateLayer``; none in
+        # most models, and everything that reads this is then as it was)
+        self._n_state = sum(isinstance(l, StateLayer)
+                            for l in self._layout)
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -978,6 +1036,18 @@ class PagedEngine:
                 "blocks there were released as the prompt advanced, so "
                 "there is nothing to adopt (adoption over band-keeping "
                 "layers is not built)")
+        if enable_prefix_cache and self._n_state:
+            raise ValueError(
+                "enable_prefix_cache: this model has layers that keep "
+                "recurrent state by slot; a prefix's blocks hold no "
+                "state to adopt, and the state behind a prefix is kept "
+                "nowhere (snapshots of it are not built)")
+        if self._spec_k and self._n_state:
+            raise ValueError(
+                "spec_tokens: this model has layers that keep recurrent "
+                "state; a rejected draft's positions cannot be taken "
+                "back out of it (verify-then-commit over state layers is "
+                "not built)")
         self.prefix_caching = bool(enable_prefix_cache)
         self.prefix_cache: Dict[tuple, tuple] = {}   # key -> block ids
         self._prefix_rev: Dict[int, set] = {}        # block -> keys
@@ -1029,7 +1099,8 @@ class PagedEngine:
         self._model_counter_names = tuple(
             getattr(model, "tick_counters", tuple)())
         self._tick_counter_names = self._model_counter_names + (
-            _BAND_COUNTERS if self._windows else ())
+            _BAND_COUNTERS if self._windows else ()) + (
+            _STATE_COUNTERS if self._n_state else ())
         self._tick_counts_seen = np.zeros(
             (len(self._tick_counter_names),), np.int64)
         # runahead_ticks: decode dispatches made while another was
@@ -1293,7 +1364,8 @@ class PagedEngine:
             else self.model.config.num_hidden_layers
         if isinstance(layers, int):
             return [CacheLayer(tuple(self._cache_rows()))] * layers
-        return [CacheLayer(*layer) for layer in layers]
+        return [layer if isinstance(layer, StateLayer)
+                else CacheLayer(*layer) for layer in layers]
 
     def _ring_blocks(self, window: int) -> int:
         """Pages a slot of a band-keeping layer's ring: the window, what
@@ -1325,12 +1397,19 @@ class PagedEngine:
         cfg = self.model.config
         pools = []
         for layer in self._layout:
+            if isinstance(layer, StateLayer):   # a slot's, not a block's
+                pools.append(tuple(self._zeros((self.R,) + tuple(shape),
+                                               dtype)
+                                   for shape, dtype in layer.arrays))
+                continue
             # a band-keeping layer: a ring a slot and the garbage block
             P = self.P if layer.window is None \
                 else self.R * self._ring_blocks(layer.window) + 1
             pools.append(tuple(
                 self._zeros((P, self.B, heads * width), cfg.dtype)
                 for heads, width in layer.rows))
+        if self._n_state:       # ``state_resets``, ``state_carries``
+            pools.append((self._zeros((2,), jnp.int32),))
         return pools, self._zeros((self.R, cfg.vocab_size), bool)
 
     def decode_route(self) -> str:
@@ -1342,6 +1421,8 @@ class PagedEngine:
         cfg = self.model.config
         routes = set()
         for layer, pool in zip(self._layout, self.pools):
+            if not layer.rows:                  # no K/V to attend over
+                continue
             heads, width = layer.rows[0]        # a query is as wide as
             q = jax.ShapeDtypeStruct(           # a cached (key) head
                 (self.R, self._spec_k + 1, cfg.num_attention_heads, width),
@@ -1445,14 +1526,21 @@ class PagedEngine:
         self._counters[key].inc(n)
 
     # ------------------------------------------------------------ jitted
-    def _paged_caches(self, pools, tables, lens, slots=None):
+    def _paged_caches(self, pools, tables, lens, slots=None, live=None,
+                      fresh=None):
         """Each cache layer's view of ``pools`` for the rows of
         ``tables`` [rows, M], which are the engine's slots in order
         unless ``slots`` [rows] names them. A band-keeping layer's table
         is not the allocator's: it is the ring of pages its slot owns
-        by position, block 0 of its pool being the garbage block."""
+        by position, block 0 of its pool being the garbage block. A
+        state layer's view is a ``SlotState``: ``live`` (a decode
+        tick's rows that advance) and ``fresh`` (a prompt call's rows
+        that start from zero) are read by it alone."""
         out = []
         for layer, p in zip(self._layout, pools):
+            if isinstance(layer, StateLayer):
+                out.append(SlotState(tuple(p), slots, lens, live, fresh))
+                continue
             tbl = tables
             if layer.window is not None:
                 Mw = self._ring_blocks(layer.window)
@@ -1467,7 +1555,7 @@ class PagedEngine:
     def _decode_step(self, params, pools, tables, lens, last_tokens,
                      keys, temps, tks, tps, seen, reps, active):
         from .sampling import repetition_penalty_rows, sample_token_rows
-        caches = self._paged_caches(pools, tables, lens)
+        caches = self._paged_caches(pools, tables, lens, live=active)
         logits, new_caches = self.fn(params, last_tokens[:, None],
                                      kv_caches=caches,
                                      positions=lens[:, None])
@@ -1479,7 +1567,7 @@ class PagedEngine:
         # the seen analogue of the authoritative req.key protection
         seen = seen.at[jnp.arange(self.R), nxt].max(active)
         return (nxt, lps, new_keys, seen,
-                [c.pool for c in new_caches])
+                self._pools_out(new_caches, pools))
 
     def _decode_step_greedy(self, params, pools, tables, lens,
                             last_tokens, seen, reps, active):
@@ -1489,7 +1577,7 @@ class PagedEngine:
         repetition_penalty is still deterministic, so the penalty rides
         here too (a no-op where() for all-1.0 rows — bit-exact)."""
         from .sampling import repetition_penalty_rows
-        caches = self._paged_caches(pools, tables, lens)
+        caches = self._paged_caches(pools, tables, lens, live=active)
         logits, new_caches = self.fn(params, last_tokens[:, None],
                                      kv_caches=caches,
                                      positions=lens[:, None])
@@ -1499,7 +1587,31 @@ class PagedEngine:
         lps = jnp.take_along_axis(jax.nn.log_softmax(raw, axis=-1),
                                   nxt[:, None], axis=-1)[:, 0]
         seen = seen.at[jnp.arange(self.R), nxt].max(active)
-        return nxt, lps, seen, [c.pool for c in new_caches]
+        return nxt, lps, seen, self._pools_out(new_caches, pools)
+
+    def _pools_out(self, new_caches, pools, events=None, moved=False):
+        """The pools a program hands back: each cache layer's arrays
+        and, behind them in an engine over state layers, the two
+        counters its prompt calls add up (``_STATE_COUNTERS``): those of
+        ``pools`` plus ``events`` (None: as they were), or zeros once a
+        tick has ``moved`` them to the token ring."""
+        out = [c.pool for c in new_caches]
+        if self._n_state:
+            ev = pools[-1][0]
+            out.append((jnp.zeros_like(ev) if moved
+                        else ev if events is None else ev + events,))
+        return out
+
+    def _state_events(self, fresh, real=True):
+        """A prompt call's (``state_resets``, ``state_carries``): of its
+        ``real`` rows (all of them unless given), those that the
+        ``fresh`` handed to the state layers starts from zero, and the
+        others. None for an engine without state layers (its ``fresh``
+        is None)."""
+        if fresh is None:
+            return None
+        return jnp.stack([jnp.sum(real & fresh),
+                          jnp.sum(real & ~fresh)]).astype(jnp.int32)
 
     # ------------------------------------------- fused device-resident tick
     def _tick_counts(self, st):
@@ -1533,12 +1645,26 @@ class PagedEngine:
         live = st["active"].astype(seen.dtype)
         return jnp.stack([jnp.sum(c * live) for c in (wb, fb, wt, ft, rel)])
 
-    def _ring_counts(self, ring, counts, st):
+    def _state_counts(self, st, events):
+        """``_STATE_COUNTERS`` of this tick (None for an engine without
+        state layers): every state layer ran once and updated the live
+        rows; ``events`` is what the prompt calls since the last tick
+        added up."""
+        if not self._n_state:
+            return None
+        n = jnp.int32(self._n_state)
+        return jnp.concatenate([
+            jnp.stack([n, n * jnp.sum(st["active"].astype(jnp.int32))]),
+            events])
+
+    def _ring_counts(self, ring, counts, st, events=None):
         """The tick's counters (the model's, then the engine's own of a
-        band-keeping cache) added into the ring's spare row, which the
-        drain reads with the tokens: no array of their own."""
+        band-keeping cache and of state layers) added into the ring's
+        spare row, which the drain reads with the tokens: no array of
+        their own."""
         parts = [c.astype(ring.dtype)
-                 for c in (counts.total, self._band_counts(st))
+                 for c in (counts.total, self._band_counts(st),
+                           self._state_counts(st, events))
                  if c is not None]
         if not parts:
             return ring
@@ -1546,7 +1672,7 @@ class PagedEngine:
         return ring.at[self.R, :total.shape[0]].add(total)
 
     def _fused_epilogue(self, st, new_caches, seen, nxt, lps, new_keys,
-                        counts):
+                        counts, pools):
         """Device-side tick bookkeeping: advance active rows' lengths /
         last tokens / budgets, fold the emitted token into the seen
         mask, and derive the done flag (eos hit or budget exhausted —
@@ -1571,12 +1697,13 @@ class PagedEngine:
         idx = st["wcur"] % st["ring"].shape[1]
         new_st.update(
             ring=self._ring_counts(st["ring"].at[r, idx].set(
-                jnp.where(act, nxt, st["ring"][r, idx])), counts, st),
+                jnp.where(act, nxt, st["ring"][r, idx])), counts, st,
+                pools[-1][0] if self._n_state else None),
             rlps=st["rlps"].at[r, idx].set(
                 jnp.where(act, lps, st["rlps"][r, idx])),
             wcur=st["wcur"] + acti)
         return (nxt, lps, done, seen,
-                [c.pool for c in new_caches], new_st)
+                self._pools_out(new_caches, pools, moved=True), new_st)
 
     def _fused_tick(self, params, pools, seen, st):
         """ONE compiled program for a mixed greedy/sampled tick:
@@ -1589,7 +1716,8 @@ class PagedEngine:
         from .sampling import repetition_penalty_rows, sample_token_rows
         with jax.named_scope("patch"):
             st = self._apply_patch_queue(st)
-        caches = self._paged_caches(pools, st["tables"], st["lens"])
+        caches = self._paged_caches(pools, st["tables"], st["lens"],
+                                    live=st["active"])
         with self._tick_counts(st) as counts:
             logits, new_caches = self.fn(params, st["last"][:, None],
                                          kv_caches=caches,
@@ -1602,7 +1730,7 @@ class PagedEngine:
                                                st["tps"])
         with jax.named_scope("epilogue"):
             return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                        new_keys, counts)
+                                        new_keys, counts, pools)
 
     def _fused_tick_greedy(self, params, pools, seen, st):
         """Argmax-only fused tick (same specialization contract as
@@ -1613,7 +1741,8 @@ class PagedEngine:
         from .sampling import repetition_penalty_rows
         with jax.named_scope("patch"):
             st = self._apply_patch_queue(st)
-        caches = self._paged_caches(pools, st["tables"], st["lens"])
+        caches = self._paged_caches(pools, st["tables"], st["lens"],
+                                    live=st["active"])
         with self._tick_counts(st) as counts:
             logits, new_caches = self.fn(params, st["last"][:, None],
                                          kv_caches=caches,
@@ -1627,7 +1756,7 @@ class PagedEngine:
                                       nxt[:, None], axis=-1)[:, 0]
         with jax.named_scope("epilogue"):
             return self._fused_epilogue(st, new_caches, seen, nxt, lps,
-                                        st["keys"], counts)
+                                        st["keys"], counts, pools)
 
     def _fused_tick_spec(self, params, pools, seen, st, *, greedy: bool):
         """ONE compiled program for a speculative multi-token tick
@@ -2078,8 +2207,11 @@ class PagedEngine:
         from .sampling import repetition_penalty_rows, sample_token_rows
         tables = jnp.broadcast_to(table_row[None], (1, self.M))
         lens = jnp.asarray([length], jnp.int32)
+        # a whole prompt: state layers start from zero
+        fresh = jnp.ones((1,), bool) if self._n_state else None
         caches = self._paged_caches(pools, tables, lens,
-                                    jnp.asarray(slot, jnp.int32)[None])
+                                    jnp.asarray(slot, jnp.int32)[None],
+                                    fresh=fresh)
         positions = jnp.arange(bucket)[None, :]
         logits, new_caches = self.fn(params, ids, kv_caches=caches,
                                      positions=positions)
@@ -2094,7 +2226,8 @@ class PagedEngine:
                                               tp[None])
         seen_row = seen_row.at[nxt[0]].set(True)
         return (nxt[0], lps[0], new_key[0], seen_row,
-                [c.pool for c in new_caches])
+                self._pools_out(new_caches, pools,
+                                self._state_events(fresh)))
 
     def _chunk_prefill(self, params, pools, table_row, ids, start,
                        total_len, key, temp, tk, tp, rep, seen_row,
@@ -2111,8 +2244,12 @@ class PagedEngine:
         from .sampling import repetition_penalty_rows, sample_token_rows
         tables = jnp.broadcast_to(table_row[None], (1, self.M))
         lens = jnp.asarray([total_len], jnp.int32)
+        # state layers: from zero at position 0, else from what the
+        # chunk before this one left in the slot
+        fresh = (jnp.asarray(start) == 0)[None] if self._n_state else None
         caches = self._paged_caches(pools, tables, lens,
-                                    jnp.asarray(slot, jnp.int32)[None])
+                                    jnp.asarray(slot, jnp.int32)[None],
+                                    fresh=fresh)
         positions = start + jnp.arange(bucket)[None, :]
         logits, new_caches = self.fn(params, ids, kv_caches=caches,
                                      positions=positions,
@@ -2129,7 +2266,8 @@ class PagedEngine:
         with jax.named_scope("epilogue"):
             seen_out = seen_row.at[nxt[0]].set(True)
         return (nxt[0], lps[0], new_key[0], seen_row, seen_out,
-                [c.pool for c in new_caches])
+                self._pools_out(new_caches, pools,
+                                self._state_events(fresh)))
 
     def _chunk_prefill_packed(self, params, pools, seen, call):
         """One call of up to ``_pack_segments`` prompts side by side,
@@ -2156,7 +2294,10 @@ class PagedEngine:
             temps, tps, reps = jax.lax.bitcast_convert_type(
                 sg[:, M + 5:M + 8], jnp.float32).T
             keys = jax.lax.bitcast_convert_type(sg[:, M + 8:], jnp.uint32)
-        caches = self._paged_caches(pools, tables, lens, slots)
+        # every segment from position 0: state layers start from zero
+        fresh = jnp.ones((S,), bool) if self._n_state else None
+        caches = self._paged_caches(pools, tables, lens, slots,
+                                    fresh=fresh)
         logits, new_caches = self.fn(params, ids[None], kv_caches=caches,
                                      positions=pos[None],
                                      segment_ids=seg[None])
@@ -2176,7 +2317,8 @@ class PagedEngine:
                 [nxt[:, None], jax.lax.bitcast_convert_type(lps, jnp.int32)
                  [:, None], jax.lax.bitcast_convert_type(new_keys,
                                                          jnp.int32)], axis=1)
-        return out, seen, [c.pool for c in new_caches]
+        return out, seen, self._pools_out(
+            new_caches, pools, self._state_events(fresh, lens > 0))
 
     # ------------------------------------------------------------- host
     @_on_device
@@ -2361,6 +2503,11 @@ class PagedEngine:
                 "attach_spill: this model has layers that keep only "
                 "their window's band of a sequence; a span's blocks "
                 "there are gone before it could be spilled")
+        if arena is not None and self._n_state:
+            raise ValueError(
+                "attach_spill: this model has layers that keep recurrent "
+                "state by slot; a span's blocks hold none of it, so a "
+                "restored span could not be decoded from")
         self._spill = arena
 
     def _spill_geometry(self) -> tuple:
@@ -2368,6 +2515,12 @@ class PagedEngine:
         skew (different model depth/heads/dims, block size, dtype, or
         chunk grid) makes the bytes meaningless — the arena refuses the
         restore and the request re-prefills."""
+        if self._n_state:
+            raise ValueError(
+                "block export / import: this model has layers that keep "
+                "recurrent state by slot, which no block holds; a span "
+                "of its blocks is not a prefix's cache (shipping state "
+                "is not built)")
         kvh, d = self._cache_rows()[0]
         return (len(self.pools), int(self.B), int(kvh), int(d),
                 str(self.pools[0][0].dtype), self.chunk)

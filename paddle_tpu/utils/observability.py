@@ -967,6 +967,13 @@ TICK_SCOPES = (
                    # (latent attention: their expansion to K and V too)
     "chunk_attn_window",   # chunk: the same over a band-keeping layer's
                            # ring: the band behind the chunk and the chunk
+    "conv",        # linear attention: the short causal convolutions
+                   # of q~, k~, v~, their SiLU, the tail's shift
+    "delta_state",     # linear attention, decode: the gated delta
+                       # rule's step with the state's read and write
+    "chunk_delta_state",   # linear attention, chunk: its chunkwise-
+                           # parallel form, the state carried in and out
+    "gate_norm",   # linear attention: the per-head norm and output gate
     "o_proj",
     "mlp",         # a dense FFN; of an expert layer the residual add
     "router",      # expert layer: float32 scores, groups, top-k, gates

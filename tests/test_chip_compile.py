@@ -222,3 +222,67 @@ def test_write_and_attend_compile_with_no_pool_sized_copy(
             for name in call.group(1).split(",")
             if name.strip().lstrip("%") in pools}
     assert read and read == params and len(params) == 1, (read, params)
+
+
+def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
+                                                      no_persistent_cache):
+    """ISSUE 38: a linear-attention layer's decode step at the hybrid
+    cell's shapes (32 rows, 30 heads, keys 96, values 192). The state is
+    stored two heads to a row (384 = 3 lane tiles): a 192-wide last axis
+    alone is padded to 256 in the chip's memory, a third more to hold
+    and to move. The step is two passes over donated state: one read
+    for ``S^T k`` and ``S^T q``, one read and write; nothing the size of
+    the state is materialised beside it."""
+    import re
+    from paddle_tpu.ops import delta_rule
+    R, H, dk, dv = 32, 30, 96, 192
+    hp = delta_rule.state_lane_heads(H, dv)
+    assert hp == 2
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(delta_rule.delta_state_step,
+                       donate_argnums=(0,)).lower(
+        arr((R, H // hp, dk, hp * dv)), arr((R, H, dk)), arr((R, H, dk)),
+        arr((R, H, dv)), arr((R, H)), arr((R, H)),
+        arr((R,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    state = R * H * dk * dv * 4
+    # the state as it is, unpadded, updated in place, no scratch copy
+    assert state <= mem.argument_size_in_bytes < 1.05 * state
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < 0.05 * state
+    text = compiled.as_text()
+    shape = f"f32[{R},{H // hp},{dk},{hp * dv}]"
+    made = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(shape)
+                      + r"\S* (\w+)\(", text, re.M)
+    # inside the entry computation: the parameter and ONE fusion that
+    # writes the new state (broadcasts inside fusions are not arrays)
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(re.escape(shape) + r"\S* fusion\(", entry)) == 1, \
+        made
+    assert not re.findall(re.escape(shape) + r"\S* (?:copy|transpose)\(",
+                          entry)
+
+
+@pytest.mark.parametrize("R,M,P", [(32, 128, 3873)])
+def test_a_query_group_of_one_compiles_for_a_v5e(one_chip,
+                                                 no_persistent_cache,
+                                                 monkeypatch, R, M, P):
+    """ISSUE 38: the hybrid cell's full layers: 30 kv heads of 128 with
+    ONE query head each, pages 3,840 columns wide."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention_pallas, use_ragged_kernel)
+    import paddle_tpu.ops.pallas as pallas
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q, kp = arr((R, 30, 128)), arr((P, 16, 30 * 128))
+    assert use_ragged_kernel(arr((R, 1, 30, 128)), kp, 30)
+    compiled = jax.jit(
+        lambda q, kp, vp, tbl, lens: ragged_paged_attention_pallas(
+            q, kp, vp, tbl, lens, 128 ** -0.5, 30)).lower(
+        q, kp, kp, arr((R, M), jnp.int32), arr((R,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

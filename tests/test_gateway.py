@@ -748,7 +748,8 @@ def test_the_benchmarks_reader_divides_events_by_hand_overs():
     assert reader.reduce(sources(a, b)) == pytest.approx(63.0)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    entry = manifest["per_layer"][-1]
+    entry = next(m for m in manifest["per_layer"]
+                 if m["name"] == reader.NAME)
     assert (entry["name"], entry["unit"], entry["better"], entry["source"],
             entry["layer"], entry["moves"]) == (
         reader.NAME, reader.UNIT, "higher", reader.SOURCE, reader.LAYER,

@@ -707,7 +707,10 @@ class TestCacheLayers:
     that answered before get the pools they got (one shape every layer,
     ``num_blocks`` pages, a K/V pair or one latent row); a model whose
     layers differ gets a pool a layer, a band-keeping layer's a ring of
-    pages a slot; ``decode_route`` answers for every layer's shapes."""
+    pages a slot; ``decode_route`` answers for every layer's shapes.
+    ISSUE 38: a layer may keep STATE by slot instead (``StateLayer``):
+    its arrays are ``[max_slots, ...]`` beside the pools, and the four
+    older families get exactly the arrays they got."""
 
     @staticmethod
     def _built(family):
@@ -717,6 +720,8 @@ class TestCacheLayers:
             LongcatFlashForCausalLM, longcat_flash_tiny)
         from paddle_tpu.models.mimo_v2 import (MiMoV2ForCausalLM,
                                                mimo_v2_tiny)
+        from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
+                                                   olmo_hybrid_tiny)
         pt.seed(0)
         return {
             "llama": lambda: LlamaForCausalLM(llama_tiny()),
@@ -724,6 +729,7 @@ class TestCacheLayers:
             "longcat": lambda: LongcatFlashForCausalLM(
                 longcat_flash_tiny(num_hidden_layers=1)),
             "mimo": lambda: MiMoV2ForCausalLM(mimo_v2_tiny()),
+            "olmo": lambda: OlmoHybridForCausalLM(olmo_hybrid_tiny()),
         }[family]()
 
     @pytest.mark.parametrize("family,pools", [
@@ -738,15 +744,29 @@ class TestCacheLayers:
         # chunk 2 + 1 pages a slot and the garbage block
         ("mimo", [((32, 8, 24), (32, 8, 16)),
                   ((21, 8, 48), (21, 8, 32)), ((21, 8, 48), (21, 8, 32))]),
+        # three linear layers: by SLOT, 8 heads' 8 x 16 states side by
+        # side in one 128-lane row and the convolution's last 3 inputs;
+        # a full layer (4 kv heads of 16) by the allocator's pages; the
+        # prompt calls' two counters behind them
+        ("olmo", [((4, 1, 8, 128), (4, 3, 256))] * 3
+         + [((32, 8, 64), (32, 8, 64)), ((2,),)]),
     ])
     def test_each_familys_pools(self, family, pools):
+        from paddle_tpu.generation.paged import StateLayer
         eng = _engine(self._built(family), chunk_prefill_tokens=16)
         assert [tuple(p.shape for p in layer) for layer in eng.pools] \
             == pools
+        state = [isinstance(layer, StateLayer) for layer in eng._layout]
         assert [layer.window for layer in eng._layout] \
-            == ([None, 12, 12] if family == "mimo" else [None] * len(pools))
-        # the extra tick counters exist only where a band is kept
+            == ([None, 12, 12] if family == "mimo"
+                else [None] * len(eng._layout))
+        # the extra tick counters exist only where a band is kept, or
+        # state by slot; every other engine holds a pool a layer
         assert ("kv_window_blocks" in eng.stats) == (family == "mimo")
+        assert ("state_resets" in eng.stats) == (family == "olmo")
+        assert state == ([True] * 3 + [False] if family == "olmo"
+                         else [False] * len(pools))
+        assert len(eng.pools) == len(eng._layout) + (family == "olmo")
 
     def test_decode_route_answers_for_every_layer(self, monkeypatch):
         """"ragged" only if EVERY cache layer's shapes take the kernel:

@@ -41,7 +41,10 @@ MOE_MLA = {"router", "experts", "shared_expert", "absorb", "zero_experts"}
 # a band-keeping (sliding-window) layer's attention, beside the
 # whole-context layers' (ISSUE 32)
 WINDOW = {"attn_window", "chunk_attn_window"}
-LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW
+# a linear-attention layer's own parts (ISSUE 38): the short
+# convolutions, the recurrence's two forms, the gated per-head norm
+LINEAR = {"conv", "delta_state", "chunk_delta_state", "gate_norm"}
+LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW - LINEAR
 PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
@@ -51,9 +54,9 @@ PROGRAMS = {
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
 DEEPSEEK = {
-    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - {"chunk_attn",
-                                                           "zero_experts"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - ATTN - {
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - LINEAR - {
+        "chunk_attn", "zero_experts"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - LINEAR - ATTN - {
         "patch", "absorb", "zero_experts"},
 }
 DEEPSEEK["_chunk_prefill_packed"] = DEEPSEEK["_chunk_prefill"] | {"patch"}
@@ -70,6 +73,15 @@ MIMO = {
         "router", "experts", "chunk_attn_window"},
 }
 MIMO["_chunk_prefill_packed"] = MIMO["_chunk_prefill"] | {"patch"}
+# Olmo-Hybrid's two layer kinds: the full layers under `attn` /
+# `chunk_attn` as ever, the linear layers' decode step under
+# `delta_state` and their chunkwise form under `chunk_delta_state`
+HYBRID = {
+    "_fused_tick_greedy": LLAMA - {"chunk_attn"} | LINEAR - {
+        "chunk_delta_state"},
+    "_chunk_prefill": LLAMA - ATTN - {"patch"} | LINEAR - {"delta_state"},
+}
+HYBRID["_chunk_prefill_packed"] = HYBRID["_chunk_prefill"] | {"patch"}
 
 
 @pytest.fixture(scope="module")
@@ -190,9 +202,42 @@ def test_window_and_full_layers_carry_scopes_of_their_own(mimo_engine,
             + ["ragged_paged_attention", "expert_share_mlp"] * 2
 
 
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
+                                               olmo_hybrid_tiny)
+    eng = PagedEngine(OlmoHybridForCausalLM(olmo_hybrid_tiny()),
+                      max_slots=4, num_blocks=32, block_size=8,
+                      max_blocks_per_seq=8, chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(HYBRID))
+def test_linear_attention_layers_carry_scopes_of_their_own(hybrid_engine,
+                                                           kernels, program):
+    """Three linear layers and one full layer: the recurrence, the
+    convolutions and the gated norm are named apart from the full
+    layer's attention, so a device trace can tell the state's read and
+    write from the pages'; one kernel call a tick, the full layer's."""
+    assert hybrid_engine.decode_route() == "ragged"
+    _, scopes = _lowered(hybrid_engine, program)
+    assert set(scopes) - {None} == HYBRID[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+    # the recurrence is no afterthought of another scope
+    own = "delta_state" if program == "_fused_tick_greedy" \
+        else "chunk_delta_state"
+    assert scopes[own] > scopes["attn" if own == "delta_state"
+                                else "chunk_attn"]
+    if not program.startswith("_chunk_prefill"):
+        names = _kernel_names(_trace(hybrid_engine, program).jaxpr.jaxpr)
+        assert names == ["ragged_paged_attention"]
+
+
 def test_the_programs_use_the_whole_vocabulary():
     assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
-                       *LONGCAT.values(), *MIMO.values()) \
+                       *LONGCAT.values(), *MIMO.values(),
+                       *HYBRID.values()) \
         == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES

@@ -2,6 +2,7 @@
 """Kernel and program checks on the chip.
 
     python tools/tpu_validate.py            # exit 0 iff every check passed
+    python tools/tpu_validate.py --only delta_rule_state    # these alone
 
 Runs every Pallas kernel and every engine program that the CPU suite
 only ever sees through the interpreter once through the real compiler
@@ -40,10 +41,14 @@ print("DEVICE_JSON " + json.dumps({"platform": dev.platform,
       "jax": jax.__version__}), flush=True)
 results = {}
 
+ONLY = %(only)r
+
 def check(name, fn):
     # a failed check is RECORDED (the others still run); the parent
     # turns any ok:false into a non-zero exit
     import traceback
+    if ONLY and name not in ONLY:
+        return
     t0 = time.time()
     try:
         fn()
@@ -525,15 +530,65 @@ def prefill_flash():
     assert rel < 2.5e-2, (err, rel)
 check("prefill_flash_vs_dense", prefill_flash)
 
+def delta_rule_state():
+    # ISSUE 38: the gated delta rule at the hybrid cell's shapes (32
+    # rows, 30 heads, keys 96 / values 192, a 256-position chunk): the
+    # decode step over the stored state (two heads a row: whole lane
+    # tiles) and the chunkwise form, each against the position-by-
+    # position scan; float32 at the highest matmul precision
+    from paddle_tpu.ops import delta_rule as dr
+    R, H, dk, dv, T = 32, 30, 96, 192, 256
+    hp = dr.state_lane_heads(H, dv)
+    assert hp == 2, hp
+    f = lambda *s: jnp.asarray(rs.standard_normal(s), jnp.float32)
+    q = dr.l2_normalize(f(R, T, H, dk)) * dk ** -0.5
+    k = dr.l2_normalize(f(R, T, H, dk))
+    v = f(R, T, H, dv)
+    beta = jnp.asarray(rs.uniform(0.01, 1.99, (R, T, H)), jnp.float32)
+    g = -jnp.asarray(np.exp(rs.uniform(-9, 1.5, (R, T, H))), jnp.float32)
+    S0 = f(R, H, dk, dv)
+    scan = jax.jit(jax.vmap(dr.gated_delta_scan))
+    o_ref, S_ref = scan(q, k, v, g, beta, S0)
+    # the chunk form, a row at a time (the engine's call is one row)
+    chunk = jax.jit(jax.vmap(lambda *a: dr.gated_delta_chunk(*a)))
+    o, S = chunk(q[:4], k[:4], v[:4], g[:4], beta[:4], S0[:4])
+    scale = float(jnp.max(jnp.abs(o_ref)))
+    err_o = float(jnp.max(jnp.abs(o - o_ref[:4]))) / scale
+    err_S = float(jnp.max(jnp.abs(S[:, 0] - S_ref[:4]))) \
+        / float(jnp.max(jnp.abs(S_ref)))
+    assert err_o < 1e-4 and err_S < 1e-4, (err_o, err_S)
+    # the decode step: every row's first position, a dead row kept
+    live = jnp.arange(R) %% 5 != 3
+    step = jax.jit(dr.delta_state_step, donate_argnums=(0,))
+    S1, o1 = step(dr.pack_state(S0, hp), q[:, 0], k[:, 0], v[:, 0],
+                  jnp.exp(g[:, 0]), beta[:, 0], live)
+    o1_ref, S1_ref = scan(q[:, :1], k[:, :1], v[:, :1], g[:, :1],
+                          beta[:, :1], S0)
+    S1 = dr.unpack_state(S1, hp)
+    want = jnp.where(live[:, None, None, None], S1_ref, S0)
+    e1 = float(jnp.max(jnp.abs(S1 - want))) / float(jnp.max(jnp.abs(want)))
+    e2 = float(jnp.max(jnp.abs(o1 - o1_ref[:, 0]))) \
+        / float(jnp.max(jnp.abs(o1_ref)))
+    assert e1 < 1e-5 and e2 < 1e-4, (e1, e2)
+    print("delta_rule_state: chunk err %%.1e / %%.1e, step err %%.1e / "
+          "%%.1e" %% (err_o, err_S, e1, e2), flush=True)
+check("delta_rule_state", delta_rule_state)
+
 print("KERNELS_JSON " + json.dumps(results), flush=True)
 """
 
 
-def main():
+def main(argv=None):
+    import argparse
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--only", nargs="*", default=[],
+                    help="run these checks alone (names as reported)")
+    args = ap.parse_args(argv)
     report = {"started": time.strftime("%Y-%m-%d %H:%M:%S")}
     t0 = time.time()
     proc = subprocess.run([sys.executable, "-c",
-                           KERNEL_CHECK % {"repo": REPO}],
+                           KERNEL_CHECK % {"repo": REPO,
+                                           "only": args.only}],
                           stdout=subprocess.PIPE, text=True)
     sys.stdout.write(proc.stdout)
     report["rc"] = proc.returncode
