@@ -250,12 +250,14 @@ class SlotState(NamedTuple):
 
 
 # what an engine over state layers adds up inside its programs beside
-# the model's own tick counters: state layers x decode ticks, live rows
-# x state layers, and the prompt segments that started from zero / from
-# the state the chunk before them left (counted in the chunk programs
-# into ``pools[-1]`` and moved to the token ring by the next tick)
-_STATE_COUNTERS = ("state_layer_ticks", "state_rows_updated",
-                   "state_resets", "state_carries")
+# the model's own tick counters: state layers x decode ticks, those of
+# them whose step took the one-pass kernel (``state_step_route``), live
+# rows x state layers, and the prompt segments that started from zero /
+# from the state the chunk before them left (counted in the chunk
+# programs into ``pools[-1]`` and moved to the token ring by the next
+# tick)
+_STATE_COUNTERS = ("state_layer_ticks", "state_kernel_ticks",
+                   "state_rows_updated", "state_resets", "state_carries")
 
 
 # what a band-keeping engine adds up inside a tick, over the live rows
@@ -505,6 +507,19 @@ def paged_decode_route(q, kp, kv_heads: int) -> str:
     used to serve that one geometry is gone (no model or cell has it)."""
     from ..ops.pallas.ragged_paged_attention import use_ragged_kernel
     return "ragged" if use_ragged_kernel(q, kp, kv_heads) else "dense"
+
+
+def state_step_route(S) -> str:
+    """Which path a linear-attention layer's decode step
+    (``ops.delta_rule.delta_state_step``) takes over a stored state
+    shaped like ``S`` [R, G, dk, L]: ``"kernel"`` (the Pallas kernel
+    that reads each slot's state once) or ``"fusions"`` (the jnp body's
+    two passes). ``paged_decode_route``'s sibling: shapes, dtype and the
+    platform decide (``use_state_kernel`` is the one gate), so the
+    engine can ask with its own arrays' geometry and count the choice
+    the traced program made."""
+    from ..ops.pallas.delta_state import use_state_kernel
+    return "kernel" if use_state_kernel(S) else "fusions"
 
 
 def _row_positions(pk: PagedKV, T: int, Tk: int):
@@ -1647,14 +1662,21 @@ class PagedEngine:
 
     def _state_counts(self, st, events):
         """``_STATE_COUNTERS`` of this tick (None for an engine without
-        state layers): every state layer ran once and updated the live
-        rows; ``events`` is what the prompt calls since the last tick
-        added up."""
+        state layers): every state layer ran once, by the route its
+        state's geometry takes as this program is traced, and updated
+        the live rows; ``events`` is what the prompt calls since the
+        last tick added up."""
         if not self._n_state:
             return None
         n = jnp.int32(self._n_state)
+        kernel = sum(
+            state_step_route(jax.ShapeDtypeStruct(
+                (self.R,) + tuple(l.arrays[0][0]), l.arrays[0][1]))
+            == "kernel"
+            for l in self._layout if isinstance(l, StateLayer))
         return jnp.concatenate([
-            jnp.stack([n, n * jnp.sum(st["active"].astype(jnp.int32))]),
+            jnp.stack([n, jnp.int32(kernel),
+                       n * jnp.sum(st["active"].astype(jnp.int32))]),
             events])
 
     def _ring_counts(self, ring, counts, st, events=None):

@@ -93,10 +93,16 @@ def delta_state_step(S, q, k, v, alpha, beta, live):
     alpha, beta [R, H]; ``live`` [R] bool: a row that is not live keeps
     its state. Returns (S, o [R, H, dv]).
 
-    Two passes over the state: one reads it for ``S^T k`` and ``S^T q``
-    together, one reads it and writes the new one; the output is
-    ``alpha S^T q + (k . q) d``, the new state's read without reading
-    it."""
+    The output is ``alpha S^T q + (k . q) d``, the new state's read
+    without reading it. A state the Pallas kernel tiles
+    (``use_state_kernel``: whole lane and sublane tiles, float32, a TPU
+    or the interpreter) takes it: ONE read and one write of each row's
+    state. Any other takes the body below, the definition the kernel is
+    held to, in two passes: one reads the state for ``S^T k`` and
+    ``S^T q`` together, one reads it and writes the new one."""
+    from .pallas.delta_state import delta_state_step_pallas, use_state_kernel
+    if use_state_kernel(S):
+        return delta_state_step_pallas(S, q, k, v, alpha, beta, live)
     R, H, dv = v.shape
     hp = H // S.shape[1]
     kx, qx = _spread(k, hp, dv), _spread(q, hp, dv)     # [R, G, dk, L]

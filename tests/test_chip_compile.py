@@ -224,28 +224,47 @@ def test_write_and_attend_compile_with_no_pool_sized_copy(
     assert read and read == params and len(params) == 1, (read, params)
 
 
+@pytest.mark.parametrize("route", ["fusions", "kernel"])
 def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
-                                                      no_persistent_cache):
+                                                      no_persistent_cache,
+                                                      monkeypatch, route):
     """ISSUE 38: a linear-attention layer's decode step at the hybrid
     cell's shapes (32 rows, 30 heads, keys 96, values 192). The state is
     stored two heads to a row (384 = 3 lane tiles): a 192-wide last axis
     alone is padded to 256 in the chip's memory, a third more to hold
-    and to move. The step is two passes over donated state: one read
-    for ``S^T k`` and ``S^T q``, one read and write; nothing the size of
-    the state is materialised beside it."""
+    and to move. Nothing the size of the state is materialised beside
+    it, by either route, and the donated state is updated in place.
+    ``fusions`` (the jnp body, the gate held shut): two passes, one read
+    for ``S^T k`` and ``S^T q``, one read and write. ``kernel`` (ISSUE
+    39, what the gate chooses on the chip): ONE custom call that reads
+    the state and writes it, aliased to its operand, and no fusion and
+    no copy of the state's shape."""
     import re
+    import paddle_tpu.ops.pallas as pallas
     from paddle_tpu.ops import delta_rule
+    from paddle_tpu.ops.pallas import delta_state
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     R, H, dk, dv = 32, 30, 96, 192
     hp = delta_rule.state_lane_heads(H, dv)
     assert hp == 2
 
     def arr(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    compiled = jax.jit(delta_rule.delta_state_step,
+    S = arr((R, H // hp, dk, hp * dv))
+    if route == "kernel":
+        monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+        assert delta_state.use_state_kernel(S)
+        # whole slots a grid step: 4.4 MB of traffic each, 5 us at the
+        # chip's bandwidth against the microsecond a step costs
+        assert delta_state._rows_per_step(R, S.size * 4 // R) \
+            * 2 * S.size * 4 // R > 4 << 20
+    else:
+        monkeypatch.setattr(delta_state, "use_state_kernel",
+                            lambda S: False)
+    compiled = jax.jit(lambda *a: delta_rule.delta_state_step(*a),
                        donate_argnums=(0,)).lower(
-        arr((R, H // hp, dk, hp * dv)), arr((R, H, dk)), arr((R, H, dk)),
-        arr((R, H, dv)), arr((R, H)), arr((R, H)),
-        arr((R,), jnp.bool_)).compile()
+        S, arr((R, H, dk)), arr((R, H, dk)), arr((R, H, dv)),
+        arr((R, H)), arr((R, H)), arr((R,), jnp.bool_)).compile()
     mem = compiled.memory_analysis()
     state = R * H * dk * dv * 4
     # the state as it is, unpadded, updated in place, no scratch copy
@@ -254,13 +273,14 @@ def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
     assert mem.temp_size_in_bytes < 0.05 * state
     text = compiled.as_text()
     shape = f"f32[{R},{H // hp},{dk},{hp * dv}]"
-    made = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = " + re.escape(shape)
-                      + r"\S* (\w+)\(", text, re.M)
-    # inside the entry computation: the parameter and ONE fusion that
-    # writes the new state (broadcasts inside fusions are not arrays)
+    # inside the entry computation: the parameter and ONE op that writes
+    # the new state (broadcasts inside fusions are not arrays)
     entry = text[text.index("ENTRY"):]
-    assert len(re.findall(re.escape(shape) + r"\S* fusion\(", entry)) == 1, \
-        made
+    fusions = len(re.findall(re.escape(shape) + r"\S* fusion\(", entry))
+    calls = len(re.findall(re.escape(shape) + r"[^=]* custom-call\(",
+                           entry))
+    assert (fusions, calls) == ((1, 0) if route == "fusions" else (0, 1))
+    assert ("tpu_custom_call" in text) == (route == "kernel")
     assert not re.findall(re.escape(shape) + r"\S* (?:copy|transpose)\(",
                           entry)
 

@@ -32,7 +32,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.generation.paged import (CacheLayer, PagedEngine, SlotState,
-                                         StateLayer)
+                                         StateLayer, state_step_route)
 from paddle_tpu.ops import delta_rule
 
 TOL = 1e-4
@@ -173,6 +173,72 @@ def test_the_decode_step_is_one_position_of_the_scan(H, dv, hp):
         assert np.abs(S[r] - (S_ref if live[r] else S0[r])).max() < 1e-5
 
 
+@pytest.fixture
+def kernels(monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+
+
+@pytest.mark.parametrize("R,H,dk,dv,live,fit,taken", [
+    (4, 8, 8, 16, "all", None, True),   # the tiny twin: 8 heads a row
+    (4, 8, 8, 16, "some", None, True),
+    (1, 8, 8, 16, "all", None, True),   # one row
+    (4, 4, 16, 64, "some", 2, True),    # two grid steps of two slots
+    (5, 4, 16, 64, "some", 2, True),    # rows no multiple of 2: of one
+    (3, 30, 96, 192, "some", 1, True),  # the cell's heads: a slot a step
+    (3, 2, 8, 128, "some", None, True),     # one head a row, whole tiles
+    (3, 6, 8, 16, "some", None, False),     # one head a row, 16 lanes
+    (3, 8, 12, 16, "some", None, False),    # keys of no whole sublane tile
+])
+def test_the_state_kernel_is_the_jnp_body_and_the_scan(
+        kernels, monkeypatch, R, H, dk, dv, live, fit, taken):
+    """ISSUE 39: the one-pass Pallas kernel (the interpreter here) over
+    three positions, each against the jnp body of ``delta_state_step``
+    (the gate held shut) from the same state and against the scan; a
+    row that is not live keeps its state bit for bit; a geometry the
+    gate refuses takes the body (no kernel call in the jaxpr) and reads
+    the same numbers. ``fit``: the slots whose four buffers the
+    kernel's VMEM budget holds (None: the budget as it is, which holds
+    every row of these small states in one grid step)."""
+    from paddle_tpu.ops.pallas import delta_state
+    hp = delta_rule.state_lane_heads(H, dv)
+    slot = H * dk * dv * 4
+    if fit:
+        monkeypatch.setattr(delta_state, "_VMEM_STATE", 4 * fit * slot)
+    assert delta_state._rows_per_step(R, slot) \
+        == (R if fit is None else fit if R % fit == 0 else 1)
+    T = 3
+    rows = [_inputs(T, H, dk, dv, seed=10 + r) for r in range(R)]
+    q, k, v, g, beta, S0 = (jnp.stack([r[i] for r in rows])
+                            for i in range(6))
+    alive = jnp.ones((R,), bool) if live == "all" \
+        else jnp.arange(R) % 3 != 1
+    S = delta_rule.pack_state(S0, hp)
+    assert delta_state.use_state_kernel(S) == taken
+    step = lambda *a: delta_rule.delta_state_step(*a)       # noqa: E731
+    jaxpr = str(jax.make_jaxpr(step)(S, q[:, 0], k[:, 0], v[:, 0],
+                                     jnp.exp(g[:, 0]), beta[:, 0], alive))
+    assert ("pallas_call" in jaxpr) == taken
+    for t in range(T):
+        a = (q[:, t], k[:, t], v[:, t], jnp.exp(g[:, t]), beta[:, t], alive)
+        S_new, o = jax.jit(step)(S, *a)
+        with monkeypatch.context() as m:
+            m.setattr(delta_state, "use_state_kernel", lambda _S: False)
+            S_body, o_body = jax.jit(lambda *a: step(*a))(S, *a)
+        assert np.abs(S_new - S_body).max() < 1e-6
+        assert np.abs(o - o_body).max() < 1e-6
+        dead = ~np.asarray(alive)
+        assert np.array_equal(np.asarray(S_new)[dead], np.asarray(S)[dead])
+        S = S_new
+    S = delta_rule.unpack_state(S, hp)
+    for r in range(R):
+        o_ref, S_ref = delta_rule.gated_delta_scan(*rows[r])
+        if alive[r]:
+            assert np.abs(S[r] - S_ref).max() < 1e-5
+            assert np.abs(o[r] - o_ref[-1]).max() < 1e-5
+        else:
+            assert np.array_equal(S[r], S0[r])
+
+
 def test_the_convolution_carries_its_tail_and_keeps_segments_apart():
     rng = np.random.default_rng(0)
     T, C = 30, 12
@@ -286,10 +352,42 @@ def test_prefill_then_decode_against_the_reference(ref, model, mode):
         # chunks of 16: 1 + 3 + 1 + 2 + 1 + 4 calls, six from zero
         assert (st["state_resets"], st["state_carries"]) == (6, 6)
         assert st["state_layer_ticks"] == 3 * st["decode_steps"]
+        # every state layer of every tick by the one route (the suite
+        # runs with the interpreter on or off by what was collected)
+        assert st["state_kernel_ticks"] == st["state_layer_ticks"] * (
+            state_step_route(eng.pools[0][0]) == "kernel")
         assert st["state_rows_updated"] == 3 * 6 * 7
         assert st["runahead_ticks"] > 0
     elif mode == "whole":
         assert (st["state_resets"], st["state_carries"]) == (6, 0)
+
+
+@pytest.mark.parametrize("route", ["kernel", "fusions"])
+def test_the_engine_counts_the_ticks_that_took_the_state_kernel(
+        ref, model, monkeypatch, route):
+    """ISSUE 39: under the interpreter the tiny twin's state (one row of
+    8 x 128 a slot: whole tiles) takes the kernel in every state layer
+    of every tick; without it (and without a TPU) the jnp body's two
+    fusions serve. The engine says which (``state_kernel_ticks``), and
+    the stream is the reference's either way."""
+    if route == "kernel":
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    eng = _engine(model)
+    assert state_step_route(eng.pools[0][0]) == route
+    prompts = _prompts([5, 37, 16, 23], seed=12)
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, max_new_tokens=8)
+    res = eng.run()
+    for i, p in enumerate(prompts):
+        err, same = _lp_error(ref, model, p, res[i], eng.logprobs[i])
+        assert err < TOL and same, (i, err)
+    st = eng.stats
+    assert st["state_layer_ticks"] == 3 * st["decode_steps"] > 0
+    assert st["state_kernel_ticks"] == \
+        (st["state_layer_ticks"] if route == "kernel" else 0)
+    assert "state_kernel_ticks" in eng.health()
 
 
 def test_a_packed_call_of_three_prompts_equals_three_calls(ref, model):

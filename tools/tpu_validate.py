@@ -557,21 +557,44 @@ def delta_rule_state():
     err_S = float(jnp.max(jnp.abs(S[:, 0] - S_ref[:4]))) \
         / float(jnp.max(jnp.abs(S_ref)))
     assert err_o < 1e-4 and err_S < 1e-4, (err_o, err_S)
-    # the decode step: every row's first position, a dead row kept
+    # the decode step: every row's first position, a dead row kept; by
+    # the route the state takes here (ISSUE 39: the one-pass Pallas
+    # kernel) and by the jnp body's two fusions, the gate held shut
+    from paddle_tpu.ops.pallas import delta_state as ds
     live = jnp.arange(R) %% 5 != 3
-    step = jax.jit(dr.delta_state_step, donate_argnums=(0,))
-    S1, o1 = step(dr.pack_state(S0, hp), q[:, 0], k[:, 0], v[:, 0],
-                  jnp.exp(g[:, 0]), beta[:, 0], live)
     o1_ref, S1_ref = scan(q[:, :1], k[:, :1], v[:, :1], g[:, :1],
                           beta[:, :1], S0)
-    S1 = dr.unpack_state(S1, hp)
     want = jnp.where(live[:, None, None, None], S1_ref, S0)
-    e1 = float(jnp.max(jnp.abs(S1 - want))) / float(jnp.max(jnp.abs(want)))
-    e2 = float(jnp.max(jnp.abs(o1 - o1_ref[:, 0]))) \
-        / float(jnp.max(jnp.abs(o1_ref)))
-    assert e1 < 1e-5 and e2 < 1e-4, (e1, e2)
-    print("delta_rule_state: chunk err %%.1e / %%.1e, step err %%.1e / "
-          "%%.1e" %% (err_o, err_S, e1, e2), flush=True)
+    packed = dr.pack_state(S0, hp)
+    rest = (q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]), beta[:, 0], live)
+    assert ds.use_state_kernel(packed), "the state kernel is not taken"
+    gate, errs = ds.use_state_kernel, {}
+    for route in ("kernel", "fusions"):
+        if route == "fusions":
+            ds.use_state_kernel = lambda S: False
+        try:
+            step = jax.jit(lambda *a: dr.delta_state_step(*a),
+                           donate_argnums=(0,)).lower(packed,
+                                                      *rest).compile()
+        finally:
+            ds.use_state_kernel = gate
+        if dev.platform == "tpu":       # the interpreter is no call
+            assert ("tpu_custom_call" in step.as_text()) \
+                == (route == "kernel"), route
+        S1, o1 = step(packed + 0.0, *rest)
+        S1 = dr.unpack_state(S1, hp)
+        dead = ~np.asarray(live)
+        assert np.array_equal(np.asarray(S1)[dead], np.asarray(S0)[dead])
+        e1 = float(jnp.max(jnp.abs(S1 - want))) \
+            / float(jnp.max(jnp.abs(want)))
+        e2 = float(jnp.max(jnp.abs(o1 - o1_ref[:, 0]))) \
+            / float(jnp.max(jnp.abs(o1_ref)))
+        assert e1 < 1e-5 and e2 < 1e-4, (route, e1, e2)
+        errs[route] = (e1, e2)
+    print("delta_rule_state: chunk err %%.1e / %%.1e, step err kernel "
+          "%%.1e / %%.1e, fusions %%.1e / %%.1e"
+          %% ((err_o, err_S) + errs["kernel"] + errs["fusions"]),
+          flush=True)
 check("delta_rule_state", delta_rule_state)
 
 print("KERNELS_JSON " + json.dumps(results), flush=True)
