@@ -18,6 +18,8 @@ from .mimo_v2 import (MiMoV2Config, MiMoV2ForCausalLM, MiMoV2Model,
                       mimo_v2_tiny)
 from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
                           OlmoHybridModel, olmo_hybrid_tiny)
+from .ling_hybrid import (LingHybridConfig, LingHybridForCausalLM,
+                          LingHybridModel, ling_hybrid_tiny)
 from .qwen2_moe import (DeepseekMoeConfig, DeepseekMoeForCausalLM,
                         Qwen2MoeConfig, Qwen2MoeForCausalLM, Qwen2MoeModel,
                         deepseek_moe_tiny, moe_lm_loss, qwen2_moe_tiny)

@@ -58,6 +58,10 @@ class DeepseekV2Config(LlamaConfig):
     # the normed latent before kv_b times sqrt(hidden / kv_lora_rank)
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # Ling 3.0 (``gated_attention_proj_granularity_type`` head_wise): a
+    # head's attention output times sigmoid(W_gate x)[head], the gate
+    # read from the layer's normed input
+    mla_head_gate: bool = False
     # ---- MoE (DeepSeek fine-grained + shared)
     num_experts: int = 64                  # n_routed_experts
     num_experts_per_tok: int = 6
@@ -171,6 +175,8 @@ class MLAttention(Layer):
         self.o_proj = RowParallelLinear(h * cfg.v_head_dim, cfg.hidden_size,
                                         has_bias=cfg.attention_bias,
                                         input_is_parallel=True)
+        if cfg.mla_head_gate:
+            self.g_proj = nn.Linear(cfg.hidden_size, h, bias_attr=False)
         self.scale = cfg.qk_head_dim ** -0.5
         if getattr(cfg, "rope_scaling", None):
             self._inv_freq, self._rope_af = yarn_params(
@@ -352,6 +358,10 @@ class MLAttention(Layer):
             out = self._expanded(q_nope, q_pe, c, k_pe,
                                  causal=attn_mask is None,
                                  attn_mask=attn_mask)
+        if cfg.mla_head_gate:
+            with jax.named_scope("head_gate"):  # obs.TICK_SCOPES
+                gate = jax.nn.sigmoid(self.g_proj(x).astype(jnp.float32))
+                out = (out * gate[..., None]).astype(out.dtype)
         with jax.named_scope("o_proj"):     # obs.TICK_SCOPES
             out = self.o_proj(out.reshape(b, s, h * cfg.v_head_dim))
         return (out, new_cache) if kv_cache is not None else out

@@ -198,13 +198,19 @@ class OlmoHybridAttention(Layer):
 
 
 class GatedDeltaNet(Layer):
-    """A linear-attention layer's mixer (module docstring)."""
+    """A linear-attention layer's mixer (module docstring). What is Gated
+    DeltaNet's own is in ``__init__``, ``_project``, ``_gates`` and
+    ``_out_gate``; the three served forms of ``forward`` read only
+    ``geometry`` and belong to any delta-rule mixer (models/
+    ling_hybrid.py's Kimi Delta Attention overrides those four)."""
 
     def __init__(self, config: OlmoHybridConfig):
         super().__init__()
         self.config = cfg = config
         hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
         dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        # key heads, value heads, their widths, the convolution's taps
+        self.geometry = (hk, hv, dk, dv, cfg.linear_conv_kernel_dim)
         col = lambda n: ColumnParallelLinear(           # noqa: E731
             cfg.hidden_size, n, has_bias=False, gather_output=False)
         self.q_proj, self.k_proj = col(hk * dk), col(hk * dk)
@@ -226,19 +232,15 @@ class GatedDeltaNet(Layer):
         """What one SLOT keeps of this layer (``StateLayer.arrays``):
         the heads' matrix states, float32, ``state_lane_heads`` of them
         side by side in a row; the convolution's last inputs."""
-        cfg = self.config
-        hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
+        hk, hv, dk, dv, taps = self.geometry
         hp = delta_rule.state_lane_heads(hv, dv)
-        return (((hv // hp, cfg.linear_key_head_dim, hp * dv), jnp.float32),
-                ((cfg.linear_conv_kernel_dim - 1, cfg.conv_channels),
-                 cfg.dtype))
+        return (((hv // hp, dk, hp * dv), jnp.float32),
+                ((taps - 1, 2 * hk * dk + hv * dv), self.config.dtype))
 
     def _heads(self, y):
         """The convolution's activated output [..., C] apart: q, k
         [..., Hv, dk] normalised (q scaled), v [..., Hv, dv]; float32."""
-        cfg = self.config
-        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        hk, hv, dk, dv, _ = self.geometry
         lead = y.shape[:-1]
         q = y[..., :hk * dk].reshape(lead + (hk, dk))
         k = y[..., hk * dk:2 * hk * dk].reshape(lead + (hk, dk))
@@ -249,6 +251,18 @@ class GatedDeltaNet(Layer):
             q = jnp.repeat(q, hv // hk, axis=-2)
             k = jnp.repeat(k, hv // hk, axis=-2)
         return q, k, v
+
+    def _project(self, x):
+        """x -> (q~, k~, v~ side by side [b, s, C], what ``_gates``
+        reads, the output gate's input [b, s, Hv * dv])."""
+        with jax.named_scope("qkv"):        # obs.TICK_SCOPES
+            u = jnp.concatenate([self.q_proj(x), self.k_proj(x),
+                                 self.v_proj(x)], -1)
+            a, bb, gate = self.a_proj(x), self.b_proj(x), self.g_proj(x)
+        return u, (a, bb), gate
+
+    def _out_gate(self, gate):
+        return jax.nn.silu(gate)
 
     def _gates(self, a, b):
         """(log-decay, beta) [..., Hv] float32 from the two projections."""
@@ -263,29 +277,23 @@ class GatedDeltaNet(Layer):
     def forward(self, x, positions, kv_cache=None, segment_ids=None,
                 paged_chunk: bool = False, paged_decode: bool = False,
                 attn_mask=None):
-        cfg = self.config
         if attn_mask is not None:
             raise NotImplementedError(
                 "a linear-attention layer takes no attention mask")
         b, s, _ = x.shape
-        hv, dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
-        dk = cfg.linear_key_head_dim
-        with jax.named_scope("qkv"):        # obs.TICK_SCOPES
-            u = jnp.concatenate([self.q_proj(x), self.k_proj(x),
-                                 self.v_proj(x)], -1)
-            a, bb, gate = self.a_proj(x), self.b_proj(x), self.g_proj(x)
+        _, hv, dk, dv, taps = self.geometry
+        u, gated, gate = self._project(x)
         hp = delta_rule.state_lane_heads(hv, dv)
         new_cache = None
         if kv_cache is None:
             # no cache: every row of the batch a sequence from zero state
             with jax.named_scope("conv"):
-                tail = jnp.zeros((cfg.linear_conv_kernel_dim - 1,
-                                  u.shape[-1]), u.dtype)
+                tail = jnp.zeros((taps - 1, u.shape[-1]), u.dtype)
                 y = jax.vmap(lambda ur: delta_rule.conv_chunk(
                     ur, self.conv_weight, tail)[0])(u)
                 q, k, v = self._heads(jax.nn.silu(y))
             with jax.named_scope("chunk_delta_state"):
-                g, beta = self._gates(a, bb)
+                g, beta = self._gates(*gated)
                 S0 = jnp.zeros((hv, dk, dv), jnp.float32)
                 o = jax.vmap(lambda *r: delta_rule.gated_delta_chunk(
                     *r, S0)[0])(q, k, v, g, beta)
@@ -298,7 +306,7 @@ class GatedDeltaNet(Layer):
                                                 tails, live)
                 q, k, v = self._heads(jax.nn.silu(y))
             with jax.named_scope("delta_state"):
-                g, beta = self._gates(a[:, 0], bb[:, 0])
+                g, beta = self._gates(*(t[:, 0] for t in gated))
                 S, o = delta_rule.delta_state_step(S, q, k, v, jnp.exp(g),
                                                    beta, live)
                 o = o[:, None]
@@ -329,9 +337,10 @@ class GatedDeltaNet(Layer):
                 tails = tails.at[slots].set(new_tails, mode="drop")
                 q, k, v = self._heads(jax.nn.silu(y))
             with jax.named_scope("chunk_delta_state"):
-                g, beta = self._gates(a[0], bb[0])
+                g, beta = self._gates(*(t[0] for t in gated))
                 # a padded position changes nothing
-                g = jnp.where(real[:, None], g, 0.0)
+                g = jnp.where(real[:, None] if g.ndim == 2
+                              else real[:, None, None], g, 0.0)
                 beta = jnp.where(real[:, None], beta, 0.0)
                 S0 = jnp.where(fresh0, 0.0,
                                delta_rule.unpack_state(S[slots[0]], hp))
@@ -342,7 +351,7 @@ class GatedDeltaNet(Layer):
                 o = o[None]
             new_cache = kv_cache._replace(arrays=(S, tails))
         with jax.named_scope("gate_norm"):     # o is float32, and stays
-            o = self.o_norm(o) * jax.nn.silu(
+            o = self.o_norm(o) * self._out_gate(
                 gate.astype(jnp.float32).reshape(b, s, hv, dv))
             o = o.astype(x.dtype).reshape(b, s, hv * dv)
         with jax.named_scope("o_proj"):

@@ -1,12 +1,17 @@
-"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464) and the short
-causal convolution in front of it: a linear-attention layer's recurrence
-in the three forms a served model needs.
+"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464; with a decay
+for every key channel Kimi Delta Attention, arXiv:2510.26692) and the
+short causal convolution in front of it: a linear-attention layer's
+recurrence in the three forms a served model needs.
 
 Per head, with a state ``S`` in R^{dk x dv}, a decay ``alpha_t`` in
 (0, 1], a write strength ``beta_t`` and L2-normalised ``q_t``, ``k_t``::
 
     S = alpha_t S;  d_t = beta_t (v_t - S^T k_t);  S = S + k_t d_t^T;
     o_t = S^T q_t
+
+``alpha_t`` is one number a head (a log-decay ``g`` of shape [T, H]) or
+one a KEY CHANNEL (``g`` [T, H, dk]): row ``c`` of ``S`` times
+``alpha_t[c]``. Every form takes either.
 
 - ``gated_delta_scan``: exactly that, position by position
   (``lax.scan``). The definition the other two are held to.
@@ -25,6 +30,16 @@ Everything here is float32 and its matrix products are asked for at
 ``highest`` precision: the state is an accumulator over a whole context.
 A position with ``beta`` 0 and ``alpha`` 1 (log-decay 0) changes
 nothing: that is how a caller pads.
+
+A channel's decays cannot be taken out of a chunk's pairwise products as
+a head's can (``(k_i . k_j) exp(G_i - G_j)``): the chunk form multiplies
+``k_i exp(G_i - G_b)`` by ``k_j exp(G_b - G_j)`` a channel, ``G_b`` the
+cumulated log-decay at the middle of ``i``'s block of ``_BASE``
+positions, and either factor grows with the block's length. It is exact
+while ``_BASE * |g|`` stays under float32's 88 (each factor then inside
+e^+-40): a channel's log-decay is at least ``CHANNEL_LOG_DECAY_MIN`` a
+position (Kimi Delta Attention bounds its own at -5), which the caller
+keeps.
 """
 from __future__ import annotations
 
@@ -41,6 +56,9 @@ __all__ = ["state_lane_heads", "pack_state", "unpack_state",
 _HI = jax.lax.Precision.HIGHEST
 SUB_CHUNK = 64          # positions solved as one triangular system
 _BASE = 16              # rows inverted by forward substitution
+# the least log-decay a position of a CHANNEL decay may carry: a block
+# of _BASE of them spans exp(80), half on either side of its reference
+CHANNEL_LOG_DECAY_MIN = -5.0
 
 
 def l2_normalize(x, eps: float = 1e-6):
@@ -90,10 +108,11 @@ def _spread(x, hp: int, dv: int):
 def delta_state_step(S, q, k, v, alpha, beta, live):
     """One position of every row. ``S`` [R, G, dk, hp*dv] float32 in the
     stored form; q, k [R, H, dk] (normalised, q scaled); v [R, H, dv];
-    alpha, beta [R, H]; ``live`` [R] bool: a row that is not live keeps
-    its state. Returns (S, o [R, H, dv]).
+    beta [R, H]; alpha [R, H], or [R, H, dk] a key channel; ``live`` [R]
+    bool: a row that is not live keeps its state. Returns (S, o [R, H,
+    dv]).
 
-    The output is ``alpha S^T q + (k . q) d``, the new state's read
+    The output is ``(alpha S)^T q + (k . q) d``, the new state's read
     without reading it. A state the Pallas kernel tiles
     (``use_state_kernel``: whole lane and sublane tiles, float32, a TPU
     or the interpreter) takes it: ONE read and one write of each row's
@@ -106,6 +125,15 @@ def delta_state_step(S, q, k, v, alpha, beta, live):
     R, H, dv = v.shape
     hp = H // S.shape[1]
     kx, qx = _spread(k, hp, dv), _spread(q, hp, dv)     # [R, G, dk, L]
+    if alpha.ndim == 3:     # a channel: the decayed state is the operand
+        Sd = S * _spread(alpha, hp, dv)
+        bx = _spread(beta, hp, dv)
+        d = bx * (v.reshape(R, H // hp, hp * dv) - jnp.sum(Sd * kx, axis=2))
+        o = jnp.sum(Sd * qx, axis=2) \
+            + _spread(jnp.sum(q * k, -1), hp, dv) * d
+        new = Sd + kx * d[:, :, None, :]
+        return (jnp.where(live[:, None, None, None], new, S),
+                o.reshape(R, H, dv))
     ax, bx = _spread(alpha, hp, dv), _spread(beta, hp, dv)  # [R, G, L]
     qk = _spread(jnp.sum(q * k, -1), hp, dv)
     vx = v.reshape(R, H // hp, hp * dv)
@@ -121,11 +149,11 @@ def delta_state_step(S, q, k, v, alpha, beta, live):
 # ------------------------------------------------------ position by position
 def gated_delta_scan(q, k, v, g, beta, S0):
     """The recurrence itself. q, k [T, H, dk]; v [T, H, dv]; ``g`` [T, H]
-    the LOG of the decay; beta [T, H]; S0 [H, dk, dv]. Returns
-    (o [T, H, dv], S [H, dk, dv])."""
+    (or [T, H, dk], a key channel) the LOG of the decay; beta [T, H];
+    S0 [H, dk, dv]. Returns (o [T, H, dv], S [H, dk, dv])."""
     def step(S, x):
         qt, kt, vt, gt, bt = x
-        S = jnp.exp(gt)[:, None, None] * S
+        S = jnp.exp(gt).reshape(gt.shape + (1,) * (3 - gt.ndim)) * S
         d = bt[:, None] * (vt - jnp.einsum("hkv,hk->hv", S, kt,
                                            precision=_HI))
         S = S + kt[:, :, None] * d[:, None, :]
@@ -169,12 +197,34 @@ def _unit_lower_inverse(A):
     return X[..., 0, :, :]
 
 
+def _sub_chunks(q, k, v, g, beta, seg, c: int):
+    """The T positions padded to N whole sub-chunks of ``c`` (a padded
+    position: beta 0, g 0, its predecessor's segment) and a head's
+    sub-chunk made one matrix: q, k [N, H, c, dk]; v [N, H, c, dv]; g
+    [N, H, c] (or [N, H, c, dk]); beta [N, H, c]; seg [N, c]."""
+    T = q.shape[0]
+    N = -(-T // c)
+    pad = N * c - T
+    if pad:
+        def grow(x, fill=0):
+            return jnp.concatenate(
+                [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)], 0)
+        q, k, v, g, beta = grow(q), grow(k), grow(v), grow(g), grow(beta)
+        seg = jnp.concatenate([seg, jnp.broadcast_to(seg[-1:], (pad,))])
+    # [N, H, c, .]: a head's sub-chunk is one matrix
+    hm = lambda x: jnp.swapaxes(x.reshape((N, c) + x.shape[1:]), 1, 2)  # noqa: E731
+    q, k, v = hm(q), hm(k), hm(v)
+    g = hm(g) if g.ndim == 3 else hm(g[..., None])[..., 0]
+    return q, k, v, g, hm(beta[..., None])[..., 0], seg.reshape(N, c)
+
+
 @functools.partial(jax.jit, inline=True,
                    static_argnames=("segments", "sub"))
 def gated_delta_chunk(q, k, v, g, beta, S0, seg=None, segments: int = 1,
                       sub: int = SUB_CHUNK):
     """A chunk of T positions at once. q, k [T, H, dk]; v [T, H, dv];
-    ``g`` [T, H] log-decay; beta [T, H]; ``S0`` [H, dk, dv] the state
+    ``g`` [T, H] log-decay (or [T, H, dk], a key channel, none under
+    ``CHANNEL_LOG_DECAY_MIN``); beta [T, H]; ``S0`` [H, dk, dv] the state
     behind position 0 (it belongs to position 0's segment). ``seg`` [T]
     int32 (None: one segment): non-decreasing segment indices below
     ``segments``; a position whose segment differs from its
@@ -187,19 +237,10 @@ def gated_delta_chunk(q, k, v, g, beta, S0, seg=None, segments: int = 1,
     if seg is None:
         seg = jnp.zeros((T,), jnp.int32)
     c = sub
-    N = -(-T // c)
-    pad = N * c - T
-    if pad:
-        def grow(x, fill=0):
-            return jnp.concatenate(
-                [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)], 0)
-        q, k, v, g, beta = grow(q), grow(k), grow(v), grow(g), grow(beta)
-        seg = jnp.concatenate([seg, jnp.broadcast_to(seg[-1:], (pad,))])
-    # [N, H, c, .]: a head's sub-chunk is one matrix
-    hm = lambda x: jnp.swapaxes(x.reshape((N, c) + x.shape[1:]), 1, 2)  # noqa: E731
-    q, k, v = hm(q), hm(k), hm(v)
-    g, beta = hm(g[..., None])[..., 0], hm(beta[..., None])[..., 0]
-    seg = seg.reshape(N, c)
+    q, k, v, g, beta, seg = _sub_chunks(q, k, v, g, beta, seg, c)
+    N = seg.shape[0]
+    if g.ndim == 4:
+        return _chunk_channel(q, k, v, g, beta, S0, seg, segments, T)
     G = jnp.cumsum(g, -1)                                   # [N, H, c]
     same = (seg[:, :, None] == seg[:, None, :])[:, None]    # [N, 1, c, c]
     i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
@@ -253,6 +294,86 @@ def gated_delta_chunk(q, k, v, g, beta, S0, seg=None, segments: int = 1,
         * jnp.exp(G_e[..., 0])                                  # [S, H]
     S_seg = from_in[..., None, None] * S_in[n_e] + jnp.einsum(
         "shjk,shjv->shkv", k[n_e] * w[..., None], vn[n_e], precision=_HI)
+    return o, S_seg
+
+
+def _chunk_channel(q, k, v, g, beta, S0, seg, segments: int, T: int):
+    """``gated_delta_chunk`` behind its sub-chunking, for a decay a key
+    channel (g [N, H, c, dk]): the same sums with every decay a vector
+    over ``dk``. Only the pairwise products differ in kind (module
+    docstring): row ``i``'s factor and column ``j``'s are both relative
+    to the cumulated log-decay ``ref`` at the middle of ``i``'s block of
+    ``_BASE`` positions."""
+    N, H, c, dk = q.shape
+    dv = v.shape[-1]
+    b = min(c, _BASE)
+    nb = c // b
+    G = jnp.cumsum(g, -2)                                   # [N, H, c, dk]
+    same = (seg[:, :, None] == seg[:, None, :])[:, None]    # [N, 1, c, c]
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    # a block's reference: the cumulated log-decay at its MIDDLE, so
+    # that a row's factor and a column's both stay inside e^+-40 (a
+    # factor near e^-80 would lose its low-order part where the chip
+    # splits a float32 product into bfloat16 pieces and flushes the
+    # smallest to zero)
+    ref = G[..., (b - 1) // 2::b, :]                        # [N, H, nb, dk]
+    up = jnp.exp(G - jnp.repeat(ref, b, axis=-2))
+    # column j as block I's rows see it: relative to the same reference
+    # (at most e^40 inside the block, decayed further and further before
+    # it), nothing behind the block
+    seen = jnp.arange(c)[None, :] < b * (jnp.arange(nb)[:, None] + 1)
+    kh = k[:, :, None] * jnp.exp(jnp.where(
+        seen[..., None], ref[..., None, :] - G[:, :, None], -jnp.inf))
+
+    def pairs(x):       # sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c]), j <= i
+        return jnp.einsum("nhIik,nhIjk->nhIij",
+                          (x * up).reshape(N, H, nb, b, dk), kh,
+                          precision=_HI).reshape(N, H, c, c)
+
+    A = jnp.where(same & (i > j), beta[..., None] * pairs(k), 0.0)
+    Tm = _unit_lower_inverse(A)
+    qk = jnp.where(same & (i >= j), pairs(q), 0.0)
+    # the segment of the state that enters each sub-chunk
+    prev = jnp.concatenate([seg[:1, 0], seg[:-1, -1]])      # [N]
+    carry = (seg == prev[:, None])[:, None, :, None]        # [N, 1, c, 1]
+    gin = jnp.where(carry, jnp.exp(G), 0.0)                 # [N, H, c, dk]
+    W = jnp.einsum("nhij,nhjk->nhik", Tm, k * beta[..., None] * gin,
+                   precision=_HI)
+    U = jnp.einsum("nhij,nhjv->nhiv", Tm, v * beta[..., None],
+                   precision=_HI)
+    # to the sub-chunk's end, inside the end's segment
+    kout = k * jnp.where(same[..., -1, :, None],
+                         jnp.exp(G[..., -1:, :] - G), 0.0)
+    keep = gin[..., -1, :]                                  # [N, H, dk]
+
+    def step(S, x):
+        qin_n, kout_n, W_n, U_n, qk_n, keep_n = x
+        vn = U_n - jnp.einsum("hik,hkv->hiv", W_n, S, precision=_HI)
+        o = jnp.einsum("hik,hkv->hiv", qin_n, S, precision=_HI) \
+            + jnp.einsum("hij,hjv->hiv", qk_n, vn, precision=_HI)
+        S_out = keep_n[..., None] * S + jnp.einsum(
+            "hjk,hjv->hkv", kout_n, vn, precision=_HI)
+        return S_out, (o, S, vn)
+
+    S_end, (o, S_in, vn) = jax.lax.scan(
+        step, S0, (q * gin, kout, W, U, qk, keep))
+    o = jnp.swapaxes(o, 1, 2).reshape(N * c, H, dv)[:T]
+    if segments == 1:
+        return o, S_end[None]
+    # a segment that ends inside a sub-chunk (``gated_delta_chunk``)
+    flat = seg.reshape(-1)
+    sid = jnp.arange(segments)
+    e = jnp.max(jnp.where(flat[None, :] == sid[:, None],
+                          jnp.arange(N * c)[None, :], 0), -1)   # [S]
+    n_e, i_e = e // c, e % c
+    G_e = jnp.take_along_axis(G[n_e], i_e[:, None, None, None], -2)
+    mine = (seg[n_e] == sid[:, None]) & (jnp.arange(c)[None, :]
+                                         <= i_e[:, None])       # [S, c]
+    w = jnp.exp(jnp.where(mine[:, None, :, None], G_e - G[n_e], -jnp.inf))
+    from_in = jnp.where(prev[n_e] == sid, 1.0, 0.0)[:, None, None] \
+        * jnp.exp(G_e[..., 0, :])                               # [S, H, dk]
+    S_seg = from_in[..., None] * S_in[n_e] + jnp.einsum(
+        "shjk,shjv->shkv", k[n_e] * w, vn[n_e], precision=_HI)
     return o, S_seg
 
 

@@ -28,11 +28,20 @@ can.
   size of the state is built beside it.
 - a row that is not ``live`` gets its output ``o`` like every row and
   its state copied as it is.
+- a decay a KEY CHANNEL (``alpha`` [R, H, dk]; Kimi Delta Attention) is
+  a column a head like ``k`` and ``q`` and rides beside them, ``[R, dk,
+  3H]``; ``beta`` and ``q . k`` then ride beside ``v``, ``[R, 3G, L]``,
+  already spread along their heads' lanes, and only ``live`` is
+  prefetched (`_state_kernel_channel`). The decayed tile ``alpha * s`` is
+  what the sums and the update read: still one read and one write.
 
 The arithmetic is the jnp body's, in float32 on the VPU: ``rk =
 sum_dk(S * kx)``, ``rq = sum_dk(S * qx)``, ``d = beta * (v - alpha *
-rk)``, ``o = alpha * rq + (q . k) * d``, ``S' = alpha * S + kx * d``.
-Only the order of the ``dk``-term column sums may differ.
+rk)``, ``o = alpha * rq + (q . k) * d``, ``S' = alpha * S + kx * d``
+(a channel decay: ``Sd = ax * S``, ``rk = sum_dk(Sd * kx)``, ``rq =
+sum_dk(Sd * qx)``, ``d = beta * (v - rk)``, ``o = rq + (q . k) * d``,
+``S' = Sd + kx * d``). Only the order of the ``dk``-term column sums
+may differ.
 """
 from __future__ import annotations
 
@@ -77,16 +86,14 @@ def _rows_per_step(R: int, slot_bytes: int) -> int:
     return rows
 
 
-def _state_kernel(live_ref, abc_ref, s_ref, kq_ref, v_ref, new_ref, o_ref,
-                  *, hp, dv):
-    rows, G, dk, L = s_ref.shape
-    H = G * hp
+def _spreader(hp, dv):
+    """``spread(of_head, g, t)``: lane tile ``t`` of group ``g``: along
+    its 128 lanes ``of_head(head)`` (a scalar, or a column [dk, 1]) of
+    the head that owns each lane; left as it is where one head owns
+    all."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
 
     def spread(of_head, g, t):
-        """Lane tile ``t`` of group ``g``: along its 128 lanes
-        ``of_head(head)`` (a scalar, or a column [dk, 1]) of the head
-        that owns each lane; left as it is where one head owns all."""
         first = t * _LANES // dv
         last = (t * _LANES + _LANES - 1) // dv
         out = of_head(g * hp + first)
@@ -94,6 +101,14 @@ def _state_kernel(live_ref, abc_ref, s_ref, kq_ref, v_ref, new_ref, o_ref,
             out = jnp.where(lane >= j * dv - t * _LANES,
                             of_head(g * hp + j), out)
         return out
+    return spread
+
+
+def _state_kernel(live_ref, abc_ref, s_ref, kq_ref, v_ref, new_ref, o_ref,
+                  *, hp, dv):
+    rows, G, dk, L = s_ref.shape
+    H = G * hp
+    spread = _spreader(hp, dv)
 
     for r in range(rows):
         row = pl.program_id(0) * rows + r
@@ -117,11 +132,41 @@ def _state_kernel(live_ref, abc_ref, s_ref, kq_ref, v_ref, new_ref, o_ref,
                 new_ref[r, g, :, at] = jnp.where(live, a * s + kx * d, s)
 
 
+def _state_kernel_channel(live_ref, s_ref, kqa_ref, vbc_ref, new_ref, o_ref,
+                          *, hp, dv):
+    """`_state_kernel` for a decay a key channel: ``kqa`` [rows, dk, 3H]
+    holds k, q and alpha a head a lane; ``vbc`` [rows, 3G, L] holds v,
+    beta and q . k in the state's lanes."""
+    rows, G, dk, L = s_ref.shape
+    H = G * hp
+    spread = _spreader(hp, dv)
+    for r in range(rows):
+        live = live_ref[pl.program_id(0) * rows + r] != 0
+        kqa = kqa_ref[r]                                # [dk, 3H]
+        for g in range(G):
+            for t in range(L // _LANES):
+                at = slice(t * _LANES, (t + 1) * _LANES)
+                s = s_ref[r, g, :, at]                  # [dk, 128]
+                kx, qx, ax = (spread(lambda h, i=i: kqa[:, i * H + h:
+                                                        i * H + h + 1], g, t)
+                              for i in range(3))
+                sd = ax * s
+                rk = jnp.sum(sd * kx, axis=0, keepdims=True)
+                rq = jnp.sum(sd * qx, axis=0, keepdims=True)
+                d = vbc_ref[r, G + g:G + g + 1, at] \
+                    * (vbc_ref[r, g:g + 1, at] - rk)
+                o_ref[r, g:g + 1, at] = rq \
+                    + vbc_ref[r, 2 * G + g:2 * G + g + 1, at] * d
+                new_ref[r, g, :, at] = jnp.where(live, sd + kx * d, s)
+
+
 def delta_state_step_pallas(S, q, k, v, alpha, beta, live):
     """`delta_rule.delta_state_step`'s operands and results: ``S`` [R, G,
-    dk, hp*dv] float32; q, k [R, H, dk]; v [R, H, dv]; alpha, beta [R,
-    H]; ``live`` [R] bool. Returns (S, o [R, H, dv])."""
-    return _step(S, q, k, v, alpha, beta, live, interpret=_interpret())
+    dk, hp*dv] float32; q, k [R, H, dk]; v [R, H, dv]; beta [R, H];
+    alpha [R, H] or, a key channel, [R, H, dk]; ``live`` [R] bool.
+    Returns (S, o [R, H, dv])."""
+    step = _step_channel if alpha.ndim == 3 else _step
+    return step(S, q, k, v, alpha, beta, live, interpret=_interpret())
 
 
 # jitted and inlined as the other kernels' wrappers are: a program of L
@@ -156,4 +201,39 @@ def _step(S, q, k, v, alpha, beta, live, *, interpret):
             vmem_limit_bytes=4 * rows * G * dk * L * 4 + _VMEM_SPARE),
         interpret=interpret,
     )(live.astype(jnp.int32), abc, S, kq, v.astype(f32).reshape(R, G, L))
+    return new, o.reshape(R, H, dv)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("interpret",))
+def _step_channel(S, q, k, v, alpha, beta, live, *, interpret):
+    R, G, dk, L = S.shape
+    H, dv = v.shape[1:]
+    hp = H // G
+    rows = _rows_per_step(R, G * dk * L * 4)
+    f32 = jnp.float32
+    kqa = jnp.concatenate([k, q, alpha], 1).astype(f32).swapaxes(1, 2)
+    in_lanes = lambda x: jnp.repeat(                        # noqa: E731
+        x.astype(f32), dv, axis=-1).reshape(R, G, L)
+    vbc = jnp.concatenate([v.astype(f32).reshape(R, G, L), in_lanes(beta),
+                           in_lanes(jnp.sum(q * k, -1))], 1)  # [R, 3G, L]
+    by_rows = lambda *tail: pl.BlockSpec(                   # noqa: E731
+        (rows,) + tail, lambda i, live: (i,) + (0,) * len(tail))
+    new, o = pl.pallas_call(
+        functools.partial(_state_kernel_channel, hp=hp, dv=dv),
+        name="delta_state_step_channel",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(R // rows,),
+            in_specs=[by_rows(G, dk, L), by_rows(dk, 3 * H),
+                      by_rows(3 * G, L)],
+            out_specs=[by_rows(G, dk, L), by_rows(G, L)]),
+        out_shape=[jax.ShapeDtypeStruct(S.shape, f32),
+                   jax.ShapeDtypeStruct((R, G, L), f32)],
+        # operand 0 is the prefetched ``live``
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=4 * rows * G * dk * L * 4 + _VMEM_SPARE),
+        interpret=interpret,
+    )(live.astype(jnp.int32), S, kqa, vbc)
     return new, o.reshape(R, H, dv)

@@ -228,6 +228,11 @@ SERVING_COUNTERS = ("moe_layer_ticks", "moe_local_assignments",
 # rows' choices (rows x top_k, a layer and tick) and those of them that
 # fell on a zero column
 ZERO_COUNTERS = ("moe_live_choices", "moe_zero_choices")
+# and, last, where the layer is asked to (``count_rows_routed``): the
+# live rows of which at least one choice fell on a HELD expert, a layer
+# and tick. Group-limited routing sends the others past this rank
+# altogether: a grouped product over sorted tokens would skip them
+ROUTED_COUNTERS = ("moe_rows_routed_here",)
 _collecting = threading.local()     # engines trace on threads of their own
 
 
@@ -242,9 +247,11 @@ class _Counts:
 @contextlib.contextmanager
 def collect_counts(rows):
     """Sum, over the expert layers traced inside the ``with``, their
-    ``SERVING_COUNTERS`` of this forward (``.total``: an int32 vector,
-    or None where no such layer ran). ``rows`` [b] says which rows of
-    the batch are live; the others are computed and not counted."""
+    ``SERVING_COUNTERS`` of this forward, then the ``ZERO_COUNTERS`` and
+    ``ROUTED_COUNTERS`` of layers that count them (``.total``: an int32
+    vector, or None where no such layer ran). ``rows`` [b] says which
+    rows of the batch are live; the others are computed and not
+    counted."""
     prev = getattr(_collecting, "box", None)
     box = _collecting.box = _Counts(rows)
     try:
@@ -282,7 +289,7 @@ class ExpertShareMLP(Layer):
                  n_group: int = 1, topk_group: int = 1,
                  scoring: str = "softmax",
                  group_score_mode: str = "max", zero_experts: int = 0,
-                 name=None):
+                 count_rows_routed: bool = False, name=None):
         super().__init__(name)
         if not 0 <= first_expert <= num_experts - experts_held:
             raise ValueError(
@@ -296,6 +303,7 @@ class ExpertShareMLP(Layer):
         self.n_group, self.topk_group = n_group, topk_group
         self.scoring, self.group_score_mode = scoring, group_score_mode
         self.zero_experts = zero_experts
+        self.count_rows_routed = count_rows_routed
         E, n, h, m = num_experts + zero_experts, experts_held, \
             hidden_size, intermediate_size
         init = I.XavierNormal()
@@ -360,6 +368,9 @@ class ExpertShareMLP(Layer):
                     jnp.sum(live, dtype=jnp.int32) * self.top_k,
                     jnp.sum((ids >= self.num_experts) & live[:, None],
                             dtype=jnp.int32)]
+            if self.count_rows_routed:  # ROUTED_COUNTERS
+                counts.append(jnp.sum(jnp.any(chose, axis=(1, 2)),
+                                      dtype=jnp.int32))
             box.add(jnp.stack(counts))
         if kernel:
             return expert_mlp.expert_share_mlp_pallas(

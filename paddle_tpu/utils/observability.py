@@ -969,11 +969,16 @@ TICK_SCOPES = (
                            # ring: the band behind the chunk and the chunk
     "conv",        # linear attention: the short causal convolutions
                    # of q~, k~, v~, their SiLU, the tail's shift
+    "decay_gate",  # linear attention with a decay a key channel (Kimi
+                   # Delta Attention): the full-rank W_f product and
+                   # the bounded sigmoid that make the log-decays
     "delta_state",     # linear attention, decode: the gated delta
                        # rule's step with the state's read and write
     "chunk_delta_state",   # linear attention, chunk: its chunkwise-
                            # parallel form, the state carried in and out
     "gate_norm",   # linear attention: the per-head norm and output gate
+    "head_gate",   # latent attention with a gate a head: sigmoid(W_gate
+                   # x)[head] times the head's output, before o_proj
     "o_proj",
     "mlp",         # a dense FFN; of an expert layer the residual add
     "router",      # expert layer: float32 scores, groups, top-k, gates

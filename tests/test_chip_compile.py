@@ -224,15 +224,22 @@ def test_write_and_attend_compile_with_no_pool_sized_copy(
     assert read and read == params and len(params) == 1, (read, params)
 
 
+@pytest.mark.parametrize("R,H,dk,dv,channel", [
+    (32, 30, 96, 192, False),       # olmo-hybrid-7b-d16
+    (128, 32, 128, 128, True),      # ling-3.0-flash-ep16-d14
+], ids=["olmo", "ling"])
 @pytest.mark.parametrize("route", ["fusions", "kernel"])
 def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
                                                       no_persistent_cache,
-                                                      monkeypatch, route):
+                                                      monkeypatch, route,
+                                                      R, H, dk, dv, channel):
     """ISSUE 38: a linear-attention layer's decode step at the hybrid
     cell's shapes (32 rows, 30 heads, keys 96, values 192). The state is
     stored two heads to a row (384 = 3 lane tiles): a 192-wide last axis
     alone is padded to 256 in the chip's memory, a third more to hold
-    and to move. Nothing the size of the state is materialised beside
+    and to move. ISSUE 41: and at Ling's (128 rows, 32 heads of 128 x
+    128, a head a lane tile) with a decay a key CHANNEL, a column beside
+    k and q. Nothing the size of the state is materialised beside
     it, by either route, and the donated state is updated in place.
     ``fusions`` (the jnp body, the gate held shut): two passes, one read
     for ``S^T k`` and ``S^T q``, one read and write. ``kernel`` (ISSUE
@@ -244,9 +251,8 @@ def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
     from paddle_tpu.ops import delta_rule
     from paddle_tpu.ops.pallas import delta_state
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
-    R, H, dk, dv = 32, 30, 96, 192
     hp = delta_rule.state_lane_heads(H, dv)
-    assert hp == 2
+    assert hp == (1 if channel else 2)
 
     def arr(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -254,17 +260,18 @@ def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
     if route == "kernel":
         monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
         assert delta_state.use_state_kernel(S)
-        # whole slots a grid step: 4.4 MB of traffic each, 5 us at the
-        # chip's bandwidth against the microsecond a step costs
+        # whole slots a grid step: 4.4 (4.2) MB of traffic each, 5 us at
+        # the chip's bandwidth against the microsecond a step costs
         assert delta_state._rows_per_step(R, S.size * 4 // R) \
-            * 2 * S.size * 4 // R > 4 << 20
+            * 2 * S.size * 4 // R >= 4 << 20
     else:
         monkeypatch.setattr(delta_state, "use_state_kernel",
                             lambda S: False)
     compiled = jax.jit(lambda *a: delta_rule.delta_state_step(*a),
                        donate_argnums=(0,)).lower(
         S, arr((R, H, dk)), arr((R, H, dk)), arr((R, H, dv)),
-        arr((R, H)), arr((R, H)), arr((R,), jnp.bool_)).compile()
+        arr((R, H, dk) if channel else (R, H)), arr((R, H)),
+        arr((R,), jnp.bool_)).compile()
     mem = compiled.memory_analysis()
     state = R * H * dk * dv * 4
     # the state as it is, unpadded, updated in place, no scratch copy
@@ -306,3 +313,59 @@ def test_a_query_group_of_one_compiles_for_a_v5e(one_chip,
             q, kp, vp, tbl, lens, 128 ** -0.5, 30)).lower(
         q, kp, kp, arr((R, M), jnp.int32), arr((R,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
+                                                no_persistent_cache,
+                                                monkeypatch, program):
+    """ISSUE 41: the family's forward in a decode tick's form (128 rows,
+    a position each) and in a prompt chunk's (one slot, 256 positions)
+    at the benchmark configuration's published widths, cut to three
+    layers that hold every kind (two Kimi-Delta-Attention layers over
+    dense FFNs, a gated latent layer over the 32 held experts of a
+    512-wide router): slot state beside a latent pool in one program. A
+    tick calls the channel-decay state kernel twice, the ragged kernel's
+    latent mode and the expert kernel once each; a chunk calls none."""
+    import json
+    import os
+    import paddle_tpu.ops.pallas as pallas
+    from benchmarks.harness import cell
+    from paddle_tpu.generation.paged import PagedKV, SlotState, StateLayer
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+    with open(os.path.join(cell.BENCH, "configs",
+                           "ling-3.0-flash-ep16-d14.json")) as f:
+        config = dict(json.load(f), num_hidden_layers=3, layer_group_size=3)
+    mod = cell.load_model(config)
+    model, spec = mod._program_model(mod.program_config(config))
+    fn = model.functional()[0]
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    params = {k: arr(s, d) for k, (s, d) in spec.items()}
+    eng = config["engine"]
+    R, M = eng["max_slots"], eng["max_blocks_per_seq"]
+    rows, T = (R, 1) if program == "tick" else (1, eng["chunk_prefill_tokens"])
+    lens = arr((rows,), jnp.int32)
+    flag = arr((rows,), jnp.bool_)
+    layers = model.paged_cache_layers()
+    assert [isinstance(x, StateLayer) for x in layers] == [True, True, False]
+    caches = [
+        SlotState(tuple(arr((R,) + tuple(s), d) for s, d in x.arrays),
+                  None if program == "tick" else arr((rows,), jnp.int32),
+                  lens, flag if program == "tick" else None,
+                  None if program == "tick" else flag)
+        if isinstance(x, StateLayer) else
+        PagedKV(arr((eng["num_blocks"], eng["block_size"], 640),
+                    jnp.bfloat16), None, arr((rows, M), jnp.int32), lens, 1,
+                False)
+        for x in layers]
+    compiled = jax.jit(
+        lambda p, ids, c, pos: fn(p, ids, kv_caches=c, positions=pos,
+                                  paged_chunk=program == "chunk")).lower(
+        params, arr((rows, T), jnp.int32), caches,
+        arr((rows, T), jnp.int32)).compile()
+    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    assert calls == (4 if program == "tick" else 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -183,3 +183,86 @@ def test_the_counters_count_live_rows_only(monkeypatch):
     with collect_counts(jnp.asarray(live)) as box:
         pass
     assert box.total is None            # no expert layer: nothing rides
+
+
+# ---- ISSUE 41: a 512-wide router in 8 groups, 16 shares of 32 experts
+WIDE = dict(scoring="sigmoid", n_group=8, topk_group=4,
+            group_score_mode="top2_sum", norm_topk_prob=True,
+            routed_scaling_factor=2.5)
+EW, KW = 512, 8
+
+
+def _wide(first=0, held=EW, seed=0, **kw):
+    pt.seed(seed)
+    layer = ExpertShareMLP(H, M, EW, KW, first, held, num_shared_experts=1,
+                           **dict(WIDE, **kw))
+    rs = np.random.RandomState(7)
+    layer.gate = jnp.asarray(rs.randn(H, EW), jnp.float32)
+    layer.expert_bias = jnp.asarray(0.01 * rs.randn(EW), jnp.float32)
+    return layer
+
+
+def _wide_share(full, first, held, **kw):
+    part = _wide(first, held, seed=1, **kw)
+    state = dict(full.state_dict())
+    for k in ("w_gate", "w_up", "w_down"):
+        state[k] = state[k][first:first + held]
+    part.set_state_dict(state)
+    return part
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["einsums", "kernel"])
+def test_sixteen_shares_of_a_512_wide_router_add_up(kernel, monkeypatch):
+    """Ling-3.0-flash's expert layer cut as its configuration cuts it:
+    512 columns in 8 groups of 64, 4 groups and 8 experts a token, 16
+    ranks of 32 experts (half a group each). The 16 routed parts and the
+    shared expert ONCE are the uncut layer; a rank whose group a token
+    did not choose adds exactly nothing for it."""
+    if kernel:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    full = _wide()
+    x = jnp.asarray(np.random.RandomState(2).randn(T, H), jnp.float32)
+    want = np.asarray(full(x), np.float64)
+    ids = np.asarray(full.route(x)[0])
+    assert ids.shape == (T, KW)
+    # group-limited: a token's 8 experts lie in 4 of the 8 groups
+    assert all(len(set(row // 64)) <= 4 for row in ids)
+    total = np.asarray(full.shared_out(x), np.float64)      # counted once
+    for first in range(0, EW, 32):
+        part = _wide_share(full, first, 32)
+        pids, gates = part.route(x)
+        assert np.array_equal(np.asarray(pids), ids)
+        out = np.asarray(part.routed(x, pids, gates), np.float64)
+        away = ~np.any((ids >= first) & (ids < first + 32), axis=-1)
+        assert away.any() and np.all(out[away] == 0)
+        total += out
+    np.testing.assert_allclose(total, want, atol=2e-5)
+
+
+def test_rows_routed_here_counts_live_rows_with_a_held_choice(monkeypatch):
+    """``count_rows_routed`` adds ``moe_rows_routed_here`` behind the
+    four counters: the live rows of which at least one choice is held,
+    a layer. A layer that is not asked counts four numbers as ever."""
+    from paddle_tpu.parallel.moe import ROUTED_COUNTERS
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    assert ROUTED_COUNTERS == ("moe_rows_routed_here",)
+    full = _wide()
+    x = jnp.asarray(np.random.RandomState(5).randn(12, 1, H), jnp.float32)
+    live = np.arange(12) % 3 != 1
+    part = _wide_share(full, 0, 32, count_rows_routed=True)
+    quiet = _wide_share(full, 0, 32)
+
+    def run(layer):
+        with collect_counts(jnp.asarray(live)) as box:
+            layer(x)
+            layer(x)
+        return np.asarray(box.total)
+
+    counted = run(part)
+    ids = np.asarray(part.route(x.reshape(-1, H))[0])
+    here = np.any(ids < 32, axis=-1) & live
+    assert 0 < here.sum() < live.sum()
+    assert counted.tolist() == run(quiet).tolist() + [2 * here.sum()]
+    assert counted[1] >= counted[4]     # assignments >= rows routed here

@@ -710,7 +710,8 @@ class TestCacheLayers:
     pages a slot; ``decode_route`` answers for every layer's shapes.
     ISSUE 38: a layer may keep STATE by slot instead (``StateLayer``):
     its arrays are ``[max_slots, ...]`` beside the pools, and the four
-    older families get exactly the arrays they got."""
+    older families get exactly the arrays they got. ISSUE 41: state
+    layers beside a LATENT pool (one array a layer) in one model."""
 
     @staticmethod
     def _built(family):
@@ -722,6 +723,8 @@ class TestCacheLayers:
                                                mimo_v2_tiny)
         from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
                                                    olmo_hybrid_tiny)
+        from paddle_tpu.models.ling_hybrid import (LingHybridForCausalLM,
+                                                   ling_hybrid_tiny)
         pt.seed(0)
         return {
             "llama": lambda: LlamaForCausalLM(llama_tiny()),
@@ -730,6 +733,8 @@ class TestCacheLayers:
                 longcat_flash_tiny(num_hidden_layers=1)),
             "mimo": lambda: MiMoV2ForCausalLM(mimo_v2_tiny()),
             "olmo": lambda: OlmoHybridForCausalLM(olmo_hybrid_tiny()),
+            "ling": lambda: LingHybridForCausalLM(ling_hybrid_tiny(
+                experts_held=4)),
         }[family]()
 
     @pytest.mark.parametrize("family,pools", [
@@ -750,6 +755,12 @@ class TestCacheLayers:
         # prompt calls' two counters behind them
         ("olmo", [((4, 1, 8, 128), (4, 3, 256))] * 3
          + [((32, 8, 64), (32, 8, 64)), ((2,),)]),
+        # two linear layers by SLOT (8 heads' 16 x 16 states in one
+        # 128-lane row, 3 inputs of 3 x 128 channels), then ONE latent
+        # row a token (32 + 8 columns padded to 128) by the allocator's
+        # pages, then the prompt calls' two counters
+        ("ling", [((4, 1, 16, 128), (4, 3, 384))] * 2
+         + [((32, 8, 128),), ((2,),)]),
     ])
     def test_each_familys_pools(self, family, pools):
         from paddle_tpu.generation.paged import StateLayer
@@ -763,10 +774,12 @@ class TestCacheLayers:
         # the extra tick counters exist only where a band is kept, or
         # state by slot; every other engine holds a pool a layer
         assert ("kv_window_blocks" in eng.stats) == (family == "mimo")
-        assert ("state_resets" in eng.stats) == (family == "olmo")
-        assert state == ([True] * 3 + [False] if family == "olmo"
+        slots = {"olmo": 3, "ling": 2}.get(family, 0)   # state layers
+        assert ("state_resets" in eng.stats) == bool(slots)
+        assert state == ([True] * slots + [False] if slots
                          else [False] * len(pools))
-        assert len(eng.pools) == len(eng._layout) + (family == "olmo")
+        assert len(eng.pools) == len(eng._layout) + bool(slots)
+        assert ("moe_rows_routed_here" in eng.stats) == (family == "ling")
 
     def test_decode_route_answers_for_every_layer(self, monkeypatch):
         """"ragged" only if EVERY cache layer's shapes take the kernel:

@@ -44,7 +44,10 @@ WINDOW = {"attn_window", "chunk_attn_window"}
 # a linear-attention layer's own parts (ISSUE 38): the short
 # convolutions, the recurrence's two forms, the gated per-head norm
 LINEAR = {"conv", "delta_state", "chunk_delta_state", "gate_norm"}
-LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW - LINEAR
+# what only Ling 3.0's layers have (ISSUE 41): the full-rank gate of a
+# decay a key channel, a gate a head on latent attention's output
+KDA = {"decay_gate", "head_gate"}
+LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW - LINEAR - KDA
 PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
@@ -54,9 +57,9 @@ PROGRAMS = {
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
 DEEPSEEK = {
-    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - LINEAR - {
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA - {
         "chunk_attn", "zero_experts"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - LINEAR - ATTN - {
+    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA - ATTN - {
         "patch", "absorb", "zero_experts"},
 }
 DEEPSEEK["_chunk_prefill_packed"] = DEEPSEEK["_chunk_prefill"] | {"patch"}
@@ -82,6 +85,17 @@ HYBRID = {
     "_chunk_prefill": LLAMA - ATTN - {"patch"} | LINEAR - {"delta_state"},
 }
 HYBRID["_chunk_prefill_packed"] = HYBRID["_chunk_prefill"] | {"patch"}
+# Ling 3.0: Kimi-Delta-Attention layers (Olmo-Hybrid's scopes and the
+# decay gate's), gated latent layers (DeepSeek's and the head gate's),
+# experts with a shared one. A chunk attends in the expanded form
+LING = {
+    "_fused_tick_greedy": LLAMA - {"chunk_attn"} | KDA | LINEAR - {
+        "chunk_delta_state"} | {"router", "experts", "shared_expert",
+                                "absorb"},
+    "_chunk_prefill": LLAMA - ATTN - {"patch"} | KDA | LINEAR - {
+        "delta_state"} | {"router", "experts", "shared_expert"},
+}
+LING["_chunk_prefill_packed"] = LING["_chunk_prefill"] | {"patch"}
 
 
 @pytest.fixture(scope="module")
@@ -238,10 +252,46 @@ def test_linear_attention_layers_carry_scopes_of_their_own(hybrid_engine,
             + [("ragged_paged_attention", "attn")]
 
 
+@pytest.fixture(scope="module")
+def ling_engine():
+    from paddle_tpu.models.ling_hybrid import (LingHybridForCausalLM,
+                                               ling_hybrid_tiny)
+    eng = PagedEngine(LingHybridForCausalLM(ling_hybrid_tiny(
+        experts_held=4)), max_slots=4, num_blocks=32, block_size=8,
+        max_blocks_per_seq=8, chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(LING))
+def test_ling_layers_carry_the_scopes_of_both_kinds(ling_engine, kernels,
+                                                    program):
+    """Two Kimi-Delta-Attention layers and a gated latent one over a
+    dense FFN and two expert layers (ISSUE 41): the scopes that exist
+    keep their names where the work is the same, and only the decay
+    gate and the head gate are new; a tick's kernel calls are the two
+    state steps at a decay a CHANNEL, the latent ragged call and the two
+    expert layers' kernels, each under the scope the readers look in."""
+    assert ling_engine.decode_route() == "ragged"
+    _, scopes = _lowered(ling_engine, program)
+    assert set(scopes) - {None} == LING[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+    if program.startswith("_chunk_prefill"):
+        assert scopes["chunk_delta_state"] > scopes["chunk_attn"]
+    else:
+        jaxpr = _trace(ling_engine, program).jaxpr.jaxpr
+        assert _kernel_calls(jaxpr) == [
+            ("delta_state_step_channel", "delta_state"),
+            ("delta_state_step_channel", "delta_state"),
+            ("expert_share_mlp", "experts"),
+            ("ragged_paged_attention", "attn"),
+            ("expert_share_mlp", "experts")]
+
+
 def test_the_programs_use_the_whole_vocabulary():
     assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
                        *LONGCAT.values(), *MIMO.values(),
-                       *HYBRID.values()) \
+                       *HYBRID.values(), *LING.values()) \
         == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES

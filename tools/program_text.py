@@ -11,7 +11,7 @@ are lowered on the CPU, nothing compiled or run, and one line a program
 is printed: its name, a digest of its StableHLO text, the text's length.
 Two checkouts that print the same lines hand XLA the same programs for
 those families: the check a PR makes that adds a family or a mechanism
-beside them (PR 33, PR 38). A family the checkout lacks is left out, so
+beside them (PR 33, PR 38, PR 41). A family the checkout lacks is left out, so
 the older checkout's lines are a subset.
 """
 import hashlib
@@ -39,6 +39,8 @@ FAMILIES = {
     "mimo": ("MiMoV2ForCausalLM", "mimo_v2_tiny", dict(experts_held=4),
              True),
     "olmo_hybrid": ("OlmoHybridForCausalLM", "olmo_hybrid_tiny", {}, False),
+    "ling_hybrid": ("LingHybridForCausalLM", "ling_hybrid_tiny",
+                    dict(experts_held=4), False),
 }
 GEOMETRY = dict(max_slots=4, num_blocks=64, block_size=4,
                 max_blocks_per_seq=16, chunk_prefill_tokens=16)

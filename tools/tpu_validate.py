@@ -9,8 +9,9 @@ only ever sees through the interpreter once through the real compiler
 on whatever backend jax selects, each against its XLA reference: the
 flash kernel's segment / window / backward variants, ``quant_matmul``,
 the decode re-block, the ragged paged-attention kernel (single- and
-multi-query, K/V and latent pools), and the tick / patch / restore
-programs of the serving engine. ``chip_smoke.py`` covers the default serving route
+multi-query, K/V and latent pools), the delta rule's state step and
+chunk form at a decay a head and a decay a channel, and the tick / patch
+/ restore programs of the serving engine. ``chip_smoke.py`` covers the default serving route
 end to end; this covers the kernels and programs off that route.
 
 The checks run in ONE child process (a chip belongs to one process;
@@ -596,6 +597,97 @@ def delta_rule_state():
           %% ((err_o, err_S) + errs["kernel"] + errs["fusions"]),
           flush=True)
 check("delta_rule_state", delta_rule_state)
+
+def kda_channel_state():
+    # ISSUE 41: the delta rule at a decay a KEY CHANNEL at Ling-3.0-
+    # flash's shapes (32 heads of 128 x 128, a head a lane tile, a
+    # 256-position chunk): the one-pass state kernel (alpha a column
+    # beside k and q) and the chunkwise form, random gates and every
+    # log-decay at the bound -5, each against the scan
+    from paddle_tpu.ops import delta_rule as dr
+    from paddle_tpu.ops.pallas import delta_state as ds
+    R, H, d, T = 16, 32, 128, 256
+    f = lambda *s: jnp.asarray(rs.standard_normal(s), jnp.float32)
+    q = dr.l2_normalize(f(R, T, H, d)) * d ** -0.5
+    k = dr.l2_normalize(f(R, T, H, d))
+    v = f(R, T, H, d)
+    beta = jnp.asarray(rs.uniform(0.01, 0.99, (R, T, H)), jnp.float32)
+    g = -5.0 * jax.nn.sigmoid(3.0 * f(R, T, H, d))
+    S0 = f(R, H, d, d)
+    scan = jax.jit(jax.vmap(dr.gated_delta_scan))
+    chunk = jax.jit(jax.vmap(lambda *a: dr.gated_delta_chunk(*a)))
+    errs = []
+    for gates in (g[:2], jnp.full_like(g[:2], -5.0)):
+        o_ref, S_ref = scan(q[:2], k[:2], v[:2], gates, beta[:2], S0[:2])
+        o, S = chunk(q[:2], k[:2], v[:2], gates, beta[:2], S0[:2])
+        assert bool(jnp.all(jnp.isfinite(o)) & jnp.all(jnp.isfinite(S)))
+        errs += [float(jnp.max(jnp.abs(o - o_ref)))
+                 / float(jnp.max(jnp.abs(o_ref))),
+                 float(jnp.max(jnp.abs(S[:, 0] - S_ref)))
+                 / float(jnp.max(jnp.abs(S_ref)))]
+    assert max(errs) < 1e-4, errs
+    live = jnp.arange(R) %% 5 != 3
+    o1_ref, S1_ref = scan(q[:, :1], k[:, :1], v[:, :1], g[:, :1],
+                          beta[:, :1], S0)
+    want = jnp.where(live[:, None, None, None], S1_ref, S0)
+    assert dr.state_lane_heads(H, d) == 1 and ds.use_state_kernel(S0)
+    step = jax.jit(lambda *a: dr.delta_state_step(*a),
+                   donate_argnums=(0,)).lower(
+        S0, q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]), beta[:, 0],
+        live).compile()
+    if dev.platform == "tpu":           # the interpreter is no call
+        assert "tpu_custom_call" in step.as_text()
+    S1, o1 = step(S0 + 0.0, q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]),
+                  beta[:, 0], live)
+    dead = ~np.asarray(live)
+    assert np.array_equal(np.asarray(S1)[dead], np.asarray(S0)[dead])
+    e1 = float(jnp.max(jnp.abs(S1 - want))) / float(jnp.max(jnp.abs(want)))
+    e2 = float(jnp.max(jnp.abs(o1 - o1_ref[:, 0]))) \
+        / float(jnp.max(jnp.abs(o1_ref)))
+    assert e1 < 1e-5 and e2 < 1e-4, (e1, e2)
+    print("kda_channel_state: chunk err %%s, step err %%.1e / %%.1e"
+          %% (["%%.1e" %% e for e in errs], e1, e2), flush=True)
+check("kda_channel_state", kda_channel_state)
+
+def ling_programs():
+    # ISSUE 41: the family's tick and chunk programs (slot state beside
+    # a latent pool, the group-limited router's expert share) through
+    # the real compiler at tiny widths: prompts of one to four chunks
+    # and decode through PagedEngine against the no-cache forward
+    import paddle_tpu as pt
+    from paddle_tpu.generation.paged import PagedEngine
+    from paddle_tpu.models.ling_hybrid import (LingHybridForCausalLM,
+                                               ling_hybrid_tiny)
+    pt.seed(0)
+    model = LingHybridForCausalLM(ling_hybrid_tiny(
+        num_hidden_layers=6, experts_held=4))
+    fn, params = model.functional()
+    prompts = [rs.randint(1, 256, n).tolist() for n in (5, 37, 16, 61)]
+    worst = 0.0
+    # float32 weights: the chip multiplies them in one bfloat16 pass
+    # unless asked, and a router's choice then flips between the two
+    # sides; both are traced at the highest precision here
+    with jax.default_matmul_precision("highest"):
+        eng = PagedEngine(model, max_slots=4, num_blocks=64, block_size=8,
+                          max_blocks_per_seq=16, chunk_prefill_tokens=16)
+        for i, p in enumerate(prompts):
+            eng.submit(i, p, max_new_tokens=8)
+        res = eng.run()
+        for i, p in enumerate(prompts):
+            lp = jax.nn.log_softmax(
+                fn(params, jnp.asarray([p + res[i]]))[0], -1)
+            want = [float(lp[len(p) - 1 + j, t])
+                    for j, t in enumerate(res[i])]
+            worst = max(worst, float(np.abs(
+                np.asarray(want) - np.asarray(eng.logprobs[i])).max()))
+    assert worst < 1e-3, worst
+    st = eng.stats
+    assert st["state_layer_ticks"] == 4 * st["decode_steps"] > 0
+    if dev.platform == "tpu":
+        assert st["state_kernel_ticks"] == st["state_layer_ticks"]
+    assert st["moe_rows_routed_here"] > 0
+    print("ling_programs: logprob err %%.1e" %% worst, flush=True)
+check("ling_programs", ling_programs)
 
 print("KERNELS_JSON " + json.dumps(results), flush=True)
 """
