@@ -214,8 +214,12 @@ class StateLayer(NamedTuple):
     ``[max_slots, *shape]`` beside the pools and hands the layer a
     ``SlotState``. Such state does not grow with the context, is
     addressed by no block table, starts from zero with every request
-    and cannot be adopted, spilled, shipped or rolled back by block."""
+    and cannot be adopted, spilled, shipped or rolled back by block.
+    ``rule``: the shapes ONE position gives its prompt chunk's delta
+    rule, (q, v, the log-decay), all float32: what decides the path the
+    rule takes (``chunk_rule_route``); () where the layer does not say."""
     arrays: tuple
+    rule: tuple = ()
     rows = ()           # no pool array of cached tokens
     window = None       # and no band of them
 
@@ -258,6 +262,12 @@ class SlotState(NamedTuple):
 # tick)
 _STATE_COUNTERS = ("state_layer_ticks", "state_kernel_ticks",
                    "state_rows_updated", "state_resets", "state_carries")
+
+
+# and what it adds up on the host as it dispatches a prompt call: state
+# layers x prompt calls, and those of them whose chunk rule took the
+# kernel (``chunk_rule_route``)
+_CHUNK_RULE_COUNTERS = ("chunk_rule_layer_calls", "chunk_rule_kernel_calls")
 
 
 # what a band-keeping engine adds up inside a tick, over the live rows
@@ -520,6 +530,18 @@ def state_step_route(S) -> str:
     the traced program made."""
     from ..ops.pallas.delta_state import use_state_kernel
     return "kernel" if use_state_kernel(S) else "fusions"
+
+
+def chunk_rule_route(q, v, g) -> str:
+    """Which path a linear-attention layer's prompt chunk
+    (``ops.delta_rule.gated_delta_chunk``) takes over q [T, H, dk], v
+    [T, H, dv] and a log-decay ``g``: ``"kernel"`` (the Pallas kernel
+    that keeps a chunk's intermediates in VMEM; a decay a key channel at
+    whole lane tiles) or ``"fusions"`` (the jnp bodies). ``state_step_
+    route``'s sibling: it asks what ``gated_delta_chunk`` asks
+    (``chunk_rule_kernel``; shapes, dtype and the platform decide)."""
+    from ..ops.delta_rule import chunk_rule_kernel
+    return "kernel" if chunk_rule_kernel(q, v, g) else "fusions"
 
 
 def _row_positions(pk: PagedKV, T: int, Tk: int):
@@ -1029,6 +1051,13 @@ class PagedEngine:
         # most models, and everything that reads this is then as it was)
         self._n_state = sum(isinstance(l, StateLayer)
                             for l in self._layout)
+        # those of them whose prompt chunks take the chunk-rule kernel
+        # (``chunk_rule_route`` of the shapes the layer gives)
+        self._n_chunk_kernel = sum(
+            chunk_rule_route(*(jax.ShapeDtypeStruct(
+                (self.chunk or 1,) + shape, jnp.float32)
+                for shape in l.rule)) == "kernel"
+            for l in self._layout if isinstance(l, StateLayer) and l.rule)
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -1142,7 +1171,8 @@ class PagedEngine:
                       "spill_spans", "spill_restores",
                       "spill_restored_tokens",
                       "spill_restore_failures")
-            + self._tick_counter_names}
+            + self._tick_counter_names
+            + (_CHUNK_RULE_COUNTERS if self._n_state else ())}
         # paged_decode_step_ms is what the host can see of one decode
         # dispatch: on the host reference path, which reads back in the
         # tick, the program's whole run, call to tokens on the host; the
@@ -1539,6 +1569,12 @@ class PagedEngine:
 
     def _count(self, key: str, n: int = 1):
         self._counters[key].inc(n)
+
+    def _count_chunk_rule(self):
+        """``_CHUNK_RULE_COUNTERS`` of one prompt call's dispatch."""
+        if self._n_state:
+            self._count("chunk_rule_layer_calls", self._n_state)
+            self._count("chunk_rule_kernel_calls", self._n_chunk_kernel)
 
     # ------------------------------------------------------------ jitted
     def _paged_caches(self, pools, tables, lens, slots=None, live=None,
@@ -2945,6 +2981,7 @@ class PagedEngine:
             np.float32(req.rep), np.int32(slot_id), bucket=bucket)
         self.seen = self.seen.at[slot_id].set(seen_row)
         self._count("prefills")
+        self._count_chunk_rule()
         first = int(nxt)
         self.keys[slot_id] = np.asarray(new_key)
         self._key_overrides.add(slot_id)
@@ -3031,6 +3068,7 @@ class PagedEngine:
                     self.params, self.pools, self.seen, call)
             self._count("prefill_chunks")
             self._count("prefill_segments", len(slot_ids))
+            self._count_chunk_rule()
             # the segments whose prompt ends in this call: their first
             # token comes back with it
             done = [live == len(self.slots[i].prompt)
@@ -3085,6 +3123,7 @@ class PagedEngine:
                     bucket=self.chunk)
             self._count("prefill_chunks")
             self._count("prefill_segments")
+            self._count_chunk_rule()
             # mid chunks keep the ids-only mask; the final chunk's
             # committed sample rides in seen_fin (mirrors the PRNG-key
             # protocol)

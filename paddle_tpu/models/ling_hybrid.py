@@ -196,6 +196,10 @@ class KimiDeltaAttention(GatedDeltaNet):
                 jnp.exp(self.A_log.astype(f32))[:, None] * f)
         return u, (g, bb), gate
 
+    def chunk_rule(self):
+        h, _, d, _, _ = self.geometry
+        return ((h, d), (h, d), (h, d))     # a decay a key channel
+
     def _gates(self, g, b):
         """(log-decay [..., H, dk], beta [..., H]) float32."""
         return g, jax.nn.sigmoid(b.astype(jnp.float32))
@@ -326,7 +330,8 @@ class LingHybridForCausalLM(CausalLMBase):
         from ..generation.paged import CacheLayer, StateLayer
         latent = CacheLayer(((1, self.config.latent_row_width),))
         return [latent if layer.is_latent
-                else StateLayer(layer.linear_attn.state_arrays())
+                else StateLayer(layer.linear_attn.state_arrays(),
+                                layer.linear_attn.chunk_rule())
                 for layer in self.model.layers]
 
     def tick_counters(self):

@@ -237,6 +237,12 @@ class GatedDeltaNet(Layer):
         return (((hv // hp, dk, hp * dv), jnp.float32),
                 ((taps - 1, 2 * hk * dk + hv * dv), self.config.dtype))
 
+    def chunk_rule(self):
+        """The shapes one position gives the prompt chunk's delta rule
+        (``StateLayer.rule``): q, v and the log-decay, here a head's."""
+        _, hv, dk, dv, _ = self.geometry
+        return ((hv, dk), (hv, dv), (hv,))
+
     def _heads(self, y):
         """The convolution's activated output [..., C] apart: q, k
         [..., Hv, dk] normalised (q scaled), v [..., Hv, dv]; float32."""
@@ -459,7 +465,8 @@ class OlmoHybridForCausalLM(CausalLMBase):
         from ..generation.paged import CacheLayer, StateLayer
         cfg = self.config
         kv = (cfg.num_key_value_heads, cfg.head_dim)
-        return [StateLayer(layer.linear_attn.state_arrays())
+        return [StateLayer(layer.linear_attn.state_arrays(),
+                           layer.linear_attn.chunk_rule())
                 if layer.is_linear else CacheLayer((kv, kv))
                 for layer in self.model.layers]
 

@@ -22,7 +22,10 @@ one a KEY CHANNEL (``g`` [T, H, dk]): row ``c`` of ``S`` times
   state moves once a sub-chunk. Another computation of the same
   numbers. It serves a PACKED call too: positions carry a segment
   index, neither state nor decay crosses a segment boundary, and every
-  segment's state at its last position comes back.
+  segment's state at its last position comes back. At a decay a key
+  channel over whole lane tiles it is ONE Pallas kernel a call
+  (``ops/pallas/delta_chunk.py``, ``chunk_rule_kernel``); the jnp form
+  below stays the definition that kernel is held to.
 - ``delta_state_step``: one decode position over every row's state, in
   the lane-dense form the engine stores it in.
 
@@ -50,7 +53,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["state_lane_heads", "pack_state", "unpack_state",
-           "gated_delta_scan", "gated_delta_chunk", "delta_state_step",
+           "gated_delta_scan", "gated_delta_chunk", "chunk_rule_kernel",
+           "delta_state_step",
            "conv_chunk", "conv_step", "l2_normalize"]
 
 _HI = jax.lax.Precision.HIGHEST
@@ -218,8 +222,17 @@ def _sub_chunks(q, k, v, g, beta, seg, c: int):
     return q, k, v, g, hm(beta[..., None])[..., 0], seg.reshape(N, c)
 
 
-@functools.partial(jax.jit, inline=True,
-                   static_argnames=("segments", "sub"))
+def chunk_rule_kernel(q, v, g, sub: int = SUB_CHUNK) -> bool:
+    """Whether ``gated_delta_chunk`` hands a chunk of these operands to
+    the Pallas kernel (``ops/pallas/delta_chunk.py``): a decay a key
+    channel that the kernel's own gate takes. A decay a head never asks:
+    its path is the fusions below."""
+    if len(g.shape) != 3:
+        return False
+    from .pallas.delta_chunk import use_chunk_kernel
+    return use_chunk_kernel(q, v, g, sub)
+
+
 def gated_delta_chunk(q, k, v, g, beta, S0, seg=None, segments: int = 1,
                       sub: int = SUB_CHUNK):
     """A chunk of T positions at once. q, k [T, H, dk]; v [T, H, dv];
@@ -231,7 +244,28 @@ def gated_delta_chunk(q, k, v, g, beta, S0, seg=None, segments: int = 1,
     predecessor's starts from ZERO state. Returns (o [T, H, dv],
     S [segments, H, dk, dv]: each segment's state at its last position).
     A padded position (beta 0, g 0) belongs to the segment before it and
-    leaves that segment's state as it was."""
+    leaves that segment's state as it was.
+
+    A decay a key channel at whole lane tiles runs as one Pallas kernel
+    (``chunk_rule_kernel``), which skips a sub-chunk of padded positions
+    alone and writes zeros for their outputs; everything else as the
+    fusions of ``_chunk_fusions``, the definition the kernel is held
+    to."""
+    if chunk_rule_kernel(q, v, g, sub):
+        from .pallas.delta_chunk import gated_delta_chunk_pallas
+        if seg is None:
+            seg = jnp.zeros((q.shape[0],), jnp.int32)
+        return gated_delta_chunk_pallas(q, k, v, g, beta, S0, seg,
+                                        segments, sub)
+    return _chunk_fusions(q, k, v, g, beta, S0, seg, segments=segments,
+                          sub=sub)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("segments", "sub"))
+def _chunk_fusions(q, k, v, g, beta, S0, seg=None, segments: int = 1,
+                   sub: int = SUB_CHUNK):
+    """``gated_delta_chunk`` in jax.numpy."""
     T, H, dk = q.shape
     dv = v.shape[-1]
     if seg is None:
@@ -361,20 +395,39 @@ def _chunk_channel(q, k, v, g, beta, S0, seg, segments: int, T: int):
     if segments == 1:
         return o, S_end[None]
     # a segment that ends inside a sub-chunk (``gated_delta_chunk``)
+    return o, _segment_states(
+        seg, segments, lambda n: (k[n], G[n], vn[n], S_in[n]))
+
+
+def _segment_states(seg, segments: int, at):
+    """Each segment's state at its own last position, a decay a key
+    channel: the sum ``gated_delta_chunk`` ends on, from the state that
+    entered the sub-chunk of that position. seg [N, c]; ``at(n)``: (k,
+    the cumulated log-decay G [H, c, dk], vn [H, c, dv], the entering
+    state [H, dk, dv]) of sub-chunk ``n``. One segment a turn of a loop
+    over the segments that HOLD a position (indices do not decrease, so
+    those up to the last position's); the others' states are zeros. A
+    packed call has room for as many segments as slots may start in one
+    call and mostly carries one: the sums over every segment at once
+    moved that many states' worth of operands for nothing."""
+    N, c = seg.shape
     flat = seg.reshape(-1)
-    sid = jnp.arange(segments)
-    e = jnp.max(jnp.where(flat[None, :] == sid[:, None],
-                          jnp.arange(N * c)[None, :], 0), -1)   # [S]
-    n_e, i_e = e // c, e % c
-    G_e = jnp.take_along_axis(G[n_e], i_e[:, None, None, None], -2)
-    mine = (seg[n_e] == sid[:, None]) & (jnp.arange(c)[None, :]
-                                         <= i_e[:, None])       # [S, c]
-    w = jnp.exp(jnp.where(mine[:, None, :, None], G_e - G[n_e], -jnp.inf))
-    from_in = jnp.where(prev[n_e] == sid, 1.0, 0.0)[:, None, None] \
-        * jnp.exp(G_e[..., 0, :])                               # [S, H, dk]
-    S_seg = from_in[..., None] * S_in[n_e] + jnp.einsum(
-        "shjk,shjv->shkv", k[n_e] * w, vn[n_e], precision=_HI)
-    return o, S_seg
+    prev = jnp.concatenate([seg[:1, 0], seg[:-1, -1]])      # [N]
+
+    def one(s, out):
+        e = jnp.max(jnp.where(flat == s, jnp.arange(N * c), 0))
+        n_e, i_e = e // c, e % c
+        k, G, vn, S_in = at(n_e)
+        G_e = jax.lax.dynamic_slice_in_dim(G, i_e, 1, 1)    # [H, 1, dk]
+        mine = (seg[n_e] == s) & (jnp.arange(c) <= i_e)     # [c]
+        w = jnp.exp(jnp.where(mine[None, :, None], G_e - G, -jnp.inf))
+        from_in = jnp.where(prev[n_e] == s, 1.0, 0.0) * jnp.exp(G_e[:, 0])
+        S = from_in[..., None] * S_in + jnp.einsum(
+            "hjk,hjv->hkv", k * w, vn, precision=_HI)
+        return out.at[s].set(S)
+    S_in = at(0)[3]
+    return jax.lax.fori_loop(
+        0, flat[-1] + 1, one, jnp.zeros((segments,) + S_in.shape, S_in.dtype))
 
 
 # ---------------------------------------------------- the short convolution

@@ -292,6 +292,35 @@ def test_the_state_step_compiles_lane_dense_for_a_v5e(one_chip,
                           entry)
 
 
+@pytest.mark.parametrize("T,segments", [(256, 1), (256, 16), (200, 3)])
+def test_the_chunk_rule_kernel_compiles_for_a_v5e(one_chip,
+                                                  no_persistent_cache,
+                                                  monkeypatch, T, segments):
+    """ISSUE 44: a prompt chunk's delta rule at a decay a key channel at
+    Ling's shapes (32 heads of 128 x 128, a call of 256 positions): one
+    custom call, for the one segment of a chunk with context behind it
+    and for the sixteen a packed call has room for (the kernel then also
+    returns the sub-chunks' entering states), and no temporary of the
+    fusions' sizes (``kh`` alone was 64 MB a layer)."""
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.ops import delta_rule
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+    H, d = 32, 128
+
+    def arr(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q, g = arr((T, H, d)), arr((T, H, d))
+    assert delta_rule.chunk_rule_kernel(q, q, g)
+    compiled = jax.jit(lambda *a: delta_rule.gated_delta_chunk(
+        *a, segments=segments)).lower(
+        q, q, q, g, arr((T, H)), arr((H, d, d)),
+        arr((T,), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 48 << 20
+
+
 @pytest.mark.parametrize("R,M,P", [(32, 128, 3873)])
 def test_a_query_group_of_one_compiles_for_a_v5e(one_chip,
                                                  no_persistent_cache,
@@ -326,7 +355,11 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
     dense FFNs, a gated latent layer over the 32 held experts of a
     512-wide router): slot state beside a latent pool in one program. A
     tick calls the channel-decay state kernel twice, the ragged kernel's
-    latent mode and the expert kernel once each; a chunk calls none."""
+    latent mode and the expert kernel once each; a chunk calls the
+    chunk-rule kernel twice (ISSUE 44), on operands that reach it as
+    they lie: no copy or transpose of a ``[256, 32, 128]`` operand in
+    front of it or behind it."""
+    import re
     import json
     import os
     import paddle_tpu.ops.pallas as pallas
@@ -366,6 +399,12 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
                                   paged_chunk=program == "chunk")).lower(
         params, arr((rows, T), jnp.int32), caches,
         arr((rows, T), jnp.int32)).compile()
-    calls = compiled.as_text().count('custom_call_target="tpu_custom_call"')
-    assert calls == (4 if program == "tick" else 0)
+    text = compiled.as_text()
+    calls = text.count('custom_call_target="tpu_custom_call"')
+    assert calls == (4 if program == "tick" else 2)
+    if program == "chunk":
+        entry = text[text.index("ENTRY"):]
+        assert not re.findall(
+            r"f32\[(?:256,32,128|8192,128|256,4096)\]\S* (?:copy|transpose)\(",
+            entry)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

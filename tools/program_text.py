@@ -19,7 +19,9 @@ import os
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-ROOT = os.path.abspath(sys.argv[1]) if len(sys.argv) > 1 else \
+# (imported, as tests/test_delta_rule_chunk_kernel.py does: this checkout)
+ROOT = os.path.abspath(sys.argv[1]) \
+    if __name__ == "__main__" and len(sys.argv) > 1 else \
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 

@@ -10,7 +10,8 @@ on whatever backend jax selects, each against its XLA reference: the
 flash kernel's segment / window / backward variants, ``quant_matmul``,
 the decode re-block, the ragged paged-attention kernel (single- and
 multi-query, K/V and latent pools), the delta rule's state step and
-chunk form at a decay a head and a decay a channel, and the tick / patch
+chunk form at a decay a head and a decay a channel (the channel's chunk
+kernel beside its fusions), and the tick / patch
 / restore programs of the serving engine. ``chip_smoke.py`` covers the default serving route
 end to end; this covers the kernels and programs off that route.
 
@@ -649,6 +650,72 @@ def kda_channel_state():
           %% (["%%.1e" %% e for e in errs], e1, e2), flush=True)
 check("kda_channel_state", kda_channel_state)
 
+def delta_rule_chunk():
+    # ISSUE 44: a prompt chunk's delta rule at a decay a key channel as
+    # ONE Pallas kernel (ops/pallas/delta_chunk.py) at Ling-3.0-flash's
+    # widths (32 heads of 128 x 128, 256 positions): the kernel and the
+    # fusions it replaces (``_chunk_channel``, the gate held shut), each
+    # against the scan: random gates, every log-decay at the bound -5, a
+    # padded tail of one whole dead sub-chunk (which the kernel skips)
+    # and a partial one, three packed segments
+    from paddle_tpu.ops import delta_rule as dr
+    from paddle_tpu.ops.pallas import delta_chunk as dc
+    H, d, T = 32, 128, 256
+    f = lambda *s: jnp.asarray(rs.standard_normal(s), jnp.float32)
+    q = dr.l2_normalize(f(T, H, d)) * d ** -0.5
+    k = dr.l2_normalize(f(T, H, d))
+    v, S0 = f(T, H, d), f(H, d, d)
+    beta = jnp.asarray(rs.uniform(0.01, 0.99, (T, H)), jnp.float32)
+    g = -5.0 * jax.nn.sigmoid(3.0 * f(T, H, d))
+    assert dc.use_chunk_kernel(q, v, g, dr.SUB_CHUNK)
+    gate = dc.use_chunk_kernel
+    scan = jax.jit(dr.gated_delta_scan)
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b))) \
+        / float(jnp.max(jnp.abs(b)))
+    real = jnp.arange(T) < 150          # sub-chunk 3 dead, 2 partly
+    pad = lambda x: jnp.where(real.reshape((T,) + (1,) * (x.ndim - 1)),
+                              x, 0.0)
+    cases = {"random": (g, beta), "bound": (jnp.full_like(g, -5.0), beta),
+             "padded": (pad(g), pad(beta))}
+    errs = {}
+    try:
+        for route, flag in (("kernel", True), ("fusions", False)):
+            dc.use_chunk_kernel = lambda *a, flag=flag: flag
+            chunk = jax.jit(lambda *a: dr.gated_delta_chunk(*a)) \
+                .lower(q, k, v, g, beta, S0).compile()
+            if dev.platform == "tpu":   # the interpreter is no call
+                assert ("tpu_custom_call" in chunk.as_text()) == flag
+            for name, (gg, bb) in cases.items():
+                o_ref, S_ref = scan(q, k, v, gg, bb, S0)
+                o, S = chunk(q, k, v, gg, bb, S0)
+                assert bool(jnp.all(jnp.isfinite(o)) & jnp.all(jnp.isfinite(S)))
+                n = 150 if name == "padded" else T
+                errs[route, name] = (rel(o[:n], o_ref[:n]),
+                                     rel(S[0], S_ref))
+            # three segments of 100 / 30 / 90 positions and padding
+            lens = (100, 30, 90)
+            seg = jnp.asarray(np.repeat([0, 1, 2, 2], lens + (T - 220,)))
+            here = jnp.arange(T) < 220
+            gs, bs = (jnp.where(here[:, None, None], g, 0.0),
+                      jnp.where(here[:, None], beta, 0.0))
+            o, S = jax.jit(lambda *a: dr.gated_delta_chunk(
+                *a, segments=3))(q, k, v, gs, bs, S0, seg)
+            worst, at = 0.0, 0
+            for i, n in enumerate(lens):
+                sl = slice(at, at + n)
+                o_ref, S_ref = scan(q[sl], k[sl], v[sl], g[sl], beta[sl],
+                                    S0 if i == 0 else jnp.zeros_like(S0))
+                worst = max(worst, rel(o[sl], o_ref), rel(S[i], S_ref))
+                at += n
+            errs[route, "packed"] = (worst, worst)
+    finally:
+        dc.use_chunk_kernel = gate
+    assert max(max(e) for e in errs.values()) < 1e-4, errs
+    print("delta_rule_chunk: " + "; ".join(
+        "%%s %%s o %%.1e S %%.1e" %% (r, n, eo, eS)
+        for (r, n), (eo, eS) in errs.items()), flush=True)
+check("delta_rule_chunk", delta_rule_chunk)
+
 def ling_programs():
     # ISSUE 41: the family's tick and chunk programs (slot state beside
     # a latent pool, the group-limited router's expert share) through
@@ -686,6 +753,7 @@ def ling_programs():
     if dev.platform == "tpu":
         assert st["state_kernel_ticks"] == st["state_layer_ticks"]
     assert st["moe_rows_routed_here"] > 0
+    assert st["chunk_rule_layer_calls"] == 4 * st["prefill_chunks"] > 0
     print("ling_programs: logprob err %%.1e" %% worst, flush=True)
 check("ling_programs", ling_programs)
 
