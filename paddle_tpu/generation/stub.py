@@ -3,18 +3,17 @@ machinery (ISSUE 9): embed -> paged KV write -> paged attention ->
 vocab projection, one layer, one head. Engine/gateway benchmarks and
 tests that drive it measure scheduling, dispatch and transport — not
 model FLOPs. Shared by ``tools/serve_loadgen.py --model stub`` and
-``tests/test_gateway.py`` so the paged-cache calling convention lives
-in ONE place (the multi-chunk global-positions contract below was
-once fixed in two copies at once; see CHANGES PR 7).
+``tests/test_gateway.py``. It is the whole of what an engine asks of a
+model: a ``config`` its pools are sized from, and a forward that hands
+each layer's view to ``ops.paged_cache.write_and_attend`` (or reads
+``view.call`` in a mixer of its own) and returns the views it got back.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .paged import (paged_chunk_attention, paged_decode_attention,
-                    paged_decode_write, paged_packed_attention,
-                    paged_prefill_write)
+from ..ops.paged_cache import write_and_attend
 
 __all__ = ["TickStubConfig", "TickStubModel"]
 
@@ -42,32 +41,12 @@ class TickStubModel:
                       out=jax.random.normal(k, (d, V)))
 
         def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False, paged_decode=False, segment_ids=None):
+               segment_ids=None):
             x = params["emb"][tokens]              # [R, s, d]
             kv = x[:, :, None, :]                  # [R, s, 1, d]
-            pk = kv_caches[0]
-            if paged_decode or tokens.shape[1] == 1:
-                # decode tick — including the speculative multi-query
-                # verify (paged_decode=True, [R, k+1]): the paged
-                # write/attention helpers handle T >= 1 natively
-                pk = paged_decode_write(pk, kv, kv)
-                o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
-            elif segment_ids is not None:
-                # a packed call: several prompts from position 0, each
-                # token into its own prompt's row, attention over the
-                # call's own rows
-                pk = paged_prefill_write(pk, kv, kv,
-                                         positions=positions[0],
-                                         segments=segment_ids[0])
-                o = paged_packed_attention(kv, kv, kv,
-                                           segment_ids)[:, :, 0]
-            else:                                  # (chunk) prefill
-                # chunk K/V lands at its GLOBAL positions — a chunk at
-                # start > 0 written at 0..s-1 reads stale data later
-                pk = paged_prefill_write(pk, kv, kv,
-                                         positions=positions[0])
-                o = paged_chunk_attention(x[:1, :, None, :], pk,
-                                          positions)[:, :, 0]
+            o, pk = write_and_attend(kv_caches[0], kv, kv, kv, positions,
+                                     segment_ids)
+            o = o[:, :, 0]
             return o @ params["out"], [pk]
 
         return fn, params

@@ -33,6 +33,10 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..nn.layer import Layer
+from ..ops.attention import dense_attention, segment_mask
+from ..ops.paged_cache import (PagedKV, paged_chunk_rows,
+                               paged_decode_write, paged_latent_attention,
+                               paged_prefill_write)
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding)
 from ..parallel.moe import (SERVING_COUNTERS, ExpertShareMLP, MoEMLP,
@@ -252,7 +256,6 @@ class MLAttention(Layer):
         """Attention in the EXPANDED form: per-head keys and values made
         from the latents c [b, t, r] and the shared roped key k_pe
         [b, t, rope]."""
-        from ..ops.attention import dense_attention
         k_nope, v = self._expand(c)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
@@ -261,14 +264,10 @@ class MLAttention(Layer):
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         return dense_attention(q, k, v, scale=self.scale, **mask)
 
-    def _paged(self, pk, q_nope, q_pe, c, k_pe, positions, paged_chunk,
-               paged_decode, segment_ids=None):
-        """Serving over the latent paged pool (generation/paged.py)."""
-        from ..ops.attention import segment_mask
-        from ..generation.paged import (paged_chunk_rows,
-                                        paged_decode_write,
-                                        paged_latent_attention,
-                                        paged_prefill_write)
+    def _paged(self, pk, q_nope, q_pe, c, k_pe, positions,
+               segment_ids=None):
+        """Serving over the latent paged pool (ops/paged_cache.py), in
+        whichever of the engine's calls the view says this is."""
         cfg = self.config
         r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         pad = cfg.latent_row_width - r - rope
@@ -280,12 +279,12 @@ class MLAttention(Layer):
             return jnp.concatenate(parts, axis=-1)
 
         new = row(c, k_pe)[:, :, None, :]                   # [b, s, 1, W]
-        if q_nope.shape[1] == 1 or paged_decode:
+        if pk.call == "decode":
             pk = paged_decode_write(pk, new)
             out = self._absorbed(
                 q_nope, lambda q_lat: paged_latent_attention(
                     row(q_lat, q_pe), pk, r, self.scale))
-        elif segment_ids is not None:
+        elif pk.call == "packed":
             # a PACKED call: several prompts side by side, each from
             # its position 0, so the call's own latents are all a query
             # can see and no page is gathered or expanded
@@ -294,7 +293,7 @@ class MLAttention(Layer):
             with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
                 out = self._expanded(q_nope, q_pe, c, k_pe, causal=True,
                                      attn_mask=segment_mask(segment_ids))
-        elif paged_chunk:
+        elif pk.call == "chunk":
             pk = paged_prefill_write(pk, new, positions=positions[0])
             with jax.named_scope("chunk_attn"):     # obs.TICK_SCOPES
                 rows = paged_chunk_rows(pk)[:, :, 0]        # [1, T, W]
@@ -309,8 +308,7 @@ class MLAttention(Layer):
         return out, pk
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, attn_start=None, segment_ids=None):
         cfg = self.config
         b, s, _ = x.shape
         h = cfg.num_attention_heads
@@ -319,12 +317,9 @@ class MLAttention(Layer):
             c, k_pe = self._latents(x, positions)
 
         new_cache = None
-        if kv_cache is not None:
-            from ..generation.paged import PagedKV
-        if kv_cache is not None and isinstance(kv_cache, PagedKV):
+        if isinstance(kv_cache, PagedKV):
             out, new_cache = self._paged(kv_cache, q_nope, q_pe, c, k_pe,
-                                         positions, paged_chunk,
-                                         paged_decode, segment_ids)
+                                         positions, segment_ids)
         elif kv_cache is not None:
             cc, cpe = kv_cache  # [b, T, r], [b, T, rope_d]
             cc = jax.lax.dynamic_update_slice(cc, c.astype(cc.dtype),
@@ -401,17 +396,14 @@ class DeepseekV2DecoderLayer(Layer):
                 aux_loss_weight=config.aux_loss_weight, **moe)
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, attn_start=None, segment_ids=None):
         # the named scopes are obs.TICK_SCOPES, as in llama.py
         with jax.named_scope("norm"):
             h = self.input_layernorm(x)
         attn = self.self_attn(h, positions,
                               kv_cache=kv_cache, cache_index=cache_index,
                               attn_mask=attn_mask, attn_start=attn_start,
-                              segment_ids=segment_ids,
-                              paged_chunk=paged_chunk,
-                              paged_decode=paged_decode)
+                              segment_ids=segment_ids)
         new_cache = None
         if kv_cache is not None:
             attn, new_cache = attn
@@ -479,8 +471,7 @@ class DeepseekV2Model(Layer):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                return_prenorm: bool = False, paged_chunk: bool = False,
-                paged_decode: bool = False, segment_ids=None):
+                return_prenorm: bool = False, segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             start = cache_index if cache_index is not None else 0
@@ -497,9 +488,7 @@ class DeepseekV2Model(Layer):
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
                               cache_index=cache_index, attn_mask=attn_mask,
                               attn_start=attn_start,
-                              segment_ids=segment_ids,
-                              paged_chunk=paged_chunk,
-                              paged_decode=paged_decode)
+                              segment_ids=segment_ids)
                 new_caches.append(nc)
             else:
                 x = layer(x, positions, attn_mask=attn_mask)
@@ -567,7 +556,6 @@ class DeepseekV2ForCausalLM(CausalLMBase):
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
                 return_mtp: bool = False, return_prenorm: bool = False,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 segment_ids=None):
         """``return_mtp`` (training-time, no cache): additionally return
         the list of MTP depth logits — depth k's logits[:, i] predict
@@ -615,7 +603,6 @@ class DeepseekV2ForCausalLM(CausalLMBase):
         out = self.model(input_ids, positions, kv_caches, cache_index,
                          attn_mask, attn_start=attn_start,
                          return_prenorm=return_prenorm,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode,
                          segment_ids=segment_ids)
         caches = None
         pre = None

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.layer import Layer, Parameter
 from ..ops import delta_rule
+from ..ops.paged_cache import CacheLayer, StateLayer
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding)
 from ..parallel.moe import (ROUTED_COUNTERS, SERVING_COUNTERS,
@@ -245,11 +246,13 @@ class LingHybridDecoderLayer(Layer):
     def mixer(self):
         return self.self_attn if self.is_latent else self.linear_attn
 
-    def forward(self, x, positions, kv_cache=None, **kw):
+    def forward(self, x, positions, kv_cache=None, segment_ids=None,
+                attn_mask=None):
         # the named scopes are obs.TICK_SCOPES, as in deepseek_v2.py
         with jax.named_scope("norm"):
             h = self.input_layernorm(x)
-        attn = self.mixer(h, positions, kv_cache=kv_cache, **kw)
+        attn = self.mixer(h, positions, kv_cache=kv_cache,
+                          segment_ids=segment_ids, attn_mask=attn_mask)
         new_cache = None
         if kv_cache is not None:
             attn, new_cache = attn
@@ -281,8 +284,7 @@ class LingHybridModel(Layer):
             self.norm.to(dtype=config.dtype)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             positions = jnp.arange(s)[None, :].repeat(b, axis=0)
@@ -293,9 +295,7 @@ class LingHybridModel(Layer):
         for i, layer in enumerate(self.layers):
             if kv_caches is not None:
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
-                              segment_ids=segment_ids,
-                              paged_chunk=paged_chunk,
-                              paged_decode=paged_decode)
+                              segment_ids=segment_ids)
                 new_caches.append(nc)
             else:
                 x = layer(x, positions, attn_mask=attn_mask)
@@ -327,7 +327,6 @@ class LingHybridForCausalLM(CausalLMBase):
         """What ``PagedEngine`` keeps for EACH layer: a latent layer's ONE
         latent row a token (``CacheLayer``), a Kimi-Delta-Attention
         layer's arrays a SLOT (``StateLayer``)."""
-        from ..generation.paged import CacheLayer, StateLayer
         latent = CacheLayer(((1, self.config.latent_row_width),))
         return [latent if layer.is_latent
                 else StateLayer(layer.linear_attn.state_arrays(),
@@ -344,11 +343,9 @@ class LingHybridForCausalLM(CausalLMBase):
         return collect_counts(rows)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         out = self.model(input_ids, positions, kv_caches,
-                         attn_mask=attn_mask, segment_ids=segment_ids,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode)
+                         attn_mask=attn_mask, segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:
             out, caches = out

@@ -29,6 +29,7 @@ from ..nn.layer import Layer, Parameter
 from ..nn.recompute import POLICIES
 from ..ops.attention import (decode_attention, dense_attention,
                              flash_attention, use_flash)
+from ..ops.paged_cache import PagedKV, write_and_attend
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding, parallel_matmul)
 from ..parallel.sharding import constraint
@@ -261,8 +262,7 @@ class LlamaAttention(Layer):
 
     def forward(self, x, positions, kv_cache: Optional[Tuple] = None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                segment_ids=None, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                segment_ids=None):
         cfg = self.config
         b, s, _ = x.shape
         nh, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -299,48 +299,12 @@ class LlamaAttention(Layer):
             v = constraint(v, None, None, "tp", None)
 
         new_cache = None
-        if kv_cache is not None:
-            from ..generation.paged import (PagedKV, paged_chunk_attention,
-                                            paged_decode_attention,
-                                            paged_decode_write,
-                                            paged_packed_attention,
-                                            paged_prefill_write)
-        if kv_cache is not None and isinstance(kv_cache, PagedKV):
-            # paged serving (generation/paged.py): block-table cache.
-            # s == 1 (or paged_decode=True at any s — the speculative
-            # verify's multi-query rows, ISSUE 7): scatter-write the
-            # tokens at each row's cursor, attend over the row's
-            # gathered blocks with per-position causal masking. Other
-            # s > 1: prefill — write the prompt's K/V into its blocks;
-            # whole-prompt prefill is plain causal attention over the
-            # prompt itself (pad tail lands in the garbage block and
-            # produces discarded rows), while a CHUNK (paged_chunk=
-            # True, positions carry the global offset) must also attend
-            # to the earlier chunks already in the row's blocks. A
-            # PACKED call (segment_ids: several prompts side by side,
-            # each from position 0) writes each token into its own
-            # prompt's row and attends over the call's own rows.
-            if s == 1 or paged_decode:
-                new_cache = paged_decode_write(kv_cache, k, v)
-                out = paged_decode_attention(q, new_cache,
-                                             window=self.window)
-            elif segment_ids is not None:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0],
-                                                segments=segment_ids[0])
-                out = paged_packed_attention(
-                    q, k.astype(kv_cache.kp.dtype),
-                    v.astype(kv_cache.vp.dtype), segment_ids,
-                    window=self.window)
-            elif paged_chunk:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0])
-                out = paged_chunk_attention(q, new_cache, positions,
-                                            window=self.window)
-            else:
-                new_cache = paged_prefill_write(kv_cache, k, v)
-                out = dense_attention(q, k, v, causal=True,
-                                      window=self.window)
+        if isinstance(kv_cache, PagedKV):
+            # paged serving: the view says which of the engine's calls
+            # this is (ops/paged_cache.py)
+            out, new_cache = write_and_attend(kv_cache, q, k, v, positions,
+                                              segment_ids,
+                                              window=self.window)
         elif kv_cache is not None:
             # static-shape decode: write current k/v at cache_index
             ck, cv = kv_cache
@@ -477,16 +441,13 @@ class LlamaDecoderLayer(Layer):
         self.mlp = LlamaMLP(config)
 
     def forward(self, x, positions, kv_cache=None, cache_index=None,
-                attn_mask=None, attn_start=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, attn_start=None, segment_ids=None):
         with jax.named_scope("norm"):
             h = self.input_layernorm(x)
         attn_out = self.self_attn(h, positions,
                                   kv_cache=kv_cache, cache_index=cache_index,
                                   attn_mask=attn_mask, attn_start=attn_start,
-                                  segment_ids=segment_ids,
-                                  paged_chunk=paged_chunk,
-                                  paged_decode=paged_decode)
+                                  segment_ids=segment_ids)
         new_cache = None
         if kv_cache is not None:
             attn_out, new_cache = attn_out
@@ -522,8 +483,7 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                segment_ids=None, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             start = cache_index if cache_index is not None else 0
@@ -547,9 +507,7 @@ class LlamaModel(Layer):
             else:
                 out = layer(x, positions, kv_cache=cache_i,
                             cache_index=cache_index, attn_mask=attn_mask,
-                            attn_start=attn_start, segment_ids=segment_ids,
-                            paged_chunk=paged_chunk,
-                            paged_decode=paged_decode)
+                            attn_start=attn_start, segment_ids=segment_ids)
             if kv_caches is not None:
                 x, nc = out
                 new_caches.append(nc)
@@ -584,12 +542,9 @@ class LlamaForCausalLM(CausalLMBase):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                segment_ids=None, paged_chunk: bool = False,
-                paged_decode: bool = False):
+                segment_ids=None):
         out = self.model(input_ids, positions, kv_caches, cache_index,
-                         attn_mask, attn_start, segment_ids=segment_ids,
-                         paged_chunk=paged_chunk,
-                         paged_decode=paged_decode)
+                         attn_mask, attn_start, segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:
             out, caches = out

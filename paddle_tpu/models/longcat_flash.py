@@ -167,7 +167,6 @@ class LongcatFlashModel(Layer):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
@@ -189,9 +188,7 @@ class LongcatFlashModel(Layer):
                                 kv_caches=kv_caches[2 * i:2 * i + 2],
                                 cache_index=cache_index,
                                 attn_mask=attn_mask, attn_start=attn_start,
-                                segment_ids=segment_ids,
-                                paged_chunk=paged_chunk,
-                                paged_decode=paged_decode)
+                                segment_ids=segment_ids)
                 new_caches += pair
         with jax.named_scope("head"):
             x = self.norm(x)
@@ -243,11 +240,9 @@ class LongcatFlashForCausalLM(CausalLMBase):
 
     def forward(self, input_ids, positions=None, kv_caches=None,
                 cache_index=None, attn_mask=None, attn_start=None,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 segment_ids=None):
         out = self.model(input_ids, positions, kv_caches, cache_index,
                          attn_mask, attn_start=attn_start,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode,
                          segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:

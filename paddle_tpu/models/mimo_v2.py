@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from .. import nn
 from ..nn.layer import Layer, Parameter
 from ..ops.attention import dense_attention
+from ..ops.paged_cache import CacheLayer, write_and_attend
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding)
 from ..parallel.moe import (SERVING_COUNTERS, ExpertShareMLP, MoEMLP,
@@ -175,7 +176,6 @@ class MiMoV2Attention(Layer):
             [apply_rotary(x[..., :rd], cos, sin), x[..., rd:]], axis=-1)
 
     def forward(self, x, positions, kv_cache=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 attn_mask=None):
         cfg = self.config
         b, s, _ = x.shape
@@ -192,36 +192,9 @@ class MiMoV2Attention(Layer):
         sink = getattr(self, "sink", None)
         new_cache = None
         if kv_cache is not None:
-            from ..generation.paged import (paged_chunk_attention,
-                                            paged_decode_attention,
-                                            paged_decode_write,
-                                            paged_packed_attention,
-                                            paged_prefill_write)
-            # the four programs of generation/paged.py, as llama.py
-            # takes them: a decode or verify row, a packed call of
-            # prompts from position 0, a chunk with cached context
-            # behind it, a whole prompt
-            if s == 1 or paged_decode:
-                new_cache = paged_decode_write(kv_cache, k, v)
-                out = paged_decode_attention(q, new_cache,
-                                             window=self.window, sink=sink)
-            elif segment_ids is not None:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0],
-                                                segments=segment_ids[0])
-                out = paged_packed_attention(
-                    q, k.astype(kv_cache.kp.dtype),
-                    v.astype(kv_cache.vp.dtype), segment_ids,
-                    window=self.window, sink=sink, band=self.is_window)
-            elif paged_chunk:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0])
-                out = paged_chunk_attention(q, new_cache, positions,
-                                            window=self.window, sink=sink)
-            else:
-                new_cache = paged_prefill_write(kv_cache, k, v)
-                out = dense_attention(q, k, v, causal=True,
-                                      window=self.window, sink=sink)
+            out, new_cache = write_and_attend(kv_cache, q, k, v, positions,
+                                              segment_ids,
+                                              window=self.window, sink=sink)
         else:
             out = dense_attention(q, k, v, causal=True, window=self.window,
                                   attn_mask=attn_mask, sink=sink)
@@ -258,10 +231,12 @@ class MiMoV2DecoderLayer(Layer):
                 capacity_factor=cfg.capacity_factor,
                 aux_loss_weight=cfg.aux_loss_weight, **moe)
 
-    def forward(self, x, positions, kv_cache=None, **kw):
+    def forward(self, x, positions, kv_cache=None, segment_ids=None,
+                attn_mask=None):
         with jax.named_scope("norm"):
             h = self.input_layernorm(x)
-        attn = self.self_attn(h, positions, kv_cache=kv_cache, **kw)
+        attn = self.self_attn(h, positions, kv_cache=kv_cache,
+                              segment_ids=segment_ids, attn_mask=attn_mask)
         new_cache = None
         if kv_cache is not None:
             attn, new_cache = attn
@@ -293,8 +268,7 @@ class MiMoV2Model(Layer):
             self.norm.to(dtype=config.dtype)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             positions = jnp.arange(s)[None, :].repeat(b, axis=0)
@@ -305,9 +279,7 @@ class MiMoV2Model(Layer):
         for i, layer in enumerate(self.layers):
             if kv_caches is not None:
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
-                              segment_ids=segment_ids,
-                              paged_chunk=paged_chunk,
-                              paged_decode=paged_decode)
+                              segment_ids=segment_ids)
                 new_caches.append(nc)
             else:
                 x = layer(x, positions, attn_mask=attn_mask)
@@ -337,10 +309,9 @@ class MiMoV2ForCausalLM(CausalLMBase):
 
     def paged_cache_layers(self):
         """What ``PagedEngine`` caches a token in EACH layer
-        (``generation.paged.CacheLayer``: the (heads, width) of the K
+        (``ops.paged_cache.CacheLayer``: the (heads, width) of the K
         and of the V pool, and the window of a layer that keeps its
         band only)."""
-        from ..generation.paged import CacheLayer
         cfg = self.config
         out = []
         for layer in self.model.layers:
@@ -359,11 +330,9 @@ class MiMoV2ForCausalLM(CausalLMBase):
         return collect_counts(rows)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         out = self.model(input_ids, positions, kv_caches,
-                         attn_mask=attn_mask, segment_ids=segment_ids,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode)
+                         attn_mask=attn_mask, segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:
             out, caches = out

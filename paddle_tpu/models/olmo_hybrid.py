@@ -46,6 +46,7 @@ from .. import nn
 from ..nn.layer import Layer, Parameter
 from ..ops import delta_rule
 from ..ops.attention import dense_attention
+from ..ops.paged_cache import CacheLayer, StateLayer, write_and_attend
 from ..parallel.layers import (ColumnParallelLinear, RowParallelLinear,
                                VocabParallelEmbedding)
 from ..parallel.sharding import constraint
@@ -149,7 +150,6 @@ class OlmoHybridAttention(Layer):
         self.k_norm = nn.RMSNorm(kv * d, cfg.rms_norm_eps)
 
     def forward(self, x, positions, kv_cache=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 attn_mask=None):
         cfg = self.config
         b, s, _ = x.shape
@@ -165,30 +165,8 @@ class OlmoHybridAttention(Layer):
             v = constraint(v, None, None, "tp", None)
         new_cache = None
         if kv_cache is not None:
-            from ..generation.paged import (paged_chunk_attention,
-                                            paged_decode_attention,
-                                            paged_decode_write,
-                                            paged_packed_attention,
-                                            paged_prefill_write)
-            # the four programs of generation/paged.py, as llama.py
-            # takes them
-            if s == 1 or paged_decode:
-                new_cache = paged_decode_write(kv_cache, k, v)
-                out = paged_decode_attention(q, new_cache)
-            elif segment_ids is not None:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0],
-                                                segments=segment_ids[0])
-                out = paged_packed_attention(
-                    q, k.astype(kv_cache.kp.dtype),
-                    v.astype(kv_cache.vp.dtype), segment_ids)
-            elif paged_chunk:
-                new_cache = paged_prefill_write(kv_cache, k, v,
-                                                positions=positions[0])
-                out = paged_chunk_attention(q, new_cache, positions)
-            else:
-                new_cache = paged_prefill_write(kv_cache, k, v)
-                out = dense_attention(q, k, v, causal=True)
+            out, new_cache = write_and_attend(kv_cache, q, k, v, positions,
+                                              segment_ids)
         else:
             out = dense_attention(q, k, v, causal=attn_mask is None,
                                   attn_mask=attn_mask)
@@ -281,7 +259,6 @@ class GatedDeltaNet(Layer):
         return g, beta
 
     def forward(self, x, positions, kv_cache=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False,
                 attn_mask=None):
         if attn_mask is not None:
             raise NotImplementedError(
@@ -303,7 +280,12 @@ class GatedDeltaNet(Layer):
                 S0 = jnp.zeros((hv, dk, dv), jnp.float32)
                 o = jax.vmap(lambda *r: delta_rule.gated_delta_chunk(
                     *r, S0)[0])(q, k, v, g, beta)
-        elif s == 1 and not paged_chunk and segment_ids is None:
+        elif kv_cache.call == "decode" and s > 1:
+            raise NotImplementedError(
+                "a linear-attention layer has no multi-position decode "
+                "rows: a rejected draft's positions cannot be taken back "
+                "out of the state")
+        elif kv_cache.call == "decode":
             # a decode tick: row r is slot r; one position a row
             S, tails = kv_cache.arrays
             live = kv_cache.live
@@ -317,14 +299,10 @@ class GatedDeltaNet(Layer):
                                                    beta, live)
                 o = o[:, None]
             new_cache = kv_cache._replace(arrays=(S, tails))
-        elif paged_decode:
-            raise NotImplementedError(
-                "a linear-attention layer has no multi-position decode "
-                "rows: a rejected draft's positions cannot be taken back "
-                "out of the state")
         else:
-            # a prompt chunk (b == 1): one slot's, behind its earlier
-            # chunks, or a packed call's segments, each from position 0
+            # a prompt call (b == 1): one slot's chunk behind its earlier
+            # chunks, its whole prompt, or a packed call's segments, each
+            # from position 0
             S, tails = kv_cache.arrays
             slots, lens = kv_cache.slots, kv_cache.seq_lens
             nseg = lens.shape[0]
@@ -384,8 +362,10 @@ class OlmoHybridDecoderLayer(Layer):
     def mixer(self):
         return self.linear_attn if self.is_linear else self.self_attn
 
-    def forward(self, x, positions, kv_cache=None, **kw):
-        out = self.mixer(x, positions, kv_cache=kv_cache, **kw)
+    def forward(self, x, positions, kv_cache=None, segment_ids=None,
+                attn_mask=None):
+        out = self.mixer(x, positions, kv_cache=kv_cache,
+                         segment_ids=segment_ids, attn_mask=attn_mask)
         new_cache = None
         if kv_cache is not None:
             out, new_cache = out
@@ -416,8 +396,7 @@ class OlmoHybridModel(Layer):
             self.norm.to(dtype=config.dtype)
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         b, s = input_ids.shape
         if positions is None:
             positions = jnp.arange(s)[None, :].repeat(b, axis=0)
@@ -428,9 +407,7 @@ class OlmoHybridModel(Layer):
         for i, layer in enumerate(self.layers):
             if kv_caches is not None:
                 x, nc = layer(x, positions, kv_cache=kv_caches[i],
-                              segment_ids=segment_ids,
-                              paged_chunk=paged_chunk,
-                              paged_decode=paged_decode)
+                              segment_ids=segment_ids)
                 new_caches.append(nc)
             else:
                 x = layer(x, positions, attn_mask=attn_mask)
@@ -462,7 +439,6 @@ class OlmoHybridForCausalLM(CausalLMBase):
         """What ``PagedEngine`` keeps for EACH layer: a full layer's K
         and V rows a token (``CacheLayer``), a linear layer's arrays a
         SLOT (``StateLayer``)."""
-        from ..generation.paged import CacheLayer, StateLayer
         cfg = self.config
         kv = (cfg.num_key_value_heads, cfg.head_dim)
         return [StateLayer(layer.linear_attn.state_arrays(),
@@ -471,11 +447,9 @@ class OlmoHybridForCausalLM(CausalLMBase):
                 for layer in self.model.layers]
 
     def forward(self, input_ids, positions=None, kv_caches=None,
-                attn_mask=None, segment_ids=None,
-                paged_chunk: bool = False, paged_decode: bool = False):
+                attn_mask=None, segment_ids=None):
         out = self.model(input_ids, positions, kv_caches,
-                         attn_mask=attn_mask, segment_ids=segment_ids,
-                         paged_chunk=paged_chunk, paged_decode=paged_decode)
+                         attn_mask=attn_mask, segment_ids=segment_ids)
         caches = None
         if kv_caches is not None:
             out, caches = out

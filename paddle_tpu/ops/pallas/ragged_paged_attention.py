@@ -81,7 +81,7 @@ _BLOCK_TOKENS = 256
 def use_ragged_kernel(q, kp, kv_heads: int) -> bool:
     """Whether this kernel serves q [R, T, h, d] (T == 1 or the
     speculative verify's multi-query rows) against a pool ``kp`` [P, B,
-    kv_heads*d]; ``generation/paged.py:paged_decode_route`` sends every
+    kv_heads*d]; ``ops/paged_cache.py:paged_decode_route`` sends every
     other shape to the dense gather. The policy of the other kernels: a
     TPU backend, or the interpreter so that CI drives the dispatch glue
     (it takes any shape with whole query-head groups). On the chip, an

@@ -42,9 +42,8 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("chip_latent_timing: needs a TPU", file=sys.stderr)
         return 1
-    from paddle_tpu.generation.paged import (PagedKV,
-                                             paged_latent_attention,
-                                             paged_latent_attention_dense)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_latent_attention,
+                                            paged_latent_attention_dense)
     routes = {"ragged": paged_latent_attention,       # the chip's route
               "dense": paged_latent_attention_dense}
 

@@ -61,8 +61,8 @@ def main(argv=None) -> int:
     if jax.devices()[0].platform != "tpu":
         print("chip_ragged_timing: needs a TPU", file=sys.stderr)
         return 1
-    from paddle_tpu.generation.paged import (PagedKV,
-                                             paged_decode_attention_dense)
+    from paddle_tpu.ops.paged_cache import (PagedKV,
+                                            paged_decode_attention_dense)
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention_pallas
 

@@ -185,10 +185,9 @@ def test_write_and_attend_compile_with_no_pool_sized_copy(
     of the Qwen cells' tick (PERF.md section 6, PR 27)."""
     import re
     import paddle_tpu.ops.pallas as pallas
-    from paddle_tpu.generation.paged import (PagedKV,
-                                             paged_decode_attention,
-                                             paged_decode_write,
-                                             paged_latent_attention)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_decode_attention,
+                                            paged_decode_write,
+                                            paged_latent_attention)
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     # the route asks jax for its backend, which is the CPU here
     monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
@@ -364,7 +363,7 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
     import os
     import paddle_tpu.ops.pallas as pallas
     from benchmarks.harness import cell
-    from paddle_tpu.generation.paged import PagedKV, SlotState, StateLayer
+    from paddle_tpu.ops.paged_cache import PagedKV, SlotState, StateLayer
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
     with open(os.path.join(cell.BENCH, "configs",
@@ -384,19 +383,20 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
     flag = arr((rows,), jnp.bool_)
     layers = model.paged_cache_layers()
     assert [isinstance(x, StateLayer) for x in layers] == [True, True, False]
+    call = "decode" if program == "tick" else "chunk"
     caches = [
         SlotState(tuple(arr((R,) + tuple(s), d) for s, d in x.arrays),
                   None if program == "tick" else arr((rows,), jnp.int32),
                   lens, flag if program == "tick" else None,
-                  None if program == "tick" else flag)
+                  None if program == "tick" else flag, call)
         if isinstance(x, StateLayer) else
         PagedKV(arr((eng["num_blocks"], eng["block_size"], 640),
                     jnp.bfloat16), None, arr((rows, M), jnp.int32), lens, 1,
-                False)
+                False, call)
         for x in layers]
     compiled = jax.jit(
-        lambda p, ids, c, pos: fn(p, ids, kv_caches=c, positions=pos,
-                                  paged_chunk=program == "chunk")).lower(
+        lambda p, ids, c, pos: fn(p, ids, kv_caches=c,
+                                  positions=pos)).lower(
         params, arr((rows, T), jnp.int32), caches,
         arr((rows, T), jnp.int32)).compile()
     text = compiled.as_text()

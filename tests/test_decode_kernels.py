@@ -197,9 +197,8 @@ def test_pallas_paged_kernel_matches_dense_gather(h, kvh, d, window):
 def test_paged_decode_attention_routes_to_kernel():
     """generation/paged.py dispatch: interpret mode must route through
     the Pallas kernel and agree with the explicit fallback."""
-    from paddle_tpu.generation.paged import (PagedKV,
-                                             paged_decode_attention,
-                                             paged_decode_route)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_decode_attention,
+                                            paged_decode_route)
     rs = np.random.RandomState(3)
     R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
     kp = jnp.asarray(rs.randn(P, B, kvh, d), jnp.float32)

@@ -23,14 +23,11 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.generation.paged import (PagedEngine, PagedKV,
-                                         paged_chunk_attention,
-                                         paged_decode_attention,
-                                         paged_decode_write,
-                                         paged_packed_attention,
-                                         paged_prefill_write)
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.generation.stub import TickStubModel as StubModel
 from paddle_tpu.models import LlamaForCausalLM
 from paddle_tpu.models.llama import llama_tiny
+from paddle_tpu.ops.paged_cache import PagedKV, paged_decode_attention
 
 from test_decode_kernels import _flat
 
@@ -49,51 +46,9 @@ def _engine(model, **kw):
 
 
 # --------------------------------------------------------------- stub model
-class _StubCfg:
-    vocab_size = 128
-    num_hidden_layers = 1
-    num_key_value_heads = 1
-    head_dim = 8
-    dtype = jnp.float32
-
-
-class StubModel:
-    """Minimal CausalLM contract (config + functional()) whose forward
-    is a single embed -> paged KV write -> paged attention -> vocab
-    projection. Model compute is negligible, so engine timings and
-    dispatch counts measure the TICK MACHINERY itself."""
-    config = _StubCfg()
-
-    def functional(self):
-        d, V = self.config.head_dim, self.config.vocab_size
-        k = jax.random.PRNGKey(0)
-        params = dict(emb=jax.random.normal(k, (V, d)),
-                      out=jax.random.normal(k, (d, V)))
-
-        def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False, segment_ids=None):
-            x = params["emb"][tokens]              # [R, s, d]
-            kv = x[:, :, None, :]                  # [R, s, 1, d]
-            pk = kv_caches[0]
-            if tokens.shape[1] == 1:               # decode tick
-                pk = paged_decode_write(pk, kv, kv)
-                o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
-            elif segment_ids is not None:          # a packed call
-                pk = paged_prefill_write(pk, kv, kv,
-                                         positions=positions[0],
-                                         segments=segment_ids[0])
-                o = paged_packed_attention(kv, kv, kv,
-                                           segment_ids)[:, :, 0]
-            else:                                  # (chunk) prefill
-                pk = paged_prefill_write(pk, kv, kv)
-                o = paged_chunk_attention(x[:, :, None, :], pk,
-                                          positions)[:, :, 0]
-            return o @ params["out"], [pk]
-
-        return fn, params
-
-
 def _stub_engine(R=8, **kw):
+    """An engine over ``generation/stub.py``'s model: negligible model
+    compute, so timings and dispatch counts measure the TICK MACHINERY."""
     base = dict(max_slots=R, num_blocks=256, block_size=64,
                 max_blocks_per_seq=8, prefill_buckets=(16,))
     base.update(kw)
@@ -347,8 +302,8 @@ class TestRaggedKernel:
         """paged_decode_attention takes the ragged kernel where it
         serves; the dense gather is a function of its own, and the two
         agree."""
-        from paddle_tpu.generation.paged import (
-            paged_decode_attention_dense, paged_decode_route)
+        from paddle_tpu.ops.paged_cache import (paged_decode_attention_dense,
+                                                paged_decode_route)
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d = 3, 16, 16, 4, 2, 4, 64
         pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
@@ -481,7 +436,7 @@ class TestRaggedKernel:
         """On the chip a page is fetched as one (B, kvh*d) slab, which
         Mosaic slices only in whole 128-lane tiles: one kv head of 64
         columns takes the dense gather."""
-        from paddle_tpu.generation.paged import paged_decode_route
+        from paddle_tpu.ops.paged_cache import paged_decode_route
         from paddle_tpu.ops import pallas
         monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET")
         monkeypatch.setattr(pallas, "tpu_backend", lambda: True)

@@ -216,9 +216,8 @@ def test_a_hard_reset_takes_fresh_latent_pools(model):
 def test_the_latent_kernel_matches_the_dense_gather(kernels, T):
     """The published widths: 64 heads over one row of 512 + 64 columns
     padded to 640, values the first 512."""
-    from paddle_tpu.generation.paged import (PagedKV,
-                                             paged_latent_attention,
-                                             paged_latent_attention_dense)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_latent_attention,
+                                            paged_latent_attention_dense)
     R, P, B, M, h, W, dv = 5, 48, 8, 8, 64, 640, 512
     rs = np.random.RandomState(T)
     q = jnp.asarray(rs.randn(R, T, h, W) * 0.2, jnp.float32)

@@ -35,8 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.generation.paged import (CacheLayer, PagedEngine, StateLayer,
-                                         state_step_route)
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.ops.paged_cache import CacheLayer, StateLayer, state_step_route
 from paddle_tpu.ops import delta_rule
 
 TOL = 1e-4

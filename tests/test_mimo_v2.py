@@ -38,7 +38,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.generation.paged import CacheLayer, PagedEngine
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.ops.paged_cache import CacheLayer
 from paddle_tpu.parallel.moe import ExpertShareMLP
 
 TOL = 1e-4
@@ -307,7 +308,7 @@ def dense_reference(q, k, v, lens, window, sink, scale):
 ])
 def test_the_kernel_against_a_dense_attention(monkeypatch, dk, dv, kvh,
                                               group, T, window, ring):
-    from paddle_tpu.generation.paged import PagedKV, paged_decode_attention
+    from paddle_tpu.ops.paged_cache import PagedKV, paged_decode_attention
     monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
     B, R, h = 8, 3, kvh * group
     M = 5 if ring else 12

@@ -31,8 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.generation.paged import (CacheLayer, PagedEngine, SlotState,
-                                         StateLayer, state_step_route)
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.ops.paged_cache import (CacheLayer, SlotState, StateLayer,
+                                        state_step_route)
 from paddle_tpu.ops import delta_rule
 
 TOL = 1e-4

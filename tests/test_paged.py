@@ -763,7 +763,7 @@ class TestCacheLayers:
          + [((32, 8, 128),), ((2,),)]),
     ])
     def test_each_familys_pools(self, family, pools):
-        from paddle_tpu.generation.paged import StateLayer
+        from paddle_tpu.ops.paged_cache import StateLayer
         eng = _engine(self._built(family), chunk_prefill_tokens=16)
         assert [tuple(p.shape for p in layer) for layer in eng.pools] \
             == pools

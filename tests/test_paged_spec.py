@@ -28,12 +28,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.generation.paged import (PagedEngine, PagedKV,
-                                         paged_chunk_attention,
-                                         paged_decode_attention,
-                                         paged_decode_write,
-                                         paged_packed_attention,
-                                         paged_prefill_write)
+from paddle_tpu.generation.paged import PagedEngine
+from paddle_tpu.ops.paged_cache import (PagedKV, paged_decode_attention,
+                                        paged_decode_write,
+                                        write_and_attend)
 from paddle_tpu.generation.prompt_lookup import (accept_length,
                                                  propose_ngram,
                                                  propose_ngram_rows)
@@ -90,25 +88,12 @@ class LookupStub:
         params = dict(emb=emb, table=table)
 
         def fn(params, tokens, kv_caches=None, positions=None,
-               paged_chunk=False, paged_decode=False, segment_ids=None):
+               segment_ids=None):
             x = params["emb"][tokens]              # [R, s, d]
             kv = x[:, :, None, :]
-            pk = kv_caches[0]
-            if tokens.shape[1] == 1 or paged_decode:
-                pk = paged_decode_write(pk, kv, kv)
-                o = paged_decode_attention(x[:, :, None, :], pk)[:, :, 0]
-            elif segment_ids is not None:          # a packed call
-                pk = paged_prefill_write(pk, kv, kv,
-                                         positions=positions[0],
-                                         segments=segment_ids[0])
-                o = paged_packed_attention(kv, kv, kv,
-                                           segment_ids)[:, :, 0]
-            else:
-                pk = paged_prefill_write(
-                    pk, kv, kv,
-                    positions=positions[0] if paged_chunk else None)
-                o = paged_chunk_attention(x[:, :, None, :], pk,
-                                          positions)[:, :, 0]
+            o, pk = write_and_attend(kv_caches[0], kv, kv, kv, positions,
+                                     segment_ids)
+            o = o[:, :, 0]
             logits = params["table"][tokens] \
                 + 0.0 * jnp.sum(o, axis=-1, keepdims=True)
             return logits, [pk]
@@ -470,8 +455,8 @@ class TestMultiQueryRagged:
     def test_paged_decode_attention_routes_multi_query(self):
         """The dispatch layer: T>1 rows take the ragged kernel too, and
         it agrees with the dense gather."""
-        from paddle_tpu.generation.paged import (
-            paged_decode_attention_dense, paged_decode_route)
+        from paddle_tpu.ops.paged_cache import (paged_decode_attention_dense,
+                                                paged_decode_route)
         rs = np.random.RandomState(8)
         R, P, B, M, kvh, h, d, T = 3, 16, 16, 4, 2, 4, 64, 3
         pk = PagedKV(jnp.asarray(rs.randn(P, B, kvh * d), jnp.float32),
@@ -562,7 +547,7 @@ class TestPromptLookupHelpers:
         chunk at its global positions) leaves the bytes a scatter into
         a [P, B, kvh, d] pool leaves: head h of a token in columns
         h*d .. (h+1)*d of its row."""
-        from paddle_tpu.generation.paged import paged_prefill_write
+        from paddle_tpu.ops.paged_cache import paged_prefill_write
         rs = np.random.RandomState(5)
         P, B, M, kvh, d = 9, 4, 4, 3, 8
         old = rs.randn(2, P, B, kvh, d).astype(np.float32)

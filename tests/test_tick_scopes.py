@@ -31,6 +31,7 @@ import pytest
 from paddle_tpu.generation import paged
 from paddle_tpu.generation.paged import PagedEngine
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+from paddle_tpu.ops import paged_cache
 from paddle_tpu.utils import observability as obs
 
 CHUNK = 16
@@ -354,8 +355,9 @@ def test_scopes_do_not_change_the_program(engine, kernels, monkeypatch,
     # the kernel's wrapper and the chunk programs' write and attentions
     # are jitted: each keeps the jaxpr it traced with the scopes on, and
     # would keep the one traced here without them
-    jitted = (ragged._attend, paged.paged_prefill_write,
-              paged.paged_chunk_attention, paged.paged_packed_attention)
+    jitted = (ragged._attend, paged_cache.paged_prefill_write,
+              paged_cache.paged_chunk_attention,
+              paged_cache.paged_packed_attention)
     for fn in jitted:
         fn.clear_cache()
     try:

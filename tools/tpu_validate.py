@@ -189,9 +189,9 @@ def latent_paged_kernel():
     # 64 query heads over one 640-column row a token, values its first
     # 512 columns, 64 rows, an 8193-block pool), single-query and
     # multi-query, against the dense gather
-    from paddle_tpu.generation.paged import (PagedKV, paged_decode_route,
-                                             paged_latent_attention,
-                                             paged_latent_attention_dense)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_decode_route,
+                                            paged_latent_attention,
+                                            paged_latent_attention_dense)
     R, P, B, M, h2, W, dv = 64, 8193, 16, 128, 64, 640, 512
     kp = jnp.asarray(rs.randn(P, B, W), jnp.bfloat16)
     tables = jnp.asarray(1 + rs.permutation(P - 1)[:R * M]
@@ -255,9 +255,9 @@ def unequal_head_paged_kernel():
     # heads of 128, 64 rows): a full layer over the allocator's table,
     # and a window layer's sink and ring of 25 pages a slot written
     # round, single-query and multi-query, against the dense gather
-    from paddle_tpu.generation.paged import (PagedKV, paged_decode_attention,
-                                             paged_decode_attention_dense,
-                                             paged_decode_route)
+    from paddle_tpu.ops.paged_cache import (PagedKV, paged_decode_attention,
+                                            paged_decode_attention_dense,
+                                            paged_decode_route)
     R, B, h2, dk, dv = 64, 16, 64, 192, 128
     lens = jnp.asarray(([0, 15, 16, 2040, 100, 576, 1023, 300]
                         + list(rs.randint(0, 2040, R - 8))), jnp.int32)
