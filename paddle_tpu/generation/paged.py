@@ -123,8 +123,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.paged_cache import (CacheLayer, PagedKV, SlotState, StateLayer,
-                               chunk_rule_route, paged_decode_route,
-                               state_step_route)
+                               chunk_attention_positions, chunk_rule_route,
+                               paged_decode_route, state_step_route)
 from ..utils import observability as obs
 from ..utils.faults import BackpressureError
 
@@ -211,6 +211,14 @@ _STATE_COUNTERS = ("state_layer_ticks", "state_kernel_ticks",
 # layers x prompt calls, and those of them whose chunk rule took the
 # kernel (``chunk_rule_route``)
 _CHUNK_RULE_COUNTERS = ("chunk_rule_layer_calls", "chunk_rule_kernel_calls")
+
+
+# and, as it dispatches a prompt chunk that has cached context behind
+# it, over the call's K/V layers: the positions the chunk's attention
+# scored (whole runs of pages) and those of them the row's table held
+# live (``ops.paged_cache.chunk_attention_positions``)
+_CHUNK_ATTN_COUNTERS = ("chunk_attn_positions_scored",
+                        "chunk_attn_positions_live")
 
 
 # what a band-keeping engine adds up inside a tick, over the live rows
@@ -751,7 +759,8 @@ class PagedEngine:
                       "spill_restored_tokens",
                       "spill_restore_failures")
             + self._tick_counter_names
-            + (_CHUNK_RULE_COUNTERS if self._n_state else ())}
+            + (_CHUNK_RULE_COUNTERS if self._n_state else ())
+            + _CHUNK_ATTN_COUNTERS}
         # paged_decode_step_ms is what the host can see of one decode
         # dispatch: on the host reference path, which reads back in the
         # tick, the program's whole run, call to tokens on the host; the
@@ -1154,6 +1163,21 @@ class PagedEngine:
         if self._n_state:
             self._count("chunk_rule_layer_calls", self._n_state)
             self._count("chunk_rule_kernel_calls", self._n_chunk_kernel)
+
+    def _count_chunk_attention(self, cached: int):
+        """``_CHUNK_ATTN_COUNTERS`` of one ``_chunk_jit.alone`` call
+        whose row holds ``cached`` tokens with the chunk's own in."""
+        scored = live = 0
+        for layer in self._layout:
+            if len(layer.rows) != 2:
+                continue    # state, or a latent row (expanded, not walked)
+            M = self.M if layer.window is None \
+                else self._ring_blocks(layer.window)
+            s, n = chunk_attention_positions(cached, M, self.B,
+                                             layer.window is not None)
+            scored, live = scored + s, live + n
+        self._count("chunk_attn_positions_scored", scored)
+        self._count("chunk_attn_positions_live", live)
 
     # ------------------------------------------------------------ jitted
     def _paged_caches(self, call, pools, tables, lens, slots=None,
@@ -2706,6 +2730,7 @@ class PagedEngine:
             self._count("prefill_chunks")
             self._count("prefill_segments")
             self._count_chunk_rule()
+            self._count_chunk_attention(start + live)
             # mid chunks keep the ids-only mask; the final chunk's
             # committed sample rides in seen_fin (mirrors the PRNG-key
             # protocol)

@@ -20,6 +20,8 @@ from .olmo_hybrid import (OlmoHybridConfig, OlmoHybridForCausalLM,
                           OlmoHybridModel, olmo_hybrid_tiny)
 from .ling_hybrid import (LingHybridConfig, LingHybridForCausalLM,
                           LingHybridModel, ling_hybrid_tiny)
+from .laguna import (LagunaConfig, LagunaForCausalLM, LagunaModel,
+                     laguna_tiny)
 from .qwen2_moe import (DeepseekMoeConfig, DeepseekMoeForCausalLM,
                         Qwen2MoeConfig, Qwen2MoeForCausalLM, Qwen2MoeModel,
                         deepseek_moe_tiny, moe_lm_loss, qwen2_moe_tiny)

@@ -27,6 +27,7 @@ from .delta_rule import chunk_rule_kernel
 __all__ = ["CacheLayer", "StateLayer", "PagedKV", "SlotState",
            "write_and_attend", "paged_decode_write", "paged_prefill_write",
            "paged_chunk_rows", "paged_chunk_attention",
+           "chunk_attention_positions",
            "paged_packed_attention", "paged_decode_attention",
            "paged_decode_attention_dense", "paged_latent_attention",
            "paged_latent_attention_dense", "paged_decode_route",
@@ -276,6 +277,11 @@ def paged_chunk_rows(pk: PagedKV, pool=None):
     return pk.split(rows.reshape(1, -1, rows.shape[-1]))
 
 
+# a masked score: finite, so that a row of the online softmax that has
+# seen no key yet has a maximum to subtract
+_MASKED = -1e30
+
+
 def _window_scope(name: str, ring: bool) -> str:
     """obs.TICK_SCOPES: a band-keeping layer's attention has a scope of
     its own beside the whole-context layers' (``attn_window`` beside
@@ -283,31 +289,104 @@ def _window_scope(name: str, ring: bool) -> str:
     return name + "_window" if ring else name
 
 
+# Pages one step of a prompt chunk's attention gathers and scores: 32
+# pages of 16 tokens are 512 positions, [heads, chunk, 512] float32
+# scores whatever the slot's length
+CHUNK_RUN_PAGES = 32
+
+
+def chunk_attention_positions(cached: int, table_blocks: int,
+                              block_size: int, ring: bool = False):
+    """(positions scored, positions live) of ONE layer's
+    ``paged_chunk_attention`` call whose row holds ``cached`` tokens once
+    the chunk's own are written, over a table of ``table_blocks`` pages:
+    the host's arithmetic of the loop below (plain ints), for the
+    engine's counters. A whole-context layer scores the runs of pages up
+    to the one that holds the row's last token; a band-keeping layer
+    (``ring``) every run of its ring. Live is what of the row the table
+    holds."""
+    run = min(table_blocks, CHUNK_RUN_PAGES)
+    runs = -(-table_blocks // run)
+    live = min(cached, table_blocks * block_size)
+    if not ring:
+        runs = min(-(-cached // (run * block_size)), runs)
+    return runs * run * block_size, live
+
+
 @functools.partial(jax.jit, inline=True, static_argnames=("window",))
 def paged_chunk_attention(q, pk: PagedKV, positions,
                           window: Optional[int] = None, sink=None):
     """Chunked-prefill attention: q [1, s, h, d] chunk queries at global
-    positions [1, s] attend over row 0's gathered blocks — the
-    previously cached chunks AND (causally) this chunk's own tokens,
-    which ``paged_prefill_write`` scattered in just before. Stale or
+    positions [1, s] attend over row 0's blocks: the previously cached
+    chunks AND (causally) this chunk's own tokens, which
+    ``paged_prefill_write`` scattered in just before. An online softmax
+    over RUNS of ``CHUNK_RUN_PAGES`` pages: each step gathers one run of
+    the row's table, scores it ([h, s, run] float32, never the slot's
+    length) and folds it into the running maximum, denominator and
+    value sum, so the work follows the context that is LIVE behind the
+    chunk: the loop ends at the run that holds the row's last token
+    (``pk.seq_lens[0]``) and, under a ``window`` over a whole table,
+    starts at the first run the chunk's first query still reaches. A
+    band-keeping layer (``pk.ring``) walks its ring, the band behind the
+    chunk and the chunk, not the row's whole table. Stale or
     never-written table positions sit beyond every query's position (or
     in unallocated garbage-block slots) and are masked by the causal
-    compare. A band-keeping layer (``pk.ring``) gathers its ring, the
-    band behind the chunk and the chunk, not the row's whole table.
-    ``sink`` [h]: see ``dense_attention``."""
+    compare. ``sink`` [h] (see ``dense_attention``) is the softmax's
+    start. One compiled program whatever is live."""
     with jax.named_scope(_window_scope("chunk_attn", pk.ring)):
-        ks = paged_chunk_rows(pk)                   # [1, T, kvh, d]
-        vs = paged_chunk_rows(pk, pk.vp)
-        kpos = _table_positions(pk, pk.seq_lens[:1] - 1) if pk.ring \
-            else jnp.arange(ks.shape[1])[None, :]           # [1, T]
+        B, M = pk.block_size, pk.block_tables.shape[1]
+        P = min(M, CHUNK_RUN_PAGES)
+        runs, T = -(-M // P), P * B
+        s, h = q.shape[1], q.shape[2]
+        g = h // pk.heads
+        scale = q.shape[-1] ** -0.5
+        table = jnp.pad(pk.block_tables[0], (0, runs * P - M))
+        kpos = _table_positions(pk, pk.seq_lens[:1] - 1)[0] if pk.ring \
+            else jnp.arange(M * B)
+        kpos = jnp.pad(kpos, (0, runs * T - M * B), constant_values=-1)
         qpos = positions[0][:, None]                        # [s, 1]
-        keep = kpos <= qpos                                 # [s, T]
-        if pk.ring:         # an entry no block has been written to yet
-            keep &= kpos >= 0
-        if window is not None:
-            keep &= qpos - kpos < window
-        return dense_attention(q, ks, vs, attn_mask=keep[None, None],
-                               sink=sink)
+        qg = jnp.moveaxis(q[0].reshape(s, pk.heads, g, -1), 0, 2)
+
+        def run(j, carry):
+            m, l, acc = carry
+            pages = jax.lax.dynamic_slice_in_dim(table, j * P, P)
+            ks = pk.split(pk.kp[pages].reshape(T, -1))      # [T, kvh, d]
+            vs = pk.split(pk.vp[pages].reshape(T, -1))
+            at = jax.lax.dynamic_slice_in_dim(kpos, j * T, T)[None, :]
+            keep = (at <= qpos) & (at >= 0)                 # [s, T]
+            if window is not None:
+                keep &= qpos - at < window
+            sc = jnp.einsum("kgsd,tkd->kgst", qg, ks).astype(jnp.float32) \
+                * scale
+            sc = jnp.where(keep, sc, _MASKED)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+            p = jnp.where(keep, jnp.exp(sc - m_new[..., None]), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "kgst,tkd->kgsd", p.astype(q.dtype), vs,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        shape = (pk.heads, g, s)
+        if sink is None:
+            m0 = jnp.full(shape, _MASKED, jnp.float32)
+            l0 = jnp.zeros(shape, jnp.float32)
+        else:       # one more key of that score whose value is zero
+            m0 = jnp.broadcast_to(
+                sink.astype(jnp.float32).reshape(pk.heads, g, 1), shape)
+            l0 = jnp.ones(shape, jnp.float32)
+        acc0 = jnp.zeros(shape + (pk.vp.shape[2] // pk.heads,), jnp.float32)
+        if pk.ring:
+            lo, hi = 0, runs
+        else:
+            hi = jnp.minimum(-(-pk.seq_lens[0] // T), runs)
+            lo = 0 if window is None else \
+                jnp.clip(positions[0, 0] - window + 1, 0) // T
+        _, l, acc = jax.lax.fori_loop(lo, hi, run, (m0, l0, acc0))
+        # a query with no key to see (a pad past the band) reads zero
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return jnp.moveaxis(out, 2, 0).reshape(1, s, h, -1).astype(q.dtype)
 
 
 @functools.partial(jax.jit, inline=True,

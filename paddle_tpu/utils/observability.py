@@ -979,6 +979,8 @@ TICK_SCOPES = (
     "gate_norm",   # linear attention: the per-head norm and output gate
     "head_gate",   # latent attention with a gate a head: sigmoid(W_gate
                    # x)[head] times the head's output, before o_proj
+    "attn_gate",   # attention with a gate a query head (Laguna): sigmoid(
+                   # W_g x)[head] times the head's result, before o_proj
     "o_proj",
     "mlp",         # a dense FFN; of an expert layer the residual add
     "router",      # expert layer: float32 scores, groups, top-k, gates

@@ -93,6 +93,67 @@ def test_unequal_head_kernel_compiles_for_a_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("M,P,group,window,ring", [
+    (448, 28673, 6, None, False),   # a full layer's tick: 48 query heads
+    (97, 6209, 9, 512, True),       # a window layer's: 72, its ring
+    (65, 4161, 9, 512, True),       # the ring at chunks of 512
+])
+def test_laguna_groups_compile_for_a_v5e(one_chip, no_persistent_cache,
+                                         monkeypatch, M, P, group, window,
+                                         ring):
+    """Laguna-S-2.1's two layer kinds (ISSUE 46): 8 kv heads of 128
+    columns under query groups of 6 and of 9 (no power of two), a table
+    of 448 pages a row, a band of 512 positions over a ring of 97."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention_pallas
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, kp, vp, tbl, lens: ragged_paged_attention_pallas(
+            q, kp, vp, tbl, lens, 128 ** -0.5, 8, window=window,
+            ring=ring)).lower(
+        arr((64, 8 * group, 128)), arr((P, 16, 1024)), arr((P, 16, 1024)),
+        arr((64, M), jnp.int32), arr((64,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("M,P,h,kvh,dk,dv,chunk,window,ring,sink", [
+    (448, 28673, 48, 8, 128, 128, 1024, None, False, False),   # laguna
+    (97, 6209, 72, 8, 128, 128, 1024, 512, True, False),
+    (128, 8193, 64, 4, 192, 128, 256, None, False, False),     # mimo
+    (25, 1601, 64, 8, 192, 128, 256, 128, True, True),
+    (128, 2049, 28, 4, 128, 128, 256, None, False, False),     # qwen2
+])
+def test_the_chunk_walk_compiles_for_a_v5e_with_no_slot_long_score(
+        one_chip, no_persistent_cache, M, P, h, kvh, dk, dv, chunk, window,
+        ring, sink):
+    """ISSUE 46: a prompt chunk's attention at the cells' shapes holds no
+    float32 array with the slot's length behind the chunk's queries: the
+    widest score is [kv heads, group, chunk, one run of 32 pages]."""
+    from paddle_tpu.ops.paged_cache import (CHUNK_RUN_PAGES, PagedKV,
+                                            paged_chunk_attention)
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, kp, vp, tbl, lens, pos, sink):
+        pk = PagedKV(kp, vp, tbl, lens, kvh, ring, "chunk")
+        return paged_chunk_attention(q, pk, pos, window=window, sink=sink)
+
+    text = jax.jit(attend).lower(
+        arr((1, chunk, h, dk)), arr((P, 16, kvh * dk)),
+        arr((P, 16, kvh * dv)), arr((1, M), jnp.int32),
+        arr((1,), jnp.int32), arr((1, chunk), jnp.int32),
+        arr((h,), jnp.float32) if sink else None).compile().as_text()
+    run = min(M, CHUNK_RUN_PAGES) * 16
+    assert f"f32[{kvh},{h // kvh},{chunk},{run}]" in text
+    assert "while(" in text or ring
+    if M * 16 > run:
+        assert f",{M * 16}]" not in text.replace("s32[", "")
+
+
 @pytest.mark.parametrize("R,T", [(64, 1), (64, 2)])
 def test_latent_kernel_compiles_for_a_v5e(one_chip, no_persistent_cache,
                                           monkeypatch, R, T):

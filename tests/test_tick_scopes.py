@@ -48,7 +48,10 @@ LINEAR = {"conv", "delta_state", "chunk_delta_state", "gate_norm"}
 # what only Ling 3.0's layers have (ISSUE 41): the full-rank gate of a
 # decay a key channel, a gate a head on latent attention's output
 KDA = {"decay_gate", "head_gate"}
-LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW - LINEAR - KDA
+# what only Laguna's layers have (ISSUE 46): a gate a query head on a
+# K/V attention's result
+GQA_GATE = {"attn_gate"}
+LLAMA = set(obs.TICK_SCOPES) - MOE_MLA - WINDOW - LINEAR - KDA - GQA_GATE
 PROGRAMS = {
     "_fused_tick": LLAMA - {"chunk_attn"},
     "_fused_tick_greedy": LLAMA - {"chunk_attn"},
@@ -58,10 +61,10 @@ PROGRAMS = {
 # DeepSeek-V3's block, both kinds of layer. A chunk attends in the
 # expanded form, so it has no `absorb`
 DEEPSEEK = {
-    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA - {
-        "chunk_attn", "zero_experts"},
-    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA - ATTN - {
-        "patch", "absorb", "zero_experts"},
+    "_fused_tick_greedy": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA
+    - GQA_GATE - {"chunk_attn", "zero_experts"},
+    "_chunk_prefill": set(obs.TICK_SCOPES) - WINDOW - LINEAR - KDA
+    - GQA_GATE - ATTN - {"patch", "absorb", "zero_experts"},
 }
 DEEPSEEK["_chunk_prefill_packed"] = DEEPSEEK["_chunk_prefill"] | {"patch"}
 # LongCat-Flash's double layer: zero-compute experts, no shared expert
@@ -97,6 +100,9 @@ LING = {
         "delta_state"} | {"router", "experts", "shared_expert"},
 }
 LING["_chunk_prefill_packed"] = LING["_chunk_prefill"] | {"patch"}
+# Laguna: MiMo-V2's two kinds of K/V attention, each head's result gated,
+# experts WITH a shared one
+LAGUNA = {k: v | GQA_GATE | {"shared_expert"} for k, v in MIMO.items()}
 
 
 @pytest.fixture(scope="module")
@@ -218,6 +224,38 @@ def test_window_and_full_layers_carry_scopes_of_their_own(mimo_engine,
 
 
 @pytest.fixture(scope="module")
+def laguna_engine():
+    from paddle_tpu.models.laguna import LagunaForCausalLM, laguna_tiny
+    eng = PagedEngine(LagunaForCausalLM(laguna_tiny(experts_held=4)),
+                      max_slots=4, num_blocks=32, block_size=8,
+                      max_blocks_per_seq=8, chunk_prefill_tokens=CHUNK)
+    eng._refresh_dev()
+    return eng
+
+
+@pytest.mark.parametrize("program", sorted(LAGUNA))
+def test_gated_heads_over_shared_kv_heads_carry_their_scopes(
+        laguna_engine, kernels, program):
+    """One full layer of 6 query heads and two window layers of 10 over
+    the same 2 kv heads (ISSUE 46): MiMo-V2's scopes where the work is
+    the same, the head gate under a scope of its own, the shared expert
+    under the expert families'; a tick's kernel calls are one ragged
+    call a layer, at its own query group, and the two expert layers'."""
+    assert laguna_engine.decode_route() == "ragged"
+    _, scopes = _lowered(laguna_engine, program)
+    assert set(scopes) - {None} == LAGUNA[program]
+    assert scopes[None] < 0.1 * sum(scopes.values()), scopes
+    if not program.startswith("_chunk_prefill"):
+        jaxpr = _trace(laguna_engine, program).jaxpr.jaxpr
+        assert _kernel_calls(jaxpr) == [
+            ("ragged_paged_attention", "attn"),
+            ("ragged_paged_attention", "attn_window"),
+            ("expert_share_mlp", "experts"),
+            ("ragged_paged_attention", "attn_window"),
+            ("expert_share_mlp", "experts")]
+
+
+@pytest.fixture(scope="module")
 def hybrid_engine():
     from paddle_tpu.models.olmo_hybrid import (OlmoHybridForCausalLM,
                                                olmo_hybrid_tiny)
@@ -292,7 +330,8 @@ def test_ling_layers_carry_the_scopes_of_both_kinds(ling_engine, kernels,
 def test_the_programs_use_the_whole_vocabulary():
     assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
                        *LONGCAT.values(), *MIMO.values(),
-                       *HYBRID.values(), *LING.values()) \
+                       *HYBRID.values(), *LING.values(),
+                       *LAGUNA.values()) \
         == set(obs.TICK_SCOPES)
     assert len(set(obs.TICK_SCOPES)) == len(obs.TICK_SCOPES)
     assert not set(obs.TICK_SCOPES) & set(obs.TICK_PHASES

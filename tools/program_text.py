@@ -43,6 +43,8 @@ FAMILIES = {
     "olmo_hybrid": ("OlmoHybridForCausalLM", "olmo_hybrid_tiny", {}, False),
     "ling_hybrid": ("LingHybridForCausalLM", "ling_hybrid_tiny",
                     dict(experts_held=4), False),
+    "laguna": ("LagunaForCausalLM", "laguna_tiny", dict(experts_held=4),
+               True),
 }
 GEOMETRY = dict(max_slots=4, num_blocks=64, block_size=4,
                 max_blocks_per_seq=16, chunk_prefill_tokens=16)

@@ -757,6 +757,45 @@ def ling_programs():
     print("ling_programs: logprob err %%.1e" %% worst, flush=True)
 check("ling_programs", ling_programs)
 
+def laguna_programs():
+    # ISSUE 46: the family's tick and chunk programs (query groups of 3
+    # and 5 over the same kv heads, a gate a head, a ring beside a whole
+    # table, the softmax router's share beside a shared expert) through
+    # the real compiler at tiny widths: prompts of one to thirteen
+    # chunks, the later ones through the run-walking chunk attention,
+    # and decode through PagedEngine against the no-cache forward
+    import paddle_tpu as pt
+    from paddle_tpu.generation.paged import PagedEngine
+    from paddle_tpu.models.laguna import LagunaForCausalLM, laguna_tiny
+    pt.seed(0)
+    model = LagunaForCausalLM(laguna_tiny(experts_held=4))
+    fn, params = model.functional()
+    prompts = [rs.randint(1, 256, n).tolist() for n in (5, 37, 16, 200)]
+    worst = 0.0
+    with jax.default_matmul_precision("highest"):
+        eng = PagedEngine(model, max_slots=4, num_blocks=96, block_size=8,
+                          max_blocks_per_seq=40, chunk_prefill_tokens=16)
+        for i, p in enumerate(prompts):
+            eng.submit(i, p, max_new_tokens=8)
+        res = eng.run()
+        for i, p in enumerate(prompts):
+            lp = jax.nn.log_softmax(
+                fn(params, jnp.asarray([p + res[i]]))[0], -1)
+            want = [float(lp[len(p) - 1 + j, t])
+                    for j, t in enumerate(res[i])]
+            worst = max(worst, float(np.abs(
+                np.asarray(want) - np.asarray(eng.logprobs[i])).max()))
+    assert worst < 1e-3, worst
+    st = eng.stats
+    assert eng.decode_route() == ("ragged" if dev.platform == "tpu"
+                                  else "dense")
+    assert st["moe_layer_ticks"] == 2 * st["decode_steps"] > 0
+    # a 40-page table is two runs of 32: the walk stops at the live one
+    assert 0 < st["chunk_attn_positions_live"] \
+        <= st["chunk_attn_positions_scored"]
+    print("laguna_programs: logprob err %%.1e" %% worst, flush=True)
+check("laguna_programs", laguna_programs)
+
 print("KERNELS_JSON " + json.dumps(results), flush=True)
 """
 
