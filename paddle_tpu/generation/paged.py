@@ -123,8 +123,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.paged_cache import (CacheLayer, PagedKV, SlotState, StateLayer,
-                               chunk_attention_positions, chunk_rule_route,
+                               chunk_attention_positions,
+                               chunk_experts_route, chunk_rule_route,
                                paged_decode_route, state_step_route)
+from ..parallel.moe import ExpertShareMLP
 from ..utils import observability as obs
 from ..utils.faults import BackpressureError
 
@@ -211,6 +213,14 @@ _STATE_COUNTERS = ("state_layer_ticks", "state_kernel_ticks",
 # layers x prompt calls, and those of them whose chunk rule took the
 # kernel (``chunk_rule_route``)
 _CHUNK_RULE_COUNTERS = ("chunk_rule_layer_calls", "chunk_rule_kernel_calls")
+
+
+# and, for a model with expert layers (``ExpertShareMLP``): expert
+# layers x prompt calls, and those of them whose forward took the
+# grouped product over the sorted (position, expert) pairs
+# (``chunk_experts_route`` of the call's positions)
+_CHUNK_EXPERTS_COUNTERS = ("chunk_experts_layer_calls",
+                           "chunk_experts_grouped_calls")
 
 
 # and, as it dispatches a prompt chunk that has cached context behind
@@ -645,6 +655,14 @@ class PagedEngine:
                 (self.chunk or 1,) + shape, jnp.float32)
                 for shape in l.rule)) == "kernel"
             for l in self._layout if isinstance(l, StateLayer) and l.rule)
+        # the stacked weights of the model's expert layers (none in most
+        # models), and by a prompt call's positions how many of them
+        # take the grouped product (``_count_chunk_experts``)
+        self._expert_stacks = [
+            layer.w_gate for _, layer in
+            getattr(model, "named_sublayers", tuple)()
+            if isinstance(layer, ExpertShareMLP)]
+        self._n_experts_grouped: Dict[int, int] = {}
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -760,6 +778,7 @@ class PagedEngine:
                       "spill_restore_failures")
             + self._tick_counter_names
             + (_CHUNK_RULE_COUNTERS if self._n_state else ())
+            + (_CHUNK_EXPERTS_COUNTERS if self._expert_stacks else ())
             + _CHUNK_ATTN_COUNTERS}
         # paged_decode_step_ms is what the host can see of one decode
         # dispatch: on the host reference path, which reads back in the
@@ -1163,6 +1182,20 @@ class PagedEngine:
         if self._n_state:
             self._count("chunk_rule_layer_calls", self._n_state)
             self._count("chunk_rule_kernel_calls", self._n_chunk_kernel)
+
+    def _count_chunk_experts(self, positions: int):
+        """``_CHUNK_EXPERTS_COUNTERS`` of one prompt call's dispatch, a
+        call of ``positions`` tokens."""
+        if not self._expert_stacks:
+            return
+        if positions not in self._n_experts_grouped:
+            self._n_experts_grouped[positions] = sum(
+                chunk_experts_route(jax.ShapeDtypeStruct(
+                    (positions, w.shape[1]), w.dtype), w) == "grouped"
+                for w in self._expert_stacks)
+        self._count("chunk_experts_layer_calls", len(self._expert_stacks))
+        self._count("chunk_experts_grouped_calls",
+                    self._n_experts_grouped[positions])
 
     def _count_chunk_attention(self, cached: int):
         """``_CHUNK_ATTN_COUNTERS`` of one ``_chunk_jit.alone`` call
@@ -2588,6 +2621,7 @@ class PagedEngine:
         self.seen = self.seen.at[slot_id].set(seen_row)
         self._count("prefills")
         self._count_chunk_rule()
+        self._count_chunk_experts(bucket)
         first = int(nxt)
         self.keys[slot_id] = np.asarray(new_key)
         self._key_overrides.add(slot_id)
@@ -2675,6 +2709,7 @@ class PagedEngine:
             self._count("prefill_chunks")
             self._count("prefill_segments", len(slot_ids))
             self._count_chunk_rule()
+            self._count_chunk_experts(self.chunk)
             # the segments whose prompt ends in this call: their first
             # token comes back with it
             done = [live == len(self.slots[i].prompt)
@@ -2730,6 +2765,7 @@ class PagedEngine:
             self._count("prefill_chunks")
             self._count("prefill_segments")
             self._count_chunk_rule()
+            self._count_chunk_experts(self.chunk)
             self._count_chunk_attention(start + live)
             # mid chunks keep the ids-only mask; the final chunk's
             # committed sample rides in seen_fin (mirrors the PRNG-key

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from .attention import dense_attention, segment_mask
 from .delta_rule import chunk_rule_kernel
+from .pallas import expert_mlp
 
 __all__ = ["CacheLayer", "StateLayer", "PagedKV", "SlotState",
            "write_and_attend", "paged_decode_write", "paged_prefill_write",
@@ -31,7 +32,7 @@ __all__ = ["CacheLayer", "StateLayer", "PagedKV", "SlotState",
            "paged_packed_attention", "paged_decode_attention",
            "paged_decode_attention_dense", "paged_latent_attention",
            "paged_latent_attention_dense", "paged_decode_route",
-           "state_step_route", "chunk_rule_route"]
+           "state_step_route", "chunk_rule_route", "chunk_experts_route"]
 
 
 class CacheLayer(NamedTuple):
@@ -448,6 +449,22 @@ def chunk_rule_route(q, v, g) -> str:
     route``'s sibling: it asks what ``gated_delta_chunk`` asks
     (``chunk_rule_kernel``; shapes, dtype and the platform decide)."""
     return "kernel" if chunk_rule_kernel(q, v, g) else "fusions"
+
+
+def chunk_experts_route(xt, w_gate) -> str:
+    """Which path an expert layer's held part
+    (``parallel.moe.ExpertShareMLP.routed``) takes for a prompt call's
+    positions xt [T, h] over stacked weights ``w_gate`` [n, h, m]:
+    ``"grouped"`` (the product over the (position, expert) pairs sorted
+    by expert, ``ops.pallas.expert_mlp.grouped_expert_mlp_pallas``),
+    ``"hit_list"`` (a call of no more positions than a tick has rows:
+    the tick's kernel) or ``"einsums"`` (every position through every
+    held expert). It asks what ``routed`` asks (``use_grouped_kernel``,
+    ``use_expert_kernel``; shapes and the platform decide)."""
+    if expert_mlp.use_grouped_kernel(xt, w_gate):
+        return "grouped"
+    return "hit_list" if expert_mlp.use_expert_kernel(xt, w_gate) \
+        else "einsums"
 
 
 def _row_positions(pk: PagedKV, T: int, Tk: int):

@@ -341,28 +341,38 @@ class ExpertShareMLP(Layer):
         return ids, gates * self.routed_scaling_factor
 
     def routed(self, xt, ids, gates):
-        """The held experts' part for tokens xt [T, h]. A forward of few
-        tokens (``use_expert_kernel``: a tick's rows) reads the weights
-        of the experts that a row of it chose and of no other, through
-        the kernel; any other multiplies every token through every held
-        expert, where nearly all are hit anyway. The same sum either
-        way: every chosen held expert of every row, live or not."""
+        """The held experts' part for tokens xt [T, h]. Where the
+        kernels run, a forward of few tokens (``use_expert_kernel``: a
+        tick's rows) puts every row through the experts that a row of it
+        chose, and a forward of more (``use_grouped_kernel``: a prompt
+        call's positions) sorts its (position, held expert) pairs by
+        expert and multiplies those alone; either reads the weights of
+        the experts hit and of no other. Any other forward multiplies
+        every token through every held expert: the einsums, which are
+        the definition. The same sum whichever way: every chosen held
+        expert of every row, live or not."""
         held = jnp.arange(self.first_expert,
                           self.first_expert + self.experts_held)
         chose = ids[:, :, None] == held[None, None, :]       # [T, k, n]
-        w = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
         from ..ops.pallas import expert_mlp
         kernel = expert_mlp.use_expert_kernel(xt, self.w_gate)
+        grouped = expert_mlp.use_grouped_kernel(xt, self.w_gate)
+        if not grouped:
+            w = jnp.sum(jnp.where(chose, gates[:, :, None], 0.0), axis=1)
         if kernel:
             order, read = expert_mlp.hit_list(jnp.any(chose, axis=(0, 1)))
         box = getattr(_collecting, "box", None)
         if box is not None:
+            if grouped:     # it visits the experts that have a pair
+                read = jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32)
+            elif not kernel:
+                read = jnp.int32(self.experts_held)
             live = jnp.repeat(box.rows, xt.shape[0] // box.rows.shape[0])
             chose = chose & live[:, None, None]
             counts = [
                 jnp.int32(1), jnp.sum(chose, dtype=jnp.int32),
                 jnp.sum(jnp.any(chose, axis=(0, 1)), dtype=jnp.int32),
-                read if kernel else jnp.int32(self.experts_held)]
+                read]
             if self.zero_experts:       # ZERO_COUNTERS
                 counts += [
                     jnp.sum(live, dtype=jnp.int32) * self.top_k,
@@ -372,6 +382,10 @@ class ExpertShareMLP(Layer):
                 counts.append(jnp.sum(jnp.any(chose, axis=(1, 2)),
                                       dtype=jnp.int32))
             box.add(jnp.stack(counts))
+        if grouped:
+            return expert_mlp.grouped_expert_mlp_pallas(
+                xt, ids, gates, self.first_expert, self.w_gate, self.w_up,
+                self.w_down)
         if kernel:
             return expert_mlp.expert_share_mlp_pallas(
                 xt, w, order, read, self.w_gate, self.w_up, self.w_down)

@@ -1,28 +1,37 @@
 #!/usr/bin/env python3
 """Time the held experts' part of an expert layer alone on the chip: the
-weight-streaming kernel (``ops/pallas/expert_mlp.py``) beside the
-einsums over all held experts, both through
-``ExpertShareMLP.routed`` with its gate held open or shut (PERF.md
-section 5, "The kernels alone"; the cut by token count,
-``expert_mlp.MAX_TOKENS``, is read off this table).
+two kernels of ``ops/pallas/expert_mlp.py`` (a tick's rows through the
+experts hit; a prompt call's sorted pairs through the grouped product)
+beside the einsums over all held experts, all through
+``ExpertShareMLP.routed`` with its gates held open or shut (PERF.md
+section 5, "The held experts' part alone").
 
-    python3 tests/chip_experts_timing.py [--hidden 7168,6144,4096]
-        [--tokens 64,128,256] [--hit 16,13,10,5] [--weights-mb 48]
+    python3 tests/chip_experts_timing.py
+        [--shapes 3072x1024x16x10,2560x768x32x8,4096x2048x16x8,7168x2048x16x8]
+        [--tokens 64,256,1024] [--hit 16,10] [--weights-mb 48] [--ops]
 
-16 held experts of width 2048 of a 256-column router, 8 choices a token
-(bf16), at the hidden sizes of the three expert configurations. Every
-token chooses ``min(8, hit)`` of the first ``hit`` held experts in
-rotation and fills up with experts the rank does not hold, so exactly
-``hit`` of the 16 get a token. One JSON line per (hidden, tokens, hit):
-the time of ONE call of each route, the bytes of the experts hit (3 x
-hidden x 2048 x 2 B each) and of all 16, and each route's share of 819
-GB/s on the bytes it has to read (the kernel: the experts hit; the
-einsums: all held). A call's time is the two-point fit of
-tests/chip_ragged_timing.py: one jitted program chains ``n`` calls,
-each call's output the next one's tokens, and (t(24) - t(8)) / 16
-leaves out the dispatch. ``--weights-mb`` sets the kernel's budget for
-its six weight buffers, which fixes its column tile. Not a pytest
-file; it refuses to run without a TPU.
+A shape is hidden x expert width x experts held x choices a token, of a
+256-column router (bf16). Rows up to ``expert_mlp.MAX_TOKENS`` take the
+tick's kernel, at ``--hit`` experts hit: every token chooses ``min(k,
+hit)`` of the first ``hit`` held experts in rotation and fills up with
+experts the rank does not hold. Rows above it take the grouped product,
+at two routings: ``uniform`` (every token draws its k columns from all
+256 without repeat, so n / 256 of the choices are held: 640 pairs of
+1,024 x 10 over 16 held) and ``all-hit`` (every token's first choice a
+held expert in rotation, the others not held: T pairs, every expert
+hit). One JSON line per (shape, tokens, routing): the time of ONE call
+of each route, the bytes of the experts hit and of all held, and each
+route's share of 819 GB/s on the bytes it has to read (a kernel: the
+experts hit; the einsums: all held). A call's time is the two-point fit
+of tests/chip_ragged_timing.py: one jitted program chains ``n`` calls,
+each call's output the next one's tokens and its routing the one
+before's moved on a token, and (t(12) - t(4)) / 8 leaves out the
+dispatch. ``--weights-mb`` sets the kernels' budget for
+their six weight buffers, which fixes their column tile. ``--ops`` adds,
+for the grouped product, the device time of a call by XLA op (one
+profiled run of the chain of 12; each op's own time, what is nested
+inside it taken out). Not a pytest file; it refuses to run without a
+TPU.
 """
 from __future__ import annotations
 
@@ -36,17 +45,19 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-N, M, E, K, FIRST = 16, 2048, 256, 8, 16
-CHAINS = (8, 24)
+E, FIRST = 256, 16
+CHAINS = (4, 12)
 REPEATS = 5
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--hidden", default="7168,6144,4096")
-    ap.add_argument("--tokens", default="64,128,256")
-    ap.add_argument("--hit", default="16,13,10,5")
+    ap.add_argument("--shapes", default="3072x1024x16x10,2560x768x32x8,"
+                    "4096x2048x16x8,7168x2048x16x8")
+    ap.add_argument("--tokens", default="64,256,1024")
+    ap.add_argument("--hit", default="16,10")
     ap.add_argument("--weights-mb", type=int, default=None)
+    ap.add_argument("--ops", action="store_true")
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
@@ -59,15 +70,43 @@ def main(argv=None) -> int:
     from paddle_tpu.parallel import moe
     if args.weights_mb:
         expert_mlp._VMEM_WEIGHTS = args.weights_mb << 20
+    gates_of = {name: getattr(expert_mlp, name)
+                for name in ("use_expert_kernel", "use_grouped_kernel")}
+    rs = np.random.RandomState(0)
 
-    def choices(T, hit):
+    def rotation(T, K, hit):
         """ids [T, K]: exactly ``hit`` of the held experts get a token."""
         ids = np.empty((T, K), np.int32)
         for t in range(T):
             for c in range(K):
-                ids[t, c] = FIRST + (t * K + c) % hit if c < hit \
+                ids[t, c] = FIRST + (t * K + c) % hit if c < min(K, hit) \
                     else (t + c) % FIRST            # an expert not held
         return ids
+
+    def uniform(T, K):
+        return np.stack([rs.permutation(E)[:K] for _ in range(T)]
+                        ).astype(np.int32)
+
+    def all_hit(T, K, N):
+        ids = (np.arange(T)[:, None] + np.arange(K)) % FIRST
+        ids[:, 0] = FIRST + np.arange(T) % N
+        return ids.astype(np.int32)
+
+    def ops_of(prog, *a):
+        """{op: us a call} of one profiled run of the chain ``prog``."""
+        import tempfile
+        from benchmarks.harness import spans, trace
+        with tempfile.TemporaryDirectory() as d:
+            with jax.profiler.trace(d):
+                prog(*a).block_until_ready()
+            planes = trace.read_planes(trace.find_xplane(d))
+        own = {}
+        for p in planes.values():
+            for raw, _, s in spans.self_times(p["ops"]):
+                key = trace.op_key(raw)
+                own[key] = own.get(key, 0.0) + s
+        return {k: round(v * 1e6 / CHAINS[1], 1)
+                for k, v in trace.top(own, 12)}
 
     def seconds(prog, *a):
         prog(*a).block_until_ready()                        # compile
@@ -81,8 +120,8 @@ def main(argv=None) -> int:
         return best
 
     rows = []
-    rs = np.random.RandomState(0)
-    for h in (int(v) for v in args.hidden.split(",")):
+    for h, M, N, K in (tuple(int(v) for v in s.split("x"))
+                       for s in args.shapes.split(",")):
         pt.seed(0)
         layer = moe.ExpertShareMLP(h, M, E, K, FIRST, N)
         params = {k: v.astype(jnp.bfloat16)
@@ -91,21 +130,34 @@ def main(argv=None) -> int:
         for T in (int(v) for v in args.tokens.split(",")):
             x = jnp.asarray(rs.randn(T, h), jnp.bfloat16)
             gates = jnp.asarray(rs.rand(T, K) * 0.2, jnp.float32)
-            einsums = None          # their time does not read the hits
-            for hit in (int(v) for v in args.hit.split(",")):
-                ids = jnp.asarray(choices(T, hit))
-                row = {"hidden": h, "tokens": T, "hit": hit,
+            few = T <= expert_mlp.MAX_TOKENS
+            routings = [(f"hit{v}", rotation(T, K, int(v)))
+                        for v in args.hit.split(",")] if few else \
+                [("uniform", uniform(T, K)), ("all-hit", all_hit(T, K, N))]
+            einsums = None          # their time does not read the routing
+            for routing, ids in routings:
+                held = (ids >= FIRST) & (ids < FIRST + N)
+                hit = len(set(ids[held].tolist()))
+                row = {"hidden": h, "width": M, "held": N, "choices": K,
+                       "tokens": T, "routing": routing,
+                       "pairs": int(held.sum()), "hit": hit,
                        "column_tile": expert_mlp._column_tile(h, M, 2),
                        "bytes_hit": hit * expert, "bytes_held": N * expert,
                        "device_kind": jax.devices()[0].device_kind}
+                ids = jnp.asarray(ids)
                 outs = {}
                 for route, flag in (("kernel", True), ("einsums", False)):
-                    expert_mlp.use_expert_kernel = lambda *_, flag=flag: flag
+                    for name, gate in gates_of.items():
+                        setattr(expert_mlp, name, gate if flag
+                                else lambda *_: False)
 
                     def fn(params, x, ids, gates, n):
                         with layer.bound(params):
-                            for _ in range(n):
-                                x = layer.routed(x, ids, gates)
+                            for i in range(n):
+                                # its own routing a call, or XLA sorts
+                                # the choices once for the whole chain
+                                x = layer.routed(x, jnp.roll(ids, i, 0),
+                                                 jnp.roll(gates, i, 0))
                         return x
                     once, short, long = (
                         jax.jit(functools.partial(fn, n=n))
@@ -117,6 +169,8 @@ def main(argv=None) -> int:
                             / (CHAINS[1] - CHAINS[0])
                         if not flag:
                             einsums = call
+                        elif args.ops and not few:
+                            row["ops_us_a_call"] = ops_of(long, *a)
                     else:
                         call = einsums
                     need = (hit if flag else N) * expert

@@ -218,6 +218,53 @@ def test_expert_kernel_reads_the_stacked_weights_as_they_lie(
     assert {name for name, _ in stacks} <= operands
 
 
+@pytest.mark.parametrize("T,h,m,n,k", [
+    (1024, 3072, 1024, 16, 10),     # laguna-s-2.1-ep16-d9, a prompt chunk
+    (256, 2560, 768, 32, 8),        # ling-3.0-flash-ep16-d14
+    (256, 4096, 2048, 16, 8),       # mimo-v2.5-ep16-d7
+    (256, 7168, 2048, 16, 8),       # gigachat3.1-702b-ep16-d6
+    (256, 6144, 2048, 16, 12),      # longcat-flash-omni-ep32-d4
+])
+def test_grouped_product_reads_the_stacked_weights_as_they_lie(
+        one_chip, no_persistent_cache, monkeypatch, T, h, m, n, k):
+    """A prompt call's positions through ``ExpertShareMLP.routed`` at
+    the five expert configurations' widths: the held experts' part
+    compiles to ONE ``tpu_custom_call`` inside the loop over passes of
+    the sorted pairs, whose weight operands are the program's parameters
+    as the loop carries them: no copy, transpose or reshape of a stack,
+    and no product over one (the dynamic row update of the float32
+    result and the VMEM it keeps are what the interpreter cannot
+    refuse)."""
+    import re
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.parallel.moe import ExpertShareMLP
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    # the gate asks jax for its backend, which is the CPU here
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+    layer = ExpertShareMLP.__new__(ExpertShareMLP)      # shapes only
+    layer.first_expert, layer.experts_held, layer.zero_experts = 16, n, 0
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def routed(xt, ids, gates, w_gate, w_up, w_down):
+        layer.__dict__.update(w_gate=w_gate, w_up=w_up, w_down=w_down)
+        return ExpertShareMLP.routed(layer, xt, ids, gates)
+
+    text = jax.jit(routed).lower(
+        arr((T, h)), arr((T, k), jnp.int32), arr((T, k), jnp.float32),
+        arr((n, h, m)), arr((n, h, m)), arr((n, m, h))).compile().as_text()
+    stacks = re.findall(
+        rf"%(\S+) = \w+\[{n},(?:{h},{m}|{m},{h})\]\S* ([\w-]+)\(", text)
+    assert sorted(op for _, op in stacks) == \
+        ["get-tuple-element"] * 3 + ["parameter"] * 3, stacks
+    call, = re.findall(r"custom-call\(([^)]*)\), "
+                       r'custom_call_target="tpu_custom_call"', text)
+    operands = set(re.findall(r"%([\w.\-]+)", call))
+    assert {name for name, op in stacks
+            if op == "get-tuple-element"} <= operands
+
+
 _COPIES = ("reshape", "copy", "copy-start", "transpose")
 
 
@@ -418,7 +465,8 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
     latent mode and the expert kernel once each; a chunk calls the
     chunk-rule kernel twice (ISSUE 44), on operands that reach it as
     they lie: no copy or transpose of a ``[256, 32, 128]`` operand in
-    front of it or behind it."""
+    front of it or behind it, and the grouped product over its sorted
+    (position, held expert) pairs once (ISSUE 47)."""
     import re
     import json
     import os
@@ -462,8 +510,9 @@ def test_lings_tick_and_chunk_compile_for_a_v5e(one_chip,
         arr((rows, T), jnp.int32)).compile()
     text = compiled.as_text()
     calls = text.count('custom_call_target="tpu_custom_call"')
-    assert calls == (4 if program == "tick" else 2)
+    assert calls == (4 if program == "tick" else 3)
     if program == "chunk":
+        assert "grouped_expert_mlp" in text
         entry = text[text.index("ENTRY"):]
         assert not re.findall(
             r"f32\[(?:256,32,128|8192,128|256,4096)\]\S* (?:copy|transpose)\(",

@@ -1,5 +1,7 @@
 """ISSUE 33: a forward of few tokens reads only the held experts that a
-row of it chose (``ops/pallas/expert_mlp.py``, called by
+row of it chose; ISSUE 47: a forward of many multiplies only the
+(position, held expert) pairs the router chose, sorted by expert
+(``ops/pallas/expert_mlp.py``, called by
 ``parallel.moe.ExpertShareMLP.routed``), under the interpreter on the CPU.
 
 - THE SAME SUM: the kernel against ``routed``'s einsums over all held
@@ -9,12 +11,22 @@ row of it chose (``ops/pallas/expert_mlp.py``, called by
 - NOTHING OF AN IDLE EXPERT IS READ INTO THE RESULT: its weights set to
   NaN leave the output finite (the einsums multiply them by a gate of 0.0
   and would not).
-- the gate adapts on what it sees: few tokens take the kernel, a chunk's
-  256 positions keep the einsums, and so does a process without kernels.
+- the gate adapts on what it sees: few tokens take the tick's kernel, a
+  chunk's positions the grouped product, a process without kernels the
+  einsums.
+- THE GROUPED PRODUCT IS THE SAME SUM at every routing: no pair routed
+  here, every pair on one expert, uniform choice, every position on as
+  many held experts as it can choose (past the row budget of a pass:
+  nothing is dropped), beside a shared expert and zero columns; an idle
+  expert's NaN never reaches the result.
 - ``moe_experts_read`` counts the experts whose weights a forward read.
 - on a tiny engine of each family the greedy streams are the einsums',
   every tick reads exactly the experts hit, and the tick program holds
-  no product over the stacked weights.
+  no product over the stacked weights; with chunks of more positions
+  than a tick has rows the streams are still the einsums' through
+  prompts of several chunks, the engine counts every expert layer of
+  every prompt call as grouped, and neither chunk program holds a
+  product over the stacks.
 """
 import contextlib
 
@@ -43,9 +55,10 @@ def kernels(monkeypatch):
 @contextlib.contextmanager
 def gate_shut():
     """``routed`` keeps its einsums whatever it is given: the path the
-    kernel is compared with (the attention kernels still run)."""
+    kernels are compared with (the attention kernels still run)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(expert_mlp, "use_expert_kernel", lambda *_: False)
+        mp.setattr(expert_mlp, "use_grouped_kernel", lambda *_: False)
         yield
 
 
@@ -196,30 +209,38 @@ def test_the_column_tile_divides_the_width_inside_the_budget(h, m, itemsize,
 
 
 # --------------------------------------------------------------- the gate
-@pytest.mark.parametrize("T,takes", [(8, True), (64, True), (128, True),
-                                     (136, False), (256, False)])
+@pytest.mark.parametrize("T,takes", [
+    (8, "expert_share_mlp"), (64, "expert_share_mlp"),
+    (128, "expert_share_mlp"), (136, "grouped_expert_mlp"),
+    (256, "grouped_expert_mlp")])
 def test_few_tokens_take_the_kernel_and_a_chunk_the_einsums(kernels, T,
                                                             takes):
     """A tick's 64 rows (and a verify tick's 128) against a chunk call's
     256 positions: the rule reads the static token count and nothing
-    else."""
+    else. Since ISSUE 47 the chunk takes the grouped product where it
+    kept the einsums (the test keeps its name): neither holds a product
+    over the stacks."""
     layer = share(64, 32)
     x = jnp.zeros((T, 64), jnp.float32)
-    assert expert_mlp.use_expert_kernel(x, layer.w_gate) is takes
+    few = takes == "expert_share_mlp"
+    assert expert_mlp.use_expert_kernel(x, layer.w_gate) is few
+    assert expert_mlp.use_grouped_kernel(x, layer.w_gate) is not few
     ids, gates = choices(T, HITS["some"])
     jaxpr = jax.make_jaxpr(layer.routed)(x, ids, gates)
-    assert (_kernel_names(jaxpr.jaxpr) == ["expert_share_mlp"]) is takes
-    assert bool(_stacked_products(jaxpr.jaxpr, layer)) is not takes
+    assert _kernel_names(jaxpr.jaxpr) == [takes]
+    assert not _stacked_products(jaxpr.jaxpr, layer)
 
 
 def test_without_kernels_the_einsums_run(monkeypatch):
     monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     layer = share(64, 32)
-    x = jnp.zeros((8, 64), jnp.float32)
-    assert not expert_mlp.use_expert_kernel(x, layer.w_gate)
-    jaxpr = jax.make_jaxpr(layer.routed)(x, *choices(8, HITS["all"]))
-    assert not _kernel_names(jaxpr.jaxpr)
-    assert len(_stacked_products(jaxpr.jaxpr, layer)) == 3
+    for T in (8, 256):
+        x = jnp.zeros((T, 64), jnp.float32)
+        assert not expert_mlp.use_expert_kernel(x, layer.w_gate)
+        assert not expert_mlp.use_grouped_kernel(x, layer.w_gate)
+        jaxpr = jax.make_jaxpr(layer.routed)(x, *choices(T, HITS["all"]))
+        assert not _kernel_names(jaxpr.jaxpr)
+        assert len(_stacked_products(jaxpr.jaxpr, layer)) == 3
 
 
 def test_on_the_chip_the_widths_are_whole_tiles(monkeypatch):
@@ -239,6 +260,208 @@ def test_on_the_chip_the_widths_are_whole_tiles(monkeypatch):
                   w((16, 4096, 2048), jnp.bfloat16))
     assert not ok(w((256, 4096), jnp.bfloat16),
                   w((16, 4096, 2048), jnp.bfloat16))
+    many = expert_mlp.use_grouped_kernel
+    assert many(w((256, 4096), jnp.bfloat16),
+                w((16, 4096, 2048), jnp.bfloat16))
+    assert many(w((1024, 3072), jnp.bfloat16),
+                w((16, 3072, 1024), jnp.bfloat16))
+    assert not many(w((64, 4096), jnp.bfloat16),
+                    w((16, 4096, 2048), jnp.bfloat16))
+    assert not many(w((256, 4000), jnp.bfloat16),
+                    w((16, 4000, 2048), jnp.bfloat16))
+    assert not many(w((256, 4096), jnp.bfloat16),
+                    w((16, 4096, 200), jnp.bfloat16))
+    assert not many(w((252, 4096), jnp.bfloat16),
+                    w((16, 4096, 2048), jnp.bfloat16))
+
+
+# ----------------------------------------------------- the grouped product
+MANY = 136      # a pass holds 384 rows of sorted pairs: three row tiles
+ROUTINGS = ["none", "one", "uniform", "most"]
+
+
+def routing(kind, T=MANY, seed=0):
+    """ids [T, K], gates [T, K] of a forward of many tokens. ``none``: no
+    choice falls on a held expert; ``one``: every token's first choice
+    is the SAME held expert, the others not held (T pairs, two tiles of
+    one expert); ``uniform``: K of the E columns without repeat (about
+    T K HELD / E pairs over all held experts, which start anywhere in a
+    tile); ``most``: every token chooses min(K, HELD) held experts (T K
+    pairs, more than a pass's rows: a second pass)."""
+    rs = np.random.RandomState(seed)
+    gates = jnp.asarray(rs.rand(T, K) + 0.1, jnp.float32)
+    if kind == "uniform":
+        ids = np.stack([rs.permutation(E)[:K] for _ in range(T)])
+    elif kind == "most":
+        ids = FIRST + (np.arange(T)[:, None] + np.arange(K)) % HELD
+    else:
+        ids = np.asarray(UNHELD)[(np.arange(T)[:, None] + np.arange(K))
+                                 % len(UNHELD)]
+        if kind == "one":
+            ids[:, 0] = FIRST + 3
+    return jnp.asarray(ids, jnp.int32), gates
+
+
+def _pairs(ids):
+    ids = np.asarray(ids)
+    return int(((ids >= FIRST) & (ids < FIRST + HELD)).sum())
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+@pytest.mark.parametrize("h,m", [(384, 128), (256, 128)],
+                         ids=["h3m", "h2m"])
+def test_the_grouped_product_is_the_einsums_at_every_routing(kernels, h, m,
+                                                             kind):
+    """Hidden sizes 3 and 2 expert widths (Laguna's 3072 over 1024,
+    MiMo's 4096 over 2048), float32 to 1e-5 of the largest result."""
+    layer = share(h, m)
+    x = jnp.asarray(np.random.RandomState(1).randn(MANY, h), jnp.float32)
+    ids, gates = routing(kind)
+    assert expert_mlp.use_grouped_kernel(x, layer.w_gate)
+    pairs = {"none": 0, "one": MANY, "most": MANY * min(K, HELD)}
+    assert _pairs(ids) == pairs.get(kind, _pairs(ids)) and (
+        kind != "uniform" or MANY < _pairs(ids) < 2 * MANY)
+    got = np.asarray(jax.jit(layer.routed)(x, ids, gates))
+    want = by_einsums(layer, x, ids, gates)
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    if kind == "none":
+        assert not got.any()            # exactly 0: no pass ran
+    else:
+        assert np.abs(got).max() > 1.0
+
+
+@pytest.mark.parametrize("T", [256, 1024])
+def test_no_pair_is_dropped_past_the_row_budget(kernels, T):
+    """Every position on min(K, HELD) held experts is twice the rows of a
+    pass (2 T): the loop takes two passes and the sum is the einsums'.
+    With ALL of them on two experts, each expert's rows cross passes."""
+    layer = share(128, 128)
+    x = jnp.asarray(np.random.RandomState(5).randn(T, 128), jnp.float32)
+    ids, gates = routing("most", T)
+    two = jnp.where(ids % 2 == 0, FIRST, FIRST + HELD - 1)
+    for chosen in (ids, two):
+        assert _pairs(chosen) == 4 * T
+        got = np.asarray(jax.jit(layer.routed)(x, chosen, gates))
+        want = by_einsums(layer, x, chosen, gates)
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("T", [512, 600])
+def test_a_forward_of_more_positions_than_the_result_holds_goes_in_blocks(
+        kernels, monkeypatch, T):
+    """The float32 result of 256 positions is all that fits: 512 are two
+    blocks, 600 three with the last filled up by positions that chose
+    nothing; each block sorts and multiplies its own pairs."""
+    h = 128
+    monkeypatch.setattr(expert_mlp, "_VMEM_RESULT", 8 * h * 256)
+    layer = share(h, 128)
+    x = jnp.asarray(np.random.RandomState(6).randn(T, h), jnp.float32)
+    ids, gates = routing("uniform", T)
+    jaxpr = jax.make_jaxpr(layer.routed)(x, ids, gates)
+    assert _kernel_names(jaxpr.jaxpr) == ["grouped_expert_mlp"]
+    assert not _stacked_products(jaxpr.jaxpr, layer)
+    got = np.asarray(jax.jit(layer.routed)(x, ids, gates))
+    want = by_einsums(layer, x, ids, gates)
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("kind", ["uniform", "most"])
+def test_the_grouped_product_rounds_where_the_einsums_round(kernels, kind):
+    """bf16 in, bf16 out, a position's pairs summed in float32 and cast
+    once: against the float32 layer the grouped product is no farther
+    off than the einsums are."""
+    h, m = 256, 128
+    exact, layer = share(h, m), share(h, m, dtype=jnp.bfloat16)
+    x = jnp.asarray(np.random.RandomState(3).randn(MANY, h) * 0.5,
+                    jnp.bfloat16)
+    ids, gates = routing(kind)
+    for name in ("w_gate", "w_up", "w_down"):       # the rounded weights
+        setattr(exact, name, getattr(layer, name).astype(jnp.float32))
+    want = by_einsums(exact, x.astype(jnp.float32), ids, gates)
+    got = jax.jit(layer.routed)(x, ids, gates)
+    assert got.dtype == jnp.bfloat16
+    err = np.abs(np.asarray(got, np.float32) - want).max()
+    ref = np.abs(by_einsums(layer, x, ids, gates) - want).max()
+    assert err <= 1.5 * ref + 1e-3 * np.abs(want).max(), (err, ref)
+
+
+@pytest.mark.parametrize("hit", ["some", "one", "none"])
+def test_idle_experts_weights_never_reach_the_grouped_result(kernels, hit):
+    """NaN in every weight of the experts no position chose: no visit
+    names them, no block of theirs is fetched or multiplied."""
+    h, m = 256, 128
+    clean, layer = share(h, m), share(h, m)
+    idle = np.array([e not in HITS[hit] for e in range(HELD)])
+    for name in ("w_gate", "w_up", "w_down"):
+        w = np.asarray(getattr(layer, name)).copy()
+        w[idle] = np.nan
+        setattr(layer, name, jnp.asarray(w))
+    x = jnp.asarray(np.random.RandomState(2).randn(MANY, h), jnp.float32)
+    ids, gates = choices(MANY, HITS[hit])
+    got = np.asarray(jax.jit(layer.routed)(x, ids, gates))
+    assert np.isfinite(got).all()
+    want = by_einsums(clean, x, ids, gates)
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * max(np.abs(want).max(), 1.0))
+    assert np.isnan(by_einsums(layer, x, ids, gates)).any()
+
+
+@pytest.mark.parametrize("beside", ["shared", "zero", "both"])
+def test_a_shared_expert_and_zero_columns_beside_the_grouped_product(
+        kernels, beside):
+    """The whole layer's forward over 160 positions (router, grouped
+    product, identity part, shared expert) is the einsums' forward."""
+    kw = {}
+    if beside in ("shared", "both"):
+        kw["num_shared_experts"] = 1
+    if beside in ("zero", "both"):
+        kw["zero_experts"] = 4
+    layer = share(256, 128, **kw)
+    rs = np.random.RandomState(4)
+    layer.gate = jnp.asarray(rs.randn(*layer.gate.shape), jnp.float32)
+    x = jnp.asarray(rs.randn(4, 40, 256), jnp.float32)
+    jaxpr = jax.make_jaxpr(layer.__call__)(x)
+    assert _kernel_names(jaxpr.jaxpr) == ["grouped_expert_mlp"]
+    got = np.asarray(layer(x))
+    with gate_shut():
+        want = np.asarray(layer(x))
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+    ids, _ = layer.route(x.reshape(-1, 256))
+    assert (np.asarray(ids) >= FIRST).any()     # the share got tokens
+    if "zero_experts" in kw:
+        assert (np.asarray(ids) >= E).any()     # and a zero column did
+
+
+@pytest.mark.parametrize("counts,base", [
+    ([0, 0, 0, 0], 0), ([5, 0, 300, 7], 0), ([5, 0, 300, 7], 256),
+    ([128, 128, 0, 1], 0), ([700, 0, 0, 0], 512), ([1, 1, 1, 1], 0),
+    ([0, 0, 0, 256], 0), ([130, 126, 1, 255], 256)])
+def test_the_visits_cover_each_experts_rows_of_each_tile_once(counts, base):
+    """``_visits`` against a walk over the sorted pairs: every (tile,
+    expert) that shares a row, in order, with that expert's rows of the
+    tile; the idle slots repeat the last visit and hold no row."""
+    rows, tile = 256, 128
+    slots = rows // tile + len(counts) - 1
+    ends = np.cumsum(counts)
+    t, e, lo, hi, count = jax.jit(
+        lambda s, f: expert_mlp._visits(s, f, base, rows, tile, slots))(
+        jnp.asarray(ends - counts, jnp.int32), jnp.asarray(ends, jnp.int32))
+    owner = np.repeat(np.arange(len(counts)), counts)[base:base + rows]
+    want = []
+    for tile_i in range(rows // tile):
+        mine = owner[tile_i * tile:(tile_i + 1) * tile]
+        for expert in sorted(set(mine.tolist())):
+            at = np.flatnonzero(mine == expert)
+            want.append((tile_i, expert, at[0], at[-1] + 1))
+    assert int(count) == len(want) <= slots
+    got = list(zip(*(np.asarray(v).tolist() for v in (t, e, lo, hi))))
+    assert got[:len(want)] == want
+    assert all(g[2] == g[3] == 0 and (not want or g[:2] == want[-1][:2])
+               for g in got[len(want):])
 
 
 # ------------------------------------------------------------ the counter
@@ -394,3 +617,107 @@ def test_the_tick_program_holds_no_product_over_the_stacks(family, kernels,
     layers = expert_layers(model)
     assert layers and not _stacked_products(jaxpr, *layers)
     assert _kernel_names(jaxpr).count("expert_share_mlp") == len(layers)
+
+
+# ------------------------------- prompt chunks of more than a tick's rows
+def ling():
+    from paddle_tpu.models.ling_hybrid import (LingHybridForCausalLM,
+                                               ling_hybrid_tiny)
+    return LingHybridForCausalLM(ling_hybrid_tiny(experts_held=4))
+
+
+def laguna():
+    from paddle_tpu.models.laguna import LagunaForCausalLM, laguna_tiny
+    return LagunaForCausalLM(laguna_tiny(experts_held=4))
+
+
+CHUNKED = dict(FAMILIES, ling=ling, laguna=laguna)
+CHUNK = 136         # positions a prompt call: more than a tick's rows
+PROMPTS = (150, 9, 200, 137)        # two chunks, a packed one, two, two
+
+
+def chunked_engine(model):
+    return PagedEngine(model, max_slots=4, num_blocks=128, block_size=8,
+                       max_blocks_per_seq=28, chunk_prefill_tokens=CHUNK)
+
+
+def serve_chunks(model):
+    eng = chunked_engine(model)
+    rng = np.random.default_rng(11)
+    for i, n in enumerate(PROMPTS):
+        eng.submit(i, rng.integers(1, 200, n).tolist(), max_new_tokens=5)
+    while eng.queue or any(s is not None for s in eng.slots):
+        eng.step()
+    return [eng.results[i] for i in range(4)], \
+        [eng.logprobs[i] for i in range(4)], dict(eng.stats)
+
+
+@pytest.fixture(scope="module", params=list(CHUNKED))
+def chunked(request):
+    """(model, its served streams with the einsums in every program)."""
+    pt.seed(0)
+    model = CHUNKED[request.param]()
+    with pytest.MonkeyPatch.context() as mp, gate_shut():
+        mp.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        served = serve_chunks(model)
+    return model, served
+
+
+def test_streams_through_several_chunks_are_the_einsums_streams(chunked,
+                                                                kernels):
+    model, (tokens, lps, before) = chunked
+    got, got_lps, stats = serve_chunks(model)
+    assert got == tokens
+    for a, b in zip(got_lps, lps):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+    # every expert layer of every prompt call took the grouped product
+    layers = len(expert_layers(model))
+    calls = stats["prefill_chunks"]
+    assert calls >= 5 and stats["prefill_segments"] >= 7
+    assert stats["chunk_experts_layer_calls"] == layers * calls
+    assert stats["chunk_experts_grouped_calls"] == layers * calls
+    assert before["chunk_experts_layer_calls"] == layers * calls
+    assert before["chunk_experts_grouped_calls"] == 0
+
+
+def _chunk_args(eng, program):
+    from paddle_tpu.generation import paged
+    if program == "_chunk_prefill_packed":
+        words = 3 * CHUNK + eng._pack_segments * (eng.M + paged._SEG_WORDS)
+        return (eng.params, eng.pools, eng.seen,
+                jnp.zeros((words,), jnp.int32)), {}
+    return ((eng.params, eng.pools, jnp.zeros((eng.M,), jnp.int32),
+             jnp.zeros((1, CHUNK), jnp.int32), np.int32(0),
+             np.int32(CHUNK), jnp.zeros((2,), jnp.uint32), np.float32(0.8),
+             np.int32(20), np.float32(0.95), np.float32(1.1), eng.seen[0],
+             np.int32(0)),
+            {"bucket": CHUNK})
+
+
+@pytest.mark.parametrize("program", ["_chunk_prefill",
+                                     "_chunk_prefill_packed"])
+def test_the_chunk_programs_hold_no_product_over_the_stacks(chunked,
+                                                            kernels,
+                                                            program):
+    model, _ = chunked
+    eng = chunked_engine(model)
+    fn = getattr(eng, program)
+    args, kw = _chunk_args(eng, program)
+    jaxpr = jax.jit(lambda *a: fn(*a, **kw)).trace(*args).jaxpr.jaxpr
+    layers = expert_layers(model)
+    assert layers and not _stacked_products(jaxpr, *layers)
+    assert _kernel_names(jaxpr).count("grouped_expert_mlp") == len(layers)
+    assert "expert_share_mlp" not in _kernel_names(jaxpr)
+    with gate_shut():
+        jaxpr = jax.jit(lambda *a: fn(*a, **kw)).trace(*args).jaxpr.jaxpr
+    assert len(_stacked_products(jaxpr, *layers)) == 3 * len(layers)
+
+
+def test_an_engine_without_expert_layers_names_no_expert_counter():
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+    pt.seed(0)
+    eng = PagedEngine(LlamaForCausalLM(llama_tiny()), max_slots=2,
+                      num_blocks=16, block_size=8, max_blocks_per_seq=4,
+                      chunk_prefill_tokens=16)
+    assert "chunk_experts_layer_calls" not in eng.stats
+    assert "chunk_experts_grouped_calls" not in eng.stats

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Digests of the serving programs' lowered text, a tiny engine a family.
 
-    python tools/program_text.py [checkout] > a.txt
+    python tools/program_text.py [checkout [chunk]] > a.txt
     python tools/program_text.py <other checkout> > b.txt ; diff a.txt b.txt
 
 For each model family served through ``PagedEngine`` a tiny engine's
@@ -12,7 +12,11 @@ is printed: its name, a digest of its StableHLO text, the text's length.
 Two checkouts that print the same lines hand XLA the same programs for
 those families: the check a PR makes that adds a family or a mechanism
 beside them (PR 33, PR 38, PR 41). A family the checkout lacks is left out, so
-the older checkout's lines are a subset.
+the older checkout's lines are a subset. ``chunk`` (16 unless given)
+is the positions of a prompt call: above a tick's 128 rows, and with
+``PADDLE_TPU_PALLAS_INTERPRET=1`` so that the kernels are taken, the
+expert families' prompt calls are the ones whose path differs from a
+tick's (PR 47).
 """
 import hashlib
 import os
@@ -46,8 +50,10 @@ FAMILIES = {
     "laguna": ("LagunaForCausalLM", "laguna_tiny", dict(experts_held=4),
                True),
 }
-GEOMETRY = dict(max_slots=4, num_blocks=64, block_size=4,
-                max_blocks_per_seq=16, chunk_prefill_tokens=16)
+CHUNK = int(sys.argv[2]) if __name__ == "__main__" and len(sys.argv) > 2 \
+    else 16
+GEOMETRY = dict(max_slots=4, num_blocks=4 * CHUNK, block_size=4,
+                max_blocks_per_seq=CHUNK, chunk_prefill_tokens=CHUNK)
 
 
 def programs(model, spec: int):
@@ -61,7 +67,7 @@ def programs(model, spec: int):
         return
     yield "tick", eng._tick_jit.lower(*state)
     yield "tick_greedy", eng._tick_greedy_jit.lower(*state)
-    eng.submit(0, list(range(1, 30)), max_new_tokens=4)
+    eng.submit(0, list(range(1, CHUNK + 14)), max_new_tokens=4)
     eng._try_admit()
     call, _ = eng._pack_call([0])
     yield "packed", eng._chunk_jit.packed.lower(
@@ -69,9 +75,10 @@ def programs(model, spec: int):
     row, key = eng._put(eng.block_tables[0]), eng._put(eng.slots[0].key)
     sampling = (np.float32(0), np.int32(0), np.float32(1), np.float32(1))
     yield "alone", eng._chunk_jit.alone.lower(
-        eng.params, eng.pools, row, eng._put(np.zeros((1, 16), np.int32)),
-        np.int32(16), np.int32(29), key, *sampling, eng.seen[0],
-        np.int32(0), bucket=16)
+        eng.params, eng.pools, row,
+        eng._put(np.zeros((1, CHUNK), np.int32)), np.int32(CHUNK),
+        np.int32(CHUNK + 13), key, *sampling, eng.seen[0], np.int32(0),
+        bucket=CHUNK)
     yield "prefill", eng._prefill_jit.lower(
         eng.params, eng.pools, row, eng._put(np.zeros((1, 32), np.int32)),
         np.int32(29), key, *sampling, np.int32(0), bucket=32)

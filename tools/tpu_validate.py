@@ -9,7 +9,8 @@ only ever sees through the interpreter once through the real compiler
 on whatever backend jax selects, each against its XLA reference: the
 flash kernel's segment / window / backward variants, ``quant_matmul``,
 the decode re-block, the ragged paged-attention kernel (single- and
-multi-query, K/V and latent pools), the delta rule's state step and
+multi-query, K/V and latent pools), the held experts' two kernels (a
+tick's rows, a prompt call's sorted pairs), the delta rule's state step and
 chunk form at a decay a head and a decay a channel (the channel's chunk
 kernel beside its fusions), and the tick / patch
 / restore programs of the serving engine. ``chip_smoke.py`` covers the default serving route
@@ -248,6 +249,45 @@ def expert_share_kernel():
                                 - ref.astype(jnp.float32))))
     assert got.shape == (64, h2) and err < 2e-2 * scale, (err, scale)
 check("expert_share_kernel", expert_share_kernel)
+
+def grouped_expert_kernel():
+    # a prompt call's positions through ExpertShareMLP.routed (one
+    # rank's 16 experts of width 1024 at Laguna-S-2.1's hidden size,
+    # 1,024 positions x 10 choices): the grouped product over the
+    # sorted (position, held expert) pairs against the einsums, at a
+    # uniform choice of the 256 columns (about 640 pairs, one pass) and
+    # with every position on 10 held experts (10,240 pairs, five passes)
+    import paddle_tpu as pt
+    from paddle_tpu.ops.pallas import expert_mlp
+    from paddle_tpu.parallel import moe
+    pt.seed(0)
+    h2, m2, held, K, T = 3072, 1024, 16, 10, 1024
+    layer = moe.ExpertShareMLP(h2, m2, 256, K, 16, held)
+    params = {k: v.astype(jnp.bfloat16) for k, v in layer.named_parameters()}
+    x = jnp.asarray(rs.randn(T, h2), jnp.bfloat16)
+    gates = jnp.asarray(rs.rand(T, K) * 0.25, jnp.float32)
+
+    def routed(params, x, ids, gates):
+        with layer.bound(params):
+            return layer.routed(x, ids, gates)
+    assert dev.platform != "tpu" \
+        or expert_mlp.use_grouped_kernel(x, params["w_gate"])
+    uniform = np.stack([rs.permutation(256)[:K] for _ in range(T)])
+    most = 16 + (np.arange(T)[:, None] + np.arange(K)) %% held
+    for ids in (uniform, most):
+        ids = jnp.asarray(ids, jnp.int32)
+        got = jax.jit(routed)(params, x, ids, gates)
+        gate = expert_mlp.use_grouped_kernel
+        expert_mlp.use_grouped_kernel = lambda *_: False
+        try:
+            ref = jax.jit(routed)(params, x, ids, gates)
+        finally:
+            expert_mlp.use_grouped_kernel = gate
+        scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                    - ref.astype(jnp.float32))))
+        assert got.shape == (T, h2) and err < 2e-2 * scale, (err, scale)
+check("grouped_expert_kernel", grouped_expert_kernel)
 
 def unequal_head_paged_kernel():
     # the ragged kernel at MiMo-V2's two layer kinds (64 query heads,
