@@ -123,7 +123,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.paged_cache import (CacheLayer, PagedKV, SlotState, StateLayer,
-                               chunk_attention_positions,
+                               chunk_attention_positions, chunk_attn_route,
                                chunk_experts_route, chunk_rule_route,
                                paged_decode_route, state_step_route)
 from ..parallel.moe import ExpertShareMLP
@@ -226,9 +226,13 @@ _CHUNK_EXPERTS_COUNTERS = ("chunk_experts_layer_calls",
 # and, as it dispatches a prompt chunk that has cached context behind
 # it, over the call's K/V layers: the positions the chunk's attention
 # scored (whole runs of pages) and those of them the row's table held
-# live (``ops.paged_cache.chunk_attention_positions``)
+# live (``ops.paged_cache.chunk_attention_positions``); and those K/V
+# layers, with how many of them took the kernel over tiles of the
+# chunk's queries (``chunk_attn_route``)
 _CHUNK_ATTN_COUNTERS = ("chunk_attn_positions_scored",
-                        "chunk_attn_positions_live")
+                        "chunk_attn_positions_live",
+                        "chunk_attn_layer_calls",
+                        "chunk_attn_kernel_calls")
 
 
 # what a band-keeping engine adds up inside a tick, over the live rows
@@ -663,6 +667,9 @@ class PagedEngine:
             getattr(model, "named_sublayers", tuple)()
             if isinstance(layer, ExpertShareMLP)]
         self._n_experts_grouped: Dict[int, int] = {}
+        # the path each K/V layer's continuation chunk takes, asked at
+        # the first such call's dispatch (``chunk_attn_routes``)
+        self._chunk_attn_routes: Optional[list] = None
         # automatic prefix caching (reference: PaddleNLP CacheKV prefix
         # sharing / vLLM APC): requests whose prompts share a prefix
         # point their block tables at the SAME physical blocks and skip
@@ -1197,20 +1204,47 @@ class PagedEngine:
         self._count("chunk_experts_grouped_calls",
                     self._n_experts_grouped[positions])
 
-    def _count_chunk_attention(self, cached: int):
-        """``_CHUNK_ATTN_COUNTERS`` of one ``_chunk_jit.alone`` call
-        whose row holds ``cached`` tokens with the chunk's own in."""
-        scored = live = 0
-        for layer in self._layout:
+    def chunk_attn_routes(self) -> list:
+        """The path each cache layer's continuation chunk takes
+        (``chunk_attn_route`` asked as the traced program asks it: the
+        chunk's own q, the layer's query heads over its kv heads, and
+        the layer's K pool); None for a layer that is not walked: state,
+        or a latent row, which is expanded."""
+        cfg = self.model.config     # (a stub's may name no heads)
+        routes = []
+        for layer, pool in zip(self._layout, self.pools):
             if len(layer.rows) != 2:
+                routes.append(None)
+                continue
+            heads, width = layer.rows[0]
+            h = layer.heads or getattr(cfg, "num_attention_heads", heads)
+            q = jax.ShapeDtypeStruct((1, self.chunk, h, width),
+                                     pool[0].dtype)
+            routes.append(chunk_attn_route(q, pool[0], heads))
+        return routes
+
+    def _count_chunk_attention(self, start: int, cached: int):
+        """``_CHUNK_ATTN_COUNTERS`` of one ``_chunk_jit.alone`` call of
+        a chunk from position ``start`` whose row holds ``cached``
+        tokens with the chunk's own in."""
+        if self._chunk_attn_routes is None:
+            self._chunk_attn_routes = self.chunk_attn_routes()
+        scored = live = calls = kernel = 0
+        for layer, route in zip(self._layout, self._chunk_attn_routes):
+            if route is None:
                 continue    # state, or a latent row (expanded, not walked)
             M = self.M if layer.window is None \
                 else self._ring_blocks(layer.window)
-            s, n = chunk_attention_positions(cached, M, self.B,
-                                             layer.window is not None)
+            s, n = chunk_attention_positions(
+                cached, M, self.B, layer.window is not None,
+                tiles=(start, self.chunk) if route == "kernel" else None,
+                window=layer.window)
             scored, live = scored + s, live + n
+            calls, kernel = calls + 1, kernel + (route == "kernel")
         self._count("chunk_attn_positions_scored", scored)
         self._count("chunk_attn_positions_live", live)
+        self._count("chunk_attn_layer_calls", calls)
+        self._count("chunk_attn_kernel_calls", kernel)
 
     # ------------------------------------------------------------ jitted
     def _paged_caches(self, call, pools, tables, lens, slots=None,
@@ -2766,7 +2800,7 @@ class PagedEngine:
             self._count("prefill_segments")
             self._count_chunk_rule()
             self._count_chunk_experts(self.chunk)
-            self._count_chunk_attention(start + live)
+            self._count_chunk_attention(start, start + live)
             # mid chunks keep the ids-only mask; the final chunk's
             # committed sample rides in seen_fin (mirrors the PRNG-key
             # protocol)

@@ -344,10 +344,12 @@ class LagunaForCausalLM(CausalLMBase):
     def paged_cache_layers(self):
         """What ``PagedEngine`` caches a token in EACH layer
         (``ops.paged_cache.CacheLayer``): K and V of the shared kv
-        heads, and the window of a layer that keeps its band only."""
+        heads, the window of a layer that keeps its band only, and the
+        layer's own number of query heads."""
         cfg = self.config
         row = (cfg.num_key_value_heads, cfg.head_dim)
-        return [CacheLayer((row, row), layer.self_attn.window)
+        return [CacheLayer((row, row), layer.self_attn.window,
+                           layer.self_attn.heads)
                 for layer in self.model.layers]
 
     def tick_counters(self):

@@ -32,7 +32,8 @@ __all__ = ["CacheLayer", "StateLayer", "PagedKV", "SlotState",
            "paged_packed_attention", "paged_decode_attention",
            "paged_decode_attention_dense", "paged_latent_attention",
            "paged_latent_attention_dense", "paged_decode_route",
-           "state_step_route", "chunk_rule_route", "chunk_experts_route"]
+           "state_step_route", "chunk_rule_route", "chunk_experts_route",
+           "chunk_attn_route", "paged_chunk_attention_walk"]
 
 
 class CacheLayer(NamedTuple):
@@ -41,9 +42,12 @@ class CacheLayer(NamedTuple):
     array of a cached token (K and V, whose widths may differ; or one
     latent row), and ``window``: None for a layer that keeps every
     block of a sequence, else the sliding window of a layer that keeps
-    only the band its queries still reach (``PagedKV.ring``)."""
+    only the band its queries still reach (``PagedKV.ring``).
+    ``heads``: the query heads that attend over it, where a model's
+    layers differ (None: the configuration's ``num_attention_heads``)."""
     rows: tuple
     window: Optional[int] = None
+    heads: Optional[int] = None
 
 
 class StateLayer(NamedTuple):
@@ -297,15 +301,42 @@ CHUNK_RUN_PAGES = 32
 
 
 def chunk_attention_positions(cached: int, table_blocks: int,
-                              block_size: int, ring: bool = False):
+                              block_size: int, ring: bool = False,
+                              tiles=None, window: Optional[int] = None):
     """(positions scored, positions live) of ONE layer's
     ``paged_chunk_attention`` call whose row holds ``cached`` tokens once
     the chunk's own are written, over a table of ``table_blocks`` pages:
-    the host's arithmetic of the loop below (plain ints), for the
-    engine's counters. A whole-context layer scores the runs of pages up
-    to the one that holds the row's last token; a band-keeping layer
-    (``ring``) every run of its ring. Live is what of the row the table
-    holds."""
+    the host's arithmetic of the paths below (plain ints), for the
+    engine's counters.
+
+    The walk (``tiles`` None): a whole-context layer scores the runs of
+    pages up to the one that holds the row's last token; a band-keeping
+    layer (``ring``) every run of its ring. Live is what of the row the
+    table holds.
+
+    The kernel (``tiles`` = (the chunk's first position, its length),
+    ``window`` the layer's): every tile of the chunk's queries scores
+    the compute blocks from the one its first query's window starts in
+    to the one that holds its last query, and what is live to it is the
+    span of positions its queries see; both are the mean over the
+    chunk's tiles (the key positions a query of the chunk was scored
+    against, as the walk's are)."""
+    if tiles is not None:
+        from .pallas.ragged_paged_attention import chunk_tiling
+        first, s = tiles
+        tq, pps = chunk_tiling(s, block_size, table_blocks, window)
+        n, scored, live = -(-s // tq), 0, 0
+        for at in range(first, first + n * tq, tq):
+            if at >= cached:    # a tile of pads walks nothing
+                continue
+            lo = 0 if window is None else max(at - window + 1, 0)
+            hi = -(-min(at + tq, cached) // block_size)
+            if not ring:
+                hi = min(hi, table_blocks)
+            blocks = -(-(hi - lo // block_size) // pps)
+            scored += blocks * pps * block_size
+            live += max(min(at + tq, cached, hi * block_size) - lo, 0)
+        return scored // n, live // n
     run = min(table_blocks, CHUNK_RUN_PAGES)
     runs = -(-table_blocks // run)
     live = min(cached, table_blocks * block_size)
@@ -318,10 +349,36 @@ def chunk_attention_positions(cached: int, table_blocks: int,
 def paged_chunk_attention(q, pk: PagedKV, positions,
                           window: Optional[int] = None, sink=None):
     """Chunked-prefill attention: q [1, s, h, d] chunk queries at global
-    positions [1, s] attend over row 0's blocks: the previously cached
-    chunks AND (causally) this chunk's own tokens, which
-    ``paged_prefill_write`` scattered in just before. An online softmax
-    over RUNS of ``CHUNK_RUN_PAGES`` pages: each step gathers one run of
+    positions [1, s] (``positions[0, 0]`` onwards, in order) attend over
+    row 0's blocks: the previously cached chunks AND (causally) this
+    chunk's own tokens, which ``paged_prefill_write`` scattered in just
+    before. Stale or never-written table positions sit beyond every
+    query's position (or in unallocated garbage-block slots) and are
+    masked by the causal compare. ``sink`` [h] (see ``dense_attention``)
+    is the softmax's start. One compiled program whatever is live.
+
+    Where ``chunk_attn_route`` says "kernel", ONE Pallas kernel a layer
+    (``ops.pallas.ragged_paged_attention.chunk_paged_attention_pallas``):
+    tiles of the chunk's queries, each over the key blocks it can see,
+    the scores in VMEM. Else ``paged_chunk_attention_walk``. What
+    either lays out around itself (the walk's transposes, the kernel's
+    one copy of q) is inside the scope."""
+    with jax.named_scope(_window_scope("chunk_attn", pk.ring)):
+        if chunk_attn_route(q, pk.kp, pk.heads) == "walk":
+            return paged_chunk_attention_walk(q, pk, positions, window,
+                                              sink)
+        from .pallas.ragged_paged_attention import \
+            chunk_paged_attention_pallas
+        return chunk_paged_attention_pallas(
+            q[0], pk.kp, pk.vp, pk.block_tables[0], positions[0, 0],
+            pk.seq_lens[0], pk.heads, window=window, sink=sink,
+            ring=pk.ring)[None]
+
+
+def paged_chunk_attention_walk(q, pk: PagedKV, positions,
+                               window: Optional[int] = None, sink=None):
+    """``paged_chunk_attention`` by an online softmax over RUNS of
+    ``CHUNK_RUN_PAGES`` pages, in XLA ops: each step gathers one run of
     the row's table, scores it ([h, s, run] float32, never the slot's
     length) and folds it into the running maximum, denominator and
     value sum, so the work follows the context that is LIVE behind the
@@ -329,65 +386,63 @@ def paged_chunk_attention(q, pk: PagedKV, positions,
     (``pk.seq_lens[0]``) and, under a ``window`` over a whole table,
     starts at the first run the chunk's first query still reaches. A
     band-keeping layer (``pk.ring``) walks its ring, the band behind the
-    chunk and the chunk, not the row's whole table. Stale or
-    never-written table positions sit beyond every query's position (or
-    in unallocated garbage-block slots) and are masked by the causal
-    compare. ``sink`` [h] (see ``dense_attention``) is the softmax's
-    start. One compiled program whatever is live."""
-    with jax.named_scope(_window_scope("chunk_attn", pk.ring)):
-        B, M = pk.block_size, pk.block_tables.shape[1]
-        P = min(M, CHUNK_RUN_PAGES)
-        runs, T = -(-M // P), P * B
-        s, h = q.shape[1], q.shape[2]
-        g = h // pk.heads
-        scale = q.shape[-1] ** -0.5
-        table = jnp.pad(pk.block_tables[0], (0, runs * P - M))
-        kpos = _table_positions(pk, pk.seq_lens[:1] - 1)[0] if pk.ring \
-            else jnp.arange(M * B)
-        kpos = jnp.pad(kpos, (0, runs * T - M * B), constant_values=-1)
-        qpos = positions[0][:, None]                        # [s, 1]
-        qg = jnp.moveaxis(q[0].reshape(s, pk.heads, g, -1), 0, 2)
+    chunk and the chunk, not the row's whole table. It is the path where
+    the kernel does not serve (the CPU, key heads of 192 columns) and
+    the reference a test or a timing script pins the kernel against, by
+    calling it (inside the caller's scope)."""
+    B, M = pk.block_size, pk.block_tables.shape[1]
+    P = min(M, CHUNK_RUN_PAGES)
+    runs, T = -(-M // P), P * B
+    s, h = q.shape[1], q.shape[2]
+    g = h // pk.heads
+    scale = q.shape[-1] ** -0.5
+    table = jnp.pad(pk.block_tables[0], (0, runs * P - M))
+    kpos = _table_positions(pk, pk.seq_lens[:1] - 1)[0] if pk.ring \
+        else jnp.arange(M * B)
+    kpos = jnp.pad(kpos, (0, runs * T - M * B), constant_values=-1)
+    qpos = positions[0][:, None]                        # [s, 1]
+    qg = jnp.moveaxis(q[0].reshape(s, pk.heads, g, -1), 0, 2)
 
-        def run(j, carry):
-            m, l, acc = carry
-            pages = jax.lax.dynamic_slice_in_dim(table, j * P, P)
-            ks = pk.split(pk.kp[pages].reshape(T, -1))      # [T, kvh, d]
-            vs = pk.split(pk.vp[pages].reshape(T, -1))
-            at = jax.lax.dynamic_slice_in_dim(kpos, j * T, T)[None, :]
-            keep = (at <= qpos) & (at >= 0)                 # [s, T]
-            if window is not None:
-                keep &= qpos - at < window
-            sc = jnp.einsum("kgsd,tkd->kgst", qg, ks).astype(jnp.float32) \
-                * scale
-            sc = jnp.where(keep, sc, _MASKED)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
-            p = jnp.where(keep, jnp.exp(sc - m_new[..., None]), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "kgst,tkd->kgsd", p.astype(q.dtype), vs,
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
+    def run(j, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, j * P, P)
+        ks = pk.split(pk.kp[pages].reshape(T, -1))      # [T, kvh, d]
+        vs = pk.split(pk.vp[pages].reshape(T, -1))
+        at = jax.lax.dynamic_slice_in_dim(kpos, j * T, T)[None, :]
+        keep = (at <= qpos) & (at >= 0)                 # [s, T]
+        if window is not None:
+            keep &= qpos - at < window
+        sc = jnp.einsum("kgsd,tkd->kgst", qg, ks).astype(jnp.float32) \
+            * scale
+        sc = jnp.where(keep, sc, _MASKED)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        p = jnp.where(keep, jnp.exp(sc - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "kgst,tkd->kgsd", p.astype(q.dtype), vs,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
 
-        shape = (pk.heads, g, s)
-        if sink is None:
-            m0 = jnp.full(shape, _MASKED, jnp.float32)
-            l0 = jnp.zeros(shape, jnp.float32)
-        else:       # one more key of that score whose value is zero
-            m0 = jnp.broadcast_to(
-                sink.astype(jnp.float32).reshape(pk.heads, g, 1), shape)
-            l0 = jnp.ones(shape, jnp.float32)
-        acc0 = jnp.zeros(shape + (pk.vp.shape[2] // pk.heads,), jnp.float32)
-        if pk.ring:
-            lo, hi = 0, runs
-        else:
-            hi = jnp.minimum(-(-pk.seq_lens[0] // T), runs)
-            lo = 0 if window is None else \
-                jnp.clip(positions[0, 0] - window + 1, 0) // T
-        _, l, acc = jax.lax.fori_loop(lo, hi, run, (m0, l0, acc0))
-        # a query with no key to see (a pad past the band) reads zero
-        out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return jnp.moveaxis(out, 2, 0).reshape(1, s, h, -1).astype(q.dtype)
+    shape = (pk.heads, g, s)
+    if sink is None:
+        m0 = jnp.full(shape, _MASKED, jnp.float32)
+        l0 = jnp.zeros(shape, jnp.float32)
+    else:       # one more key of that score whose value is zero
+        m0 = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(pk.heads, g, 1), shape)
+        l0 = jnp.ones(shape, jnp.float32)
+    acc0 = jnp.zeros(shape + (pk.vp.shape[2] // pk.heads,), jnp.float32)
+    if pk.ring:
+        lo, hi = 0, runs
+    else:
+        hi = jnp.minimum(-(-pk.seq_lens[0] // T), runs)
+        lo = 0 if window is None else \
+            jnp.clip(positions[0, 0] - window + 1, 0) // T
+    _, l, acc = jax.lax.fori_loop(lo, hi, run, (m0, l0, acc0))
+    # a query with no key to see (a pad past the band) reads zero
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.moveaxis(out, 2, 0).reshape(1, s, h, -1).astype(q.dtype)
 
 
 @functools.partial(jax.jit, inline=True,
@@ -425,6 +480,20 @@ def paged_decode_route(q, kp, kv_heads: int) -> str:
     used to serve that one geometry is gone (no model or cell has it)."""
     from .pallas.ragged_paged_attention import use_ragged_kernel
     return "ragged" if use_ragged_kernel(q, kp, kv_heads) else "dense"
+
+
+def chunk_attn_route(q, kp, kv_heads: int) -> str:
+    """Which path ``paged_chunk_attention`` takes for a prompt chunk's
+    queries q [1, s, h, d] against pools shaped like ``kp`` [P, B,
+    kv_heads*d]: ``"kernel"`` (the Pallas kernel over tiles of the
+    queries, each walking the pages it can see) or ``"walk"`` (the
+    online softmax over runs of pages in XLA ops).
+    ``paged_decode_route``'s sibling: shapes, dtype and the platform
+    decide (``use_chunk_kernel`` is the one gate), so the engine can ask
+    with its own geometry and count the choice the traced program
+    made."""
+    from .pallas.ragged_paged_attention import use_chunk_kernel
+    return "kernel" if use_chunk_kernel(q, kp, kv_heads) else "walk"
 
 
 def state_step_route(S) -> str:

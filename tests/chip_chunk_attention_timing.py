@@ -12,11 +12,15 @@
 2. A PROMPT CHUNK'S ATTENTION by the path ISSUE 46 replaced (row 0's
    WHOLE table gathered, one dense attention under the position mask:
    kept here as the yardstick) and by the walk over live runs of pages
-   (``paged_chunk_attention``), at Laguna's slot (48 and 72 heads, a
+   (``paged_chunk_attention_walk``) and, where ``chunk_attn_route``
+   says so, by the kernel over tiles of the chunk's queries (ISSUE 48:
+   ``paged_chunk_attention``), at Laguna's slot (48 and 72 heads, a
    chunk of 1,024, 448 pages / a ring of 97), MiMo-V2's (64 heads, keys
    192 / values 128, a chunk of 256, 128 pages / a ring of 25 with a
-   sink), Qwen2-7B's (28 heads over 4) and Olmo-Hybrid's (30 over 30),
-   at several live lengths: each pair compared, then timed.
+   sink: the walk alone), Qwen2-7B's (28 heads over 4) and
+   Olmo-Hybrid's (30 over 30), at several live lengths: the walk and the
+   kernel each compared with the gather (and the kernel with the walk),
+   then timed. The gate's shape rule is read from this table.
 
 One JSON line a case on stdout and all of them in
 ``chiprun_out/chunk_attention_timing.json``. Not a pytest file; it
@@ -133,7 +137,9 @@ def chunk_cases(emit):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.ops.paged_cache import PagedKV, paged_chunk_attention
+    from paddle_tpu.ops.paged_cache import (PagedKV, chunk_attn_route,
+                                            paged_chunk_attention,
+                                            paged_chunk_attention_walk)
     rng = np.random.default_rng(47)
     gather = dense_gather()
     for (name, h, kvh, dk, dv, chunk, M, window, ring, has_sink,
@@ -147,8 +153,11 @@ def chunk_cases(emit):
         q = jnp.asarray(rng.normal(size=(1, chunk, h, dk)), jnp.bfloat16)
         sink = jnp.asarray(rng.normal(size=(h,)), jnp.float32) \
             if has_sink else None
-        walk = jax.jit(lambda q, pk, pos, sink: paged_chunk_attention(
-            q, pk, pos, window=window, sink=sink))
+        walk = jax.jit(lambda q, pk, pos, sink: paged_chunk_attention_walk(
+            q, pk, pos, window, sink))
+        kernel = jax.jit(lambda q, pk, pos, sink: paged_chunk_attention(
+            q, pk, pos, window=window, sink=sink)) \
+            if chunk_attn_route(q, kp, kvh) == "kernel" else None
         dense = jax.jit(lambda q, pk, pos, sink: gather(
             q, pk, pos, window=window, sink=sink))
         for live in lives:
@@ -165,11 +174,21 @@ def chunk_cases(emit):
                    "max_abs": float(np.abs(want).max()),
                    "dense_ms": 1e3 * timed(dense, q, pk, pos, sink),
                    "walk_ms": 1e3 * timed(walk, q, pk, pos, sink)}
+            if kernel is not None:
+                out = np.asarray(kernel(q, pk, pos, sink), np.float32)
+                row["kernel_ms"] = 1e3 * timed(kernel, q, pk, pos, sink)
+                row["kernel_err_vs_walk"] = float(np.abs(out - got).max())
+                row["kernel_err_vs_dense"] = float(np.abs(out - want).max())
+                assert row["kernel_err_vs_dense"] \
+                    < 2e-2 * max(row["max_abs"], 1), row
             if pairs:
                 row["flops_floor_ms"] = 1e3 * pairs * h * 2 * (dk + dv) \
                     / 197e12
                 row["walk_flops_pct"] = 100 * row["flops_floor_ms"] \
                     / row["walk_ms"]
+                if kernel is not None:
+                    row["kernel_flops_pct"] = 100 * row["flops_floor_ms"] \
+                        / row["kernel_ms"]
             # bf16 probabilities and values: the dense path rounds its
             # normalised probabilities, the walk its unnormalised ones
             assert row["max_abs_err"] < 2e-2 * max(row["max_abs"], 1), row

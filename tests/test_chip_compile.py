@@ -133,14 +133,16 @@ def test_the_chunk_walk_compiles_for_a_v5e_with_no_slot_long_score(
     float32 array with the slot's length behind the chunk's queries: the
     widest score is [kv heads, group, chunk, one run of 32 pages]."""
     from paddle_tpu.ops.paged_cache import (CHUNK_RUN_PAGES, PagedKV,
-                                            paged_chunk_attention)
+                                            _window_scope,
+                                            paged_chunk_attention_walk)
 
     def arr(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def attend(q, kp, vp, tbl, lens, pos, sink):
         pk = PagedKV(kp, vp, tbl, lens, kvh, ring, "chunk")
-        return paged_chunk_attention(q, pk, pos, window=window, sink=sink)
+        with jax.named_scope(_window_scope("chunk_attn", ring)):
+            return paged_chunk_attention_walk(q, pk, pos, window, sink)
 
     text = jax.jit(attend).lower(
         arr((1, chunk, h, dk)), arr((P, 16, kvh * dk)),
@@ -152,6 +154,50 @@ def test_the_chunk_walk_compiles_for_a_v5e_with_no_slot_long_score(
     assert "while(" in text or ring
     if M * 16 > run:
         assert f",{M * 16}]" not in text.replace("s32[", "")
+
+
+@pytest.mark.parametrize("M,P,h,kvh,chunk,window,ring", [
+    (448, 28673, 48, 8, 1024, None, False),     # laguna, a full layer
+    (97, 6209, 72, 8, 1024, 512, True),         # a window layer, its ring
+    (128, 2049, 28, 4, 256, None, False),       # qwen2-7b
+    (128, 2049, 30, 30, 256, None, False),      # olmo-hybrid, groups of 1
+])
+def test_the_chunk_kernel_compiles_for_a_v5e_with_no_score_in_hbm(
+        one_chip, no_persistent_cache, monkeypatch, M, P, h, kvh, chunk,
+        window, ring):
+    """ISSUE 48: where the route is "kernel" a prompt chunk's attention
+    at the cells' shapes (heads of 128 columns, pages of 16) is ONE
+    Mosaic call a layer: no loop of XLA ops, no float32 array with the
+    chunk's queries behind the heads, no copy of a pool."""
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.ops.paged_cache import (PagedKV, chunk_attn_route,
+                                            paged_chunk_attention)
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    # the gate asks jax for its backend, which is the CPU here
+    monkeypatch.setattr(pallas, "tpu_backend", lambda: True)
+    paged_chunk_attention.clear_cache()     # traced for the walk above
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(q, kp, vp, tbl, lens, pos):
+        pk = PagedKV(kp, vp, tbl, lens, kvh, ring, "chunk")
+        assert chunk_attn_route(q, kp, kvh) == "kernel"
+        return paged_chunk_attention(q, pk, pos, window=window)
+
+    try:
+        text = jax.jit(attend).lower(
+            arr((1, chunk, h, 128)), arr((P, 16, kvh * 128)),
+            arr((P, 16, kvh * 128)), arr((1, M), jnp.int32),
+            arr((1,), jnp.int32), arr((1, chunk), jnp.int32)) \
+            .compile().as_text()
+    finally:
+        paged_chunk_attention.clear_cache()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "while(" not in text
+    assert f"f32[{kvh},{h // kvh},{chunk}," not in text
+    pools = _pool_instructions(text, P)
+    assert sorted(op for _, op in pools.values()) == ["parameter"] * 2, pools
 
 
 @pytest.mark.parametrize("R,T", [(64, 1), (64, 2)])

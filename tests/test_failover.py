@@ -204,9 +204,12 @@ def _warm_engine():
     executable build — far over the sub-second test watchdog deadline
     — so every fleet engine serves one request before it can take
     watched traffic (what a real fleet's readiness probe guarantees;
-    the chaos loadgen's factory does the same)."""
+    the chaos loadgen's factory does the same). The warm-up prompt is
+    two chunks long, as the tests' is: the program of a chunk with
+    cached context behind it is built here too, not under the watchdog
+    (other tokens than the tests' prompt, so nothing of it is adopted)."""
     e = _engine()
-    e.submit("warmup", [list(range(1, 5))], max_new_tokens=4)
+    e.submit("warmup", [list(range(101, 113))], max_new_tokens=4)
     e.run()
     e.results.pop("warmup", None)
     e.logprobs.pop("warmup", None)

@@ -218,8 +218,8 @@ def test_prefill_in_chunks_then_decode_through_both_kinds_of_pool(
     eng = engine(model)
     assert eng.decode_route() == route
     row = ((2, 16), (2, 16))
-    assert eng._layout == [CacheLayer(row, None), CacheLayer(row, 12),
-                           CacheLayer(row, 12)]
+    assert eng._layout == [CacheLayer(row, None, 6), CacheLayer(row, 12, 10),
+                           CacheLayer(row, 12, 10)]
     # a full layer: the allocator's 64 blocks; a window layer: 4 slots x
     # 5 pages and the garbage block
     assert [tuple(p.shape for p in layer) for layer in eng.pools] == [
@@ -242,8 +242,17 @@ def test_prefill_in_chunks_then_decode_through_both_kinds_of_pool(
                     st["chunk_attn_positions_scored"])
     full = 32 + 48 + 64 + 70 + 32 + 33      # the full layer: all cached
     band = 2 * (32 + 40 + 40 + 40 + 32 + 33)    # a ring holds 40
+    if route == "ragged":   # with the interpreter the chunk kernel runs:
+        # a chunk is one tile, and live to it is the band its queries
+        # see, the window's 11 behind its first and the chunk's own
+        band = 2 * (27 + 27 + 27 + 17 + 27 + 12)
     assert live == full + band
-    assert scored == 6 * (16 * 8 + 2 * 5 * 8)
+    # the walk scores a table's pages, the kernel one block of a whole
+    # lane tile of keys (128) whatever the table
+    assert scored == 6 * (16 * 8 + 2 * (128 if route == "ragged" else 5 * 8))
+    # the K/V layers of the six calls, and the path they took
+    assert st["chunk_attn_layer_calls"] == 6 * 3
+    assert st["chunk_attn_kernel_calls"] == (18 if route == "ragged" else 0)
 
 
 def test_a_slot_many_windows_deep_walks_runs_of_pages(ref, model,
@@ -254,6 +263,8 @@ def test_a_slot_many_windows_deep_walks_runs_of_pages(ref, model,
     windows deep."""
     from benchmarks.harness import verify
     from paddle_tpu.ops import paged_cache
+    # without the interpreter no kernel runs here: the walk
+    monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
     monkeypatch.setattr(paged_cache, "CHUNK_RUN_PAGES", 4)
     paged_cache.paged_chunk_attention.clear_cache()
     eng = engine(model, max_slots=2, num_blocks=80, max_blocks_per_seq=32)

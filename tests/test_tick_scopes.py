@@ -281,8 +281,10 @@ def test_linear_attention_layers_carry_scopes_of_their_own(hybrid_engine,
     assert set(scopes) - {None} == HYBRID[program]
     assert scopes[None] < 0.1 * sum(scopes.values()), scopes
     # the recurrence is no afterthought of another scope: the chunkwise
-    # form by its share of the ops, the decode step by its kernel's call
+    # form by its share of the program's ops (the full layer's chunk
+    # kernel is one of them), the decode step by its kernel's call
     if program.startswith("_chunk_prefill"):
+        _, scopes = _lowered(hybrid_engine, program, kernel_bodies=False)
         assert scopes["chunk_delta_state"] > scopes["chunk_attn"]
     else:
         jaxpr = _trace(hybrid_engine, program).jaxpr.jaxpr
@@ -327,6 +329,43 @@ def test_ling_layers_carry_the_scopes_of_both_kinds(ling_engine, kernels,
             ("expert_share_mlp", "experts")]
 
 
+@pytest.mark.parametrize("interpreted", [True, False],
+                         ids=["interpreter", "no-kernel"])
+@pytest.mark.parametrize("name", [
+    "engine", "deepseek_engine", "mimo_engine", "laguna_engine",
+    "hybrid_engine", "ling_engine"])
+def test_the_engine_counts_the_route_the_chunk_program_traced(
+        request, monkeypatch, name, interpreted):
+    """``chunk_attn_kernel_calls`` (ISSUE 48) is counted on the host from
+    ``PagedEngine.chunk_attn_routes``: what it says of each layer is
+    what the traced ``_chunk_prefill`` holds, a chunk kernel's call
+    under the layer's own scope or none."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as ragged
+    if interpreted:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    else:   # (tests/test_flash_segments.py sets it for a whole worker)
+        monkeypatch.delenv("PADDLE_TPU_PALLAS_INTERPRET", raising=False)
+    eng = request.getfixturevalue(name)
+    jitted = (ragged._attend_chunk, paged_cache.paged_chunk_attention)
+    for fn in jitted:       # each keeps what it traced under the other
+        fn.clear_cache()
+    try:
+        calls = _kernel_calls(_trace(eng, "_chunk_prefill").jaxpr.jaxpr)
+        routes = eng.chunk_attn_routes()
+    finally:
+        for fn in jitted:
+            fn.clear_cache()
+    assert [scope for kernel, scope in calls
+            if kernel == "chunk_paged_attention"] == [
+        "chunk_attn_window" if layer.window else "chunk_attn"
+        for layer, route in zip(eng._layout, routes) if route == "kernel"]
+    walked = [r for r in routes if r is not None]
+    assert len(routes) == len(eng._layout)
+    assert set(walked) <= ({"kernel"} if interpreted else {"walk"})
+    # the latent families expand their rows and walk nothing
+    assert bool(walked) == (name not in ("deepseek_engine", "ling_engine"))
+
+
 def test_the_programs_use_the_whole_vocabulary():
     assert set().union(*PROGRAMS.values(), *DEEPSEEK.values(),
                        *LONGCAT.values(), *MIMO.values(),
@@ -366,11 +405,20 @@ def test_serving_kernels_are_named(engine, kernels, route, name):
     assert names == [name] * layers
 
 
-def _lowered(eng, program):
+def _lowered(eng, program, kernel_bodies=True):
     """(opcode histogram, how many ops each scope covers; None for the
-    ops under no scope) of a fresh trace."""
+    ops under no scope) of a fresh trace. Without ``kernel_bodies`` a
+    kernel's call is ONE op of its scope: the body the interpreter
+    inlines under the call's own name is the interpreter's, not the
+    program's."""
     low = _trace(eng, program).lower()
     names = re.findall(r'loc\("(jit\([^"]*)"', low.as_text(debug_info=True))
+    call = "/pallas_call"
+    bodies = tuple({n[:-len(call)] + "/" for n in names if n.endswith(call)
+                    and not n[:-len(call)].endswith(")")})
+    if not kernel_bodies:
+        names = [n for n in names if n.endswith(call)
+                 or not n.startswith(bodies)]
     return (collections.Counter(re.findall(
         r"\b(?:stablehlo|chlo|func)\.[\w.]+", low.as_text())),
         collections.Counter(_scope(n) for n in names))
@@ -394,7 +442,8 @@ def test_scopes_do_not_change_the_program(engine, kernels, monkeypatch,
     # the kernel's wrapper and the chunk programs' write and attentions
     # are jitted: each keeps the jaxpr it traced with the scopes on, and
     # would keep the one traced here without them
-    jitted = (ragged._attend, paged_cache.paged_prefill_write,
+    jitted = (ragged._attend, ragged._attend_chunk,
+              paged_cache.paged_prefill_write,
               paged_cache.paged_chunk_attention,
               paged_cache.paged_packed_attention)
     for fn in jitted:
